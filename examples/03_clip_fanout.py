@@ -24,7 +24,7 @@ def _model():
     return _state["apply"]
 
 
-@task_queue(tpu="v5e-1", cpu=2, memory="8Gi",
+@task_queue(tpu="v5e-1", cpu=2, memory="16Gi",
             autoscaler=QueueDepthAutoscaler(max_containers=16,
                                             tasks_per_container=4))
 def embed_image(url: str = "", pixels=None):

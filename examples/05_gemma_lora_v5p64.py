@@ -43,7 +43,7 @@ def finetune(dataset_path: str = "", steps: int = 100, lr: float = 1e-4,
     step = build_lora_train_step(cfg, opt, scale=lora.lora_scale(lora_rank))
 
     losses = []
-    with mesh:
+    with jax.set_mesh(mesh):
         for i in range(steps):
             # dataset iterator elided: per-host shards of dataset_path
             tokens = jax.random.randint(jax.random.PRNGKey(i), (8, 512), 0,

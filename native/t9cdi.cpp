@@ -111,7 +111,7 @@ std::string emit(const Ctx& ctx) {
     out += "          \"TPU_CHIPS_PER_PROCESS_BOUNDS=1,1,1\",\n";
     out += "          \"TPU_PROCESS_BOUNDS=1,1,1\",\n";
     out += "          \"TPU_SKIP_MDS_QUERY=1\",\n";
-    out += "          \"PJRT_DEVICE=TPU\"\n";
+    out += "          \"JAX_PLATFORMS=tpu\"\n";
     out += "        ]\n";
     out += "      }\n";
     out += "    },\n";
@@ -141,7 +141,7 @@ std::string emit(const Ctx& ctx) {
          + bounds_for(ctx.chips.size()) + "\",\n";
   out += "          \"TPU_PROCESS_BOUNDS=1,1,1\",\n";
   out += "          \"TPU_SKIP_MDS_QUERY=1\",\n";
-  out += "          \"PJRT_DEVICE=TPU\"\n";
+  out += "          \"JAX_PLATFORMS=tpu\"\n";
   out += "        ]";
   if (!ctx.libtpu.empty()) {
     out += ",\n        \"mounts\": [\n";

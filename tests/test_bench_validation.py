@@ -25,13 +25,13 @@ def test_chip_spec_lookup():
     assert v5e.name == "tpu-v5e"
     assert v5e.hbm_gbps == 819.0
     assert chip_spec("TPU v4").name == "tpu-v4"
-    # unknown chips get a GENEROUS ceiling (can't mask impossible numbers)
-    unk = chip_spec("mystery accelerator")
-    assert unk.peak_bf16_tflops > chip_spec("TPU v6e").peak_bf16_tflops
+    # a device the table does not know is an error, never an invented peak
+    with pytest.raises(KeyError, match="mystery accelerator"):
+        chip_spec("mystery accelerator")
 
 
 def test_round2_number_is_rejected():
-    """The exact BENCH_r02 fiction: llama-1b (≈2.47 GB bf16 streamed),
+    """The round-2 fiction: llama-1b (≈2.47 GB bf16 streamed),
     batch 8, 0.109 ms/step on a v5e ⇒ ~23 TB/s. Must be flagged."""
     spec = chip_spec("TPU v5 lite")
     phys = decode_physics(step_ms=0.109, batch=8,
@@ -55,7 +55,7 @@ def test_plausible_number_passes():
 
 
 def test_kernel_mfu_rejection():
-    """BENCH_r02's flash '0.029 ms' at [4,2048,16,128] ⇒ ~4.7 PFLOP/s on a
+    """Round 2's flash '0.029 ms' at [4,2048,16,128] ⇒ ~4.7 PFLOP/s on a
     197-TFLOP/s chip. Must be flagged."""
     spec = chip_spec("TPU v5 lite")
     b, t, h, d = 4, 2048, 16, 128

@@ -81,7 +81,8 @@ async def test_gang_containers_run_with_rank_env():
         coords = {r["coord"] for r in results}
         assert len(coords) == 1 and list(coords)[0]
         assert all(r["chips"] == "0,1,2,3" for r in results)
-        assert all(r["accel"] == "v5p-8" for r in results)
+        # tpu9's "v5p-8" (8 chips) in the platform's spelling (16 cores)
+        assert all(r["accel"] == "v5p-16" for r in results)
         assert sorted(r["tpu_worker_id"] for r in results) == ["0", "1"]
 
         # chips are reserved on both hosts while the gang runs

@@ -91,7 +91,8 @@ def load_engine():
     from tpu9.types import parse_tpu_spec
 
     # the worker handed this container a full v5e-8 host slice
-    assert os.environ.get("TPU_ACCELERATOR_TYPE") == "v5e-8", \\
+    # (in the platform's spelling, which libtpu reads)
+    assert os.environ.get("TPU_ACCELERATOR_TYPE") == "v5litepod-8", \\
         os.environ.get("TPU_ACCELERATOR_TYPE")
     assert len(os.environ.get("TPU_VISIBLE_CHIPS", "").split(",")) == 8
 

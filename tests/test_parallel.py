@@ -92,7 +92,7 @@ def test_fsdp_train_step_loss_decreases():
 
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0,
                                 TINY.vocab_size)
-    with mesh:
+    with jax.set_mesh(mesh):
         losses = []
         for _ in range(5):
             state, metrics = step(state, tokens)
@@ -112,7 +112,7 @@ def test_lora_fsdp_train_step():
     step = build_lora_train_step(TINY, opt, scale=2.0, remat=True)
     tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 32), 0,
                                 TINY.vocab_size)
-    with mesh:
+    with jax.set_mesh(mesh):
         losses = []
         for _ in range(5):
             adapters, opt_state, metrics = step(adapters, opt_state, sharded,

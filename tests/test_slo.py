@@ -332,6 +332,19 @@ def test_ingest_heartbeat_records_engine_series_and_prices_mfu():
     assert mfu == pytest.approx(100 * 1.97e12 / (197.0 * 1e12))
 
 
+def test_ingest_heartbeat_prices_nothing_for_an_unknown_device():
+    obs, _ = _observer()
+    obs.ingest_heartbeat(
+        "c2", "ws", "st", token_pressure=0.4, active_streams=2,
+        extra={"tokens_per_sec": 100.0,
+               "decode_bytes_per_token_per_chip": 8.19e9,
+               "decode_flops_per_token_per_chip": 1.97e12,
+               "device_kind": "cpu"})
+    names = obs.timeline.series_names()
+    assert "engine.c2.tokens_per_sec" in names
+    assert "engine.c2.mbu" not in names and "engine.c2.mfu" not in names
+
+
 async def test_sampler_tick_records_router_series_and_folds_burn():
     stub = Stub(stub_id="s1", workspace_id="ws")
     obs, router = _observer([stub])

@@ -107,6 +107,43 @@ async def test_zygote_child_runs_jax(tmp_path):
         await zy.stop()
 
 
+async def test_zygote_child_with_a_tpu_assignment_leaves_the_cpu_pin(
+        tmp_path, monkeypatch):
+    """ISSUE 21 finding 2: the zygote imports jax pinned to ``cpu`` and a
+    forked child re-points ``jax_platforms`` from its own env — so the env a
+    TPU assignment carries must name the platform, or a plain
+    ``@endpoint(tpu=...)`` container silently computes on the CPU."""
+    from tpu9.types import ContainerRequest
+    from tpu9.worker.tpu_manager import TpuDeviceManager
+    monkeypatch.delenv("TPU9_FAKE_TPU_CHIPS", raising=False)
+    monkeypatch.setattr(TpuDeviceManager, "_inventory",
+                        staticmethod(lambda: ["/dev/vfio/1"]))
+    assignment = TpuDeviceManager(generation="v5e").assign(
+        ContainerRequest(container_id="c1", tpu="v5e-1"))
+    assert assignment.devices == ["/dev/vfio/1"]
+    assert assignment.env["TPU_ACCELERATOR_TYPE"] == "v5litepod-1"
+    assert "PJRT_DEVICE" not in assignment.env
+
+    zy = ZygoteClient(str(tmp_path / "zy.sock"))
+    assert await zy.ensure_started()
+    try:
+        mod_dir = tmp_path / "mods"
+        mod_dir.mkdir()
+        # config only: initialising the backend would need the chip
+        (mod_dir / "whichplatform.py").write_text(
+            "import jax\n"
+            "print('jax_platforms=' + str(jax.config.jax_platforms))\n")
+        proc = await zy.spawn(
+            {**assignment.env, "PYTHONPATH": str(mod_dir),
+             "PATH": os.environ.get("PATH", "")},
+            str(tmp_path), "whichplatform")
+        out, code = await asyncio.gather(_pump_all(proc.stdout), proc.wait())
+        assert code == 0, out
+        assert "jax_platforms=tpu" in out
+    finally:
+        await zy.stop()
+
+
 async def test_zygote_kill_and_fallback(tmp_path):
     """A zygote that dies mid-flight must not wedge the runtime: spawn
     raises, ProcessRuntime falls back to exec."""

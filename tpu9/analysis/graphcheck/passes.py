@@ -174,7 +174,7 @@ def walk_eqns(jaxpr):
 
 
 def _sub_jaxprs(v):
-    import jax.core as core
+    from jax.extend import core
     if isinstance(v, core.ClosedJaxpr):
         yield v.jaxpr
     elif isinstance(v, core.Jaxpr):

@@ -199,17 +199,23 @@ class FleetObserver:
                 note(stub_id, ready_s)
         # MFU/MBU priced control-plane-side from the engine's physics
         # constants (bytes / FLOPs per token per chip) × tokens/sec,
-        # against the chip's public peaks — honest ~0 on CPU hosts
+        # against the chip's public peaks. A replica on a device the peak
+        # table does not know (a CPU host) publishes neither series.
         tps = _num(stats, "tokens_per_sec")
         bpt = _num(stats, "decode_bytes_per_token_per_chip")
         fpt = _num(stats, "decode_flops_per_token_per_chip")
         if tps > 0 and (bpt > 0 or fpt > 0):
             from ..benchsuite.physics import chip_spec
-            spec = chip_spec(str(stats.get("device_kind", "")))
-            self.timeline.record(prefix + "mbu",
-                                 tps * bpt / (spec.hbm_gbps * 1e9))
-            self.timeline.record(prefix + "mfu",
-                                 tps * fpt / (spec.peak_bf16_tflops * 1e12))
+            try:
+                spec = chip_spec(str(stats.get("device_kind", "")))
+            except KeyError:
+                spec = None
+            if spec is not None:
+                self.timeline.record(prefix + "mbu",
+                                     tps * bpt / (spec.hbm_gbps * 1e9))
+                self.timeline.record(
+                    prefix + "mfu",
+                    tps * fpt / (spec.peak_bf16_tflops * 1e12))
         self.goodput.engine_sample(container_id, workspace_id, stub_id,
                                    stats)
 

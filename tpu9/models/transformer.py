@@ -132,7 +132,8 @@ def _act(x: jnp.ndarray, kind: str) -> jnp.ndarray:
 def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
                 positions: jnp.ndarray, sin, cos,
                 kv_cache: Optional[Params], layer_idx: int,
-                cache_len: Optional[jnp.ndarray], decode: bool):
+                cache_len: Optional[jnp.ndarray], decode: bool,
+                mesh=None):
     b, t, _ = x.shape
     h = rms_norm(x, layer["attn_norm"], cfg.norm_eps, cfg.norm_offset)
     q = maybe_matmul(h, layer["wq"]).reshape(b, t, cfg.n_heads, cfg.head_dim)
@@ -143,7 +144,7 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
 
     new_cache = None
     if kv_cache is None:
-        out = attention(q, k, v, causal=True)
+        out = attention(q, k, v, causal=True, mesh=mesh)
     elif decode and "table" in kv_cache:
         # paged decode: scatter this token's k/v into the slot's physical
         # pool block, then block-table paged attention over the prefix.
@@ -166,13 +167,13 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
             k_sc = kv_cache["k_scale"][layer_idx].at[bi, oi].set(sk)
             v_sc = kv_cache["v_scale"][layer_idx].at[bi, oi].set(sv)
             out = paged_attention_dispatch(q, k_pool, v_pool, table,
-                                           cache_len, k_sc, v_sc)
+                                           cache_len, k_sc, v_sc, mesh=mesh)
             new_cache = (k_pool, v_pool, k_sc, v_sc)
         else:
             k_pool = kv_cache["k"][layer_idx].at[bi, oi].set(k[:, 0])
             v_pool = kv_cache["v"][layer_idx].at[bi, oi].set(v[:, 0])
             out = paged_attention_dispatch(q, k_pool, v_pool, table,
-                                           cache_len)
+                                           cache_len, mesh=mesh)
             new_cache = (k_pool, v_pool)
     elif "table" in kv_cache:
         # paged multi-token VERIFY (speculative decoding): scatter all T
@@ -212,7 +213,7 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
             kv_cache["v"][layer_idx], v,
             (0, positions[0, 0], 0, 0)) if b == 1 else _scatter_kv(
                 kv_cache["v"][layer_idx], v, positions)
-        out = decode_attention(q, k_cache, v_cache, cache_len)
+        out = decode_attention(q, k_cache, v_cache, cache_len, mesh=mesh)
         new_cache = (k_cache, v_cache)
     elif cache_len is not None:
         # CHUNKED prefill: write this chunk at its PER-ROW offset, then
@@ -246,7 +247,7 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
             kv_cache["k"][layer_idx], k, (0, 0, 0, 0))
         v_cache = jax.lax.dynamic_update_slice(
             kv_cache["v"][layer_idx], v, (0, 0, 0, 0))
-        out = attention(q, k, v, causal=True)
+        out = attention(q, k, v, causal=True, mesh=mesh)
         new_cache = (k_cache, v_cache)
 
     out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)
@@ -282,13 +283,17 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
                     cache_len: Optional[jnp.ndarray] = None,
                     decode: bool = False,
                     return_hidden: bool = False,
-                    return_moe_aux: bool = False):
+                    return_moe_aux: bool = False,
+                    mesh=None):
     """Run the decoder.
 
     - train/eval: ``decoder_forward(params, tokens, cfg)`` → logits [B,T,V]
     - prefill:   pass ``kv_cache`` (positions default to arange) → (logits, cache)
     - decode:    ``decode=True`` with tokens [B,1], positions [B,1], cache_len [B]
                  → (logits [B,1,V], cache)
+    - ``mesh``:  the serving mesh when params and cache are sharded over one
+                 (``MeshPolicy.mesh``) — the attention kernels then run per
+                 chip on its own heads
     """
     b, t = tokens.shape
     if positions is None:
@@ -315,7 +320,7 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
     moe_balance = jnp.zeros((), jnp.float32)
     for i, layer in enumerate(params["layers"]):
         x, updated = _attn_block(layer, x, cfg, positions, sin, cos,
-                                 kv_cache, i, cache_len, decode)
+                                 kv_cache, i, cache_len, decode, mesh)
         if updated is not None:
             updates.append(updated)
         x, aux = _mlp_block(layer, x, cfg)

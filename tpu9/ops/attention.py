@@ -12,6 +12,13 @@ Design notes (see /opt/skills/guides/pallas_guide.md):
 
 On CPU (tests) the same kernel runs with ``interpret=True``; model code picks
 the XLA path automatically when not on TPU.
+
+The dispatchers (:func:`attention`, :func:`decode_attention`,
+:func:`paged_attention_dispatch`) take the serving mesh: GSPMD cannot
+partition a Mosaic kernel, so on a mesh each chip runs the kernel on its own
+heads under ``shard_map``. Each has a ``*_kernel_declined`` twin that says
+why a shape takes the XLA oracle instead — the engine logs and reports it at
+build, so a TPU replica that is not running the kernels is visible.
 """
 
 from __future__ import annotations
@@ -22,8 +29,37 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from ..utils import on_tpu
 
 NEG_INF = -1e30
+
+# mesh axis the q/kv HEAD axis shards over (serving.shard's tp axis)
+HEAD_AXIS = "tp"
+_KERNEL_HEAD_DIMS = (64, 128, 256)
+# every head-carrying operand keeps its heads on axis 2: q [B,T,QH,D],
+# k/v cache [B,S,KH,D], paged pool [N,BS,KH,D], scale planes [N,BS,KH]
+_HEADS4 = P(None, None, HEAD_AXIS, None)
+_HEADS3 = P(None, None, HEAD_AXIS)
+
+
+def _per_chip_heads(kernel, mesh, in_specs):
+    """``kernel`` as is on one chip; on a mesh, under ``shard_map`` with
+    the head axis split over ``tp`` (table/length operands replicated, as
+    is everything across the other mesh axes). The result is q-shaped."""
+    if mesh is None:
+        return kernel
+    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                         out_specs=_HEADS4, check_vma=False)
+
+
+def _no_kernel_for(head_dim: int) -> str:
+    if not on_tpu():
+        return f"backend {jax.default_backend()} is not tpu"
+    if head_dim not in _KERNEL_HEAD_DIMS:
+        return f"head_dim {head_dim} not in {_KERNEL_HEAD_DIMS}"
+    return ""
 
 
 def _expand_gqa(k: jnp.ndarray, q_heads: int) -> jnp.ndarray:
@@ -171,19 +207,42 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out.reshape(batch, q_heads, t, head_dim).transpose(0, 2, 1, 3)
 
 
+def flash_kernel_declined(t: int, s: int, head_dim: int,
+                          kv_offset: int = 0) -> str:
+    """Why :func:`attention` takes the XLA path for these shapes ('' = the
+    flash kernel runs)."""
+    if (why := _no_kernel_for(head_dim)):
+        return why
+    if kv_offset:
+        return "the flash kernel has no kv_offset"
+    if t % 128 or s % 128:
+        return f"sequence ({t}, {s}) is not a multiple of the 128 block"
+    return ""
+
+
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-              causal: bool = True, kv_offset: int = 0) -> jnp.ndarray:
+              causal: bool = True, kv_offset: int = 0,
+              mesh=None) -> jnp.ndarray:
     """Dispatch: pallas flash on TPU for block-aligned shapes, XLA otherwise."""
-    from ..utils import on_tpu as _on_tpu
-    t, s = q.shape[1], k.shape[1]
-    if (_on_tpu() and kv_offset == 0 and t % 128 == 0 and s % 128 == 0
-            and q.shape[-1] in (64, 128, 256)):
-        return flash_attention(q, k, v, causal=causal)
-    return xla_attention(q, k, v, causal=causal, kv_offset=kv_offset)
+    if flash_kernel_declined(q.shape[1], k.shape[1], q.shape[-1], kv_offset):
+        return xla_attention(q, k, v, causal=causal, kv_offset=kv_offset)
+    return _per_chip_heads(
+        functools.partial(flash_attention, causal=causal), mesh,
+        (_HEADS4, _HEADS4, _HEADS4))(q, k, v)
+
+
+def ragged_kernel_declined(s_max: int, head_dim: int) -> str:
+    """Why :func:`decode_attention` takes the XLA path ('' = the ragged
+    kernel runs)."""
+    if (why := _no_kernel_for(head_dim)):
+        return why
+    if s_max < 512 or s_max % 256:
+        return f"cache length {s_max} is not a multiple of 256 from 512 up"
+    return ""
 
 
 def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
-                     cache_len: jnp.ndarray) -> jnp.ndarray:
+                     cache_len: jnp.ndarray, mesh=None) -> jnp.ndarray:
     """Single-token decode attention against a contiguous KV cache.
 
     q: [B, 1, QH, D]; k_cache/v_cache: [B, S_max, KH, D]; cache_len: [B]
@@ -194,13 +253,12 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     skipped blocks are saved bandwidth); otherwise one fused XLA graph with
     a masked softmax over the full cache.
     """
-    s_max = k_cache.shape[1]
-    from ..utils import on_tpu as _on_tpu
-    if (_on_tpu() and s_max >= 512 and s_max % 256 == 0
-            and q.shape[-1] in (64, 128, 256)):
-        from .paged_attention import ragged_decode_attention
-        return ragged_decode_attention(q, k_cache, v_cache, cache_len)
-    return xla_decode_attention(q, k_cache, v_cache, cache_len)
+    if ragged_kernel_declined(k_cache.shape[1], q.shape[-1]):
+        return xla_decode_attention(q, k_cache, v_cache, cache_len)
+    from .paged_attention import ragged_decode_attention
+    return _per_chip_heads(
+        ragged_decode_attention, mesh,
+        (_HEADS4, _HEADS4, _HEADS4, P()))(q, k_cache, v_cache, cache_len)
 
 
 def chunk_prefill_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -265,30 +323,42 @@ def paged_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     return chunk_prefill_attention(q, k, v, positions)
 
 
+def paged_kernel_declined(block_s: int, head_dim: int) -> str:
+    """Why :func:`paged_attention_dispatch` takes the XLA oracle ('' = the
+    paged kernel runs)."""
+    if (why := _no_kernel_for(head_dim)):
+        return why
+    if block_s % 128:
+        return f"kv block {block_s} is not a multiple of 128"
+    return ""
+
+
 def paged_attention_dispatch(q: jnp.ndarray, k_pool: jnp.ndarray,
                              v_pool: jnp.ndarray, block_table: jnp.ndarray,
                              cache_len: jnp.ndarray,
                              k_scale: jnp.ndarray = None,
-                             v_scale: jnp.ndarray = None) -> jnp.ndarray:
+                             v_scale: jnp.ndarray = None,
+                             mesh=None) -> jnp.ndarray:
     """Block-table paged decode dispatch: pallas kernel on TPU (physical
     blocks DMA'd by table lookup in the index map — no densify copy),
     gather + XLA oracle elsewhere. ``k_scale``/``v_scale`` [N, BS, KH]
     mark an int8 pool — the kernel dequantizes in-register after the DMA,
     so HBM only ever moves the int8 payload + the per-vector scales."""
-    from ..utils import on_tpu as _on_tpu
     from .paged_attention import (paged_decode_attention,
                                   paged_decode_attention_quant,
                                   xla_paged_decode_attention)
-    block_s = k_pool.shape[1]
-    if (_on_tpu() and block_s % 128 == 0
-            and q.shape[-1] in (64, 128, 256)):
-        if k_scale is not None:
-            return paged_decode_attention_quant(
+    if paged_kernel_declined(k_pool.shape[1], q.shape[-1]):
+        return xla_paged_decode_attention(q, k_pool, v_pool, block_table,
+                                          cache_len, k_scale, v_scale)
+    if k_scale is not None:
+        return _per_chip_heads(
+            paged_decode_attention_quant, mesh,
+            (_HEADS4, _HEADS4, _HEADS4, _HEADS3, _HEADS3, P(), P()))(
                 q, k_pool, v_pool, k_scale, v_scale, block_table, cache_len)
-        return paged_decode_attention(q, k_pool, v_pool, block_table,
-                                      cache_len)
-    return xla_paged_decode_attention(q, k_pool, v_pool, block_table,
-                                      cache_len, k_scale, v_scale)
+    return _per_chip_heads(
+        paged_decode_attention, mesh,
+        (_HEADS4, _HEADS4, _HEADS4, P(), P()))(
+            q, k_pool, v_pool, block_table, cache_len)
 
 
 def xla_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,

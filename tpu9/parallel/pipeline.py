@@ -21,7 +21,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from .compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Params = Any
@@ -98,6 +97,7 @@ def pipeline_forward(block_fn: Callable[[Params, jnp.ndarray], jnp.ndarray],
         outbuf = jnp.where(stage == s - 1, outbuf, 0.0)
         return jax.lax.psum(outbuf, axis)
 
-    out = shard_map(
-        _pipe, mesh=mesh, in_specs=(p_specs, x_spec), out_specs=x_spec)(stacked_params, xs)
+    out = jax.shard_map(
+        _pipe, mesh=mesh, in_specs=(p_specs, x_spec), out_specs=x_spec,
+        check_vma=False)(stacked_params, xs)
     return out.reshape(b, *x.shape[1:])

@@ -19,7 +19,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from .compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -59,7 +58,8 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     spec = P(None, axis, None, None)
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+        jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+        out_specs=spec, check_vma=False)
     def _ring(q_blk, k_blk, v_blk):
         idx = jax.lax.axis_index(axis)
         tq = q_blk.shape[1]

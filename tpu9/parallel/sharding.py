@@ -150,11 +150,10 @@ def _fit_spec(x, spec: P, mesh: Mesh) -> P:
 
 
 def constrain(x: jnp.ndarray, spec: P) -> jnp.ndarray:
-    """Activation sharding hint inside jit (no-op outside a mesh context —
-    but a BAD spec must still raise: swallowing an axis-name typo would
-    silently drop the layout hint and ship a perf/memory regression)."""
-    env = getattr(jax.interpreters.pxla, "thread_resources", None)
-    mesh = getattr(getattr(env, "env", None), "physical_mesh", None)
-    if mesh is None or mesh.empty:
+    """Activation sharding hint inside jit. The mesh is the one the caller
+    entered with ``jax.set_mesh``; outside any it is a no-op — but a BAD
+    spec must still raise: swallowing an axis-name typo would silently
+    drop the layout hint and ship a perf/memory regression."""
+    if jax.sharding.get_abstract_mesh().empty:
         return x                     # genuinely outside any mesh context
     return jax.lax.with_sharding_constraint(x, spec)

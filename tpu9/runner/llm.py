@@ -654,7 +654,10 @@ async def amain() -> None:
                               "graph_compile_stall_s",
                               "decode_bytes_per_token_per_chip",
                               "decode_flops_per_token_per_chip",
-                              "device_kind"):
+                              # the devices the engine is placed on, as
+                              # its own jax reports them
+                              "device_platform", "device_kind",
+                              "device_count"):
                         if k in stats:
                             extra[k] = stats[k]
                     pc = stats.get("prefix_cache")

@@ -14,8 +14,9 @@ runner module — skipping interpreter boot + imports entirely.
 Fork-safety contract (verified by tests/test_zygote.py):
 - the zygote imports but NEVER runs a jax computation → no backend client,
   no XLA thread pools; after warmup only MainThread exists
-- children initialize their own backend post-fork (CPU or the TPU tunnel,
-  per their env), so device state is never shared across forks
+- children initialize their own backend post-fork (``JAX_PLATFORMS`` of
+  their env: ``cpu``, or ``tpu`` for a TPU assignment), so device state is
+  never shared across forks
 
 Protocol (SOCK_STREAM unix socket, one connection per spawn):
   worker → zygote: JSON line {"env": {...}, "cwd": ..., "module": ...,

@@ -25,7 +25,7 @@ from ..utils.aio import cancellable_wait, spawn
 _ENV_ALLOWLIST = ("PATH", "HOME", "LANG", "TERM")
 
 # runner modules eligible for zygote (pre-warmed fork) starts. llm/build
-# are excluded: llm containers dial accelerators with env the fork must
+# are excluded: llm containers take accelerators with env the fork must
 # not half-inherit, builds run arbitrary shell.
 _ZYGOTE_MODULES = ("tpu9.runner.endpoint", "tpu9.runner.taskqueue",
                    "tpu9.runner.function")

@@ -5,7 +5,7 @@ Reference analogue: ``sdk/src/beta9/abstractions/endpoint.py:43``
 
     from tpu9 import endpoint
 
-    @endpoint(cpu=1, memory="2Gi", tpu="v5e-1", keep_warm_seconds=30)
+    @endpoint(cpu=1, memory="16Gi", tpu="v5e-1", keep_warm_seconds=30)
     def predict(prompt: str = ""):
         return {"output": model(prompt)}
 

@@ -32,6 +32,7 @@ split-off responsibilities live next door with an explicit boundary
 from __future__ import annotations
 
 import asyncio
+import logging
 import time
 from dataclasses import dataclass, field
 from typing import Any, Optional
@@ -78,9 +79,9 @@ class EngineConfig:
     top_p: float = 1.0
     eos_id: int = -1              # -1 disables EOS stopping
     # decode-window buckets: K steps run on-device (lax.scan) per host
-    # sync. Each host↔device round-trip costs wall-clock (dramatically so
-    # over a TPU relay), so the loop amortizes it over K tokens; K drops
-    # to 1 whenever requests wait for admission.
+    # sync. Each host↔device round-trip costs wall-clock, so the loop
+    # amortizes it over K tokens; K drops to 1 whenever requests wait for
+    # admission.
     decode_steps: tuple = (1, 4, 16)
     # ---- paged KV (VERDICT r03 #5) ----
     # block size of the shared KV pool; 0 = legacy dense [B, S] cache
@@ -422,10 +423,13 @@ class InferenceEngine:
             nparams += size
         self._phys_bytes_per_token_per_chip = wb / n_chips
         self._phys_flops_per_token_per_chip = 2.0 * nparams / n_chips
-        try:
-            self._device_kind = jax.devices()[0].device_kind
-        except Exception:   # noqa: BLE001 — physics labels are best-effort
-            self._device_kind = ""
+        # the devices this engine is PLACED on, as jax reports them — the
+        # runner's /health and heartbeat carry these, so a replica that
+        # came up on the wrong backend is visible from outside the process
+        self._devices = self.policy.devices()
+        # which attention path each phase takes (pallas kernel, or the XLA
+        # oracle with the reason the kernel declined these shapes)
+        self._attention = self._attention_paths()
         # ---- replica health plane (ISSUE 14) ----
         # liveness watermark: monotonic progress counters + dispatch/
         # progress stamps the runner-side watchdog classifies from. All
@@ -459,6 +463,29 @@ class InferenceEngine:
         # the forensic record HERE before fan-out clears the evidence;
         # the runner ships it to the gateway on the next heartbeat
         self.last_postmortem: Optional[dict] = None
+
+    def _attention_paths(self) -> dict:
+        """``{"decode", "prefill"}`` → ``"pallas"`` or ``"xla: <why>"`` for
+        this engine's shapes, from the same predicates the dispatchers in
+        ``ops.attention`` decide with. A TPU replica whose decode declined
+        the kernel still serves correctly through the oracle, so say why
+        once here, where an operator reads the bring-up log."""
+        from ..ops import attention as ops
+        hd = self.cfg.head_dim
+        if self.paged:
+            decode = ops.paged_kernel_declined(self.ecfg.kv_block_size, hd)
+            prefill = "chunked prefill has no kernel"
+        else:
+            decode = ops.ragged_kernel_declined(self.ecfg.max_seq_len, hd)
+            prefill = "; ".join(sorted(
+                {ops.flash_kernel_declined(bk, bk, hd)
+                 for bk in self._buckets} - {""}))
+        if decode and self._devices[0].platform == "tpu":
+            logging.getLogger("tpu9.serving").warning(
+                "decode attention runs the XLA oracle, not the pallas "
+                "kernel: %s", decode)
+        return {"decode": f"xla: {decode}" if decode else "pallas",
+                "prefill": f"xla: {prefill}" if prefill else "pallas"}
 
     # -- compiled steps (serving.graphs) + scheduling (serving.schedule) ----
     # Thin delegates: the implementations moved out with the ISSUE 9
@@ -1027,7 +1054,13 @@ class InferenceEngine:
             self._phys_bytes_per_token_per_chip
         out["decode_flops_per_token_per_chip"] = \
             self._phys_flops_per_token_per_chip
-        out["device_kind"] = self._device_kind
+        out["device_platform"] = self._devices[0].platform
+        out["device_kind"] = self._devices[0].device_kind
+        out["device_count"] = len(self._devices)
+        out["attention_decode"] = self._attention["decode"]
+        out["attention_prefill"] = self._attention["prefill"]
+        # tpu_custom_call count per AOT-compiled graph (precompile only)
+        out["graph_kernels"] = dict(self.graphs.kernel_calls)
         # topology (ISSUE 9): flat scalars so the runner heartbeat can
         # forward them into the store hash behind /api/v1/metrics
         # "engines" unchanged — tp/fsdp/n_chips plus live per-chip HBM
@@ -1038,7 +1071,10 @@ class InferenceEngine:
         out["topo_tp"] = topo["tp"]
         out["topo_fsdp"] = topo["fsdp"]
         out["topo_n_chips"] = topo["n_chips"]
-        out["hbm_used_gb_per_chip"] = self.policy.hbm_used_gb_per_chip()
+        mem = self.policy.memory_stats()     # one sweep per stats() read
+        out["hbm_used_gb_by_chip"] = self.policy.hbm_gb_by_chip(stats=mem)
+        out["hbm_used_gb_per_chip"] = max(out["hbm_used_gb_by_chip"],
+                                          default=0.0)
         # ---- replica health plane (ISSUE 14) ----
         # liveness watermark: progress counters + dispatch/progress ages
         # the runner-side watchdog classifies ok/degraded/stalled from.
@@ -1050,12 +1086,14 @@ class InferenceEngine:
             if self._last_dispatch_mono else -1.0)
         out["last_progress_age_s"] = round(
             now_m - self._last_progress_mono, 3)
-        # HBM watermarks: peak tracks the read-path samples (heartbeat
-        # cadence); predicted is the planner-arithmetic residency of the
-        # exact trees this engine holds; limit is the chip's capacity
-        # (0.0 where the backend has no memory stats, i.e. CPU)
-        self._hbm_peak_gb = max(self._hbm_peak_gb,
-                                out["hbm_used_gb_per_chip"])
+        # HBM watermarks: peak is the device's own high-water mark (max
+        # across the submesh), never below a read-path sample; predicted is
+        # the planner-arithmetic residency of the exact trees this engine
+        # holds; limit is the chip's capacity (0.0 where the backend has no
+        # memory stats, i.e. CPU)
+        self._hbm_peak_gb = max(
+            self._hbm_peak_gb, out["hbm_used_gb_per_chip"],
+            *self.policy.hbm_gb_by_chip("peak_bytes_in_use", stats=mem))
         out["hbm_peak_gb_per_chip"] = self._hbm_peak_gb
         out["hbm_predicted_gb_per_chip"] = self.hbm_predicted_gb_per_chip
         out["hbm_limit_gb_per_chip"] = self._hbm_limit_gb
@@ -1710,7 +1748,7 @@ class InferenceEngine:
         """Prefill + cache splice for one request. Returns the slot's
         first-token DEVICE value — the serve loop syncs a whole admission
         batch in one host round-trip (each blocking ``int()`` here would
-        cost a full RTT, brutal over a TPU relay)."""
+        cost a full one)."""
         t0_mono, t0_wall = time.monotonic(), time.time()
         self._obs_admit_start(req, t0_mono, t0_wall)
         il0 = self._stats["admit_interleaved_windows"]
@@ -1851,7 +1889,6 @@ class InferenceEngine:
             # every known request with the cause, and make generate()
             # fail FAST from now on (the loop is never restarted; the
             # runner's health surface flips on engine_dead)
-            import logging
             logging.getLogger("tpu9.serving").exception("engine loop died")
             self._dead_reason = f"{type(exc).__name__}: {exc}"
             # black box FIRST (ISSUE 14): _fail_all_requests clears the
@@ -2031,8 +2068,7 @@ class InferenceEngine:
 
     def _drain_windows(self) -> None:
         """Host-process every in-flight window. ONE transfer for all of
-        them — N sequential device_gets would pay N round-trips over a
-        TPU relay."""
+        them — N sequential device_gets would pay N round-trips."""
         wins, self._deferred_windows = self._deferred_windows, []
         if not wins:
             return

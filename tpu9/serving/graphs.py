@@ -64,6 +64,10 @@ class GraphFactory:
         # jax.jit compiles lazily at that first call
         self.post_seal_stall_s = 0.0
         self._sealed = False
+        # graph name -> ``tpu_custom_call`` count in its AOT-compiled HLO
+        # (:meth:`precompile`): the evidence that a step really contains
+        # the pallas kernels rather than having taken the XLA oracle
+        self.kernel_calls: dict[str, int] = {}
 
     def _build(self, key, builder):
         """Cache-or-build a graph under ``key`` — the ONE miss path, so
@@ -111,7 +115,8 @@ class GraphFactory:
             positions = cache_len[:, None]          # next position per slot
             logits, kv_cache = decoder_forward(
                 params, last_token, cfg, positions=positions,
-                kv_cache=kv_cache, cache_len=cache_len + 1, decode=True)
+                kv_cache=kv_cache, cache_len=cache_len + 1, decode=True,
+                mesh=policy.mesh)
             rng, sub = jax.random.split(rng)
             next_tok = sample_logits(logits[:, -1], sub,
                                      temperature=ecfg.temperature,
@@ -165,7 +170,8 @@ class GraphFactory:
             positions = cache_len[:, None] + jnp.arange(t)[None, :]
             logits, kv_cache = decoder_forward(
                 params, tokens, cfg, positions=positions,
-                kv_cache=kv_cache, cache_len=cache_len + t, decode=False)
+                kv_cache=kv_cache, cache_len=cache_len + t, decode=False,
+                mesh=policy.mesh)
             rng, sub = jax.random.split(rng)
             out = sample_logits(logits, sub, temperature=ecfg.temperature,
                                 top_k=ecfg.top_k,
@@ -195,7 +201,8 @@ class GraphFactory:
                 # real token and the per-layer k/v for the prefix.
                 logits, cache = decoder_forward(
                     params, tokens, cfg,
-                    kv_cache=init_kv_cache(cfg, 1, bucket), decode=False)
+                    kv_cache=init_kv_cache(cfg, 1, bucket), decode=False,
+                    mesh=policy.mesh)
                 last = logits[0, length - 1]
                 return last, policy.constrain_kv(cache)
 
@@ -233,7 +240,8 @@ class GraphFactory:
         positions = offset + jnp.arange(c)[None, :]
         logits, scratch = decoder_forward(
             params, tok_row[None, :], self.cfg, positions=positions,
-            kv_cache=scratch, cache_len=offset + c, decode=False)
+            kv_cache=scratch, cache_len=offset + c, decode=False,
+            mesh=self.policy.mesh)
         last = jax.lax.dynamic_index_in_dim(
             logits[0], last_idx, axis=0, keepdims=False)
         return last, scratch
@@ -478,6 +486,8 @@ class GraphFactory:
                 if isinstance(key, tuple) else str(key)
             timings[f"compile_{name}_s"] = \
                 round(time.perf_counter() - t0, 4)
+            self.kernel_calls[name] = \
+                self.compiled[key].as_text().count("tpu_custom_call")
         self.seal()
         return timings
 

@@ -39,18 +39,28 @@ def resolve_preset(name: str, quantize: Optional[str] = None):
     return presets[base], quantized
 
 
-def build_params(name: str, seed: int = 0, quantize: Optional[str] = None):
+def build_params(name: str, seed: int = 0, quantize: Optional[str] = None,
+                 policy=None):
     """Random-initialized params for a preset (weight loading from a real
     checkpoint is ``tpu9.serving.weights``' concern). int8 presets are
-    synthesized directly at int8 so the bf16 intermediate never exists."""
+    synthesized directly at int8 so the bf16 intermediate never exists.
+    A mesh ``policy`` builds every leaf straight into its shard — a model
+    sharded BECAUSE it fits no single chip (llama3-8b bf16 on v5e-4) must
+    never be materialized on the first one."""
     import jax
     cfg, quantized = resolve_preset(name, quantize)
-    rng = jax.random.PRNGKey(seed)
+    return init_params(cfg, quantized, jax.random.PRNGKey(seed), policy), cfg
+
+
+def init_params(cfg, quantized: bool, rng, policy=None):
+    """:func:`build_params` for an explicit DecoderConfig."""
     if quantized:
-        from ..ops.quant import init_quantized_decoder
-        return init_quantized_decoder(rng, cfg), cfg
-    from ..models import init_decoder
-    return init_decoder(rng, cfg), cfg
+        from ..ops.quant import init_quantized_decoder as init
+    else:
+        from ..models import init_decoder as init
+    if policy is None:
+        return init(rng, cfg)
+    return policy.build_params(lambda r: init(r, cfg), rng)
 
 
 def abstract_params_for(cfg, quantized: bool = False):
@@ -184,6 +194,7 @@ def load_engine(name: str, *, max_batch: int = 8, max_seq_len: int = 2048,
 
         from ..observability import coldstart as _cs
         from ..observability.trace import tracer
+        from ..utils import on_tpu
         spec, _ = params_spec(name, quantize)
         engine = InferenceEngine(spec, cfg, ecfg, policy=policy)
         timings: dict = {}
@@ -209,10 +220,15 @@ def load_engine(name: str, *, max_batch: int = 8, max_seq_len: int = 2048,
                                     name="tpu9-compile-ahead", daemon=True)
         compiler.start()
         params, _ = build_params(name, seed=seed,    # ∥ the compile
-                                 quantize=quantize)
+                                 quantize=quantize, policy=policy)
         load_end = time.monotonic()
         compiler.join()
         if errors:
+            if on_tpu():
+                # lazy recompilation would meet the same compiler error at
+                # the first request, later and less legibly
+                raise RuntimeError(
+                    f"compile-ahead of {name!r} failed") from errors[0]
             # lazy compile still serves correctly — but the bring-up stall
             # compile-ahead exists to hide must be attributable in logs
             logging.getLogger("tpu9.serving").warning(
@@ -240,9 +256,12 @@ def load_engine(name: str, *, max_batch: int = 8, max_seq_len: int = 2048,
             "bind_s": round(bind_end - bind_start, 4),
             "compile_overlap_s": round(_cs.interval_overlap_s(
                 (anchor_mono, load_end),
-                (compile_iv[0], compile_iv[1])), 4)}
+                (compile_iv[0], compile_iv[1])), 4),
+            # per-graph compile_<graph>_s, so a slow bring-up names its graph
+            **timings}
         return engine
-    params, _ = build_params(name, seed=seed, quantize=quantize)
+    params, _ = build_params(name, seed=seed, quantize=quantize,
+                             policy=policy)
     # placement through the policy BEFORE construction: the engine's pool
     # arrays and the weights must land on the same submesh
     return InferenceEngine(policy.place_params(params), cfg, ecfg,

@@ -57,6 +57,11 @@ class SingleDevicePolicy:
     def place_params(self, params: Params) -> Params:
         return params
 
+    def build_params(self, init, rng) -> Params:
+        """``init(rng)`` with every leaf built where :meth:`place_params`
+        would put it."""
+        return init(rng)
+
     def place_kv(self, tree: Params) -> Params:
         return tree
 
@@ -113,14 +118,27 @@ class SingleDevicePolicy:
     def devices(self) -> list:
         return [jax.devices()[0]] if jax.devices() else []
 
-    def hbm_used_gb_per_chip(self) -> float:
-        return _hbm_used_gb(self.devices())
+    def memory_stats(self) -> list:
+        """Each chip's ``memory_stats()`` in device order, one sweep —
+        ``[]`` where the backend reports none (CPU)."""
+        stats = [d.memory_stats() for d in self.devices()]
+        return stats if all(stats) else []
+
+    def hbm_gb_by_chip(self, key: str = "bytes_in_use",
+                       stats: Optional[list] = None) -> list:
+        """``key`` of each chip's memory stats, GB (``stats``: a sweep
+        already taken). A mesh whose first chip holds far more than the
+        rest is the everything-landed-on-device-0 placement fault,
+        readable here."""
+        if stats is None:
+            stats = self.memory_stats()
+        return [round(s.get(key, 0) / 1e9, 3) for s in stats]
 
     def hbm_limit_gb_per_chip(self) -> float:
         """Smallest per-chip HBM capacity across the submesh, GB — the
         denominator of the health plane's headroom gauges (ISSUE 14).
         0.0 where the backend has no memory stats (CPU)."""
-        return _hbm_limit_gb(self.devices())
+        return min(self.hbm_gb_by_chip("bytes_limit"), default=0.0)
 
 
 class MeshPolicy(SingleDevicePolicy):
@@ -185,6 +203,15 @@ class MeshPolicy(SingleDevicePolicy):
             specs = jax.tree_util.tree_map(lambda _: P(), params)
         return shard_params(params, self.mesh, specs)
 
+    def build_params(self, init, rng) -> Params:
+        # jit-with-out-shardings, as in :meth:`zeros`: each chip generates
+        # only its shard. A model sharded BECAUSE it fits no single chip
+        # must never be materialized on the first one.
+        shardings = jax.tree_util.tree_map(
+            lambda a: a.sharding, self.abstract(jax.eval_shape(init, rng)))
+        build = jax.jit(init, out_shardings=shardings)
+        return build(rng)
+
     def place_kv(self, tree: Params) -> Params:
         return {name: jax.device_put(arr,
                                      self._kv_sharding(name, arr.shape))
@@ -248,35 +275,6 @@ def _sharded_zeros(shape: tuple, dtype, sharding):
     """Cached jitted sharded-zeros builder (NamedSharding hashes by mesh +
     spec): pools of one shape/layout compile their init exactly once."""
     return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=sharding)
-
-
-def _hbm_used_gb(devices: list) -> float:
-    """Max live HBM across the submesh's chips, GB — 0.0 where the
-    backend has no memory stats (CPU)."""
-    worst = 0.0
-    for d in devices:
-        try:
-            stats = d.memory_stats()
-        except Exception:   # noqa: BLE001 — backend-optional API
-            return 0.0
-        if not stats:
-            return 0.0
-        worst = max(worst, stats.get("bytes_in_use", 0) / 1e9)
-    return round(worst, 3)
-
-
-def _hbm_limit_gb(devices: list) -> float:
-    """Min per-chip capacity across the submesh, GB (0.0 = no stats)."""
-    best = float("inf")
-    for d in devices:
-        try:
-            stats = d.memory_stats()
-        except Exception:   # noqa: BLE001 — backend-optional API
-            return 0.0
-        if not stats or not stats.get("bytes_limit"):
-            return 0.0
-        best = min(best, stats["bytes_limit"] / 1e9)
-    return round(best, 3) if best != float("inf") else 0.0
 
 
 def make_policy(topology: "Topology | str | None",

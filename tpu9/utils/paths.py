@@ -16,6 +16,15 @@ def repo_root() -> str:
         os.path.abspath(__file__))))
 
 
+def compile_cache_dir() -> str:
+    """JAX's persistent compile cache — the ONE rule every process follows:
+    ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it, else
+    ``<checkout>/.cache/xla``. The path is part of the cache key, so it is
+    never derived from a temp name, a pid or a time."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        repo_root(), ".cache", "xla")
+
+
 def native_binary(name: str) -> str:
     """Path of a built native component (native/build/<name>) — the ONE
     definition every consumer (runtimes, lifecycle, cachefs, CLI) uses, so

@@ -27,12 +27,17 @@ from ..runtime.base import ContainerSpec, Runtime
 from ..types import (ContainerRequest, ContainerState, ContainerStatus,
                      LifecyclePhase, StopReason, StubType)
 from ..utils.aio import spawn
-from ..utils.paths import validate_path_part
+from ..utils.paths import compile_cache_dir, validate_path_part
 from .tpu_manager import TpuDeviceManager
 
 log = logging.getLogger("tpu9.worker")
 
 READINESS_TIMEOUT_S = 120.0
+# the LLM runner is ready when it is SERVEABLE: weights built, every graph
+# compiled and warmed. Cold, llama3-8b-int8 on a v5e does not fit 120 s (PR 21:
+# two starts were killed at the deadline, each leaving a warmer compile cache,
+# before a third came up in 66 s)
+LLM_READINESS_TIMEOUT_S = 600.0
 
 # identity tenant serving containers drop to under NativeRuntime ("nobody")
 UNPRIVILEGED_UID = 65534
@@ -228,7 +233,11 @@ class ContainerLifecycle:
                 StubType.REALTIME.value, StubType.TASK_QUEUE.value,
                 StubType.FUNCTION.value, StubType.SCHEDULE.value)
             if needs_probe:
-                ready = await self._wait_ready(container_id, address)
+                ready = await self._wait_ready(
+                    container_id, address,
+                    LLM_READINESS_TIMEOUT_S
+                    if request.env.get("TPU9_RUNNER") == "llm"
+                    else READINESS_TIMEOUT_S)
                 if not ready:
                     # one-shot containers (function/schedule) can finish
                     # their whole job before the probe ever succeeds — a
@@ -641,10 +650,11 @@ class ContainerLifecycle:
             "PYTHONUNBUFFERED": "1",
         })
         # persistent XLA compile cache: jit recompiles are the real TPU
-        # cold-start tail; share them across containers on this host
-        env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                       os.path.join(self.cfg.containers_dir, "..",
-                                    "xla-cache"))
+        # cold-start tail; share them across containers on this host. The
+        # worker's own rule (utils.compile_cache_dir) is forwarded as is —
+        # the path is part of the cache key, so it must not move with
+        # containers_dir
+        env.setdefault("JAX_COMPILATION_CACHE_DIR", compile_cache_dir())
         if request.checkpoint_id:
             env["TPU9_RESTORED"] = "1"
         if image_site:
@@ -815,9 +825,10 @@ class ContainerLifecycle:
                 await asyncio.sleep(0.05)
         return False
 
-    async def _wait_ready(self, container_id: str, address: str) -> bool:
+    async def _wait_ready(self, container_id: str, address: str,
+                          timeout_s: float) -> bool:
         """Poll the runner's /health endpoint (buffer.go:334 equivalent)."""
-        deadline = time.monotonic() + READINESS_TIMEOUT_S
+        deadline = time.monotonic() + timeout_s
         url = f"http://{address}/health"
         async with aiohttp.ClientSession() as session:
             while time.monotonic() < deadline:

@@ -5,16 +5,21 @@ generation, env injection).
 On a TPU VM host, chips appear as ``/dev/accel{0..n}`` (or ``/dev/vfio/*``)
 and user code reaches them through libtpu. The manager:
 
-- inventories chips (``/dev/accel*`` glob; ``TPU9_FAKE_TPU_CHIPS`` fakes an
-  inventory for tests/dev, playing the role nvidia-smi mocks play in the
-  reference);
+- inventories chips (``/dev/accel*``, else the numbered ``/dev/vfio``
+  groups a v5e host exposes; ``TPU9_FAKE_TPU_CHIPS`` fakes an inventory for
+  tests/dev, playing the role nvidia-smi mocks play in the reference);
 - assigns chips to containers exclusively (scheduler guarantees fit; the
   manager enforces it);
-- emits the device list + env a container needs: ``TPU_VISIBLE_CHIPS``,
-  ``TPU_CHIPS_PER_PROCESS_BOUNDS``, ``TPU_PROCESS_BOUNDS``, plus gang env
-  (``TPU9_GANG_*``, ``TPU_WORKER_ID``, ``TPU_WORKER_HOSTNAMES``,
-  ``JAX_COORDINATOR_ADDRESS``) for multi-host slices — the TPU analogue of
-  ``NVIDIA_VISIBLE_DEVICES`` injection (nvidia.go:289-440).
+- emits the device list + env a container needs: ``JAX_PLATFORMS``,
+  ``TPU_VISIBLE_CHIPS``, ``TPU_CHIPS_PER_PROCESS_BOUNDS``,
+  ``TPU_PROCESS_BOUNDS``, ``TPU_ACCELERATOR_TYPE``, ``TPU_SKIP_MDS_QUERY``,
+  plus gang env (``TPU9_GANG_*``, ``TPU_WORKER_ID``,
+  ``TPU_WORKER_HOSTNAMES``, ``JAX_COORDINATOR_ADDRESS``) for multi-host
+  slices — the TPU analogue of ``NVIDIA_VISIBLE_DEVICES`` injection
+  (nvidia.go:289-440). The container starts from an allowlisted environment,
+  so this is ALL libtpu learns about the host. A v5e host initialised from
+  exactly this set with one process owning one chip of one and four of four
+  (PR 21); several processes sharing a host's chips are unproven.
 """
 
 from __future__ import annotations
@@ -39,13 +44,15 @@ class TpuDeviceManager:
     def __init__(self, generation: str = "", hostnames: str = "") -> None:
         self.generation = generation or env_tpu_gen()
         self.hostnames = hostnames
-        self._devices = self._inventory()
+        fake = int(os.environ.get("TPU9_FAKE_TPU_CHIPS") or 0)
+        # a faked chip can only be driven by the CPU backend
+        self._platform = "cpu" if fake else "tpu"
+        self._devices = [f"/dev/fake-accel{i}" for i in range(fake)] \
+            if fake else self._inventory()
         self._assigned: dict[str, list[int]] = {}   # container_id -> chip ids
 
-    def _inventory(self) -> list[str]:
-        fake = os.environ.get("TPU9_FAKE_TPU_CHIPS")
-        if fake:
-            return [f"/dev/fake-accel{i}" for i in range(int(fake))]
+    @staticmethod
+    def _inventory() -> list[str]:
         return sorted(glob.glob("/dev/accel*")) or sorted(
             glob.glob("/dev/vfio/[0-9]*"))
 
@@ -86,12 +93,19 @@ class TpuDeviceManager:
     def _env_for(self, request: ContainerRequest, spec: TpuSpec,
                  chip_ids: list[int]) -> dict[str, str]:
         env = {
+            # explicit, never inferred: a TPU container whose libtpu cannot
+            # reach the chip must fail, not fall back to the CPU backend
+            # (and a zygote-forked runner re-points jax at exactly this)
+            "JAX_PLATFORMS": self._platform,
             "TPU_VISIBLE_CHIPS": ",".join(str(i) for i in chip_ids),
             "TPU_CHIPS_PER_PROCESS_BOUNDS": _bounds_for(len(chip_ids)),
             "TPU_PROCESS_BOUNDS": "1,1,1",
-            "TPU_ACCELERATOR_TYPE": spec.name,
+            # the platform's own spelling ("v5litepod-4", not "v5e-4")
+            "TPU_ACCELERATOR_TYPE": spec.gce_accelerator_type,
+            # topology comes from this env, not the metadata server: where
+            # none answers, libtpu init never returns (PR 21: every probed
+            # env without this hung past 120 s)
             "TPU_SKIP_MDS_QUERY": "1",
-            "PJRT_DEVICE": "TPU",
             "TPU9_SLICE_TOPOLOGY": spec.topology,
         }
         gang = request.gang
