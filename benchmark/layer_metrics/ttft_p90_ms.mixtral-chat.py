@@ -1,0 +1,9 @@
+"""client: ``ttft_p90_ms`` of this cell — median/percentile of due -> first
+streamed token over the judged requests. Not an end-to-end metric here: in two
+sets of six runs its spread was 12-16 % (PERF.md, Findings), more than a bound
+of 10 % can carry. Read in the traced run, so with the profiler's overhead."""
+from benchmark import readers
+
+
+def read(ctx):
+    return readers.demoted_latency(ctx, __file__)

@@ -1,0 +1,203 @@
+"""What runs INSIDE the runner container: the deployed handler calls
+:func:`build_engine`, which turns a configuration file into the program's
+``DecoderConfig`` and ``InferenceEngine`` — weights made on the device from the
+seed in one jitted call — and starts the benchmark's mailbox thread.
+
+The mailbox is how the harness reaches the one process that holds the chip
+without a second process (a chip belongs to one process at a time, and a new
+one takes ~15 s to reach it): the harness drops ``<op>.request.json`` into the
+run directory, the thread answers with ``<op>.result.json``.
+
+- ``reference``: the plain float32 reference over the probes, on the weights
+  the engine serves (and, on a mesh, on the same mesh), while the engine idles
+- ``memory``: ``peak_bytes_in_use`` of every device of the engine
+- ``trace``: a profiler trace of a stated number of seconds, started and
+  stopped here. The runner's own ``POST /profile`` counts main-loop decode
+  windows only, so a few armed windows of an open loop traced 15-35 s and
+  writing that out held the run for minutes (PR 23); only the process that
+  holds the chip can trace it, and this thread is in it.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import threading
+import time
+
+
+def model_sizes(config: dict) -> dict:
+    """The sizes the reference and the program need, from a configuration
+    file in the published ``config.json`` vocabulary."""
+    assumed = {k: v["value"] for k, v in config.get("assumed", {}).items()}
+    model = {k: config[k] for k in (
+        "hidden_size", "intermediate_size", "num_attention_heads",
+        "num_key_value_heads", "num_hidden_layers", "vocab_size",
+        "max_position_embeddings", "rope_theta", "rms_norm_eps")}
+    model["num_local_experts"] = config.get("num_local_experts", 0)
+    model["num_experts_per_tok"] = config.get("num_experts_per_tok", 0)
+    model["head_dim"] = assumed.get(
+        "head_dim", model["hidden_size"] // model["num_attention_heads"])
+    model["moe_capacity_factor"] = assumed.get("moe_capacity_factor", 0.0)
+    for key, want in (("hidden_act", "silu"), ("tie_word_embeddings", False),
+                      ("sliding_window", None), ("torch_dtype", "bfloat16")):
+        if config.get(key, want) != want:
+            raise ValueError(f"{key}={config[key]!r}: this harness builds "
+                             f"only {want!r}")
+    return model
+
+
+def decoder_config(model: dict):
+    from tpu9.models.transformer import DecoderConfig
+    moe = {}
+    if model["num_local_experts"]:
+        moe = dict(n_experts=model["num_local_experts"],
+                   moe_top_k=model["num_experts_per_tok"],
+                   moe_capacity_factor=model["moe_capacity_factor"])
+    return DecoderConfig(
+        vocab_size=model["vocab_size"], dim=model["hidden_size"],
+        n_layers=model["num_hidden_layers"],
+        n_heads=model["num_attention_heads"],
+        n_kv_heads=model["num_key_value_heads"], head_dim=model["head_dim"],
+        hidden_dim=model["intermediate_size"], norm_eps=model["rms_norm_eps"],
+        rope_theta=model["rope_theta"],
+        max_seq_len=model["max_position_embeddings"], act="silu",
+        tie_embeddings=False, **moe)
+
+
+def engine_config(knobs: dict):
+    from tpu9.serving import EngineConfig
+    return EngineConfig(
+        max_batch=knobs["max_batch"], max_seq_len=knobs["max_seq_len"],
+        prefill_buckets=(knobs["prefill_chunk"],),
+        decode_steps=tuple(knobs["decode_steps"]),
+        kv_block_size=knobs["kv_block_size"],
+        kv_pool_blocks=knobs["kv_pool_blocks"],
+        prefill_chunk=knobs["prefill_chunk"],
+        prefix_cache_blocks=knobs["prefix_cache_blocks"],
+        admit_group_chunks=knobs["admit_group_chunks"])
+
+
+def seed_key(seed: int):
+    """A PRNG key from any whole number (the driver's seeds pass 2**31)."""
+    import jax
+    return jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF),
+                              seed >> 31)
+
+
+def build_params(cfg, policy, seed: int):
+    """Weights on the device from the seed in ONE jitted call, in the type
+    they are served in, each leaf built where the policy shards it."""
+    import jax
+
+    from tpu9.models import init_decoder
+
+    def init(rng):
+        return init_decoder(rng, cfg)
+
+    if policy.mesh is None:
+        return jax.jit(init)(seed_key(seed))
+    return policy.build_params(init, seed_key(seed))
+
+
+def build_engine(args: dict):
+    """``args``: ``config`` (the configuration as run, overrides applied),
+    ``seed``, ``run_dir``."""
+    t0 = time.monotonic()
+    import jax
+    devices = jax.devices()
+    opened = time.monotonic()
+
+    from tpu9.serving import InferenceEngine
+    from tpu9.serving.shard import make_policy
+    config = args["config"]
+    model = model_sizes(config)
+    cfg = decoder_config(model)
+    policy = make_policy(config["engine"]["topology"])
+    # as load_engine(compile_ahead=True) does for a preset: the engine is
+    # built on the ABSTRACT weights and its programs compile (or load from
+    # the cache) on the host while the device makes the weights
+    built: dict = {}
+
+    def make_weights():
+        built["params"] = jax.block_until_ready(
+            build_params(cfg, policy, args["seed"]))
+        built["loaded"] = time.monotonic()
+
+    weights = threading.Thread(target=make_weights, name="benchmark-weights")
+    weights.start()
+    from tpu9.serving.presets import abstract_params_for
+    engine = InferenceEngine(abstract_params_for(cfg, False), cfg,
+                             engine_config(config["engine"]), policy=policy)
+    timings = engine.precompile()
+    compiled = time.monotonic()
+    weights.join()
+    if "params" not in built:
+        raise RuntimeError("building the weights failed (see the log above)")
+    engine.bind_params(built["params"])
+    # the benchmark's own spans around the calls into each layer; the runner
+    # adds handler_s / warmup_s / ready_s and /health shows all as coldstart_*
+    engine.bringup = {"device_open_s": round(opened - t0, 4),
+                      "load_s": round(built["loaded"] - opened, 4),
+                      "compile_ahead_s": round(compiled - opened, 4),
+                      **timings}
+    threading.Thread(target=_mailbox, name="benchmark-mailbox", daemon=True,
+                     args=(args["run_dir"], engine, model, config,
+                           len(devices))).start()
+    return engine
+
+
+def _answer(run_dir: str, op: str, fn) -> None:
+    req = os.path.join(run_dir, f"{op}.request.json")
+    if not os.path.exists(req):
+        return
+    with open(req) as f:
+        payload = json.load(f)
+    os.remove(req)
+    try:
+        out = fn(payload)
+    except Exception as exc:    # noqa: BLE001 — the harness reads the error
+        out = {"error": f"{type(exc).__name__}: {exc}"}
+    tmp = os.path.join(run_dir, f"{op}.result.json.tmp")
+    with open(tmp, "w") as f:
+        json.dump(out, f)
+    os.replace(tmp, os.path.join(run_dir, f"{op}.result.json"))
+
+
+def _mailbox(run_dir, engine, model, config, n_devices) -> None:
+    def reference(payload):
+        from benchmark.correctness import probe_margins
+        t0 = time.monotonic()
+        out = probe_margins(engine.params, model, payload["probes"],
+                            config["reference"])
+        out["seconds"] = round(time.monotonic() - t0, 3)
+        return out
+
+    def trace(payload):
+        import jax
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0     # millions of host events otherwise
+        options.host_tracer_level = 1
+        t0 = time.monotonic()
+        jax.profiler.start_trace(payload["dir"], profiler_options=options)
+        started = time.monotonic()
+        time.sleep(float(payload["seconds"]))
+        asked = time.monotonic()
+        jax.profiler.stop_trace()
+        return {"start_s": round(started - t0, 3),
+                "traced_s": round(asked - started, 3),
+                "stop_s": round(time.monotonic() - asked, 3)}
+
+    def memory(_payload):
+        peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                 for d in engine._devices]
+        return {"peak_bytes_by_device": peaks, "devices_visible": n_devices}
+
+    while True:
+        try:
+            _answer(run_dir, "reference", reference)
+            _answer(run_dir, "trace", trace)
+            _answer(run_dir, "memory", memory)
+        except OSError:
+            pass
+        time.sleep(0.05)
