@@ -3072,7 +3072,7 @@ def bench_obs(quick: bool = False) -> dict:
     def microbench_hooks(eng) -> tuple[float, float]:
         """(per-window, per-request) instrumentation cost in µs, driven
         through the REAL hook methods on the live engine — flight record
-        + per-slot decode_window spans + histogram observes, with the
+        + per-request decode-span accounting + histogram observes, with the
         metric reservoirs saturated to their steady-state (sorted-insert)
         cost by the iteration count itself."""
         from tpu9.serving.engine import _Request, _Window
@@ -3308,7 +3308,7 @@ def bench_obs(quick: bool = False) -> dict:
             parts = sum(sp["durationMs"] for sp in spans
                         if sp["name"] in ("engine.queue_wait",
                                           "engine.prefill",
-                                          "engine.decode_window"))
+                                          "engine.decode"))
             if d > 0:
                 coverage.append(parts / d)
         if coverage:

@@ -305,14 +305,14 @@ async def test_request_lifecycle_trace_e2e():
                     "GET", f"/api/v1/traces?trace_id={trace_id}")
                 assert status == 200
                 tree = filt["spans"]
-                if {"engine.prefill", "engine.decode_window"} <= \
+                if {"engine.prefill", "engine.decode"} <= \
                         {s["name"] for s in tree}:
                     break
             await asyncio.sleep(0.5)
         by_name: dict = {}
         for sp in tree:
             by_name.setdefault(sp["name"], []).append(sp)
-        assert {"engine.prefill", "engine.decode_window"} <= set(by_name), \
+        assert {"engine.prefill", "engine.decode"} <= set(by_name), \
             f"engine spans never arrived: {sorted(by_name)}"
 
         # ONE trace id across every layer
@@ -332,14 +332,17 @@ async def test_request_lifecycle_trace_e2e():
         assert "replica" in disp and "affinity_hit" in disp
 
         # engine.request hangs off the invoke span (X-Tpu9-Trace), and
-        # queue-wait/prefill/decode windows hang off engine.request
+        # queue-wait/prefill/decode hang off engine.request: one decode
+        # span for the request, whatever its number of windows
         req = by_name["engine.request"][0]
         assert req["parentSpanId"] == invoke["spanId"]
         assert req["attributes"]["tokens_generated"] == 8
-        windows = by_name["engine.decode_window"]
-        assert len(windows) >= 1
+        windows = by_name["engine.decode"]
+        assert len(windows) == 1
+        assert windows[0]["attributes"]["tokens"] == 7
+        assert windows[0]["attributes"]["windows"] >= 1
         for name in ("engine.queue_wait", "engine.prefill",
-                     "engine.decode_window"):
+                     "engine.decode"):
             for sp in by_name[name]:
                 assert sp["parentSpanId"] == req["spanId"], (name, sp)
 

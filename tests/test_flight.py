@@ -99,7 +99,8 @@ def test_engine_records_admits_and_windows(tiny):
     assert s["flight"]["records"] == len(recs)
     assert s["flight"]["last_seq"] == recs[-1]["seq"]
     lat = s["latency"]
-    for phase in ("ttft", "queue_wait", "prefill", "decode_window", "e2e"):
+    for phase in ("ttft", "queue_wait", "prefill", "first_hold",
+                  "decode_window", "e2e"):
         assert f"{phase}_p50_s" in lat, (phase, lat)
     assert lat["ttft_count"] == 2
     # decomposition sanity at unit scale: queue+prefill ≤ ttft ≤ e2e
@@ -134,17 +135,28 @@ def test_engine_spans_under_remote_context(tiny):
     assert req["parentSpanId"] == parent
     assert req["attributes"]["prompt_tokens"] == 8
     assert req["attributes"]["tokens_generated"] == 6
-    for child in ("engine.queue_wait", "engine.prefill",
-                  "engine.decode_window"):
+    for child in ("engine.queue_wait", "engine.prefill", "engine.decode"):
         assert child in by_name, (child, list(by_name))
         for sp in by_name[child]:
             assert sp["parentSpanId"] == req["spanId"]
             # gapless: children sit inside the request span's interval
             assert sp["startTimeUnixNano"] >= req["startTimeUnixNano"]
             assert sp["endTimeUnixNano"] <= req["endTimeUnixNano"] + 10**6
-    windows = by_name["engine.decode_window"]
-    assert sum(sp["attributes"]["tokens"] for sp in windows) == 5  # 6 - first
-    assert all(sp["attributes"]["k"] >= 1 for sp in windows)
+    # ONE decode span per request, whatever the number of windows
+    # (ISSUE 24): per-window detail is the flight recorder's
+    assert "engine.decode_window" not in by_name
+    assert len(by_name["engine.decode"]) == 1
+    dec = by_name["engine.decode"][0]["attributes"]
+    assert dec["tokens"] == 5                       # 6 - the first token
+    assert 1 <= dec["windows"] <= 5
+    assert 0 <= dec["k1_windows"] <= dec["windows"]
+    assert dec["interleaved_windows"] == 0
+    flight = [r for r in eng.flight_records() if r["kind"] == "decode"
+              and dec["request_id"] in r["slots"].values()]
+    assert sum(sum(r["tokens"].values()) for r in flight) >= 5
+    # the prefill span ends where the decode span may begin
+    assert dec["windows"] == sum(
+        1 for r in flight if any(r["tokens"].values()))
 
 
 def test_verify_windows_record_spec_outcome():
@@ -197,67 +209,72 @@ def test_flight_disabled_is_inert(tiny):
 
 
 def test_arm_profile_runs_and_stops(tiny):
+    """``arm_profile(seconds=...)`` (ISSUE 24): returns at once, traces
+    from a worker thread — the serve loop keeps serving meanwhile — refuses
+    a second arm, and stops on its own after the stated seconds."""
     import os
+    import time
     eng = _engine(tiny)
 
     async def go():
         await eng.start()
-        info = eng.arm_profile(windows=2)
+        t0 = time.monotonic()
+        info = eng.arm_profile(seconds=1.0)
+        assert time.monotonic() - t0 < 0.5, "arming must not trace inline"
+        assert eng.stats()["profile"]["active"] is True
         # double-arm reports the in-flight one instead of clobbering it
-        again = eng.arm_profile(windows=5)
+        again = eng.arm_profile(seconds=5)
         assert again.get("already_armed") and again["path"] == info["path"]
-        await eng.generate(list(range(8)), max_new_tokens=12)
-        for _ in range(100):
-            if not eng._profile_active and eng._profile_remaining == 0:
+        # the loop is not held: requests are served while the trace runs
+        out = await asyncio.wait_for(
+            eng.generate(list(range(8)), max_new_tokens=12), 30)
+        assert len(out) == 12
+        for _ in range(200):
+            if not eng.stats()["profile"]["active"]:
                 break
             await asyncio.sleep(0.05)
-        # the profiler must stop on its own once the armed windows drain
-        # (live replicas never call stop()): parking idle with a zombie
-        # overlap window used to strand the trace active forever
-        assert not eng._profile_active, "profiler still active at idle"
-        assert eng._profile_remaining == 0
+        # it stops on its own (live replicas never call stop())
+        assert not eng.stats()["profile"]["active"], "profile never stopped"
         await eng.stop()
         return info
 
     info = _run(go())
     s = eng.stats()["profile"]
-    assert s["active"] is False and s["armed"] == 0
+    assert s["active"] is False and s["seconds"] == 1.0
     assert s["error"] == "", s
     assert s["path"] == info["path"] and os.path.isdir(info["path"])
     events = [r for r in eng.flight_records() if r["kind"] == "profile"]
     assert [e["event"] for e in events] == ["armed", "stopped"]
+    assert 0.9 <= events[1]["traced_s"] <= 3.0 and events[1]["error"] == ""
 
     with pytest.raises(ValueError):
-        eng.arm_profile(windows=0)
+        eng.arm_profile(seconds=0)
 
 
-def test_arm_profile_stops_early_when_traffic_dries_up(tiny):
-    """Arming more windows than traffic produces must still stop the
-    trace at idle (partial dump + re-armable), not leak parked-idle time
-    into the profiler forever."""
+def test_arm_profile_is_cut_short_by_stop_and_can_be_armed_again(tiny):
+    """An armed profile must not outlive the engine: ``stop()`` cuts the
+    stated seconds short and waits for the dump; afterwards the hook is
+    re-armable (not already_armed forever)."""
+    import time
     eng = _engine(tiny)
 
     async def go():
         await eng.start()
-        info = eng.arm_profile(windows=50)
+        info = eng.arm_profile(seconds=600)
         await eng.generate(list(range(8)), max_new_tokens=6)
-        for _ in range(100):
-            if not eng._profile_active:
-                break
-            await asyncio.sleep(0.05)
-        assert not eng._profile_active, \
-            "under-dispatched armed profile must stop at idle"
-        assert eng._profile_remaining == 0
-        # and the hook is re-armable (not already_armed forever)
-        again = eng.arm_profile(windows=1)
-        assert not again.get("already_armed"), again
-        await eng.generate(list(range(4)), max_new_tokens=4)
+        t0 = time.monotonic()
         await eng.stop()
+        assert time.monotonic() - t0 < 60
+        assert not eng.stats()["profile"]["active"]
+        again = eng.arm_profile(seconds=0.2)
+        assert not again.get("already_armed"), again
+        assert again["path"] != info["path"]
+        eng._profile_thread.join(60)
         return info
 
     info = _run(go())
     events = [r for r in eng.flight_records() if r["kind"] == "profile"]
     stops = [e for e in events if e["event"] == "stopped"]
     assert len(stops) == 2 and stops[0]["path"] == info["path"]
-    assert stops[0]["windows_left"] > 0      # stopped early, honestly
+    assert stops[0]["traced_s"] < 60          # cut short, honestly
     assert all(e["error"] == "" for e in stops)

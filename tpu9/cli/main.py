@@ -1040,14 +1040,15 @@ def failover_cmd(stub_id: str, limit: int, as_json: bool) -> None:
 
 @cli.command("profile")
 @click.argument("stub_id")
-@click.option("--windows", default=8, help="windows to profile")
+@click.option("--seconds", default=4.0, help="seconds to trace")
 @click.option("--container-id", default="", help="pin one replica")
 @click.option("--out-dir", default="", help="dump dir on the replica")
-def profile_cmd(stub_id: str, windows: int, container_id: str,
+def profile_cmd(stub_id: str, seconds: float, container_id: str,
                 out_dir: str) -> None:
-    """Arm jax.profiler on a live replica for the next N engine windows;
-    prints the replica-side dump path."""
-    body = {"stub_id": stub_id, "windows": windows}
+    """Trace a live replica with jax.profiler for the next --seconds
+    seconds (device planes, the serve loop's host phases, the model's
+    scopes); prints the replica-side dump path."""
+    body = {"stub_id": stub_id, "seconds": seconds}
     if container_id:
         body["container_id"] = container_id
     if out_dir:

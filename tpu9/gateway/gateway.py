@@ -897,18 +897,18 @@ class Gateway:
                             content_type="application/json")
 
     async def _profile(self, request: web.Request) -> web.Response:
-        """Arm jax.profiler on a live replica for the next N windows
-        (ISSUE 8): body {stub_id, windows, container_id?}; returns the
+        """Trace a live replica with jax.profiler for the next N seconds
+        (ISSUE 24): body {stub_id, seconds, container_id?}; returns the
         runner-side dump path immediately. The dump lands on the replica's
         filesystem — fetch it with `tpu9 shell`/volume tooling."""
         data = await request.json()
         stub = await self._stub_for(request, data.get("stub_id", ""))
-        windows = int(data.get("windows", 8))
+        seconds = float(data.get("seconds", 4.0))
         cid = data.get("container_id", "")
         result = await self.endpoints.forward(
             stub, "POST", "/profile",
             [("Content-Type", "application/json")],
-            json.dumps({"windows": windows,
+            json.dumps({"seconds": seconds,
                         "out_dir": data.get("out_dir", "")}).encode(),
             prefer=[cid] if cid else [],
             timeout_s=self.cfg.router.rpc_timeout_s)

@@ -100,50 +100,55 @@ def moe_ffn(params: Params, x: jnp.ndarray, cfg: MoeConfig,
     xf = x.reshape(n, d)
 
     # -- routing (f32 for numerics) ------------------------------------------
-    logits = xf.astype(jnp.float32) @ params["router"]          # [N, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)               # [N, k]
-    # renormalize the chosen gates so outputs are a convex combination
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe.route"):
+        logits = xf.astype(jnp.float32) @ params["router"]      # [N, E]
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, k)           # [N, k]
+        # renormalize the chosen gates so outputs are a convex combination
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
 
-    # one-hot expert assignment per (token, slot): [N, k, E]
-    assign = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32)
+        # one-hot expert assignment per (token, slot): [N, k, E]
+        assign = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32)
 
-    # position of each (token, slot) within its expert's buffer: running
-    # count of earlier claims on the same expert (token-major, slot-minor
-    # priority — earlier tokens win capacity, the GShard convention)
-    flat = assign.reshape(n * k, e)
-    pos = jnp.cumsum(flat, axis=0) - flat                        # [N*k, E]
-    pos = (pos * flat).sum(-1).reshape(n, k).astype(jnp.int32)   # [N, k]
-    in_cap = (pos < c).astype(jnp.float32)
+        # position of each (token, slot) within its expert's buffer:
+        # running count of earlier claims on the same expert (token-major,
+        # slot-minor priority — earlier tokens win capacity, the GShard
+        # convention)
+        flat = assign.reshape(n * k, e)
+        pos = jnp.cumsum(flat, axis=0) - flat                    # [N*k, E]
+        pos = (pos * flat).sum(-1).reshape(n, k).astype(jnp.int32)  # [N, k]
+        in_cap = (pos < c).astype(jnp.float32)
 
-    # dispatch [N, E, C]: 1 where token n goes to expert e at slot c
-    slot_oh = jax.nn.one_hot(pos, c, dtype=jnp.float32)          # [N, k, C]
-    dispatch = jnp.einsum("nke,nkc->nec", assign, slot_oh * in_cap[..., None])
-    combine = jnp.einsum("nke,nkc,nk->nec", assign,
-                         slot_oh * in_cap[..., None], gate_vals)
+        # dispatch [N, E, C]: 1 where token n goes to expert e at slot c
+        slot_oh = jax.nn.one_hot(pos, c, dtype=jnp.float32)      # [N, k, C]
+        dispatch = jnp.einsum("nke,nkc->nec", assign,
+                              slot_oh * in_cap[..., None])
+        combine = jnp.einsum("nke,nkc,nk->nec", assign,
+                             slot_oh * in_cap[..., None], gate_vals)
 
     # -- expert compute (leading E dim sharded over ep) ----------------------
-    xe = jnp.einsum("nec,nd->ecd", dispatch.astype(cfg.dtype),
-                    xf.astype(cfg.dtype))                        # [E, C, d]
-    if ep_sharded:
-        xe = jax.lax.with_sharding_constraint(xe, P("ep", None, None))
-    # maybe_einsum: expert stacks may be per-expert int8 entries
-    # (tpu9.ops.quant.quantize_weight_stacked) — the int8 operand stays
-    # int8 in HBM, scales [E, 1, out] apply on the einsum output
-    from ..ops.quant import maybe_einsum
-    h = maybe_einsum("ecd,edh->ech", xe, params["w_gate"])
-    if cfg.act == "silu":
-        h = jax.nn.silu(h)
-    else:
-        h = jax.nn.gelu(h, approximate=True)
-    h = h * maybe_einsum("ecd,edh->ech", xe, params["w_up"])
-    ye = maybe_einsum("ech,ehd->ecd", h, params["w_down"])       # [E, C, d]
-    if ep_sharded:
-        ye = jax.lax.with_sharding_constraint(ye, P("ep", None, None))
+    with jax.named_scope("moe.experts"):
+        xe = jnp.einsum("nec,nd->ecd", dispatch.astype(cfg.dtype),
+                        xf.astype(cfg.dtype))                    # [E, C, d]
+        if ep_sharded:
+            xe = jax.lax.with_sharding_constraint(xe, P("ep", None, None))
+        # maybe_einsum: expert stacks may be per-expert int8 entries
+        # (tpu9.ops.quant.quantize_weight_stacked) — the int8 operand
+        # stays int8 in HBM, scales [E, 1, out] apply on the einsum output
+        from ..ops.quant import maybe_einsum
+        h = maybe_einsum("ecd,edh->ech", xe, params["w_gate"])
+        if cfg.act == "silu":
+            h = jax.nn.silu(h)
+        else:
+            h = jax.nn.gelu(h, approximate=True)
+        h = h * maybe_einsum("ecd,edh->ech", xe, params["w_up"])
+        ye = maybe_einsum("ech,ehd->ecd", h, params["w_down"])   # [E, C, d]
+        if ep_sharded:
+            ye = jax.lax.with_sharding_constraint(ye, P("ep", None, None))
 
-    out = jnp.einsum("nec,ecd->nd", combine.astype(cfg.dtype), ye)
+    with jax.named_scope("moe.combine"):
+        out = jnp.einsum("nec,ecd->nd", combine.astype(cfg.dtype), ye)
 
     # -- aux: load-balance loss + stats --------------------------------------
     # fraction of tokens whose TOP-1 lands on e, times mean router prob

@@ -129,6 +129,35 @@ def _act(x: jnp.ndarray, kind: str) -> jnp.ndarray:
     raise ValueError(kind)
 
 
+# Device scopes (ISSUE 24): the ``jax.named_scope`` names the forward pass
+# runs under — they reach every HLO instruction's ``op_name``, so a
+# profiler trace's device time can be summed by part of the model.
+# ``serving.graphs`` adds ``kv.gather``/``kv.splice`` around the pool
+# plumbing; norms, the rng split and the length update carry none.
+DEVICE_SCOPES = ("embed", "attn.qkv", "attn.rope", "kv.slice", "kv.write",
+                 "kv.pack", "kv.gather", "kv.splice", "attn.core",
+                 "attn.out", "ffn", "moe.route", "moe.experts",
+                 "moe.combine", "head", "sample")
+
+
+def _pool_write(pool: jnp.ndarray, layer_idx: int, idx, value):
+    """One layer's plane of the paged pool with ``value`` written at
+    ``idx``: the per-layer read of the pool, then the write."""
+    with jax.named_scope("kv.slice"):
+        plane = pool[layer_idx]
+    with jax.named_scope("kv.write"):
+        return plane.at[idx].set(value)
+
+
+def _cache_write(cache: jnp.ndarray, layer_idx: int, item, start):
+    """One layer's plane of a dense cache with ``item`` written at
+    ``start`` (``dynamic_update_slice``)."""
+    with jax.named_scope("kv.slice"):
+        plane = cache[layer_idx]
+    with jax.named_scope("kv.write"):
+        return jax.lax.dynamic_update_slice(plane, item, start)
+
+
 def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
                 positions: jnp.ndarray, sin, cos,
                 kv_cache: Optional[Params], layer_idx: int,
@@ -136,15 +165,21 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
                 mesh=None):
     b, t, _ = x.shape
     h = rms_norm(x, layer["attn_norm"], cfg.norm_eps, cfg.norm_offset)
-    q = maybe_matmul(h, layer["wq"]).reshape(b, t, cfg.n_heads, cfg.head_dim)
-    k = maybe_matmul(h, layer["wk"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
-    v = maybe_matmul(h, layer["wv"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
-    q = apply_rope(q, positions, sin, cos)
-    k = apply_rope(k, positions, sin, cos)
+    with jax.named_scope("attn.qkv"):
+        q = maybe_matmul(h, layer["wq"]).reshape(
+            b, t, cfg.n_heads, cfg.head_dim)
+        k = maybe_matmul(h, layer["wk"]).reshape(
+            b, t, cfg.n_kv_heads, cfg.head_dim)
+        v = maybe_matmul(h, layer["wv"]).reshape(
+            b, t, cfg.n_kv_heads, cfg.head_dim)
+    with jax.named_scope("attn.rope"):
+        q = apply_rope(q, positions, sin, cos)
+        k = apply_rope(k, positions, sin, cos)
 
     new_cache = None
     if kv_cache is None:
-        out = attention(q, k, v, causal=True, mesh=mesh)
+        with jax.named_scope("attn.core"):
+            out = attention(q, k, v, causal=True, mesh=mesh)
     elif decode and "table" in kv_cache:
         # paged decode: scatter this token's k/v into the slot's physical
         # pool block, then block-table paged attention over the prefix.
@@ -160,20 +195,24 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
         bi = table[rows, pos // bs]
         oi = pos % bs
         if "k_scale" in kv_cache:
-            qk, sk = quantize_kv(k[:, 0])              # [B,KH,D], [B,KH]
-            qv, sv = quantize_kv(v[:, 0])
-            k_pool = kv_cache["k"][layer_idx].at[bi, oi].set(qk)
-            v_pool = kv_cache["v"][layer_idx].at[bi, oi].set(qv)
-            k_sc = kv_cache["k_scale"][layer_idx].at[bi, oi].set(sk)
-            v_sc = kv_cache["v_scale"][layer_idx].at[bi, oi].set(sv)
-            out = paged_attention_dispatch(q, k_pool, v_pool, table,
-                                           cache_len, k_sc, v_sc, mesh=mesh)
+            with jax.named_scope("kv.write"):
+                qk, sk = quantize_kv(k[:, 0])          # [B,KH,D], [B,KH]
+                qv, sv = quantize_kv(v[:, 0])
+            k_pool = _pool_write(kv_cache["k"], layer_idx, (bi, oi), qk)
+            v_pool = _pool_write(kv_cache["v"], layer_idx, (bi, oi), qv)
+            k_sc = _pool_write(kv_cache["k_scale"], layer_idx, (bi, oi), sk)
+            v_sc = _pool_write(kv_cache["v_scale"], layer_idx, (bi, oi), sv)
+            with jax.named_scope("attn.core"):
+                out = paged_attention_dispatch(q, k_pool, v_pool, table,
+                                               cache_len, k_sc, v_sc,
+                                               mesh=mesh)
             new_cache = (k_pool, v_pool, k_sc, v_sc)
         else:
-            k_pool = kv_cache["k"][layer_idx].at[bi, oi].set(k[:, 0])
-            v_pool = kv_cache["v"][layer_idx].at[bi, oi].set(v[:, 0])
-            out = paged_attention_dispatch(q, k_pool, v_pool, table,
-                                           cache_len, mesh=mesh)
+            k_pool = _pool_write(kv_cache["k"], layer_idx, (bi, oi), k[:, 0])
+            v_pool = _pool_write(kv_cache["v"], layer_idx, (bi, oi), v[:, 0])
+            with jax.named_scope("attn.core"):
+                out = paged_attention_dispatch(q, k_pool, v_pool, table,
+                                               cache_len, mesh=mesh)
             new_cache = (k_pool, v_pool)
     elif "table" in kv_cache:
         # paged multi-token VERIFY (speculative decoding): scatter all T
@@ -188,32 +227,36 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
         bi = jnp.take_along_axis(table, positions // bs, axis=1)  # [B,T]
         oi = positions % bs
         if "k_scale" in kv_cache:
-            qk, sk = quantize_kv(k)                    # [B,T,KH,D],[B,T,KH]
-            qv, sv = quantize_kv(v)
-            k_pool = kv_cache["k"][layer_idx].at[bi, oi].set(qk)
-            v_pool = kv_cache["v"][layer_idx].at[bi, oi].set(qv)
-            k_sc = kv_cache["k_scale"][layer_idx].at[bi, oi].set(sk)
-            v_sc = kv_cache["v_scale"][layer_idx].at[bi, oi].set(sv)
-            out = paged_verify_attention(q, k_pool, v_pool, table,
-                                         positions, k_sc, v_sc)
+            with jax.named_scope("kv.write"):
+                qk, sk = quantize_kv(k)                # [B,T,KH,D],[B,T,KH]
+                qv, sv = quantize_kv(v)
+            k_pool = _pool_write(kv_cache["k"], layer_idx, (bi, oi), qk)
+            v_pool = _pool_write(kv_cache["v"], layer_idx, (bi, oi), qv)
+            k_sc = _pool_write(kv_cache["k_scale"], layer_idx, (bi, oi), sk)
+            v_sc = _pool_write(kv_cache["v_scale"], layer_idx, (bi, oi), sv)
+            with jax.named_scope("attn.core"):
+                out = paged_verify_attention(q, k_pool, v_pool, table,
+                                             positions, k_sc, v_sc)
             new_cache = (k_pool, v_pool, k_sc, v_sc)
         else:
-            k_pool = kv_cache["k"][layer_idx].at[bi, oi].set(k)
-            v_pool = kv_cache["v"][layer_idx].at[bi, oi].set(v)
-            out = paged_verify_attention(q, k_pool, v_pool, table,
-                                         positions)
+            k_pool = _pool_write(kv_cache["k"], layer_idx, (bi, oi), k)
+            v_pool = _pool_write(kv_cache["v"], layer_idx, (bi, oi), v)
+            with jax.named_scope("attn.core"):
+                out = paged_verify_attention(q, k_pool, v_pool, table,
+                                             positions)
             new_cache = (k_pool, v_pool)
     elif decode:
         # scatter this token's k/v at positions, then attend over the prefix
-        k_cache = jax.lax.dynamic_update_slice(
-            kv_cache["k"][layer_idx], k,
+        k_cache = _cache_write(
+            kv_cache["k"], layer_idx, k,
             (0, positions[0, 0], 0, 0)) if b == 1 else _scatter_kv(
                 kv_cache["k"][layer_idx], k, positions)
-        v_cache = jax.lax.dynamic_update_slice(
-            kv_cache["v"][layer_idx], v,
+        v_cache = _cache_write(
+            kv_cache["v"], layer_idx, v,
             (0, positions[0, 0], 0, 0)) if b == 1 else _scatter_kv(
                 kv_cache["v"][layer_idx], v, positions)
-        out = decode_attention(q, k_cache, v_cache, cache_len, mesh=mesh)
+        with jax.named_scope("attn.core"):
+            out = decode_attention(q, k_cache, v_cache, cache_len, mesh=mesh)
         new_cache = (k_cache, v_cache)
     elif cache_len is not None:
         # CHUNKED prefill: write this chunk at its PER-ROW offset, then
@@ -227,44 +270,46 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
         from ..ops.attention import chunk_prefill_attention
         if b == 1:
             off = positions[0, 0]
-            k_cache = jax.lax.dynamic_update_slice(
-                kv_cache["k"][layer_idx], k, (0, off, 0, 0))
-            v_cache = jax.lax.dynamic_update_slice(
-                kv_cache["v"][layer_idx], v, (0, off, 0, 0))
+            k_cache = _cache_write(kv_cache["k"], layer_idx, k,
+                                   (0, off, 0, 0))
+            v_cache = _cache_write(kv_cache["v"], layer_idx, v,
+                                   (0, off, 0, 0))
         else:
             def write_chunk(c, item, off0):
                 return jax.lax.dynamic_update_slice(c, item, (off0, 0, 0))
 
-            k_cache = jax.vmap(write_chunk)(
-                kv_cache["k"][layer_idx], k, positions[:, 0])
-            v_cache = jax.vmap(write_chunk)(
-                kv_cache["v"][layer_idx], v, positions[:, 0])
-        out = chunk_prefill_attention(q, k_cache, v_cache, positions)
+            with jax.named_scope("kv.write"):
+                k_cache = jax.vmap(write_chunk)(
+                    kv_cache["k"][layer_idx], k, positions[:, 0])
+                v_cache = jax.vmap(write_chunk)(
+                    kv_cache["v"][layer_idx], v, positions[:, 0])
+        with jax.named_scope("attn.core"):
+            out = chunk_prefill_attention(q, k_cache, v_cache, positions)
         new_cache = (k_cache, v_cache)
     else:
         # prefill: write [0, t) then causal-attend within the prefix
-        k_cache = jax.lax.dynamic_update_slice(
-            kv_cache["k"][layer_idx], k, (0, 0, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            kv_cache["v"][layer_idx], v, (0, 0, 0, 0))
-        out = attention(q, k, v, causal=True, mesh=mesh)
+        k_cache = _cache_write(kv_cache["k"], layer_idx, k, (0, 0, 0, 0))
+        v_cache = _cache_write(kv_cache["v"], layer_idx, v, (0, 0, 0, 0))
+        with jax.named_scope("attn.core"):
+            out = attention(q, k, v, causal=True, mesh=mesh)
         new_cache = (k_cache, v_cache)
 
-    out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)
-    return x + maybe_matmul(out, layer["wo"]), new_cache
+    with jax.named_scope("attn.out"):
+        out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)
+        return x + maybe_matmul(out, layer["wo"]), new_cache
 
 
 def _scatter_kv(cache: jnp.ndarray, kv: jnp.ndarray,
                 positions: jnp.ndarray) -> jnp.ndarray:
     """Per-sequence scatter of one token: cache [B,S,KH,D], kv [B,1,KH,D],
     positions [B,1]."""
-    b = cache.shape[0]
     idx = positions[:, 0]
 
     def write_one(c, item, i):
         return jax.lax.dynamic_update_slice(c, item, (i, 0, 0))
 
-    return jax.vmap(write_one)(cache, kv, idx)
+    with jax.named_scope("kv.write"):
+        return jax.vmap(write_one)(cache, kv, idx)
 
 
 def _mlp_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig):
@@ -272,9 +317,12 @@ def _mlp_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig):
     if cfg.n_experts:
         from .moe import moe_ffn
         y, aux = moe_ffn(layer["moe"], h, _moe_cfg(cfg), ep_sharded=False)
-        return x + y, aux
-    gated = _act(maybe_matmul(h, layer["w_gate"]), cfg.act) * maybe_matmul(h, layer["w_up"])
-    return x + maybe_matmul(gated, layer["w_down"]), None
+        with jax.named_scope("moe.combine"):
+            return x + y, aux
+    with jax.named_scope("ffn"):
+        gated = _act(maybe_matmul(h, layer["w_gate"]), cfg.act) \
+            * maybe_matmul(h, layer["w_up"])
+        return x + maybe_matmul(gated, layer["w_down"]), None
 
 
 def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
@@ -299,9 +347,10 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(t), (b, t))
 
-    x = params["embed"][tokens].astype(cfg.dtype)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.dim ** 0.5, dtype=cfg.dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)
+        if cfg.embed_scale:
+            x = x * jnp.asarray(cfg.dim ** 0.5, dtype=cfg.dtype)
 
     # the rope table must cover every cache slot: positions past the table
     # are CLAMPED by JAX's gather, rotating distinct positions identically
@@ -314,7 +363,8 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
             raise ValueError(
                 f"kv cache length {cache_s} exceeds rope table "
                 f"{rope_len} — positions past it would alias")
-    sin, cos = rope_table(rope_len, cfg.head_dim, cfg.rope_theta)
+    with jax.named_scope("attn.rope"):
+        sin, cos = rope_table(rope_len, cfg.head_dim, cfg.rope_theta)
 
     updates: list = []        # per-layer (k, v[, k_scale, v_scale]) tuples
     moe_balance = jnp.zeros((), jnp.float32)
@@ -331,15 +381,20 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
     if return_hidden:
         logits = None
     else:
-        if cfg.tie_embeddings:
-            logits = (x @ params["embed"].T.astype(cfg.dtype)).astype(jnp.float32)
-        else:
-            logits = maybe_matmul(x, params["lm_head"]).astype(jnp.float32)
-        if cfg.logit_softcap > 0:
-            logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
+        with jax.named_scope("head"):
+            if cfg.tie_embeddings:
+                logits = (x @ params["embed"].T.astype(cfg.dtype)).astype(
+                    jnp.float32)
+            else:
+                logits = maybe_matmul(x, params["lm_head"]).astype(
+                    jnp.float32)
+            if cfg.logit_softcap > 0:
+                logits = cfg.logit_softcap * jnp.tanh(
+                    logits / cfg.logit_softcap)
 
     out = x if return_hidden else logits
 
+    @jax.named_scope("kv.pack")
     def _pack_cache():
         cache = {"k": jnp.stack([u[0] for u in updates]),
                  "v": jnp.stack([u[1] for u in updates])}

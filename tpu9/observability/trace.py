@@ -24,6 +24,12 @@ process boundary carries the pair explicitly — ``Tracer.context()`` reads
 it, ``start_span(trace_id=..., parent_id=...)`` / ``span(parent_id=...)``
 re-attach under it (the gateway ships it to runners in the
 ``X-Tpu9-Trace`` header).
+
+Host phases (ISSUE 24) are NOT spans: :class:`phase` puts an interval on
+the profiler's own clock (``jax.profiler.TraceAnnotation``, beside the
+device planes of any running trace) and adds its self time to a
+:class:`PhaseTotals` table — no ring entry, no id, no wall clock. The ring
+keeps its per-request role.
 """
 
 from __future__ import annotations
@@ -43,6 +49,84 @@ _current_span: contextvars.ContextVar[Optional["Span"]] = \
 
 def new_trace_id() -> str:
     return uuid.uuid4().hex
+
+
+class PhaseTotals(dict):
+    """``{phase name: [count, self seconds]}`` of one thread's phases. Self
+    time: a phase's elapsed time less that of the phases opened inside it,
+    so the table's seconds add up to the time the thread spent under any
+    phase — the same innermost-wins attribution a trace reader makes."""
+
+    __slots__ = ("open",)
+
+    def __init__(self):
+        super().__init__()
+        self.open: list = []     # seconds spent in children, per open phase
+
+
+def _no_annotation(_name, **_attrs):
+    return None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or a stand-in where jax is absent
+    (the control plane's processes never import it)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return _no_annotation
+    return TraceAnnotation
+
+
+_annotation = None
+
+
+class phase:
+    """``with phase("engine.window.dispatch", totals, k=8):`` — one named
+    interval of host work. While a profiler trace runs it is an event on
+    the ``/host:CPU`` plane's line of this thread, on the clock of the
+    ``/device:TPU:n`` planes, with ``attrs`` as its stats; with no trace
+    running the annotation is one atomic load. ``totals`` (a
+    :class:`PhaseTotals`) gets the self time. ``set`` adds stats known
+    only at the end."""
+
+    __slots__ = ("name", "totals", "ann", "t0")
+
+    def __init__(self, name: str, totals: Optional[PhaseTotals] = None,
+                 **attrs):
+        global _annotation
+        if _annotation is None:
+            _annotation = _trace_annotation()
+        self.name = name
+        self.totals = totals
+        self.ann = _annotation(name, **attrs)
+
+    def set(self, **attrs) -> None:
+        if self.ann is not None:
+            self.ann.set_metadata(**attrs)
+
+    def __enter__(self) -> "phase":
+        if self.ann is not None:
+            self.ann.__enter__()
+        if self.totals is not None:
+            self.totals.open.append(0.0)
+            self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        totals = self.totals
+        if totals is not None:
+            elapsed = time.monotonic() - self.t0
+            inside = totals.open.pop()
+            if totals.open:
+                totals.open[-1] += elapsed
+            rec = totals.get(self.name)
+            if rec is None:
+                rec = totals[self.name] = [0, 0.0]
+            rec[0] += 1
+            rec[1] += elapsed - inside
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
 
 
 class Span:

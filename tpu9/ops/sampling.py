@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("sample")
 def sample_logits(logits: jnp.ndarray, rng: jax.Array,
                   temperature: float = 0.0, top_k: int = 0,
                   top_p: float = 1.0) -> jnp.ndarray:

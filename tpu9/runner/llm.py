@@ -360,6 +360,9 @@ async def amain() -> None:
                     os._exit(17)
                 await sr.write(
                     f"data: {json.dumps({'token': tok})}\n\n".encode())
+                if len(out) == 1:
+                    # stream lag: first token's queue put -> written here
+                    state["engine"].note_first_write(req)
                 if kv_pending:
                     kv_pending = False
                     ev = await _kv_publish(prompt, trace)
@@ -404,14 +407,15 @@ async def amain() -> None:
                 limit=limit, since_seq=since_seq)})
 
     async def profile(request: web.Request) -> web.Response:
-        """Arm jax.profiler for the next N engine windows (ISSUE 8);
-        returns the dump path on THIS replica immediately."""
+        """Trace the next ``seconds`` seconds with jax.profiler from a
+        worker thread of the engine (ISSUE 24); returns the dump path on
+        THIS replica immediately."""
         if not state["ready"]:
             return web.json_response({"error": "not ready"}, status=503)
         try:
             payload = json.loads(await request.read() or b"{}")
             out = state["engine"].arm_profile(
-                windows=int(payload.get("windows", 8)),
+                seconds=float(payload.get("seconds", 4.0)),
                 out_dir=str(payload.get("out_dir", "") or ""))
         except ValueError as exc:
             return web.json_response({"error": str(exc)}, status=400)
@@ -580,7 +584,7 @@ async def amain() -> None:
         # peer-cache publications this replica made ((key_hex16, digest,
         # n_tokens)) — re-advertised each beat, bounded
         peer_pub: list = []
-        from ..observability.trace import RING_CAP, tracer
+        from ..observability.trace import RING_CAP, phase, tracer
         # replica health plane (ISSUE 14): the watchdog classifies the
         # engine's liveness watermark each beat and the verdict rides the
         # heartbeat — this loop is exactly the "runner still alive while
@@ -604,91 +608,92 @@ async def amain() -> None:
                 headers={"Authorization": f"Bearer {token}"}) as session:
             while True:
                 try:
-                    stats = engine.stats()
-                    # fleet-router / observability extras (ISSUE 2
-                    # satellite): queue depth, KV headroom, prefix-cache
-                    # hit rate — flat scalars only (the pressure table is
-                    # a store hash; nested dicts don't round-trip)
-                    extra = {"queued": stats.get("queued", 0)}
-                    for k in ("kv_blocks_free", "kv_blocks_used",
-                              "kv_blocks_reserved", "kv_block_size",
-                              # int8 KV pool flag (ISSUE 6): the block
-                              # counts already reflect the 2x pool, this
-                              # labels WHY a replica reports double
-                              "kv_quant",
-                              # speculative-decoding acceptance (ISSUE 5):
-                              # the router aggregates these into the
-                              # fleet-wide tpu9_router_spec_* signals
-                              "spec_proposed", "spec_accepted",
-                              "spec_acceptance_rate",
-                              # serving submesh (ISSUE 9): which topology
-                              # this replica runs and its worst-chip live
-                              # HBM — the fleet view's multichip evidence
-                              "topo_tp", "topo_fsdp", "topo_n_chips",
-                              "hbm_used_gb_per_chip",
-                              # HBM watermarks + liveness watermark
-                              # (ISSUE 14): peak/predicted/limit make
-                              # planner-vs-reality drift graphable; the
-                              # ages are the watchdog's raw evidence,
-                              # surfaced so `tpu9 top` / the black box
-                              # can show WHY a verdict was reached
-                              "hbm_peak_gb_per_chip",
-                              "hbm_predicted_gb_per_chip",
-                              "hbm_limit_gb_per_chip",
-                              "windows_processed",
-                              "last_dispatch_age_s",
-                              "last_progress_age_s",
-                              # recompile sentinel (ISSUE 11): a non-zero
-                              # post_warmup count is a mid-serve XLA
-                              # compile — the closed-signature invariant
-                              # broke at runtime
-                              "graph_compiles",
-                              "graph_compiles_post_warmup",
-                              # fleet timeline + goodput accounting
-                              # (ISSUE 12): windowed tokens/sec, the
-                              # cumulative counters the gateway's
-                              # accountant differentiates, and the decode
-                              # physics constants the control plane
-                              # prices MFU/MBU from
-                              "tokens_per_sec", "tokens_generated",
-                              "graph_compile_stall_s",
-                              "decode_bytes_per_token_per_chip",
-                              "decode_flops_per_token_per_chip",
-                              # the devices the engine is placed on, as
-                              # its own jax reports them
-                              "device_platform", "device_kind",
-                              "device_count"):
-                        if k in stats:
-                            extra[k] = stats[k]
-                    pc = stats.get("prefix_cache")
-                    if isinstance(pc, dict):
-                        hits = pc.get("hits", 0)
-                        misses = pc.get("misses", 0)
-                        extra["prefix_hits"] = hits
-                        extra["prefix_misses"] = misses
-                        extra["prefix_hit_rate"] = (
-                            hits / (hits + misses) if hits + misses else 0.0)
-                    # cold-start decomposition (ISSUE 13): the runner half
-                    # of the per-replica readiness record — flat
-                    # coldstart_* scalars merged by /api/v1/coldstart
-                    for k, v in stats.items():
-                        if k.startswith("coldstart_"):
-                            extra[k] = v
-                    # kvwire (ISSUE 16): block-ship counters + latency
-                    # percentiles — one prefix covers the whole family
-                    # (engine.stats() keeps them flat on purpose)
-                    for k, v in stats.items():
-                        if k.startswith("kvwire_"):
-                            extra[k] = v
-                    # kv tiering (ISSUE 20): occupancy/paging counters
-                    # (same one-startswith-loop contract as kvwire_*),
-                    # then the directory summaries: a bounded top-K
-                    # prefix-key digest, the eviction-delta retractions,
-                    # and this replica's peer-cache publications — never
-                    # full key lists
-                    for k, v in stats.items():
-                        if k.startswith("kvtier_"):
-                            extra[k] = v
+                    with phase("runner.heartbeat", engine.host_phases):
+                        stats = engine.stats()
+                        # fleet-router / observability extras (ISSUE 2
+                        # satellite): queue depth, KV headroom, prefix-cache
+                        # hit rate — flat scalars only (the pressure table is
+                        # a store hash; nested dicts don't round-trip)
+                        extra = {"queued": stats.get("queued", 0)}
+                        for k in ("kv_blocks_free", "kv_blocks_used",
+                                  "kv_blocks_reserved", "kv_block_size",
+                                  # int8 KV pool flag (ISSUE 6): the block
+                                  # counts already reflect the 2x pool, this
+                                  # labels WHY a replica reports double
+                                  "kv_quant",
+                                  # speculative-decoding acceptance (ISSUE 5):
+                                  # the router aggregates these into the
+                                  # fleet-wide tpu9_router_spec_* signals
+                                  "spec_proposed", "spec_accepted",
+                                  "spec_acceptance_rate",
+                                  # serving submesh (ISSUE 9): which topology
+                                  # this replica runs and its worst-chip live
+                                  # HBM — the fleet view's multichip evidence
+                                  "topo_tp", "topo_fsdp", "topo_n_chips",
+                                  "hbm_used_gb_per_chip",
+                                  # HBM watermarks + liveness watermark
+                                  # (ISSUE 14): peak/predicted/limit make
+                                  # planner-vs-reality drift graphable; the
+                                  # ages are the watchdog's raw evidence,
+                                  # surfaced so `tpu9 top` / the black box
+                                  # can show WHY a verdict was reached
+                                  "hbm_peak_gb_per_chip",
+                                  "hbm_predicted_gb_per_chip",
+                                  "hbm_limit_gb_per_chip",
+                                  "windows_processed",
+                                  "last_dispatch_age_s",
+                                  "last_progress_age_s",
+                                  # recompile sentinel (ISSUE 11): a non-zero
+                                  # post_warmup count is a mid-serve XLA
+                                  # compile — the closed-signature invariant
+                                  # broke at runtime
+                                  "graph_compiles",
+                                  "graph_compiles_post_warmup",
+                                  # fleet timeline + goodput accounting
+                                  # (ISSUE 12): windowed tokens/sec, the
+                                  # cumulative counters the gateway's
+                                  # accountant differentiates, and the decode
+                                  # physics constants the control plane
+                                  # prices MFU/MBU from
+                                  "tokens_per_sec", "tokens_generated",
+                                  "graph_compile_stall_s",
+                                  "decode_bytes_per_token_per_chip",
+                                  "decode_flops_per_token_per_chip",
+                                  # the devices the engine is placed on, as
+                                  # its own jax reports them
+                                  "device_platform", "device_kind",
+                                  "device_count"):
+                            if k in stats:
+                                extra[k] = stats[k]
+                        pc = stats.get("prefix_cache")
+                        if isinstance(pc, dict):
+                            hits = pc.get("hits", 0)
+                            misses = pc.get("misses", 0)
+                            extra["prefix_hits"] = hits
+                            extra["prefix_misses"] = misses
+                            extra["prefix_hit_rate"] = (
+                                hits / (hits + misses) if hits + misses else 0.0)
+                        # cold-start decomposition (ISSUE 13): the runner half
+                        # of the per-replica readiness record — flat
+                        # coldstart_* scalars merged by /api/v1/coldstart
+                        for k, v in stats.items():
+                            if k.startswith("coldstart_"):
+                                extra[k] = v
+                        # kvwire (ISSUE 16): block-ship counters + latency
+                        # percentiles — one prefix covers the whole family
+                        # (engine.stats() keeps them flat on purpose)
+                        for k, v in stats.items():
+                            if k.startswith("kvwire_"):
+                                extra[k] = v
+                        # kv tiering (ISSUE 20): occupancy/paging counters
+                        # (same one-startswith-loop contract as kvwire_*),
+                        # then the directory summaries: a bounded top-K
+                        # prefix-key digest, the eviction-delta retractions,
+                        # and this replica's peer-cache publications — never
+                        # full key lists
+                        for k, v in stats.items():
+                            if k.startswith("kvtier_"):
+                                extra[k] = v
                     tier_hi = last_tier_delta
                     if kvtier_hb and state["engine"] is not None:
                         # serving-plane kv_tier choices (spill scoring,
@@ -728,77 +733,79 @@ async def amain() -> None:
                             extra["kvtier_peer"] = ",".join(
                                 f"{hx}:{dig}:{nt}"
                                 for hx, dig, nt in peer_pub)
-                    # scale-out readiness (ISSUE 17): per-group bind
-                    # progress of a streaming restore — the router's
-                    # partial-readiness admission reads these off the
-                    # pressure hash, the coordinator off the heartbeat
-                    for k, v in stats.items():
-                        if k.startswith("scaleout_"):
+                    with phase("runner.heartbeat", engine.host_phases) as beat:
+                        # scale-out readiness (ISSUE 17): per-group bind
+                        # progress of a streaming restore — the router's
+                        # partial-readiness admission reads these off the
+                        # pressure hash, the coordinator off the heartbeat
+                        for k, v in stats.items():
+                            if k.startswith("scaleout_"):
+                                extra[k] = v
+                        # latency decomposition (ISSUE 8): per-phase p50/p95
+                        # flat scalars → /api/v1/metrics "engines" section
+                        for k, v in (stats.get("latency") or {}).items():
                             extra[k] = v
-                    # latency decomposition (ISSUE 8): per-phase p50/p95
-                    # flat scalars → /api/v1/metrics "engines" section
-                    for k, v in (stats.get("latency") or {}).items():
-                        extra[k] = v
-                    fl = stats.get("flight")
-                    if isinstance(fl, dict):
-                        extra["flight_records"] = fl.get("records", 0)
-                        extra["flight_last_seq"] = fl.get("last_seq", 0)
-                    # health verdict (ISSUE 14): classified HERE, shipped
-                    # on the same beat — the gateway folds it into the
-                    # engines merge and the router ejects on `stalled`
-                    health, reason = watchdog.assess(stats)
-                    extra["health"] = health
-                    extra["health_reason"] = reason
-                    extra["health_since_s"] = round(watchdog.in_state_s, 3)
-                    # post-mortem triggers: a watchdog trip (once per
-                    # incident) or the serve loop's own death (the crash
-                    # handler left engine.last_postmortem behind). The
-                    # record is held until the gateway ACCEPTS it — a
-                    # gateway blip must not eat the black box.
-                    if pending_pm is None:
-                        pm_reason = pm_exc = ""
-                        if stats.get("engine_dead") and not crash_shipped:
-                            crash_shipped = True
-                            pm_reason, pm_exc = ("engine_dead",
-                                                 "serve loop dead")
-                            # the dead engine trips the watchdog's stall
-                            # flag too — SAME incident: consume it, or
-                            # the next beat ships a duplicate
-                            # watchdog_stall record for this death
-                            watchdog.pop_stall_trip()
-                        elif watchdog.pop_stall_trip():
-                            pm_reason, pm_exc = "watchdog_stall", reason
-                        if pm_reason:
-                            # blackbox() reads live engine state next to
-                            # a dead/wedged loop — a failing snapshot
-                            # must degrade to a header-only record, never
-                            # kill THIS loop (the replica would fall
-                            # silent, the outcome the watchdog prevents)
-                            try:
-                                raw = (engine.last_postmortem
-                                       if pm_reason == "engine_dead"
-                                       and engine.last_postmortem
-                                       else engine.blackbox(pm_reason,
-                                                            pm_exc))
-                                pending_pm = build_postmortem(
-                                    container_id=cfg.container_id, **raw)
-                            except Exception:   # noqa: BLE001
-                                log.exception(
-                                    "post-mortem snapshot failed")
-                                pending_pm = build_postmortem(
-                                    reason=pm_reason,
-                                    exception=f"{pm_exc} (snapshot "
-                                              "failed; header only)",
-                                    container_id=cfg.container_id,
-                                    stats={k: v for k, v in stats.items()
-                                           if isinstance(v, (int, float,
-                                                             str, bool))})
-                    # engine spans ride the heartbeat the way worker rings
-                    # ride the keepalive (worker.py ship analogue)
-                    spans, ship_hi = tracer.export_new(
-                        since_mono=last_span_ship, limit=RING_CAP)
-                    decs, dec_hi = decision_ledger.export_new(
-                        since_seq=last_dec_ship, limit=512)
+                        fl = stats.get("flight")
+                        if isinstance(fl, dict):
+                            extra["flight_records"] = fl.get("records", 0)
+                            extra["flight_last_seq"] = fl.get("last_seq", 0)
+                        # health verdict (ISSUE 14): classified HERE, shipped
+                        # on the same beat — the gateway folds it into the
+                        # engines merge and the router ejects on `stalled`
+                        health, reason = watchdog.assess(stats)
+                        extra["health"] = health
+                        extra["health_reason"] = reason
+                        extra["health_since_s"] = round(watchdog.in_state_s, 3)
+                        # post-mortem triggers: a watchdog trip (once per
+                        # incident) or the serve loop's own death (the crash
+                        # handler left engine.last_postmortem behind). The
+                        # record is held until the gateway ACCEPTS it — a
+                        # gateway blip must not eat the black box.
+                        if pending_pm is None:
+                            pm_reason = pm_exc = ""
+                            if stats.get("engine_dead") and not crash_shipped:
+                                crash_shipped = True
+                                pm_reason, pm_exc = ("engine_dead",
+                                                     "serve loop dead")
+                                # the dead engine trips the watchdog's stall
+                                # flag too — SAME incident: consume it, or
+                                # the next beat ships a duplicate
+                                # watchdog_stall record for this death
+                                watchdog.pop_stall_trip()
+                            elif watchdog.pop_stall_trip():
+                                pm_reason, pm_exc = "watchdog_stall", reason
+                            if pm_reason:
+                                # blackbox() reads live engine state next to
+                                # a dead/wedged loop — a failing snapshot
+                                # must degrade to a header-only record, never
+                                # kill THIS loop (the replica would fall
+                                # silent, the outcome the watchdog prevents)
+                                try:
+                                    raw = (engine.last_postmortem
+                                           if pm_reason == "engine_dead"
+                                           and engine.last_postmortem
+                                           else engine.blackbox(pm_reason,
+                                                                pm_exc))
+                                    pending_pm = build_postmortem(
+                                        container_id=cfg.container_id, **raw)
+                                except Exception:   # noqa: BLE001
+                                    log.exception(
+                                        "post-mortem snapshot failed")
+                                    pending_pm = build_postmortem(
+                                        reason=pm_reason,
+                                        exception=f"{pm_exc} (snapshot "
+                                                  "failed; header only)",
+                                        container_id=cfg.container_id,
+                                        stats={k: v for k, v in stats.items()
+                                               if isinstance(v, (int, float,
+                                                                 str, bool))})
+                        # engine spans ride the heartbeat the way worker rings
+                        # ride the keepalive (worker.py ship analogue)
+                        spans, ship_hi = tracer.export_new(
+                            since_mono=last_span_ship, limit=RING_CAP)
+                        decs, dec_hi = decision_ledger.export_new(
+                            since_seq=last_dec_ship, limit=512)
+                        beat.set(spans=len(spans))
                     if faults is not None and faults.active(
                             "heartbeat_loss"):
                         # induced heartbeat loss: the replica falls

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Optional
@@ -43,7 +44,7 @@ import numpy as np
 
 from ..models.transformer import DecoderConfig, init_kv_cache
 from ..observability.metrics import Metrics
-from ..observability.trace import tracer
+from ..observability.trace import PhaseTotals, phase, tracer
 from ..ops.sampling import sample_logits
 from ..utils.aio import reap
 from .flight import maybe as flight_maybe
@@ -168,7 +169,7 @@ class _Window:
     # this K was picked, allocator snapshot at dispatch, and the host
     # fan-out outcome (tokens delivered per live slot) filled in during
     # processing — everything the flight record and the per-request
-    # decode-window spans need, with zero extra device syncs
+    # decode span need, with zero extra device syncs
     t_mono: float = 0.0
     t_wall: float = 0.0
     pick: str = ""
@@ -197,12 +198,19 @@ class _Request:
     trace: Optional[tuple] = None
     span: Any = None
     span_id: str = ""    # survives _obs_done so the window that RETIRES a
-    #                      request can still parent its decode_window span
+    #                      request can still parent its decode span
     t_enqueue_mono: float = 0.0
     t_enqueue_wall: float = 0.0
+    t_admit_end_mono: float = 0.0           # admission (prefill) dispatched
     t_first_mono: float = 0.0               # first token delivered
+    t_done_mono: float = 0.0                # terminal answer given
     admit_cached: int = 0                   # prefix-cache tokens reused
     admit_chunks: int = 0                   # prefill chunks dispatched
+    # the ONE engine.decode span of a traced request (ISSUE 24), summed
+    # over its windows and recorded when it retires: the wall/monotonic
+    # anchors of its first window's dispatch, and the span's attributes
+    dec_anchor: tuple = ()
+    dec: Optional[dict] = None
 
 
 class InferenceEngine:
@@ -397,12 +405,15 @@ class InferenceEngine:
         self._flight_kv_allocs = 0   # marker for per-record deltas
         # (lifetime allocation counter lives on the KvPool manager)
         self._flight_evictions = 0
-        # on-demand jax.profiler hook (/rpc/llm/profile): armed for the
-        # next N windows, started/stopped at window boundaries
-        self._profile_remaining = 0
-        self._profile_active = False
-        self._profile_path = ""
-        self._profile_error = ""
+        # on-demand jax.profiler hook (/rpc/llm/profile): a worker thread
+        # traces a stated number of seconds (arm_profile)
+        self._profile = {"active": False, "path": "", "seconds": 0.0,
+                         "error": ""}
+        self._profile_thread: Optional[threading.Thread] = None
+        self._profile_cut = threading.Event()
+        # host phases of the serve loop's thread (ISSUE 24): self seconds
+        # and counts by phase name — stats() "host_phase_s"/"host_phase_n"
+        self.host_phases = PhaseTotals()
         # ---- fleet timeline physics (ISSUE 12) ----
         # tokens/sec window: (monotonic, tokens_generated) pairs appended
         # on the stats() READ path (heartbeat cadence), zero serve-loop
@@ -744,11 +755,14 @@ class InferenceEngine:
         return timings
 
     async def stop(self) -> None:
-        if self._profile_active:
-            # a dangling device trace outlives the engine otherwise
-            self._profile_remaining = 0
-            self._deferred_windows.clear()
-            self._profile_maybe_stop()
+        thread = self._profile_thread
+        if thread is not None and thread.is_alive():
+            # a dangling device trace outlives the engine otherwise: cut
+            # the armed seconds short and wait for the dump off the loop
+            self._profile_cut.set()
+            await asyncio.get_running_loop().run_in_executor(
+                None, thread.join)
+            await asyncio.sleep(0)      # the thread's "stopped" record
         if self._loop_task:
             # reap: absorbs the loop's CancelledError AND an Exception exit
             # (the loop ALREADY died; its failure was logged + fanned out)
@@ -1110,10 +1124,20 @@ class InferenceEngine:
         # backs /api/v1/metrics "engines" unchanged.
         if self.flight is not None:
             out["flight"] = self.flight.summary()
-        out["profile"] = {"armed": self._profile_remaining,
-                          "active": self._profile_active,
-                          "path": self._profile_path,
-                          "error": self._profile_error}
+        out["profile"] = dict(self._profile)
+        # host phases of the serve loop's thread (ISSUE 24): self seconds
+        # and counts by phase, cumulative — where the loop's time goes,
+        # for an operator without a profiler. Nested: kept off the flat
+        # heartbeat, read whole off GET /health.
+        out["host_phase_s"] = {k: round(v[1], 6)
+                               for k, v in self.host_phases.items()}
+        out["host_phase_n"] = {k: v[0]
+                               for k, v in self.host_phases.items()}
+        # which scope (jax.named_scope) each instruction of a precompiled
+        # program belongs to: a trace names device operations by their
+        # HLO instruction, and this is the way back to the model's parts
+        if self.graphs.device_scopes:
+            out["device_scopes"] = self.graphs.device_scopes
         # cold-start decomposition (ISSUE 13): flat coldstart_* scalars so
         # the runner heartbeat forwards them into the pressure hash that
         # backs /api/v1/metrics "engines" and /api/v1/coldstart unchanged
@@ -1132,14 +1156,14 @@ class InferenceEngine:
         out["scaleout_ready_groups"] = ",".join(sg["bound"])
         lat = {}
         summaries = self.metrics.to_dict()["summaries"]
-        for phase in ("ttft", "tbt", "queue_wait", "prefill",
-                      "decode_window", "e2e"):
-            snap = summaries.get(f"tpu9_engine_{phase}_s")
+        for part in ("ttft", "tbt", "queue_wait", "prefill", "first_hold",
+                     "stream_lag", "decode_window", "e2e"):
+            snap = summaries.get(f"tpu9_engine_{part}_s")
             if snap:
-                lat[f"{phase}_p50_s"] = round(snap["p50"], 6)
-                lat[f"{phase}_p95_s"] = round(snap["p95"], 6)
-                lat[f"{phase}_count"] = snap["count"]
-                lat[f"{phase}_mean_s"] = round(snap["mean"], 6)
+                lat[f"{part}_p50_s"] = round(snap["p50"], 6)
+                lat[f"{part}_p95_s"] = round(snap["p95"], 6)
+                lat[f"{part}_count"] = snap["count"]
+                lat[f"{part}_mean_s"] = round(snap["mean"], 6)
         out["latency"] = lat
         # kvwire (ISSUE 16): ship-path latency percentiles, flat under
         # the same kvwire_* prefix as the counters so the runner
@@ -1204,15 +1228,104 @@ class InferenceEngine:
         syncs here; the serve loop syncs the whole admission batch once.
         Returns the first-token device value."""
         from .paged_kv import blocks_for
+        totals = self.host_phases
         bs = self.ecfg.kv_block_size
         n = len(req.prompt)
-        if self._slot_blocks[slot]:
-            # leftovers (bench_reset_slots / defensive): return them first
-            self.allocator.release(self._slot_blocks[slot])
-            self._slot_blocks[slot] = []
-        self._slot_reserved[slot] = self.allocator.reserve(
-            self._worst_case_tokens(req))
+        with phase("engine.admit.plan", totals):
+            if self._slot_blocks[slot]:
+                # leftovers (bench_reset_slots / defensive): return them
+                self.allocator.release(self._slot_blocks[slot])
+                self._slot_blocks[slot] = []
+            self._slot_reserved[slot] = self.allocator.reserve(
+                self._worst_case_tokens(req))
 
+        with phase("engine.admit.lookup", totals):
+            shared, p = await self._admit_lookup(req)
+
+        with phase("engine.admit.plan", totals):
+            total_blocks = blocks_for(n + 1, bs)
+            fresh = self._alloc_blocks(total_blocks - len(shared))
+            self._slot_blocks[slot] = shared + fresh
+            # the DEVICE table row stays all-trash until admission
+            # completes: decode windows interleaved below scatter every
+            # INACTIVE lane's write through its table row at position 0,
+            # which must never be one of the blocks being spliced here
+            row = np.full((self._mb,), self._trash_block, dtype=np.int32)
+            row[:len(self._slot_blocks[slot])] = self._slot_blocks[slot]
+
+        scratch = self._scratch
+        if p:
+            with phase("engine.admit.dispatch", totals, g=0):
+                dense = self._gather_fn()(self._pool_dict(),
+                                          jnp.asarray(row))
+                scratch = {"k": dense["k"], "v": dense["v"]}
+                self._stats["admit_dispatches"] += 1
+
+        with phase("engine.admit.plan", totals):
+            toks_all, offsets, last_idxs, phys_all = self._chunk_tables(
+                req, slot, p)
+        n_chunks = len(offsets)
+        last = None
+        group = max(1, self.ecfg.admit_group_chunks)
+        k_chunk = 0
+        while k_chunk < n_chunks:
+            # FULL groups use the fused scan graph warmup compiled; a
+            # partial tail (2..group-1 chunks) runs through the warmed
+            # single-chunk graphs instead of JIT-compiling a fresh scan
+            # shape mid-traffic (which would stall every active stream
+            # behind an XLA compile)
+            g = group if n_chunks - k_chunk >= group else 1
+            sl = slice(k_chunk, k_chunk + g)
+            with phase("engine.admit.dispatch", totals, g=g):
+                if g > 1:
+                    pool, scratch, last = self._chunk_group_fn(g)(
+                        self.params, self._pool_dict(), scratch,
+                        jnp.asarray(toks_all[sl]),
+                        jnp.asarray(offsets[sl]),
+                        jnp.asarray(last_idxs[sl]),
+                        jnp.asarray(phys_all[sl]))
+                    self._set_pool(pool)
+                    self._stats["admit_dispatches"] += 1
+                else:
+                    last, scratch = self._chunk_fn()(
+                        self.params, jnp.asarray(toks_all[sl]),
+                        int(offsets[k_chunk]), scratch,
+                        int(last_idxs[k_chunk]))
+                    self._set_pool(self._splice_fn()(
+                        self._pool_dict(), scratch["k"], scratch["v"],
+                        int(offsets[k_chunk]),
+                        jnp.asarray(phys_all[k_chunk])))
+                    self._stats["admit_dispatches"] += 2
+            k_chunk += g
+            if k_chunk < n_chunks:
+                # long admission: keep the decode batch producing tokens
+                # and let streaming consumers drain
+                with phase("engine.window.dispatch", totals, kind="decode",
+                           pick="interleave") as ph:
+                    ph.set(k=self._interleave_decode_window())
+                with phase("engine.yield", totals):
+                    await asyncio.sleep(0)
+        self._scratch = scratch
+
+        with phase("engine.admit.finish", totals):
+            if self.ecfg.prefix_cache_blocks > 0:
+                self.prefix_cache.insert(req.prompt,
+                                         self._slot_blocks[slot])
+            self._push_table(slot)        # real row becomes visible NOW
+            self.cache_len = self.cache_len.at[slot].set(n)
+            self._host_len[slot] = n
+            self._rng, sub = jax.random.split(self._rng)
+            first = sample_logits(last, sub,
+                                  temperature=self.ecfg.temperature,
+                                  top_k=self.ecfg.top_k,
+                                  top_p=self.ecfg.top_p)
+            self.last_token = self.last_token.at[slot, 0].set(first)
+            self._occupy_slot(req, slot)
+        return first
+
+    async def _admit_lookup(self, req: _Request) -> tuple:
+        """Prefix-cache lookup of one admission: ``(shared blocks, retained
+        for the slot; cached tokens, rounded down to a chunk)``."""
         entry = self.prefix_cache.lookup(req.prompt) \
             if self.ecfg.prefix_cache_blocks > 0 else None
         if entry is not None and entry.tier == "host":
@@ -1237,26 +1350,14 @@ class InferenceEngine:
             # blocks are retained: a concurrent admission's eviction can
             # no longer free them under us — drop the lookup pin
             self.prefix_cache.release_pin(entry)
+        return shared, p
 
-        total_blocks = blocks_for(n + 1, bs)
-        fresh = self._alloc_blocks(total_blocks - len(shared))
-        self._slot_blocks[slot] = shared + fresh
-        # the DEVICE table row stays all-trash until admission completes:
-        # decode windows interleaved below scatter every INACTIVE lane's
-        # write through its table row at position 0, which must never be
-        # one of the blocks being spliced here
-        row = np.full((self._mb,), self._trash_block, dtype=np.int32)
-        row[:len(self._slot_blocks[slot])] = self._slot_blocks[slot]
-
-        scratch = self._scratch
-        if p:
-            dense = self._gather_fn()(self._pool_dict(), jnp.asarray(row))
-            scratch = {"k": dense["k"], "v": dense["v"]}
-            self._stats["admit_dispatches"] += 1
-
-        # per-chunk host arrays, built once (the former per-chunk python
-        # bookkeeping between dispatches was the loop's biggest host-side
-        # overhead — now it's one numpy pass + one transfer per group)
+    def _chunk_tables(self, req: _Request, slot: int, p: int) -> tuple:
+        """Per-chunk host arrays of one admission past ``p`` cached tokens,
+        built once (the former per-chunk python bookkeeping between
+        dispatches was the loop's biggest host-side overhead — now it's
+        one numpy pass + one transfer per group)."""
+        bs = self.ecfg.kv_block_size
         c = self._chunk
         nb = c // bs
         suffix = req.prompt[p:]
@@ -1281,54 +1382,7 @@ class InferenceEngine:
                 idx = first_block + j
                 if idx < len(self._slot_blocks[slot]):
                     phys_all[k_chunk, j] = self._slot_blocks[slot][idx]
-
-        last = None
-        group = max(1, self.ecfg.admit_group_chunks)
-        k_chunk = 0
-        while k_chunk < n_chunks:
-            # FULL groups use the fused scan graph warmup compiled; a
-            # partial tail (2..group-1 chunks) runs through the warmed
-            # single-chunk graphs instead of JIT-compiling a fresh scan
-            # shape mid-traffic (which would stall every active stream
-            # behind an XLA compile)
-            g = group if n_chunks - k_chunk >= group else 1
-            sl = slice(k_chunk, k_chunk + g)
-            if g > 1:
-                pool, scratch, last = self._chunk_group_fn(g)(
-                    self.params, self._pool_dict(), scratch,
-                    jnp.asarray(toks_all[sl]),
-                    jnp.asarray(offsets[sl]), jnp.asarray(last_idxs[sl]),
-                    jnp.asarray(phys_all[sl]))
-                self._set_pool(pool)
-                self._stats["admit_dispatches"] += 1
-            else:
-                last, scratch = self._chunk_fn()(
-                    self.params, jnp.asarray(toks_all[sl]),
-                    int(offsets[k_chunk]), scratch, int(last_idxs[k_chunk]))
-                self._set_pool(self._splice_fn()(
-                    self._pool_dict(), scratch["k"], scratch["v"],
-                    int(offsets[k_chunk]), jnp.asarray(phys_all[k_chunk])))
-                self._stats["admit_dispatches"] += 2
-            k_chunk += g
-            if k_chunk < n_chunks:
-                # long admission: keep the decode batch producing tokens
-                # and let streaming consumers drain
-                self._interleave_decode_window()
-                await asyncio.sleep(0)
-        self._scratch = scratch
-
-        if self.ecfg.prefix_cache_blocks > 0:
-            self.prefix_cache.insert(req.prompt, self._slot_blocks[slot])
-
-        self._push_table(slot)            # real row becomes visible NOW
-        self.cache_len = self.cache_len.at[slot].set(n)
-        self._host_len[slot] = n
-        self._rng, sub = jax.random.split(self._rng)
-        first = sample_logits(last, sub, temperature=self.ecfg.temperature,
-                              top_k=self.ecfg.top_k, top_p=self.ecfg.top_p)
-        self.last_token = self.last_token.at[slot, 0].set(first)
-        self._occupy_slot(req, slot)
-        return first
+        return toks_all, offsets, last_idxs, phys_all
 
     # -- KV tiering: up-page / down-page (ISSUE 20) --------------------------
 
@@ -1393,7 +1447,8 @@ class InferenceEngine:
             # the scatter is dispatched, not synced: yield so the serve
             # loop can run while it lands — admission's own data deps
             # guarantee residency before the blocks are read
-            await asyncio.sleep(0)
+            with phase("engine.yield", self.host_phases):
+                await asyncio.sleep(0)
             dt = time.perf_counter() - t0
             self._stats["kvtier_uppages"] += 1
             self.metrics.observe("tpu9_kvtier_uppage_s", dt)
@@ -1511,8 +1566,9 @@ class InferenceEngine:
 
     def _obs_admit_end(self, req: _Request, t0_mono: float, t0_wall: float,
                        il0: int) -> None:
-        dur = max(time.monotonic() - t0_mono, 0.0)
-        self._last_progress_mono = time.monotonic()   # admission = progress
+        req.t_admit_end_mono = time.monotonic()
+        dur = max(req.t_admit_end_mono - t0_mono, 0.0)
+        self._last_progress_mono = req.t_admit_end_mono   # = progress
         self.metrics.observe("tpu9_engine_prefill_s", dur)
         interleaved = self._stats["admit_interleaved_windows"] - il0
         if req.trace is not None and req.span is not None:
@@ -1545,9 +1601,12 @@ class InferenceEngine:
         return win
 
     def _obs_window(self, win: _Window, t_host0: float) -> None:
-        """One flight record + per-traced-request window spans at host
-        processing time. ``wait_s`` (dispatch → fan-out start) includes
-        the deliberate one-window overlap; ``host_s`` is the fan-out."""
+        """One flight record at host processing time, and for each traced
+        request the window's share of its ONE ``engine.decode`` span,
+        recorded once the request has retired (per-window detail lives in
+        the flight record and the ``engine.window.*`` phases). ``wait_s``
+        (dispatch → fan-out start) includes the deliberate one-window
+        overlap; ``host_s`` is the fan-out."""
         now_m = time.monotonic()
         self.metrics.observe("tpu9_engine_decode_window_s",
                              max(t_host0 - win.t_mono, 0.0))
@@ -1590,21 +1649,51 @@ class InferenceEngine:
                         prefix_pinned=self.prefix_cache.pinned)
                     self._flight_evictions = ev
             self.flight.record(win.kind, **rec)
-        for slot, n_tok in delivered.items():
-            req = win.reqs[slot]
-            if (n_tok > 0 and req is not None and req.trace is not None
-                    and req.span_id):
+        for slot, req in enumerate(win.reqs):
+            if (req is None or req.trace is None or not req.span_id
+                    or not win.mask[slot]):
+                continue
+            n_tok = delivered.get(slot, 0)
+            if n_tok > 0:
+                if req.dec is None:
+                    req.dec_anchor = (win.t_wall, win.t_mono)
+                    req.dec = {"request_id": req.request_id, "windows": 0,
+                               "k1_windows": 0, "tokens": 0,
+                               "interleaved_windows": 0}
+                req.dec["windows"] += 1
+                req.dec["k1_windows"] += win.k == 1
+                req.dec["tokens"] += n_tok
+                req.dec["interleaved_windows"] += win.pick == "interleave"
+            if req.dec is not None and req.done.is_set():
+                # retired inside this window's fan-out (or, cancelled,
+                # before it): its decode interval is complete
+                attrs, req.dec = req.dec, None
                 tracer.record_span(
-                    "engine.decode_window", req.trace[0], req.span_id,
-                    win.t_wall, win.t_mono,
-                    attrs={"kind": win.kind, "k": win.k, "tokens": n_tok,
-                           "pick": win.pick})
+                    "engine.decode", req.trace[0], req.span_id,
+                    *req.dec_anchor, end_mono=req.t_done_mono or now_m,
+                    attrs=attrs)
 
     def _obs_first_token(self, req: _Request) -> None:
+        """TTFT, and its last part: the hold between the end of a request's
+        admission and the delivery of its first token (the first tokens of
+        a batch of admissions sync together after the last)."""
         req.t_first_mono = time.monotonic()
         self.metrics.observe(
             "tpu9_engine_ttft_s",
             max(req.t_first_mono - req.t_enqueue_mono, 0.0))
+        if req.t_admit_end_mono:
+            self.metrics.observe(
+                "tpu9_engine_first_hold_s",
+                max(req.t_first_mono - req.t_admit_end_mono, 0.0))
+
+    def note_first_write(self, req: _Request) -> None:
+        """Stream lag (ISSUE 24), fed by the runner: from the first token's
+        queue put to the handler having written it to the client — the
+        wait for the event loop the serve loop shares."""
+        if req.t_first_mono:
+            self.metrics.observe(
+                "tpu9_engine_stream_lag_s",
+                max(time.monotonic() - req.t_first_mono, 0.0))
 
     def _obs_done(self, req: _Request) -> None:
         """Idempotent: reachable from both _retire (slot completion) and
@@ -1612,6 +1701,7 @@ class InferenceEngine:
         now = time.monotonic()
         n = len(req.generated)
         if req.t_enqueue_mono:
+            req.t_done_mono = now
             self.metrics.observe("tpu9_engine_e2e_s",
                                  max(now - req.t_enqueue_mono, 0.0))
             if req.t_first_mono and n > 1:
@@ -1626,65 +1716,72 @@ class InferenceEngine:
 
     # -- on-demand profiling (ISSUE 8) ---------------------------------------
 
-    def arm_profile(self, windows: int = 8, out_dir: str = "") -> dict:
-        """Arm ``jax.profiler`` for the next ``windows`` dispatched
-        windows. Returns the dump path immediately; the trace starts at
-        the next window boundary and stops once the armed windows have
-        drained — a live replica gets profiled without a restart or a
-        single out-of-band device sync."""
-        if windows <= 0:
-            raise ValueError(f"windows must be positive, got {windows}")
-        if self._profile_active or self._profile_remaining > 0:
-            return {"path": self._profile_path,
-                    "windows": self._profile_remaining,
-                    "already_armed": True}
+    def arm_profile(self, seconds: float = 4.0, out_dir: str = "") -> dict:
+        """Trace the next ``seconds`` seconds with ``jax.profiler``, from a
+        worker thread: returns the dump path at once and never holds the
+        serve loop (writing out a trace of four chips takes minutes). The
+        options are the benchmark's, so the operator's dump is the trace
+        its readers take: device planes, and on the host plane the serve
+        loop's phases. ``device_scopes.json`` beside it maps each
+        program's HLO instructions to the model's scopes."""
+        if not seconds > 0:
+            raise ValueError(f"seconds must be positive, got {seconds}")
+        if self._profile["active"]:
+            return {**self._profile, "already_armed": True}
         import tempfile
-        self._profile_path = out_dir or tempfile.mkdtemp(
-            prefix="tpu9-profile-")
-        self._profile_remaining = windows
-        self._profile_error = ""
+        path = out_dir or tempfile.mkdtemp(prefix="tpu9-profile-")
+        self._profile = {"active": True, "path": path,
+                         "seconds": float(seconds), "error": ""}
+        self._profile_cut.clear()
         if self.flight is not None:
-            self.flight.record("profile", event="armed",
-                               windows=windows, path=self._profile_path)
-        return {"path": self._profile_path, "windows": windows}
-
-    def _profile_window_start(self) -> None:
-        if self._profile_remaining <= 0 or self._profile_active:
-            return
+            self.flight.record("profile", event="armed", seconds=seconds,
+                               path=path)
         try:
-            jax.profiler.start_trace(self._profile_path)
-            self._profile_active = True
-        except Exception as exc:    # noqa: BLE001 — profiling must never
-            # take the serve loop down; surface the failure in stats()
-            self._profile_error = f"{type(exc).__name__}: {exc}"
-            self._profile_remaining = 0
+            loop = asyncio.get_running_loop()
+        except RuntimeError:
+            loop = None
+        self._profile_thread = threading.Thread(
+            target=self._profile_run, args=(path, float(seconds), loop),
+            name="tpu9-profile", daemon=True)
+        self._profile_thread.start()
+        return {"path": path, "seconds": float(seconds)}
 
-    def _profile_window_dispatched(self) -> None:
-        if self._profile_active and self._profile_remaining > 0:
-            self._profile_remaining -= 1
-
-    def _profile_maybe_stop(self, idle: bool = False) -> None:
-        """Stop once every armed window has been host-processed (device
-        work complete), so the dump covers the whole window set.
-        ``idle=True`` (the serve loop about to park) stops EARLY even
-        with armed windows left: traffic dried up before the armed count,
-        and a partial dump beats tracing hours of parked silence — which
-        would also leave ``arm_profile`` reporting already_armed forever."""
-        if not self._profile_active or self._deferred_windows:
-            return
-        if self._profile_remaining > 0 and not idle:
-            return
-        left, self._profile_remaining = self._profile_remaining, 0
+    def _profile_run(self, path: str, seconds: float, loop) -> None:
+        """The profile thread's body. Profiling must never take the serve
+        loop down: a failure lands in ``stats()["profile"]["error"]``."""
+        error, t0 = "", time.monotonic()
         try:
-            jax.profiler.stop_trace()
-        except Exception as exc:  # noqa: BLE001 — see start
-            self._profile_error = f"{type(exc).__name__}: {exc}"
-        self._profile_active = False
-        if self.flight is not None:
-            self.flight.record("profile", event="stopped",
-                               path=self._profile_path,
-                               windows_left=left,
-                               error=self._profile_error)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0   # millions of events otherwise
+            options.host_tracer_level = 1     # TraceAnnotation: the phases
+            jax.profiler.start_trace(path, profiler_options=options)
+            try:
+                self._profile_cut.wait(seconds)
+                traced = time.monotonic() - t0
+            finally:
+                jax.profiler.stop_trace()
+            if self.graphs.device_scopes:
+                import json
+                import os
+                with open(os.path.join(path, "device_scopes.json"),
+                          "w") as f:
+                    json.dump(self.graphs.device_scopes, f)
+        except Exception as exc:    # noqa: BLE001 — see the docstring
+            error, traced = f"{type(exc).__name__}: {exc}", 0.0
+
+        def done():
+            self._profile = {**self._profile, "active": False,
+                             "error": error}
+            if self.flight is not None:
+                self.flight.record(
+                    "profile", event="stopped", path=path,
+                    traced_s=round(traced, 3), error=error,
+                    dump_s=round(time.monotonic() - t0 - traced, 3))
+
+        try:    # on the loop that armed it, if that loop still runs
+            loop.call_soon_threadsafe(done)
+        except (AttributeError, RuntimeError):
+            done()
 
     def _occupy_slot(self, req: _Request, slot: int) -> None:
         req.slot = slot
@@ -1694,12 +1791,13 @@ class InferenceEngine:
             from .spec import make_slot_state
             self._spec_slots[slot] = make_slot_state(req.prompt)
 
-    def _interleave_decode_window(self) -> None:
+    def _interleave_decode_window(self) -> int:
         """Dispatch one decode window for the active batch WITHOUT syncing
         (results processed after the admission sync). Room accounting must
-        include steps already in flight from earlier interleaved windows."""
+        include steps already in flight from earlier interleaved windows.
+        Returns the window's steps (0: none dispatched)."""
         if not self.active.any():
-            return
+            return 0
         ks = self.ecfg.decode_steps
         want = ks[1] if len(ks) > 1 else ks[0]
         # total in-flight overshoot must stay within the max(decode_steps)
@@ -1725,7 +1823,7 @@ class InferenceEngine:
             if cand <= limit:
                 k = max(k, cand)
         if k <= 0:
-            return              # out of cache room or reservation slack
+            return 0            # out of cache room or reservation slack
         for slot in range(self.ecfg.max_batch):
             if self.active[slot]:
                 self._ensure_slot_blocks(
@@ -1743,6 +1841,7 @@ class InferenceEngine:
         self._inflight_steps += k
         self._stats["decode_steps"] += k
         self._stats["admit_interleaved_windows"] += 1
+        return k
 
     async def _admit(self, req: _Request, slot: int):
         """Prefill + cache splice for one request. Returns the slot's
@@ -1750,13 +1849,18 @@ class InferenceEngine:
         batch in one host round-trip (each blocking ``int()`` here would
         cost a full one)."""
         t0_mono, t0_wall = time.monotonic(), time.time()
-        self._obs_admit_start(req, t0_mono, t0_wall)
-        il0 = self._stats["admit_interleaved_windows"]
-        if self.paged:
-            first = await self._admit_paged(req, slot)
-        else:
-            first = self._admit_dense(req, slot)
-        self._obs_admit_end(req, t0_mono, t0_wall, il0)
+        with phase("engine.admit", self.host_phases,
+                   request_id=req.trace[0] if req.trace else req.request_id,
+                   prompt_tokens=len(req.prompt)) as ph:
+            self._obs_admit_start(req, t0_mono, t0_wall)
+            il0 = self._stats["admit_interleaved_windows"]
+            if self.paged:
+                first = await self._admit_paged(req, slot)
+            else:
+                with phase("engine.admit.dispatch", self.host_phases):
+                    first = self._admit_dense(req, slot)
+            self._obs_admit_end(req, t0_mono, t0_wall, il0)
+            ph.set(cached_tokens=req.admit_cached, chunks=req.admit_chunks)
         return first
 
     def _admit_dense(self, req: _Request, slot: int):
@@ -1904,9 +2008,8 @@ class InferenceEngine:
             raise
 
     async def _serve_loop_inner(self) -> None:
+        totals = self.host_phases
         while True:
-            # armed profile done? stop once every profiled window drained
-            self._profile_maybe_stop()
             # admit as many queued requests as there are free slots; ALL
             # their first tokens sync in one device round-trip at the end.
             # An imminent admission first drains the steady-state overlap
@@ -1941,14 +2044,11 @@ class InferenceEngine:
                     # a zombie overlap window (its slots all retired during
                     # the previous iteration's drain, with this successor
                     # already in flight): process it BEFORE parking, or its
-                    # device work goes unaccounted and the armed profiler
-                    # below can never observe an empty flight
+                    # device work goes unaccounted
                     self._drain_windows()
-                # an armed profile must stop NOW — even mid-arm-count —
-                # parked-idle time must not leak into the dump
-                self._profile_maybe_stop(idle=True)
                 # idle: block for work
-                req = await self._queue.get()
+                with phase("engine.park", totals):
+                    req = await self._queue.get()
                 if req.cancelled:
                     self._finish(req)
                     continue
@@ -1963,11 +2063,13 @@ class InferenceEngine:
                 self._admitting = None
 
             if pending:
-                # tpu9: noqa[JAX001] intended sync point: ONE batched read of all admitted prefill first-tokens (TTFT requires delivering them now)
-                firsts = np.asarray(jax.device_get(
-                    jnp.stack([f for _, f in pending])))
-                for (req, _), first in zip(pending, firsts):
-                    self._deliver_first(req, int(first))
+                with phase("engine.first_sync", totals, n=len(pending)):
+                    # tpu9: noqa[JAX001] intended sync point: ONE batched read of all admitted prefill first-tokens (TTFT requires delivering them now)
+                    firsts = np.asarray(jax.device_get(
+                        jnp.stack([f for _, f in pending])))
+                with phase("engine.deliver_first", totals):
+                    for (req, _), first in zip(pending, firsts):
+                        self._deliver_first(req, int(first))
                 # windows dispatched during those admissions: their tokens
                 # are ready by now (device work ordered before firsts) —
                 # drain them in one transfer
@@ -1983,13 +2085,16 @@ class InferenceEngine:
             # window boundary: down-page LRU prefixes to host DRAM when
             # the pool nears eviction pressure (ISSUE 20; no-op untiered)
             if self.paged and self.pool.tiered:
-                self._kvtier_tick()
+                with phase("engine.kvtier_tick", totals):
+                    self._kvtier_tick()
             # one WINDOW for the whole batch — speculative verify when the
             # acceptance EWMAs justify it, classic k-step decode otherwise
-            self._profile_window_start()
-            win = self._dispatch_window()
+            with phase("engine.window.dispatch", totals) as ph:
+                win = self._dispatch_window()
+                if win is not None:
+                    ph.set(kind=win.kind, k=win.k, pick=win.pick,
+                           batch=int(win.mask.sum()))
             if win is not None:
-                self._profile_window_dispatched()
                 self._deferred_windows.append(win)
                 # steady-state overlap (ISSUE 5 satellite): keep exactly
                 # ONE window in flight — the host fan-out of every older
@@ -1998,7 +2103,8 @@ class InferenceEngine:
                 while len(self._deferred_windows) > 1:
                     self._process_deferred(self._deferred_windows.pop(0))
             # yield to the event loop so new requests can land
-            await asyncio.sleep(0)
+            with phase("engine.yield", totals):
+                await asyncio.sleep(0)
 
     # -- window dispatch / processing ---------------------------------------
 
@@ -2072,10 +2178,12 @@ class InferenceEngine:
         wins, self._deferred_windows = self._deferred_windows, []
         if not wins:
             return
-        # tpu9: noqa[JAX001] intended sync point: the ONE batched window-boundary device_get (PR 5); N sequential reads would pay N round-trips
-        payload = jax.device_get(
-            [(w.toks,) if w.n_acc is None else (w.toks, w.n_acc)
-             for w in wins])
+        with phase("engine.window.sync", self.host_phases,
+                   windows=len(wins)):
+            # tpu9: noqa[JAX001] intended sync point: the ONE batched window-boundary device_get (PR 5); N sequential reads would pay N round-trips
+            payload = jax.device_get(
+                [(w.toks,) if w.n_acc is None else (w.toks, w.n_acc)
+                 for w in wins])
         for w, arrs in zip(wins, payload):
             self._inflight_steps -= w.k
             self._process_window_host(
@@ -2083,12 +2191,13 @@ class InferenceEngine:
                 np.asarray(arrs[1]) if len(arrs) > 1 else None)  # tpu9: noqa[JAX001] host memory, no device sync
 
     def _process_deferred(self, win: _Window) -> None:
-        if win.n_acc is None:
-            # tpu9: noqa[JAX001] intended sync point: the window's compute is DONE (one-window-overlap drains here); this read is the host fan-out
-            toks, n_acc = jax.device_get(win.toks), None
-        else:
-            toks, n_acc = jax.device_get((win.toks, win.n_acc))  # tpu9: noqa[JAX001] intended sync point: batched toks+n_acc read at the window boundary
-            n_acc = np.asarray(n_acc)  # tpu9: noqa[JAX001] host memory after device_get, no sync
+        with phase("engine.window.sync", self.host_phases, windows=1):
+            if win.n_acc is None:
+                # tpu9: noqa[JAX001] intended sync point: the window's compute is DONE (one-window-overlap drains here); this read is the host fan-out
+                toks, n_acc = jax.device_get(win.toks), None
+            else:
+                toks, n_acc = jax.device_get((win.toks, win.n_acc))  # tpu9: noqa[JAX001] intended sync point: batched toks+n_acc read at the window boundary
+                n_acc = np.asarray(n_acc)  # tpu9: noqa[JAX001] host memory after device_get, no sync
         self._inflight_steps -= win.k
         self._process_window_host(win, np.asarray(toks), n_acc)  # tpu9: noqa[JAX001] host memory after device_get, no sync
 
@@ -2129,13 +2238,15 @@ class InferenceEngine:
         carry [k, B] (every step, every slot); verify windows carry the
         model outputs [B, 1+s] plus per-slot accepted-draft counts —
         tokens-per-slot-per-window is VARIABLE (1..1+s)."""
-        t_host0 = time.monotonic()
-        win.delivered = {}
-        if win.kind == "verify":
-            self._process_verify_host(win, window, n_acc)
-        else:
-            self._process_decode_host(win, window)
-        self._obs_window(win, t_host0)
+        with phase("engine.window.fanout", self.host_phases) as ph:
+            t_host0 = time.monotonic()
+            win.delivered = {}
+            if win.kind == "verify":
+                self._process_verify_host(win, window, n_acc)
+            else:
+                self._process_decode_host(win, window)
+            self._obs_window(win, t_host0)
+            ph.set(tokens=sum(win.delivered.values()))
 
     def _process_decode_host(self, win: _Window, window) -> None:
         shadow: dict[int, list[int]] = {}

@@ -23,18 +23,95 @@ the same physical indices as the payload (``traced_splice``).
 from __future__ import annotations
 
 import logging
+import re
 import time
 from typing import Any, Iterator
 
 import jax
 import jax.numpy as jnp
 
-from ..models.transformer import decoder_forward, init_kv_cache
+from ..models.transformer import (DEVICE_SCOPES, decoder_forward,
+                                  init_kv_cache)
 from ..ops.sampling import sample_logits
 
 Params = dict[str, Any]
 
 log = logging.getLogger("tpu9.serving")
+
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_FUSED = re.compile(r"\bcalls=%?([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# an operand: a %name not behind "=" (calls=%body, to_apply=%add, ...)
+_HLO_OPERAND = re.compile(r"(?<![=\w])%([\w.\-]+)")
+# instructions that never run as an operation of their own on the device
+# (a ``-start`` is on a trace's line of asynchronous operations, its
+# ``-done`` among the operations)
+_HLO_NO_OP = re.compile(
+    r" (parameter|get-tuple-element|tuple|constant|bitcast|[\w\-]+-start)\(")
+
+
+def hlo_scopes(text: str, scopes=DEVICE_SCOPES) -> dict:
+    """``{scope: [instruction names]}`` of one compiled program's HLO text:
+    each instruction that runs on its own (the body of a fusion does not)
+    under the innermost ``jax.named_scope`` of ``scopes`` on its
+    ``op_name`` path. An instruction the compiler made carries no
+    ``op_name``: a fusion takes its body's root's, anything else (the
+    ``copy-done`` of an asynchronous copy, the ``slice-done`` of a weight
+    prefetch) its first operand's, else that of the first instruction it
+    feeds. A profiler trace names device operations by these names."""
+    known = set(scopes)
+
+    def innermost(path: str) -> str:
+        return next((part for part in reversed(path.split("/"))
+                     if part in known), "")
+
+    rows: list = []             # (computation, name, runs on its own)
+    scope_of: dict = {}         # instruction -> its scope, own or taken
+    first_user: dict = {}       # instruction -> the first one it feeds
+    made: set = set()           # instructions with no op_name at all
+    body_scope: dict = {}       # computation -> scope of its root
+    computation = ""
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            m = _HLO_COMPUTATION.match(line)
+            computation = m.group(1) if m else ""
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        name = m.group(1)
+        operands = _HLO_OPERAND.findall(line, m.end())
+        for operand in operands:
+            first_user.setdefault(operand, name)
+        found = _HLO_OP_NAME.search(line)
+        body = _HLO_FUSED.search(line) if " fusion(" in line else None
+        if found:               # the program's own: a scope, or none
+            scope = innermost(found.group(1))
+            if scope:
+                body_scope[computation] = scope
+        else:                   # the compiler's: taken from a neighbour
+            made.add(name)
+            scope = (body_scope.get(body.group(1), "") if body else "") \
+                or (scope_of.get(operands[0], "") if operands else "")
+        scope_of[name] = scope
+        rows.append((computation, name, not _HLO_NO_OP.search(line),
+                     body.group(1) if body else ""))
+    fused = {body for _, _, _, body in rows if body}
+    out: dict = {}
+    for computation, name, alone, _ in rows:
+        if computation in fused or not alone:
+            continue
+        scope, user = scope_of[name], name
+        for _ in range(4):      # copy-start -> copy-done -> what it feeds
+            if scope or name not in made or user not in first_user:
+                break
+            user = first_user[user]
+            scope = scope_of.get(user, "")
+        if scope:
+            out.setdefault(scope, []).append(name)
+    return out
 
 
 class GraphFactory:
@@ -68,6 +145,11 @@ class GraphFactory:
         # (:meth:`precompile`): the evidence that a step really contains
         # the pallas kernels rather than having taken the XLA oracle
         self.kernel_calls: dict[str, int] = {}
+        # graph name -> {scope: [HLO instruction names]} of its
+        # AOT-compiled text (:func:`hlo_scopes`); a graph whose executable
+        # names no scope (the persistent cache served one compiled before
+        # the scopes existed: its key ignores them) is left out
+        self.device_scopes: dict[str, dict] = {}
 
     def _build(self, key, builder):
         """Cache-or-build a graph under ``key`` — the ONE miss path, so
@@ -216,6 +298,7 @@ class GraphFactory:
         policy = self.policy
 
         def build():
+            @jax.named_scope("kv.splice")
             def splice(k, v, ck, cv, slot):
                 k = jax.lax.dynamic_update_slice(
                     k, ck[:, :, :bucket], (0, slot, 0, 0, 0))
@@ -253,20 +336,21 @@ class GraphFactory:
         scales land in the scale planes at the same physical index)."""
         bs = self.ecfg.kv_block_size
         pool = dict(pool)
-        for j in range(self.chunk // bs):
-            blk_k = jax.lax.dynamic_slice_in_dim(
-                scratch_k[:, 0], offset + j * bs, bs, axis=1)
-            blk_v = jax.lax.dynamic_slice_in_dim(
-                scratch_v[:, 0], offset + j * bs, bs, axis=1)
-            if "k_scale" in pool:
-                from ..ops.quant import quantize_kv
-                blk_k, sk = quantize_kv(blk_k)   # [L,bs,KH,D], [L,bs,KH]
-                blk_v, sv = quantize_kv(blk_v)
-                pool["k_scale"] = pool["k_scale"].at[:, phys[j]].set(sk)
-                pool["v_scale"] = pool["v_scale"].at[:, phys[j]].set(sv)
-            pool["k"] = pool["k"].at[:, phys[j]].set(blk_k)
-            pool["v"] = pool["v"].at[:, phys[j]].set(blk_v)
-        return self.policy.constrain_kv(pool)
+        with jax.named_scope("kv.splice"):
+            for j in range(self.chunk // bs):
+                blk_k = jax.lax.dynamic_slice_in_dim(
+                    scratch_k[:, 0], offset + j * bs, bs, axis=1)
+                blk_v = jax.lax.dynamic_slice_in_dim(
+                    scratch_v[:, 0], offset + j * bs, bs, axis=1)
+                if "k_scale" in pool:
+                    from ..ops.quant import quantize_kv
+                    blk_k, sk = quantize_kv(blk_k)  # [L,bs,KH,D], [L,bs,KH]
+                    blk_v, sv = quantize_kv(blk_v)
+                    pool["k_scale"] = pool["k_scale"].at[:, phys[j]].set(sk)
+                    pool["v_scale"] = pool["v_scale"].at[:, phys[j]].set(sv)
+                pool["k"] = pool["k"].at[:, phys[j]].set(blk_k)
+                pool["v"] = pool["v"].at[:, phys[j]].set(blk_v)
+            return self.policy.constrain_kv(pool)
 
     def chunk_fn(self):
         """Jitted chunked-prefill step: write one C-token chunk into the
@@ -298,6 +382,7 @@ class GraphFactory:
         policy = self.policy
 
         def build():
+            @jax.named_scope("kv.gather")
             def gather(pool, row):
                 # pool [L, N, BS, KH, D], row [MB] → dense [L, 1, S, KH,
                 # D]. The row's final column is the ALWAYS-TRASH block —
@@ -486,8 +571,18 @@ class GraphFactory:
                 if isinstance(key, tuple) else str(key)
             timings[f"compile_{name}_s"] = \
                 round(time.perf_counter() - t0, 4)
-            self.kernel_calls[name] = \
-                self.compiled[key].as_text().count("tpu_custom_call")
+            text = self.compiled[key].as_text()
+            self.kernel_calls[name] = text.count("tpu_custom_call")
+            scopes = hlo_scopes(text)
+            if scopes:
+                self.device_scopes[name] = scopes
+            else:
+                log.warning(
+                    "graph %s: its executable names none of the model's "
+                    "scopes — the compile cache served one built before "
+                    "they existed (its key ignores op names); a trace of "
+                    "it cannot be read by scope until the entry is "
+                    "removed from the cache directory", name)
         self.seal()
         return timings
 
