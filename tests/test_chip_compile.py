@@ -7,6 +7,7 @@ A compile that passes is not a chip run; ``chip_smoke.py`` is.
 """
 
 import os
+import re
 import subprocess
 import sys
 
@@ -59,13 +60,15 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-def _paged_args(d, sharding, quant=False):
-    """(q, k_pool, v_pool[, k_scale, v_scale], table, lens) shapes."""
+def _paged_args(d, sharding, quant=False, layers=()):
+    """(q, k_pool, v_pool[, k_scale, v_scale], table, lens) shapes;
+    ``layers=(L,)`` stacks the pool as the engine holds it."""
     def s(shape, dt, sh=sharding):
         return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
     pool_dt = jnp.int8 if quant else jnp.bfloat16
-    pool = s((N_BLOCKS, BS, KH, d), pool_dt)
-    scales = [s((N_BLOCKS, BS, KH), jnp.float32)] * 2 if quant else []
+    pool = s((*layers, N_BLOCKS, BS, KH, d), pool_dt)
+    scales = [s((*layers, N_BLOCKS, BS, KH), jnp.float32)] * 2 \
+        if quant else []
     return [s((B, 1, QH, d), jnp.bfloat16), pool, pool, *scales,
             s((B, MB), jnp.int32), s((B,), jnp.int32)]
 
@@ -99,6 +102,9 @@ def _kernel_case(name, d):
     # mesh with pool and q sharded on the head axis (refused before PR 21:
     # "Mosaic kernels cannot be automatically partitioned")
     ("paged_on_mesh", 128), ("paged_int8_on_mesh", 128),
+    # the same over the whole stacked pool (ISSUE 25), at a layer that is
+    # not the first: what a decode step of the engine dispatches
+    ("paged_pool_on_mesh", 128), ("paged_int8_pool_on_mesh", 128),
 ])
 def test_kernel_compiles_for_a_described_v5e(v5e, no_compile_cache,
                                              monkeypatch, name, head_dim):
@@ -107,22 +113,25 @@ def test_kernel_compiles_for_a_described_v5e(v5e, no_compile_cache,
         monkeypatch.setattr(attention_ops, "on_tpu", lambda: True)
         mesh = Mesh(np.array(v5e).reshape(1, 1, 1, 4),
                     ("dp", "fsdp", "sp", "tp"))
-        heads4 = NamedSharding(mesh, P(None, None, "tp", None))
-        heads3 = NamedSharding(mesh, P(None, None, "tp"))
-        rep = NamedSharding(mesh, P())
-        args = _paged_args(head_dim, None, quant="int8" in name)
-        args = [jax.ShapeDtypeStruct(
-            a.shape, a.dtype,
-            sharding={4: heads4, 3: heads3}.get(len(a.shape), rep))
-            for a in args]
-        if "int8" in name:
+        quant = "int8" in name
+        layers, layer, pool, scales = ((3,), 2, attention_ops._POOL5,
+                                       attention_ops._POOL4) \
+            if "_pool_" in name else ((), 0, attention_ops._HEADS4,
+                                      attention_ops._HEADS3)
+        specs = [attention_ops._HEADS4, pool, pool,
+                 *([scales] * 2 * quant), P(), P()]
+        args = [jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                     sharding=NamedSharding(mesh, spec))
+                for a, spec in zip(
+                    _paged_args(head_dim, None, quant, layers), specs)]
+        if quant:
             def fn(q, k, v, ks, vs, table, lens):
                 return paged_attention_dispatch(q, k, v, table, lens, ks,
-                                                vs, mesh=mesh)
+                                                vs, mesh=mesh, layer=layer)
         else:
             def fn(q, k, v, table, lens):
                 return paged_attention_dispatch(q, k, v, table, lens,
-                                                mesh=mesh)
+                                                mesh=mesh, layer=layer)
     else:
         from jax.sharding import SingleDeviceSharding
         fn, build = _kernel_case(name, head_dim)
@@ -132,7 +141,7 @@ def test_kernel_compiles_for_a_described_v5e(v5e, no_compile_cache,
     if name.endswith("_on_mesh"):
         # each chip holds a quarter of the k and v pools, and nothing
         # gathers them
-        pools = 2 * N_BLOCKS * BS * KH * head_dim * args[1].dtype.itemsize
+        pools = 2 * args[1].size * args[1].dtype.itemsize
         assert compiled.memory_analysis().argument_size_in_bytes \
             < 0.3 * pools
         assert "all-gather" not in compiled.as_text()
@@ -143,29 +152,139 @@ def test_kernel_compiles_for_a_described_v5e(v5e, no_compile_cache,
                     q, k, v, t, n)).lower(*args).compile()
 
 
+def _pool_shaped(text: str, pool: tuple) -> list:
+    """Instructions of an optimised HLO text whose result is the pool or
+    one layer's plane of it (with or without a leading 1), other than what
+    carries the pool through the program (parameters, tuple plumbing) and
+    the in-place scatter of the token write."""
+    shapes = {",".join(map(str, dims))
+              for dims in (pool, pool[1:], (1, *pool[1:]))}
+    result = re.compile(r"^\s+(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]\S* "
+                        r"([\w\-]+)\(")
+    scatter_bodies, computation = set(), ""
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            computation = line.split(" ", 1)[0].lstrip("%")
+        elif " ROOT " in f" {line.lstrip()}" and " scatter(" in line:
+            scatter_bodies.add(computation)
+    found = []
+    for line in text.splitlines():
+        m = result.match(line)
+        if not m or m.group(1) not in shapes:
+            continue
+        op = m.group(2)
+        calls = re.search(r"calls=%?([\w.\-]+)", line)
+        if op in ("parameter", "get-tuple-element", "scatter") or (
+                op == "fusion" and calls
+                and calls.group(1) in scatter_bodies):
+            continue
+        found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("configuration", ["mixtral-8x7b-l4",
+                                           "mistral-7b-v0.3-tp4"])
+def test_decode_programs_carry_the_pool_whole_on_a_described_v5e(
+        v5e, no_compile_cache, monkeypatch, configuration):
+    """The K = 1 and K = 8 decode programs at a benchmark configuration's
+    engine shapes (abstract arguments, no weights; four chips for the
+    tensor-parallel one): the compiler holds no second copy of the pool,
+    no plane of it, and gathers none of it across chips."""
+    from benchmark import manifest, serve
+    from tpu9.serving.graphs import GraphFactory, abstract_state
+    from tpu9.serving.presets import abstract_params_for
+    from tpu9.serving.shard.plan import parse_topology
+    from tpu9.serving.shard.policy import MeshPolicy
+    monkeypatch.setattr(attention_ops, "on_tpu", lambda: True)
+    config = manifest.load_config(manifest.load(), configuration)
+    cfg = serve.decoder_config(serve.model_sizes(config))
+    ecfg = serve.engine_config(config["engine"])
+    topology = parse_topology(config["engine"]["topology"])
+    policy = MeshPolicy(topology, devices=v5e[:topology.n_chips])
+    graphs = GraphFactory(cfg, ecfg, policy, chunk=ecfg.prefill_chunk)
+    st = abstract_state(cfg, ecfg, policy)
+    k_pool = st["kv_cache"]["k"]
+    per_chip = (*k_pool.shape[:3], k_pool.shape[3] // topology.n_chips,
+                k_pool.shape[4])
+    pool_bytes = 2 * int(np.prod(per_chip)) * k_pool.dtype.itemsize
+    programs = [job for job in graphs.lowering_jobs(
+        abstract_params_for(cfg, False), st["kv_cache"], st["pool"],
+        st["scratch"], st["mb"], [ecfg.prefill_chunk], (), st["rng"])
+        if job[0][0] == "decode"]
+    assert [key for key, _, _ in programs] == [("decode", 1), ("decode", 8)]
+    for key, fn, args in programs:
+        compiled = fn.lower(*args).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == cfg.n_layers, key
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < pool_bytes / 4, key
+        assert _pool_shaped(text, per_chip) == [], key
+        # the sampler gathers a few scalars per slot; nothing of the pool
+        gathers = [ln for ln in text.splitlines() if " all-gather(" in ln]
+        assert not [ln for ln in gathers if re.search(
+            rf"\[[\d,]*{BS},{per_chip[3]},{per_chip[4]}\]", ln)], key
+
+
+def test_pool_shaped_finds_what_the_old_program_did():
+    """The reader above, on hand-made text: a plane cut out, planes stacked
+    back and the compiler's own pool-shaped copy are found; parameters, the
+    loop's plumbing and the in-place scatter are not."""
+    text = '''HloModule jit_decode
+%fused_scatter (p0: bf16[4,9,128,8,128], p1: s32[2], p2: bf16[2,8,128]) -> bf16[4,9,128,8,128] {
+  %p0 = bf16[4,9,128,8,128]{4,3,2,1,0} parameter(0)
+  ROOT %scatter.1 = bf16[4,9,128,8,128]{4,3,2,1,0} scatter(%p0, %p1, %p2), to_apply=%region
+}
+%fused_slice (p0.1: bf16[4,9,128,8,128]) -> bf16[9,128,8,128] {
+  %p0.1 = bf16[4,9,128,8,128]{4,3,2,1,0} parameter(0)
+  ROOT %bitcast.2 = bf16[9,128,8,128]{3,2,1,0} bitcast(%slice.1)
+}
+ENTRY %main (k: bf16[4,9,128,8,128]) -> bf16[4,9,128,8,128] {
+  %k = bf16[4,9,128,8,128]{4,3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %gte = bf16[4,9,128,8,128]{4,3,2,1,0} get-tuple-element(%while), index=2
+  %fusion.4 = bf16[4,9,128,8,128]{4,3,2,1,0} fusion(%k, %i, %u), kind=kCustom, calls=%fused_scatter
+  %slice_bitcast_fusion = bf16[9,128,8,128]{3,2,1,0} fusion(%k), kind=kLoop, calls=%fused_slice
+  %pad_maximum_fusion = bf16[4,9,128,8,128]{4,3,2,1,0} fusion(%a, %b), kind=kLoop, calls=%fused_pad
+  %copy-done.3 = bf16[1,9,128,8,128]{4,3,2,1,0} copy-done(%copy-start.3)
+  %fusion.9 = bf16[2,8,4,128]{3,2,1,0} fusion(%q), kind=kLoop, calls=%fused_q
+}
+'''
+    found = _pool_shaped(text, (4, 9, 128, 8, 128))
+    assert [ln.split(" ", 1)[0] for ln in found] == [
+        "ROOT", "%slice_bitcast_fusion", "%pad_maximum_fusion",
+        "%copy-done.3"]
+
+
 @pytest.mark.multichip
-def test_paged_kernel_under_shard_map_matches_the_oracle():
+@pytest.mark.parametrize("layers,layer", [((), 0), ((3,), 2)],
+                         ids=["plane", "pool_layer_2"])
+def test_paged_kernel_under_shard_map_matches_the_oracle(layers, layer):
     """The same shard_map wrapping, RUN: four virtual CPU devices, the
-    kernel interpreted, against the XLA oracle on unsharded inputs."""
+    kernel interpreted, against the XLA oracle on unsharded inputs — over
+    one layer's plane, and over the stacked pool at a layer that is not
+    the first."""
     import functools
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(1, 1, 1, 4),
                 ("dp", "fsdp", "sp", "tp"))
     b, qh, kh, d, bs, mb = 2, 8, 4, 32, 16, 4
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (b, 1, qh, d), jnp.float32)
-    kp = jax.random.normal(ks[1], (b * mb + 1, bs, kh, d), jnp.float32)
-    vp = jax.random.normal(ks[2], (b * mb + 1, bs, kh, d), jnp.float32)
+    kp = jax.random.normal(ks[1], (*layers, b * mb + 1, bs, kh, d),
+                           jnp.float32)
+    vp = jax.random.normal(ks[2], (*layers, b * mb + 1, bs, kh, d),
+                           jnp.float32)
     table = (jnp.arange(b * mb, dtype=jnp.int32) + 1).reshape(b, mb)
     lens = jnp.asarray([bs * mb, bs + 3], jnp.int32)
     heads = NamedSharding(mesh, P(None, None, "tp", None))
+    pool_spec = attention_ops._POOL5 if layers else attention_ops._HEADS4
+    pool_heads = NamedSharding(mesh, pool_spec)
     sharded = attention_ops._per_chip_heads(
         functools.partial(paged_decode_attention, interpret=True), mesh,
-        (attention_ops._HEADS4, attention_ops._HEADS4,
-         attention_ops._HEADS4, P(), P()))
+        (attention_ops._HEADS4, pool_spec, pool_spec, P(), P(), P()))
     got = jax.jit(sharded)(jax.device_put(q, heads),
-                           jax.device_put(kp, heads),
-                           jax.device_put(vp, heads), table, lens)
-    want = xla_paged_decode_attention(q, kp, vp, table, lens)
+                           jax.device_put(kp, pool_heads),
+                           jax.device_put(vp, pool_heads), table, lens,
+                           jnp.int32(layer))
+    want = xla_paged_decode_attention(q, kp, vp, table, lens, layer=layer)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
     assert got.sharding.spec == P(None, None, "tp", None)

@@ -133,29 +133,46 @@ def _act(x: jnp.ndarray, kind: str) -> jnp.ndarray:
 # runs under — they reach every HLO instruction's ``op_name``, so a
 # profiler trace's device time can be summed by part of the model.
 # ``serving.graphs`` adds ``kv.gather``/``kv.splice`` around the pool
-# plumbing; norms, the rng split and the length update carry none.
+# plumbing; norms, the rng split and the length update carry none. Since
+# ISSUE 25 carries the cache whole, nothing runs under ``kv.pack`` and
+# ``kv.slice`` is only the read of a dense cache at its layer — both stay
+# declared, because the benchmark's readers sum the ``kv.*`` names.
 DEVICE_SCOPES = ("embed", "attn.qkv", "attn.rope", "kv.slice", "kv.write",
                  "kv.pack", "kv.gather", "kv.splice", "attn.core",
                  "attn.out", "ffn", "moe.route", "moe.experts",
                  "moe.combine", "head", "sample")
 
 
-def _pool_write(pool: jnp.ndarray, layer_idx: int, idx, value):
-    """One layer's plane of the paged pool with ``value`` written at
-    ``idx``: the per-layer read of the pool, then the write."""
-    with jax.named_scope("kv.slice"):
-        plane = pool[layer_idx]
+def _pool_write(pool: jnp.ndarray, layer_idx: int, bi, oi, value):
+    """The paged pool ``[L, N, BS, ...]`` with ``value`` written at
+    ``[layer_idx, bi, oi]``: a scatter into the whole array, which XLA
+    does in place on a donated or carried pool — no plane is cut out and
+    none is stacked back."""
     with jax.named_scope("kv.write"):
-        return plane.at[idx].set(value)
+        return pool.at[layer_idx, bi, oi].set(value)
 
 
-def _cache_write(cache: jnp.ndarray, layer_idx: int, item, start):
-    """One layer's plane of a dense cache with ``item`` written at
-    ``start`` (``dynamic_update_slice``)."""
-    with jax.named_scope("kv.slice"):
-        plane = cache[layer_idx]
+def _cache_write(cache: jnp.ndarray, layer_idx: int, item, positions):
+    """The dense cache ``[L, B, S, KH, D]`` with ``item`` ``[B, T, KH, D]``
+    written at ``layer_idx``, each row's ``T`` tokens from that row's first
+    position on: ``dynamic_update_slice`` into the whole array at batch 1,
+    a scatter with the same clamp of the start over several rows."""
+    b, t = item.shape[:2]
     with jax.named_scope("kv.write"):
-        return jax.lax.dynamic_update_slice(plane, item, start)
+        if b == 1:
+            return jax.lax.dynamic_update_slice(
+                cache, item[None], (layer_idx, 0, positions[0, 0], 0, 0))
+        start = jnp.clip(positions[:, :1], 0, cache.shape[2] - t)
+        return cache.at[layer_idx, jnp.arange(b)[:, None],
+                        start + jnp.arange(t)].set(item)
+
+
+def _cache_read(cache: jnp.ndarray, layer_idx: int):
+    """One layer's plane of a dense cache, for an attention that takes
+    ``[B, S, KH, D]``: an XLA consumer fuses the slice; the ragged pallas
+    kernel has it materialised."""
+    with jax.named_scope("kv.slice"):
+        return cache[layer_idx]
 
 
 def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
@@ -163,6 +180,10 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
                 kv_cache: Optional[Params], layer_idx: int,
                 cache_len: Optional[jnp.ndarray], decode: bool,
                 mesh=None):
+    """One layer's attention. Returns ``(x, kv_cache)``: the cache dict is
+    carried WHOLE from layer to layer — every branch writes this layer's
+    k/v into the ``[L, ...]`` arrays in place and reads them at
+    ``layer_idx``; nothing is sliced out and re-stacked."""
     b, t, _ = x.shape
     h = rms_norm(x, layer["attn_norm"], cfg.norm_eps, cfg.norm_offset)
     with jax.named_scope("attn.qkv"):
@@ -176,140 +197,90 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
         q = apply_rope(q, positions, sin, cos)
         k = apply_rope(k, positions, sin, cos)
 
-    new_cache = None
     if kv_cache is None:
         with jax.named_scope("attn.core"):
             out = attention(q, k, v, causal=True, mesh=mesh)
-    elif decode and "table" in kv_cache:
-        # paged decode: scatter this token's k/v into the slot's physical
-        # pool block, then block-table paged attention over the prefix.
-        # Pool layout [N_BLOCKS, BS, KH, D] is shared by all sequences —
-        # prefix blocks can be referenced by many tables (prefix reuse).
-        # An int8 pool ("k_scale" present) quantizes the write per
-        # (token, head) vector and the attention dequantizes in-kernel.
-        from ..ops.attention import paged_attention_dispatch
-        table = kv_cache["table"]                      # [B, MB]
-        bs = kv_cache["k"].shape[2]                    # [L,N,BS,KH,D]
-        pos = positions[:, 0]                          # [B]
-        rows = jnp.arange(b)
-        bi = table[rows, pos // bs]
-        oi = pos % bs
-        if "k_scale" in kv_cache:
-            with jax.named_scope("kv.write"):
-                qk, sk = quantize_kv(k[:, 0])          # [B,KH,D], [B,KH]
-                qv, sv = quantize_kv(v[:, 0])
-            k_pool = _pool_write(kv_cache["k"], layer_idx, (bi, oi), qk)
-            v_pool = _pool_write(kv_cache["v"], layer_idx, (bi, oi), qv)
-            k_sc = _pool_write(kv_cache["k_scale"], layer_idx, (bi, oi), sk)
-            v_sc = _pool_write(kv_cache["v_scale"], layer_idx, (bi, oi), sv)
-            with jax.named_scope("attn.core"):
-                out = paged_attention_dispatch(q, k_pool, v_pool, table,
-                                               cache_len, k_sc, v_sc,
-                                               mesh=mesh)
-            new_cache = (k_pool, v_pool, k_sc, v_sc)
-        else:
-            k_pool = _pool_write(kv_cache["k"], layer_idx, (bi, oi), k[:, 0])
-            v_pool = _pool_write(kv_cache["v"], layer_idx, (bi, oi), v[:, 0])
-            with jax.named_scope("attn.core"):
-                out = paged_attention_dispatch(q, k_pool, v_pool, table,
-                                               cache_len, mesh=mesh)
-            new_cache = (k_pool, v_pool)
     elif "table" in kv_cache:
-        # paged multi-token VERIFY (speculative decoding): scatter all T
-        # window tokens' k/v into the slots' physical pool blocks in one
-        # shot, then attend each query over its own absolute-position
-        # prefix. Rejected draft positions simply hold garbage KV after
-        # the window — attention masks by position, and the next window's
-        # writes overwrite them (paged scratch re-splice semantics).
-        from ..ops.attention import paged_verify_attention
+        # paged: scatter the window's k/v into the slots' physical pool
+        # blocks, then attend over each slot's block table. Pool layout
+        # [L, N_BLOCKS, BS, KH, D] is shared by all sequences — prefix
+        # blocks can be referenced by many tables (prefix reuse). An int8
+        # pool ("k_scale" present) quantizes the write per (token, head)
+        # vector and the attention dequantizes after the block read.
+        #
+        # decode (T = 1): block-table paged attention over the prefix.
+        # Otherwise a multi-token VERIFY (speculative decoding): all T
+        # window tokens are written in one shot and each query attends
+        # over its own absolute-position prefix. Rejected draft positions
+        # simply hold garbage KV after the window — attention masks by
+        # position, and the next window's writes overwrite them (paged
+        # scratch re-splice semantics).
+        from ..ops.attention import (paged_attention_dispatch,
+                                     paged_verify_attention)
         table = kv_cache["table"]                      # [B, MB]
         bs = kv_cache["k"].shape[2]                    # [L,N,BS,KH,D]
-        bi = jnp.take_along_axis(table, positions // bs, axis=1)  # [B,T]
-        oi = positions % bs
+        if decode:
+            pos = positions[:, 0]                      # [B]
+            bi = table[jnp.arange(b), pos // bs]
+            k, v = k[:, 0], v[:, 0]                    # [B,KH,D]
+        else:
+            pos = positions                            # [B,T]
+            bi = jnp.take_along_axis(table, pos // bs, axis=1)
+        oi = pos % bs
+        kv_cache = dict(kv_cache)
+        scales = ()
         if "k_scale" in kv_cache:
             with jax.named_scope("kv.write"):
-                qk, sk = quantize_kv(k)                # [B,T,KH,D],[B,T,KH]
-                qv, sv = quantize_kv(v)
-            k_pool = _pool_write(kv_cache["k"], layer_idx, (bi, oi), qk)
-            v_pool = _pool_write(kv_cache["v"], layer_idx, (bi, oi), qv)
-            k_sc = _pool_write(kv_cache["k_scale"], layer_idx, (bi, oi), sk)
-            v_sc = _pool_write(kv_cache["v_scale"], layer_idx, (bi, oi), sv)
-            with jax.named_scope("attn.core"):
-                out = paged_verify_attention(q, k_pool, v_pool, table,
-                                             positions, k_sc, v_sc)
-            new_cache = (k_pool, v_pool, k_sc, v_sc)
-        else:
-            k_pool = _pool_write(kv_cache["k"], layer_idx, (bi, oi), k)
-            v_pool = _pool_write(kv_cache["v"], layer_idx, (bi, oi), v)
-            with jax.named_scope("attn.core"):
-                out = paged_verify_attention(q, k_pool, v_pool, table,
-                                             positions)
-            new_cache = (k_pool, v_pool)
-    elif decode:
-        # scatter this token's k/v at positions, then attend over the prefix
-        k_cache = _cache_write(
-            kv_cache["k"], layer_idx, k,
-            (0, positions[0, 0], 0, 0)) if b == 1 else _scatter_kv(
-                kv_cache["k"][layer_idx], k, positions)
-        v_cache = _cache_write(
-            kv_cache["v"], layer_idx, v,
-            (0, positions[0, 0], 0, 0)) if b == 1 else _scatter_kv(
-                kv_cache["v"][layer_idx], v, positions)
+                k, sk = quantize_kv(k)                 # [..,KH,D], [..,KH]
+                v, sv = quantize_kv(v)
+            for name, sc in (("k_scale", sk), ("v_scale", sv)):
+                kv_cache[name] = _pool_write(kv_cache[name], layer_idx,
+                                             bi, oi, sc)
+            scales = (kv_cache["k_scale"], kv_cache["v_scale"])
+        kv_cache["k"] = _pool_write(kv_cache["k"], layer_idx, bi, oi, k)
+        kv_cache["v"] = _pool_write(kv_cache["v"], layer_idx, bi, oi, v)
         with jax.named_scope("attn.core"):
-            out = decode_attention(q, k_cache, v_cache, cache_len, mesh=mesh)
-        new_cache = (k_cache, v_cache)
-    elif cache_len is not None:
-        # CHUNKED prefill: write this chunk at its PER-ROW offset, then
-        # attend over prefix + chunk with the absolute-position mask —
-        # graph shapes are (C, S) no matter how long the prompt is. The
-        # engine admits chunks at batch 1, but the signature accepts
-        # [B, C] positions: applying row 0's offset to every row would
-        # write other rows' chunks at the wrong cache slots (and their
-        # queries would then mask out their own chunk) — silently wrong
-        # logits, so scatter per row.
-        from ..ops.attention import chunk_prefill_attention
-        if b == 1:
-            off = positions[0, 0]
-            k_cache = _cache_write(kv_cache["k"], layer_idx, k,
-                                   (0, off, 0, 0))
-            v_cache = _cache_write(kv_cache["v"], layer_idx, v,
-                                   (0, off, 0, 0))
-        else:
-            def write_chunk(c, item, off0):
-                return jax.lax.dynamic_update_slice(c, item, (off0, 0, 0))
-
-            with jax.named_scope("kv.write"):
-                k_cache = jax.vmap(write_chunk)(
-                    kv_cache["k"][layer_idx], k, positions[:, 0])
-                v_cache = jax.vmap(write_chunk)(
-                    kv_cache["v"][layer_idx], v, positions[:, 0])
-        with jax.named_scope("attn.core"):
-            out = chunk_prefill_attention(q, k_cache, v_cache, positions)
-        new_cache = (k_cache, v_cache)
+            if decode:
+                out = paged_attention_dispatch(
+                    q, kv_cache["k"], kv_cache["v"], table, cache_len,
+                    *scales, mesh=mesh, layer=layer_idx)
+            else:
+                out = paged_verify_attention(
+                    q, kv_cache["k"], kv_cache["v"], table, positions,
+                    *scales, layer=layer_idx)
     else:
-        # prefill: write [0, t) then causal-attend within the prefix
-        k_cache = _cache_write(kv_cache["k"], layer_idx, k, (0, 0, 0, 0))
-        v_cache = _cache_write(kv_cache["v"], layer_idx, v, (0, 0, 0, 0))
-        with jax.named_scope("attn.core"):
-            out = attention(q, k, v, causal=True, mesh=mesh)
-        new_cache = (k_cache, v_cache)
+        # dense cache [L, B, S, KH, D]. Decode: this token's k/v at each
+        # row's position, then attention over the prefix. CHUNKED prefill
+        # (cache_len given): this chunk at its PER-ROW offset, then
+        # attention over prefix + chunk with the absolute-position mask —
+        # graph shapes are (C, S) no matter how long the prompt is; the
+        # engine admits chunks at batch 1, but the signature accepts
+        # [B, C] positions, and row 0's offset applied to every row would
+        # write other rows' chunks at the wrong cache slots (silently
+        # wrong logits), so the write is per row. Whole-prompt prefill:
+        # [0, t), then causal attention within the prompt itself.
+        kv_cache = dict(
+            kv_cache,
+            k=_cache_write(kv_cache["k"], layer_idx, k, positions),
+            v=_cache_write(kv_cache["v"], layer_idx, v, positions))
+        if not decode and cache_len is None:
+            with jax.named_scope("attn.core"):
+                out = attention(q, k, v, causal=True, mesh=mesh)
+        else:
+            k_cache = _cache_read(kv_cache["k"], layer_idx)
+            v_cache = _cache_read(kv_cache["v"], layer_idx)
+            with jax.named_scope("attn.core"):
+                if decode:
+                    out = decode_attention(q, k_cache, v_cache, cache_len,
+                                           mesh=mesh)
+                else:
+                    from ..ops.attention import chunk_prefill_attention
+                    out = chunk_prefill_attention(q, k_cache, v_cache,
+                                                  positions)
 
     with jax.named_scope("attn.out"):
         out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)
-        return x + maybe_matmul(out, layer["wo"]), new_cache
-
-
-def _scatter_kv(cache: jnp.ndarray, kv: jnp.ndarray,
-                positions: jnp.ndarray) -> jnp.ndarray:
-    """Per-sequence scatter of one token: cache [B,S,KH,D], kv [B,1,KH,D],
-    positions [B,1]."""
-    idx = positions[:, 0]
-
-    def write_one(c, item, i):
-        return jax.lax.dynamic_update_slice(c, item, (i, 0, 0))
-
-    with jax.named_scope("kv.write"):
-        return jax.vmap(write_one)(cache, kv, idx)
+        return x + maybe_matmul(out, layer["wo"]), kv_cache
 
 
 def _mlp_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig):
@@ -366,13 +337,10 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
     with jax.named_scope("attn.rope"):
         sin, cos = rope_table(rope_len, cfg.head_dim, cfg.rope_theta)
 
-    updates: list = []        # per-layer (k, v[, k_scale, v_scale]) tuples
     moe_balance = jnp.zeros((), jnp.float32)
     for i, layer in enumerate(params["layers"]):
-        x, updated = _attn_block(layer, x, cfg, positions, sin, cos,
-                                 kv_cache, i, cache_len, decode, mesh)
-        if updated is not None:
-            updates.append(updated)
+        x, kv_cache = _attn_block(layer, x, cfg, positions, sin, cos,
+                                  kv_cache, i, cache_len, decode, mesh)
         x, aux = _mlp_block(layer, x, cfg)
         if aux is not None:
             moe_balance = moe_balance + aux["balance_loss"]
@@ -394,25 +362,14 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
 
     out = x if return_hidden else logits
 
-    @jax.named_scope("kv.pack")
-    def _pack_cache():
-        cache = {"k": jnp.stack([u[0] for u in updates]),
-                 "v": jnp.stack([u[1] for u in updates])}
-        if updates and len(updates[0]) == 4:     # int8 pool: scales ride
-            cache["k_scale"] = jnp.stack([u[2] for u in updates])
-            cache["v_scale"] = jnp.stack([u[3] for u in updates])
-        if "table" in (kv_cache or {}):
-            cache["table"] = kv_cache["table"]   # paged: table rides along
-        return cache
-
     if return_moe_aux:
         # mean balance loss across layers (training regularizer)
         aux = moe_balance / max(cfg.n_layers, 1)
         if kv_cache is not None:
-            return out, _pack_cache(), aux
+            return out, kv_cache, aux
         return out, aux
     if kv_cache is not None:
-        return out, _pack_cache()
+        return out, kv_cache
     return out
 
 

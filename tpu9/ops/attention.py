@@ -39,9 +39,12 @@ NEG_INF = -1e30
 HEAD_AXIS = "tp"
 _KERNEL_HEAD_DIMS = (64, 128, 256)
 # every head-carrying operand keeps its heads on axis 2: q [B,T,QH,D],
-# k/v cache [B,S,KH,D], paged pool [N,BS,KH,D], scale planes [N,BS,KH]
+# k/v cache [B,S,KH,D], one layer's plane of the paged pool [N,BS,KH,D] and
+# of its scales [N,BS,KH]; the whole pool has the layer axis in front
 _HEADS4 = P(None, None, HEAD_AXIS, None)
 _HEADS3 = P(None, None, HEAD_AXIS)
+_POOL5 = P(None, None, None, HEAD_AXIS, None)
+_POOL4 = P(None, None, None, HEAD_AXIS)
 
 
 def _per_chip_heads(kernel, mesh, in_specs):
@@ -296,11 +299,13 @@ def paged_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, block_table: jnp.ndarray,
                            positions: jnp.ndarray,
                            k_scale: jnp.ndarray = None,
-                           v_scale: jnp.ndarray = None) -> jnp.ndarray:
+                           v_scale: jnp.ndarray = None,
+                           layer: int = 0) -> jnp.ndarray:
     """Multi-token attention against the paged pool for one speculative
     VERIFY pass: q [B, T, QH, D] are the window's queries at absolute
-    ``positions`` [B, T]; k/v_pool [N, BS, KH, D] already contain the
-    window's keys (scattered by the caller).
+    ``positions`` [B, T]; k/v_pool [L, N, BS, KH, D] at ``layer`` (or one
+    layer's plane [N, BS, KH, D]) already contain the window's keys
+    (scattered by the caller).
 
     Each slot's block-table row is densified with an XLA gather and the
     per-query position mask (key_pos <= q_pos) hides everything past each
@@ -313,13 +318,13 @@ def paged_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     table without the densify copy is the on-chip optimization path; the
     gather form is the correctness-first dispatch every backend runs.)
 
-    An int8 pool passes ``k_scale``/``v_scale`` [N, BS, KH] — blocks are
-    dequantized right after the gather (per-vector scales, see
+    An int8 pool passes ``k_scale``/``v_scale`` (one rank less) — blocks
+    are dequantized right after the gather (per-vector scales, see
     ``tpu9.ops.quant.quantize_kv``; densify+dequant shared with the
     decode oracle via ``paged_attention.gather_paged``)."""
     from .paged_attention import gather_paged
-    k = gather_paged(k_pool, block_table, k_scale, q.dtype)
-    v = gather_paged(v_pool, block_table, v_scale, q.dtype)
+    k = gather_paged(k_pool, block_table, k_scale, q.dtype, layer)
+    v = gather_paged(v_pool, block_table, v_scale, q.dtype, layer)
     return chunk_prefill_attention(q, k, v, positions)
 
 
@@ -338,27 +343,34 @@ def paged_attention_dispatch(q: jnp.ndarray, k_pool: jnp.ndarray,
                              cache_len: jnp.ndarray,
                              k_scale: jnp.ndarray = None,
                              v_scale: jnp.ndarray = None,
-                             mesh=None) -> jnp.ndarray:
+                             mesh=None, layer=0) -> jnp.ndarray:
     """Block-table paged decode dispatch: pallas kernel on TPU (physical
     blocks DMA'd by table lookup in the index map — no densify copy),
-    gather + XLA oracle elsewhere. ``k_scale``/``v_scale`` [N, BS, KH]
-    mark an int8 pool — the kernel dequantizes in-register after the DMA,
-    so HBM only ever moves the int8 payload + the per-vector scales."""
+    gather + XLA oracle elsewhere. k/v_pool are the whole pool
+    [L, N, BS, KH, D], read at ``layer`` where it lives, or one layer's
+    plane [N, BS, KH, D]. ``k_scale``/``v_scale`` (one rank less) mark an
+    int8 pool — the kernel dequantizes in-register after the DMA, so HBM
+    only ever moves the int8 payload + the per-vector scales."""
     from .paged_attention import (paged_decode_attention,
                                   paged_decode_attention_quant,
                                   xla_paged_decode_attention)
-    if paged_kernel_declined(k_pool.shape[1], q.shape[-1]):
+    if paged_kernel_declined(k_pool.shape[-3], q.shape[-1]):
         return xla_paged_decode_attention(q, k_pool, v_pool, block_table,
-                                          cache_len, k_scale, v_scale)
+                                          cache_len, k_scale, v_scale,
+                                          layer)
+    pool, scales = (_POOL5, _POOL4) if k_pool.ndim == 5 \
+        else (_HEADS4, _HEADS3)
+    layer = jnp.asarray(layer, jnp.int32)
     if k_scale is not None:
         return _per_chip_heads(
             paged_decode_attention_quant, mesh,
-            (_HEADS4, _HEADS4, _HEADS4, _HEADS3, _HEADS3, P(), P()))(
-                q, k_pool, v_pool, k_scale, v_scale, block_table, cache_len)
+            (_HEADS4, pool, pool, scales, scales, P(), P(), P()))(
+                q, k_pool, v_pool, k_scale, v_scale, block_table, cache_len,
+                layer)
     return _per_chip_heads(
         paged_decode_attention, mesh,
-        (_HEADS4, _HEADS4, _HEADS4, P(), P()))(
-            q, k_pool, v_pool, block_table, cache_len)
+        (_HEADS4, pool, pool, P(), P(), P()))(
+            q, k_pool, v_pool, block_table, cache_len, layer)
 
 
 def xla_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,

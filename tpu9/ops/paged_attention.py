@@ -162,37 +162,61 @@ def _table_block(table, b, sb, lens, block_s: int):
     return table[b, jnp.minimum(sb, last)]
 
 
-def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(table_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, scale: float, block_s: int,
                   num_sb: int, kv_heads: int):
     """Same online-softmax body as _kernel; the difference is entirely in
-    the BlockSpec index maps (physical blocks come from the table)."""
-    del table_ref
+    the BlockSpec index maps (physical blocks come from the table, the
+    pool's layer from ``layer_ref``)."""
+    del table_ref, layer_ref
     _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
             scale=scale, block_s=block_s, num_sb=num_sb, kv_heads=kv_heads)
+
+
+def _as_pool(layer, k_pool: jnp.ndarray, *rest: jnp.ndarray):
+    """``(layer [1] int32, k_pool, *rest)`` as the kernels take them: the
+    pool ``[L, N, BS, ...]`` and the layer to read, a scalar-prefetch
+    operand like the table. One layer's plane ``[N, BS, KH, D]`` (with its
+    companions) is layer 0 of a one-layer pool, a free reshape, and has no
+    other. A kernel never takes a plane cut out of a stacked pool: a pallas
+    call takes whole operands, so XLA would copy ``pool[layer]`` out first —
+    the index maps pick the layer and a decode step reads the pool where
+    it lives. The layer is an operand and not a constant of the index map
+    so that every layer of a model runs ONE kernel, traced and lowered
+    once: a constant costs a trace per layer in every bring-up (PERF.md §6,
+    PR 25)."""
+    if k_pool.ndim == 4:
+        layer = 0
+        k_pool, *rest = (x[None] for x in (k_pool, *rest))
+    return (jnp.asarray(layer, jnp.int32).reshape(1), k_pool, *rest)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, block_table: jnp.ndarray,
-                           cache_len: jnp.ndarray,
+                           cache_len: jnp.ndarray, layer=0,
                            interpret: bool = False) -> jnp.ndarray:
     """Block-table paged decode attention (vLLM-style, TPU-first).
 
-    q [B,1,QH,D]; k/v_pool [N_BLOCKS, BS, KH, D] — a POOL shared by every
-    sequence; block_table [B, MAX_BLOCKS] int32 maps each sequence's logical
-    block i to a physical pool block (entries past the valid prefix are
-    ignored); cache_len [B] valid tokens incl. current. Returns [B,1,QH,D].
+    q [B,1,QH,D]; k/v_pool [L, N_BLOCKS, BS, KH, D] — the whole POOL, every
+    layer of it, shared by every sequence — read at ``layer``, an int or an
+    int32 scalar (one layer's [N_BLOCKS, BS, KH, D] plane is taken as a
+    one-layer pool); block_table [B, MAX_BLOCKS] int32 maps each sequence's
+    logical block i to a physical pool block (entries past the valid prefix
+    are ignored); cache_len [B] valid tokens incl. current. Returns
+    [B,1,QH,D].
 
     Reference analogue: the engine-side KV management the reference's
     LLM router assumes (pkg/abstractions/pod/llm.go token pressure); the
     kernel itself is the TPU equivalent of paged_attention — physical
     blocks are DMA'd straight from the pool by table lookup in the
     BlockSpec index map (scalar-prefetch), so fragmentation-free sharing
-    (prefix reuse) costs nothing on the read path.
+    (prefix reuse) costs nothing on the read path, and neither does the
+    layer (:func:`_as_pool`).
     """
+    layer, k_pool, v_pool = _as_pool(layer, k_pool, v_pool)
     batch, _, q_heads, head_dim = q.shape
-    n_blocks, block_s, kv_heads, _ = k_pool.shape
+    _, n_blocks, block_s, kv_heads, _ = k_pool.shape
     max_sb = block_table.shape[1]
     assert q_heads % kv_heads == 0
     group = q_heads // kv_heads
@@ -203,26 +227,26 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                                block_s=block_s, num_sb=max_sb,
                                kv_heads=kv_heads)
 
-    def kv_index(b, sb, table, lens):
-        return (_table_block(table, b, sb, lens, block_s), 0, 0, 0)
+    def kv_index(b, sb, table, lens, layer):
+        return (layer[0], _table_block(table, b, sb, lens, block_s),
+                0, 0, 0)
+
+    def q_index(b, sb, table, lens, layer):
+        return (b, 0, 0, 0)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, kv_heads, group, head_dim),
-                             lambda b, sb, table, lens: (b, 0, 0, 0)),
-                pl.BlockSpec((1, block_s, kv_heads, head_dim),
-                             lambda b, sb, table, lens: kv_index(
-                                 b, sb, table, lens)),
-                pl.BlockSpec((1, block_s, kv_heads, head_dim),
-                             lambda b, sb, table, lens: kv_index(
-                                 b, sb, table, lens)),
+                pl.BlockSpec((1, kv_heads, group, head_dim), q_index),
+                pl.BlockSpec((None, 1, block_s, kv_heads, head_dim),
+                             kv_index),
+                pl.BlockSpec((None, 1, block_s, kv_heads, head_dim),
+                             kv_index),
             ],
-            out_specs=pl.BlockSpec((1, kv_heads, group, head_dim),
-                                   lambda b, sb, table, lens: (b, 0, 0, 0)),
+            out_specs=pl.BlockSpec((1, kv_heads, group, head_dim), q_index),
             scratch_shapes=[
                 pltpu.VMEM((kv_heads, group, 128), jnp.float32),
                 pltpu.VMEM((kv_heads, group, 128), jnp.float32),
@@ -231,21 +255,21 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), cache_len.astype(jnp.int32),
+    )(block_table.astype(jnp.int32), cache_len.astype(jnp.int32), layer,
       qt, k_pool, v_pool)
 
     return out.reshape(batch, 1, q_heads, head_dim)
 
 
-def _paged_quant_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
-                        vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
+def _paged_quant_kernel(table_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
+                        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
                         scale: float, block_s: int, num_sb: int,
                         kv_heads: int):
     """int8-pool variant of :func:`_paged_kernel`: the k/v blocks DMA'd by
     table lookup are int8 and the per-vector scales ride in two small f32
     side inputs with the SAME index map — dequantization is one in-register
     multiply per block, so HBM moves half the cache bytes."""
-    del table_ref
+    del table_ref, layer_ref
     b = pl.program_id(0)
     sb = pl.program_id(1)
     seq_len = len_ref[b]
@@ -278,15 +302,17 @@ def paged_decode_attention_quant(q: jnp.ndarray, k_pool: jnp.ndarray,
                                  k_scale: jnp.ndarray,
                                  v_scale: jnp.ndarray,
                                  block_table: jnp.ndarray,
-                                 cache_len: jnp.ndarray,
+                                 cache_len: jnp.ndarray, layer=0,
                                  interpret: bool = False) -> jnp.ndarray:
     """:func:`paged_decode_attention` over an int8 pool: k/v_pool
-    [N_BLOCKS, BS, KH, D] int8, k/v_scale [N_BLOCKS, BS, KH] f32 (one
-    absmax scale per (token, head) vector — ``tpu9.ops.quant.quantize_kv``).
-    Identical masking/softmax semantics; the only difference is the
-    in-kernel dequant multiply after each block DMA."""
+    [L, N_BLOCKS, BS, KH, D] int8, k/v_scale [L, N_BLOCKS, BS, KH] f32 (one
+    absmax scale per (token, head) vector — ``tpu9.ops.quant.quantize_kv``),
+    or one layer's planes of both. Identical masking/softmax semantics; the
+    only difference is the in-kernel dequant multiply after each block DMA."""
+    layer, k_pool, v_pool, k_scale, v_scale = _as_pool(
+        layer, k_pool, v_pool, k_scale, v_scale)
     batch, _, q_heads, head_dim = q.shape
-    n_blocks, block_s, kv_heads, _ = k_pool.shape
+    _, n_blocks, block_s, kv_heads, _ = k_pool.shape
     max_sb = block_table.shape[1]
     assert q_heads % kv_heads == 0
     group = q_heads // kv_heads
@@ -297,35 +323,31 @@ def paged_decode_attention_quant(q: jnp.ndarray, k_pool: jnp.ndarray,
                                block_s=block_s, num_sb=max_sb,
                                kv_heads=kv_heads)
 
-    def kv_index(b, sb, table, lens):
-        return (_table_block(table, b, sb, lens, block_s), 0, 0, 0)
+    def kv_index(b, sb, table, lens, layer):
+        return (layer[0], _table_block(table, b, sb, lens, block_s),
+                0, 0, 0)
 
-    def sc_index(b, sb, table, lens):
-        return (_table_block(table, b, sb, lens, block_s), 0, 0)
+    def sc_index(b, sb, table, lens, layer):
+        return (layer[0], _table_block(table, b, sb, lens, block_s), 0, 0)
+
+    def q_index(b, sb, table, lens, layer):
+        return (b, 0, 0, 0)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, kv_heads, group, head_dim),
-                             lambda b, sb, table, lens: (b, 0, 0, 0)),
-                pl.BlockSpec((1, block_s, kv_heads, head_dim),
-                             lambda b, sb, table, lens: kv_index(
-                                 b, sb, table, lens)),
-                pl.BlockSpec((1, block_s, kv_heads, head_dim),
-                             lambda b, sb, table, lens: kv_index(
-                                 b, sb, table, lens)),
-                pl.BlockSpec((1, block_s, kv_heads),
-                             lambda b, sb, table, lens: sc_index(
-                                 b, sb, table, lens)),
-                pl.BlockSpec((1, block_s, kv_heads),
-                             lambda b, sb, table, lens: sc_index(
-                                 b, sb, table, lens)),
+                pl.BlockSpec((1, kv_heads, group, head_dim), q_index),
+                pl.BlockSpec((None, 1, block_s, kv_heads, head_dim),
+                             kv_index),
+                pl.BlockSpec((None, 1, block_s, kv_heads, head_dim),
+                             kv_index),
+                pl.BlockSpec((None, 1, block_s, kv_heads), sc_index),
+                pl.BlockSpec((None, 1, block_s, kv_heads), sc_index),
             ],
-            out_specs=pl.BlockSpec((1, kv_heads, group, head_dim),
-                                   lambda b, sb, table, lens: (b, 0, 0, 0)),
+            out_specs=pl.BlockSpec((1, kv_heads, group, head_dim), q_index),
             scratch_shapes=[
                 pltpu.VMEM((kv_heads, group, 128), jnp.float32),
                 pltpu.VMEM((kv_heads, group, 128), jnp.float32),
@@ -334,7 +356,7 @@ def paged_decode_attention_quant(q: jnp.ndarray, k_pool: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), cache_len.astype(jnp.int32),
+    )(block_table.astype(jnp.int32), cache_len.astype(jnp.int32), layer,
       qt, k_pool, v_pool, k_scale, v_scale)
 
     return out.reshape(batch, 1, q_heads, head_dim)
@@ -342,20 +364,26 @@ def paged_decode_attention_quant(q: jnp.ndarray, k_pool: jnp.ndarray,
 
 def gather_paged(pool: jnp.ndarray, block_table: jnp.ndarray,
                  scale: jnp.ndarray = None,
-                 dtype=None) -> jnp.ndarray:
-    """Densify a paged cache: pool [N,BS,KH,D] + table [B,MB] →
-    [B, MB*BS, KH, D]. The XLA fallback path and the chunked-prefill
-    prefix view both use this. ``scale`` [N,BS,KH] marks an int8 pool:
-    the scale planes are gathered by the SAME table and the result is
-    dequantized to ``dtype`` — one implementation of densify+dequant so
-    the decode-oracle and verify paths cannot drift."""
+                 dtype=None, layer: int = 0) -> jnp.ndarray:
+    """Densify a paged cache: pool [L,N,BS,KH,D] at ``layer`` (or one
+    layer's plane [N,BS,KH,D]) + table [B,MB] → [B, MB*BS, KH, D]. The XLA
+    fallback path and the chunked-prefill prefix view both use this: ONE
+    gather at ``[layer, table]``, so no plane of a stacked pool is built
+    on the way. ``scale`` (one rank less) marks an int8 pool: the scale
+    planes are gathered by the SAME table and the result is dequantized
+    to ``dtype`` — one implementation of densify+dequant so the
+    decode-oracle and verify paths cannot drift."""
     b, mb = block_table.shape
-    _, bs, kh, d = pool.shape
+    bs, kh, d = pool.shape[-3:]
     flat = block_table.reshape(-1)
-    dense = pool[flat].reshape(b, mb * bs, kh, d)
+
+    def rows(x):
+        return x[layer, flat] if pool.ndim == 5 else x[flat]
+
+    dense = rows(pool).reshape(b, mb * bs, kh, d)
     if scale is not None:
         from .quant import dequantize_kv
-        sc = scale[flat].reshape(b, mb * bs, kh)
+        sc = rows(scale).reshape(b, mb * bs, kh)
         dense = dequantize_kv(dense, sc, dtype or jnp.bfloat16)
     return dense
 
@@ -365,11 +393,12 @@ def xla_paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                                block_table: jnp.ndarray,
                                cache_len: jnp.ndarray,
                                k_scale: jnp.ndarray = None,
-                               v_scale: jnp.ndarray = None) -> jnp.ndarray:
+                               v_scale: jnp.ndarray = None,
+                               layer: int = 0) -> jnp.ndarray:
     """Correctness oracle + CPU path: densify then regular ragged decode.
-    ``k_scale``/``v_scale`` [N, BS, KH] mark an int8 pool — blocks are
-    dequantized right after the gather."""
+    ``k_scale``/``v_scale`` mark an int8 pool — blocks are dequantized
+    right after the gather. Pools and ``layer`` as :func:`gather_paged`."""
     from .attention import xla_decode_attention
-    k = gather_paged(k_pool, block_table, k_scale, q.dtype)
-    v = gather_paged(v_pool, block_table, v_scale, q.dtype)
+    k = gather_paged(k_pool, block_table, k_scale, q.dtype, layer)
+    v = gather_paged(v_pool, block_table, v_scale, q.dtype, layer)
     return xla_decode_attention(q, k, v, cache_len)
