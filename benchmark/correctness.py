@@ -10,12 +10,12 @@ logit changes on rounding.
 
 from __future__ import annotations
 
-import importlib
+from benchmark import manifest
 
 
 def load_reference(name: str):
     """The reference module a configuration file names, found by name."""
-    return importlib.import_module(f"benchmark.reference.{name}")
+    return manifest.module("reference", name)
 
 
 def probe_margins(params, model: dict, probes: list[dict],

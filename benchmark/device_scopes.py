@@ -1,7 +1,8 @@
 """Decode-program device time by part of the model.
 
-The program runs its forward pass under ``jax.named_scope`` names
-(``tpu9.models.transformer.DEVICE_SCOPES``). A TPU trace names a device
+The program runs its forward pass under ``jax.named_scope`` names; which of
+them each decode share sums is the configuration's family's to say
+(``SCOPE_GROUPS`` in ``families/<f>.py``). A TPU trace names a device
 operation by its HLO instruction and carries no scope, so the engine reports
 on ``/health``, for every program it compiled ahead, which instructions
 belong to which scope (``device_scopes``: ``{program: {scope: [instruction
@@ -25,10 +26,6 @@ from benchmark import host_phases
 from benchmark.trace import CONTAINERS, op_key, program_key
 
 OTHER = "other"
-# the groups the per-layer metrics read
-KV_POOL = ("kv.slice", "kv.write", "kv.pack", "kv.gather", "kv.splice")
-ATTENTION = ("attn.core",)
-FFN = ("ffn", "moe.route", "moe.experts", "moe.combine")
 
 
 def by_scope(ops: list, modules: list, maps: dict,
@@ -87,11 +84,13 @@ def decode_seconds(ctx: dict) -> dict:
     return seconds
 
 
-def share(ctx: dict, scopes: tuple):
+def share(ctx: dict, group: str):
     """One reader's number: the share of the decode programs' device time
-    spent under ``scopes``, in %."""
+    spent under the scopes of ``group`` (``kv_pool``, ``attention``, ``ffn``:
+    the ``SCOPE_GROUPS`` of the configuration's family), in %."""
     seconds = decode_seconds(ctx)
     total = sum(seconds.values())
     if not total:
         return None
+    scopes = ctx["family"].SCOPE_GROUPS[group]
     return 100.0 * sum(seconds.get(s, 0.0) for s in scopes) / total

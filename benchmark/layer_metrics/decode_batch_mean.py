@@ -5,9 +5,4 @@ from benchmark import readers
 
 
 def read(ctx):
-    tokens = readers.counter_delta(ctx, "tokens_generated")
-    steps = readers.counter_delta(ctx, "decode_steps")
-    firsts = readers.nested_delta(ctx, "latency", "ttft_count")
-    if not steps or tokens is None:
-        return None
-    return (tokens - (firsts or 0)) / steps
+    return readers.decode_batch(ctx)

@@ -1,7 +1,7 @@
 """kernels: share of the chip's HBM bandwidth that the bytes a decode step
-NEEDS (``peaks.decode_bytes_per_step``: weights a token of the batch passes
-through, keys and values of the resident context; per chip) would take in the
-time a decode step TOOK (``decode_step_ms``, device trace)."""
+NEEDS (the family's ``decode_bytes_per_step``: weights a token of the batch
+passes through, keys and values of the resident context; per chip) would take
+in the time a decode step TOOK (``decode_step_ms``, device trace)."""
 from benchmark import metrics, peaks, readers
 
 
@@ -13,7 +13,7 @@ def read(ctx):
         return None
     batch = tokens / steps
     context = metrics.mean_resident_context(ctx["records"], ctx["seconds"])
-    need = peaks.decode_bytes_per_step(ctx["model"], batch, context) \
-        / ctx["chips"]
+    need = ctx["family"].decode_bytes_per_step(
+        ctx["model"], batch, context) / ctx["chips"]
     peak = peaks.chip_peaks(ctx["device"]["kind"])["hbm_gbps"] * 1e9
     return 100.0 * need / (step_ms / 1e3) / peak

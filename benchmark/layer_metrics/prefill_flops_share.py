@@ -1,6 +1,7 @@
 """kernels: share of peak bf16 FLOP/s that the matmul FLOPs the prefilled
-tokens NEED (``peaks.prefill_flops_per_token``; attention scores not counted,
-so a lower bound) reach in the time the chunk programs TOOK, over all chips."""
+tokens NEED (the family's ``prefill_flops_per_token``; attention scores not
+counted, so a lower bound) reach in the time the chunk programs TOOK, over
+all chips."""
 from benchmark import peaks, readers
 
 
@@ -9,5 +10,5 @@ def read(ctx):
     if not tokens or not seconds:
         return None
     peak = peaks.chip_peaks(ctx["device"]["kind"])["bf16_tflops"] * 1e12
-    return 100.0 * peaks.prefill_flops_per_token(ctx["model"]) * tokens \
-        / seconds / (peak * ctx["chips"])
+    need = ctx["family"].prefill_flops_per_token(ctx["model"]) * tokens
+    return 100.0 * need / seconds / (peak * ctx["chips"])

@@ -4,6 +4,7 @@ a file of its own, found here by name: adding a cell edits no file that is
 there.
 
     configuration <c>   benchmark/configs/<c>.json      (its ``file``)
+    its family          benchmark/families/<f>.py       (named in the file)
     its reference       benchmark/reference/<r>.py      (named in the file)
     traffic mix <t>     benchmark/traffic/<t>.json
     its generator kind  benchmark/traffic/kinds/<k>.py  (named in the file)
@@ -12,13 +13,21 @@ there.
 
 from __future__ import annotations
 
-import importlib
 import importlib.util
 import json
 import os
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+
+# what a family module gives; nothing else in the harness knows an architecture
+FAMILY_GIVES = ("model_sizes", "program_config", "decode_bytes_per_step",
+                "prefill_flops_per_token", "kernel_cost", "STEP_MARKER",
+                "marker_calls_per_step", "SCOPE_GROUPS")
+# keys of a configuration file that are the harness's own, not the source's
+HARNESS_KEYS = ("source", "family", "reference", "reduced", "assumed",
+                "deployment", "correct_tolerance_logit",
+                "correct_tolerance_why", "engine", "endpoint")
 
 
 def load(root: str = ROOT) -> dict:
@@ -51,8 +60,38 @@ def load_traffic(name: str) -> dict:
         return json.load(f)
 
 
+def module(*path: str):
+    """The module in the file ``benchmark/<path>.py``. By path and not by
+    import: a name may hold ``.`` and ``-``, which a module name may not, and
+    the file is looked for under ``HERE`` as it stands now. It is named as an
+    import would name it, so that a relative import inside it finds the
+    benchmark's packages."""
+    file = os.path.join(HERE, *path) + ".py"
+    if not os.path.exists(file):
+        raise KeyError(f"no {'/'.join(path)}.py under benchmark/")
+    spec = importlib.util.spec_from_file_location(
+        ".".join(("benchmark",) + path), file)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def traffic_kind(kind: str):
-    return importlib.import_module(f"benchmark.traffic.kinds.{kind}")
+    return module("traffic", "kinds", kind)
+
+
+def family(config: dict):
+    """The family module a configuration file names: all that the harness
+    knows about its architecture. No ``family`` key is an error, not a
+    default."""
+    if "family" not in config:
+        raise KeyError("the configuration file names no \"family\" "
+                       "(benchmark/families/<f>.py)")
+    mod = module("families", config["family"])
+    lacks = [name for name in FAMILY_GIVES if not hasattr(mod, name)]
+    if lacks:
+        raise KeyError(f"families/{config['family']}.py lacks {lacks}")
+    return mod
 
 
 def reports(metric: dict, cell_name: str) -> bool:
@@ -70,12 +109,5 @@ def layer_reader_path(name: str) -> str:
 
 
 def layer_reader(name: str):
-    """The reader module of one per-layer metric. Loaded by path: a metric's
-    name may hold ``.`` and ``-``, which a module name may not."""
-    spec = importlib.util.spec_from_file_location(
-        "benchmark_layer_metric_" + "".join(
-            ch if ch.isalnum() else "_" for ch in name),
-        layer_reader_path(name))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+    """The reader module of one per-layer metric."""
+    return module("layer_metrics", name)

@@ -109,18 +109,38 @@ def gen_late_ms(records: list, p: int = 99):
     return percentile(late, p) if late else None
 
 
-def mean_resident_context(records: list, seconds: float) -> float:
-    """Time-average over the window of the context tokens held by requests
-    that are decoding: sum over requests of (prompt + half the answer) x the
-    time between its first and last token, over the window's length."""
-    total = 0.0
+def _decoding(records: list, seconds: float):
+    """(context tokens, seconds of decoding inside the window) of every
+    request that decoded in it: prompt + half the answer, between its first
+    and last token."""
     for r in records:
         if r["due_s"] is None or len(r["token_s"]) < 2:
             continue
         a, b = max(r["token_s"][0], 0.0), min(r["token_s"][-1], seconds)
         if b > a:
-            total += (r["prompt_len"] + len(r["token_s"]) / 2) * (b - a)
+            yield r["prompt_len"] + len(r["token_s"]) / 2, b - a
+
+
+def mean_resident_context(records: list, seconds: float) -> float:
+    """Time-average over the window of the context tokens held by requests
+    that are decoding: sum over requests of (prompt + half the answer) x the
+    time between its first and last token, over the window's length."""
+    total = 0.0
+    for context, duration in _decoding(records, seconds):
+        total += context * duration
     return total / seconds
+
+
+def mean_decoding_context(records: list, seconds: float):
+    """Mean context of ONE request while it decodes: the same sum over the
+    time the requests spent decoding instead of the window's length. Times
+    the engine's mean decode batch it is the context resident at a decode
+    step, which ``mean_resident_context`` understates where the device
+    spends part of the window not decoding (a closed loop admits in waves)."""
+    pairs = list(_decoding(records, seconds))
+    if not pairs:
+        return None
+    return sum(c * d for c, d in pairs) / sum(d for _, d in pairs)
 
 
 def finite(x) -> bool:

@@ -21,6 +21,18 @@ def nested_delta(ctx: dict, group: str, key: str):
     return b - a
 
 
+def decode_batch(ctx: dict):
+    """Mean number of sequences in a decode step: tokens the decode steps
+    produced (all tokens less one first token per request, which prefill
+    produces) over decode steps, from ``engine.stats()`` deltas."""
+    tokens = counter_delta(ctx, "tokens_generated")
+    steps = counter_delta(ctx, "decode_steps")
+    firsts = nested_delta(ctx, "latency", "ttft_count")
+    if not steps or tokens is None:
+        return None
+    return (tokens - (firsts or 0)) / steps
+
+
 def engine_phase_mean_ms(ctx: dict, phase: str):
     """Mean of one engine latency phase (``queue_wait``, ``ttft``, ...) over
     the requests of the window: the summaries are cumulative mean and count,
@@ -79,6 +91,20 @@ def program_seconds(ctx: dict, names: tuple):
     if not hit:
         return None, {}
     return sum(v["seconds"] for v in hit.values()), hit
+
+
+def kernel_seconds_per_step(ctx: dict, kernel: str,
+                            program: str = "jit_decode"):
+    """Device seconds per decode step, per chip, of the operations the trace
+    prints as ``kernel`` inside the runs of ``program``; None where the
+    trace holds no such operation or no counted step."""
+    trace = ctx["trace"] or {}
+    steps = ((trace.get("programs") or {}).get(program) or {}).get("steps")
+    seconds = sum(v for k, v in (trace.get("op_seconds") or {}).items()
+                  if k.split(":", 1)[0] == f"{program}/{kernel}")
+    if not steps or not seconds:
+        return None
+    return seconds / steps
 
 
 def demoted_latency(ctx: dict, reader_file: str):

@@ -163,8 +163,8 @@ class Session:
     # -- bring-up -----------------------------------------------------------
 
     def bring_up(self) -> None:
-        from benchmark import serve
-        self.model = serve.model_sizes(self.config)
+        self.family = manifest.family(self.config)
+        self.model = self.family.model_sizes(self.config)
         started = self.stack.start()
         app = stack_mod.APP.format(
             args={"config": self.config, "seed": self.args.seed,
@@ -287,8 +287,8 @@ def layer_context(s: Session, got: dict, seconds: float, trace: dict) -> dict:
             "health_ready": got["health_ready"], "health0": got["health0"],
             "health1": got["health1"], "gateway0": got["gateway0"],
             "gateway1": got["gateway1"], "trace": trace, "model": s.model,
-            "engine": s.config["engine"], "device": got["device"],
-            "chips": s.chips, "cell": s.cell["name"]}
+            "family": s.family, "engine": s.config["engine"],
+            "device": got["device"], "chips": s.chips, "cell": s.cell["name"]}
 
 
 def result_line(s: Session, got: dict, seconds: float) -> dict:
@@ -339,11 +339,17 @@ def result_line(s: Session, got: dict, seconds: float) -> dict:
 
     os.environ["JAX_PLATFORMS"] = "cpu"   # reading a trace opens no chip
     trace = trace_mod.reduce_dir(os.path.join(s.run_dir, "trace"),
-                                 s.model["num_hidden_layers"])
+                                 s.family.STEP_MARKER,
+                                 s.family.marker_calls_per_step(s.model))
     info(profile=got.get("profile"),
          trace={k: v for k, v in trace.items()
-                if k not in ("device_ops", "idle_gaps")})
+                if k not in ("device_ops", "idle_gaps", "op_seconds")})
     ctx = layer_context(s, got, seconds, trace)
+    # what the readers read, beside the trace: ``tools/reduce_again.py``
+    # takes a saved run's per-layer metrics from them once more
+    with open(os.path.join(s.run_dir, "context.json"), "w") as f:
+        json.dump(dict(ctx, family=s.config["family"], trace=None,
+                       config=s.config), f)
     for m in manifest.cell_metrics(s.manifest, s.cell["name"], "per_layer"):
         value = manifest.layer_reader(m["name"]).read(ctx)
         if metrics.finite(value):     # a reader that finds nothing: left out

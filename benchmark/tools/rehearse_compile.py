@@ -49,8 +49,8 @@ def main() -> int:
         or [c["name"] for c in m["configs"]]
     for name in names:
         config = manifest.load_config(m, name)
-        model = serve.model_sizes(config)
-        cfg = serve.decoder_config(model)
+        family = manifest.family(config)
+        cfg = family.program_config(family.model_sizes(config))
         ecfg = serve.engine_config(config["engine"])
         t = parse_topology(config["engine"]["topology"]) or Topology(1, 1)
         policy = MeshPolicy(t, devices=topo.devices[:t.n_chips])

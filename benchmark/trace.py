@@ -86,11 +86,13 @@ def union_ns(intervals: list) -> float:
     return busy
 
 
-def reduce_planes(planes: list, n_layers: int = 0,
-                  attention_kernel: str = "paged_decode_attention") -> dict:
+def reduce_planes(planes: list, step_marker: str = "",
+                  calls_per_step: int = 0) -> dict:
     """Everything the per-layer readers and the result line take from a
     trace. Times in seconds; sums over chips are divided by the number of
-    chips, so a four-chip cell reads per chip."""
+    chips, so a four-chip cell reads per chip. Decode steps are counted by
+    the calls of the kernel ``step_marker``, ``calls_per_step`` to a step
+    (the configuration's family says both); without them, no steps."""
     planes = [p for p in planes if p["lines"].get(OPS_LINE)]
     if not planes:
         return {}
@@ -129,17 +131,18 @@ def reduce_planes(planes: list, n_layers: int = 0,
     mods = sorted((a, d, program_key(nm))
                   for nm, a, d in first["lines"].get(MODULES_LINE, []))
     # by the operation's OWN name: the full text of a consumer names it too
-    attn = sorted(a for nm, a, _ in first["lines"][OPS_LINE]
-                  if op_key(nm).startswith(attention_kernel))
+    marks = sorted(a for nm, a, _ in first["lines"][OPS_LINE]
+                   if step_marker and op_key(nm).startswith(step_marker))
     programs: dict = {}
     decode_step_ns = []
     for a, d, key in mods:
         rec = programs.setdefault(key, {"runs": 0, "seconds": 0.0})
         rec["runs"] += 1
         rec["seconds"] += d / 1e9
-        if key.endswith("decode") and n_layers:
-            calls = bisect.bisect_left(attn, a + d) - bisect.bisect_left(attn, a)
-            steps = round(calls / n_layers)
+        if key.endswith("decode") and calls_per_step:
+            calls = bisect.bisect_left(marks, a + d) \
+                - bisect.bisect_left(marks, a)
+            steps = round(calls / calls_per_step)
             if steps >= 1:
                 decode_step_ns.append(d / steps)
                 rec["steps"] = rec.get("steps", 0) + steps
@@ -159,14 +162,16 @@ def reduce_planes(planes: list, n_layers: int = 0,
             "programs": programs,
             "decode_step_ms": (statistics.median(decode_step_ns) / 1e6
                                if decode_step_ns else None),
+            "op_seconds": {k: v / 1e9 for k, v in by_op.items()},
             "device_ops": top(by_op, 1e-9), "idle_gaps": top(gaps)}
 
 
-def reduce_dir(trace_dir: str, n_layers: int = 0) -> dict:
+def reduce_dir(trace_dir: str, step_marker: str = "",
+               calls_per_step: int = 0) -> dict:
     path = find_xplane(trace_dir)
     if not path:
         return {}
-    out = reduce_planes(read_planes(path), n_layers)
+    out = reduce_planes(read_planes(path), step_marker, calls_per_step)
     if out:
         out["file"] = path
     return out

@@ -4,6 +4,10 @@ import pytest
 
 from benchmark import device_scopes, host_phases, manifest
 
+M = manifest.load()
+# the scopes are the Mistral family's: the first configuration's family
+FAMILY = manifest.family(manifest.load_config(M, M["configs"][0]["name"]))
+
 MS = 1e6
 MAPS = {
     "decode_1": {"kv.slice": ["fusion.1"], "kv.pack": ["fusion.2"],
@@ -64,7 +68,7 @@ def test_the_three_readers(monkeypatch):
     monkeypatch.setattr(device_scopes, "_seconds", {})
     monkeypatch.setitem(host_phases._loaded, "made-up",
                         {"phases": None, "ops": OPS, "modules": MODULES})
-    ctx = {"trace": {"file": "made-up"},
+    ctx = {"trace": {"file": "made-up"}, "family": FAMILY,
            "health_ready": {"device_scopes": MAPS}}
     got = {m: manifest.layer_reader(m).read(ctx) for m in (
         "decode_kv_pool_share", "decode_attention_share", "decode_ffn_share")}
@@ -74,8 +78,7 @@ def test_the_three_readers(monkeypatch):
         "decode_ffn_share": 100 * 18 / 30})
     assert sum(got.values()) <= 100.0
     # an older program reports no maps; a run without a trace has no file
-    for bare in ({"trace": {"file": "made-up"}, "health_ready": {}},
-                 {"trace": {}, "health_ready": {"device_scopes": MAPS}}):
+    for bare in (dict(ctx, health_ready={}), dict(ctx, trace={})):
         assert all(manifest.layer_reader(m).read(bare) is None for m in got)
 
 
@@ -84,8 +87,8 @@ def test_the_groups_cover_the_programs_scopes():
     is in a group; the rest (embed, projections, rope, head, sample) is what
     PERF.md names as the remainder."""
     from tpu9.models.transformer import DEVICE_SCOPES
-    grouped = set(device_scopes.KV_POOL + device_scopes.ATTENTION
-                  + device_scopes.FFN)
+    assert sorted(FAMILY.SCOPE_GROUPS) == ["attention", "ffn", "kv_pool"]
+    grouped = {s for names in FAMILY.SCOPE_GROUPS.values() for s in names}
     assert grouped <= set(DEVICE_SCOPES)
     assert set(DEVICE_SCOPES) - grouped == {
         "embed", "attn.qkv", "attn.rope", "attn.out", "head", "sample"}

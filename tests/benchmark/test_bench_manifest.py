@@ -9,7 +9,7 @@ import shutil
 
 import pytest
 
-from benchmark import manifest
+from benchmark import correctness, device_scopes, host_phases, manifest, trace
 
 M = manifest.load()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -90,6 +90,17 @@ def test_configuration_files(config):
     assert [c["file"] for c in M["configs"]].count(config["file"]) == 1
     assert os.path.exists(os.path.join(
         manifest.HERE, "reference", body["reference"] + ".py"))
+    assert NAME.match(body["family"]) and os.path.exists(os.path.join(
+        manifest.HERE, "families", body["family"] + ".py"))
+    family = manifest.family(body)
+    for name in ("model_sizes", "program_config", "decode_bytes_per_step",
+                 "prefill_flops_per_token", "kernel_cost",
+                 "marker_calls_per_step"):
+        assert callable(getattr(family, name)), name
+    assert isinstance(family.STEP_MARKER, str) and family.STEP_MARKER
+    assert sorted(family.SCOPE_GROUPS) == ["attention", "ffn", "kv_pool"]
+    assert family.marker_calls_per_step(family.model_sizes(body)) >= 1
+    assert set(manifest.HARNESS_KEYS) <= set(body)
     assert body["correct_tolerance_logit"] > 0 and body["correct_tolerance_why"]
     for key in ("topology", "max_batch", "max_seq_len", "kv_block_size",
                 "prefill_chunk", "kv_pool_blocks", "prefix_cache_blocks",
@@ -196,3 +207,199 @@ def test_a_cell_is_added_by_files_alone(tmp_path, monkeypatch):
     assert "ttft_p90_ms.later-cell" in names
     assert manifest.layer_reader("ttft_p90_ms.later-cell").read({}) == 42.0
     assert all(p.read_bytes() == data for p, data in before.items())
+
+
+LATER_FAMILY = '''"""A made-up architecture: the decoder's block with the gates of the chosen
+experts left as the router gave them, a token exchange before the experts
+under a scope of its own, and an attention kernel of its own in every other
+layer."""
+from benchmark.families import decoder
+
+STEP_MARKER = "latent_decode_attention"
+SCOPE_GROUPS = dict(decoder.SCOPE_GROUPS,
+                    ffn=decoder.SCOPE_GROUPS["ffn"] + ("moe.dispatch",))
+decode_bytes_per_step = decoder.decode_bytes_per_step
+prefill_flops_per_token = decoder.prefill_flops_per_token
+
+
+def model_sizes(config):
+    if config.get("norm_topk_prob") is not False:
+        raise ValueError("norm_topk_prob: this family builds only False")
+    rest = {k: v for k, v in config.items() if k != "norm_topk_prob"}
+    return dict(decoder.model_sizes(rest), norm_topk_prob=False)
+
+
+def program_config(model):
+    return {"decoder": decoder.program_config(model),
+            "renormalise_gates": model["norm_topk_prob"]}
+
+
+def marker_calls_per_step(model):
+    return model["num_hidden_layers"] // 2
+
+
+def kernel_cost(kernel, model, engine, batch, resident_context):
+    if kernel == STEP_MARKER:
+        rows = marker_calls_per_step(model) * resident_context
+        return {"bytes": 512.0 * rows, "flops": 4.0 * 512 * rows}
+    return decoder.kernel_cost(kernel, model, engine, batch, resident_context)
+'''
+LATER_REFERENCE = '''"""The made-up architecture's plain reference (here: the decoder's)."""
+from benchmark.reference import decoder
+
+
+def forward(params, tokens, model):
+    return decoder.forward(params, tokens, model)
+'''
+LATER_READER = '''"""kernels: bytes a step needs of the made-up kernel over its seconds."""
+from benchmark import readers
+
+KERNEL = "latent_decode_attention"
+
+
+def read(ctx):
+    took = readers.kernel_seconds_per_step(ctx, KERNEL)
+    cost = ctx["family"].kernel_cost(KERNEL, ctx["model"], ctx["engine"],
+                                     4, 1000.0)
+    return cost["bytes"] / took if took and cost else None
+'''
+
+
+def add_a_configuration_of_another_family(root, family):
+    """What a later ``model_config`` PR adds, in a copy of the benchmark
+    under ``root``: a family, a reference, a configuration with its
+    rehearsal sizes, a mix, a per-layer reader and the entries. Returns
+    (the grown manifest, every file that was there with its bytes)."""
+    here = root / "benchmark"
+    shutil.copytree(manifest.HERE, here, ignore=shutil.ignore_patterns(
+        "out", "__pycache__"))
+    shutil.copy(os.path.join(manifest.ROOT, "BENCHMARK.json"), root)
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    first = M["configs"][0]
+    body = manifest.load_config(M, first["name"])
+    body.update(family=family, reference="later_reference",
+                norm_topk_prob=False)
+    (here / "families" / "later_family.py").write_text(LATER_FAMILY)
+    (here / "reference" / "later_reference.py").write_text(LATER_REFERENCE)
+    (here / "configs" / "later-model.json").write_text(json.dumps(body))
+    shutil.copy(here / "rehearsal" / (first["name"] + ".json"),
+                here / "rehearsal" / "later-model.json")
+    mix = manifest.load_traffic(M["workloads"][0]["traffic"])
+    (here / "traffic" / "later-mix.json").write_text(json.dumps(mix))
+    (here / "layer_metrics" / "latent_attn_bytes_per_s.py").write_text(
+        LATER_READER)
+    grown = json.loads(json.dumps(M))
+    grown["configs"].append(dict(first, name="later-model",
+                                 file="benchmark/configs/later-model.json"))
+    grown["workloads"].append({"name": "later-cell", "config": "later-model",
+                               "traffic": "later-mix", "chips": 1, "why": "x"})
+    grown["per_layer"].append({"name": "latent_attn_bytes_per_s",
+                               "unit": "B/s", "better": "higher",
+                               "source": "device_trace", "layer": "kernels",
+                               "moves": "tpot_p50_ms",
+                               "workloads": ["later-cell"]})
+    return grown, before
+
+
+def later_planes():
+    """One chip, a model of four layers whose every other layer calls the
+    made-up kernel: a K=2 decode run (4 calls) and a K=1 run (2 calls)."""
+    ms = 1e6
+    starts = (0, 1, 2, 3, 6, 7)
+    kernel = "%latent_decode_attention.3 = bf16[4,8,128]{2,1,0} custom-call(%q)"
+    ops = [(kernel, t * ms, 0.5 * ms) for t in starts]
+    ops += [("%fusion.9 = bf16[4,4096]{1,0} fusion(%x)", t * ms + 0.5 * ms,
+             0.25 * ms) for t in starts]
+    mods = [("jit_decode(1)", 0.0, 4 * ms), ("jit_decode(2)", 6 * ms, 2 * ms)]
+    return [{"name": "/device:TPU:0",
+             "lines": {trace.OPS_LINE: ops, trace.MODULES_LINE: mods}}]
+
+
+def test_a_configuration_of_another_family_is_added_by_files_alone(
+        tmp_path, monkeypatch):
+    """A later PR's configuration of an architecture the decoder family does
+    not build: new files and entries, walked from the manifest to the step
+    count; no file that is there is edited."""
+    grown, before = add_a_configuration_of_another_family(tmp_path,
+                                                         "later_family")
+    monkeypatch.setattr(manifest, "HERE", str(tmp_path / "benchmark"))
+    cell = manifest.cell(grown, "later-cell")
+    config = manifest.load_config(grown, cell["config"], root=str(tmp_path))
+    # the family: sizes with the key the decoder family refuses, the
+    # program's config, at the published and at the rehearsal sizes
+    family = manifest.family(config)
+    assert family.__file__.startswith(str(tmp_path))
+    model = family.model_sizes(config)
+    assert model["norm_topk_prob"] is False and model["hidden_size"] == 4096
+    program = family.program_config(model)
+    assert program["renormalise_gates"] is False
+    assert program["decoder"].dim == 4096
+    with open(os.path.join(manifest.HERE, "rehearsal", "later-model.json")) as f:
+        tiny = dict(config, **json.load(f)["model"])
+    assert family.program_config(family.model_sizes(tiny))["decoder"].dim == 128
+    # the reference and the mix
+    assert callable(correctness.load_reference(config["reference"]).forward)
+    traffic = manifest.load_traffic(cell["traffic"])
+    plan = manifest.traffic_kind(traffic["kind"]).plan(traffic, 1, 10,
+                                                       model["vocab_size"])
+    assert plan["requests"]
+    # the step count, by the family's marker: 4 + 2 calls, 2 to a step
+    assert family.marker_calls_per_step(model) == 2
+    reduced = trace.reduce_planes(later_planes(), family.STEP_MARKER,
+                                  family.marker_calls_per_step(model))
+    assert reduced["programs"]["jit_decode"]["steps"] == 3
+    assert reduced["decode_step_ms"] == pytest.approx(2.0)
+    # the decoder family's marker finds no step in this trace
+    other = manifest.family(manifest.load_config(M, M["configs"][0]["name"]))
+    assert trace.reduce_planes(later_planes(), other.STEP_MARKER, 4)[
+        "decode_step_ms"] is None
+    # the reader, through the family's cost function: 3 ms of the kernel
+    # over 3 steps; 2 calls x 1000 tokens x 512 B a step
+    names = [m["name"] for m in manifest.cell_metrics(grown, "later-cell",
+                                                      "per_layer")]
+    assert "latent_attn_bytes_per_s" in names
+    ctx = {"trace": dict(reduced, file="made-up-later"), "family": family,
+           "model": model, "engine": config["engine"],
+           "health_ready": {"device_scopes": {"decode_1": {
+               "attn.core": ["latent_decode_attention.3"],
+               "moe.dispatch": ["fusion.9"]}}}}
+    assert manifest.layer_reader("latent_attn_bytes_per_s").read(ctx) == \
+        pytest.approx(2 * 1000 * 512 / 1e-3)
+    # the scope groups: the new scope counts under ``ffn`` for this family
+    # and falls out of it for the decoder family
+    monkeypatch.setattr(device_scopes, "_seconds", {})
+    plane = later_planes()[0]["lines"]
+    monkeypatch.setitem(host_phases._loaded, "made-up-later", {
+        "phases": None, "ops": plane[trace.OPS_LINE],
+        "modules": plane[trace.MODULES_LINE]})
+    assert device_scopes.share(ctx, "ffn") == pytest.approx(100 * 1.5 / 4.5)
+    assert device_scopes.share(ctx, "attention") == pytest.approx(100 * 3 / 4.5)
+    assert device_scopes.share(dict(ctx, family=other), "ffn") == 0.0
+    assert all(p.read_bytes() == data for p, data in before.items())
+
+
+def test_the_decoder_family_refuses_the_other_architectures_key(
+        tmp_path, monkeypatch):
+    """The same files under ``"family": "decoder"``: refused, not built as
+    the nearest thing the decoder family knows."""
+    grown, _ = add_a_configuration_of_another_family(tmp_path, "decoder")
+    monkeypatch.setattr(manifest, "HERE", str(tmp_path / "benchmark"))
+    config = manifest.load_config(grown, "later-model", root=str(tmp_path))
+    with pytest.raises(ValueError, match="norm_topk_prob"):
+        manifest.family(config).model_sizes(config)
+
+
+@pytest.mark.parametrize("config,lacks", [
+    ({}, "family"), ({"family": "no_such_family"}, "no_such_family")])
+def test_a_configuration_without_a_family_is_an_error(config, lacks):
+    with pytest.raises(KeyError, match=lacks):
+        manifest.family(config)
+
+
+def test_a_family_that_lacks_a_name_is_an_error(tmp_path, monkeypatch):
+    here = tmp_path / "benchmark"
+    (here / "families").mkdir(parents=True)
+    (here / "families" / "half.py").write_text("STEP_MARKER = 'k'\n")
+    monkeypatch.setattr(manifest, "HERE", str(here))
+    with pytest.raises(KeyError, match="SCOPE_GROUPS"):
+        manifest.family({"family": "half"})

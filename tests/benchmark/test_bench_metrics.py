@@ -102,6 +102,21 @@ def test_mean_resident_context_weights_by_decode_time():
         pytest.approx((1000 + 2.5) * 4 / 10)
 
 
+def test_mean_decoding_context_is_per_request_and_over_decode_time_only():
+    """Two requests that decode one after the other through 8 of 10 s: the
+    window's average holds 0.8 of a request, a decode step holds one."""
+    a = rec(due=0.0, first=1.0, gap=1.0, n=5, prompt_len=1000)   # 1..5
+    b = rec(due=0.0, first=5.0, gap=1.0, n=5, prompt_len=3000)   # 5..9
+    each = metrics.mean_decoding_context([a, b], 10.0)
+    assert each == pytest.approx((1002.5 + 3002.5) / 2)
+    assert metrics.mean_resident_context([a, b], 10.0) == \
+        pytest.approx(each * 0.8)
+    # cut by the window's end: weighted by the time inside it
+    assert metrics.mean_decoding_context([a, b], 7.0) == \
+        pytest.approx((1002.5 * 4 + 3002.5 * 2) / 6)
+    assert metrics.mean_decoding_context([rec(n=1)], 10.0) is None
+
+
 @pytest.mark.parametrize("x,ok", [(1.0, True), (0, True), (None, False),
                                   (float("inf"), False), (float("nan"), False),
                                   ("1", False)])

@@ -12,12 +12,15 @@ import numpy as np
 import pytest
 
 from benchmark import correctness, manifest, serve
-from benchmark.reference import decoder as reference
+
+M = manifest.load()
+CONFIGS = [c["name"] for c in M["configs"]]
+MIXTRAL = "mixtral-8x7b-l4"     # the checks of its own numbers
 
 
 def tiny(name):
-    with open(os.path.join(manifest.HERE, "configs", f"{name}.json")) as f:
-        config = json.load(f)
+    """Configuration ``name`` at its rehearsal sizes, on one chip."""
+    config = manifest.load_config(M, name)
     with open(os.path.join(manifest.HERE, "rehearsal", f"{name}.json")) as f:
         reh = json.load(f)
     config.update(reh["model"])
@@ -26,7 +29,11 @@ def tiny(name):
     return config
 
 
-CONFIGS = ("mixtral-8x7b-l4", "mistral-7b-v0.3-tp4")
+def built(config):
+    """(sizes, the program's config) through the configuration's family."""
+    family = manifest.family(config)
+    model = family.model_sizes(config)
+    return model, family.program_config(model)
 
 
 @pytest.mark.parametrize("name", CONFIGS)
@@ -35,8 +42,10 @@ def test_reference_equals_the_program_in_float32(name):
     published equations and ``decoder_forward`` agree to rounding."""
     from tpu9.models import init_decoder
     from tpu9.models.transformer import decoder_forward
-    model = serve.model_sizes(tiny(name))
-    cfg = dataclasses.replace(serve.decoder_config(model), dtype=jnp.float32)
+    config = tiny(name)
+    reference = correctness.load_reference(config["reference"])
+    model, cfg = built(config)
+    cfg = dataclasses.replace(cfg, dtype=jnp.float32)
     params = init_decoder(serve.seed_key(2 ** 31 + 7), cfg)
     tokens = jnp.asarray(np.random.default_rng(0).integers(3, 512, 70),
                          jnp.int32)
@@ -49,9 +58,10 @@ def test_reference_equals_the_program_in_float32(name):
 
 
 def test_reference_is_independent_of_the_programs_model_code():
-    with open(reference.__file__) as f:
-        text = f.read()
-    assert "import tpu9" not in text and "from tpu9" not in text
+    for name in {manifest.load_config(M, c)["reference"] for c in CONFIGS}:
+        with open(correctness.load_reference(name).__file__) as f:
+            text = f.read()
+        assert "import tpu9" not in text and "from tpu9" not in text
 
 
 def test_seed_key_takes_seeds_past_32_bits():
@@ -66,11 +76,11 @@ def served():
     """The tiny Mixtral through the program's engine, bf16 as served."""
     from tpu9.serving import InferenceEngine
     from tpu9.serving.shard import make_policy
-    config = tiny("mixtral-8x7b-l4")
-    model = serve.model_sizes(config)
+    config = tiny(MIXTRAL)
+    model, cfg = built(config)
     policy = make_policy("1x1")
-    params = serve.build_params(serve.decoder_config(model), policy, 11)
-    engine = InferenceEngine(params, serve.decoder_config(model),
+    params = serve.build_params(cfg, policy, 11)
+    engine = InferenceEngine(params, cfg,
                              serve.engine_config(config["engine"]),
                              policy=policy)
     rng = np.random.default_rng(3)
@@ -105,16 +115,17 @@ def test_a_wrong_token_is_outside_the_margin(served):
 @pytest.mark.parametrize("key,value", [("hidden_act", "gelu"),
                                        ("sliding_window", 4096),
                                        ("torch_dtype", "float16"),
-                                       ("tie_word_embeddings", True)])
+                                       ("tie_word_embeddings", True),
+                                       ("norm_topk_prob", False),
+                                       ("num_experts", 64)])
 def test_a_configuration_the_harness_cannot_build_is_refused(key, value):
-    config = dict(tiny("mixtral-8x7b-l4"), **{key: value})
+    config = dict(tiny(MIXTRAL), **{key: value})
     with pytest.raises(ValueError):
-        serve.model_sizes(config)
+        manifest.family(config).model_sizes(config)
 
 
 def test_published_widths_reach_the_programs_config():
-    with open(os.path.join(manifest.HERE, "configs", "mixtral-8x7b-l4.json")) as f:
-        cfg = serve.decoder_config(serve.model_sizes(json.load(f)))
+    _, cfg = built(manifest.load_config(M, MIXTRAL))
     assert (cfg.dim, cfg.hidden_dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
             cfg.n_experts, cfg.moe_top_k, cfg.vocab_size, cfg.n_layers) == \
         (4096, 14336, 32, 8, 128, 8, 2, 32000, 4)

@@ -11,6 +11,7 @@ import pytest
 from benchmark import trace
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
+MARKER = "paged_decode_attention"   # the kernel the made-up planes count by
 
 
 @pytest.mark.parametrize("intervals,want", [
@@ -72,7 +73,7 @@ def made_up_planes():
 
 
 def test_reduce_made_up_planes():
-    got = trace.reduce_planes(made_up_planes(), n_layers=2)
+    got = trace.reduce_planes(made_up_planes(), MARKER, 2)
     assert got["chips"] == 2
     assert got["window_s"] == pytest.approx(8e-3)
     assert got["busy_s"] == pytest.approx((4 * 0.75 + 0.2 + 2 + 0.2 + 0.4) * 1e-3)
@@ -83,6 +84,22 @@ def test_reduce_made_up_planes():
     assert got["decode_step_ms"] == pytest.approx(1.5)
     assert got["device_ops"][0] == ["jit_decode/fusion", pytest.approx(2.4e-3)]
     assert got["idle_gaps"] == [["jit_decode->jit_chunk", pytest.approx(1e-3)]]
+    # every operation group, per chip: the kernels' readers take theirs here
+    assert got["op_seconds"] == pytest.approx({
+        "jit_decode/paged_decode_attention": 1.2e-3, "jit_decode/fusion": 2.4e-3,
+        "jit_decode/all-reduce": 0.2e-3, "jit_chunk/fusion": 2e-3})
+
+
+@pytest.mark.parametrize("marker,per_step,steps,step_ms", [
+    ("paged_decode_attention", 2, 3, 1.5), ("paged_decode_attention", 1, 6, 0.75),
+    ("fusion", 2, 2, 2.0), ("no_such_kernel", 2, None, None),
+    ("", 2, None, None), ("paged_decode_attention", 0, None, None)])
+def test_steps_are_counted_by_the_marker_the_family_names(marker, per_step,
+                                                          steps, step_ms):
+    got = trace.reduce_planes(made_up_planes(), marker, per_step)
+    assert got["programs"]["jit_decode"].get("steps") == steps
+    assert got["decode_step_ms"] == (pytest.approx(step_ms) if step_ms
+                                     else None)
 
 
 def test_asynchronous_collectives_count_from_start_to_done():
@@ -91,7 +108,7 @@ def test_asynchronous_collectives_count_from_start_to_done():
         p["lines"] = dict(p["lines"], **{trace.ASYNC_LINE: [
             ("%all-reduce-start.5 = f32[4096]{0} all-reduce-start(%x)", 1e6, 3e5),
             ("%copy-start.1 = bf16[8]{0} copy-start(%y)", 2e6, 9e5)]})
-    got = trace.reduce_planes(planes, n_layers=2)
+    got = trace.reduce_planes(planes, MARKER, 2)
     assert got["collective_s"] == pytest.approx(0.5e-3)
     assert got["busy_s"] == pytest.approx((4 * 0.75 + 0.2 + 2 + 0.2 + 0.4) * 1e-3)
 
@@ -107,7 +124,7 @@ def test_reduce_the_recorded_tpu_trace():
         planes = json.load(f)
     with open(os.path.join(DATA, "recorded_planes.expect.json")) as f:
         expect = json.load(f)
-    got = trace.reduce_planes(planes, n_layers=expect["n_layers"])
+    got = trace.reduce_planes(planes, MARKER, expect["n_layers"])
     assert got["chips"] == expect["chips"]
     for key in ("window_s", "busy_s", "decode_step_ms"):
         assert got[key] == pytest.approx(expect[key], rel=1e-6), key
