@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
 """graphcheck gate (ISSUE 11) — fails on ANY graph-invariant finding.
 
-Tier-1 wiring next to lint_gate.py / bench_guard.py (tests/
-test_graphcheck.py runs it): Pass A lowers the full preset × topology
+Tier-1 wiring next to lint_gate.py (tests/test_graphcheck.py runs it):
+Pass A lowers the full preset × topology
 matrix on a forced 8-device CPU mesh and verifies sharding / dtype /
 donation / closed-signature invariants; Pass B gates the SHD/DTY AST
 rules against the triaged lint baseline. Unlike the lint ratchet there

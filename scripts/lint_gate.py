@@ -1,10 +1,10 @@
 #!/usr/bin/env python3
 """tpu9lint ratchet gate (ISSUE 7) — fails on any NEW finding.
 
-The fast suite runs this next to bench_guard.py (tests/test_lint.py): the
-triaged debt lives in scripts/lint_baseline.json, inline ``# tpu9:
-noqa[RULE] reason`` suppressions cover reviewed sites, and anything else is
-a regression that fails CI. Gate semantics (scoped stale filtering,
+The fast suite runs this (tests/test_lint.py): the triaged debt lives in
+scripts/lint_baseline.json, inline ``# tpu9: noqa[RULE] reason``
+suppressions cover reviewed sites, and anything else is a regression that
+fails CI. Gate semantics (scoped stale filtering,
 baseline updates that preserve out-of-scope triage, ``--strict-stale``)
 are shared with wire_gate.py via tpu9/analysis/gatelib.py.
 

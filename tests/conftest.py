@@ -20,9 +20,97 @@ from tpu9.utils import force_cpu  # noqa: E402
 force_cpu(host_devices=8)
 
 import asyncio  # noqa: E402
+import glob  # noqa: E402
 import inspect  # noqa: E402
+import shutil  # noqa: E402
+import subprocess  # noqa: E402
+import tempfile  # noqa: E402
 
 import pytest  # noqa: E402
+
+NATIVE_DIR = str(Path(__file__).resolve().parent.parent / "native")
+HAVE_TOOLCHAIN = bool(shutil.which("g++") and shutil.which("make"))
+
+
+def pytest_configure(config):
+    """Build the native components once, BEFORE collection, so that what a
+    run collects does not depend on whether an earlier run on the same tree
+    left ``native/build/`` behind: the ``skipif(not ...supported())`` marks
+    of the native-runtime and CacheFS files look for its binaries while
+    they are imported. Only the controller builds — xdist workers are
+    spawned after this hook and must not race ``make``. A build that breaks
+    where a toolchain exists fails the run; it does not skip tests."""
+    if hasattr(config, "workerinput"):
+        return
+    if not (config.option.collectonly or config.option.setupplan):
+        config._tpu9_tmp_before = _stack_leftovers()
+    if not HAVE_TOOLCHAIN:
+        return
+    done = subprocess.run(["make", "-C", NATIVE_DIR], capture_output=True,
+                          text=True)
+    if done.returncode != 0:
+        raise pytest.UsageError(
+            f"make -C native failed:\n{done.stdout}{done.stderr}")
+
+
+def _stack_leftovers() -> set:
+    """Directories the program makes with ``mkdtemp`` and no owner removes:
+    a worker's object cache (``tpu9 worker``, every e2e stack) and an
+    engine's default profile dir."""
+    tmp = tempfile.gettempdir()
+    return {d for prefix in ("tpu9-objects-", "tpu9-profile-")
+            for d in glob.glob(os.path.join(tmp, prefix + "*"))}
+
+
+def pytest_sessionfinish(session):
+    """Remove the temp dirs THIS run's stacks left (not those that stood
+    before it: they may belong to a live worker), so that a thousand runs
+    on one machine do not fill its temp dir. Controller only: it outlives
+    every xdist worker. A session that runs no test (``--collect-only``,
+    ``--setup-plan``: test_native.py starts one inside a run) took no
+    snapshot and removes nothing. Two sessions that RUN tests at once must
+    not share a temp dir: each would take the other's for its own."""
+    before = getattr(session.config, "_tpu9_tmp_before", None)
+    if before is None:
+        return
+    for d in _stack_leftovers() - before:
+        shutil.rmtree(d, ignore_errors=True)
+
+
+@pytest.fixture(scope="session")
+def built():
+    """The native build dir, for a test that cannot run without it: skipped
+    on a host with no toolchain, FAILED where there is one and a component
+    is missing — ``pytest_configure`` built them, so the build broke."""
+    if not HAVE_TOOLCHAIN:
+        pytest.skip("no C++ toolchain")
+    build = os.path.join(NATIVE_DIR, "build")
+    for name in ("t9proc", "t9container", "t9cachefs", "t9cdi",
+                 "t9lazy_preload.so", "vcache_preload.so"):
+        assert os.path.exists(os.path.join(build, name)), f"{name} not built"
+    return build
+
+
+# sizeof(sockaddr_un.sun_path) - 1 on Linux, and the longest path a socket
+# gets below its base dir: CacheFS's ``/fuse/<32>-<8>.fault.sock`` (58; the
+# lazy fill's ``/bundles/.sock/img-<16>/fill.sock`` is 45)
+_SUN_PATH_MAX = 107
+_SOCKET_TAIL = 58
+
+
+@pytest.fixture
+def short_tmp():
+    """``tmp_path`` for a test whose code binds unix sockets below it (the
+    lazy-fill and CacheFS fault sockets live under the work dir): pytest's
+    ``tmp_path`` carries the test's name and, under xdist, ``popen-gwN/``,
+    and overflows ``sun_path`` ("AF_UNIX path too long", or a FUSE mount
+    that never comes up). This one is short under any number of workers,
+    and says so itself if it ever is not."""
+    base = tempfile.mkdtemp(dir="/tmp", prefix="t9")
+    assert len(base) + _SOCKET_TAIL <= _SUN_PATH_MAX, \
+        f"{base}: a socket {_SOCKET_TAIL} bytes below it overflows sun_path"
+    yield Path(base)
+    shutil.rmtree(base, ignore_errors=True)
 
 
 def pytest_collection_modifyitems(config, items):

@@ -141,45 +141,3 @@ def test_unknown_preset_raises():
         resolve_preset("llama-nope")
     cfg, q = resolve_preset("llama3-8b-int8")
     assert q and cfg.n_layers == 32
-
-
-def test_compact_line_is_small_and_complete():
-    """VERDICT r03 #1a: the driver's tail capture truncated the r03 output
-    line mid-JSON and the headline was lost. The final line must stay
-    compact regardless of how much evidence the run produced."""
-    import importlib.util
-    import json
-    import os
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..", "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    detail = {
-        "backend": "tpu", "on_tpu": True, "model": "llama3-8b-int8",
-        "endpoint_tokens_per_sec_per_chip": 1234.5,
-        "endpoint_served_proof_ok": True,
-        "endpoint_container_on_tpu": True,
-        "endpoint_physics": {"mbu": 0.61, "mfu": 0.05},
-        "cold_start_p50_s": 0.9,
-        "validation": {"violations": [], "ok": True},
-        # a huge evidence blob that must NOT reach the stdout line
-        "phase_timeline": {f"phase{i}": {"p50": 0.1} for i in range(500)},
-    }
-    line = bench.compact_line(detail)
-    assert len(json.dumps(line)) < 2000
-    assert line["metric"] == "endpoint_tokens_per_sec_per_chip"
-    assert line["extra"]["backend"] == "tpu"
-    assert line["extra"]["endpoint_served_proof_ok"] is True
-    assert "phase_timeline" not in line["extra"]
-
-    # CPU fallback keeps cold start as the headline
-    cpu_detail = {"backend": "cpu", "cold_start_p50_s": 0.9,
-                  "validation": {"violations": [], "ok": True}}
-    line = bench.compact_line(cpu_detail)
-    assert line["metric"] == "cold_start_p50_s"
-
-    # a TPU number whose served proof failed must NOT become the headline
-    bad = dict(detail)
-    bad["endpoint_served_proof_ok"] = False
-    assert bench.compact_line(bad)["metric"] == "cold_start_p50_s"

@@ -197,7 +197,8 @@ def test_decode_programs_carry_the_pool_whole_on_a_described_v5e(
     from tpu9.serving.shard.policy import MeshPolicy
     monkeypatch.setattr(attention_ops, "on_tpu", lambda: True)
     config = manifest.load_config(manifest.load(), configuration)
-    cfg = serve.decoder_config(serve.model_sizes(config))
+    family = manifest.family(config)
+    cfg = family.program_config(family.model_sizes(config))
     ecfg = serve.engine_config(config["engine"])
     topology = parse_topology(config["engine"]["topology"])
     policy = MeshPolicy(topology, devices=v5e[:topology.n_chips])

@@ -24,10 +24,10 @@ pytestmark = [
 ]
 
 
-async def _setup(tmp_path, populate_store: bool):
+async def _setup(short_tmp, populate_store: bool):
     """A manifest over a small tree; chunks live either in the local store
     (warm) or only behind the client's source fn (cold → fault path)."""
-    src = tmp_path / "src"
+    src = short_tmp / "src"
     (src / "sub").mkdir(parents=True)
     big = os.urandom(5 * 1024 * 1024 + 333)       # spans chunks
     (src / "sub" / "weights.bin").write_bytes(big)
@@ -39,7 +39,7 @@ async def _setup(tmp_path, populate_store: bool):
                             put_chunk=lambda d, h: origin.__setitem__(h, d))
     manifest.image_id = "cfs-test"
 
-    store = DiskStore(str(tmp_path / "store"))
+    store = DiskStore(str(short_tmp / "store"))
 
     async def peers():
         return []
@@ -54,10 +54,10 @@ async def _setup(tmp_path, populate_store: bool):
     return manifest, client, big
 
 
-async def test_warm_mount_reads_and_mmap(tmp_path):
-    manifest, client, big = await _setup(tmp_path, populate_store=True)
-    mgr = CacheFsManager(client, str(tmp_path / "fuse"))
-    mnt = str(tmp_path / "mnt")
+async def test_warm_mount_reads_and_mmap(short_tmp):
+    manifest, client, big = await _setup(short_tmp, populate_store=True)
+    mgr = CacheFsManager(client, str(short_tmp / "fuse"))
+    mnt = str(short_tmp / "mnt")
     mount = await mgr.mount(manifest, mnt)
     try:
         assert sorted(os.listdir(mnt)) == ["hello.txt", "link.txt", "sub"]
@@ -80,12 +80,12 @@ async def test_warm_mount_reads_and_mmap(tmp_path):
         await mgr.close()
 
 
-async def test_cold_mount_faults_chunks_through_cache(tmp_path):
+async def test_cold_mount_faults_chunks_through_cache(short_tmp):
     """Chunks absent from the local store: reads must fault them in via
     the socket → CacheClient → source, then succeed with correct bytes."""
-    manifest, client, big = await _setup(tmp_path, populate_store=False)
-    mgr = CacheFsManager(client, str(tmp_path / "fuse"))
-    mnt = str(tmp_path / "mnt")
+    manifest, client, big = await _setup(short_tmp, populate_store=False)
+    mgr = CacheFsManager(client, str(short_tmp / "fuse"))
+    mnt = str(short_tmp / "mnt")
     mount = await asyncio.wait_for(mgr.mount(manifest, mnt), 30)
     try:
         p = os.path.join(mnt, "sub", "weights.bin")
@@ -117,17 +117,17 @@ async def test_cold_mount_faults_chunks_through_cache(tmp_path):
         await mgr.close()
 
 
-async def test_missing_chunk_is_eio_not_zeros(tmp_path):
+async def test_missing_chunk_is_eio_not_zeros(short_tmp):
     """A chunk nobody can produce must fail the read loudly — never
     silently serve placeholder zeros."""
-    manifest, client, _ = await _setup(tmp_path, populate_store=False)
+    manifest, client, _ = await _setup(short_tmp, populate_store=False)
 
     async def broken_source(digest):
         return None
 
     client.source = broken_source
-    mgr = CacheFsManager(client, str(tmp_path / "fuse"))
-    mnt = str(tmp_path / "mnt")
+    mgr = CacheFsManager(client, str(short_tmp / "fuse"))
+    mnt = str(short_tmp / "mnt")
     mount = await mgr.mount(manifest, mnt)
     try:
         def read_all():
@@ -141,7 +141,7 @@ async def test_missing_chunk_is_eio_not_zeros(tmp_path):
         await mgr.close()
 
 
-async def test_lazy_oci_bundle_is_fuse_mounted(tmp_path):
+async def test_lazy_oci_bundle_is_fuse_mounted(short_tmp):
     """OCI rootfs manifests ≥ the lazy threshold become FUSE mounts (the
     overlay lowerdir streams on demand) instead of eager materialization —
     closing the 'OCI images stay eager' gap."""
@@ -150,7 +150,7 @@ async def test_lazy_oci_bundle_is_fuse_mounted(tmp_path):
     from tpu9.images.manifest import snapshot_dir
     from tpu9.images.puller import ImagePuller
 
-    src = tmp_path / "tree"
+    src = short_tmp / "tree"
     (src / "rootfs" / "usr").mkdir(parents=True)
     payload = os.urandom(3 * 1024 * 1024)
     (src / "rootfs" / "usr" / "big.bin").write_bytes(payload)
@@ -162,7 +162,7 @@ async def test_lazy_oci_bundle_is_fuse_mounted(tmp_path):
     manifest.kind = "oci"
     manifest.env = {"FROM_IMAGE": "1"}
 
-    store = DiskStore(str(tmp_path / "store"))
+    store = DiskStore(str(short_tmp / "store"))
 
     async def peers():
         return []
@@ -171,8 +171,8 @@ async def test_lazy_oci_bundle_is_fuse_mounted(tmp_path):
         return origin.get(digest)
 
     client = CacheClient(store, peers, source=source)
-    mgr = CacheFsManager(client, str(tmp_path / "fuse"))
-    puller = ImagePuller(client, str(tmp_path / "bundles"),
+    mgr = CacheFsManager(client, str(short_tmp / "fuse"))
+    puller = ImagePuller(client, str(short_tmp / "bundles"),
                          lazy_threshold=1024 * 1024, fusefs=mgr)
 
     bundle = await puller.pull("img-ocilazy", manifest=manifest)
@@ -185,7 +185,7 @@ async def test_lazy_oci_bundle_is_fuse_mounted(tmp_path):
         # overlay over the FUSE lowerdir: the exact shape NativeRuntime
         # mounts for OCI bundles (rootfs as lowerdir)
         lower = os.path.join(bundle, "rootfs")
-        upper, work, merged = (str(tmp_path / d) for d in
+        upper, work, merged = (str(short_tmp / d) for d in
                                ("up", "wk", "mg"))
         for d in (upper, work, merged):
             os.makedirs(d)
@@ -214,4 +214,4 @@ async def test_lazy_oci_bundle_is_fuse_mounted(tmp_path):
         assert os.path.exists(os.path.join(bundle, ".tpu9-env.json"))
     finally:
         await puller.close()
-        shutil.rmtree(str(tmp_path / "bundles"), ignore_errors=True)
+        shutil.rmtree(str(short_tmp / "bundles"), ignore_errors=True)

@@ -92,16 +92,12 @@ def handler(op="", **kwargs):
 """
 
 
-async def test_lazy_image_container_starts_before_fill(tmp_path):
+async def test_lazy_image_container_starts_before_fill(tmp_path, built):
     """VERDICT r03 #3 e2e: with a lazy image, container.ready precedes full
     materialization, and an on-demand open of a streamed file returns
     correct bytes through the shim gate."""
     import hashlib
     import shutil
-    shim = os.path.join(os.path.dirname(__file__), "..", "native", "build",
-                        "t9lazy_preload.so")
-    if not os.path.exists(shim):
-        pytest.skip("t9lazy_preload.so not built")
 
     async with LocalStack() as stack:
         # workers are pool-created on demand and read cfg.cache at
@@ -146,9 +142,7 @@ async def test_lazy_image_container_starts_before_fill(tmp_path):
         import asyncio as aio
         await aio.wait_for(fill.wait(), 60)
         assert os.path.exists(os.path.join(bundle, ".tpu9-complete"))
-        # whether readiness beat the 12 MB fill is host-speed dependent;
-        # the strict GB-scale ready-before-complete guarantee lives in
-        # bench.py's coldstart_native phase. Here: the fill really
-        # streamed the payload.
+        # whether readiness beat the 12 MB fill is host-speed dependent:
+        # not asserted. Here: the fill really streamed the payload.
         assert fill.stats["bytes_streamed"] >= 12 * 2**20
         del ready_before_complete

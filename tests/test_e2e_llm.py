@@ -221,6 +221,14 @@ async def test_llm_token_streaming_sse():
             f"all {len(events)} events arrived in read "
             f"{read_of_event[-1]} of {reads} — stream was buffered")
 
+        # served proof, on the container's side: the runner's own counter
+        # on /health accounts for every token the two requests received
+        # (a first token is sampled by its prefill: counted once a request)
+        status, health = await stack.api("GET", "/endpoint/llm-sse/health")
+        assert status == 200, health
+        assert int(health["tokens_generated"]) + 2 \
+            >= len(warm["tokens"]) + len(toks), health
+
 
 @pytest.mark.slow
 async def test_llm_streaming_scales_from_zero():

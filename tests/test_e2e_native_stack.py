@@ -72,17 +72,13 @@ def handler(op="", **kwargs):
 """
 
 
-def test_lazy_image_under_native_containment(monkeypatch):
+def test_lazy_image_under_native_containment(monkeypatch, built):
     """Lazy-streamed image + netns + ro bundle bind + dropped uid all at
     once: the shim's fault socket must be reachable from inside the netns
-    (fs socket over the rw .sock bind) and the gated read must return real
-    bytes."""
+    (fs socket over the rw bind of its directory) and the gated read must
+    return real bytes."""
     import hashlib
     import shutil
-    shim = os.path.join(os.path.dirname(__file__), "..", "native", "build",
-                        "t9lazy_preload.so")
-    if not os.path.exists(shim):
-        pytest.skip("t9lazy_preload.so not built")
     monkeypatch.setenv("TPU9_RUNTIME", "native")
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     from tpu9.testing.localstack import LocalStack

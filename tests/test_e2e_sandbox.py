@@ -183,18 +183,13 @@ async def test_run_code_via_spawned_python():
         assert chunk["exit_code"] == 0
 
 
-async def test_t9proc_is_pid1_and_reaps_zombies():
+async def test_t9proc_is_pid1_and_reaps_zombies(built):
     """VERDICT r03 #7 'Done' criteria: sandbox processes run under the
     t9proc supervisor (not nsenter-style exec) and orphaned children are
     reaped — no zombies accumulate under the container's init."""
     import base64
     import os
     import shutil
-
-    t9proc = os.path.join(os.path.dirname(__file__), "..", "native",
-                          "build", "t9proc")
-    if not os.path.exists(t9proc):
-        pytest.skip("t9proc not built")
 
     async with LocalStack() as stack:
         cid = await make_sandbox(stack)

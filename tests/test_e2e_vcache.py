@@ -2,17 +2,12 @@
 containers (node-cache copy wins over the volume path)."""
 
 import os
-import shutil
-import subprocess
 
 import pytest
 
 from tpu9.testing.localstack import LocalStack
 
 pytestmark = pytest.mark.e2e
-
-NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "native")
-SHIM = os.path.join(NATIVE_DIR, "build", "vcache_preload.so")
 
 READER = """
 import os
@@ -23,12 +18,9 @@ def handler(path="", **kw):
 """
 
 
-@pytest.mark.skipif(shutil.which("g++") is None, reason="no C++ toolchain")
-async def test_volume_reads_hit_node_cache():
-    subprocess.run(["make", "-C", NATIVE_DIR], check=True,
-                   capture_output=True)
+async def test_volume_reads_hit_node_cache(built):
     async with LocalStack() as stack:
-        stack.cfg.worker.vcache_so = os.path.abspath(SHIM)
+        stack.cfg.worker.vcache_so = os.path.join(built, "vcache_preload.so")
         stack.cfg.worker.vcache_dir = os.path.join(stack.tmp.name, "vcache")
 
         ws = stack.gateway.default_workspace.workspace_id

@@ -40,7 +40,7 @@ def _cfg(tmp_path) -> AppConfig:
     return cfg
 
 
-async def test_volume_cachefs_mount_reads_and_writes_back(tmp_path):
+async def test_volume_cachefs_mount_reads_and_writes_back(short_tmp):
     from tpu9.cache import CacheClient, DiskStore
     from tpu9.images.manifest import ImageManifest
     from tpu9.repository import ContainerRepository
@@ -50,7 +50,7 @@ async def test_volume_cachefs_mount_reads_and_writes_back(tmp_path):
     from tpu9.worker.lifecycle import ContainerLifecycle
     from tpu9.worker.tpu_manager import TpuDeviceManager
 
-    gw = Gateway(_cfg(tmp_path), store=MemoryStore())
+    gw = Gateway(_cfg(short_tmp), store=MemoryStore())
     await gw.start()
     base_url = f"http://127.0.0.1:{gw.port}"
     ws_id = gw.default_workspace.workspace_id
@@ -92,15 +92,15 @@ async def test_volume_cachefs_mount_reads_and_writes_back(tmp_path):
     async def peers():
         return []
 
-    store = DiskStore(str(tmp_path / "chunkstore"))
+    store = DiskStore(str(short_tmp / "chunkstore"))
     client = CacheClient(store, peers, source=source)
-    fusefs = CacheFsManager(client, str(tmp_path / "fuse"))
+    fusefs = CacheFsManager(client, str(short_tmp / "fuse"))
     mounter = VolumeMounter(fusefs, volume_manifest, volume_push,
-                            str(tmp_path / "volmounts"),
+                            str(short_tmp / "volmounts"),
                             min_bytes=1024 * 1024)
 
-    cfg = WorkerConfig(containers_dir=str(tmp_path / "c"),
-                       storage_root=str(tmp_path / "unshared"),
+    cfg = WorkerConfig(containers_dir=str(short_tmp / "c"),
+                       storage_root=str(short_tmp / "unshared"),
                        storage_shared=False)
     lc = ContainerLifecycle(
         "w1", cfg, ProcessRuntime(base_dir=cfg.containers_dir),

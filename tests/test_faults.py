@@ -1,7 +1,7 @@
 """Unit tests for the deterministic fault-injection plane (ISSUE 15):
 spec parsing, trigger arithmetic, seeded reproducibility, flag-file
 arming, and engine-instance instrumentation — the plane the chaos e2e
-and ``bench.py --phase faults`` drive."""
+tests drive."""
 
 import os
 

@@ -111,7 +111,10 @@ def test_engine_records_admits_and_windows(tiny):
 def test_engine_spans_under_remote_context(tiny):
     from tpu9.observability.trace import tracer
     eng = _engine(tiny)
-    trace_id, parent = "ab" * 16, "cd" * 8
+    # an id no other file uses: the tracer is process-wide, and whichever
+    # test files share an xdist worker share its ring (test_phases.py
+    # traces "ab" * 16 with a 100-token prompt)
+    trace_id, parent = "f1" * 16, "cd" * 8
 
     async def go():
         await eng.start()

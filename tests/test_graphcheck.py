@@ -2,7 +2,7 @@
 (each seeded violation must produce exactly its rule's finding), the
 recompile sentinel, the json schema round-trip, the new boundary edges,
 and the tier-1 gate itself (this test IS the wiring, next to
-test_lint.py / test_bench_guard.py)."""
+test_lint.py)."""
 
 import ast
 import json

@@ -106,20 +106,20 @@ def _make_cache(tmp_path, builder):
     return CacheClient(store, peers, source=source)
 
 
-async def test_lazy_pull_skeleton_then_fill(tmp_path):
+async def test_lazy_pull_skeleton_then_fill(short_tmp):
     import asyncio
     import hashlib
 
     from tpu9.images.builder import ImageBuilder
 
-    builder = ImageBuilder(str(tmp_path / "registry"))
+    builder = ImageBuilder(str(short_tmp / "registry"))
     spec = ImageSpec(commands=[
         "mkdir -p env && for i in 1 2 3 4; do "
         "head -c 2097152 /dev/urandom > env/f$i.bin; done "
         "&& echo small > env/tiny.txt && ln -s tiny.txt env/link.txt"])
     manifest = await builder.build(spec)
-    client = _make_cache(tmp_path, builder)
-    puller = ImagePuller(client, str(tmp_path / "bundles"),
+    client = _make_cache(short_tmp, builder)
+    puller = ImagePuller(client, str(short_tmp / "bundles"),
                          lazy_threshold=1)   # force lazy
 
     bundle = await puller.pull(spec.image_id, manifest=manifest)
@@ -161,23 +161,24 @@ async def test_lazy_pull_skeleton_then_fill(tmp_path):
     await client.close()
 
 
-async def test_lazy_pull_restarts_after_crash(tmp_path):
+async def test_lazy_pull_restarts_after_crash(short_tmp):
     """No completion marker on disk → the next pull must re-skeleton and
     refill rather than trusting half-written placeholders."""
     from tpu9.images.builder import ImageBuilder
 
-    builder = ImageBuilder(str(tmp_path / "registry"))
+    builder = ImageBuilder(str(short_tmp / "registry"))
     spec = ImageSpec(commands=["mkdir -p env && echo hello > env/a.txt"])
     manifest = await builder.build(spec)
-    client = _make_cache(tmp_path, builder)
+    client = _make_cache(short_tmp, builder)
 
     # simulate a crashed fill: placeholders present, no marker
-    dest = os.path.join(str(tmp_path / "bundles"), spec.image_id)
+    dest = os.path.join(str(short_tmp / "bundles"), spec.image_id)
     os.makedirs(os.path.join(dest, "env"), exist_ok=True)
     with open(os.path.join(dest, "env", "a.txt"), "wb") as f:
         f.truncate(6)
 
-    puller = ImagePuller(client, str(tmp_path / "bundles"), lazy_threshold=1)
+    puller = ImagePuller(client, str(short_tmp / "bundles"),
+                         lazy_threshold=1)
     bundle = await puller.pull(spec.image_id, manifest=manifest)
     fill = puller.active_fill(spec.image_id)
     if fill is not None:

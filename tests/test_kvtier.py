@@ -134,6 +134,32 @@ def test_downpage_moves_entry_to_host_and_frees_blocks():
     assert ts["host_bytes"] > 0
 
 
+def test_eviction_storm_keeps_prefixes_findable_only_with_a_host_tier():
+    """Twenty two-block prefixes through a sixteen-block pool, each wave
+    of pressure spilling what it can before it evicts: with a host tier
+    more of them are still findable at the end than the untiered pool
+    keeps (which destroys what it evicts)."""
+    def alive_after_storm(host_mb: int) -> int:
+        pool, kv = _pool(host_mb=host_mb, prefix_cache_blocks=12)
+        keys = []
+        for i in range(20):
+            blocks = pool.alloc_blocks(2)
+            tokens = [(i * 97 + j) % 241 + 1 for j in range(2 * BS)]
+            pool.prefix_cache.insert(tokens, blocks)
+            pool.allocator.release(blocks)
+            keys.append(PrefixCache._key(tokens))
+            if pool.allocator.free_count < 6:
+                if pool.tiered:
+                    for entry in pool.prefix_cache.spill_candidates(2):
+                        pool.downpage(kv, entry)
+                pool.prefix_cache.evict_for_space(4)
+        return sum(pool.prefix_cache.contains(k) for k in keys)
+
+    untiered, tiered = alive_after_storm(0), alive_after_storm(64)
+    assert untiered < 20            # the storm really evicted
+    assert tiered > untiered, (tiered, untiered)
+
+
 def test_downpage_never_moves_a_pinned_entry():
     """Down-page vs lookup pin: an admission holding the lookup pin is
     about to retain the blocks — moving them mid-splice would hand it a

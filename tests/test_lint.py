@@ -1,6 +1,6 @@
 """tpu9lint (ISSUE 7): rule fixtures, suppression/baseline round-trips, the
 boundaries.toml-vs-reality check, and the repo gate itself (this test IS the
-tier-1 wiring, next to test_bench_guard.py)."""
+tier-1 wiring)."""
 
 import ast
 import json
@@ -815,7 +815,7 @@ def test_slo_observability_contracts_declared_and_live():
     """ISSUE 12 satellite: the fleet SLO/timeline modules carry explicit
     boundary contracts — observability is a closed leaf (no reverse edge
     into serving/router/gateway), and the slo/timeline modules are
-    restricted to the control plane + CLI + bench. The cross-check test
+    restricted to the control plane + CLI. The cross-check test
     above asserts these against the real import graph; this one asserts
     they are DECLARED (a deleted contract must fail loudly, not vacuously
     pass) and still live."""
@@ -845,7 +845,7 @@ def test_health_plane_contract_declared_and_live():
     """ISSUE 14 satellite: the replica health plane is a closed leaf —
     the watchdog/black-box module is restricted to the runner (watchdog
     on the heartbeat loop), the gateway (verdict fold + black-box store),
-    the CLI and bench; the serving engine and the router must NOT import
+    and the CLI; the serving engine and the router must NOT import
     it (they exchange plain scalars over the heartbeat). Declared here,
     asserted against the real import graph by the cross-check test."""
     cfg = bnd.BoundaryConfig.load(
@@ -880,7 +880,7 @@ def test_health_plane_contract_declared_and_live():
 def test_fault_plane_contract_declared_and_live():
     """ISSUE 15 satellite: the fault-injection plane is chaos tooling —
     restricted to its declared hook sites (runner/worker/cache, all
-    env-gated lazy imports), the test plane and bench. The gateway/
+    env-gated lazy imports) and the test plane. The gateway/
     router/serving planes must never import it: the recovery machinery
     under test cannot depend on the failure injector."""
     cfg = bnd.BoundaryConfig.load(
@@ -923,16 +923,16 @@ def test_fault_plane_contract_declared_and_live():
 def test_kvwire_contract_declared_and_live():
     """ISSUE 16 satellite: the KV wire format is a serialization boundary
     — restricted to the two ends of the pipe (serving encodes/decodes,
-    the runner moves payloads between transport and engine), the cache
-    transport and bench. The gateway and router must NEVER import it:
-    they speak keys/flags/token counts, and a payload crossing the
+    the runner moves payloads between transport and engine) and the
+    cache transport. The gateway and router must NEVER import it: they
+    speak keys/flags/token counts, and a payload crossing the
     control plane is exactly the layering bug this contract catches."""
     cfg = bnd.BoundaryConfig.load(
         os.path.join(REPO, "tpu9", "analysis", "boundaries.toml"))
     rmod = "tpu9.serving.kvwire"
     assert rmod in cfg.restricted
     importers = cfg.restricted[rmod]
-    for needed in ("tpu9.serving", "tpu9.runner", "tpu9.cache", "bench"):
+    for needed in ("tpu9.serving", "tpu9.runner", "tpu9.cache"):
         assert needed in importers, importers
     for banned in ("tpu9.gateway", "tpu9.router"):
         assert not any(i == banned or i.startswith(banned + ".")
@@ -960,7 +960,7 @@ def test_scaleout_contract_declared_and_live():
     config/utils; never serving, router, gateway or worker: the planes
     CALL it, it calls nobody back), and a [restricted] list names its
     only importers (gateway coordinator host, abstractions predictive
-    wrapper, CLI tree-hint bootstrap, bench). Declared here, asserted
+    wrapper, CLI tree-hint bootstrap). Declared here, asserted
     against the real import graph by the cross-check test above."""
     cfg = bnd.BoundaryConfig.load(
         os.path.join(REPO, "tpu9", "analysis", "boundaries.toml"))
@@ -970,8 +970,7 @@ def test_scaleout_contract_declared_and_live():
         assert banned not in cfg.allow["tpu9.scaleout"]
     assert "tpu9.scaleout" in cfg.restricted
     importers = cfg.restricted["tpu9.scaleout"]
-    for needed in ("tpu9.gateway", "tpu9.abstractions", "tpu9.cli",
-                   "bench"):
+    for needed in ("tpu9.gateway", "tpu9.abstractions", "tpu9.cli"):
         assert needed in importers, importers
     # the serving engine ships flat scaleout_* scalars over the
     # heartbeat and the router parses plain stats — no import edge

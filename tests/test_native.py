@@ -11,18 +11,8 @@ import sys
 
 import pytest
 
-NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "native")
-BUILD_DIR = os.path.join(NATIVE_DIR, "build")
-
 pytestmark = pytest.mark.skipif(shutil.which("g++") is None,
                                 reason="no C++ toolchain")
-
-
-@pytest.fixture(scope="module")
-def built():
-    subprocess.run(["make", "-C", NATIVE_DIR], check=True,
-                   capture_output=True)
-    return BUILD_DIR
 
 
 def test_vcache_redirects_cached_reads(built, tmp_path):
@@ -191,3 +181,42 @@ def test_t9cdi_sparse_and_vfio_only_hosts(built, tmp_path):
                         capture_output=True, text=True)
     assert rc.returncode == 2
     assert "refusing" in rc.stderr
+
+
+def _unskipped(root: str) -> list[str]:
+    """The tests of test_native_runtime.py that a run from ``root`` would
+    not skip: ``--setup-plan`` evaluates the ``skipif`` marks and lists
+    the rest without running them."""
+    out = subprocess.run(
+        [sys.executable, "-m", "pytest", "tests/test_native_runtime.py",
+         "--setup-plan", "-q", "-p", "no:cacheprovider", "-p", "no:xdist"],
+        cwd=root, capture_output=True, text=True, timeout=300)
+    assert out.returncode in (0, 5), out.stdout + out.stderr
+    return sorted(ln.split()[0] for ln in out.stdout.splitlines()
+                  if ln.strip().startswith("tests/test_native_runtime.py::"))
+
+
+def test_collection_does_not_depend_on_a_left_over_build(built, tmp_path):
+    """The count of tier-1 is a function of the commit: a checkout that no
+    run has built yet (``native/build/`` is git-ignored) selects the same
+    tests as a built tree, because ``pytest_configure`` builds before the
+    ``skipif(not NativeRuntime.supported())`` marks are evaluated."""
+    from tpu9.runtime import NativeRuntime
+    if not NativeRuntime.supported():
+        pytest.skip("needs root + ip: the marks skip on either tree")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    fresh = tmp_path / "checkout"
+    (fresh / "tests").mkdir(parents=True)
+    shutil.copytree(os.path.join(repo, "native"), fresh / "native",
+                    ignore=shutil.ignore_patterns("build"))
+    for name in ("conftest.py", "test_native_runtime.py"):
+        shutil.copy(os.path.join(repo, "tests", name), fresh / "tests")
+    shutil.copy(os.path.join(repo, "pyproject.toml"), fresh)
+    # the package by link: native_binary() resolves under the checkout
+    # the package is imported from, and abspath keeps the link
+    os.symlink(os.path.join(repo, "tpu9"), fresh / "tpu9")
+    assert not (fresh / "native" / "build").exists()
+    here = _unskipped(repo)
+    assert len(here) >= 8, here
+    assert _unskipped(str(fresh)) == here
+    assert (fresh / "native" / "build" / "t9container").exists()

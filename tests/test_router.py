@@ -10,6 +10,8 @@ import hashlib
 import json
 import time
 
+import pytest
+
 from tpu9.config import RouterConfig
 from tpu9.abstractions.common.buffer import ForwardResult
 from tpu9.router import (AffinityRouter, FleetRouter, QueuedRequest,
@@ -421,6 +423,50 @@ async def test_same_prefix_routes_to_same_replica():
     # the recorded replica
     assert len(set(chosen[1:])) == 1
     assert router.affinity.stats()["hits"] >= 4
+
+
+@pytest.mark.parametrize("disagg", [True, False], ids=["on", "off"])
+async def test_disagg_biases_long_prompts_to_the_prefill_partition(disagg):
+    """Disaggregated placement (ISSUE 16) through the real router over a
+    mixed queue: with it on, prompts past ``disagg_prefill_tokens`` land on
+    the prefill partition (sorted ids, the first ceil(0.5 x 4) = r0, r1),
+    short chats and KV-adopting resubmissions on the decode rest; with it
+    off, placement ignores the split. Nothing is shed either way."""
+    router = make_router(cids=("r0", "r1", "r2", "r3"),
+                         disagg_enabled=disagg, disagg_prefill_tokens=512,
+                         disagg_prefill_fraction=0.5, max_queue_depth=10000)
+    stub = make_stub()
+    placed = {"long": [], "short": [], "adopt": []}
+
+    def fwd(kind):
+        async def forward(prefer):
+            placed[kind].append(prefer[0])
+            return ForwardResult(status=200, body=b"{}",
+                                 container_id=prefer[0])
+        return forward
+
+    async def one(i):
+        kind = ("long", "short", "short", "adopt", "short")[i % 5]
+        n = 48 if kind == "short" else 640
+        payload = {"tokens": [(i * 17 + j) % 251 + 1 for j in range(n)],
+                   "max_new_tokens": 16}
+        if kind == "adopt":
+            payload["adopt_kv"] = {"key": "k", "n_tokens": 600}
+        res = await router.submit(stub, "mix", json.dumps(payload).encode(),
+                                  fwd(kind))
+        return res.status
+
+    statuses = await asyncio.gather(*[one(i) for i in range(60)])
+    await router.stop()
+    assert statuses.count(200) == 60
+    prefill, decode = {"r0", "r1"}, {"r2", "r3"}
+    if disagg:
+        assert set(placed["long"]) <= prefill, placed["long"]
+        assert set(placed["short"]) <= decode, placed["short"]
+        assert set(placed["adopt"]) <= decode, placed["adopt"]
+    else:
+        # no split: short chats are not kept off r0 / r1
+        assert set(placed["short"]) & prefill, placed["short"]
 
 
 async def test_drain_replica_stops_routing_and_waits_for_inflight():

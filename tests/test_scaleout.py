@@ -653,3 +653,116 @@ async def test_restore_params_replans_mid_transfer_onto_survivor(
         for cl, srv in holders:
             await cl.close()
             await srv.stop()
+
+
+async def test_wave_of_joiners_costs_the_holder_its_fanout_not_its_count(
+        tmp_path):
+    """One holder, three joiners, ``tree_fanout`` 1, over real ChunkServers
+    and the real coordinator: the plan chains the joiners (holder → j → j →
+    j), each restores along its planned edge and re-serves what it took.
+    The holder ships ONE copy of the checkpoint however many join, every
+    other copy comes off a joiner, and the source tier is never touched —
+    the bytes at the top of the tree are O(fanout), not O(N)."""
+    import numpy as np
+
+    from tpu9.serving import weights as wfmt
+    from tpu9.worker.checkpoint import CheckpointManager
+
+    src = tmp_path / "src"
+    rng = np.random.default_rng(5)
+    for g in range(2):
+        wfmt.save_params(
+            {"w": [rng.standard_normal(16384, dtype=np.float32)
+                   for _ in range(2)]}, str(src / f"g{g}.tpu9w"))
+    manifests = {}
+
+    async def record(stub, ws, cid):
+        return "ckpt"
+
+    async def store_manifest(cid, blob):
+        manifests[cid] = blob
+
+    async def fetch_manifest(cid):
+        return manifests.get(cid)
+
+    async def no_peers():
+        return []
+
+    async def source(digest):
+        raise AssertionError("source tier touched with a live holder")
+
+    def ident(entry, arr):
+        return arr
+
+    async def serve(client, store):
+        srv = await ChunkServer(store, groups_fn=lambda: client.groups
+                                ).start()
+        client.self_address = srv.address
+        return srv
+
+    seed_store = DiskStore(str(tmp_path / "seed"))
+    seed = CacheClient(seed_store, no_peers)
+    seed_cm = CheckpointManager(seed, record=record,
+                                store_manifest=store_manifest,
+                                fetch_manifest=fetch_manifest)
+    assert await seed_cm.create("s", "w", "seed", str(src))
+    trees, _ = await seed_cm.restore_params("ckpt", device_put=ident)
+    assert len(trees) == 2
+    servers = [await serve(seed, seed_store)]
+    keys = sorted(seed.groups)
+
+    holding: set = set()
+
+    async def fleet():
+        # the peers a joiner knows: the replicas that hold the checkpoint
+        return sorted(holding)
+
+    joiners = []
+    for i in range(3):
+        store = DiskStore(str(tmp_path / f"j{i}"))
+        # no hedge: every byte is attributed to the edge it came over
+        cl = CacheClient(store, fleet, source=source, hedge_delay_s=5.0)
+        servers.append(await serve(cl, store))
+        joiners.append(cl)
+
+    coord = ScaleoutCoordinator(ScaleoutConfig(tree_fanout=1))
+    coord.observe_worker("seed", {"cache": seed.snapshot()}, now=100.0)
+    for i, cl in enumerate(joiners):
+        coord.observe_worker(f"j{i}", {"cache": cl.snapshot()}, now=100.0)
+    plan = coord.refresh(now=100.0)
+    assert coord.stats()["source_edges"] == 0
+
+    def primary(cl):
+        return plan.parents(cl.self_address, keys[0])[0]
+
+    # restore in tree order: a joiner goes once its parent holds the group
+    served_by: dict = {}
+    holding.add(seed.self_address)
+    try:
+        while len(holding) < 4:
+            ready = [cl for cl in joiners
+                     if cl.self_address not in holding
+                     and primary(cl) in holding]
+            assert ready, "the plan does not chain off the holder"
+            for cl in ready:
+                async def hints(key, cl=cl):
+                    return plan.peer_prefs(cl.self_address, key)
+                cm = CheckpointManager(cl, fetch_manifest=fetch_manifest,
+                                       tree_hints=hints)
+                trees, metrics = await cm.restore_params(
+                    "ckpt", device_put=ident)
+                assert len(trees) == 2
+                assert cl.stats["bytes_source"] == 0
+                for addr, n in metrics["peer_bytes"].items():
+                    served_by[addr] = served_by.get(addr, 0) + n
+                holding.add(cl.self_address)
+    finally:
+        for cl in joiners + [seed]:
+            await cl.close()
+        for srv in servers:
+            await srv.stop()
+    one_copy = served_by[seed.self_address]
+    assert one_copy == 2 * 2 * 16384 * 4        # every shard, once
+    # three joiners took three copies, the holder shipped one of them
+    assert sum(served_by.values()) == 3 * one_copy
+    assert sorted(served_by.values()) == [one_copy] * 3

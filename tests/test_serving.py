@@ -66,7 +66,8 @@ async def test_streaming():
 
 
 async def test_stats_and_pressure():
-    eng = make_engine()
+    import asyncio
+    eng = make_engine(max_batch=4)
     await eng.start()
     try:
         await eng.generate([1, 2], max_new_tokens=4)
@@ -74,6 +75,15 @@ async def test_stats_and_pressure():
         assert s["tokens_generated"] >= 3
         assert 0.0 <= s["token_pressure"] <= 1.0
         assert s["active_streams"] == 0
+        # served proof: the engine's own counter accounts for every token
+        # its callers received (a request's first token is sampled by its
+        # prefill and is not in tokens_generated: counted once a request)
+        outs = await asyncio.gather(*[
+            eng.generate([3 + i, 5, 7], max_new_tokens=6) for i in range(6)])
+        received = sum(len(o) for o in outs)
+        assert received == 36
+        counted = eng.stats()["tokens_generated"] - s["tokens_generated"]
+        assert counted + len(outs) >= received, (counted, received)
     finally:
         await eng.stop()
 

@@ -31,11 +31,21 @@ def test_resume_payload_splices_at_the_watermark():
     assert body["temperature"] == 0          # extra payload keys survive
 
 
-def test_splice_produces_duplicate_free_sequence_across_a_kill():
+@pytest.mark.parametrize("prompt,max_new,die_after", [
+    ([3, 1, 4], 12, 5),
+    ([1, 1], 8, 1),            # killed after the first token
+    ([2, 4], 9, 8),            # killed with one token still owed
+    ([7, 5], 16, 9),
+    ([9, 11], 2, 1),           # the shortest stream a kill can split
+    ([12, 3], 13, 12),
+])
+def test_splice_produces_duplicate_free_sequence_across_a_kill(
+        prompt, max_new, die_after):
     """Simulate the whole failover: a deterministic 'model' generates
     f(prefix) token by token; the first replica dies mid-stream; the
     resumed attempt replays prompt+delivered and continues. The client
-    must see exactly the sequence an unkilled replica would have sent."""
+    must see exactly the sequence an unkilled replica would have sent,
+    wherever in the stream the kill lands."""
     def model_next(prefix: list) -> int:
         return (sum(prefix) * 31 + len(prefix)) % 997
 
@@ -49,16 +59,16 @@ def test_splice_produces_duplicate_free_sequence_across_a_kill():
             ctx.append(t)
         return toks, False
 
-    prompt, max_new = [3, 1, 4], 12
     reference, died = serve(prompt, max_new)
     assert not died
 
     res = sv.StreamResumption(prompt, max_new, {"tokens": prompt,
                                                 "max_new_tokens": max_new})
-    got, died = serve(prompt, max_new, die_after=5)
+    got, died = serve(prompt, max_new, die_after=die_after)
     for t in got:
         res.note_token(t)
-    assert died and res.watermark == 5 and res.remaining == 7
+    assert died and res.watermark == die_after \
+        and res.remaining == max_new - die_after
     body = json.loads(res.resume_payload())
     got2, died2 = serve(body["tokens"], body["max_new_tokens"])
     assert not died2
@@ -178,6 +188,94 @@ async def test_submit_with_failover_recovers_and_avoids_failed_replica():
     assert result.status == 200
     assert calls == [(1, set()), (2, {"r1"}), (3, {"r1", "r2"})]
     assert [f[1] for f in failovers] == ["r1", "r2"]
+
+
+async def test_seeded_chaos_through_the_router_loses_no_request():
+    """A fake three-replica fleet behind the REAL FleetRouter and the REAL
+    failover driver, under one seeded fault plan — crashes that keep a
+    replica down for its next calls, stalls that end in a stream-gap 502,
+    transport resets: every request must end in a 200, and the plan must
+    really have injected faults and forced failovers. No clock in it: an
+    outage is counted in calls and the backoff does not sleep."""
+    from tpu9.config import RouterConfig
+    from tpu9.router import FleetRouter
+    from tpu9.testing.faults import FaultPlane, parse_spec
+    from tpu9.types import (ContainerState, ContainerStatus, Stub,
+                            StubConfig)
+
+    class Fleet:
+        states = [ContainerState(container_id=f"r{i}", stub_id="s",
+                                 status=ContainerStatus.RUNNING.value,
+                                 address=f"127.0.0.1:{9100 + i}")
+                  for i in range(3)]
+
+        async def containers_by_stub(self, stub_id, status=None):
+            return list(self.states)
+
+    plane = FaultPlane(parse_spec(
+        "crash:prob=0.03,times=5;stall:prob=0.04;rpc_error:prob=0.05"),
+        seed=1994)
+    cfg = RouterConfig(default_replica_inflight=8, max_queue_depth=10000,
+                       max_queue_wait_s=10.0, failover_max_attempts=6)
+    router = FleetRouter(cfg, MemoryStore(), Fleet())
+    stub = Stub(stub_id="s", name="s", workspace_id="w",
+                config=StubConfig(timeout_s=30.0))
+    injected = {"crash": 0, "stall": 0, "rpc_error": 0}
+    down_for: dict = {}            # replica -> calls it still refuses
+    failovers = []
+
+    def forward_for(avoid):
+        async def forward(prefer):
+            # the buffer's avoid semantics: failed replicas are passed
+            # over unless nothing else exists
+            cands = [c for c in (prefer or ["r0"])
+                     if c not in avoid] or list(prefer or ["r0"])
+            cid = cands[0]
+            if down_for.get(cid, 0) > 0:
+                down_for[cid] -= 1
+                return ForwardResult(
+                    status=502, body=b'{"error":"ConnectRefused"}',
+                    container_id=cid)
+            for kind, status, body, outage in (
+                    ("crash", 500, b'{"error":"engine failure: induced"}',
+                     4),
+                    ("rpc_error", 502, b'{"error":"ConnectionResetError"}',
+                     0),
+                    ("stall", 502, b'{"error":"stream_gap"}', 0)):
+                if plane.fire(kind):
+                    injected[kind] += 1
+                    down_for[cid] = outage
+                    return ForwardResult(status=status, body=body,
+                                         container_id=cid)
+            await asyncio.sleep(0)
+            return ForwardResult(status=200, body=b'{"ok":1}',
+                                 container_id=cid)
+        return forward
+
+    async def no_sleep(delay):
+        await asyncio.sleep(0)
+
+    async def one(i: int) -> int:
+        body = json.dumps({"tokens": [i % 7, i % 11, i % 13],
+                           "max_new_tokens": 8}).encode()
+
+        async def attempt(attempt, avoid):
+            return await router.submit(stub, "chaos", body,
+                                       forward_for(avoid))
+
+        budget = sv.FailoverBudget(cfg.failover_max_attempts,
+                                   BackoffPolicy(base_s=0.001, jitter=0.0))
+        res = await sv.submit_with_failover(
+            attempt, budget, sleep=no_sleep,
+            on_failover=lambda a, failed, d: failovers.append(a))
+        return res.status
+
+    try:
+        statuses = await asyncio.gather(*[one(i) for i in range(120)])
+    finally:
+        await router.stop()
+    assert statuses.count(200) == 120, [s for s in statuses if s != 200]
+    assert failovers and sum(injected.values()) >= 5, (injected, failovers)
 
 
 async def test_submit_with_failover_returns_last_failure_on_exhaustion():

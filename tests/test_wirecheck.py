@@ -9,10 +9,14 @@ surfaces; the scanner skips this file entirely.
 """
 
 import ast
+import functools
 import json
 import os
+import re
 import sys
 import textwrap
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "scripts"))
@@ -447,6 +451,84 @@ def test_contracts_env_readers_exist():
         assert var.startswith("TPU9_"), var
         for rd in t.get("readers", []):
             assert os.path.exists(os.path.join(REPO, rd)), (var, rd)
+
+
+@pytest.mark.parametrize("checker", ["tpu9.analysis.runner",
+                                     "tpu9.analysis.wirecheck"])
+def test_default_roots_are_in_the_checkout(checker):
+    """Every root a checker scans by default exists: a root that names a
+    deleted file is walked as nothing, in silence, and the gate goes on
+    reporting a clean scan of a tree it no longer covers."""
+    import importlib
+    roots = importlib.import_module(checker).DEFAULT_ROOTS
+    assert roots and "tpu9" in roots
+    gone = [r for r in roots if not os.path.exists(os.path.join(REPO, r))]
+    assert not gone, gone
+
+
+def test_contracts_justifications_name_files_that_exist():
+    """A reason that says "asserted in tests/x.py" or "read by
+    benchmark/y.py" is a claim about the tree: every repo path a string
+    or comment of contracts.toml names must exist (a test module may go
+    without its ``.py``). Would have caught metrics "guarded by" a script
+    that was about to go."""
+    with open(os.path.join(REPO, "tpu9", "analysis", "contracts.toml"),
+              encoding="utf-8") as fh:
+        text = fh.read()
+    named = set(re.findall(
+        r"(?<![\w/.-])((?:tpu9|tests|scripts|benchmark|deploy|examples"
+        r"|native)/[\w./-]*\w)", text))
+    assert len(named) > 20, named
+    gone = sorted(n for n in named
+                  if not os.path.exists(os.path.join(REPO, n))
+                  and not os.path.exists(os.path.join(REPO, n + ".py")))
+    assert not gone, gone
+
+
+# files a document may name that no checkout holds, and why
+_WRITTEN_AT_RUN_TIME = {
+    "device_scopes.json",       # beside a runner's profile dump
+    "index.json",               # inside a saved .tpu9w weights directory
+    "wire_baseline.json",       # wire_gate.py --update-baseline; absent = empty
+}
+
+
+def _braces(token: str) -> list:
+    m = re.search(r"\{([^}]*)\}", token)
+    if not m:
+        return [token]
+    return [out for alt in m.group(1).split(",")
+            for out in _braces(token[:m.start()] + alt + token[m.end():])]
+
+
+@functools.lru_cache(maxsize=None)
+def _checkout_files() -> frozenset:
+    tree = set()
+    for d, dirs, files in os.walk(REPO):
+        dirs[:] = [x for x in dirs if not x.startswith(".")
+                   and x not in ("__pycache__", "chiprun_out")]
+        tree.update(os.path.relpath(os.path.join(d, f), REPO)
+                    for f in files)
+    return frozenset(tree)
+
+
+@pytest.mark.parametrize("doc", ["README.md", "ARCHITECTURE.md",
+                                 ".claude/skills/verify/SKILL.md"])
+def test_documents_name_files_that_exist(doc):
+    """Every backticked ``*.py`` / ``*.json`` path in the documents a new
+    owner reads first is the tail of a file in the checkout (``a/{b,c}.py``
+    names two): a document that sends its reader to a deleted script is
+    caught here and not by the reader."""
+    tree = _checkout_files()
+    with open(os.path.join(REPO, doc), encoding="utf-8") as fh:
+        named = set(re.findall(r"`([^`\s]+\.(?:py|json))`", fh.read()))
+    assert named, doc
+    gone = sorted(
+        path for token in named if not token.startswith("/")
+        for path in _braces(token)
+        if os.path.basename(path) not in _WRITTEN_AT_RUN_TIME
+        and not any(f == path or f.endswith("/" + path) for f in tree))
+    assert not gone, gone
 
 
 def test_contracts_external_routes_are_registered():

@@ -19,7 +19,7 @@ from . import rules
 from .findings import (Baseline, Finding, apply_suppressions,
                        assign_occurrences, load_baseline, parse_suppressions)
 
-DEFAULT_ROOTS = ("tpu9", "scripts", "examples", "bench.py")
+DEFAULT_ROOTS = ("tpu9", "scripts", "examples")
 DEFAULT_BASELINE = os.path.join("scripts", "lint_baseline.json")
 BOUNDARIES_TOML = os.path.join(os.path.dirname(__file__), "boundaries.toml")
 

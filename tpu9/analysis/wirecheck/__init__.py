@@ -29,7 +29,7 @@ from .checks import ALL_CHECKS, WireContracts
 
 DEFAULT_CONTRACTS = "tpu9/analysis/contracts.toml"
 DEFAULT_BASELINE = "scripts/wire_baseline.json"
-DEFAULT_ROOTS = ("tpu9", "scripts", "examples", "tests", "bench.py")
+DEFAULT_ROOTS = ("tpu9", "scripts", "examples", "tests")
 
 WIRE_RULES = {
     "WIR001": "stats/heartbeat field consumed-but-never-produced (and "
@@ -40,8 +40,7 @@ WIRE_RULES = {
               "non-atomic multi-writer op / missing TTL discipline",
     "ENV001": "TPU9_* env read outside tpu9/config.py or its declared "
               "reader; divergent inline defaults",
-    "RPC001": "registered route without caller / call without handler; "
-              "bench_guard HARD_FIELDS bench.py cannot emit",
+    "RPC001": "registered route without caller / call without handler",
 }
 
 

@@ -17,9 +17,7 @@ Five rules over four wire surfaces:
 - ENV001 — ``TPU9_*`` env reads: undeclared var (error), reader outside
   the declared set (error), divergent inline defaults (error).
 - RPC001 — route agreement: registered-but-never-called (error unless
-  declared external), called-but-never-registered (error), bench_guard
-  ``HARD_FIELDS`` a bench phase cannot emit (error), guarded fields
-  absent from bench.py (warn tier).
+  declared external), called-but-never-registered (error).
 
 Errors gate; warns report. Both carry the shared finding schema.
 """
@@ -162,9 +160,6 @@ class CheckContext:
         self.env_reads: list[ex.EnvRead] = []
         self.routes_registered: list[ex.RouteUse] = []
         self.route_calls: list[ex.RouteUse] = []
-        self.bench_literals: set[str] = set()
-        self.guard_fields: dict = {}     # from scripts/bench_guard.py
-        self.hard_fields: tuple = ()
 
     # role predicates — which inventory a file feeds
     @staticmethod
@@ -223,17 +218,6 @@ class CheckContext:
                 self.metric_asserts.extend(ex.extract_metric_literals(idx))
             if not self._is_test(rel):
                 self.env_reads.extend(ex.extract_env_reads(idx))
-            if rel == "bench.py":
-                for node in __import__("ast").walk(idx.tree):
-                    lit = ex._lit_str(node)
-                    if lit is not None:
-                        self.bench_literals.add(lit)
-            if rel == "scripts/bench_guard.py":
-                self.hard_fields = tuple(
-                    e for e in idx.consts.get("HARD_FIELDS", ())
-                    if isinstance(e, str))
-                # GUARDED_FIELDS is a dict literal — pull keys by AST
-                self.guard_fields = _dict_const_keys(idx, "GUARDED_FIELDS")
 
     def contracts_site(self, needle: str) -> tuple[int, int]:
         """Line of the first contracts.toml line containing ``needle`` —
@@ -246,22 +230,6 @@ class CheckContext:
         except OSError:
             pass
         return 1, 0
-
-
-def _dict_const_keys(idx: ex.ModuleIndex, name: str) -> dict:
-    import ast
-    for node in ast.walk(idx.tree):
-        if isinstance(node, ast.Assign) and \
-                isinstance(node.value, ast.Dict) and \
-                any(isinstance(t, ast.Name) and t.id == name
-                    for t in node.targets):
-            out = {}
-            for k, v in zip(node.value.keys, node.value.values):
-                key = ex._lit_str(k)
-                if key is not None:
-                    out[key] = ex._lit_str(v)
-            return out
-    return {}
 
 
 def _f(rule, site: ex.Site, message: str, symbol: str) -> Finding:
@@ -594,7 +562,6 @@ def check_env(ctx: CheckContext) -> tuple[list[Finding], list[Finding]]:
 
 def check_rpc(ctx: CheckContext) -> tuple[list[Finding], list[Finding]]:
     findings: list[Finding] = []
-    warns: list[Finding] = []
     c = ctx.contracts
     seen: set[str] = set()
     for reg in ctx.routes_registered:
@@ -622,22 +589,7 @@ def check_rpc(ctx: CheckContext) -> tuple[list[Finding], list[Finding]]:
                 "RPC001", call.site,
                 f"'{call.pattern}' is called here but no handler "
                 "registers it — the call can only 404", call.pattern))
-    # bench_guard cross-check: a HARD field bench.py cannot emit would
-    # make every future round a guaranteed guard failure
-    for fld in ctx.hard_fields:
-        if fld not in ctx.bench_literals:
-            findings.append(Finding(
-                "RPC001", "scripts/bench_guard.py", 1, 0,
-                f"HARD field '{fld}' does not appear in bench.py — no "
-                "phase can emit it, so its presence check can never "
-                "pass", symbol=fld))
-    for fld in ctx.guard_fields:
-        if fld not in ctx.bench_literals:
-            warns.append(Finding(
-                "RPC001", "scripts/bench_guard.py", 1, 0,
-                f"guarded field '{fld}' does not appear in bench.py — "
-                "the guard entry is dead weight", symbol=fld))
-    return findings, warns
+    return findings, []
 
 
 ALL_CHECKS = {

@@ -57,11 +57,10 @@ def chip_spec(device_kind: str) -> ChipSpec:
 
 def _ratio(x: float) -> float:
     """Round a utilization ratio to 4 SIGNIFICANT digits, not 4 decimal
-    places: a CPU bench run judged against the generous unknown-chip
-    ceiling produces honest ratios in the 1e-5 range, and fixed-point
-    rounding collapsed them to a flat 0.0 in BENCH_DETAIL.json — which
-    reads as 'no evidence' instead of 'tiny but real' (ISSUE 5
-    satellite)."""
+    places: a run far under the chip's ceiling produces honest ratios
+    in the 1e-5 range, and fixed-point rounding collapses them to a flat
+    0.0 — which reads as 'no evidence' instead of 'tiny but real' (ISSUE
+    5 satellite)."""
     return float(f"{x:.4g}")
 
 

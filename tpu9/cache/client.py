@@ -239,9 +239,8 @@ class CacheClient:
         return entry
 
     def _note_exchange(self, peer: str, dt: float, nbytes: int) -> None:
-        """One verified peer exchange: per-peer EWMA + histogram + bytes.
-        This is the hook ``bench.py --phase obs`` prices (µs-scale dict
-        math per multi-MiB chunk)."""
+        """One verified peer exchange: per-peer EWMA + histogram + bytes
+        (µs-scale dict math per multi-MiB chunk)."""
         prior = self._peer_lat.get(peer)
         self._peer_lat[peer] = dt if prior is None \
             else 0.2 * dt + 0.8 * prior

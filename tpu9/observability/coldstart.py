@@ -5,15 +5,15 @@ replica bring-up (``restore.request`` ⊃ per-group ``restore.fetch`` ∥
 ``restore.device_put``, plus ``restore.load`` / ``restore.compile_ahead`` /
 ``restore.bind`` on the runner side) and one *readiness record* per replica
 (plan→fetch→put→compile→ready wall intervals, bytes by cache tier, hedge
-outcomes). Three consumers read that evidence and must agree on its shape:
+outcomes). Its consumers must agree on its shape:
 
 - the gateway's ``GET /api/v1/coldstart`` (merges the worker-half record
   shipped on the heartbeat with the runner-half ``coldstart_*`` pressure
   extras),
-- ``bench.py --phase coldstart_stream`` (cross-checks its measured phase
-  medians against the traced span intervals — the ≤10% agreement gate),
-- the ROADMAP item-3 ``--phase scaleout`` bench, which will gate 1→N
-  replica fan-out on exactly these per-transfer records.
+- the chip benchmark's ``bringup_load_s`` / ``bringup_compile_s``, read
+  off the runner's ``coldstart_*`` fields on ``/health``,
+- the restore tests, which hold a phase's measured seconds against the
+  traced span intervals (``decompose_spans`` + ``agreement``).
 
 This module is that single source of truth: span names, the interval
 helpers, and the trace→decomposition fold. It is a passive leaf like the
@@ -63,7 +63,7 @@ def overlap_frac(fetch: Optional[tuple], put: Optional[tuple]) -> float:
 
 def decompose_spans(spans: list[dict]) -> dict:
     """Fold one trace's span dicts (``Span.to_dict`` shape) into per-phase
-    interval sums — the traced side of the bench agreement check. Spans of
+    interval sums — the traced side of the tests' agreement check. Spans of
     the same phase are summed; the request/bringup roots are reported as
     wall envelopes, not added into the phase sum."""
     out = {"fetch_s": 0.0, "device_put_s": 0.0, "load_s": 0.0,
@@ -90,9 +90,8 @@ def decompose_spans(spans: list[dict]) -> dict:
 
 
 def agreement(traced_s: float, measured_s: float) -> float:
-    """Relative disagreement between a traced interval sum and the bench's
-    measured median for the same phase (0.0 = identical). Guarded ≤0.10 by
-    the coldstart_stream phase."""
+    """Relative disagreement between a traced interval sum and a measured
+    time for the same phase (0.0 = identical)."""
     denom = max(traced_s, measured_s)
     if denom <= 0:
         return 0.0

@@ -94,9 +94,7 @@ class DecisionLedger:
                workspace_id: str = "", ts: Optional[float] = None,
                mono: Optional[float] = None) -> dict:
         """Append one decision record. Hot path (runs inside admission /
-        dispatch): one dict build + two deque appends + a counter bump —
-        priced by ``bench.py --phase obs`` under the same ≤8µs absolute
-        gate as the cache plane's ``_note_exchange``."""
+        dispatch): one dict build + two deque appends + a counter bump."""
         self._seq += 1
         m = mono if mono is not None else time.monotonic()
         rec = {"plane": plane, "decision": decision, "chosen": chosen,

@@ -1,9 +1,9 @@
 """Deterministic fault-injection plane (ISSUE 15).
 
-One seedable, per-container fault plan that the chaos tests and
-``bench.py --phase faults`` drive instead of hand-rolling one-off
-``FaultyEngine`` subclasses (the ISSUE 14 e2e pattern, promoted to a
-first-class plane). Production processes opt in via env::
+One seedable, per-container fault plan that the chaos tests drive
+instead of hand-rolling one-off ``FaultyEngine`` subclasses (the
+ISSUE 14 e2e pattern, promoted to a first-class plane). Production
+processes opt in via env::
 
     TPU9_FAULTS="crash:after_tokens=8,flag=1;rpc_error:times=2,prob=0.5"
     TPU9_FAULTS_SEED=42
