@@ -194,9 +194,12 @@ def test_clean_tiny_cell_no_findings():
 
 
 @pytest.mark.multichip
-def test_missing_constrain_kv_is_gra002():
+@pytest.mark.parametrize("graph", [("decode", 1), ("chunkgroup", 2)],
+                         ids=["decode", "chunkgroup"])
+def test_missing_constrain_kv_is_gra002(graph):
     """Seeded violation: a policy whose constrain_kv is the identity —
-    the pool outputs leave the graph unpinned."""
+    the pool outputs leave the graph unpinned (the admission group's pool
+    and scratch too: it is a flat program, pinned at its own top level)."""
     def strip_constraint(pol):
         class NoConstraint(pol.__class__):
             def __init__(self):
@@ -208,7 +211,7 @@ def test_missing_constrain_kv_is_gra002():
 
     cell, cfg, pol, factory, jobs, *_ = _tiny_objects(
         policy=strip_constraint)
-    key, fn, args = next(j for j in jobs if j[0] == ("decode", 1))
+    key, fn, args = next(j for j in jobs if j[0] == graph)
     fs = passes.check_job(cell, cfg, pol, key, fn, args,
                           compile_jobs=False)
     assert fs and {f.rule for f in fs} == {"GRA002"}

@@ -14,11 +14,10 @@ buffer or touching a device:
   specs leaf-for-leaf.
 - **GRA002** KV constraint: every KV-state output of every graph must be
   produced by ``sharding_constraint`` carrying the policy's declared
-  head-axis spec (through ``lax.scan`` carries too), and the compiled
-  output shardings must keep the head axis — so a donation round-trip
-  can never hand GSPMD an excuse to gather the pool. On 1x1 the SAME
-  check inverts: no constraint op may exist at all (the bit-identical
-  single-device graph contract).
+  head-axis spec, and the compiled output shardings must keep the head
+  axis — so a donation round-trip can never hand GSPMD an excuse to
+  gather the pool. On 1x1 the SAME check inverts: no constraint op may
+  exist at all (the bit-identical single-device graph contract).
 - **GRA003** donation: the pool/cache/scratch argument of every
   round-trip graph must be declared donated, and every donated leaf must
   be genuinely aliased in the compiled executable
@@ -142,21 +141,15 @@ def _producer(jaxpr, var):
 
 
 def constraint_for_output(jaxpr, var):
-    """The ``sharding_constraint`` sharding pinning ``var``, descending
-    into scan carries (the fused-admission pool rides a scan carry whose
-    constraint lives in the body). None when the output is unpinned."""
+    """The ``sharding_constraint`` sharding pinning ``var`` (every graph
+    pins its KV outputs at its own top level or inside a jitted helper,
+    never inside a loop body). None when the output is unpinned."""
     eqn = _producer(jaxpr, var)
     if eqn is None:
         return None
     name = eqn.primitive.name
     if name == "sharding_constraint":
         return eqn.params.get("sharding")
-    if name == "scan":
-        idx = next(i for i, v in enumerate(eqn.outvars) if v is var)
-        num_carry = eqn.params.get("num_carry", 0)
-        if idx < num_carry:
-            body = eqn.params["jaxpr"].jaxpr
-            return constraint_for_output(body, body.outvars[idx])
     if name == "pjit":
         idx = next(i for i, v in enumerate(eqn.outvars) if v is var)
         body = eqn.params["jaxpr"].jaxpr

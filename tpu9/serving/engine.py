@@ -107,10 +107,12 @@ class EngineConfig:
     # Requires the paged engine ("" = full-precision pool).
     kv_quant: str = ""
     # chunks per fused admission dispatch (VERDICT r04 #6): a group of G
-    # chunks runs as ONE lax.scan graph (chunk prefill + block splice
-    # fused), and the serve loop interleaves a decode window between
-    # groups so a long admission doesn't starve the decode batch.
-    # 1 = one dispatch per chunk (legacy shape, still no per-chunk sync)
+    # chunks runs as ONE forward over its G·C contiguous positions (the
+    # weights are read once per group) with the block splice fused in,
+    # and the serve loop interleaves a decode window between groups so a
+    # long admission doesn't starve the decode batch. The wide forward's
+    # temporaries are G times a chunk's. 1 = every chunk through the
+    # single-chunk graphs (still no per-chunk sync)
     admit_group_chunks: int = 4
     # ---- speculative decoding (ISSUE 5) ----
     # max draft tokens per verify window (prompt-lookup n-gram drafts,
@@ -365,6 +367,9 @@ class InferenceEngine:
         self._spec_disabled_windows = 0
         self._stats = {"active_streams": 0, "queued": 0, "tokens_generated": 0,
                        "decode_steps": 0, "admit_dispatches": 0,
+                       # prefill chunks admitted, and those of them that
+                       # went through the wide group forward
+                       "admit_chunks": 0, "admit_chunks_grouped": 0,
                        "admit_interleaved_windows": 0,
                        "spec_windows": 0, "spec_proposed": 0,
                        "spec_accepted": 0, "deadline_expired": 0,
@@ -687,23 +692,19 @@ class InferenceEngine:
                                       self.kv_cache["table"][0])
             np.asarray(jax.device_get(dense["k"].ravel()[:4]))
             timings["splice_gather_s"] = _time.perf_counter() - t0
-            g = max(1, self.ecfg.admit_group_chunks)
+            g = self.graphs.group_chunks
             if g > 1:
                 # fused admission graph for the steady-state group size.
-                # Partial tails never need their own scan shape:
+                # Partial tails never need a width of their own:
                 # _admit_paged drops to the warmed single-chunk graphs
                 # for them, so this IS the last reachable signature
                 # (graphcheck GRA005 / the recompile sentinel both
                 # assert the set is closed here)
                 t0 = _time.perf_counter()
-                s = self.ecfg.max_seq_len
-                offs = np.minimum(np.arange(g) * self._chunk,
-                                  s - self._chunk).astype(np.int32)
                 pool, self._scratch, last = self._chunk_group_fn(g)(
                     self.params, self._pool_dict(), self._scratch,
-                    jnp.zeros((g, self._chunk), jnp.int32),
-                    jnp.asarray(offs),
-                    jnp.full((g,), self._chunk - 1, jnp.int32),
+                    jnp.zeros((g, self._chunk), jnp.int32), 0,
+                    self._chunk - 1,
                     jnp.full((g, self._chunk // bs), self._trash_block,
                              jnp.int32))
                 self._set_pool(pool)
@@ -1222,11 +1223,12 @@ class InferenceEngine:
     async def _admit_paged(self, req: _Request, slot: int):
         """Paged admission: reserve budget, reuse any cached prefix blocks,
         chunk-prefill the suffix in FUSED GROUPS of ``admit_group_chunks``
-        (one lax.scan dispatch per group, splice included — VERDICT r04
-        #6), interleaving a decode window between groups so the running
-        batch keeps producing tokens during a long admission. Zero host
-        syncs here; the serve loop syncs the whole admission batch once.
-        Returns the first-token device value."""
+        (one forward over the group's contiguous positions per dispatch,
+        splice included — VERDICT r04 #6), interleaving a decode window
+        between groups so the running batch keeps producing tokens
+        during a long admission. Zero host syncs here; the serve loop
+        syncs the whole admission batch once. Returns the first-token
+        device value."""
         from .paged_kv import blocks_for
         totals = self.host_phases
         bs = self.ecfg.kv_block_size
@@ -1265,27 +1267,31 @@ class InferenceEngine:
             toks_all, offsets, last_idxs, phys_all = self._chunk_tables(
                 req, slot, p)
         n_chunks = len(offsets)
+        self._stats["admit_chunks"] += n_chunks
         last = None
-        group = max(1, self.ecfg.admit_group_chunks)
+        group = self.graphs.group_chunks
         k_chunk = 0
         while k_chunk < n_chunks:
-            # FULL groups use the fused scan graph warmup compiled; a
-            # partial tail (2..group-1 chunks) runs through the warmed
-            # single-chunk graphs instead of JIT-compiling a fresh scan
-            # shape mid-traffic (which would stall every active stream
+            # FULL groups use the wide group graph warmup compiled; a
+            # partial tail (1..group-1 chunks) runs through the warmed
+            # single-chunk graphs instead of JIT-compiling a fresh group
+            # width mid-traffic (which would stall every active stream
             # behind an XLA compile)
             g = group if n_chunks - k_chunk >= group else 1
             sl = slice(k_chunk, k_chunk + g)
             with phase("engine.admit.dispatch", totals, g=g):
                 if g > 1:
+                    # a group's chunks are contiguous: one offset, and
+                    # only its final chunk can be partial
                     pool, scratch, last = self._chunk_group_fn(g)(
                         self.params, self._pool_dict(), scratch,
                         jnp.asarray(toks_all[sl]),
-                        jnp.asarray(offsets[sl]),
-                        jnp.asarray(last_idxs[sl]),
+                        int(offsets[k_chunk]),
+                        int(last_idxs[k_chunk + g - 1]),
                         jnp.asarray(phys_all[sl]))
                     self._set_pool(pool)
                     self._stats["admit_dispatches"] += 1
+                    self._stats["admit_chunks_grouped"] += g
                 else:
                     last, scratch = self._chunk_fn()(
                         self.params, jnp.asarray(toks_all[sl]),

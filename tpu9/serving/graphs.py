@@ -127,6 +127,11 @@ class GraphFactory:
         self.policy = policy
         self.chunk = chunk
         self.kv_quant = kv_quant
+        # chunks per admission-group dispatch; 1 (no group graph) in dense
+        # mode and where one group would not fit the scratch
+        g = max(1, ecfg.admit_group_chunks)
+        self.group_chunks = g if chunk and g * chunk <= ecfg.max_seq_len \
+            else 1
         self.compiled: dict[Any, Any] = {}
         # recompile sentinel (ISSUE 11): executable-cache misses. After
         # seal() (warmup/precompile done) a miss means steady-state
@@ -315,29 +320,31 @@ class GraphFactory:
 
     def traced_chunk_step(self, params, scratch, tok_row, offset,
                           last_idx):
-        """Traced body shared by the single-chunk and fused-group graphs
-        (one implementation — the two admission paths must never diverge):
-        prefill one C-token chunk into the scratch at ``offset`` and
-        return the logits at ``last_idx``."""
-        c = self.chunk
-        positions = offset + jnp.arange(c)[None, :]
+        """Traced body shared by the single-chunk and admission-group
+        graphs (one implementation — the two admission paths must never
+        diverge): prefill the contiguous tokens of ``tok_row`` (one chunk,
+        or a group's g·C) into the scratch at ``offset`` and return the
+        logits at ``last_idx``."""
+        width = tok_row.shape[0]
+        positions = offset + jnp.arange(width)[None, :]
         logits, scratch = decoder_forward(
             params, tok_row[None, :], self.cfg, positions=positions,
-            kv_cache=scratch, cache_len=offset + c, decode=False,
+            kv_cache=scratch, cache_len=offset + width, decode=False,
             mesh=self.policy.mesh)
         last = jax.lax.dynamic_index_in_dim(
             logits[0], last_idx, axis=0, keepdims=False)
         return last, scratch
 
     def traced_splice(self, pool, scratch_k, scratch_v, offset, phys):
-        """Traced block copy shared by the splice and fused-group graphs:
-        scratch positions [offset, offset+C) → pool blocks phys[0..C/BS).
+        """Traced block copy shared by the splice and admission-group
+        graphs: scratch positions [offset, offset + len(phys)·BS) → pool
+        blocks phys[0..] (one chunk's C/BS blocks, or a group's g·C/BS).
         An int8 pool quantizes each block on the way in (per-vector absmax
         scales land in the scale planes at the same physical index)."""
         bs = self.ecfg.kv_block_size
         pool = dict(pool)
         with jax.named_scope("kv.splice"):
-            for j in range(self.chunk // bs):
+            for j in range(phys.shape[0]):
                 blk_k = jax.lax.dynamic_slice_in_dim(
                     scratch_k[:, 0], offset + j * bs, bs, axis=1)
                 blk_v = jax.lax.dynamic_slice_in_dim(
@@ -411,31 +418,26 @@ class GraphFactory:
             self.traced_splice, donate_argnums=(0,)))
 
     def chunk_group_fn(self, g: int):
-        """Fused admission graph (VERDICT r04 #6): lax.scan over ``g``
-        chunks — each step chunk-prefills into the scratch AND splices its
-        blocks into the pool. One dispatch replaces 2g, and the per-chunk
-        host bookkeeping (table math, array uploads) collapses into one
-        transfer of [g, ...] arrays. Returns the final chunk's last-token
-        logits so the caller can sample the first output."""
+        """Admission-group graph: ONE forward over the ``g·C`` contiguous
+        positions of ``g`` chunks, then the splice of their g·C/BS blocks
+        into the pool. The weights cross the HBM bus once per group, not
+        once per chunk, and one dispatch replaces 2g. Only the group's
+        final chunk may be partial: ``last_idx`` is its last real token's
+        index in that chunk, and the logits returned are that token's, so
+        the caller can sample the first output."""
         policy = self.policy
+        c = self.chunk
 
         def build():
-            def group(params, pool, scratch, toks, offsets, last_idxs,
-                      phys):
-                # toks [g, C] offsets [g] last_idxs [g] phys [g, C/BS]
-                def body(carry, xs):
-                    pool, scratch = carry
-                    tok, off, li, ph = xs
-                    last, scratch = self.traced_chunk_step(
-                        params, scratch, tok, off, li)
-                    pool = self.traced_splice(
-                        pool, scratch["k"], scratch["v"], off, ph)
-                    return (pool, scratch), last
-
-                (pool, scratch), lasts = jax.lax.scan(
-                    body, (pool, scratch), (toks, offsets, last_idxs,
-                                            phys))
-                return pool, policy.constrain_kv(scratch), lasts[-1]
+            def group(params, pool, scratch, toks, offset, last_idx, phys):
+                # toks [g, C] phys [g, C/BS]; offset, last_idx scalars
+                last, scratch = self.traced_chunk_step(
+                    params, scratch, toks.reshape(g * c), offset,
+                    (g - 1) * c + last_idx)
+                pool = self.traced_splice(
+                    pool, scratch["k"], scratch["v"], offset,
+                    phys.reshape(-1))
+                return pool, policy.constrain_kv(scratch), last
 
             return jax.jit(group, donate_argnums=(1, 2))
 
@@ -473,13 +475,11 @@ class GraphFactory:
                     jax.ShapeDtypeStruct((c // bs,), i32)))
             yield ("gather", self.gather_fn(),
                    (apool, jax.ShapeDtypeStruct((mb,), i32)))
-            g = max(1, self.ecfg.admit_group_chunks)
+            g = self.group_chunks
             if g > 1:
                 yield (("chunkgroup", g), self.chunk_group_fn(g),
                        (pspec, apool, ascratch,
-                        jax.ShapeDtypeStruct((g, c), i32),
-                        jax.ShapeDtypeStruct((g,), i32),
-                        jax.ShapeDtypeStruct((g,), i32),
+                        jax.ShapeDtypeStruct((g, c), i32), 0, 0,
                         jax.ShapeDtypeStruct((g, c // bs), i32)))
         else:
             cfg = self.cfg
@@ -525,7 +525,7 @@ class GraphFactory:
           from the engine's ``spec_lens`` buckets.
         - ``("chunk", c)`` / ``"splice"`` / ``"gather"``: paged admission
           — ONE validated chunk length; partial tail groups reuse these,
-          never a fresh scan shape.
+          never a fresh group width.
         - ``("chunkgroup", g)``: paged admission dispatches FULL groups
           only (``_admit_paged`` drops to the single-chunk graphs for
           tails).
@@ -536,9 +536,8 @@ class GraphFactory:
         keys |= {("verify", s) for s in spec_lens}
         if self.chunk:
             keys |= {("chunk", self.chunk), "splice", "gather"}
-            g = max(1, self.ecfg.admit_group_chunks)
-            if g > 1:
-                keys.add(("chunkgroup", g))
+            if self.group_chunks > 1:
+                keys.add(("chunkgroup", self.group_chunks))
         else:
             for bucket in buckets:
                 keys |= {bucket, ("dsplice", bucket)}
