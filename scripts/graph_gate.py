@@ -11,7 +11,7 @@ or the gate is red.
 
     python scripts/graph_gate.py                 # full matrix
     python scripts/graph_gate.py --cell llama3-8b@2x1
-    python scripts/graph_gate.py --budget-s 120  # enforce the runtime gate
+    python scripts/graph_gate.py --budget-s 400  # enforce the runtime gate
 
 When the forced CPU mesh is unavailable (caller pinned XLA_FLAGS without
 the device-count forcing), the gate SKIPS LOUDLY with the re-run recipe
@@ -42,10 +42,15 @@ force_cpu(host_devices=8)
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--cell", action="append", default=None)
-    ap.add_argument("--budget-s", type=float, default=120.0,
-                    help="fail when the full matrix exceeds this wall "
-                         "clock (0 disables; default %(default)s — the "
-                         "tier-1 contract)")
+    ap.add_argument("--budget-s", type=float, default=400.0,
+                    help="fail when the full matrix takes more than this "
+                         "much CPU time of this process, all threads (0 "
+                         "disables; default %(default)s — the tier-1 "
+                         "contract). CPU time and not wall clock: the "
+                         "matrix is 50-60 s of wall clock alone and about "
+                         "160 s of CPU, and under a parallel test run its "
+                         "wall clock says how busy the machine is, not what "
+                         "the matrix costs")
     ap.add_argument("--no-compile", action="store_true")
     ap.add_argument("--repo-root", default=None)
     ap.add_argument("--strict-stale", action="store_true",
@@ -89,11 +94,11 @@ def main(argv=None) -> int:
     for f in findings:
         print(f"FAIL {f.format()}")
     elapsed = time.perf_counter() - t0
-    matrix_s = report["elapsed_s"]
+    matrix_s, cpu_s = report["elapsed_s"], report["cpu_s"]
     n_graphs = sum(s["jobs"] for s in report["cells"])
     print(f"graph_gate: {len(report['cells'])} cells / {n_graphs} graphs "
-          f"in {matrix_s:.1f}s (+ lint, total {elapsed:.1f}s) — "
-          f"{len(findings)} findings")
+          f"in {matrix_s:.1f}s, {cpu_s:.1f}s of CPU (+ lint, total "
+          f"{elapsed:.1f}s) — {len(findings)} findings")
 
     if findings:
         print("graph_gate: FAIL — graph invariants violated (Pass A "
@@ -102,8 +107,8 @@ def main(argv=None) -> int:
         return 1
     # the budget is the MATRIX contract — Pass B's repo-wide lint scan
     # scales with repo size, not with the matrix, and must not bill it
-    if args.budget_s and not args.cell and matrix_s > args.budget_s:
-        print(f"graph_gate: FAIL — full matrix took {matrix_s:.1f}s > "
+    if args.budget_s and not args.cell and cpu_s > args.budget_s:
+        print(f"graph_gate: FAIL — full matrix took {cpu_s:.1f}s of CPU > "
               f"budget {args.budget_s:.0f}s (trim the matrix or move a "
               "cell to the slow tier)", file=sys.stderr)
         return 1

@@ -226,6 +226,42 @@ def test_decode_programs_carry_the_pool_whole_on_a_described_v5e(
             rf"\[[\d,]*{BS},{per_chip[3]},{per_chip[4]}\]", ln)], key
 
 
+def test_a_looped_decode_program_carries_the_pool_through_its_pass_loop(
+        v5e, no_compile_cache, monkeypatch):
+    """The looped configuration at its published widths, two layers deep:
+    the passes are ONE device loop whose body holds the layers' kernels
+    once (the plane ``u * n_layers + l`` is an operand), and the compiler
+    keeps the ``kv_layers``-deep pool in place through it — no copy, no
+    plane of it."""
+    from dataclasses import replace
+
+    from benchmark import manifest, serve
+    from tpu9.serving.graphs import GraphFactory, abstract_state
+    from tpu9.serving.presets import abstract_params_for
+    from tpu9.serving.shard.plan import Topology
+    from tpu9.serving.shard.policy import MeshPolicy
+    monkeypatch.setattr(attention_ops, "on_tpu", lambda: True)
+    config = manifest.load_config(manifest.load(), "ouro-2.6b")
+    family = manifest.family(config)
+    cfg = replace(family.program_config(family.model_sizes(config)),
+                  n_layers=2)
+    assert cfg.loop_steps == 4 and cfg.kv_layers == 8
+    ecfg = serve.engine_config(config["engine"])
+    policy = MeshPolicy(Topology(1, 1), devices=v5e[:1])
+    graphs = GraphFactory(cfg, ecfg, policy, chunk=ecfg.prefill_chunk)
+    st = abstract_state(cfg, ecfg, policy)
+    pool = st["kv_cache"]["k"].shape
+    assert pool[0] == 8
+    (key, fn, args), = [job for job in graphs.lowering_jobs(
+        abstract_params_for(cfg, False), st["kv_cache"], st["pool"],
+        st["scratch"], st["mb"], [ecfg.prefill_chunk], (), st["rng"])
+        if job[0] == ("decode", 1)]
+    text = fn.lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == cfg.n_layers
+    assert " while(" in text
+    assert _pool_shaped(text, pool) == []
+
+
 def test_pool_shaped_finds_what_the_old_program_did():
     """The reader above, on hand-made text: a plane cut out, planes stacked
     back and the compiler's own pool-shaped copy are found; parameters, the

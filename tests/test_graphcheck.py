@@ -582,15 +582,23 @@ def test_gate_fails_on_seeded_finding(monkeypatch, capsys):
     monkeypatch.setattr(
         passes, "run_matrix",
         lambda cells, compile_jobs=True: {
-            "findings": [seeded], "cells": [], "elapsed_s": 0.0})
+            "findings": [seeded], "cells": [], "elapsed_s": 0.0,
+            "cpu_s": 0.0})
     rc = graph_gate.main([])
     out = capsys.readouterr().out
     assert rc == 1 and "GRA002" in out and "FAIL" in out
 
 
 @pytest.mark.multichip
-def test_repo_graph_gate_is_green():
+def test_repo_graph_gate_is_green(capsys):
     """THE tier-1 gate: the full preset × topology matrix verifies clean
-    on this repo, inside the runtime budget (acceptance: < 120 s)."""
-    rc = graph_gate.main(["--budget-s", "120"])
-    assert rc == 0
+    on this repo — the findings are judged first, so that a finding is
+    never mistaken for a slow machine — inside its runtime budget: 400 s
+    of this process's CPU time (about 160 s here; 50-60 s of wall clock
+    alone). Wall clock was the budget until ISSUE 34, and tripped under
+    six busy xdist workers with 0 findings."""
+    rc = graph_gate.main(["--budget-s", "400"])
+    out = capsys.readouterr()
+    assert f"{len(MATRIX)} cells" in out.out and " 0 findings" in out.out, \
+        out.out[-2000:]
+    assert rc == 0, out.err[-2000:]

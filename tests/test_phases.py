@@ -196,7 +196,10 @@ def test_first_hold_and_stream_lag_reach_the_latency_summary(tiny):
     parts = sum(lat[f"{p}_mean_s"]
                 for p in ("queue_wait", "prefill", "first_hold"))
     assert parts == pytest.approx(lat["ttft_mean_s"], abs=2e-3)
-    assert 0.0 <= lat["stream_lag_mean_s"] < 1.0
+    # the lag lies inside the request's own lifetime; a bound in seconds
+    # would be a bound on how busy the test machine is (1.4-2.0 s read
+    # under ten busy processes; PR 34)
+    assert 0.0 <= lat["stream_lag_mean_s"] <= lat["e2e_mean_s"]
 
 
 # ---------------------------------------------------------------------------

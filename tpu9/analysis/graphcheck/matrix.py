@@ -6,9 +6,10 @@ lowered/compiled artifact on a forced CPU mesh), so cells are
 depth-reduced: ``n_layers=2`` keeps flagship-shaped per-layer tensors
 (the sharding/dtype/donation invariants are per-layer identical — layer
 3 traces the same eqns as layer 2) while the full matrix stays inside
-the tier-1 budget (<120 s). Per-layer SHAPES are never reduced: head
-counts, head_dim, hidden/vocab dims are the flagship's, so divisibility
-(the silent-replication trap) is checked against the real arithmetic.
+the tier-1 budget (400 s of CPU time, about 55 s of wall clock alone).
+Per-layer SHAPES are never reduced: head counts, head_dim, hidden/vocab
+dims are the flagship's, so divisibility (the silent-replication trap) is
+checked against the real arithmetic.
 
 Extending the matrix when adding a preset or a graph: add a Cell (or a
 knob) here; Pass A derives everything else from the GraphFactory's own
@@ -28,6 +29,7 @@ class Cell:
     quantize: str = ""            # "" | "int8" weight quantization
     kv_quant: str = ""            # "" | "int8" paged-KV pool
     n_layers: int = 2             # depth reduction (shapes stay flagship)
+    loop_steps: int = 0           # passes of a looped preset (0 = its own)
     paged: bool = True            # False = legacy dense-cache graph set
     max_batch: int = 2
     max_seq_len: int = 256
@@ -70,6 +72,11 @@ MATRIX: tuple = (
     Cell("mixtral-8x7b", "2x2"),
     # legacy dense cache: prefill buckets + dense splice graphs
     Cell("llama3-8b", "2x1", paged=False),
+    # looped decoder: a pool ``kv_layers`` deep carried through the device
+    # loop over the passes (two passes of two layers trace what four of
+    # forty-eight do), the exit gate and sandwich norms replicated
+    Cell("ouro-2.6b", "1x1", loop_steps=2),
+    Cell("ouro-2.6b", "2x1", loop_steps=2),
 )
 
 

@@ -172,22 +172,32 @@ def decode_byte_counts(params, cfg, batch: int, mean_ctx: int) -> dict:
     - matmul params: same tensors counted in parameters.
     - kv bytes: read of ``mean_ctx`` K+V rows per layer per sequence plus
       the single-row write.
+
+    A looped decoder (``cfg.loop_steps`` passes over one set of layers)
+    streams everything under ``layers`` once a PASS and passes a token
+    through those matrices as often; its KV state is ``cfg.kv_layers``
+    deep. What is outside the layers (head, final norm, gate) is read
+    once — the final norm's few bytes a pass are left at once.
     """
     import numpy as np
 
     streamed = 0
     matmul_params = 0
 
+    passes = getattr(cfg, "loop_steps", 1)
+    kv_layers = getattr(cfg, "kv_layers", cfg.n_layers)
+
     def walk(node, path=()):
         nonlocal streamed, matmul_params
+        times = passes if path[:1] == ("layers",) else 1
         if isinstance(node, dict):
             if "q" in node and "scale" in node and getattr(
                     node["q"], "ndim", 0) == 2:   # quantized entry
                 streamed_local = (node["q"].size * node["q"].dtype.itemsize
                                   + node["scale"].size
                                   * node["scale"].dtype.itemsize)
-                streamed += streamed_local
-                matmul_params += int(node["q"].size)
+                streamed += times * streamed_local
+                matmul_params += times * int(node["q"].size)
                 return
             for k, v in node.items():
                 walk(v, path + (k,))
@@ -204,20 +214,20 @@ def decode_byte_counts(params, cfg, batch: int, mean_ctx: int) -> dict:
                     matmul_params += int(node.size)
                 return                      # gather: B rows, negligible
             if node.ndim >= 2:              # projection / moe weight
-                streamed += node.size * node.dtype.itemsize
-                matmul_params += int(node.size)
+                streamed += times * node.size * node.dtype.itemsize
+                matmul_params += times * int(node.size)
             else:                           # norm vectors: tiny but real
-                streamed += node.size * node.dtype.itemsize
+                streamed += times * node.size * node.dtype.itemsize
 
     walk(params)
 
     kv_dtype_bytes = 2  # bf16 cache
     kv_row = cfg.n_kv_heads * cfg.head_dim * kv_dtype_bytes
-    kv_read = 2 * cfg.n_layers * batch * mean_ctx * kv_row      # K and V
-    kv_write = 2 * cfg.n_layers * batch * kv_row
+    kv_read = 2 * kv_layers * batch * mean_ctx * kv_row         # K and V
+    kv_write = 2 * kv_layers * batch * kv_row
     # attention FLOPs: qk^T + att*v over mean_ctx keys, grouped-query
     attn_flops = 4.0 * batch * mean_ctx * cfg.n_heads * cfg.head_dim \
-        * cfg.n_layers
+        * kv_layers
     return {
         "streamed_bytes": int(streamed),
         "matmul_params": int(matmul_params),

@@ -1234,14 +1234,16 @@ from tpu9 import endpoint
 
 
 @endpoint(tpu="{tpu}", runner="llm", model="{model}",
-          extra={{"max_batch": {max_batch}, "max_seq_len": {max_seq_len}}},
+          extra={{"max_batch": {max_batch}, "max_seq_len": {max_seq_len},
+                 "kv_pool_blocks": {kv_pool_blocks}, "kv_block_size": 128}},
           concurrent_requests={concurrency}, timeout=1800,
           keep_warm_seconds={keep_warm})
 def load():
     from tpu9.serving.presets import load_engine
     return load_engine("{model}", max_batch={max_batch},
                        max_seq_len={max_seq_len},
-                       prefill_buckets=(128, {max_seq_len}))
+                       prefill_buckets=(128, {max_seq_len}),
+                       kv_pool_blocks={kv_pool_blocks})
 '''
 
 
@@ -1254,10 +1256,16 @@ def load():
 @click.option("--name", default="")
 @click.option("--max-batch", default=8)
 @click.option("--max-seq-len", default=2048)
+@click.option("--kv-pool-blocks", default=0,
+              help="pin the paged KV pool to this many 128-token blocks "
+                   "(0: as many as max_batch x max_seq_len tokens need); "
+                   "what a model whose KV state is many planes deep "
+                   "deploys with")
 @click.option("--concurrency", default=64)
 @click.option("--keep-warm", default=300)
 def llm_deploy(model: str, tpu: str, name: str, max_batch: int,
-               max_seq_len: int, concurrency: int, keep_warm: int) -> None:
+               max_seq_len: int, kv_pool_blocks: int, concurrency: int,
+               keep_warm: int) -> None:
     """One-command LLM serving: generates the engine app, validates HBM
     feasibility at the gateway, deploys behind @endpoint."""
     import tempfile
@@ -1266,7 +1274,10 @@ def llm_deploy(model: str, tpu: str, name: str, max_batch: int,
         from ..serving.feasibility import validate_llm_deployment
         # client-side pre-check: the arithmetic BEFORE uploading anything
         budget = validate_llm_deployment(model, tpu, max_batch=max_batch,
-                                         max_seq_len=max_seq_len)
+                                         max_seq_len=max_seq_len,
+                                         kv_pool_blocks=kv_pool_blocks,
+                                         # the app's chunk caps a block
+                                         kv_block_size=128)
         click.echo(f"fits: {budget.as_dict()}", err=True)
     else:
         from ..serving.presets import resolve_preset
@@ -1275,6 +1286,7 @@ def llm_deploy(model: str, tpu: str, name: str, max_batch: int,
     app = _LLM_APP_TEMPLATE.format(model=model, tpu=tpu,
                                    max_batch=max_batch,
                                    max_seq_len=max_seq_len,
+                                   kv_pool_blocks=kv_pool_blocks,
                                    concurrency=concurrency,
                                    keep_warm=keep_warm)
     name = name or model.replace(".", "-")

@@ -1068,7 +1068,12 @@ class Gateway:
                     config.extra["model"], config.runtime.tpu,
                     max_batch=int(config.extra.get("max_batch", 8)),
                     max_seq_len=int(config.extra.get("max_seq_len", 2048)),
-                    tp=int(config.extra.get("tp", 0)))
+                    tp=int(config.extra.get("tp", 0)),
+                    # a pinned paged pool is priced as pinned (a model whose
+                    # KV state is many planes deep deploys with one)
+                    kv_pool_blocks=int(
+                        config.extra.get("kv_pool_blocks", 0)),
+                    kv_block_size=int(config.extra.get("kv_block_size", 0)))
             except InfeasibleDeployment as exc:
                 return web.json_response({"error": str(exc)}, status=400)
             except (KeyError, ValueError) as exc:

@@ -13,11 +13,13 @@ from .clip_vit import ClipVisionConfig, init_clip_vision, clip_vision_forward, C
 from .classifier import TextClassifierConfig, init_classifier, classifier_forward
 from . import lora, moe
 from .mixtral import MIXTRAL_PRESETS, mixtral_config
+from .ouro import OURO_PRESETS, ouro_config
 
 __all__ = [
     "DecoderConfig", "init_decoder", "decoder_forward", "init_kv_cache",
     "LLAMA_PRESETS", "llama_config", "GEMMA_PRESETS", "gemma_config",
     "MIXTRAL_PRESETS", "mixtral_config", "moe",
+    "OURO_PRESETS", "ouro_config",
     "ClipVisionConfig", "init_clip_vision", "clip_vision_forward", "CLIP_VIT_L14",
     "TextClassifierConfig", "init_classifier", "classifier_forward", "lora",
 ]

@@ -1,4 +1,6 @@
-"""Decoder-only transformer core shared by the Llama and Gemma families.
+"""Decoder-only transformer core shared by every served family: Llama,
+Gemma, Mixtral (sparse experts) and the looped decoder (``loop_steps``
+passes over one set of layers, four norms a layer, an exit gate).
 
 Functional style: ``init_decoder`` builds a param pytree (nested dicts with
 stable path names the sharding rules in ``tpu9.parallel.sharding`` pattern-
@@ -37,7 +39,8 @@ class DecoderConfig:
     norm_eps: float = 1e-5
     rope_theta: float = 500000.0
     max_seq_len: int = 8192
-    # family switches
+    # what the families differ in: descriptors of the architecture, read
+    # by ``init_decoder`` and ``decoder_forward``; no model is named
     act: str = "silu"              # silu (llama) | gelu (gemma)
     norm_offset: float = 0.0       # 1.0 for gemma's (1+w) RMSNorm
     embed_scale: bool = False      # gemma scales embeddings by sqrt(dim)
@@ -47,11 +50,51 @@ class DecoderConfig:
     n_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
+    # looped decoder: the layers run ``loop_steps`` times a token over ONE
+    # set of weights, each pass with keys and values of its own and closed
+    # by the final norm (1 = a plain decoder)
+    loop_steps: int = 1
+    # four norms a layer: a second one on each sub-layer's OUTPUT, inside
+    # the residual branch
+    sandwich_norm: bool = False
+    # a learned gate after every pass: the head reads the state of the
+    # first pass at which the gates' cumulative exit probability reaches
+    # ``exit_threshold``, else the last pass's
+    exit_gate: bool = False
+    exit_threshold: float = 1.0
     dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        if self.loop_steps < 1:
+            raise ValueError(f"loop_steps={self.loop_steps}: at least 1")
+        if self.sandwich_norm and self.n_experts:
+            raise ValueError("sandwich_norm with experts: no served model "
+                             "has both, and the expert branch is not built "
+                             "with a norm on its output")
+        if self.exit_gate and self.exit_threshold != 1.0:
+            raise ValueError(
+                f"exit_threshold={self.exit_threshold}: only 1.0 is built. "
+                "Below it sequences of one batch stop after different "
+                "passes: the decode programs have no per-sequence pass "
+                "count, the paged pool no keys and values for the passes "
+                "a token skipped (later tokens read them), and the "
+                "scheduler prices every step alike")
 
     @property
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
+
+    @property
+    def looped(self) -> bool:
+        """Whether the forward pass is the pass loop (and a decode program
+        returns the exit pass beside each token)."""
+        return self.loop_steps > 1 or self.exit_gate
+
+    @property
+    def kv_layers(self) -> int:
+        """Depth of the KV state: a token owns one plane of keys and
+        values per (pass, layer), although the weights have ``n_layers``."""
+        return self.n_layers * self.loop_steps
 
 
 def _dense_init(rng, in_dim: int, out_dim: int, dtype) -> jnp.ndarray:
@@ -79,6 +122,8 @@ def init_decoder(rng: jax.Array, cfg: DecoderConfig) -> Params:
         params["lm_head"] = _dense_init(nxt(), cfg.dim, cfg.vocab_size, dt)
     else:
         nxt()
+    if cfg.exit_gate:
+        params["exit_gate"] = init_exit_gate(rng, cfg)
 
     q_dim = cfg.n_heads * cfg.head_dim
     kv_dim = cfg.n_kv_heads * cfg.head_dim
@@ -100,8 +145,33 @@ def init_decoder(rng: jax.Array, cfg: DecoderConfig) -> Params:
             layer["w_gate"] = _dense_init(nxt(), cfg.dim, cfg.hidden_dim, dt)
             layer["w_up"] = _dense_init(nxt(), cfg.dim, cfg.hidden_dim, dt)
             layer["w_down"] = _dense_init(nxt(), cfg.hidden_dim, cfg.dim, dt)
+        layer.update(init_post_norms(cfg))
         params["layers"].append(layer)
     return params
+
+
+def init_post_norms(cfg: DecoderConfig) -> Params:
+    """The two extra norm vectors of a ``sandwich_norm`` layer (none
+    otherwise). They start at ``1 / sqrt(2 n_layers)``, the scaled
+    residual initialisation: a norm on a branch's OUTPUT fixes what the
+    branch adds to the stream, and at 1.0 each of ``2 n_layers`` branches
+    would add as much as the stream holds — seeded weights would then be a
+    chaotic map that amplifies a rounding of 2 % after one pass to 17-51 %
+    after four (width 256; PERF.md, PR 34). A checkpoint brings its own."""
+    if not cfg.sandwich_norm:
+        return {}
+    gain = (2.0 * cfg.n_layers) ** -0.5
+    return {name: jnp.full((cfg.dim,), gain, jnp.float32) - cfg.norm_offset
+            for name in ("attn_post_norm", "mlp_post_norm")}
+
+
+def init_exit_gate(rng: jax.Array, cfg: DecoderConfig) -> Params:
+    """The exit gate: one logit a position, ``w . h + b``, float32 like
+    the norm vectors. Its rng is folded from the tree's own, so the
+    schedule of every other leaf is the plain decoder's."""
+    w = _dense_init(jax.random.fold_in(rng, cfg.dim), cfg.dim, 1,
+                    jnp.float32)
+    return {"w": w[:, 0], "b": jnp.zeros((1,), jnp.float32)}
 
 
 def _moe_cfg(cfg: DecoderConfig):
@@ -114,10 +184,11 @@ def _moe_cfg(cfg: DecoderConfig):
 
 def init_kv_cache(cfg: DecoderConfig, batch: int, max_len: int = 0,
                   dtype=None) -> Params:
-    """Contiguous per-sequence KV cache: k/v [L, B, S, KH, D]."""
+    """Contiguous per-sequence KV cache: k/v [L, B, S, KH, D], ``L`` the
+    depth of the KV state (``cfg.kv_layers``)."""
     s = max_len or cfg.max_seq_len
     dt = dtype or cfg.dtype
-    shape = (cfg.n_layers, batch, s, cfg.n_kv_heads, cfg.head_dim)
+    shape = (cfg.kv_layers, batch, s, cfg.n_kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype=dt), "v": jnp.zeros(shape, dtype=dt)}
 
 
@@ -141,6 +212,10 @@ DEVICE_SCOPES = ("embed", "attn.qkv", "attn.rope", "kv.slice", "kv.write",
                  "kv.pack", "kv.gather", "kv.splice", "attn.core",
                  "attn.out", "ffn", "moe.route", "moe.experts",
                  "moe.combine", "head", "sample")
+# A looped decoder's pass-closing norm, exit gate and selection (ISSUE 34);
+# no plain program runs anything under them. Apart from ``DEVICE_SCOPES``:
+# the benchmark's accepted tests pin what that tuple leaves ungrouped.
+LOOP_SCOPES = ("loop.norm", "loop.gate", "loop.select")
 
 
 def _pool_write(pool: jnp.ndarray, layer_idx: int, bi, oi, value):
@@ -175,17 +250,26 @@ def _cache_read(cache: jnp.ndarray, layer_idx: int):
         return cache[layer_idx]
 
 
+def _pre_norm(x: jnp.ndarray, weight: jnp.ndarray, cfg: DecoderConfig,
+              compute_dtype):
+    """A sub-layer's input norm. ``compute_dtype`` is what the sub-layer
+    computes in where the residual stream is carried wider than that (a
+    looped decoder's float32 stream); None: the stream's own type."""
+    h = rms_norm(x, weight, cfg.norm_eps, cfg.norm_offset)
+    return h if compute_dtype is None else h.astype(compute_dtype)
+
+
 def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
                 positions: jnp.ndarray, sin, cos,
                 kv_cache: Optional[Params], layer_idx: int,
                 cache_len: Optional[jnp.ndarray], decode: bool,
-                mesh=None):
+                mesh=None, compute_dtype=None):
     """One layer's attention. Returns ``(x, kv_cache)``: the cache dict is
     carried WHOLE from layer to layer — every branch writes this layer's
     k/v into the ``[L, ...]`` arrays in place and reads them at
     ``layer_idx``; nothing is sliced out and re-stacked."""
     b, t, _ = x.shape
-    h = rms_norm(x, layer["attn_norm"], cfg.norm_eps, cfg.norm_offset)
+    h = _pre_norm(x, layer["attn_norm"], cfg, compute_dtype)
     with jax.named_scope("attn.qkv"):
         q = maybe_matmul(h, layer["wq"]).reshape(
             b, t, cfg.n_heads, cfg.head_dim)
@@ -280,11 +364,16 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
 
     with jax.named_scope("attn.out"):
         out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)
+        if cfg.sandwich_norm:
+            return x + rms_norm(maybe_matmul(out, layer["wo"]),
+                                layer["attn_post_norm"], cfg.norm_eps,
+                                cfg.norm_offset), kv_cache
         return x + maybe_matmul(out, layer["wo"]), kv_cache
 
 
-def _mlp_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig):
-    h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps, cfg.norm_offset)
+def _mlp_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
+               compute_dtype=None):
+    h = _pre_norm(x, layer["mlp_norm"], cfg, compute_dtype)
     if cfg.n_experts:
         from .moe import moe_ffn
         y, aux = moe_ffn(layer["moe"], h, _moe_cfg(cfg), ep_sharded=False)
@@ -293,7 +382,96 @@ def _mlp_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig):
     with jax.named_scope("ffn"):
         gated = _act(maybe_matmul(h, layer["w_gate"]), cfg.act) \
             * maybe_matmul(h, layer["w_up"])
+        if cfg.sandwich_norm:
+            return x + rms_norm(maybe_matmul(gated, layer["w_down"]),
+                                layer["mlp_post_norm"], cfg.norm_eps,
+                                cfg.norm_offset), None
         return x + maybe_matmul(gated, layer["w_down"]), None
+
+
+def _layers(params: Params, x, cfg: DecoderConfig, positions, sin, cos,
+            kv_cache, cache_base, cache_len, decode: bool, mesh,
+            moe_balance, compute_dtype=None):
+    """Every layer once. Layer ``l`` keeps its keys and values at plane
+    ``cache_base + l`` of the cache: 0 for a plain decoder (the plane is
+    then a Python int, as it always was), a traced ``u * n_layers`` inside
+    the pass loop of a looped one."""
+    for i, layer in enumerate(params["layers"]):
+        x, kv_cache = _attn_block(layer, x, cfg, positions, sin, cos,
+                                  kv_cache, cache_base + i, cache_len,
+                                  decode, mesh, compute_dtype)
+        x, aux = _mlp_block(layer, x, cfg, compute_dtype)
+        if aux is not None:
+            moe_balance = moe_balance + aux["balance_loss"]
+    return x, kv_cache, moe_balance
+
+
+def _looped_passes(params: Params, x, cfg: DecoderConfig, positions, sin,
+                   cos, kv_cache, cache_len, decode: bool, mesh,
+                   moe_balance):
+    """``loop_steps`` passes over the one set of layers as a DEVICE loop:
+    the layer bodies are traced once whatever the number of passes. Pass
+    ``u`` reads and writes planes ``[u * n_layers, (u + 1) * n_layers)`` of
+    the cache, the final norm closes every pass and its output is what the
+    next pass starts from. With an exit gate the carry also holds the
+    probability that no earlier pass exited, the selected state and its
+    pass: ``selected`` is the state of the first pass at which the
+    cumulative exit probability reaches the threshold, else the last
+    pass's. Every pass always runs — later tokens read its keys and
+    values. Returns ``(selected, kv_cache, moe_balance, exit_info)``,
+    ``exit_info`` int32 ``[B, T, 2]``: the pass whose state the head reads,
+    and the passes the loop RAN (counted in the loop's own carry, so a loop
+    that one day stops early says so itself).
+
+    The residual stream is carried in float32 and every sub-layer still
+    computes in the type the embeddings came in: a branch adds a fraction
+    of what the stream holds, so rounding the SUM to bfloat16 at every add
+    costs several times what rounding the branch does, and the same layers
+    applied ``loop_steps`` times carry the error on (at width 256 the
+    logits' error against float32 after four passes was 3.5-4.6 % of their
+    spread with a bfloat16 stream and 2-3 % with this one; PERF.md, PR 34).
+    """
+    b, t, _ = x.shape
+    last = cfg.loop_steps - 1
+    compute_dtype = x.dtype
+    x = x.astype(jnp.float32)
+
+    def one_pass(u, carry):
+        x, kv, balance, remaining, cdf, selected, exit_step, ran = carry
+        ran = ran + 1
+        x, kv, balance = _layers(params, x, cfg, positions, sin, cos, kv,
+                                 u * cfg.n_layers, cache_len, decode, mesh,
+                                 balance, compute_dtype)
+        with jax.named_scope("loop.norm"):
+            x = rms_norm(x, params["final_norm"], cfg.norm_eps,
+                         cfg.norm_offset)
+        if not cfg.exit_gate:
+            return x, kv, balance, remaining, cdf, x, exit_step, ran
+        with jax.named_scope("loop.gate"):
+            gate = params["exit_gate"]
+            lam = jax.nn.sigmoid(
+                jnp.sum(x.astype(jnp.float32) * gate["w"], axis=-1)
+                + gate["b"][0])
+        with jax.named_scope("loop.select"):
+            # p_u = lam_u * prod_{j<u}(1 - lam_j), what remains at the
+            # last pass; ``cdf`` is their running sum, as published
+            cdf = cdf + jnp.where(u == last, remaining, lam * remaining)
+            take = (exit_step < 0) & ((cdf >= cfg.exit_threshold)
+                                      | (u == last))
+            selected = jnp.where(take[..., None], x, selected)
+            exit_step = jnp.where(take, u, exit_step)
+            remaining = remaining * (1.0 - lam)
+        return x, kv, balance, remaining, cdf, selected, exit_step, ran
+
+    carry = (x, kv_cache, moe_balance, jnp.ones((b, t), jnp.float32),
+             jnp.zeros((b, t), jnp.float32), jnp.zeros_like(x),
+             jnp.full((b, t), -1, jnp.int32), jnp.zeros((), jnp.int32))
+    _, kv_cache, moe_balance, _, _, selected, exit_step, ran = \
+        jax.lax.fori_loop(0, cfg.loop_steps, one_pass, carry)
+    if not cfg.exit_gate:
+        exit_step = jnp.full((b, t), last, jnp.int32)
+    exit_info = jnp.stack([exit_step, jnp.broadcast_to(ran, (b, t))], -1)
+    return selected.astype(compute_dtype), kv_cache, moe_balance, exit_info
 
 
 def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
@@ -303,7 +481,7 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
                     decode: bool = False,
                     return_hidden: bool = False,
                     return_moe_aux: bool = False,
-                    mesh=None):
+                    mesh=None, return_exit: bool = False):
     """Run the decoder.
 
     - train/eval: ``decoder_forward(params, tokens, cfg)`` → logits [B,T,V]
@@ -313,6 +491,10 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
     - ``mesh``:  the serving mesh when params and cache are sharded over one
                  (``MeshPolicy.mesh``) — the attention kernels then run per
                  chip on its own heads
+    - ``return_exit``: also return, last, int32 [B, T, 2]: the pass whose
+                 state the head read at each position (a looped decoder's
+                 exit gate; the last pass where there is no gate) and the
+                 passes the device ran for it (1 for a plain decoder)
     """
     b, t = tokens.shape
     if positions is None:
@@ -338,14 +520,16 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
         sin, cos = rope_table(rope_len, cfg.head_dim, cfg.rope_theta)
 
     moe_balance = jnp.zeros((), jnp.float32)
-    for i, layer in enumerate(params["layers"]):
-        x, kv_cache = _attn_block(layer, x, cfg, positions, sin, cos,
-                                  kv_cache, i, cache_len, decode, mesh)
-        x, aux = _mlp_block(layer, x, cfg)
-        if aux is not None:
-            moe_balance = moe_balance + aux["balance_loss"]
-
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_offset)
+    exit_info = None
+    if not cfg.looped:
+        x, kv_cache, moe_balance = _layers(
+            params, x, cfg, positions, sin, cos, kv_cache, 0, cache_len,
+            decode, mesh, moe_balance)
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_offset)
+    else:
+        x, kv_cache, moe_balance, exit_info = _looped_passes(
+            params, x, cfg, positions, sin, cos, kv_cache, cache_len,
+            decode, mesh, moe_balance)
     if return_hidden:
         logits = None
     else:
@@ -360,17 +544,17 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
                 logits = cfg.logit_softcap * jnp.tanh(
                     logits / cfg.logit_softcap)
 
-    out = x if return_hidden else logits
-
+    out = (x if return_hidden else logits,)
+    if kv_cache is not None:
+        out += (kv_cache,)
     if return_moe_aux:
         # mean balance loss across layers (training regularizer)
-        aux = moe_balance / max(cfg.n_layers, 1)
-        if kv_cache is not None:
-            return out, kv_cache, aux
-        return out, aux
-    if kv_cache is not None:
-        return out, kv_cache
-    return out
+        out += (moe_balance / max(cfg.n_layers, 1),)
+    if return_exit:
+        out += (jnp.stack([jnp.zeros((b, t), jnp.int32),
+                           jnp.ones((b, t), jnp.int32)], -1)
+                if exit_info is None else exit_info,)
+    return out if len(out) > 1 else out[0]
 
 
 def count_params(params: Params) -> int:

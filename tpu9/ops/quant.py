@@ -158,7 +158,9 @@ def init_quantized_decoder(rng, cfg) -> Params:
     directly on device. Same tree structure/path names as
     ``tpu9.models.transformer.init_decoder`` so sharding rules and
     ``decoder_forward`` apply unchanged. MoE configs get per-expert int8
-    stacks under ``layer["moe"]`` (router f32, like ``init_moe_layer``)."""
+    stacks under ``layer["moe"]`` (router f32, like ``init_moe_layer``); a
+    looped decoder's extra norm vectors and exit gate stay float32, under
+    ``init_decoder``'s names (``tpu9.ops`` may not import the models)."""
     per_layer = 5 if cfg.n_experts else 7   # 4 attn + 1 moe | 4 attn + 3 ffn
     n_rngs = cfg.n_layers * per_layer + 3
     rngs = jax.random.split(rng, n_rngs)
@@ -178,6 +180,12 @@ def init_quantized_decoder(rng, cfg) -> Params:
         params["lm_head"] = _random_quantized(nxt(), cfg.dim, cfg.vocab_size)
     else:
         nxt()
+    if cfg.exit_gate:
+        params["exit_gate"] = {
+            "w": jax.random.normal(jax.random.fold_in(rng, cfg.dim),
+                                   (cfg.dim,), jnp.float32)
+            * (2.0 / (cfg.dim + 1)) ** 0.5,
+            "b": jnp.zeros((1,), jnp.float32)}
     q_dim = cfg.n_heads * cfg.head_dim
     kv_dim = cfg.n_kv_heads * cfg.head_dim
     for _ in range(cfg.n_layers):
@@ -210,6 +218,12 @@ def init_quantized_decoder(rng, cfg) -> Params:
                                               cfg.hidden_dim)
             layer["w_down"] = _random_quantized(nxt(), cfg.hidden_dim,
                                                 cfg.dim)
+        if cfg.sandwich_norm:
+            # as ``init_post_norms``: the scaled residual initialisation
+            for name in ("attn_post_norm", "mlp_post_norm"):
+                layer[name] = jnp.full((cfg.dim,),
+                                       (2.0 * cfg.n_layers) ** -0.5,
+                                       jnp.float32) - cfg.norm_offset
         params["layers"].append(layer)
     return params
 

@@ -42,6 +42,8 @@ def _layer_specs(layer: Params, tp: str, fsdp: Optional[str],
     base = {
         "attn_norm": P(),
         "mlp_norm": P(),
+        "attn_post_norm": P(),      # a sandwich-norm layer's other two
+        "mlp_post_norm": P(),
         "wq": P(fsdp, tp),
         "wk": P(fsdp, tp),
         "wv": P(fsdp, tp),
@@ -76,6 +78,8 @@ def decoder_param_specs(params: Params, tp: str = "tp",
     }
     if "lm_head" in params:
         specs["lm_head"] = _quant_aware(P(fsdp, tp), params["lm_head"])
+    if "exit_gate" in params:       # [D] + bias: replicated like a norm
+        specs["exit_gate"] = {"w": P(), "b": P()}
     return specs
 
 

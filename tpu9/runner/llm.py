@@ -58,12 +58,16 @@ def _build_engine(obj):
         # TPU9_QUANTIZE / TPU9_KV_QUANT (e.g. "int8") opt into quantized
         # serving (ISSUE 6): int8 weights / int8 paged KV pool — same
         # no-handler-change contract, per-deployment.
+        # TPU9_KV_POOL_BLOCKS pins the paged pool (0: dense parity), which
+        # a model whose KV state is many planes deep needs to fit a chip.
         from ..serving.presets import load_engine
         spec_len = int(os.environ.get("TPU9_SPEC_LEN", "0") or 0)
         quantize = os.environ.get("TPU9_QUANTIZE", "") or None
         kv_quant = os.environ.get("TPU9_KV_QUANT", "") or None
+        pool = int(os.environ.get("TPU9_KV_POOL_BLOCKS", "0") or 0)
         return load_engine(obj, compile_ahead=True, spec_len=spec_len,
-                           quantize=quantize, kv_quant=kv_quant)
+                           quantize=quantize, kv_quant=kv_quant,
+                           kv_pool_blocks=pool)
     raise TypeError(f"handler must return an engine, (params, cfg) or a "
                     f"preset name; got {type(obj)}")
 

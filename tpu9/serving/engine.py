@@ -49,6 +49,7 @@ from ..ops.sampling import sample_logits
 from ..utils.aio import reap
 from .flight import maybe as flight_maybe
 from .graphs import GraphFactory
+from .paged_kv import kv_block_bytes
 from .schedule import WindowScheduler
 
 Params = dict[str, Any]
@@ -164,6 +165,9 @@ class _Window:
     mask: Any                 # np active snapshot at dispatch
     reqs: tuple               # slot_req snapshot at dispatch
     n_acc: Any = None         # device [B] (verify): accepted drafts/slot
+    exits: Any = None         # device [k, B, 2] (decode, looped decoder):
+    #                           the pass whose state the head read and the
+    #                           passes the device ran, per token
     spec_len: int = 0
     n_real: Any = None        # np [B] (verify): real (non-pad) drafts
     # observability (ISSUE 8): monotonic/wall anchor pair captured at
@@ -387,6 +391,13 @@ class InferenceEngine:
                        "kvtier_downpages": 0, "kvtier_uppages": 0,
                        "kvtier_uppage_failures": 0,
                        "kvtier_peer_spills": 0}
+        # a looped decoder (ISSUE 34): tokens its decode windows produced,
+        # the passes the device ran for them, and how often the head read
+        # each pass. A plain decoder has none of the three: its programs
+        # return no exit pass, so nothing here could move
+        self._loop_exit_hist = [0] * cfg.loop_steps
+        if cfg.looped:
+            self._stats.update(loop_tokens=0, loop_passes=0)
         # ---- observability (ISSUE 8) ----
         # flight recorder: bounded per-window ring (None = disabled)
         self.flight = flight_maybe(engine_cfg.flight_cap)
@@ -431,14 +442,26 @@ class InferenceEngine:
         # the whole resident weight shard (KV bytes excluded — second-
         # order for the fleet-utilization signal this feeds).
         n_chips = max(int(self.policy.describe().get("n_chips", 1)), 1)
-        wb = nparams = 0
-        for leaf in jax.tree_util.tree_leaves(params):
-            size = getattr(leaf, "size", 0)
-            itemsize = getattr(getattr(leaf, "dtype", None), "itemsize", 0)
-            wb += size * itemsize
-            nparams += size
-        self._phys_bytes_per_token_per_chip = wb / n_chips
-        self._phys_flops_per_token_per_chip = 2.0 * nparams / n_chips
+
+        def tree_size(tree) -> tuple:
+            nbytes = count = 0
+            for leaf in jax.tree_util.tree_leaves(tree):
+                size = getattr(leaf, "size", 0)
+                nbytes += size * getattr(getattr(leaf, "dtype", None),
+                                         "itemsize", 0)
+                count += size
+            return nbytes, count
+
+        wb, nparams = tree_size(params)
+        # a looped decoder streams its layers once a PASS, and a token
+        # passes through them as often; what is resident (``wb``) stays
+        # what the HBM prediction below prices
+        lb, lparams = tree_size(params["layers"]) \
+            if cfg.loop_steps > 1 else (0, 0)
+        extra = cfg.loop_steps - 1
+        self._phys_bytes_per_token_per_chip = (wb + extra * lb) / n_chips
+        self._phys_flops_per_token_per_chip = \
+            2.0 * (nparams + extra * lparams) / n_chips
         # the devices this engine is PLACED on, as jax reports them — the
         # runner's /health and heartbeat carry these, so a replica that
         # came up on the wrong backend is visible from outside the process
@@ -676,9 +699,8 @@ class InferenceEngine:
             # paged prefill path: chunk + splice + gather graphs
             t0 = _time.perf_counter()
             toks = jnp.zeros((1, self._chunk), jnp.int32)
-            last, scratch = self._chunk_fn()(
+            last, self._scratch = self._chunk_fn()(
                 self.params, toks, 0, self._scratch, 0)
-            self._scratch = scratch
             np.asarray(jax.device_get(last[:4]))
             timings[f"chunk_{self._chunk}_s"] = _time.perf_counter() - t0
             t0 = _time.perf_counter()
@@ -688,9 +710,15 @@ class InferenceEngine:
             self._set_pool(self._splice_fn()(
                 self._pool_dict(), self._scratch["k"], self._scratch["v"],
                 0, phys))
+            # as in admission: the gathered copy REPLACES the scratch, and
+            # the old one is let go first so that the two are never live
+            # together (1.6 GB each where the KV state is 192 planes deep)
+            self._scratch = None
             dense = self._gather_fn()(self._pool_dict(),
                                       self.kv_cache["table"][0])
             np.asarray(jax.device_get(dense["k"].ravel()[:4]))
+            self._scratch = {"k": dense["k"], "v": dense["v"]}
+            del dense
             timings["splice_gather_s"] = _time.perf_counter() - t0
             g = self.graphs.group_chunks
             if g > 1:
@@ -734,7 +762,7 @@ class InferenceEngine:
         for k in self.ecfg.decode_steps:
             t0 = _time.perf_counter()
             (self.last_token, self.kv_cache, self.cache_len, self._rng,
-             toks) = self._decode_k(k)(
+             toks, *_exits) = self._decode_k(k)(
                 self.params, self.kv_cache, self.last_token,
                 self.cache_len, inactive, self._rng)
             np.asarray(jax.device_get(toks[-1, :4]))
@@ -1069,6 +1097,14 @@ class InferenceEngine:
             self._phys_bytes_per_token_per_chip
         out["decode_flops_per_token_per_chip"] = \
             self._phys_flops_per_token_per_chip
+        # the depth of the KV state and what one token costs the pool,
+        # whole model: a looped decoder keeps a plane a (pass, layer)
+        out["kv_layers"] = self.cfg.kv_layers
+        out["kv_bytes_per_token"] = kv_block_bytes(self.cfg, 1,
+                                                   self.kv_quant)
+        if self.cfg.looped:
+            out["loop_steps"] = self.cfg.loop_steps
+            out["loop_exit_hist"] = list(self._loop_exit_hist)
         out["device_platform"] = self._devices[0].platform
         out["device_kind"] = self._devices[0].device_kind
         out["device_count"] = len(self._devices)
@@ -1258,9 +1294,13 @@ class InferenceEngine:
         scratch = self._scratch
         if p:
             with phase("engine.admit.dispatch", totals, g=0):
+                # the densified prefix REPLACES the scratch: let the old
+                # one go first, or both are live at the gather's peak
+                scratch = self._scratch = None
                 dense = self._gather_fn()(self._pool_dict(),
                                           jnp.asarray(row))
                 scratch = {"k": dense["k"], "v": dense["v"]}
+                del dense
                 self._stats["admit_dispatches"] += 1
 
         with phase("engine.admit.plan", totals):
@@ -1666,6 +1706,8 @@ class InferenceEngine:
                     req.dec = {"request_id": req.request_id, "windows": 0,
                                "k1_windows": 0, "tokens": 0,
                                "interleaved_windows": 0}
+                    if self.cfg.looped:
+                        req.dec["loop_steps"] = self.cfg.loop_steps
                 req.dec["windows"] += 1
                 req.dec["k1_windows"] += win.k == 1
                 req.dec["tokens"] += n_tok
@@ -1837,13 +1879,14 @@ class InferenceEngine:
                               + self._inflight_steps + k + 1,
                               self.ecfg.max_seq_len))
         (self.last_token, self.kv_cache, self.cache_len, self._rng,
-         toks) = self._decode_k(k)(
+         toks, *exits) = self._decode_k(k)(
             self.params, self.kv_cache, self.last_token, self.cache_len,
             jnp.asarray(self.active), self._rng)
         self._pick_reason = "interleave"
         self._deferred_windows.append(self._obs_stamp_window(
             _Window(kind="decode", k=k, toks=toks, mask=self.active.copy(),
-                    reqs=tuple(self.slot_req))))
+                    reqs=tuple(self.slot_req), exits=exits[0] if exits
+                    else None)))
         self._inflight_steps += k
         self._stats["decode_steps"] += k
         self._stats["admit_interleaved_windows"] += 1
@@ -2148,14 +2191,15 @@ class InferenceEngine:
                                   + self._inflight_steps + k + 1,
                                   self.ecfg.max_seq_len))
         (self.last_token, self.kv_cache,
-         self.cache_len, self._rng, toks) = self._decode_k(k)(
+         self.cache_len, self._rng, toks, *exits) = self._decode_k(k)(
             self.params, self.kv_cache, self.last_token,
             self.cache_len, jnp.asarray(self.active), self._rng)
         self._stats["decode_steps"] += k
         self._inflight_steps += k
         return self._obs_stamp_window(
             _Window(kind="decode", k=k, toks=toks,
-                    mask=self.active.copy(), reqs=tuple(self.slot_req)))
+                    mask=self.active.copy(), reqs=tuple(self.slot_req),
+                    exits=exits[0] if exits else None))
 
     def _dispatch_verify(self, s: int, drafts, n_real) -> _Window:
         t = s + 1
@@ -2187,25 +2231,29 @@ class InferenceEngine:
         with phase("engine.window.sync", self.host_phases,
                    windows=len(wins)):
             # tpu9: noqa[JAX001] intended sync point: the ONE batched window-boundary device_get (PR 5); N sequential reads would pay N round-trips
-            payload = jax.device_get(
-                [(w.toks,) if w.n_acc is None else (w.toks, w.n_acc)
-                 for w in wins])
+            payload = jax.device_get([self._window_arrays(w) for w in wins])
         for w, arrs in zip(wins, payload):
             self._inflight_steps -= w.k
             self._process_window_host(
                 w, np.asarray(arrs[0]),  # tpu9: noqa[JAX001] arrs are already host memory (device_get above); asarray is a no-copy view
                 np.asarray(arrs[1]) if len(arrs) > 1 else None)  # tpu9: noqa[JAX001] host memory, no device sync
 
+    @staticmethod
+    def _window_arrays(win: _Window) -> tuple:
+        """What the host fetches of a window: its tokens, and the ONE array
+        that may ride beside them — a verify window's accepted counts, or a
+        looped decoder's exit passes and pass counts on a decode window."""
+        second = win.n_acc if win.kind == "verify" else win.exits
+        return (win.toks,) if second is None else (win.toks, second)
+
     def _process_deferred(self, win: _Window) -> None:
         with phase("engine.window.sync", self.host_phases, windows=1):
-            if win.n_acc is None:
-                # tpu9: noqa[JAX001] intended sync point: the window's compute is DONE (one-window-overlap drains here); this read is the host fan-out
-                toks, n_acc = jax.device_get(win.toks), None
-            else:
-                toks, n_acc = jax.device_get((win.toks, win.n_acc))  # tpu9: noqa[JAX001] intended sync point: batched toks+n_acc read at the window boundary
-                n_acc = np.asarray(n_acc)  # tpu9: noqa[JAX001] host memory after device_get, no sync
+            # tpu9: noqa[JAX001] intended sync point: the window's compute is DONE (one-window-overlap drains here); this ONE batched read is the host fan-out
+            arrs = jax.device_get(self._window_arrays(win))
         self._inflight_steps -= win.k
-        self._process_window_host(win, np.asarray(toks), n_acc)  # tpu9: noqa[JAX001] host memory after device_get, no sync
+        self._process_window_host(
+            win, np.asarray(arrs[0]),  # tpu9: noqa[JAX001] host memory after device_get, no sync
+            np.asarray(arrs[1]) if len(arrs) > 1 else None)  # tpu9: noqa[JAX001] host memory after device_get, no sync
 
     def _deliver_token(self, slot: int, tok: int) -> None:
         """Deliver ONE generated token to the slot's request, retiring the
@@ -2239,22 +2287,25 @@ class InferenceEngine:
                 and self.slot_req[slot] is win.reqs[slot])
 
     def _process_window_host(self, win: _Window, window,
-                             n_acc=None) -> None:
+                             second=None) -> None:
         """Host-side consumption of one window's tokens. Decode windows
-        carry [k, B] (every step, every slot); verify windows carry the
-        model outputs [B, 1+s] plus per-slot accepted-draft counts —
-        tokens-per-slot-per-window is VARIABLE (1..1+s)."""
+        carry [k, B] (every step, every slot; ``second`` a looped
+        decoder's exit passes and pass counts, [k, B, 2]); verify windows
+        carry the model outputs [B, 1+s] and in ``second`` the per-slot
+        accepted-draft counts — tokens-per-slot-per-window is VARIABLE
+        (1..1+s)."""
         with phase("engine.window.fanout", self.host_phases) as ph:
             t_host0 = time.monotonic()
             win.delivered = {}
             if win.kind == "verify":
-                self._process_verify_host(win, window, n_acc)
+                self._process_verify_host(win, window, second)
             else:
-                self._process_decode_host(win, window)
+                self._process_decode_host(win, window, second)
             self._obs_window(win, t_host0)
             ph.set(tokens=sum(win.delivered.values()))
 
-    def _process_decode_host(self, win: _Window, window) -> None:
+    def _process_decode_host(self, win: _Window, window,
+                             exits=None) -> None:
         shadow: dict[int, list[int]] = {}
         if self._spec_lens:
             # shadow drafts: what WOULD prompt lookup have proposed for
@@ -2307,6 +2358,16 @@ class InferenceEngine:
                 st.observe(m, acc)
         win.delivered = {slot: len(toks)
                          for slot, toks in enumerate(delivered) if toks}
+        if exits is not None:
+            # a looped decoder: beside each delivered token (a slot's are
+            # the window's first steps) the device says which pass the head
+            # read, ``exits[step, slot, 0]``, and how many passes its loop
+            # ran for it, ``exits[step, slot, 1]``
+            for slot, n in win.delivered.items():
+                self._stats["loop_tokens"] += n
+                self._stats["loop_passes"] += int(exits[:n, slot, 1].sum())
+                for step in exits[:n, slot, 0]:
+                    self._loop_exit_hist[int(step)] += 1
 
     def _process_verify_host(self, win: _Window, out, n_acc) -> None:
         s = win.spec_len

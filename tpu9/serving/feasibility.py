@@ -121,14 +121,20 @@ def hbm_budget(preset: str, tpu: "str | TpuSpec", *, max_batch: int = 8,
                max_seq_len: int = 2048, tp: int = 0, fsdp: int = 1,
                overhead_frac: float = 0.10,
                quantize: "str | None" = None,
-               kv_quant: bool = False) -> HbmBudget:
+               kv_quant: bool = False, kv_pool_blocks: int = 0,
+               kv_block_size: int = 0) -> HbmBudget:
     """Compute the per-chip HBM budget for serving ``preset`` on ``tpu``
     with tensor parallelism ``tp`` (default: all chips of the slice) and
     optional weight-only ``fsdp`` sharding on top (ISSUE 9 topology
     planner: weights divide by tp×fsdp; KV divides by the tp head shard
     only). ``quantize="int8"`` prices a PLAIN preset name as int8 weights
     — the same opt-in surface ``load_engine(quantize=)``/TPU9_QUANTIZE
-    uses, so a knob-opted deployment is not mispriced as bf16."""
+    uses, so a knob-opted deployment is not mispriced as bf16.
+    ``kv_pool_blocks`` of ``kv_block_size`` tokens price a PINNED paged
+    pool (``EngineConfig.kv_pool_blocks``, plus its trash block) instead
+    of the dense-parity one — what a model whose KV state is many planes
+    deep deploys with: the pool's reservation, not ``max_batch``, then
+    bounds the batch."""
     from .presets import resolve_preset
     cfg, quantized = resolve_preset(preset, quantize)
     spec = parse_tpu_spec(tpu) if isinstance(tpu, str) else tpu
@@ -149,7 +155,15 @@ def hbm_budget(preset: str, tpu: "str | TpuSpec", *, max_batch: int = 8,
     # actually allocates ~2x and approve deploys that OOM at engine
     # construction. Deployments that pin kv_pool_blocks explicitly can
     # price themselves with kv_cache_bytes(kv_quant=True) directly.
-    kv = kv_cache_bytes(cfg, max_batch, max_seq_len) / kv_shard
+    if kv_pool_blocks:
+        if not kv_block_size:
+            raise ValueError("a pinned kv_pool_blocks is priced by its "
+                             "kv_block_size: give both")
+        from .paged_kv import kv_block_bytes
+        kv = (kv_pool_blocks + 1) \
+            * kv_block_bytes(cfg, kv_block_size, kv_quant) / kv_shard
+    else:
+        kv = kv_cache_bytes(cfg, max_batch, max_seq_len) / kv_shard
     # paged engine's batch-1 dense prefill scratch rides on one chip's
     # shard of the kv lanes (always model-dtype — the int8 pool
     # quantizes at splice, the scratch itself stays bf16)
@@ -171,7 +185,8 @@ def hbm_budget(preset: str, tpu: "str | TpuSpec", *, max_batch: int = 8,
 def validate_llm_deployment(preset: str, tpu: "str | TpuSpec", *,
                             max_batch: int = 8, max_seq_len: int = 2048,
                             tp: int = 0, quantize: "str | None" = None,
-                            kv_quant: bool = False) -> HbmBudget:
+                            kv_quant: bool = False, kv_pool_blocks: int = 0,
+                            kv_block_size: int = 0) -> HbmBudget:
     """Deploy-time gate: raises :class:`InfeasibleDeployment` with the
     arithmetic when the configuration cannot fit; returns the budget when
     it can. Suggests the standard remedies in the message. ``quantize``/
@@ -179,7 +194,9 @@ def validate_llm_deployment(preset: str, tpu: "str | TpuSpec", *,
     deployments are priced as what they serve."""
     budget = hbm_budget(preset, tpu, max_batch=max_batch,
                         max_seq_len=max_seq_len, tp=tp,
-                        quantize=quantize, kv_quant=kv_quant)
+                        quantize=quantize, kv_quant=kv_quant,
+                        kv_pool_blocks=kv_pool_blocks,
+                        kv_block_size=kv_block_size)
     if not budget.fits:
         d = budget.as_dict()
         raise InfeasibleDeployment(
@@ -190,5 +207,6 @@ def validate_llm_deployment(preset: str, tpu: "str | TpuSpec", *,
             f"{int(budget.overhead_frac * 100)}% overhead) but the chip "
             f"has {d['hbm_per_chip_gb']} GB. Remedies: int8 weights "
             f"(-50% weight bytes), smaller max_batch/max_seq_len (KV "
-            f"scales linearly), or a larger slice.")
+            f"scales linearly), a pinned kv_pool_blocks, or a larger "
+            f"slice.")
     return budget

@@ -30,8 +30,8 @@ from typing import Any, Iterator
 import jax
 import jax.numpy as jnp
 
-from ..models.transformer import (DEVICE_SCOPES, decoder_forward,
-                                  init_kv_cache)
+from ..models.transformer import (DEVICE_SCOPES, LOOP_SCOPES,
+                                  decoder_forward, init_kv_cache)
 from ..ops.sampling import sample_logits
 
 Params = dict[str, Any]
@@ -200,10 +200,13 @@ class GraphFactory:
 
         def one_step(params, kv_cache, last_token, cache_len, active, rng):
             positions = cache_len[:, None]          # next position per slot
-            logits, kv_cache = decoder_forward(
+            # a looped decoder also says which pass the head read and how
+            # many passes ran: ``exits`` is ``(exit_info [B, 1, 2],)`` for it
+            # and empty for a plain one
+            logits, kv_cache, *exits = decoder_forward(
                 params, last_token, cfg, positions=positions,
                 kv_cache=kv_cache, cache_len=cache_len + 1, decode=True,
-                mesh=policy.mesh)
+                mesh=policy.mesh, return_exit=cfg.looped)
             rng, sub = jax.random.split(rng)
             next_tok = sample_logits(logits[:, -1], sub,
                                      temperature=ecfg.temperature,
@@ -211,21 +214,24 @@ class GraphFactory:
             # only live slots advance; idle lanes stay parked at 0 so the
             # token-pressure signal reflects real cache occupancy
             new_len = cache_len + active.astype(jnp.int32)
-            return next_tok[:, None].astype(jnp.int32), kv_cache, new_len, rng
+            return (next_tok[:, None].astype(jnp.int32), kv_cache, new_len,
+                    rng, exits)
 
         def decode(params, kv_cache, last_token, cache_len, active, rng):
             def body(carry, _):
                 last, kv, clen, r = carry
-                last, kv, clen, r = one_step(params, kv, last, clen,
-                                             active, r)
-                return (last, kv, clen, r), last[:, 0]
+                last, kv, clen, r, exits = one_step(params, kv, last, clen,
+                                                    active, r)
+                return (last, kv, clen, r), \
+                    (last[:, 0], *(e[:, 0] for e in exits))
 
-            (last, kv_cache, cache_len, rng), toks = jax.lax.scan(
+            (last, kv_cache, cache_len, rng), per_step = jax.lax.scan(
                 body, (last_token, kv_cache, cache_len, rng), None,
                 length=k)
-            # toks [k, B]: the host consumes the whole window in one sync
+            # toks [k, B] (and a looped decoder's exit pass and pass count,
+            # [k, B, 2]): the host consumes the whole window in one sync
             return (last, policy.constrain_kv(kv_cache), cache_len, rng,
-                    toks)
+                    *per_step)
 
         return jax.jit(decode, donate_argnums=(1,))
 
@@ -485,7 +491,7 @@ class GraphFactory:
             cfg = self.cfg
             for bucket in buckets:
                 pre = jax.ShapeDtypeStruct(
-                    (cfg.n_layers, 1, bucket, cfg.n_kv_heads,
+                    (cfg.kv_layers, 1, bucket, cfg.n_kv_heads,
                      cfg.head_dim), cfg.dtype)
                 adense = policy.abstract(
                     {"k": kv_cache["k"], "v": kv_cache["v"]}, kv=True)
@@ -572,7 +578,8 @@ class GraphFactory:
                 round(time.perf_counter() - t0, 4)
             text = self.compiled[key].as_text()
             self.kernel_calls[name] = text.count("tpu_custom_call")
-            scopes = hlo_scopes(text)
+            # a plain program runs nothing under the loop's scopes
+            scopes = hlo_scopes(text, DEVICE_SCOPES + LOOP_SCOPES)
             if scopes:
                 self.device_scopes[name] = scopes
             else:

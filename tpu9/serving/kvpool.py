@@ -205,7 +205,7 @@ class KvPool:
         :meth:`array_specs` abstracts from (they cannot drift)."""
         import jax.numpy as jnp
         cfg, ecfg = self.cfg, self.ecfg
-        pool_shape = (cfg.n_layers, self.n_blocks, ecfg.kv_block_size,
+        pool_shape = (cfg.kv_layers, self.n_blocks, ecfg.kv_block_size,
                       cfg.n_kv_heads, cfg.head_dim)
         dt = jnp.int8 if self.kv_quant else cfg.dtype
         shapes = {"k": (pool_shape, dt), "v": (pool_shape, dt),

@@ -17,7 +17,8 @@ Format v1 (little-endian)::
 
 Header fields:
 
-- geometry: ``n_layers``, ``kv_block_size``, ``n_kv_heads``,
+- geometry: ``n_layers`` (the depth of the pool, ``cfg.kv_layers``: a looped
+  decoder keeps a plane per pass and layer), ``kv_block_size``, ``n_kv_heads``,
   ``head_dim``, ``kv_dtype`` ("bfloat16" | "int8" | ...) — must match
   the importing pool exactly (block ids are meaningless across
   geometries);
@@ -75,7 +76,7 @@ def _np_dtype(name: str) -> np.dtype:
 
 def geometry(cfg, ecfg, kv_quant: bool) -> dict:
     """The pool-identity fields import refuses to cross."""
-    return {"n_layers": int(cfg.n_layers),
+    return {"n_layers": int(cfg.kv_layers),
             "kv_block_size": int(ecfg.kv_block_size),
             "n_kv_heads": int(cfg.n_kv_heads),
             "head_dim": int(cfg.head_dim),

@@ -33,7 +33,8 @@ def blocks_for(n_tokens: int, block_s: int) -> int:
 
 
 def kv_block_bytes(cfg, block_s: int, quantized: bool = False) -> int:
-    """HBM bytes ONE k+v pool block holds across all layers of ``cfg``.
+    """HBM bytes ONE k+v pool block holds across the whole depth of the KV
+    state of ``cfg`` (``kv_layers``: every layer of every pass).
     The single source of truth the engine's equal-HBM pool sizing, the
     feasibility gate, and the quant bench all price blocks with — int8
     blocks carry 1 byte/element plus one f32 absmax scale per
@@ -43,7 +44,7 @@ def kv_block_bytes(cfg, block_s: int, quantized: bool = False) -> int:
                               else np.dtype(cfg.dtype).itemsize)
     if quantized:
         per_vec += 4                       # f32 scale alongside the pool
-    return 2 * cfg.n_layers * block_s * cfg.n_kv_heads * per_vec
+    return 2 * cfg.kv_layers * block_s * cfg.n_kv_heads * per_vec
 
 
 @dataclass

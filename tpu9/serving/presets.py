@@ -31,7 +31,9 @@ def resolve_preset(name: str, quantize: Optional[str] = None):
     from ..models.gemma import GEMMA_PRESETS
     from ..models.llama import LLAMA_PRESETS
     from ..models.mixtral import MIXTRAL_PRESETS
-    presets = {**LLAMA_PRESETS, **GEMMA_PRESETS, **MIXTRAL_PRESETS}
+    from ..models.ouro import OURO_PRESETS
+    presets = {**LLAMA_PRESETS, **GEMMA_PRESETS, **MIXTRAL_PRESETS,
+               **OURO_PRESETS}
     quantized = name.endswith("-int8") or quantize == "int8"
     base = name[:-len("-int8")] if name.endswith("-int8") else name
     if base not in presets:
@@ -144,7 +146,10 @@ def load_engine(name: str, *, max_batch: int = 8, max_seq_len: int = 2048,
     from .shard import make_policy, resolve_topology
     topo = resolve_topology(topology, preset=name, tpu=tpu,
                             max_batch=max_batch, max_seq_len=max_seq_len,
-                            quantize=quantize, kv_quant=bool(kv_quant))
+                            quantize=quantize, kv_quant=bool(kv_quant),
+                            kv_pool_blocks=kv_pool_blocks,
+                            kv_block_size=min(kv_block_size,
+                                              min(prefill_buckets)))
     policy = make_policy(topo)
     from ..ops.quant import validate_quant_mode
     kv_quant = validate_quant_mode(kv_quant, "kv_quant")

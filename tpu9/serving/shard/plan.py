@@ -132,7 +132,8 @@ def candidate_topologies(n_kv_heads: int, max_chips: int) -> list[Topology]:
 def plan_topology(preset: str, tpu: "str | Any", *, max_batch: int = 8,
                   max_seq_len: int = 2048, quantize: "str | None" = None,
                   kv_quant: bool = False,
-                  overhead_frac: float = 0.10) -> TopologyPlan:
+                  overhead_frac: float = 0.10, kv_pool_blocks: int = 0,
+                  kv_block_size: int = 0) -> TopologyPlan:
     """Smallest power-of-two submesh of ``tpu`` that provably serves
     ``preset``. Raises :class:`InfeasibleDeployment` (with the full
     arithmetic of the LARGEST candidate) when even the whole slice cannot
@@ -151,7 +152,9 @@ def plan_topology(preset: str, tpu: "str | Any", *, max_batch: int = 8,
         budget = hbm_budget(preset, spec, max_batch=max_batch,
                             max_seq_len=max_seq_len, tp=topo.tp,
                             fsdp=topo.fsdp, overhead_frac=overhead_frac,
-                            quantize=quantize, kv_quant=kv_quant)
+                            quantize=quantize, kv_quant=kv_quant,
+                            kv_pool_blocks=kv_pool_blocks,
+                            kv_block_size=kv_block_size)
         if budget.fits:
             return TopologyPlan(preset=preset, topology=topo, budget=budget,
                                 rejected=tuple(rejected))
