@@ -226,6 +226,32 @@ def test_decode_programs_carry_the_pool_whole_on_a_described_v5e(
             rf"\[[\d,]*{BS},{per_chip[3]},{per_chip[4]}\]", ln)], key
 
 
+def test_the_sorted_moe_layer_compiles_at_mixtral_widths(
+        v5e, no_compile_cache, monkeypatch):
+    """An admission group's MoE layer (512 tokens, 8 experts of 4096 x
+    14336, top-2) as the chip runs it: sort, gather, ONE grouped-FFN kernel
+    over the tiles that hold rows, weighted sum. The kernel asks for more
+    VMEM than the default scope (12 MB of weight blocks a step, twice),
+    which only the TPU's compiler can refuse; and the layer keeps no
+    ``[E, 512, 14336]`` temporary (117 MB each in the one-hot form)."""
+    import tpu9.ops.grouped_ffn as grouped_ops
+    from tpu9.models.moe import MoeConfig, moe_ffn_sorted
+    monkeypatch.setattr(grouped_ops, "on_tpu", lambda: True)
+    e, d, h, n = 8, 4096, 14336, 512
+    one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    params = {"router": s((d, e), jnp.float32), "w_gate": s((e, d, h)),
+              "w_up": s((e, d, h)), "w_down": s((e, h, d))}
+    cfg = MoeConfig(dim=d, hidden_dim=h, n_experts=e, top_k=2,
+                    capacity_factor=4.0, dtype=jnp.bfloat16)
+    compiled = jax.jit(lambda p, x: moe_ffn_sorted(p, x, cfg)).lower(
+        params, s((1, n, d))).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
 def test_a_looped_decode_program_carries_the_pool_through_its_pass_loop(
         v5e, no_compile_cache, monkeypatch):
     """The looped configuration at its published widths, two layers deep:

@@ -18,6 +18,7 @@ Params layout: every expert tensor has a leading ``n_experts`` dim sharded
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any
 
@@ -79,6 +80,17 @@ def moe_param_specs(params: Params, axis: str = "ep") -> Params:
     }
 
 
+def _top_k_gates(params: Params, xf: jnp.ndarray, k: int):
+    """Routing, f32 for numerics: xf [N, d] → (probs [N, E], the chosen
+    gates [N, k] renormalised to a convex combination, their experts)."""
+    logits = xf.astype(jnp.float32) @ params["router"]          # [N, E]
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, gate_idx = jax.lax.top_k(probs, k)               # [N, k]
+    gate_vals = gate_vals / jnp.maximum(
+        gate_vals.sum(-1, keepdims=True), 1e-9)
+    return probs, gate_vals, gate_idx
+
+
 def _capacity(n_tokens: int, cfg: MoeConfig) -> int:
     cap = int(cfg.top_k * n_tokens * cfg.capacity_factor / cfg.n_experts)
     # capacity must be static, positive, and lane-friendly
@@ -101,12 +113,7 @@ def moe_ffn(params: Params, x: jnp.ndarray, cfg: MoeConfig,
 
     # -- routing (f32 for numerics) ------------------------------------------
     with jax.named_scope("moe.route"):
-        logits = xf.astype(jnp.float32) @ params["router"]      # [N, E]
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, gate_idx = jax.lax.top_k(probs, k)           # [N, k]
-        # renormalize the chosen gates so outputs are a convex combination
-        gate_vals = gate_vals / jnp.maximum(
-            gate_vals.sum(-1, keepdims=True), 1e-9)
+        probs, gate_vals, gate_idx = _top_k_gates(params, xf, k)
 
         # one-hot expert assignment per (token, slot): [N, k, E]
         assign = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32)
@@ -160,3 +167,82 @@ def moe_ffn(params: Params, x: jnp.ndarray, cfg: MoeConfig,
     aux = {"balance_loss": balance_loss, "dropped_frac": dropped,
            "expert_load": frac_tokens}
     return out.reshape(b, t, d).astype(x.dtype), aux
+
+
+# -- serving: dropless, by sorting --------------------------------------------
+
+# A serving call of more than this many tokens takes the sorted form. The
+# number is the chip-independent reading of "E*n rows of FLOPs exceed one
+# pass over the experts", rounded up to the next chunk multiple: the
+# one-hot form multiplies every expert by a capacity of n rows, so its cost
+# is E*n rows whatever holds a token, and it sits at its HBM floor (one read
+# of the expert stacks) up to peak FLOP/s over peak bytes/s rows a call —
+# about 240 for bf16 on a v5e (197e12 / 819e9) — and is compute-bound on
+# empty rows above. The programs put 32 (a decode step), 128 (a chunk) or
+# 512 (an admission group) rows into a call, so nothing sits near it.
+SORTED_MIN_TOKENS = 256
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "grouped_ffn"))
+def moe_ffn_sorted(params: Params, x: jnp.ndarray, cfg: MoeConfig,
+                   grouped_ffn=None) -> jnp.ndarray:
+    """Dropless top-k by sorting: x [B, T, dim] → [B, T, dim].
+
+    The N*k (token, slot) assignments are ordered by expert with a counting
+    sort — stable, so a token's place inside an expert is its place in the
+    call — the rows are gathered into that order, each expert's rows padded
+    to whole row tiles, and ``tpu9.ops.grouped_ffn`` multiplies every
+    expert's weights by that expert's tiles only. No capacity: every token
+    reaches all k of its experts under any routing, and the work is N*k
+    rows plus at most a tile an expert, whatever the routing. Routing in
+    f32, operands in ``cfg.dtype`` with f32 accumulation, the per-token sum
+    in f32: the precision of :func:`moe_ffn`, whose dropless case
+    (``capacity_factor = E/k``) this equals. ``grouped_ffn`` overrides the
+    backend's choice of grouped matmul (tests: the kernel, interpreted).
+    Jitted so that a program traces and lowers it once for all its layers."""
+    from ..ops import grouped_ffn as ops
+    b, t, d = x.shape
+    n, e, k = b * t, cfg.n_experts, cfg.top_k
+    tm = ops.ROW_TILE
+    # an expert's rows end inside a tile: at most tm - 1 rows of padding each
+    n_rows = (n * k + e * (tm - 1)) // tm * tm
+    xf = x.reshape(n, d)
+
+    with jax.named_scope("moe.route"):
+        _, gate_vals, gate_idx = _top_k_gates(params, xf, k)
+        expert = gate_idx.reshape(n * k)            # token-major, slot-minor
+        assign = jax.nn.one_hot(expert, e, dtype=jnp.int32)      # [N*k, E]
+        counts = assign.sum(0)
+        # a (token, slot)'s rank among its expert's rows: earlier claims
+        rank = ((jnp.cumsum(assign, axis=0) - assign) * assign).sum(-1)
+        tiles = (counts + tm - 1) // tm
+        first_row = (jnp.cumsum(tiles) - tiles) * tm   # an expert's first
+        dest = first_row[expert] + rank             # the row that holds it
+        # the layout's rows, back to their tokens (padding rows: token 0,
+        # whose product no token reads)
+        token = jnp.zeros(n_rows, jnp.int32).at[dest].set(
+            jnp.arange(n * k, dtype=jnp.int32) // k, unique_indices=True)
+        xs = xf.astype(cfg.dtype)[token]                         # [R, d]
+
+    with jax.named_scope("moe.experts"):
+        ys = (grouped_ffn or ops.grouped_ffn)(
+            xs, tiles, params["w_gate"], params["w_up"], params["w_down"],
+            act=cfg.act)                                         # [R, d] f32
+
+    with jax.named_scope("moe.combine"):
+        out = (ys[dest.reshape(n, k)] * gate_vals[..., None]).sum(1)
+    return out.reshape(b, t, d).astype(x.dtype)
+
+
+def takes_sorted_form(params: Params, n_tokens: int, mesh=None) -> bool:
+    """The shape rule of a serving call: more tokens than
+    ``SORTED_MIN_TOKENS`` and expert stacks that are plain bf16 arrays in
+    one device's memory. Int8 entries keep their scaled einsum and stacks
+    sharded over a mesh keep the einsums XLA partitions (a kernel is not
+    partitioned): both stay with the one-hot form."""
+    from ..ops.quant import is_quantized_entry
+    stacks = [params[w] for w in ("w_gate", "w_up", "w_down")]
+    return (n_tokens > SORTED_MIN_TOKENS
+            and (mesh is None or mesh.size == 1)
+            and not any(is_quantized_entry(w) for w in stacks)
+            and all(w.dtype == jnp.bfloat16 for w in stacks))

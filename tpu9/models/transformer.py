@@ -372,11 +372,18 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
 
 
 def _mlp_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
-               compute_dtype=None):
+               compute_dtype=None, serving: bool = False, mesh=None):
     h = _pre_norm(x, layer["mlp_norm"], cfg, compute_dtype)
     if cfg.n_experts:
-        from .moe import moe_ffn
-        y, aux = moe_ffn(layer["moe"], h, _moe_cfg(cfg), ep_sharded=False)
+        from .moe import moe_ffn, moe_ffn_sorted, takes_sorted_form
+        # one algorithm, dropless top-k, in the form that is cheapest for
+        # the rows of this call (a serving step keeps no router statistics)
+        if serving and takes_sorted_form(
+                layer["moe"], h.shape[0] * h.shape[1], mesh):
+            y, aux = moe_ffn_sorted(layer["moe"], h, _moe_cfg(cfg)), None
+        else:
+            y, aux = moe_ffn(layer["moe"], h, _moe_cfg(cfg),
+                             ep_sharded=False)
         with jax.named_scope("moe.combine"):
             return x + y, aux
     with jax.named_scope("ffn"):
@@ -400,7 +407,8 @@ def _layers(params: Params, x, cfg: DecoderConfig, positions, sin, cos,
         x, kv_cache = _attn_block(layer, x, cfg, positions, sin, cos,
                                   kv_cache, cache_base + i, cache_len,
                                   decode, mesh, compute_dtype)
-        x, aux = _mlp_block(layer, x, cfg, compute_dtype)
+        x, aux = _mlp_block(layer, x, cfg, compute_dtype,
+                            serving=kv_cache is not None, mesh=mesh)
         if aux is not None:
             moe_balance = moe_balance + aux["balance_loss"]
     return x, kv_cache, moe_balance
