@@ -39,7 +39,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 
-from benchmark import manifest, stack as stack_mod    # noqa: E402
+from benchmark import chips, manifest, stack as stack_mod    # noqa: E402
 
 PROBE_TOKENS = 16            # answered per probe
 BRINGUP_TIMEOUT_S = 1100     # a first run compiles
@@ -159,6 +159,10 @@ class Session:
                 f"--xla_force_host_platform_device_count={self.chips}"
         self.stack = stack_mod.Stack(self.run_dir, env, self.chips,
                                      fake_chips=args.rehearse)
+        self.wall: dict = {}     # step -> seconds since the process started
+
+    def mark(self, step: str) -> None:
+        self.wall[step] = round(time.time() - T_START, 2)
 
     # -- bring-up -----------------------------------------------------------
 
@@ -172,7 +176,13 @@ class Session:
             tpu=self.config["endpoint"]["tpu"],
             memory=self.config["endpoint"]["memory"], env=self.container_env)
         self.stack.deploy(self.name, app)
-        info(stack_s=started["seconds"], worker_chips=started["worker_chips"])
+        # `native_build`: the tier-1 count and the program's native paths turn
+        # on this ignored directory (PR 32), so a check's two sides can be
+        # told apart afterwards
+        info(stack_s=started["seconds"], worker_chips=started["worker_chips"],
+             chips_wait_s=started["chips_wait_s"],
+             native_build=os.path.isdir(os.path.join(ROOT, "native", "build")))
+        self.mark("stack_up")
 
     def health(self) -> dict:
         return self.stack.api("GET", f"/endpoint/{self.name}/health",
@@ -229,8 +239,10 @@ async def measure(s: Session, plan: dict, seconds: float, trace: bool) -> dict:
         ref = await off_loop(mailbox, s.run_dir, "reference",
                              {"probes": probes}, REFERENCE_TIMEOUT_S)
         out["reference"] = ref
+        # the runner holds the chips now: a probe that reads none of them
+        # busy here is blind, and the wait at the next start with it
         info(probes=len(probes), bringup_request_s=out["bringup_request_s"],
-             reference=ref,
+             reference=ref, chips_held_by_runner=sorted(chips.busy()),
              coldstart={k: v for k, v in h.items()
                         if k.startswith("coldstart_")})
 
@@ -251,13 +263,15 @@ async def measure(s: Session, plan: dict, seconds: float, trace: bool) -> dict:
                 off_loop(s.health), off_loop(s.gateway_metrics))
             if trace:
                 # a third of the way into the window, the benchmark's thread
-                # in the runner traces the mix's number of seconds
+                # in the runner traces the mix's number of decode steps, or
+                # its number of seconds where those come first
                 await asyncio.sleep(max(seconds * 0.3 - cl.clock(), 0))
                 marks["tracing"] = off_loop(
                     mailbox, s.run_dir, "trace",
                     {"dir": os.path.join(s.run_dir, "trace"),
                      "seconds": min(float(s.traffic.get("trace_seconds", 5)),
-                                    seconds * 0.6)}, 300)
+                                    seconds * 0.6),
+                     "steps": s.traffic.get("trace_steps")}, 300)
             await asyncio.sleep(max(seconds * 0.5 - cl.clock(), 0))
             marks["health_mid"] = await off_loop(s.health)
 
@@ -298,9 +312,12 @@ def result_line(s: Session, got: dict, seconds: float) -> dict:
     # process start -> window open, less the TPU runtime's own start-up in
     # the runner (`device_open_s`, a per-layer metric): it read 5.8-15.5 s on
     # one chip from machine to machine (PR 23) while everything else in
-    # set-up repeated to 1 %, and no code of this repository runs in it
+    # set-up repeated to 1 %, and no code of this repository runs in it.
+    # Less, too, the wait for free chips before the worker started
+    # (`chips_wait_s`; 0 where the last run on the machine had let go)
     opened = got["health_ready"].get("coldstart_device_open_s", 0.0)
-    setup_s = got["t_window_open"] - T_START - opened
+    waited = s.stack.chips_wait_s
+    setup_s = got["t_window_open"] - T_START - opened - waited
     c = metrics.counts(records)
     tol = s.config["correct_tolerance_logit"]
     compiles = got["health1"]["graph_compiles_post_warmup"]
@@ -315,7 +332,8 @@ def result_line(s: Session, got: dict, seconds: float) -> dict:
     for q in ("ttft", "tpot"):
         for p in (50, 90, 95):
             tails[f"{q}_p{p}_ms"] = metrics.latency(records, q, p)
-    info(counts=c, setup_s=setup_s, device_open_s=opened, latencies=tails,
+    info(counts=c, setup_s=setup_s, device_open_s=opened,
+         chips_wait_s=waited, latencies=tails,
          gen_late_p99_ms=metrics.gen_late_ms(records),
          out_tok_s=metrics.out_tok_s(records, seconds),
          window_end_clock=got["window_end_clock"],
@@ -341,6 +359,7 @@ def result_line(s: Session, got: dict, seconds: float) -> dict:
     trace = trace_mod.reduce_dir(os.path.join(s.run_dir, "trace"),
                                  s.family.STEP_MARKER,
                                  s.family.marker_calls_per_step(s.model))
+    s.mark("trace_read")      # the device planes; the readers parse again
     info(profile=got.get("profile"),
          trace={k: v for k, v in trace.items()
                 if k not in ("device_ops", "idle_gaps", "op_seconds")})
@@ -423,14 +442,20 @@ def main() -> int:
             plan = s.kind.plan(s.traffic, args.seed, seconds,
                                s.model["vocab_size"])
             got = asyncio.run(measure(s, plan, seconds, bool(args.trace)))
+            s.wall["window_open"] = round(got["t_window_open"] - T_START, 2)
+            s.wall["window_end"] = round(
+                s.wall["window_open"] + got["window_end_clock"], 2)
+            s.mark("measured")            # window, drain, the trace written
             if s.stack.failed_starts():
                 raise RunFailed("the worker lost containers on the way: "
                                 f"{s.stack.failed_starts()}")
-            s.stack.stop()                # the chip is free; now reduce
+            s.stack.stop()                # the chip is let go; now reduce
+            s.mark("stack_stopped")
             with open(os.path.join(s.run_dir, "records.json"), "w") as f:
                 json.dump([{k: v for k, v in r.items() if k != "tokens"}
                            for r in got["records"]], f)
             line = result_line(s, got, seconds)
+            s.mark("reduced")
             code = 3 if args.rehearse else 0
     except (RunFailed, stack_mod.StackError, asyncio.TimeoutError,
             OSError, KeyError) as exc:
@@ -438,6 +463,11 @@ def main() -> int:
         s.stack.dump_logs()
     finally:
         s.stack.stop()
+        # the next run on this machine finds the chips free; reducing the
+        # trace has already covered most of this wait
+        released = s.stack.release()
+        s.mark("chips_released")
+        info(wall_s=s.wall, chips_release_s=round(released, 3))
     if line is None:
         return code if code != 0 else 1
     with open(os.path.join(s.run_dir, "result.json"), "w") as f:

@@ -12,11 +12,12 @@ run directory, the thread answers with ``<op>.result.json``.
 - ``reference``: the plain float32 reference over the probes, on the weights
   the engine serves (and, on a mesh, on the same mesh), while the engine idles
 - ``memory``: ``peak_bytes_in_use`` of every device of the engine
-- ``trace``: a profiler trace of a stated number of seconds, started and
-  stopped here. The runner's own ``POST /profile`` counts main-loop decode
-  windows only, so a few armed windows of an open loop traced 15-35 s and
-  writing that out held the run for minutes (PR 23); only the process that
-  holds the chip can trace it, and this thread is in it.
+- ``trace``: a profiler trace of a stated number of decode steps, or of a
+  stated number of seconds where those come first, started and stopped here
+  (:func:`bounded_trace`). The runner's own ``POST /profile`` counts main-loop
+  decode windows only, so a few armed windows of an open loop traced 15-35 s
+  and writing that out held the run for minutes (PR 23); only the process
+  that holds the chip can trace it, and this thread is in it.
 """
 
 from __future__ import annotations
@@ -126,6 +127,35 @@ def build_engine(args: dict):
     return engine
 
 
+TRACE_POLL_S = 0.02
+
+
+def bounded_trace(profiler, steps_now, directory: str, seconds: float,
+                  steps=None, **start_options) -> dict:
+    """Trace until ``steps`` more decode steps have been dispatched
+    (``steps_now()`` is the engine's counter) or ``seconds`` have passed,
+    whichever comes first. What stopping a trace and reducing it cost
+    follows the number of traced events, not the seconds (PR 34), and a
+    closed loop with a faster step puts more steps into the same seconds:
+    bounded by steps, a traced run takes as long on a faster program."""
+    t0 = time.monotonic()
+    profiler.start_trace(directory, **start_options)
+    started = time.monotonic()
+    first = steps_now()
+    while True:
+        left = started + seconds - time.monotonic()
+        if left <= 0 or (steps and steps_now() - first >= steps):
+            break
+        time.sleep(min(TRACE_POLL_S, left))
+    asked = time.monotonic()
+    traced_steps = steps_now() - first
+    profiler.stop_trace()
+    return {"start_s": round(started - t0, 3),
+            "traced_s": round(asked - started, 3),
+            "traced_steps": traced_steps,
+            "stop_s": round(time.monotonic() - asked, 3)}
+
+
 def _answer(run_dir: str, op: str, fn) -> None:
     req = os.path.join(run_dir, f"{op}.request.json")
     if not os.path.exists(req):
@@ -157,15 +187,13 @@ def _mailbox(run_dir, engine, model, config, n_devices) -> None:
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0     # millions of host events otherwise
         options.host_tracer_level = 1
-        t0 = time.monotonic()
-        jax.profiler.start_trace(payload["dir"], profiler_options=options)
-        started = time.monotonic()
-        time.sleep(float(payload["seconds"]))
-        asked = time.monotonic()
-        jax.profiler.stop_trace()
-        return {"start_s": round(started - t0, 3),
-                "traced_s": round(asked - started, 3),
-                "stop_s": round(time.monotonic() - asked, 3)}
+        # the counter itself, not ``engine.stats()``: that sweeps every
+        # chip's memory statistics and the latency rings, fifty times a
+        # second beside the serve loop it would slow what is being traced
+        return bounded_trace(
+            jax.profiler, lambda: engine._stats["decode_steps"],
+            payload["dir"], float(payload["seconds"]), payload.get("steps"),
+            profiler_options=options)
 
     def memory(_payload):
         peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))

@@ -20,6 +20,8 @@ import time
 import urllib.error
 import urllib.request
 
+from benchmark import chips
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -86,8 +88,17 @@ class Stack:
         self.fake_chips = fake_chips
         self.procs: list = []
         self.url = self.token = ""
+        self.chips_wait_s = 0.0
 
     def start(self) -> dict:
+        # the last run on this machine, by this harness or an older one, may
+        # still hold the chips (``chips.py``): a worker started now would lose
+        # its first replica. No code of the program runs in this wait, and it
+        # is reported apart (`chips_wait_s`), not as set-up.
+        try:
+            self.chips_wait_s = chips.wait_free()
+        except chips.ChipsBusy as exc:
+            raise StackError(f"the chips are not free: {exc}") from None
         t0 = time.time()
         w = self.workdir
         http_port, state_port = _free_port(), _free_port()
@@ -127,11 +138,12 @@ class Stack:
             self._check_alive()
             workers = self.api("GET", "/api/v1/worker")
         check(workers, "no worker registered within 60 s")
-        chips = workers[0].get("tpu_chip_count")
-        check(chips == self.n_chips,
-              f"the worker found {chips} TPU chips on this machine, the cell "
+        found = workers[0].get("tpu_chip_count")
+        check(found == self.n_chips,
+              f"the worker found {found} TPU chips on this machine, the cell "
               f"asks for {self.n_chips}")
-        return {"seconds": round(time.time() - t0, 2), "worker_chips": chips}
+        return {"seconds": round(time.time() - t0, 2), "worker_chips": found,
+                "chips_wait_s": round(self.chips_wait_s, 3)}
 
     def _spawn(self, cmd: list, log_path: str, env: dict) -> None:
         with open(log_path, "w") as log:
@@ -248,6 +260,19 @@ class Stack:
                 os.path.exists(f"/proc/{pid}") and _alive(pid)
                 for pid in pids):
             time.sleep(0.05)
+
+    def release(self) -> float:
+        """After ``stop``: the seconds until the chips are free again, which
+        on four chips is after the process trees have gone, so that the next
+        run on this machine finds nothing to wait for. A run's result does
+        not hang on it: chips that stay busy are the next start's to name."""
+        if not self.procs:      # nothing was started: nothing to let go
+            return 0.0
+        try:
+            return chips.wait_free()
+        except chips.ChipsBusy as exc:
+            print(f"benchmark: the chips are not free: {exc}", file=sys.stderr)
+            return chips.WAIT_S
 
 
 def _alive(pid: int) -> bool:

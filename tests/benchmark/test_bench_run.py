@@ -62,6 +62,24 @@ def test_rehearsal_walks_every_step_and_prints_no_result(cell, trace):
         assert "decode_step_ms" not in line["metrics"]
         assert "decode_batch_mean" in line["metrics"]
         assert "busy_s" not in line["device"]
+        # the trace's bounds and what they held: steps first, seconds the
+        # ceiling (at these sizes the seconds come first)
+        profile = next(i["profile"] for i in infos if "profile" in i)
+        assert set(profile) == {"start_s", "traced_s", "traced_steps", "stop_s"}
+        assert profile["traced_steps"] > 0 and profile["traced_s"] > 0
+    # no device node here, so nothing to wait for; the wait, the release and
+    # where the run's seconds went are said all the same
+    started = next(i for i in infos if "stack_s" in i)
+    assert started["chips_wait_s"] == 0.0
+    assert isinstance(started["native_build"], bool)
+    assert next(i for i in infos if "setup_s" in i)["chips_wait_s"] == 0.0
+    assert next(i for i in infos if "probes" in i)["chips_held_by_runner"] == []
+    end = next(i for i in infos if "wall_s" in i)
+    assert end["chips_release_s"] == 0.0
+    assert list(end["wall_s"]) == [
+        "stack_up", "window_open", "window_end", "measured", "stack_stopped",
+        *(["trace_read"] if trace else []), "reduced", "chips_released"]
+    assert list(end["wall_s"].values()) == sorted(end["wall_s"].values())
     ref = next(i["reference"] for i in infos if "reference" in i)
     assert ref["tokens_checked"] == 96
     counts = next(i["counts"] for i in infos if "counts" in i)
