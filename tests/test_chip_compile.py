@@ -152,6 +152,105 @@ def test_kernel_compiles_for_a_described_v5e(v5e, no_compile_cache,
                     q, k, v, t, n)).lower(*args).compile()
 
 
+def _kernel_names(text: str) -> list:
+    """The names, without their numbers, of the instructions of an optimised
+    HLO text that are Mosaic kernels."""
+    return [re.sub(r"[.\d]+$", "", ln.split(" = ", 1)[0].split()[-1]
+                   .lstrip("%"))
+            for ln in text.splitlines()
+            if " custom-call(" in ln and "tpu_custom_call" in ln]
+
+
+# the benchmark's cells as the kernel sees them (ISSUE 40): slots, KV heads,
+# query heads a KV head, table columns, pool blocks, planes of the pool, chips
+CELL_SHAPES = {"mistral-tp4-long": (8, 8, 4, 129, 897, 32, 4),
+               "ouro-qa": (16, 16, 1, 9, 31, 192, 1),
+               "mixtral": (32, 8, 4, 33, 513, 4, 1)}
+
+
+@pytest.mark.parametrize("cell", list(CELL_SHAPES))
+def test_the_page_walk_compiles_at_a_cells_shapes(v5e, no_compile_cache,
+                                                  monkeypatch, cell):
+    """The dispatcher's call at a cell's widths, the whole stacked pool and
+    a layer that is an operand: two KV heads a chip under ``shard_map`` with
+    a 129-column table, sixteen heads without grouping and 9 columns, eight
+    heads and 33. The kernel copies its pages itself (nothing of the pool
+    is a temporary) and the compiler prints it under the step marker's
+    name."""
+    from benchmark.families import decoder
+    monkeypatch.setattr(attention_ops, "on_tpu", lambda: True)
+    b, kh, group, columns, blocks, planes, chips = CELL_SHAPES[cell]
+    mesh = Mesh(np.array(v5e[:chips]).reshape(1, 1, 1, chips),
+                ("dp", "fsdp", "sp", "tp"))
+
+    def s(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+    pool = s((planes, blocks, BS, kh, D), jnp.bfloat16, attention_ops._POOL5)
+    args = [s((b, 1, kh * group, D), jnp.bfloat16, attention_ops._HEADS4),
+            pool, pool, s((b, columns), jnp.int32, P()),
+            s((b,), jnp.int32, P()), s((), jnp.int32, P())]
+    compiled = jax.jit(
+        lambda q, k, v, table, lens, layer: paged_attention_dispatch(
+            q, k, v, table, lens, mesh=mesh, layer=layer)).lower(
+                *args).compile()
+    text = compiled.as_text()
+    assert _kernel_names(text) == [decoder.STEP_MARKER]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+    assert "all-gather" not in text
+
+
+@pytest.mark.parametrize("configuration", [
+    "mixtral-8x7b-l4", "mistral-7b-v0.3-tp4", "ouro-2.6b"])
+def test_a_decode_programs_only_kernels_are_the_step_markers(
+        v5e, no_compile_cache, monkeypatch, configuration):
+    """What the benchmark counts decode steps by (``STEP_MARKER``,
+    ``marker_calls_per_step``): in a configuration's K = 1 decode program
+    (two layers of it) every Mosaic kernel is an instruction named
+    ``paged_decode_attention``, one a layer, and no other instruction
+    carries that prefix — a helper kernel or a split into partial and
+    combine calls would make the trace's step count wrong."""
+    cfg, family, _, _, jobs = _decode_programs(v5e, monkeypatch,
+                                               configuration, n_layers=2)
+    (_, fn, args), = [job for job in jobs if job[0] == ("decode", 1)]
+    text = fn.lower(*args).compile().as_text()
+    assert _kernel_names(text) == [family.STEP_MARKER] * cfg.n_layers
+    named = [ln for ln in text.splitlines() if re.match(
+        rf"\s+(ROOT )?%?{family.STEP_MARKER}", ln)]
+    assert len(named) == cfg.n_layers
+    assert all("tpu_custom_call" in ln for ln in named)
+
+
+def _decode_programs(v5e, monkeypatch, configuration, n_layers=None):
+    """``(cfg, family, n_chips, k_pool, jobs)``: a benchmark configuration's
+    decode programs at its engine's shapes (abstract arguments, no weights;
+    as many described chips as its topology names), dispatching as on the
+    chip; ``n_layers`` cuts the model's depth."""
+    from dataclasses import replace
+
+    from benchmark import manifest, serve
+    from tpu9.serving.graphs import GraphFactory, abstract_state
+    from tpu9.serving.presets import abstract_params_for
+    from tpu9.serving.shard.plan import parse_topology
+    from tpu9.serving.shard.policy import MeshPolicy
+    monkeypatch.setattr(attention_ops, "on_tpu", lambda: True)
+    config = manifest.load_config(manifest.load(), configuration)
+    family = manifest.family(config)
+    cfg = family.program_config(family.model_sizes(config))
+    if n_layers:
+        cfg = replace(cfg, n_layers=n_layers)
+    ecfg = serve.engine_config(config["engine"])
+    topology = parse_topology(config["engine"]["topology"])
+    policy = MeshPolicy(topology, devices=v5e[:topology.n_chips])
+    graphs = GraphFactory(cfg, ecfg, policy, chunk=ecfg.prefill_chunk)
+    st = abstract_state(cfg, ecfg, policy)
+    jobs = [job for job in graphs.lowering_jobs(
+        abstract_params_for(cfg, False), st["kv_cache"], st["pool"],
+        st["scratch"], st["mb"], [ecfg.prefill_chunk], (), st["rng"])
+        if job[0][0] == "decode"]
+    return cfg, family, topology.n_chips, st["kv_cache"]["k"], jobs
+
+
 def _pool_shaped(text: str, pool: tuple) -> list:
     """Instructions of an optimised HLO text whose result is the pool or
     one layer's plane of it (with or without a leading 1), other than what
@@ -190,28 +289,11 @@ def test_decode_programs_carry_the_pool_whole_on_a_described_v5e(
     engine shapes (abstract arguments, no weights; four chips for the
     tensor-parallel one): the compiler holds no second copy of the pool,
     no plane of it, and gathers none of it across chips."""
-    from benchmark import manifest, serve
-    from tpu9.serving.graphs import GraphFactory, abstract_state
-    from tpu9.serving.presets import abstract_params_for
-    from tpu9.serving.shard.plan import parse_topology
-    from tpu9.serving.shard.policy import MeshPolicy
-    monkeypatch.setattr(attention_ops, "on_tpu", lambda: True)
-    config = manifest.load_config(manifest.load(), configuration)
-    family = manifest.family(config)
-    cfg = family.program_config(family.model_sizes(config))
-    ecfg = serve.engine_config(config["engine"])
-    topology = parse_topology(config["engine"]["topology"])
-    policy = MeshPolicy(topology, devices=v5e[:topology.n_chips])
-    graphs = GraphFactory(cfg, ecfg, policy, chunk=ecfg.prefill_chunk)
-    st = abstract_state(cfg, ecfg, policy)
-    k_pool = st["kv_cache"]["k"]
-    per_chip = (*k_pool.shape[:3], k_pool.shape[3] // topology.n_chips,
+    cfg, _, n_chips, k_pool, programs = _decode_programs(
+        v5e, monkeypatch, configuration)
+    per_chip = (*k_pool.shape[:3], k_pool.shape[3] // n_chips,
                 k_pool.shape[4])
     pool_bytes = 2 * int(np.prod(per_chip)) * k_pool.dtype.itemsize
-    programs = [job for job in graphs.lowering_jobs(
-        abstract_params_for(cfg, False), st["kv_cache"], st["pool"],
-        st["scratch"], st["mb"], [ecfg.prefill_chunk], (), st["rng"])
-        if job[0][0] == "decode"]
     assert [key for key, _, _ in programs] == [("decode", 1), ("decode", 8)]
     for key, fn, args in programs:
         compiled = fn.lower(*args).compile()
@@ -259,29 +341,12 @@ def test_a_looped_decode_program_carries_the_pool_through_its_pass_loop(
     once (the plane ``u * n_layers + l`` is an operand), and the compiler
     keeps the ``kv_layers``-deep pool in place through it — no copy, no
     plane of it."""
-    from dataclasses import replace
-
-    from benchmark import manifest, serve
-    from tpu9.serving.graphs import GraphFactory, abstract_state
-    from tpu9.serving.presets import abstract_params_for
-    from tpu9.serving.shard.plan import Topology
-    from tpu9.serving.shard.policy import MeshPolicy
-    monkeypatch.setattr(attention_ops, "on_tpu", lambda: True)
-    config = manifest.load_config(manifest.load(), "ouro-2.6b")
-    family = manifest.family(config)
-    cfg = replace(family.program_config(family.model_sizes(config)),
-                  n_layers=2)
+    cfg, _, _, k_pool, jobs = _decode_programs(v5e, monkeypatch, "ouro-2.6b",
+                                               n_layers=2)
     assert cfg.loop_steps == 4 and cfg.kv_layers == 8
-    ecfg = serve.engine_config(config["engine"])
-    policy = MeshPolicy(Topology(1, 1), devices=v5e[:1])
-    graphs = GraphFactory(cfg, ecfg, policy, chunk=ecfg.prefill_chunk)
-    st = abstract_state(cfg, ecfg, policy)
-    pool = st["kv_cache"]["k"].shape
+    pool = k_pool.shape
     assert pool[0] == 8
-    (key, fn, args), = [job for job in graphs.lowering_jobs(
-        abstract_params_for(cfg, False), st["kv_cache"], st["pool"],
-        st["scratch"], st["mb"], [ecfg.prefill_chunk], (), st["rng"])
-        if job[0] == ("decode", 1)]
+    (_, fn, args), = [job for job in jobs if job[0] == ("decode", 1)]
     text = fn.lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") == cfg.n_layers
     assert " while(" in text

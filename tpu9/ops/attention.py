@@ -345,7 +345,7 @@ def paged_attention_dispatch(q: jnp.ndarray, k_pool: jnp.ndarray,
                              v_scale: jnp.ndarray = None,
                              mesh=None, layer=0) -> jnp.ndarray:
     """Block-table paged decode dispatch: pallas kernel on TPU (physical
-    blocks DMA'd by table lookup in the index map — no densify copy),
+    blocks DMA'd by table lookup inside the kernel — no densify copy),
     gather + XLA oracle elsewhere. k/v_pool are the whole pool
     [L, N, BS, KH, D], read at ``layer`` where it lives, or one layer's
     plane [N, BS, KH, D]. ``k_scale``/``v_scale`` (one rank less) mark an

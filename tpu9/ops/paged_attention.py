@@ -14,6 +14,13 @@ same block, so clamped (out-of-range) steps issue no DMA; ``pl.when`` then
 skips their compute. The kernel consumes the cache in its native
 [B, S, KH, D] layout (blocking the S axis directly) — no transpose/copy of
 the cache is ever materialized.
+
+The block-table PAGED kernel (what serving runs) does not spend a grid step
+on a table column: a grid step is a sequence, and the kernel walks that
+sequence's ``ceil(len / BS)`` pages itself, copying them out of the pool a
+wave at a time (:func:`_walk_kernel`). Pools whose pages Mosaic cannot cut
+out of HBM (int8 with its scale planes, heads under 128 wide) keep the
+grid-over-columns form (:func:`_page_grid`).
 """
 
 from __future__ import annotations
@@ -28,14 +35,14 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _head_update(h, q, k, v, sb, seq_len, m_scr, l_scr, acc_scr,
-                 block_s: int):
-    """One kv head's online-softmax update for one sequence block — the
-    body shared by the bf16 and int8-dequant kernels (q/k/v arrive f32,
-    q pre-scaled; dequantization, if any, already happened)."""
+def _head_update(h, q, k, v, first_pos, seq_len, m_scr, l_scr, acc_scr):
+    """One kv head's online-softmax update for a run of cache rows that
+    starts at position ``first_pos`` — the body every kernel here shares
+    (q/k/v arrive f32, q pre-scaled; dequantization, if any, already
+    happened)."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    pos = sb * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    pos = first_pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(pos < seq_len, s, NEG_INF)
 
     m_prev = m_scr[h]
@@ -60,8 +67,13 @@ def _finalize_heads(o_ref, m_scr, l_scr, acc_scr, kv_heads: int):
             o_ref.dtype)
 
 
-def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            scale: float, block_s: int, num_sb: int, kv_heads: int):
+def _kernel(len_ref, q_ref, k_ref, v_ref, *refs, scale: float, block_s: int,
+            num_sb: int, kv_heads: int):
+    """One cache block a grid step. ``refs``: an int8 cache's two scale
+    blocks (f32, one scale a (token, head) vector; dequantization is one
+    multiply after the block's DMA), then the output block and the online
+    softmax's running maximum, sum and accumulator."""
+    *scales, o_ref, m_scr, l_scr, acc_scr = refs
     b = pl.program_id(0)
     sb = pl.program_id(1)
     seq_len = len_ref[b]
@@ -81,8 +93,11 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             q = q_ref[0, h].astype(jnp.float32) * scale     # [group, D]
             k = k_ref[0, :, h, :].astype(jnp.float32)       # [block_s, D]
             v = v_ref[0, :, h, :].astype(jnp.float32)
-            _head_update(h, q, k, v, sb, seq_len, m_scr, l_scr, acc_scr,
-                         block_s)
+            if scales:
+                k = k * scales[0][0, :, h][:, None]
+                v = v * scales[1][0, :, h][:, None]
+            _head_update(h, q, k, v, sb * block_s, seq_len, m_scr, l_scr,
+                         acc_scr)
 
     @pl.when(sb == num_sb - 1)
     def _finalize():
@@ -150,38 +165,180 @@ def ragged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
 # block-table paged decode: cache lives in a shared block POOL
 # ---------------------------------------------------------------------------
 
-def _table_block(table, b, sb, lens, block_s: int):
-    """Physical pool block for grid step ``sb``: past-the-end steps CLAMP
-    to the sequence's last valid block (same physical index as the
-    previous step ⇒ Mosaic elides the DMA), so only ceil(len/BS) pool
-    blocks are read per sequence regardless of table width. ONE
-    implementation — the bf16 and int8 kernels' index maps (payload AND
-    scale planes) must never diverge on this."""
-    last = jnp.maximum(
-        jax.lax.div(lens[b] + block_s - 1, block_s) - 1, 0)
-    return table[b, jnp.minimum(sb, last)]
+# K bytes a wave copies: the page walk fetches this much of a sequence's keys
+# (and as much of its values) at once, every page's copy in flight together,
+# while it works on the wave before. A page is 64 KB (two KV heads a chip) to
+# 512 KB (sixteen) on the served shapes, so a wave is 32 / 8 / 4 pages: a
+# static shape, no knob. Swept on the chip from 256 KB to 4 MB (PERF.md §6,
+# PR 40): smaller waves pay a copy's latency and an update's fixed cost more
+# often (256 KB: + 15…40 % a call), larger ones gain nothing on two shapes of
+# three and double the 8 MB of buffers.
+WAVE_BYTES = 2 << 20
+# and at most this many pages: a wave's copies are started one by one
+MAX_WAVE_PAGES = 32
+_VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def _paged_kernel(table_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale: float, block_s: int,
-                  num_sb: int, kv_heads: int):
-    """Same online-softmax body as _kernel; the difference is entirely in
-    the BlockSpec index maps (physical blocks come from the table, the
-    pool's layer from ``layer_ref``)."""
-    del table_ref, layer_ref
-    _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-            scale=scale, block_s=block_s, num_sb=num_sb, kv_heads=kv_heads)
+def _pages_per_wave(page_bytes: int, table_width: int) -> int:
+    """A power of two: a wave's arithmetic goes in runs of W, W/2, ... 1
+    pages (:func:`_walk_kernel`)."""
+    pages = max(1, min(WAVE_BYTES // page_bytes, MAX_WAVE_PAGES, table_width))
+    return 1 << (pages.bit_length() - 1)
+
+
+def _walk_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
+                 k_buf, v_buf, sem, slot_ref, m_scr, l_scr, acc_scr, *,
+                 scale: float, block_s: int, wave: int, kv_heads: int):
+    """One sequence a grid step; the walk over its pages is in here.
+
+    ``k_hbm`` / ``v_hbm`` are the pools whole, in HBM; ``k_buf`` / ``v_buf``
+    ``[2, wave * BS, KH, D]`` hold two waves of pages each; ``sem`` ``[2, 2]``
+    (slot, k or v); ``slot_ref`` (SMEM; scratch lives across grid steps) is
+    the slot of this step's first wave.
+
+    A sequence owns ``ceil(len / BS)`` pages, taken ``wave`` at a time: one
+    copy a page from ``pool[layer, table[b, page]]``, a wave's copies all in
+    flight together, into the slot the arithmetic is not reading. The next
+    wave — after a sequence's last one, the next sequence's first — is
+    started before the arithmetic on the current one, so a call costs the
+    pages that hold tokens, and a slot without tokens one empty step. The
+    table is read only below ``ceil(len / BS)``.
+
+    The arithmetic on a wave goes in runs of pages: a full wave is one
+    online-softmax update over ``wave * BS`` positions (an update's fixed
+    cost, paid once a page, was the larger part of a page's time), a
+    shorter last wave takes the runs of W/2, W/4, ... 1 pages that its
+    count's binary digits name, so no row without a page is ever computed
+    on, whatever VMEM holds there.
+    """
+    b = pl.program_id(0)
+    batch = pl.num_programs(0)
+    layer = layer_ref[0]
+
+    def pages_of(seq):
+        return jnp.minimum(
+            jax.lax.div(len_ref[seq] + block_s - 1, block_s),
+            table_ref.shape[1])
+
+    def for_wave(seq, w, slot, act):
+        """``act`` on every copy that brings wave ``w`` of ``seq`` into
+        ``slot``: two a page the sequence holds there."""
+        def one(page, _):
+            block = table_ref[seq, w * wave + page]
+            rows = pl.ds(pl.multiple_of(page * block_s, block_s), block_s)
+            for i, (pool, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                act(pltpu.make_async_copy(pool.at[layer, block],
+                                          buf.at[slot, rows],
+                                          sem.at[slot, i]))
+        jax.lax.fori_loop(
+            0, jnp.minimum(pages_of(seq) - w * wave, wave), one, None)
+
+    def start(seq, w, slot):
+        for_wave(seq, w, slot, lambda copy: copy.start())
+
+    def wait(seq, w, slot):
+        for_wave(seq, w, slot, lambda copy: copy.wait())
+
+    seq_len = len_ref[b]
+    n_pages = pages_of(b)
+    n_waves = jax.lax.div(n_pages + wave - 1, wave)
+
+    @pl.when(b == 0)
+    def _first_sequence():
+        slot_ref[0] = 0
+        start(0, 0, 0)
+
+    first_slot = slot_ref[0]
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def update(h, k, v, first_pos):
+        q = q_ref[0, h].astype(jnp.float32) * scale
+        _head_update(h, q, k, v, first_pos, seq_len, m_scr, l_scr, acc_scr)
+
+    def run_of_pages(slot, first, rows, first_pos):
+        """Every KV head's update over ``rows`` rows from row ``first`` of
+        ``slot``, which start at position ``first_pos``.
+
+        A page lies in VMEM as it does in the pool, ``[BS, KH, D]``: a
+        head's rows are ``KH`` apart, and cutting them out one head at a
+        time re-reads the whole page for every head. A bfloat16 pool is read
+        as 32-bit words instead: one word holds the same lane of two
+        neighbouring heads, so ONE strided load brings a pair of heads'
+        rows, and each half widens to float32 by a shift or a mask, which is
+        exact. The pairs are a loop, one pair a trip, and not unrolled code:
+        every bring-up traces and lowers this body, and sixteen heads times
+        the run lengths cost it seconds (PERF.md §6, PR 40)."""
+        if k_buf.dtype != jnp.bfloat16:
+            for h in range(kv_heads):
+                update(h, *(buf[slot, pl.ds(first, rows), h, :].astype(
+                    jnp.float32) for buf in (k_buf, v_buf)), first_pos)
+            return
+        pairs = kv_heads // 2
+
+        def words(buf, j):
+            flat = buf.reshape(-1, buf.shape[-1]).bitcast(jnp.uint32)
+            at = (slot * wave * block_s + first) * pairs + j
+            return flat[pl.ds(at, rows, stride=pairs) if pairs > 1
+                        else pl.ds(at, rows), :]
+
+        def half(x, odd):
+            x = x & jnp.uint32(0xFFFF0000) if odd else x << 16
+            return pltpu.bitcast(x, jnp.float32)
+
+        def one_pair(j, _):
+            kw, vw = words(k_buf, j), words(v_buf, j)
+            for odd in (0, 1):
+                update(2 * j + odd, half(kw, odd), half(vw, odd), first_pos)
+
+        if pairs == 1:
+            one_pair(0, None)
+        else:
+            jax.lax.fori_loop(0, pairs, one_pair, None)
+
+    def one_wave(w, _):
+        slot = jax.lax.rem(first_slot + w, 2)
+
+        @pl.when(w + 1 < n_waves)
+        def _next_wave():
+            start(b, w + 1, 1 - slot)
+
+        @pl.when((w + 1 == n_waves) & (b + 1 < batch))
+        def _next_sequence():
+            start(b + 1, 0, 1 - slot)
+
+        wait(b, w, slot)
+        held = jnp.minimum(n_pages - w * wave, wave)
+        run = wave
+        while run:
+            @pl.when((held & run) != 0)
+            def _run(run=run):
+                # the pages of the larger runs come first
+                page = held & (-2 * run)
+                run_of_pages(slot, page * block_s, run * block_s,
+                             (w * wave + page) * block_s)
+            run //= 2
+
+    jax.lax.fori_loop(0, n_waves, one_wave, None)
+
+    @pl.when((n_waves == 0) & (b + 1 < batch))
+    def _empty_slot():
+        start(b + 1, 0, first_slot)
+
+    slot_ref[0] = jax.lax.rem(first_slot + n_waves, 2)
+    _finalize_heads(o_ref, m_scr, l_scr, acc_scr, kv_heads)
 
 
 def _as_pool(layer, k_pool: jnp.ndarray, *rest: jnp.ndarray):
-    """``(layer [1] int32, k_pool, *rest)`` as the kernels take them: the
+    """``(layer [1] int32, k_pool, *rest)`` as the kernel takes them: the
     pool ``[L, N, BS, ...]`` and the layer to read, a scalar-prefetch
     operand like the table. One layer's plane ``[N, BS, KH, D]`` (with its
     companions) is layer 0 of a one-layer pool, a free reshape, and has no
-    other. A kernel never takes a plane cut out of a stacked pool: a pallas
+    other. The kernel never takes a plane cut out of a stacked pool: a pallas
     call takes whole operands, so XLA would copy ``pool[layer]`` out first —
-    the index maps pick the layer and a decode step reads the pool where
-    it lives. The layer is an operand and not a constant of the index map
+    the page copies pick the layer and a decode step reads the pool where
+    it lives. The layer is an operand and not a constant of the kernel
     so that every layer of a model runs ONE kernel, traced and lowered
     once: a constant costs a trace per layer in every bring-up (PERF.md §6,
     PR 25)."""
@@ -189,6 +346,133 @@ def _as_pool(layer, k_pool: jnp.ndarray, *rest: jnp.ndarray):
         layer = 0
         k_pool, *rest = (x[None] for x in (k_pool, *rest))
     return (jnp.asarray(layer, jnp.int32).reshape(1), k_pool, *rest)
+
+
+def _page_walk(q, k_pool, v_pool, block_table, cache_len, layer, interpret):
+    """:func:`paged_decode_attention` by :func:`_walk_kernel`."""
+    layer, k_pool, v_pool = _as_pool(layer, k_pool, v_pool)
+    batch, _, q_heads, head_dim = q.shape
+    _, _, block_s, kv_heads, _ = k_pool.shape
+    assert q_heads % kv_heads == 0
+    group = q_heads // kv_heads
+    wave = _pages_per_wave(
+        block_s * kv_heads * head_dim * k_pool.dtype.itemsize,
+        block_table.shape[1])
+
+    qt = q.reshape(batch, kv_heads, group, head_dim)
+
+    def q_index(b, table, lens, layer):
+        return (b, 0, 0, 0)
+
+    out = pl.pallas_call(
+        functools.partial(_walk_kernel, scale=head_dim ** -0.5,
+                          block_s=block_s, wave=wave, kv_heads=kv_heads),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(batch,),
+            in_specs=[pl.BlockSpec((1, kv_heads, group, head_dim), q_index),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, kv_heads, group, head_dim), q_index),
+            scratch_shapes=[
+                pltpu.VMEM((2, wave * block_s, kv_heads, head_dim),
+                           k_pool.dtype),
+                pltpu.VMEM((2, wave * block_s, kv_heads, head_dim),
+                           v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((kv_heads, group, 128), jnp.float32),
+                pltpu.VMEM((kv_heads, group, 128), jnp.float32),
+                pltpu.VMEM((kv_heads, group, head_dim), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        # the slot and the copies in flight pass from one step to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        # the benchmark counts decode steps by this operation's calls
+        name="paged_decode_attention",
+        interpret=interpret,
+    )(block_table.astype(jnp.int32), cache_len.astype(jnp.int32), layer,
+      qt, k_pool, v_pool)
+    return out.reshape(batch, 1, q_heads, head_dim)
+
+
+def _table_block(table, b, sb, lens, block_s: int):
+    """Physical pool block for grid step ``sb`` of :func:`_page_grid`:
+    past-the-end steps CLAMP to the sequence's last valid block (same
+    physical index as the previous step ⇒ Mosaic elides the DMA), so only
+    ceil(len/BS) pool blocks are read per sequence regardless of table
+    width, and a table entry past them is never dereferenced."""
+    last = jnp.maximum(
+        jax.lax.div(lens[b] + block_s - 1, block_s) - 1, 0)
+    return table[b, jnp.minimum(sb, last)]
+
+
+def _grid_kernel(table_ref, len_ref, layer_ref, *refs, **static):
+    """:func:`_kernel`; the difference is entirely in the BlockSpec index
+    maps (physical blocks come from the table, the pool's layer from
+    ``layer_ref``)."""
+    del table_ref, layer_ref
+    _kernel(len_ref, *refs, **static)
+
+
+def _page_grid(q, pools, block_table, cache_len, layer, interpret):
+    """The walk as a grid, one step a table COLUMN, the pages fetched by
+    the BlockSpec pipeline: every column costs a step whether or not it
+    holds a page (PERF.md §6, PR 40: ≈ 0.2 µs each), so this is only for
+    the pools :func:`_page_walk` cannot take."""
+    layer, *pools = _as_pool(layer, *pools)
+    batch, _, q_heads, head_dim = q.shape
+    _, _, block_s, kv_heads, _ = pools[0].shape
+    max_sb = block_table.shape[1]
+    assert q_heads % kv_heads == 0
+    group = q_heads // kv_heads
+
+    qt = q.reshape(batch, kv_heads, group, head_dim)
+
+    def page_index(trailing):
+        def index(b, sb, table, lens, layer):
+            return (layer[0], _table_block(table, b, sb, lens, block_s),
+                    *(0,) * trailing)
+        return index
+
+    def q_index(b, sb, table, lens, layer):
+        return (b, 0, 0, 0)
+
+    out = pl.pallas_call(
+        functools.partial(_grid_kernel, scale=head_dim ** -0.5,
+                          block_s=block_s, num_sb=max_sb, kv_heads=kv_heads),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(batch, max_sb),
+            in_specs=[pl.BlockSpec((1, kv_heads, group, head_dim), q_index)]
+            + [pl.BlockSpec((None, 1, *pool.shape[2:]),
+                            page_index(pool.ndim - 2)) for pool in pools],
+            out_specs=pl.BlockSpec((1, kv_heads, group, head_dim), q_index),
+            scratch_shapes=[
+                pltpu.VMEM((kv_heads, group, 128), jnp.float32),
+                pltpu.VMEM((kv_heads, group, 128), jnp.float32),
+                pltpu.VMEM((kv_heads, group, head_dim), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        interpret=interpret,
+    )(block_table.astype(jnp.int32), cache_len.astype(jnp.int32), layer,
+      qt, *pools)
+    return out.reshape(batch, 1, q_heads, head_dim)
+
+
+def _pages_can_be_cut(pool: jnp.ndarray) -> bool:
+    """Whether the kernel itself may copy ``pool[layer, block]`` out of HBM.
+    Mosaic slices an HBM operand only along dimensions its tiling does not
+    pad (asked of the compiler for a described v5e, PR 40): a head of whole
+    128-lane rows and, below 32 bits, as many KV heads as a tile has rows
+    (2, 4, or a multiple of 8). The BlockSpec pipeline has no such limit."""
+    *_, kv_heads, head_dim = pool.shape
+    return head_dim % 128 == 0 and (
+        pool.dtype.itemsize == 4 or kv_heads in (2, 4) or kv_heads % 8 == 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -203,97 +487,22 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     int32 scalar (one layer's [N_BLOCKS, BS, KH, D] plane is taken as a
     one-layer pool); block_table [B, MAX_BLOCKS] int32 maps each sequence's
     logical block i to a physical pool block (entries past the valid prefix
-    are ignored); cache_len [B] valid tokens incl. current. Returns
+    are never read); cache_len [B] valid tokens incl. current. Returns
     [B,1,QH,D].
 
     Reference analogue: the engine-side KV management the reference's
     LLM router assumes (pkg/abstractions/pod/llm.go token pressure); the
     kernel itself is the TPU equivalent of paged_attention — physical
-    blocks are DMA'd straight from the pool by table lookup in the
-    BlockSpec index map (scalar-prefetch), so fragmentation-free sharing
+    blocks are DMA'd straight from the pool by table lookup inside the
+    kernel (:func:`_walk_kernel`), so fragmentation-free sharing
     (prefix reuse) costs nothing on the read path, and neither does the
     layer (:func:`_as_pool`).
     """
-    layer, k_pool, v_pool = _as_pool(layer, k_pool, v_pool)
-    batch, _, q_heads, head_dim = q.shape
-    _, n_blocks, block_s, kv_heads, _ = k_pool.shape
-    max_sb = block_table.shape[1]
-    assert q_heads % kv_heads == 0
-    group = q_heads // kv_heads
-
-    qt = q.reshape(batch, kv_heads, group, head_dim)
-    grid = (batch, max_sb)
-    kernel = functools.partial(_paged_kernel, scale=head_dim ** -0.5,
-                               block_s=block_s, num_sb=max_sb,
-                               kv_heads=kv_heads)
-
-    def kv_index(b, sb, table, lens, layer):
-        return (layer[0], _table_block(table, b, sb, lens, block_s),
-                0, 0, 0)
-
-    def q_index(b, sb, table, lens, layer):
-        return (b, 0, 0, 0)
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, kv_heads, group, head_dim), q_index),
-                pl.BlockSpec((None, 1, block_s, kv_heads, head_dim),
-                             kv_index),
-                pl.BlockSpec((None, 1, block_s, kv_heads, head_dim),
-                             kv_index),
-            ],
-            out_specs=pl.BlockSpec((1, kv_heads, group, head_dim), q_index),
-            scratch_shapes=[
-                pltpu.VMEM((kv_heads, group, 128), jnp.float32),
-                pltpu.VMEM((kv_heads, group, 128), jnp.float32),
-                pltpu.VMEM((kv_heads, group, head_dim), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        interpret=interpret,
-    )(block_table.astype(jnp.int32), cache_len.astype(jnp.int32), layer,
-      qt, k_pool, v_pool)
-
-    return out.reshape(batch, 1, q_heads, head_dim)
-
-
-def _paged_quant_kernel(table_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
-                        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                        scale: float, block_s: int, num_sb: int,
-                        kv_heads: int):
-    """int8-pool variant of :func:`_paged_kernel`: the k/v blocks DMA'd by
-    table lookup are int8 and the per-vector scales ride in two small f32
-    side inputs with the SAME index map — dequantization is one in-register
-    multiply per block, so HBM moves half the cache bytes."""
-    del table_ref, layer_ref
-    b = pl.program_id(0)
-    sb = pl.program_id(1)
-    seq_len = len_ref[b]
-
-    @pl.when(sb == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    @pl.when(sb * block_s < seq_len)
-    def _compute():
-        for h in range(kv_heads):
-            q = q_ref[0, h].astype(jnp.float32) * scale     # [group, D]
-            k = (k_ref[0, :, h, :].astype(jnp.float32)
-                 * ks_ref[0, :, h][:, None])                # [block_s, D]
-            v = (v_ref[0, :, h, :].astype(jnp.float32)
-                 * vs_ref[0, :, h][:, None])
-            _head_update(h, q, k, v, sb, seq_len, m_scr, l_scr, acc_scr,
-                         block_s)
-
-    @pl.when(sb == num_sb - 1)
-    def _finalize():
-        _finalize_heads(o_ref, m_scr, l_scr, acc_scr, kv_heads)
+    if not _pages_can_be_cut(k_pool):
+        return _page_grid(q, (k_pool, v_pool), block_table, cache_len,
+                          layer, interpret)
+    return _page_walk(q, k_pool, v_pool, block_table, cache_len, layer,
+                      interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -308,58 +517,11 @@ def paged_decode_attention_quant(q: jnp.ndarray, k_pool: jnp.ndarray,
     [L, N_BLOCKS, BS, KH, D] int8, k/v_scale [L, N_BLOCKS, BS, KH] f32 (one
     absmax scale per (token, head) vector — ``tpu9.ops.quant.quantize_kv``),
     or one layer's planes of both. Identical masking/softmax semantics; the
-    only difference is the in-kernel dequant multiply after each block DMA."""
-    layer, k_pool, v_pool, k_scale, v_scale = _as_pool(
-        layer, k_pool, v_pool, k_scale, v_scale)
-    batch, _, q_heads, head_dim = q.shape
-    _, n_blocks, block_s, kv_heads, _ = k_pool.shape
-    max_sb = block_table.shape[1]
-    assert q_heads % kv_heads == 0
-    group = q_heads // kv_heads
-
-    qt = q.reshape(batch, kv_heads, group, head_dim)
-    grid = (batch, max_sb)
-    kernel = functools.partial(_paged_quant_kernel, scale=head_dim ** -0.5,
-                               block_s=block_s, num_sb=max_sb,
-                               kv_heads=kv_heads)
-
-    def kv_index(b, sb, table, lens, layer):
-        return (layer[0], _table_block(table, b, sb, lens, block_s),
-                0, 0, 0)
-
-    def sc_index(b, sb, table, lens, layer):
-        return (layer[0], _table_block(table, b, sb, lens, block_s), 0, 0)
-
-    def q_index(b, sb, table, lens, layer):
-        return (b, 0, 0, 0)
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, kv_heads, group, head_dim), q_index),
-                pl.BlockSpec((None, 1, block_s, kv_heads, head_dim),
-                             kv_index),
-                pl.BlockSpec((None, 1, block_s, kv_heads, head_dim),
-                             kv_index),
-                pl.BlockSpec((None, 1, block_s, kv_heads), sc_index),
-                pl.BlockSpec((None, 1, block_s, kv_heads), sc_index),
-            ],
-            out_specs=pl.BlockSpec((1, kv_heads, group, head_dim), q_index),
-            scratch_shapes=[
-                pltpu.VMEM((kv_heads, group, 128), jnp.float32),
-                pltpu.VMEM((kv_heads, group, 128), jnp.float32),
-                pltpu.VMEM((kv_heads, group, head_dim), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        interpret=interpret,
-    )(block_table.astype(jnp.int32), cache_len.astype(jnp.int32), layer,
-      qt, k_pool, v_pool, k_scale, v_scale)
-
-    return out.reshape(batch, 1, q_heads, head_dim)
+    only difference is the in-kernel dequant multiply after each block DMA,
+    so HBM moves half the cache bytes. Always the grid: a scale page
+    ``[BS, KH]`` is padded to 128 lanes in HBM (:func:`_pages_can_be_cut`)."""
+    return _page_grid(q, (k_pool, v_pool, k_scale, v_scale), block_table,
+                      cache_len, layer, interpret)
 
 
 def gather_paged(pool: jnp.ndarray, block_table: jnp.ndarray,

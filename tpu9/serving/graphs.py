@@ -200,20 +200,24 @@ class GraphFactory:
 
         def one_step(params, kv_cache, last_token, cache_len, active, rng):
             positions = cache_len[:, None]          # next position per slot
+            # an idle lane attends to nothing: its length is 0, so the paged
+            # kernel walks no page for it (its token, like its write to the
+            # trash block, is discarded)
+            live = active.astype(jnp.int32)
             # a looped decoder also says which pass the head read and how
             # many passes ran: ``exits`` is ``(exit_info [B, 1, 2],)`` for it
             # and empty for a plain one
             logits, kv_cache, *exits = decoder_forward(
                 params, last_token, cfg, positions=positions,
-                kv_cache=kv_cache, cache_len=cache_len + 1, decode=True,
-                mesh=policy.mesh, return_exit=cfg.looped)
+                kv_cache=kv_cache, cache_len=(cache_len + 1) * live,
+                decode=True, mesh=policy.mesh, return_exit=cfg.looped)
             rng, sub = jax.random.split(rng)
             next_tok = sample_logits(logits[:, -1], sub,
                                      temperature=ecfg.temperature,
                                      top_k=ecfg.top_k, top_p=ecfg.top_p)
             # only live slots advance; idle lanes stay parked at 0 so the
             # token-pressure signal reflects real cache occupancy
-            new_len = cache_len + active.astype(jnp.int32)
+            new_len = cache_len + live
             return (next_tok[:, None].astype(jnp.int32), kv_cache, new_len,
                     rng, exits)
 
