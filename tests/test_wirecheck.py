@@ -180,6 +180,29 @@ def test_wir002_ghost_assert(tmp_path):
     assert _gate(root) == 1
 
 
+def test_wir002_an_interval_told_twice_is_an_emission(tmp_path):
+    """``tracer.record_interval(span, registry, summary, ...)`` observes
+    ``summary`` (ISSUE 41): a test may assert the series, and the same
+    call with another name leaves the assertion a ghost."""
+    root = _mini_repo(tmp_path)
+    (root / "tests" / "test_mini.py").write_text(textwrap.dedent("""\
+        def test_requests(snapshot):
+            assert "tpu9_mini_hop_s" in snapshot
+    """))
+    for summary, clean in (("tpu9_mini_hop_s", True),
+                           ("tpu9_mini_other_s", False)):
+        (root / "tpu9" / "hop.py").write_text(textwrap.dedent(f"""\
+            def hop(tracer, registry, anchor, t0, t1, trace):
+                return tracer.record_interval(
+                    "mini.hop", registry, "{summary}", anchor, t0, t1,
+                    trace=trace)
+        """))
+        res = _check(root)
+        ghosts = [f for f in res.findings if f.rule == "WIR002"
+                  and f.symbol == "tpu9_mini_hop_s"]
+        assert (ghosts == []) == clean, [f.format() for f in res.findings]
+
+
 def test_wir002_gauge_without_remove(tmp_path):
     root = _mini_repo(tmp_path)
     (root / "tpu9" / "metrics_use.py").write_text(textwrap.dedent("""\

@@ -65,7 +65,14 @@ class StreamHandle:
     buffer's demand signal until closed — the autoscaler must not scale
     the serving container away mid-stream."""
 
-    def __init__(self, resp, container_id: str, release):
+    def __init__(self, resp, container_id: str, release,
+                 acquire_s: float, t_send_mono: float):
+        # the gateway's leg of a streamed request (ISSUE 41): how long
+        # admission waited for a container, and the monotonic stamps just
+        # before the request was sent and, here, with its headers back
+        self.acquire_s = acquire_s
+        self.t_send_mono = t_send_mono
+        self.t_open_mono = time.monotonic()
         self._resp = resp
         self.container_id = container_id
         self._release = release
@@ -208,6 +215,7 @@ class RequestBuffer:
         # if the autoscaler can see this request waiting (same contract as
         # the buffered path and _ws_proxy's hold_demand)
         self._open += 1
+        t_acquire = time.monotonic()
         # full request timeout for admission, same as the buffered path —
         # a scale-from-zero LLM cold start routinely exceeds 30s and a
         # streaming request must ride it out like any other
@@ -218,6 +226,7 @@ class RequestBuffer:
             return ForwardResult(status=504,
                                  body=b'{"error":"no capacity"}')
         container_id, address = target
+        acquire_s = time.monotonic() - t_acquire
         released = False
 
         async def release() -> None:
@@ -237,6 +246,7 @@ class RequestBuffer:
         gap_s = float(os.environ.get("TPU9_STREAM_GAP_S", "") or 0) \
             or min(gap_s or self.request_timeout_s,
                    self.request_timeout_s)
+        t_send = time.monotonic()
         try:
             resp = await self._session.request(
                 method, f"http://{address}{path}", data=body or None,
@@ -252,7 +262,8 @@ class RequestBuffer:
                 status=502,
                 body=f'{{"error":"{type(exc).__name__}"}}'.encode(),
                 container_id=container_id)
-        return StreamHandle(resp, container_id, release)
+        return StreamHandle(resp, container_id, release,
+                            acquire_s=acquire_s, t_send_mono=t_send)
 
     @contextlib.contextmanager
     def hold_demand(self):

@@ -548,27 +548,40 @@ def extract_metrics(idx: ModuleIndex) -> list[MetricUse]:
 
     out: list[MetricUse] = []
     for node in ast.walk(idx.tree):
-        if not (isinstance(node, ast.Call)
-                and isinstance(node.func, ast.Attribute)
-                and node.func.attr in METRIC_METHODS
-                and node.args):
+        emits = _metric_call(node)
+        if emits is None:
             continue
-        recv = node.func.value
-        recv_name = recv.id if isinstance(recv, ast.Name) else \
-            recv.attr if isinstance(recv, ast.Attribute) else ""
-        if "metric" not in recv_name:
-            continue
+        method, name_arg = emits
         fn = fn_of.get(id(node))
-        for name, family in _resolve_metric_names(node.args[0], fn, idx):
+        for name, family in _resolve_metric_names(name_arg, fn, idx):
             if not family and not METRIC_RE.match(name):
                 continue
             out.append(MetricUse(
-                name, node.func.attr,
+                name, method,
                 Site(idx.path, node.lineno, node.col_offset,
                      symbols.get(id(node), "<module>")),
                 family=family,
                 label_keys=_label_keys(node, fn)))
     return out
+
+
+def _metric_call(node):
+    """``(method, the argument naming the series)`` of a call that emits a
+    metric, or None: ``<...metric...>.<METRIC_METHODS>(name, ...)``, and
+    ``<tracer>.record_interval(span, registry, summary, ...)`` — one
+    interval told as a span and as an observation of ``summary``
+    (``tpu9/observability/trace.py``)."""
+    if not (isinstance(node, ast.Call)
+            and isinstance(node.func, ast.Attribute)):
+        return None
+    if node.func.attr == "record_interval" and len(node.args) >= 3:
+        return "observe", node.args[2]
+    if node.func.attr not in METRIC_METHODS or not node.args:
+        return None
+    recv = node.func.value
+    recv_name = recv.id if isinstance(recv, ast.Name) else \
+        recv.attr if isinstance(recv, ast.Attribute) else ""
+    return (node.func.attr, node.args[0]) if "metric" in recv_name else None
 
 
 def extract_metric_literals(idx: ModuleIndex) -> list[MetricUse]:

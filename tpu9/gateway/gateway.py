@@ -582,6 +582,10 @@ class Gateway:
         """Concurrency-quota rejections surface as 429 wherever the request
         originated (pod create, task submit, deploy scale-up...)."""
         from ..scheduler.quota import QuotaExceeded
+        # the first gateway code that sees a request: the (wall, monotonic)
+        # anchor a streamed request's hop intervals are told against
+        # (ISSUE 41, `_serve_stub_stream_inner`)
+        request["t_gateway"] = (time.time(), time.monotonic())
         try:
             return await handler(request)
         except QuotaExceeded as exc:
@@ -2457,6 +2461,22 @@ class Gateway:
         avoid: set = set()
         sr: Optional[web.StreamResponse] = None
         trace_ref = ["", ""]           # [trace_id, span_id] for failover
+        # hop intervals (ISSUE 41): entry -> sent -> headers back -> first
+        # token written, each a child of gateway.invoke and a summary of
+        # the process registry; once a client request, the first attempt
+        t_gateway = request["t_gateway"]        # `_quota_middleware`
+        admit_s = 0.0
+        hop_attrs = {"stub_id": stub.stub_id,
+                     "workspace_id": stub.workspace_id}
+
+        def first_token_written() -> None:
+            # a child that outlives its parent: gateway.invoke ended with
+            # the headers, this with the first token on the client's socket
+            tracer.record_interval(
+                "gateway.first_token", metrics,
+                "tpu9_gateway_stream_first_s", t_gateway,
+                handle.t_open_mono, time.monotonic(), trace=trace_ref,
+                attrs=dict(hop_attrs))
         finished = False
         terminal_error = False         # stream ended on a forwarded error
         last_failure: Optional[sv.AttemptOutcome] = None
@@ -2548,6 +2568,9 @@ class Gateway:
             sp = span_cm.__enter__() if span_cm is not None else None
             try:
                 if sp is not None:
+                    # backdated to the gateway's entry, as engine.request
+                    # is to the enqueue: the root covers entry -> headers
+                    sp.start, sp.start_mono = t_gateway
                     trace_ref[0], trace_ref[1] = sp.trace_id, sp.span_id
                 hdrs.append(("X-Tpu9-Trace",
                              f"{trace_ref[0]}:{trace_ref[1]}"))
@@ -2557,9 +2580,11 @@ class Gateway:
                     # its replica for minutes) but still shed at the door
                     # and carry the router's affinity preference; their
                     # budget slot rides the handle's lifetime via on_close
+                    t_admit = time.monotonic()
                     shed, prefer = await self.fleet_router.admit_stream(
                         stub, tenant, attempt_body,
                         deadline_mono=ctx.deadline_mono)
+                    admit_s = time.monotonic() - t_admit
                     if shed is not None:
                         # usage records for sheds on BOTH paths: metrics/
                         # billing must not diverge between buffered and
@@ -2629,6 +2654,20 @@ class Gateway:
                     out = await self._relay_stream_legacy(request, handle)
                     await _finish_journal(getattr(handle, "status", 200))
                     return out
+                if budget.attempt == 1:
+                    tracer.record_interval(
+                        "gateway.pre_forward", metrics,
+                        "tpu9_gateway_stream_pre_s", t_gateway,
+                        t_gateway[1], handle.t_send_mono, trace=trace_ref,
+                        attrs={**hop_attrs, "admit_s": round(admit_s, 6),
+                               "acquire_s": round(handle.acquire_s, 6)})
+                    tracer.record_interval(
+                        "gateway.connect", metrics,
+                        "tpu9_gateway_stream_connect_s", t_gateway,
+                        handle.t_send_mono, handle.t_open_mono,
+                        trace=trace_ref,
+                        attrs={**hop_attrs,
+                               "container_id": handle.container_id})
                 if sr is None:
                     sr = web.StreamResponse(status=handle.status)
                     skip = {"connection", "transfer-encoding",
@@ -2637,6 +2676,9 @@ class Gateway:
                     for k, v in handle.headers:
                         if k.lower() not in skip:
                             sr.headers.add(k, v)
+                    # the id a user quotes for a slow request:
+                    # /api/v1/traces?trace_id=<id> is its waterfall
+                    sr.headers["X-Tpu9-Trace-Id"] = trace_ref[0]
                     try:
                         await sr.prepare(request)
                     except (ConnectionResetError, OSError) as exc:
@@ -2646,7 +2688,8 @@ class Gateway:
                         await _finish_journal(499)
                         return sr
                 outcome = await self._relay_stream_events(
-                    handle, resume, sr)
+                    handle, resume, sr,
+                    first_token_written if budget.attempt == 1 else None)
                 await handle.close()
                 if outcome.kind == "done":
                     finished = True
@@ -2808,12 +2851,15 @@ class Gateway:
         return sr
 
     async def _relay_stream_events(self, handle, resume,
-                                   sr: web.StreamResponse):
+                                   sr: web.StreamResponse,
+                                   on_first_token=None):
         """Event-aware relay for one attempt of a resumable LLM stream:
         forward token events (advancing the watermark), swallow the
         attempt's own done/error events (the terminal event is owned by
         the failover loop — a resumed attempt's done only knows its own
-        suffix), and classify how the attempt ended."""
+        suffix), and classify how the attempt ended. ``on_first_token``
+        is called once, when the first token event of the attempt has
+        been written to the client."""
         import aiohttp as _aiohttp
         from . import survival as sv
         parser = sv.SseParser()
@@ -2846,6 +2892,9 @@ class Gateway:
                     except (ConnectionResetError, OSError) as exc:
                         log.debug("client gone mid-stream: %s", exc)
                         return sv.AttemptOutcome(kind="client_gone")
+                    if on_first_token is not None:
+                        on_first_token()
+                        on_first_token = None
                 elif "kv_key" in ev:
                     # kvwire announcement (ISSUE 16): the exporting
                     # replica published this stream's KV blocks —

@@ -25,6 +25,14 @@ it, ``start_span(trace_id=..., parent_id=...)`` / ``span(parent_id=...)``
 re-attach under it (the gateway ships it to runners in the
 ``X-Tpu9-Trace`` header).
 
+Hop intervals (ISSUE 41): a request's time to first token is told as
+nested intervals — client ⊃ gateway ⊃ runner ⊃ engine — each between two
+``time.monotonic()`` stamps of ONE process, so a hop's self time is its
+interval less its child's and no clock is shared between hosts. A hop
+stamps its boundaries once; :meth:`Tracer.record_interval` turns each
+pair into both a span of the request's trace and an observation of a
+summary, once a request and never a token.
+
 Host phases (ISSUE 24) are NOT spans: :class:`phase` puts an interval on
 the profiler's own clock (``jax.profiler.TraceAnnotation``, beside the
 device planes of any running trace) and adds its self time to a
@@ -254,6 +262,27 @@ class Tracer:
                                 parent_id=parent_id, start=start_wall,
                                 start_mono=first_mono, attrs=attrs,
                                 end_mono=last_mono)
+
+    def record_interval(self, name: str, registry, summary: str,
+                        anchor: tuple, t0_mono: float, t1_mono: float,
+                        trace: Optional[tuple] = None,
+                        attrs: Optional[dict] = None) -> float:
+        """One interval between two ``time.monotonic()`` stamps of THIS
+        process, told twice (ISSUE 41): always an observation of
+        ``summary`` in ``registry`` (a :class:`Metrics`), and, where the
+        request carries a trace context ``(trace_id, parent_span_id)``,
+        the span ``name`` under it. ``anchor`` is the hop's one
+        ``(wall, monotonic)`` pair, as for :meth:`record_window`. A hop
+        stamps its boundaries once and hands each pair here, so a summary
+        and its span can never disagree about what they cover. Returns
+        the seconds."""
+        seconds = max(t1_mono - t0_mono, 0.0)
+        registry.observe(summary, seconds)
+        if trace is not None and trace[0]:
+            self.record_window(name, anchor[0], anchor[1], t0_mono, t1_mono,
+                               trace_id=trace[0], parent_id=trace[1],
+                               attrs=attrs)
+        return seconds
 
     def current_trace_id(self) -> str:
         sp = _current_span.get()

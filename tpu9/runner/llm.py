@@ -267,6 +267,9 @@ async def amain() -> None:
             return None
 
     async def generate(request: web.Request) -> web.StreamResponse:
+        # the runner's leg of a request starts here (ISSUE 41): the one
+        # (wall, monotonic) anchor its intervals are told against
+        t_in = (_now.time(), _now.monotonic())
         if not state["ready"]:
             return web.json_response({"error": "not ready"}, status=503)
         if faults is not None and faults.fire("rpc_error"):
@@ -304,8 +307,9 @@ async def amain() -> None:
                              or payload.get("export_after_prefill"))
             if payload.get("stream") or \
                     "text/event-stream" in request.headers.get("Accept", ""):
-                return await _generate_sse(request, prompt, max_new, trace,
-                                           budget, kv_export=kv_export)
+                return await _generate_sse(request, prompt, max_new, t_in,
+                                           trace, budget,
+                                           kv_export=kv_export)
             out = await state["engine"].generate(prompt,
                                                  max_new_tokens=max_new,
                                                  trace=trace,
@@ -327,7 +331,8 @@ async def amain() -> None:
             return web.json_response(error_payload(exc), status=500)
 
     async def _generate_sse(request: web.Request, prompt: list,
-                            max_new: int, trace=None, budget=None,
+                            max_new: int, t_in: tuple, trace=None,
+                            budget=None,
                             kv_export: bool = False) -> web.StreamResponse:
         """Server-sent token stream: one `data: {"token": N}` event per
         generated token, then `data: {"done": true, "tokens": [...]}` —
@@ -337,11 +342,13 @@ async def amain() -> None:
         req = await state["engine"].generate(prompt, max_new_tokens=max_new,
                                              stream=True, trace=trace,
                                              budget_s=budget)
+        t_enqueued = _now.monotonic()
         sr = web.StreamResponse(
             status=200, headers={"Content-Type": "text/event-stream",
                                  "Cache-Control": "no-cache",
                                  "X-Accel-Buffering": "no"})
         await sr.prepare(request)
+        state["engine"].note_ingest(req, t_in, t_enqueued, _now.monotonic())
         out: list = []
         # export_after_prefill (ISSUE 16): announce once, right after the
         # first token proves prefill (and its prefix-cache insert) is done
@@ -365,7 +372,8 @@ async def amain() -> None:
                 await sr.write(
                     f"data: {json.dumps({'token': tok})}\n\n".encode())
                 if len(out) == 1:
-                    # stream lag: first token's queue put -> written here
+                    # stream lag and the runner's first-token interval:
+                    # both end where the first token is written
                     state["engine"].note_first_write(req)
                 if kv_pending:
                     kv_pending = False

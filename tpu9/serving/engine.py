@@ -209,6 +209,11 @@ class _Request:
     t_enqueue_wall: float = 0.0
     t_admit_end_mono: float = 0.0           # admission (prefill) dispatched
     t_first_mono: float = 0.0               # first token delivered
+    # the runner's leg of a streamed request (ISSUE 41, `note_ingest`):
+    # the handler's (wall, monotonic) anchor at its first line, and the
+    # monotonic stamp at which the response's headers were written
+    ingest_anchor: tuple = ()
+    t_ready_mono: float = 0.0
     t_done_mono: float = 0.0                # terminal answer given
     admit_cached: int = 0                   # prefix-cache tokens reused
     admit_chunks: int = 0                   # prefill chunks dispatched
@@ -1193,8 +1198,9 @@ class InferenceEngine:
         out["scaleout_ready_groups"] = ",".join(sg["bound"])
         lat = {}
         summaries = self.metrics.to_dict()["summaries"]
-        for part in ("ttft", "tbt", "queue_wait", "prefill", "first_hold",
-                     "stream_lag", "decode_window", "e2e"):
+        for part in ("ttft", "queue_wait", "prefill", "first_hold",
+                     "stream_lag", "ingest", "runner_first",
+                     "decode_window", "e2e"):
             snap = summaries.get(f"tpu9_engine_{part}_s")
             if snap:
                 lat[f"{part}_p50_s"] = round(snap["p50"], 6)
@@ -1586,46 +1592,47 @@ class InferenceEngine:
 
     def _obs_admit_start(self, req: _Request, t0_mono: float,
                          t0_wall: float) -> None:
-        wait = max(t0_mono - req.t_enqueue_mono, 0.0)
-        self.metrics.observe("tpu9_engine_queue_wait_s", wait)
-        if req.trace is None:
-            return
-        trace_id, parent = req.trace
-        topo = self.policy.describe()
-        req.span = tracer.start_span(
-            "engine.request", trace_id=trace_id, parent_id=parent,
-            attrs={"request_id": req.request_id,
-                   "prompt_tokens": len(req.prompt),
-                   "max_new_tokens": req.max_new_tokens,
-                   # multichip evidence rides the PR-8 observability
-                   # layer (ISSUE 9): which submesh served this request
-                   "tp": topo["tp"], "n_chips": topo["n_chips"]})
-        req.span_id = req.span.span_id
-        # backdate to the enqueue anchor: the request span covers
-        # queue-wait + prefill + every decode window
-        req.span.start, req.span.start_mono = (req.t_enqueue_wall,
-                                               req.t_enqueue_mono)
-        tracer.record_span(
-            "engine.queue_wait", trace_id, req.span.span_id,
-            req.t_enqueue_wall, req.t_enqueue_mono,
-            attrs={"request_id": req.request_id}, end_mono=t0_mono)
+        anchor = (req.t_enqueue_wall, req.t_enqueue_mono)
+        if req.trace is not None:
+            topo = self.policy.describe()
+            req.span = tracer.start_span(
+                "engine.request", trace_id=req.trace[0],
+                parent_id=req.trace[1],
+                attrs={"request_id": req.request_id,
+                       "prompt_tokens": len(req.prompt),
+                       "max_new_tokens": req.max_new_tokens,
+                       # multichip evidence rides the PR-8 observability
+                       # layer (ISSUE 9): which submesh served this request
+                       "tp": topo["tp"], "n_chips": topo["n_chips"]})
+            req.span_id = req.span.span_id
+            # backdate to the enqueue anchor: the request span covers
+            # queue-wait + prefill + every decode window
+            req.span.start, req.span.start_mono = anchor
+        tracer.record_interval(
+            "engine.queue_wait", self.metrics, "tpu9_engine_queue_wait_s",
+            anchor, req.t_enqueue_mono, t0_mono, trace=self._under(req),
+            attrs={"request_id": req.request_id})
+
+    @staticmethod
+    def _under(req: _Request) -> Optional[tuple]:
+        """The trace context of a child of ``engine.request``, or None for
+        a request that carries no trace."""
+        return (req.trace[0], req.span_id) if req.span_id else None
 
     def _obs_admit_end(self, req: _Request, t0_mono: float, t0_wall: float,
                        il0: int) -> None:
         req.t_admit_end_mono = time.monotonic()
-        dur = max(req.t_admit_end_mono - t0_mono, 0.0)
         self._last_progress_mono = req.t_admit_end_mono   # = progress
-        self.metrics.observe("tpu9_engine_prefill_s", dur)
         interleaved = self._stats["admit_interleaved_windows"] - il0
-        if req.trace is not None and req.span is not None:
-            tracer.record_span(
-                "engine.prefill", req.trace[0], req.span.span_id,
-                t0_wall, t0_mono,
-                attrs={"request_id": req.request_id,
-                       "prompt_tokens": len(req.prompt),
-                       "cached_tokens": req.admit_cached,
-                       "chunks": req.admit_chunks,
-                       "interleaved_windows": interleaved})
+        dur = tracer.record_interval(
+            "engine.prefill", self.metrics, "tpu9_engine_prefill_s",
+            (t0_wall, t0_mono), t0_mono, req.t_admit_end_mono,
+            trace=self._under(req),
+            attrs={"request_id": req.request_id,
+                   "prompt_tokens": len(req.prompt),
+                   "cached_tokens": req.admit_cached,
+                   "chunks": req.admit_chunks,
+                   "interleaved_windows": interleaved})
         if self.flight is not None:
             self.flight.record(
                 "admit", request_id=req.request_id, slot=req.slot,
@@ -1730,18 +1737,53 @@ class InferenceEngine:
             "tpu9_engine_ttft_s",
             max(req.t_first_mono - req.t_enqueue_mono, 0.0))
         if req.t_admit_end_mono:
-            self.metrics.observe(
+            tracer.record_interval(
+                "engine.first_hold", self.metrics,
                 "tpu9_engine_first_hold_s",
-                max(req.t_first_mono - req.t_admit_end_mono, 0.0))
+                (req.t_enqueue_wall, req.t_enqueue_mono),
+                req.t_admit_end_mono, req.t_first_mono,
+                trace=self._under(req),
+                attrs={"request_id": req.request_id})
+
+    # The runner's leg (ISSUE 41), fed by the SSE handler beside the
+    # engine's own: intervals of the handler's clock, which is this
+    # process's, so they nest around the engine's parts with no clock
+    # shared between hosts. Once a request; nothing a token.
+
+    def note_ingest(self, req: _Request, anchor: tuple, t_enqueued: float,
+                    t_ready: float) -> None:
+        """``anchor``: the handler's (wall, monotonic) pair at its first
+        line; ``t_enqueued``: ``generate`` has returned ``req``;
+        ``t_ready``: the response's headers are written. ``ingest`` is
+        first line -> headers: body read, ``json.loads``, the ``int()``
+        loop, the enqueue, ``prepare``."""
+        req.ingest_anchor, req.t_ready_mono = anchor, t_ready
+        tracer.record_interval(
+            "runner.ingest", self.metrics, "tpu9_engine_ingest_s", anchor,
+            anchor[1], t_ready, trace=req.trace,
+            attrs={"request_id": req.request_id,
+                   "prompt_tokens": len(req.prompt),
+                   "parse_s": round(max(t_enqueued - anchor[1], 0.0), 6)})
 
     def note_first_write(self, req: _Request) -> None:
-        """Stream lag (ISSUE 24), fed by the runner: from the first token's
-        queue put to the handler having written it to the client — the
-        wait for the event loop the serve loop shares."""
+        """Fed by the runner when its handler has written a request's
+        first token to the client. Stream lag (ISSUE 24): from the first
+        token's queue put to here — the wait for the event loop the serve
+        loop shares. ``runner_first`` (ISSUE 41): from the headers to
+        here — queue wait + admission + hold + stream lag, less what of
+        the enqueue lay before the headers; ``ttft`` + ``stream_lag`` is
+        its check."""
+        now = time.monotonic()
         if req.t_first_mono:
             self.metrics.observe(
                 "tpu9_engine_stream_lag_s",
-                max(time.monotonic() - req.t_first_mono, 0.0))
+                max(now - req.t_first_mono, 0.0))
+        if req.t_ready_mono:
+            tracer.record_interval(
+                "runner.first_token", self.metrics,
+                "tpu9_engine_runner_first_s", req.ingest_anchor,
+                req.t_ready_mono, now, trace=req.trace,
+                attrs={"request_id": req.request_id})
 
     def _obs_done(self, req: _Request) -> None:
         """Idempotent: reachable from both _retire (slot completion) and
@@ -1752,10 +1794,6 @@ class InferenceEngine:
             req.t_done_mono = now
             self.metrics.observe("tpu9_engine_e2e_s",
                                  max(now - req.t_enqueue_mono, 0.0))
-            if req.t_first_mono and n > 1:
-                self.metrics.observe(
-                    "tpu9_engine_tbt_s",
-                    max(now - req.t_first_mono, 0.0) / (n - 1))
             req.t_enqueue_mono = 0.0
         if req.span is not None:
             sp, req.span = req.span, None     # exactly one finish per span
