@@ -88,6 +88,11 @@ def _kernel_case(name, d):
         return ragged_decode_attention, lambda sh: (
             dense(sh, 1)[0], dense(sh, 1)[1], dense(sh, 1)[1],
             jax.ShapeDtypeStruct((B,), jnp.int32, sharding=sh))
+    if name == "chunk":
+        from tpu9.ops.chunk_attention import flash_chunk_prefill_attention
+        return flash_chunk_prefill_attention, lambda sh: (
+            dense(sh, 128)[0], dense(sh, 128)[1], dense(sh, 128)[1],
+            jax.ShapeDtypeStruct((2,), jnp.int32, sharding=sh))
     if name == "paged":
         return paged_decode_attention, lambda sh: _paged_args(d, sh)
     return paged_decode_attention_quant, \
@@ -98,6 +103,10 @@ def _kernel_case(name, d):
     ("flash", 128), ("ragged", 128), ("paged", 128), ("paged_int8", 128),
     # llama-1b: same head counts, head 64
     ("flash", 64), ("paged", 64), ("paged_int8", 64),
+    # the chunk kernel over one layer's plane, two rows; heads that are not
+    # 128 wide are loaded a head at a time (Mosaic's strided word load
+    # wants 128 lanes)
+    ("chunk", 128), ("chunk", 64), ("chunk", 256),
     # the mesh-sharded replica: the dispatcher's shard_map over a 4-chip
     # mesh with pool and q sharded on the head axis (refused before PR 21:
     # "Mosaic kernels cannot be automatically partitioned")
@@ -221,11 +230,12 @@ def test_a_decode_programs_only_kernels_are_the_step_markers(
     assert all("tpu_custom_call" in ln for ln in named)
 
 
-def _decode_programs(v5e, monkeypatch, configuration, n_layers=None):
+def _decode_programs(v5e, monkeypatch, configuration, n_layers=None,
+                     kinds=("decode",)):
     """``(cfg, family, n_chips, k_pool, jobs)``: a benchmark configuration's
-    decode programs at its engine's shapes (abstract arguments, no weights;
-    as many described chips as its topology names), dispatching as on the
-    chip; ``n_layers`` cuts the model's depth."""
+    decode programs (or those of ``kinds``) at its engine's shapes (abstract
+    arguments, no weights; as many described chips as its topology names),
+    dispatching as on the chip; ``n_layers`` cuts the model's depth."""
     from dataclasses import replace
 
     from benchmark import manifest, serve
@@ -233,7 +243,9 @@ def _decode_programs(v5e, monkeypatch, configuration, n_layers=None):
     from tpu9.serving.presets import abstract_params_for
     from tpu9.serving.shard.plan import parse_topology
     from tpu9.serving.shard.policy import MeshPolicy
+    import tpu9.ops.grouped_ffn as grouped_ops
     monkeypatch.setattr(attention_ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(grouped_ops, "on_tpu", lambda: True)
     config = manifest.load_config(manifest.load(), configuration)
     family = manifest.family(config)
     cfg = family.program_config(family.model_sizes(config))
@@ -247,8 +259,88 @@ def _decode_programs(v5e, monkeypatch, configuration, n_layers=None):
     jobs = [job for job in graphs.lowering_jobs(
         abstract_params_for(cfg, False), st["kv_cache"], st["pool"],
         st["scratch"], st["mb"], [ecfg.prefill_chunk], (), st["rng"])
-        if job[0][0] == "decode"]
+        if job[0][0] in kinds]
     return cfg, family, topology.n_chips, st["kv_cache"]["k"], jobs
+
+
+# the scratch as the chunk kernel sees it (ISSUE 44): KV heads, query heads a
+# KV head, max_seq_len, planes of the scratch, chips
+SCRATCH_SHAPES = {"mistral-tp4-long": (8, 4, 16384, 32, 4),
+                  "ouro-qa": (16, 1, 1024, 192, 1),
+                  "mixtral": (8, 4, 4096, 4, 1)}
+CHUNK_KERNEL = "chunk_prefill_attention"
+
+
+@pytest.mark.parametrize("width", [128, 512])
+@pytest.mark.parametrize("cell", list(SCRATCH_SHAPES))
+def test_the_chunk_kernel_compiles_at_a_cells_shapes(v5e, no_compile_cache,
+                                                     monkeypatch, cell,
+                                                     width):
+    """The dispatcher's call for a chunk and for an admission group at a
+    cell's widths: the whole stacked scratch, a layer that is an operand,
+    two KV heads a chip under ``shard_map`` on the four-chip mesh. The
+    kernel reads the scratch where it lies: nothing of it is a temporary,
+    nothing is gathered, and the compiler prints the kernel under its own
+    name, which the decode step marker is no prefix of."""
+    from benchmark.families import decoder
+    monkeypatch.setattr(attention_ops, "on_tpu", lambda: True)
+    kh, group, s_max, planes, chips = SCRATCH_SHAPES[cell]
+    mesh = Mesh(np.array(v5e[:chips]).reshape(1, 1, 1, chips),
+                ("dp", "fsdp", "sp", "tp"))
+
+    def s(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+    scratch = s((planes, 1, s_max, kh, D), jnp.bfloat16,
+                attention_ops._POOL5)
+    args = [s((1, width, kh * group, D), jnp.bfloat16,
+              attention_ops._HEADS4), scratch, scratch,
+            s((1, width), jnp.int32, P()), s((), jnp.int32, P())]
+    compiled = jax.jit(
+        lambda q, k, v, positions, layer:
+        attention_ops.chunk_prefill_attention(
+            q, k, v, positions, layer=layer, mesh=mesh)).lower(
+                *args).compile()
+    text = compiled.as_text()
+    assert _kernel_names(text) == [CHUNK_KERNEL]
+    assert not CHUNK_KERNEL.startswith(decoder.STEP_MARKER)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+    assert "all-gather" not in text
+
+
+@pytest.mark.parametrize("configuration", [
+    "mixtral-8x7b-l4", "mistral-7b-v0.3-tp4", "ouro-2.6b"])
+def test_prefill_programs_attend_in_the_chunk_kernel(
+        v5e, no_compile_cache, monkeypatch, configuration):
+    """A configuration's ``chunk`` and admission-``group`` programs (two
+    layers of it) at the benchmark's sizes, as the chip compiles them: the
+    chunk kernel once a layer — under ``shard_map`` on four chips, once in
+    the body of the looped family's pass loop — and no kernel with the
+    decode step marker's name. What the XLA form kept in HBM is gone: no
+    float32 ``[heads, queries, max_seq_len]`` (its scores; the hidden
+    width of Mixtral is ``max_seq_len`` too, so the test is of the whole
+    shape) and no scratch broadcast to the query heads (``_expand_gqa``)."""
+    from benchmark import manifest
+    cfg, family, n_chips, _, jobs = _decode_programs(
+        v5e, monkeypatch, configuration, n_layers=2,
+        kinds=("chunk", "chunkgroup"))
+    engine = manifest.load_config(manifest.load(), configuration)["engine"]
+    s_max, chunk = engine["max_seq_len"], engine["prefill_chunk"]
+    widths = {("chunk", chunk): chunk,
+              ("chunkgroup", engine["admit_group_chunks"]):
+                  chunk * engine["admit_group_chunks"]}
+    assert {key for key, _, _ in jobs} == set(widths)
+    kh, group = cfg.n_kv_heads // n_chips, cfg.n_heads // cfg.n_kv_heads
+    for key, fn, args in jobs:
+        text = fn.lower(*args).compile().as_text()
+        kernels = _kernel_names(text)
+        assert kernels.count(CHUNK_KERNEL) == cfg.n_layers, key
+        assert not [k for k in kernels
+                    if k.startswith(family.STEP_MARKER)], key
+        assert not re.search(rf"f32\[(1,)?{cfg.n_heads // n_chips},"
+                             rf"{widths[key]},{s_max}\]", text), key
+        assert group == 1 or not re.search(
+            rf"\[[\d,]*{s_max},{kh},{group},{cfg.head_dim}\]", text), key
 
 
 def _pool_shaped(text: str, pool: tuple) -> list:

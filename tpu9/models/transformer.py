@@ -243,9 +243,11 @@ def _cache_write(cache: jnp.ndarray, layer_idx: int, item, positions):
 
 
 def _cache_read(cache: jnp.ndarray, layer_idx: int):
-    """One layer's plane of a dense cache, for an attention that takes
-    ``[B, S, KH, D]``: an XLA consumer fuses the slice; the ragged pallas
-    kernel has it materialised."""
+    """One layer's plane of a dense cache, for a decode attention that
+    takes ``[B, S, KH, D]``: an XLA consumer fuses the slice; the ragged
+    pallas kernel has it materialised. Chunked prefill cuts no plane: its
+    kernel reads the cache at its layer (``chunk_prefill_attention``; the XLA
+    form it falls back to slices there, under the same scope)."""
     with jax.named_scope("kv.slice"):
         return cache[layer_idx]
 
@@ -350,17 +352,18 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
         if not decode and cache_len is None:
             with jax.named_scope("attn.core"):
                 out = attention(q, k, v, causal=True, mesh=mesh)
-        else:
+        elif decode:
             k_cache = _cache_read(kv_cache["k"], layer_idx)
             v_cache = _cache_read(kv_cache["v"], layer_idx)
             with jax.named_scope("attn.core"):
-                if decode:
-                    out = decode_attention(q, k_cache, v_cache, cache_len,
-                                           mesh=mesh)
-                else:
-                    from ..ops.attention import chunk_prefill_attention
-                    out = chunk_prefill_attention(q, k_cache, v_cache,
-                                                  positions)
+                out = decode_attention(q, k_cache, v_cache, cache_len,
+                                       mesh=mesh)
+        else:
+            from ..ops.attention import chunk_prefill_attention
+            with jax.named_scope("attn.core"):
+                out = chunk_prefill_attention(
+                    q, kv_cache["k"], kv_cache["v"], positions,
+                    layer=layer_idx, mesh=mesh)
 
     with jax.named_scope("attn.out"):
         out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)

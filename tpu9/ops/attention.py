@@ -9,16 +9,20 @@ Design notes (see /opt/skills/guides/pallas_guide.md):
 - causal blocks fully above the diagonal are skipped via ``pl.when`` so the
   kernel does ~half the work of the dense path at long sequence lengths.
 - accumulation in f32; inputs may be bf16.
+- chunked prefill has a kernel of its own (``ops/chunk_attention.py``): the
+  same schedule over the dense scratch where it lies, bounded by the keys
+  that are written.
 
 On CPU (tests) the same kernel runs with ``interpret=True``; model code picks
 the XLA path automatically when not on TPU.
 
 The dispatchers (:func:`attention`, :func:`decode_attention`,
-:func:`paged_attention_dispatch`) take the serving mesh: GSPMD cannot
-partition a Mosaic kernel, so on a mesh each chip runs the kernel on its own
-heads under ``shard_map``. Each has a ``*_kernel_declined`` twin that says
-why a shape takes the XLA oracle instead — the engine logs and reports it at
-build, so a TPU replica that is not running the kernels is visible.
+:func:`chunk_prefill_attention`, :func:`paged_attention_dispatch`) take the
+serving mesh: GSPMD cannot partition a Mosaic kernel, so on a mesh each chip
+runs the kernel on its own heads under ``shard_map``. Each has a
+``*_kernel_declined`` twin that says why a shape takes the XLA oracle instead
+— the engine logs and reports it at build, so a TPU replica that is not
+running the kernels is visible.
 """
 
 from __future__ import annotations
@@ -264,10 +268,12 @@ def decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
         (_HEADS4, _HEADS4, _HEADS4, P()))(q, k_cache, v_cache, cache_len)
 
 
-def chunk_prefill_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
-                            v_cache: jnp.ndarray,
-                            positions: jnp.ndarray) -> jnp.ndarray:
-    """Attention for one prefill CHUNK against the whole written prefix.
+def xla_chunk_prefill_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
+                                v_cache: jnp.ndarray,
+                                positions: jnp.ndarray) -> jnp.ndarray:
+    """Attention for one prefill CHUNK against the whole written prefix, as
+    one XLA graph: the oracle of the chunk kernel and what serves the shapes
+    it declines (:func:`chunk_prefill_attention` dispatches).
 
     q [B, C, QH, D] are the chunk's queries at absolute ``positions``
     [B, C]; k/v_cache [B, S, KH, D] already contain the prefix AND this
@@ -276,9 +282,10 @@ def chunk_prefill_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     causal structure within the chunk (and hides garbage past the written
     region, since garbage positions exceed every query position).
 
-    This is what makes long-prompt prefill WITHOUT a full-length compile
-    bucket possible (VERDICT r03 weak #5 'chunked prefill'): the graph's
-    shapes are (C, S) regardless of prompt length.
+    The graph's shapes are (C, S) whatever the prompt's length, which is
+    what lets a long prompt prefill without a compile bucket of its own;
+    the cost is float32 scores ``[QH, C, S]`` in HBM over ALL ``S``
+    positions, with the KV heads repeated to the query heads.
     """
     q_heads = q.shape[2]
     k = _expand_gqa(k_cache, q_heads)
@@ -293,6 +300,40 @@ def chunk_prefill_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhts,bshd->bthd", probs, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def chunk_kernel_declined(t: int, s: int, head_dim: int) -> str:
+    """Why :func:`chunk_prefill_attention` takes the XLA path for ``t``
+    queries against an ``s``-wide cache ('' = the chunk kernel runs)."""
+    if (why := _no_kernel_for(head_dim)):
+        return why
+    if t % 128 or s % 128:
+        return f"chunk ({t}, {s}) is not a multiple of the 128 block"
+    return ""
+
+
+def chunk_prefill_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
+                            v_cache: jnp.ndarray, positions: jnp.ndarray,
+                            layer=0, mesh=None) -> jnp.ndarray:
+    """Chunked-prefill dispatch: q [B, C, QH, D] at absolute ``positions``
+    [B, C], each row's contiguous from its first, against the dense cache
+    [L, B, S, KH, D] at ``layer`` (or one layer's [B, S, KH, D]) that
+    already holds the prefix AND the chunk. On TPU, for block-aligned
+    shapes, the pallas kernel (``ops/chunk_attention.py``), which reads the
+    cache where it lies and only as far as it is written; otherwise
+    :func:`xla_chunk_prefill_attention` over the layer's whole plane."""
+    if chunk_kernel_declined(q.shape[1], k_cache.shape[-3], q.shape[-1]):
+        if k_cache.ndim == 5:
+            with jax.named_scope("kv.slice"):
+                k_cache, v_cache = k_cache[layer], v_cache[layer]
+        return xla_chunk_prefill_attention(q, k_cache, v_cache, positions)
+    from .chunk_attention import flash_chunk_prefill_attention
+    cache = _POOL5 if k_cache.ndim == 5 else _HEADS4
+    return _per_chip_heads(
+        flash_chunk_prefill_attention, mesh,
+        (_HEADS4, cache, cache, P(), P()))(
+            q, k_cache, v_cache, positions[:, 0],
+            jnp.asarray(layer, jnp.int32))
 
 
 def paged_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
@@ -314,9 +355,11 @@ def paged_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     forward verifies ``T = 1 + spec_len`` positions for the whole batch,
     which is the entire point of speculative decoding in the
     bandwidth-bound decode regime: the weight stream is paid once for T
-    tokens instead of once per token. (A pallas kernel that walks the
-    table without the densify copy is the on-chip optimization path; the
-    gather form is the correctness-first dispatch every backend runs.)
+    tokens instead of once per token. The densified rows go through
+    :func:`chunk_prefill_attention`, whose shape rule gives a window of
+    ``1 + spec_len`` queries the XLA form (the chunk kernel takes multiples
+    of 128). A kernel that walks the table itself, without the densify
+    copy, is not built (ROADMAP R10).
 
     An int8 pool passes ``k_scale``/``v_scale`` (one rank less) — blocks
     are dequantized right after the gather (per-vector scales, see

@@ -60,6 +60,27 @@ def _head_update(h, q, k, v, first_pos, seq_len, m_scr, l_scr, acc_scr):
     m_scr[h] = m_new
 
 
+def head_pair_words(buf, first_row, rows: int, j):
+    """Rows ``first_row`` … ``first_row + rows`` of KV heads ``2j`` and
+    ``2j + 1`` out of a bfloat16 buffer laid ``[..., rows, KH, D]`` as the
+    cache is, as ``[rows, D]`` 32-bit words: one word holds the same lane of
+    the two neighbouring heads, so ONE strided load brings the pair where
+    ``buf[:, h, :]`` re-reads the whole buffer for every head.
+    :func:`widen_half` makes each half float32."""
+    pairs = buf.shape[-2] // 2
+    flat = buf.reshape(-1, buf.shape[-1]).bitcast(jnp.uint32)
+    at = first_row * pairs + j
+    return flat[pl.ds(at, rows, stride=pairs) if pairs > 1
+                else pl.ds(at, rows), :]
+
+
+def widen_half(x, odd):
+    """The even (low half) or odd head of :func:`head_pair_words` as float32:
+    a shift or a mask, which is exact."""
+    x = x & jnp.uint32(0xFFFF0000) if odd else x << 16
+    return pltpu.bitcast(x, jnp.float32)
+
+
 def _finalize_heads(o_ref, m_scr, l_scr, acc_scr, kv_heads: int):
     for h in range(kv_heads):
         l = l_scr[h][:, :1]
@@ -275,27 +296,18 @@ def _walk_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
                 update(h, *(buf[slot, pl.ds(first, rows), h, :].astype(
                     jnp.float32) for buf in (k_buf, v_buf)), first_pos)
             return
-        pairs = kv_heads // 2
-
-        def words(buf, j):
-            flat = buf.reshape(-1, buf.shape[-1]).bitcast(jnp.uint32)
-            at = (slot * wave * block_s + first) * pairs + j
-            return flat[pl.ds(at, rows, stride=pairs) if pairs > 1
-                        else pl.ds(at, rows), :]
-
-        def half(x, odd):
-            x = x & jnp.uint32(0xFFFF0000) if odd else x << 16
-            return pltpu.bitcast(x, jnp.float32)
 
         def one_pair(j, _):
-            kw, vw = words(k_buf, j), words(v_buf, j)
+            kw, vw = (head_pair_words(buf, slot * wave * block_s + first,
+                                      rows, j) for buf in (k_buf, v_buf))
             for odd in (0, 1):
-                update(2 * j + odd, half(kw, odd), half(vw, odd), first_pos)
+                update(2 * j + odd, widen_half(kw, odd), widen_half(vw, odd),
+                       first_pos)
 
-        if pairs == 1:
+        if kv_heads == 2:
             one_pair(0, None)
         else:
-            jax.lax.fori_loop(0, pairs, one_pair, None)
+            jax.lax.fori_loop(0, kv_heads // 2, one_pair, None)
 
     def one_wave(w, _):
         slot = jax.lax.rem(first_slot + w, 2)

@@ -511,19 +511,25 @@ class InferenceEngine:
     def _attention_paths(self) -> dict:
         """``{"decode", "prefill"}`` → ``"pallas"`` or ``"xla: <why>"`` for
         this engine's shapes, from the same predicates the dispatchers in
-        ``ops.attention`` decide with. A TPU replica whose decode declined
-        the kernel still serves correctly through the oracle, so say why
-        once here, where an operator reads the bring-up log."""
+        ``ops.attention`` decide with when a program is traced (a paged
+        engine's prefill: the chunk kernel at its chunk's and its admission
+        group's widths). A TPU replica whose attention declined a kernel
+        still serves correctly through the oracle, so say why once here,
+        where an operator reads the bring-up log."""
         from ..ops import attention as ops
         hd = self.cfg.head_dim
+        s_max = self.ecfg.max_seq_len
         if self.paged:
             decode = ops.paged_kernel_declined(self.ecfg.kv_block_size, hd)
-            prefill = "chunked prefill has no kernel"
+            # a chunk and an admission group are the two widths admitted
+            chunk = self.graphs.chunk
+            reasons = {ops.chunk_kernel_declined(t, s_max, hd)
+                       for t in (chunk, chunk * self.graphs.group_chunks)}
         else:
-            decode = ops.ragged_kernel_declined(self.ecfg.max_seq_len, hd)
-            prefill = "; ".join(sorted(
-                {ops.flash_kernel_declined(bk, bk, hd)
-                 for bk in self._buckets} - {""}))
+            decode = ops.ragged_kernel_declined(s_max, hd)
+            reasons = {ops.flash_kernel_declined(bk, bk, hd)
+                       for bk in self._buckets}
+        prefill = "; ".join(sorted(reasons - {""}))
         if decode and self._devices[0].platform == "tpu":
             logging.getLogger("tpu9.serving").warning(
                 "decode attention runs the XLA oracle, not the pallas "
