@@ -62,6 +62,14 @@ class DecoderConfig:
     # ``exit_threshold``, else the last pass's
     exit_gate: bool = False
     exit_threshold: float = 1.0
+    # attention that is exact inside a window of ``attn_window`` positions
+    # (block-diagonal: it does not slide) and reads one summary for every
+    # ``attn_chunk`` positions of every earlier window (``ops.
+    # summary_attention``); 0 = plain causal attention. The cache then holds
+    # ``attn_window / attn_chunk`` entries a closed window, not one a token
+    # (``kv_entry``), and the residual stream is carried in float32
+    attn_window: int = 0
+    attn_chunk: int = 0
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self):
@@ -79,6 +87,20 @@ class DecoderConfig:
                 "count, the paged pool no keys and values for the passes "
                 "a token skipped (later tokens read them), and the "
                 "scheduler prices every step alike")
+        if self.attn_window or self.attn_chunk:
+            if (self.attn_window <= 0 or self.attn_chunk <= 0
+                    or self.attn_window % self.attn_chunk):
+                raise ValueError(
+                    f"attn_window={self.attn_window}, attn_chunk="
+                    f"{self.attn_chunk}: a window is a whole number of "
+                    "chunks, because a summary stands for one whole chunk "
+                    "of a closed window")
+            if self.n_experts or self.looped:
+                raise ValueError(
+                    "attn_window with experts or a pass loop: no served "
+                    "model has both; the window's summarise is not built "
+                    "for a cache of loop_steps planes a layer, and no "
+                    "expert layer was ever run over a float32 stream")
 
     @property
     def q_per_kv(self) -> int:
@@ -95,6 +117,45 @@ class DecoderConfig:
         """Depth of the KV state: a token owns one plane of keys and
         values per (pass, layer), although the weights have ``n_layers``."""
         return self.n_layers * self.loop_steps
+
+    # -- where a token lives in its cache -----------------------------------
+    # ``pos // block`` used to be the place of a token in its cache
+    # everywhere. It is ``kv_entry(pos) // block`` now: the three functions
+    # below are the one place that knows the rule, they take ints, numpy and
+    # jax arrays alike, and for plain attention each returns its argument
+    # itself, so that a plain program lowers as it did.
+
+    @property
+    def window_entries(self) -> int:
+        """Cache entries a CLOSED window keeps: one a chunk."""
+        return self.attn_window // self.attn_chunk if self.attn_window else 0
+
+    def kv_entry(self, pos):
+        """The cache entry of the token at position ``pos``: the summaries
+        of the windows before its own come first, then its place in its
+        window."""
+        if not self.attn_window:
+            return pos
+        return (self.window_entries * (pos // self.attn_window)
+                + pos % self.attn_window)
+
+    def kv_entries(self, n):
+        """Entries a sequence of ``n`` tokens holds (what attention masks
+        by): one past its last token's. A window is summarised when the
+        NEXT one opens, so this is not ``kv_entry(n)``."""
+        if not self.attn_window:
+            return n
+        return (self.kv_entry(n - 1) + 1) * (n > 0)
+
+    def kv_entries_peak(self, n: int) -> int:
+        """The most entries a sequence addresses on its way to ``n`` tokens
+        (what a reservation, a table and the prefill scratch are sized by):
+        a window is at its widest just before it closes."""
+        if not self.attn_window or n <= self.attn_window:
+            return n
+        last = (n - 1) // self.attn_window
+        return max(self.kv_entry(last * self.attn_window - 1) + 1,
+                   self.kv_entry(n - 1) + 1)
 
 
 def _dense_init(rng, in_dim: int, out_dim: int, dtype) -> jnp.ndarray:
@@ -146,8 +207,19 @@ def init_decoder(rng: jax.Array, cfg: DecoderConfig) -> Params:
             layer["w_up"] = _dense_init(nxt(), cfg.dim, cfg.hidden_dim, dt)
             layer["w_down"] = _dense_init(nxt(), cfg.hidden_dim, cfg.dim, dt)
         layer.update(init_post_norms(cfg))
+        layer.update(init_summary_vectors(jax.random.fold_in(rng, li), cfg))
         params["layers"].append(layer)
     return params
+
+
+def init_summary_vectors(rng: jax.Array, cfg: DecoderConfig) -> Params:
+    """The two vectors a head that weigh a chunk's tokens into its summary
+    (``attn_window``; none otherwise). Their rng is folded from the tree's
+    own, so the schedule of every other leaf is the plain decoder's."""
+    if not cfg.attn_window:
+        return {}
+    from ..ops.summary_attention import init_vectors
+    return init_vectors(rng, cfg.n_kv_heads, cfg.head_dim)
 
 
 def init_post_norms(cfg: DecoderConfig) -> Params:
@@ -216,6 +288,10 @@ DEVICE_SCOPES = ("embed", "attn.qkv", "attn.rope", "kv.slice", "kv.write",
 # no plain program runs anything under them. Apart from ``DEVICE_SCOPES``:
 # the benchmark's accepted tests pin what that tuple leaves ungrouped.
 LOOP_SCOPES = ("loop.norm", "loop.gate", "loop.select")
+# The summarise of a closed window (``attn_window``, ISSUE 46), in every
+# program that runs it: ``serving.graphs`` opens the scope. Apart for the
+# same reason.
+SUMMARY_SCOPES = ("kv.summarise",)
 
 
 def _pool_write(pool: jnp.ndarray, layer_idx: int, bi, oi, value):
@@ -283,9 +359,27 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
         q = apply_rope(q, positions, sin, cos)
         k = apply_rope(k, positions, sin, cos)
 
+    # rotary keeps the position; the cache is addressed, and attention is
+    # masked, by ENTRY (the same arrays for plain attention)
+    entries = cfg.kv_entry(positions)
+    if cache_len is not None:
+        cache_len = cfg.kv_entries(cache_len)
+    if cfg.attn_window and kv_cache is not None and (
+            "table" not in kv_cache and (decode or cache_len is None)):
+        raise NotImplementedError(
+            "attn_window over a dense cache is built for chunked prefill "
+            "alone (the paged engine's scratch): a dense decode cache has "
+            "no program that summarises a closed window")
+
     if kv_cache is None:
         with jax.named_scope("attn.core"):
-            out = attention(q, k, v, causal=True, mesh=mesh)
+            if cfg.attn_window:
+                from ..ops.summary_attention import windowed_summary_attention
+                out = windowed_summary_attention(
+                    q, k, v, layer["summary_mu"], layer["summary_phi"],
+                    cfg.attn_window, cfg.attn_chunk)
+            else:
+                out = attention(q, k, v, causal=True, mesh=mesh)
     elif "table" in kv_cache:
         # paged: scatter the window's k/v into the slots' physical pool
         # blocks, then attend over each slot's block table. Pool layout
@@ -306,11 +400,11 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
         table = kv_cache["table"]                      # [B, MB]
         bs = kv_cache["k"].shape[2]                    # [L,N,BS,KH,D]
         if decode:
-            pos = positions[:, 0]                      # [B]
+            pos = entries[:, 0]                        # [B]
             bi = table[jnp.arange(b), pos // bs]
             k, v = k[:, 0], v[:, 0]                    # [B,KH,D]
         else:
-            pos = positions                            # [B,T]
+            pos = entries                              # [B,T]
             bi = jnp.take_along_axis(table, pos // bs, axis=1)
         oi = pos % bs
         kv_cache = dict(kv_cache)
@@ -332,7 +426,7 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
                     *scales, mesh=mesh, layer=layer_idx)
             else:
                 out = paged_verify_attention(
-                    q, kv_cache["k"], kv_cache["v"], table, positions,
+                    q, kv_cache["k"], kv_cache["v"], table, entries,
                     *scales, layer=layer_idx)
     else:
         # dense cache [L, B, S, KH, D]. Decode: this token's k/v at each
@@ -347,8 +441,8 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
         # [0, t), then causal attention within the prompt itself.
         kv_cache = dict(
             kv_cache,
-            k=_cache_write(kv_cache["k"], layer_idx, k, positions),
-            v=_cache_write(kv_cache["v"], layer_idx, v, positions))
+            k=_cache_write(kv_cache["k"], layer_idx, k, entries),
+            v=_cache_write(kv_cache["v"], layer_idx, v, entries))
         if not decode and cache_len is None:
             with jax.named_scope("attn.core"):
                 out = attention(q, k, v, causal=True, mesh=mesh)
@@ -362,7 +456,7 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
             from ..ops.attention import chunk_prefill_attention
             with jax.named_scope("attn.core"):
                 out = chunk_prefill_attention(
-                    q, kv_cache["k"], kv_cache["v"], positions,
+                    q, kv_cache["k"], kv_cache["v"], entries,
                     layer=layer_idx, mesh=mesh)
 
     with jax.named_scope("attn.out"):
@@ -533,10 +627,18 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
     moe_balance = jnp.zeros((), jnp.float32)
     exit_info = None
     if not cfg.looped:
+        # with ``attn_window`` the residual stream is float32 and every
+        # sub-layer still computes in the embeddings' type, as in the pass
+        # loop (``_looped_passes``); None: the stream's own type throughout
+        compute_dtype = x.dtype if cfg.attn_window else None
+        if compute_dtype is not None:
+            x = x.astype(jnp.float32)
         x, kv_cache, moe_balance = _layers(
             params, x, cfg, positions, sin, cos, kv_cache, 0, cache_len,
-            decode, mesh, moe_balance)
+            decode, mesh, moe_balance, compute_dtype)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_offset)
+        if compute_dtype is not None:
+            x = x.astype(compute_dtype)
     else:
         x, kv_cache, moe_balance, exit_info = _looped_passes(
             params, x, cfg, positions, sin, cos, kv_cache, cache_len,

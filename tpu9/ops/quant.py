@@ -188,7 +188,7 @@ def init_quantized_decoder(rng, cfg) -> Params:
             "b": jnp.zeros((1,), jnp.float32)}
     q_dim = cfg.n_heads * cfg.head_dim
     kv_dim = cfg.n_kv_heads * cfg.head_dim
-    for _ in range(cfg.n_layers):
+    for li in range(cfg.n_layers):
         layer = {
             "attn_norm": jnp.ones((cfg.dim,), jnp.float32) - cfg.norm_offset,
             "mlp_norm": jnp.ones((cfg.dim,), jnp.float32) - cfg.norm_offset,
@@ -224,6 +224,10 @@ def init_quantized_decoder(rng, cfg) -> Params:
                 layer[name] = jnp.full((cfg.dim,),
                                        (2.0 * cfg.n_layers) ** -0.5,
                                        jnp.float32) - cfg.norm_offset
+        if cfg.attn_window:
+            from .summary_attention import init_vectors
+            layer.update(init_vectors(jax.random.fold_in(rng, li),
+                                      cfg.n_kv_heads, cfg.head_dim))
         params["layers"].append(layer)
     return params
 

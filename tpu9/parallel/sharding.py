@@ -44,6 +44,8 @@ def _layer_specs(layer: Params, tp: str, fsdp: Optional[str],
         "mlp_norm": P(),
         "attn_post_norm": P(),      # a sandwich-norm layer's other two
         "mlp_post_norm": P(),
+        "summary_mu": P(),          # [KH, D] a layer: replicated like a norm
+        "summary_phi": P(),
         "wq": P(fsdp, tp),
         "wk": P(fsdp, tp),
         "wv": P(fsdp, tp),

@@ -151,6 +151,63 @@ class EngineConfig:
     flight_cap: int = 256
 
 
+def refuse_unbuilt_with_summaries(cfg, ecfg: "EngineConfig",
+                                  topo: dict) -> None:
+    """What is not built for a cache of window summaries (``DecoderConfig.
+    attn_window``): refused when the engine is made, each with its reason,
+    and not half-built."""
+    w, entries = cfg.attn_window, cfg.window_entries
+    chunk = ecfg.prefill_chunk or min(ecfg.prefill_buckets)
+
+    def refuse(what: str, why: str):
+        raise ValueError(f"attn_window={w} with {what}: {why}")
+
+    if ecfg.kv_block_size <= 0:
+        refuse("kv_block_size=0 (the dense cache)",
+               "a closed window's summaries replace its tokens page by "
+               "page; only the paged pool has pages, and the dense decode "
+               "cache has no program that summarises")
+    if ecfg.kv_block_size != entries:
+        refuse(f"kv_block_size={ecfg.kv_block_size}",
+               f"a closed window's {entries} summaries (attn_window / "
+               "attn_chunk) must be exactly one page, so that the pool has "
+               "one page shape and a window's first page can take them")
+    if w % chunk:
+        refuse(f"prefill_chunk={chunk}",
+               "a chunk that straddles two windows would need the first "
+               "one summarised in the middle of its forward pass")
+    group = max(1, ecfg.admit_group_chunks) * chunk
+    if group <= ecfg.max_seq_len and w % group:
+        refuse(f"admit_group_chunks={ecfg.admit_group_chunks} x "
+               f"prefill_chunk={chunk}",
+               "an admission group is one forward pass and may not "
+               "straddle two windows either")
+    if ecfg.prefix_cache_blocks > 0:
+        refuse(f"prefix_cache_blocks={ecfg.prefix_cache_blocks}",
+               "the pages of an open window are rewritten in place when it "
+               "closes, so a page shared by two sequences would be "
+               "overwritten by one of them; sharing whole closed windows' "
+               "summary pages is not built")
+    if ecfg.kv_quant:
+        refuse(f"kv_quant={ecfg.kv_quant!r}",
+               "the summarise reads and writes the pool in the model's "
+               "type and knows no scale planes")
+    if topo["tp"] > 1:
+        refuse(f"tp={topo['tp']}",
+               "the summarise loop is one chip's program: under a mesh it "
+               "would have to run per chip on its own heads, which is not "
+               "built")
+    if ecfg.spec_len > 0:
+        refuse(f"spec_len={ecfg.spec_len} (verify)",
+               "a verify window writes several positions a lane at once "
+               "and may cross a window's end inside one forward pass; "
+               "rejected drafts would also have been summarised")
+    if ecfg.kv_host_pool_mb > 0:
+        refuse(f"kv_host_pool_mb={ecfg.kv_host_pool_mb}",
+               "the host tier and the kvwire format address blocks by "
+               "token position, and this pool is addressed by entry")
+
+
 @dataclass
 class _Window:
     """One dispatched decode/verify window whose host fan-out is deferred:
@@ -265,6 +322,8 @@ class InferenceEngine:
                 "chips on fsdp, e.g. 'tp=2,fsdp=2') or topology='auto'")
         b, s = engine_cfg.max_batch, engine_cfg.max_seq_len
         self.paged = engine_cfg.kv_block_size > 0
+        if cfg.attn_window:
+            refuse_unbuilt_with_summaries(cfg, engine_cfg, topo)
         from ..ops.quant import validate_quant_mode
         _kvq = validate_quant_mode(engine_cfg.kv_quant, "kv_quant")
         if _kvq and _kvq != "int8":
@@ -323,7 +382,10 @@ class InferenceEngine:
             self._mb = self.pool.mb
             # batch-1 dense scratch the chunked prefill writes through
             # before splicing into pool blocks — ONE lane, not B of them
-            self._scratch = policy.place_kv(init_kv_cache(cfg, 1, s))
+            # (a row a cache ENTRY: ``max_seq_len`` for plain attention)
+            from .paged_kv import scratch_len
+            self._scratch = policy.place_kv(init_kv_cache(
+                cfg, 1, scratch_len(cfg, s, chunk)))
         else:
             self.pool = None
             self.kv_cache = policy.place_kv(init_kv_cache(cfg, b, s))
@@ -403,6 +465,25 @@ class InferenceEngine:
         self._loop_exit_hist = [0] * cfg.loop_steps
         if cfg.looped:
             self._stats.update(loop_tokens=0, loop_passes=0)
+        # a cache of window summaries (ISSUE 46): windows closed by prefill
+        # chunks and inside decode programs, and, summed over the lanes of
+        # every decode step, the tokens resident, the cache entries that
+        # hold them (what the step's attention reads) and how many of those
+        # are summaries. Plain attention has none of the five: an entry is
+        # a token there
+        if cfg.attn_window:
+            self._stats.update(windows_closed_prefill=0,
+                               windows_closed_decode=0,
+                               decode_resident_tokens=0,
+                               decode_resident_entries=0,
+                               decode_summary_entries=0,
+                               # the most pages the pool ever held in use
+                               # (less the trash block), and the most by
+                               # which they ever exceeded the reservations,
+                               # which are in entries: 0, they never did
+                               # (no prefix cache holds pages here)
+                               kv_pages_used_peak=0,
+                               kv_pages_over_reservation=0)
         # ---- observability (ISSUE 8) ----
         # flight recorder: bounded per-window ring (None = disabled)
         self.flight = flight_maybe(engine_cfg.flight_cap)
@@ -523,6 +604,7 @@ class InferenceEngine:
             decode = ops.paged_kernel_declined(self.ecfg.kv_block_size, hd)
             # a chunk and an admission group are the two widths admitted
             chunk = self.graphs.chunk
+            s_max = self.graphs.scratch_len
             reasons = {ops.chunk_kernel_declined(t, s_max, hd)
                        for t in (chunk, chunk * self.graphs.group_chunks)}
         else:
@@ -624,11 +706,44 @@ class InferenceEngine:
         slack = max(self.ecfg.decode_steps) + 1
         if self._spec_lens:
             slack = max(slack, 2 * (self._spec_lens[-1] + 1) + 1)
-        return min(len(req.prompt) + req.max_new_tokens + slack,
-                   self.ecfg.max_seq_len)
+        # (in cache entries, which is what the allocator's blocks hold)
+        return self.cfg.kv_entries_peak(
+            min(len(req.prompt) + req.max_new_tokens + slack,
+                self.ecfg.max_seq_len))
 
     def _alloc_blocks(self, n: int) -> list[int]:
-        return self.pool.alloc_blocks(n)
+        got = self.pool.alloc_blocks(n)
+        if self.cfg.attn_window:
+            self._note_pages()
+        return got
+
+    def _note_pages(self) -> None:
+        """After an allocation of a model with ``attn_window``: pages in
+        use, and against pages reserved."""
+        st = self._stats
+        used = self.allocator.used_count - 1
+        st["kv_pages_used_peak"] = max(st["kv_pages_used_peak"], used)
+        st["kv_pages_over_reservation"] = max(
+            st["kv_pages_over_reservation"], used - self.allocator.reserved)
+
+    def _note_decode_entries(self, k: int) -> None:
+        """The summary-cache counters of one decode window of ``k`` steps
+        about to be dispatched, from the host's mirror of the lengths: step
+        ``i`` of a lane that holds ``n`` tokens writes position ``n + i``
+        and attends ``kv_entries(n + i + 1)`` entries."""
+        cfg = self.cfg
+        if not cfg.attn_window:
+            return
+        lanes = self._host_len[self.active] + self._inflight_steps
+        pos = lanes[:, None] + np.arange(k)[None, :]      # written positions
+        windows = pos // cfg.attn_window                  # closed before it
+        st = self._stats
+        st["windows_closed_decode"] += int(
+            ((pos > 0) & (pos % cfg.attn_window == 0)).sum())
+        st["decode_resident_tokens"] += int((pos + 1).sum())
+        st["decode_resident_entries"] += int(cfg.kv_entries(pos + 1).sum())
+        st["decode_summary_entries"] += int(
+            (windows * cfg.window_entries).sum())
 
     def _push_table(self, slot: int) -> None:
         self.kv_cache["table"] = self.pool.push_table(slot)
@@ -638,6 +753,8 @@ class InferenceEngine:
         positions. Returns True when the table changed."""
         if not self.pool.ensure_slot_blocks(slot, n_tokens):
             return False
+        if self.cfg.attn_window:
+            self._note_pages()
         self._push_table(slot)
         return True
 
@@ -715,8 +832,7 @@ class InferenceEngine:
             np.asarray(jax.device_get(last[:4]))
             timings[f"chunk_{self._chunk}_s"] = _time.perf_counter() - t0
             t0 = _time.perf_counter()
-            bs = self.ecfg.kv_block_size
-            phys = jnp.full((self._chunk // bs,), self._trash_block,
+            phys = jnp.full(self.graphs.splice_shape(1), self._trash_block,
                             jnp.int32)
             self._set_pool(self._splice_fn()(
                 self._pool_dict(), self._scratch["k"], self._scratch["v"],
@@ -744,7 +860,7 @@ class InferenceEngine:
                     self.params, self._pool_dict(), self._scratch,
                     jnp.zeros((g, self._chunk), jnp.int32), 0,
                     self._chunk - 1,
-                    jnp.full((g, self._chunk // bs), self._trash_block,
+                    jnp.full(self.graphs.splice_shape(g), self._trash_block,
                              jnp.int32))
                 self._set_pool(pool)
                 np.asarray(jax.device_get(last[:4]))
@@ -928,8 +1044,11 @@ class InferenceEngine:
         block refs keep the blocks alive for the synchronous gather; the
         in-flight decode window only ever writes positions past the
         delivered sequence, which land in blocks beyond the shipped run.
-        None = request not active or under one full block."""
-        if not self.paged:
+        None = request not active or under one full block — or a pool of
+        window summaries, whose blocks are addressed by cache entry and not
+        by token position (the kvwire format's ``n_tokens``): the caller
+        re-prefills, as for any miss."""
+        if not self.paged or self.cfg.attn_window:
             return None
         from .paged_kv import PrefixCache
         for slot in range(self.ecfg.max_batch):
@@ -1293,7 +1412,7 @@ class InferenceEngine:
             shared, p = await self._admit_lookup(req)
 
         with phase("engine.admit.plan", totals):
-            total_blocks = blocks_for(n + 1, bs)
+            total_blocks = blocks_for(self.cfg.kv_entries_peak(n + 1), bs)
             fresh = self._alloc_blocks(total_blocks - len(shared))
             self._slot_blocks[slot] = shared + fresh
             # the DEVICE table row stays all-trash until admission
@@ -1320,6 +1439,11 @@ class InferenceEngine:
                 req, slot, p)
         n_chunks = len(offsets)
         self._stats["admit_chunks"] += n_chunks
+        if self.cfg.attn_window:
+            # a chunk whose first position opens a window closes the one
+            # before it (its program summarises at its head)
+            self._stats["windows_closed_prefill"] += int(
+                ((offsets > 0) & (offsets % self.cfg.attn_window == 0)).sum())
         last = None
         group = self.graphs.group_chunks
         k_chunk = 0
@@ -1340,7 +1464,8 @@ class InferenceEngine:
                         jnp.asarray(toks_all[sl]),
                         int(offsets[k_chunk]),
                         int(last_idxs[k_chunk + g - 1]),
-                        jnp.asarray(phys_all[sl]))
+                        self._splice_blocks(phys_all, k_chunk, g, slot,
+                                            int(offsets[k_chunk])))
                     self._set_pool(pool)
                     self._stats["admit_dispatches"] += 1
                     self._stats["admit_chunks_grouped"] += g
@@ -1352,7 +1477,8 @@ class InferenceEngine:
                     self._set_pool(self._splice_fn()(
                         self._pool_dict(), scratch["k"], scratch["v"],
                         int(offsets[k_chunk]),
-                        jnp.asarray(phys_all[k_chunk])))
+                        self._splice_blocks(phys_all, k_chunk, 1, slot,
+                                            int(offsets[k_chunk]))))
                     self._stats["admit_dispatches"] += 2
             k_chunk += g
             if k_chunk < n_chunks:
@@ -1435,12 +1561,30 @@ class InferenceEngine:
             toks_all[k_chunk, :valid] = suffix[i:i + valid]
             offsets[k_chunk] = p + i
             last_idxs[k_chunk] = valid - 1
-            first_block = (p + i) // bs
+            first_block = self.cfg.kv_entry(p + i) // bs
             for j in range(nb):
                 idx = first_block + j
                 if idx < len(self._slot_blocks[slot]):
                     phys_all[k_chunk, j] = self._slot_blocks[slot][idx]
         return toks_all, offsets, last_idxs, phys_all
+
+    def _splice_blocks(self, phys_all, k_chunk: int, g: int, slot: int,
+                       offset: int):
+        """The physical blocks that the splice of ``g`` chunks from
+        ``k_chunk`` on writes, as its program takes them: ``[C/BS]`` for
+        one chunk, ``[g, C/BS]`` for a group. With ``attn_window`` flat and
+        led by one more: the page that takes the summaries of the window
+        this chunk closed — the column before the chunk's own — or the
+        trash block where the chunk (at position ``offset``) opens none."""
+        rows = phys_all[k_chunk] if g == 1 else phys_all[k_chunk:k_chunk + g]
+        if not self.cfg.attn_window:
+            return jnp.asarray(rows)
+        lead = self._trash_block
+        if offset and offset % self.cfg.attn_window == 0:
+            lead = self._slot_blocks[slot][
+                self.cfg.kv_entry(offset) // self.ecfg.kv_block_size - 1]
+        return jnp.asarray(np.concatenate(
+            [[lead], rows.ravel()]).astype(np.int32))
 
     # -- KV tiering: up-page / down-page (ISSUE 20) --------------------------
 
@@ -1922,6 +2066,7 @@ class InferenceEngine:
                     slot, min(int(self._host_len[slot])
                               + self._inflight_steps + k + 1,
                               self.ecfg.max_seq_len))
+        self._note_decode_entries(k)
         (self.last_token, self.kv_cache, self.cache_len, self._rng,
          toks, *exits) = self._decode_k(k)(
             self.params, self.kv_cache, self.last_token, self.cache_len,
@@ -2234,6 +2379,7 @@ class InferenceEngine:
                         slot, min(int(self._host_len[slot])
                                   + self._inflight_steps + k + 1,
                                   self.ecfg.max_seq_len))
+        self._note_decode_entries(k)
         (self.last_token, self.kv_cache,
          self.cache_len, self._rng, toks, *exits) = self._decode_k(k)(
             self.params, self.kv_cache, self.last_token,

@@ -112,9 +112,11 @@ def kv_cache_bytes(cfg, max_batch: int, max_seq: int,
     """Dense-equivalent KV bytes: ``max_batch`` sequences of ``max_seq``
     tokens, priced by the SAME helper the engine's pool sizing divides by
     (``paged_kv.kv_block_bytes`` — one arithmetic, no drift when modes
-    are added)."""
+    are added) — per cache ENTRY such a sequence can address
+    (``kv_entries_peak``: a token, for plain attention)."""
     from .paged_kv import kv_block_bytes
-    return max_batch * kv_block_bytes(cfg, max_seq, kv_quant)
+    return max_batch * kv_block_bytes(cfg, cfg.kv_entries_peak(max_seq),
+                                      kv_quant)
 
 
 def hbm_budget(preset: str, tpu: "str | TpuSpec", *, max_batch: int = 8,

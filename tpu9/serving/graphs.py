@@ -30,7 +30,7 @@ from typing import Any, Iterator
 import jax
 import jax.numpy as jnp
 
-from ..models.transformer import (DEVICE_SCOPES, LOOP_SCOPES,
+from ..models.transformer import (DEVICE_SCOPES, LOOP_SCOPES, SUMMARY_SCOPES,
                                   decoder_forward, init_kv_cache)
 from ..ops.sampling import sample_logits
 
@@ -132,6 +132,13 @@ class GraphFactory:
         g = max(1, ecfg.admit_group_chunks)
         self.group_chunks = g if chunk and g * chunk <= ecfg.max_seq_len \
             else 1
+        # rows of the batch-1 prefill scratch (``max_seq_len`` for plain
+        # attention), and the blocks a splice writes before its chunk's own:
+        # with ``attn_window`` the page of summaries that the chunk's
+        # program may have made, which lies just below the chunk's entries
+        from .paged_kv import scratch_len
+        self.scratch_len = scratch_len(cfg, ecfg.max_seq_len, chunk)
+        self.splice_lead = 1 if cfg.attn_window else 0
         self.compiled: dict[Any, Any] = {}
         # recompile sentinel (ISSUE 11): executable-cache misses. After
         # seal() (warmup/precompile done) a miss means steady-state
@@ -198,8 +205,14 @@ class GraphFactory:
     def build_decode(self, k: int = 1):
         cfg, ecfg, policy = self.cfg, self.ecfg, self.policy
 
-        def one_step(params, kv_cache, last_token, cache_len, active, rng):
+        def one_step(params, kv_cache, last_token, cache_len, active, rng,
+                     vectors):
             positions = cache_len[:, None]          # next position per slot
+            if cfg.attn_window:
+                # a lane whose next token opens a window: the window it
+                # closed becomes its summaries first
+                kv_cache = self.traced_summarise_pool(vectors, kv_cache,
+                                                      cache_len, active)
             # an idle lane attends to nothing: its length is 0, so the paged
             # kernel walks no page for it (its token, like its write to the
             # trash block, is discarded)
@@ -222,10 +235,14 @@ class GraphFactory:
                     rng, exits)
 
         def decode(params, kv_cache, last_token, cache_len, active, rng):
+            # the layers' summary vectors, stacked once a program
+            vectors = self._summary_vectors(params) if cfg.attn_window \
+                else None
+
             def body(carry, _):
                 last, kv, clen, r = carry
                 last, kv, clen, r, exits = one_step(params, kv, last, clen,
-                                                    active, r)
+                                                    active, r, vectors)
                 return (last, kv, clen, r), \
                     (last[:, 0], *(e[:, 0] for e in exits))
 
@@ -337,6 +354,8 @@ class GraphFactory:
         logits at ``last_idx``."""
         width = tok_row.shape[0]
         positions = offset + jnp.arange(width)[None, :]
+        if self.cfg.attn_window:
+            scratch = self.traced_summarise_scratch(params, scratch, offset)
         logits, scratch = decoder_forward(
             params, tok_row[None, :], self.cfg, positions=positions,
             kv_cache=scratch, cache_len=offset + width, decode=False,
@@ -353,12 +372,24 @@ class GraphFactory:
         scales land in the scale planes at the same physical index)."""
         bs = self.ecfg.kv_block_size
         pool = dict(pool)
+        # the scratch is addressed by entry. With ``attn_window`` the first
+        # block of ``phys`` takes the page BEFORE the chunk's own: the
+        # summaries of the window that this chunk's program closed (the
+        # host names the trash block there for every other chunk)
+        offset = self.cfg.kv_entry(offset)
+        if self.splice_lead:
+            offset = offset - bs            # below 0 for the first chunk
+
+        def source(j):
+            at = offset + j * bs
+            return jnp.maximum(at, 0) if self.splice_lead and j == 0 else at
+
         with jax.named_scope("kv.splice"):
             for j in range(phys.shape[0]):
                 blk_k = jax.lax.dynamic_slice_in_dim(
-                    scratch_k[:, 0], offset + j * bs, bs, axis=1)
+                    scratch_k[:, 0], source(j), bs, axis=1)
                 blk_v = jax.lax.dynamic_slice_in_dim(
-                    scratch_v[:, 0], offset + j * bs, bs, axis=1)
+                    scratch_v[:, 0], source(j), bs, axis=1)
                 if "k_scale" in pool:
                     from ..ops.quant import quantize_kv
                     blk_k, sk = quantize_kv(blk_k)  # [L,bs,KH,D], [L,bs,KH]
@@ -368,6 +399,86 @@ class GraphFactory:
                 pool["k"] = pool["k"].at[:, phys[j]].set(blk_k)
                 pool["v"] = pool["v"].at[:, phys[j]].set(blk_v)
             return self.policy.constrain_kv(pool)
+
+    # -- the summarise of a closed window (``attn_window``) -------------------
+
+    def _summary_vectors(self, params) -> tuple:
+        """``(mu, phi)``, each ``[L, KH, D]``: the layers' vectors stacked,
+        for a loop that takes its layer as an operand."""
+        return tuple(jnp.stack([layer[name] for layer in params["layers"]])
+                     for name in ("summary_mu", "summary_phi"))
+
+    def traced_summarise_pool(self, vectors, kv_cache, cache_len, active):
+        """Inside a decode step, before the forward pass (``vectors``: the
+        layers' summary vectors stacked, :meth:`_summary_vectors`): for every live
+        lane whose next position is the first of a window, the pages of the
+        window it closed are read (the ``window / block`` columns of its
+        table row from the window's first on), summarised layer by layer,
+        and the summaries written as ONE page, over the window's own first.
+        A device loop of ``lanes that roll x layers`` trips: a step in which
+        no lane rolls over runs none and pays for none. The ``kv.summarise``
+        scope holds the loop's body and nothing else, so that a trace's
+        operations under it are one trip each."""
+        from ..ops.summary_attention import summarise
+        cfg = self.cfg
+        w, layers = cfg.attn_window, cfg.n_layers
+        pages = w // self.ecfg.kv_block_size
+        shape = (w, cfg.n_kv_heads, cfg.head_dim)
+        table = kv_cache["table"]
+        mu, phi = vectors
+        rolls = (active & (cache_len > 0)
+                 & (cache_len % w == 0)).astype(jnp.int32)
+
+        @jax.named_scope("kv.summarise")
+        def one(i, pools):
+            # trip i: layer i % L of the (i // L)-th lane that rolls over
+            lane = jnp.argmax(jnp.cumsum(rolls) > i // layers)
+            layer = i % layers
+            first = cache_len[lane] // w - 1    # column of the closed window
+            cols = jax.lax.dynamic_slice_in_dim(table[lane], first, pages)
+            summaries = summarise(
+                *(pool[layer, cols].reshape(shape) for pool in pools),
+                mu[layer], phi[layer], cfg.attn_chunk)
+            return tuple(jax.lax.dynamic_update_slice(
+                pool, s.astype(pool.dtype)[None, None],
+                (layer, cols[0], 0, 0, 0))
+                for pool, s in zip(pools, summaries))
+
+        k, v = jax.lax.fori_loop(
+            0, jnp.sum(rolls) * layers, one,
+            (kv_cache["k"], kv_cache["v"]))
+        return dict(kv_cache, k=k, v=v)
+
+    def traced_summarise_scratch(self, params, scratch, offset):
+        """At the head of a chunk (or group) program, before the forward
+        pass: where the chunk's first position opens a window, the window
+        before it is summarised in the scratch, layer by layer, its
+        summaries written over its own first entries — just below where the
+        chunk's tokens go. ``prefill_chunk`` (and a group) divides the
+        window, so no chunk straddles one. A chunk that opens no window runs
+        no trip of the loop."""
+        from ..ops.summary_attention import summarise
+        cfg = self.cfg
+        w = cfg.attn_window
+        size = (1, 1, w, cfg.n_kv_heads, cfg.head_dim)
+        mu, phi = self._summary_vectors(params)
+        opens = (offset > 0) & (offset % w == 0)
+        first = cfg.kv_entry(offset) - cfg.window_entries
+
+        @jax.named_scope("kv.summarise")
+        def one(layer, caches):
+            at = (layer, 0, first, 0, 0)
+            summaries = summarise(
+                *(jax.lax.dynamic_slice(c, at, size)[0, 0] for c in caches),
+                mu[layer], phi[layer], cfg.attn_chunk)
+            return tuple(jax.lax.dynamic_update_slice(
+                c, s.astype(c.dtype)[None, None], at)
+                for c, s in zip(caches, summaries))
+
+        k, v = jax.lax.fori_loop(
+            0, jnp.where(opens, cfg.n_layers, 0), one,
+            (scratch["k"], scratch["v"]))
+        return dict(scratch, k=k, v=v)
 
     def chunk_fn(self):
         """Jitted chunked-prefill step: write one C-token chunk into the
@@ -394,7 +505,7 @@ class GraphFactory:
         dtype, so chunk prefill attends exact dequantized values. The
         traced body derives the table width from the row argument (one
         cache entry regardless of width — it never changes mid-lifetime)."""
-        s = self.ecfg.max_seq_len
+        s = self.scratch_len
         dt = self.cfg.dtype
         policy = self.policy
 
@@ -420,6 +531,15 @@ class GraphFactory:
             return jax.jit(gather)
 
         return self._build("gather", build)
+
+    def splice_shape(self, g: int) -> tuple:
+        """Shape of the physical-block operand of a splice of ``g`` chunks:
+        ``[C/BS]`` for one chunk, ``[g, C/BS]`` for a group; flat and one
+        longer (the leading page of summaries) with ``attn_window``."""
+        nb = self.chunk // self.ecfg.kv_block_size
+        if self.splice_lead:
+            return (g * nb + self.splice_lead,)
+        return (nb,) if g == 1 else (g, nb)
 
     def splice_fn(self):
         """Jitted copy of one chunk's blocks from the scratch into their
@@ -473,7 +593,6 @@ class GraphFactory:
         b = self.ecfg.max_batch
         i32 = jnp.int32
         if self.chunk:
-            bs = self.ecfg.kv_block_size
             c = self.chunk
             ascratch = policy.abstract(scratch, kv=True)
             apool = policy.abstract(pool, kv=True)
@@ -482,7 +601,7 @@ class GraphFactory:
                     0))
             yield ("splice", self.splice_fn(),
                    (apool, ascratch["k"], ascratch["v"], 0,
-                    jax.ShapeDtypeStruct((c // bs,), i32)))
+                    jax.ShapeDtypeStruct(self.splice_shape(1), i32)))
             yield ("gather", self.gather_fn(),
                    (apool, jax.ShapeDtypeStruct((mb,), i32)))
             g = self.group_chunks
@@ -490,7 +609,7 @@ class GraphFactory:
                 yield (("chunkgroup", g), self.chunk_group_fn(g),
                        (pspec, apool, ascratch,
                         jax.ShapeDtypeStruct((g, c), i32), 0, 0,
-                        jax.ShapeDtypeStruct((g, c // bs), i32)))
+                        jax.ShapeDtypeStruct(self.splice_shape(g), i32)))
         else:
             cfg = self.cfg
             for bucket in buckets:
@@ -583,7 +702,8 @@ class GraphFactory:
             text = self.compiled[key].as_text()
             self.kernel_calls[name] = text.count("tpu_custom_call")
             # a plain program runs nothing under the loop's scopes
-            scopes = hlo_scopes(text, DEVICE_SCOPES + LOOP_SCOPES)
+            scopes = hlo_scopes(
+                text, DEVICE_SCOPES + LOOP_SCOPES + SUMMARY_SCOPES)
             if scopes:
                 self.device_scopes[name] = scopes
             else:
@@ -612,8 +732,10 @@ def abstract_state(cfg, ecfg, policy, kv_quant: bool = False) -> dict:
         mgr = KvPool(cfg, ecfg, kv_quant, policy)
         kv_cache = mgr.array_specs()
         pool = {k: v for k, v in kv_cache.items() if k != "table"}
-        scratch = jax.eval_shape(
-            lambda: init_kv_cache(cfg, 1, ecfg.max_seq_len))
+        from .paged_kv import scratch_len
+        chunk = ecfg.prefill_chunk or min(ecfg.prefill_buckets)
+        scratch = jax.eval_shape(lambda: init_kv_cache(
+            cfg, 1, scratch_len(cfg, ecfg.max_seq_len, chunk)))
         return {"kv_cache": kv_cache, "pool": pool, "scratch": scratch,
                 "mb": mgr.mb, "rng": rng}
     kv_cache = jax.eval_shape(

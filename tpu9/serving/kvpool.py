@@ -143,7 +143,8 @@ class KvPool:
         if ecfg.kv_pool_blocks:
             base_blocks = ecfg.kv_pool_blocks
         else:
-            base_blocks = b * s // bs            # dense parity
+            # dense parity, in cache entries (positions, for plain attention)
+            base_blocks = b * cfg.kv_entries_peak(s) // bs
             if kv_quant:
                 # equal-HBM sizing: the int8 pool spends the same bytes
                 # the bf16 pool would have — ~2x the blocks, which is the
@@ -161,8 +162,9 @@ class KvPool:
         # regression must not corrupt data) computes pos // bs == S/bs
         # which would otherwise CLAMP onto the last real block and
         # overwrite valid KV; the extra column absorbs it harmlessly
-        # (attention masks by cache_len, so it is never read)
-        self.mb = s // bs + 1                    # table width
+        # (attention masks by cache_len, so it is never read). A column is
+        # a block of cache ENTRIES: ``kv_entry(pos) // bs`` is a token's
+        self.mb = cfg.kv_entries_peak(s) // bs + 1   # table width
         self.allocator = BlockAllocator(self.n_blocks, bs)
         self.trash_block = self.allocator.alloc(1)[0]
         # inactive decode lanes scatter through their (zero-padded) table
@@ -495,10 +497,12 @@ class KvPool:
         return self.device_table()
 
     def ensure_slot_blocks(self, slot: int, n_tokens: int) -> bool:
-        """Grow the slot's physical block list to cover ``n_tokens``
-        positions. Returns True when the table changed (the caller must
-        install :meth:`device_table` / the value from :meth:`push_table`)."""
-        need = blocks_for(n_tokens, self.ecfg.kv_block_size)
+        """Grow the slot's physical block list to cover the cache entries a
+        sequence addresses on its way to ``n_tokens`` positions. Returns True
+        when the table changed (the caller must install :meth:`device_table`
+        / the value from :meth:`push_table`)."""
+        need = blocks_for(self.cfg.kv_entries_peak(n_tokens),
+                          self.ecfg.kv_block_size)
         have = len(self.slot_blocks[slot])
         if need <= have:
             return False

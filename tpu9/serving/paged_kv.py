@@ -32,6 +32,17 @@ def blocks_for(n_tokens: int, block_s: int) -> int:
     return max(0, -(-n_tokens // block_s))
 
 
+def scratch_len(cfg, max_seq_len: int, chunk: int) -> int:
+    """Rows of the batch-1 scratch that chunked prefill writes through: one a
+    cache ENTRY a sequence of ``max_seq_len`` tokens can address
+    (``DecoderConfig.kv_entries_peak``), in whole chunks. For plain
+    attention an entry is a position and this is ``max_seq_len``."""
+    n = cfg.kv_entries_peak(max_seq_len)
+    if n == max_seq_len or not chunk:
+        return n
+    return -(-n // chunk) * chunk
+
+
 def kv_block_bytes(cfg, block_s: int, quantized: bool = False) -> int:
     """HBM bytes ONE k+v pool block holds across the whole depth of the KV
     state of ``cfg`` (``kv_layers``: every layer of every pass).
