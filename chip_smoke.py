@@ -757,7 +757,7 @@ def serve_phase(run: Run, phase: str, name: str, engine_args: dict,
         check(line["device"] == dict(run.device, count=want_count),
               f"runner's engine is on {line['device']}, the probe saw "
               f"{run.device} and the engine should span {want_count}")
-        check(line["attention_decode"] == "pallas",
+        check(line["attention_decode"].startswith("pallas"),
               f"decode attention is {line['attention_decode']!r}")
         layers = engine_args["layers"] or run.model["n_layers"]
         for graph, calls in line["graph_kernels"].items():

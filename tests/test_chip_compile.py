@@ -19,6 +19,8 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+# the benchmark's cells as the kernel sees them: one table, the script's
+from scripts.paged_kernel_bench import CELL_SHAPES
 import tpu9.ops.attention as attention_ops
 from tpu9.ops.attention import flash_attention, paged_attention_dispatch
 from tpu9.ops.paged_attention import (paged_decode_attention,
@@ -170,22 +172,16 @@ def _kernel_names(text: str) -> list:
             if " custom-call(" in ln and "tpu_custom_call" in ln]
 
 
-# the benchmark's cells as the kernel sees them (ISSUE 40): slots, KV heads,
-# query heads a KV head, table columns, pool blocks, planes of the pool, chips
-CELL_SHAPES = {"mistral-tp4-long": (8, 8, 4, 129, 897, 32, 4),
-               "ouro-qa": (16, 16, 1, 9, 31, 192, 1),
-               "mixtral": (32, 8, 4, 33, 513, 4, 1)}
-
-
 @pytest.mark.parametrize("cell", list(CELL_SHAPES))
 def test_the_page_walk_compiles_at_a_cells_shapes(v5e, no_compile_cache,
                                                   monkeypatch, cell):
     """The dispatcher's call at a cell's widths, the whole stacked pool and
     a layer that is an operand: two KV heads a chip under ``shard_map`` with
     a 129-column table, sixteen heads without grouping and 9 columns, eight
-    heads and 33. The kernel copies its pages itself (nothing of the pool
-    is a temporary) and the compiler prints it under the step marker's
-    name."""
+    heads and 33, thirty-two without grouping and 24 (ISSUE 47: four blocks
+    of eight heads an update). The kernel copies its pages itself (nothing
+    of the pool is a temporary) and the compiler prints it under the step
+    marker's name."""
     from benchmark.families import decoder
     monkeypatch.setattr(attention_ops, "on_tpu", lambda: True)
     b, kh, group, columns, blocks, planes, chips = CELL_SHAPES[cell]
@@ -210,7 +206,8 @@ def test_the_page_walk_compiles_at_a_cells_shapes(v5e, no_compile_cache,
 
 
 @pytest.mark.parametrize("configuration", [
-    "mixtral-8x7b-l4", "mistral-7b-v0.3-tp4", "ouro-2.6b"])
+    "mixtral-8x7b-l4", "mistral-7b-v0.3-tp4", "ouro-2.6b",
+    "evabyte-6.5b-l16"])
 def test_a_decode_programs_only_kernels_are_the_step_markers(
         v5e, no_compile_cache, monkeypatch, configuration):
     """What the benchmark counts decode steps by (``STEP_MARKER``,

@@ -366,3 +366,22 @@ def test_an_engine_whose_chunk_is_no_multiple_of_128_says_so(tiny,
         kv_pool_blocks=16, prefix_cache_blocks=0))
     assert engine.stats()["attention_prefill"] == \
         "xla: chunk (32, 256) is not a multiple of the 128 block"
+    assert engine.stats()["attention_decode"] == \
+        "xla: kv block 32 is not a multiple of 128"
+
+
+def test_an_engine_names_the_paged_decode_kernels_body(tiny, monkeypatch):
+    """``stats()["attention_decode"]`` on the chip: which body of the paged
+    kernel this engine's pool takes (a float32 pool of heads under 128 wide
+    keeps the grid, a head an update; the walk's blocks of heads are named
+    in ``test_paged_walk``)."""
+    cfg, params = tiny
+    monkeypatch.setattr(attention_ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(attention_ops, "_KERNEL_HEAD_DIMS", (cfg.head_dim,))
+    engine = InferenceEngine(params, cfg, _ecfg(
+        kv_block_size=128, kv_pool_blocks=24, prefix_cache_blocks=0))
+    said = engine.stats()["attention_decode"]
+    assert said == "pallas grid, 1 head an update, 1 page a step"
+    assert said == attention_ops.paged_kernel_form(
+        engine.kv_cache["k"], cfg.n_heads, engine._mb)
+    assert engine.stats()["attention_prefill"] == "pallas"

@@ -1,9 +1,11 @@
 """The page walk inside the paged decode kernel (ISSUE 40): the kernel copies
 ``ceil(len / BS)`` pages a sequence out of the pool itself, a wave of several
-at a time, and never touches what lies past them. Interpreted on the CPU at
-the three benchmark cells' per-chip shapes, against the XLA oracle."""
+at a time, and never touches what lies past them; its arithmetic goes a block
+of KV heads an update (ISSUE 47). Interpreted on the CPU at the benchmark
+cells' per-chip shapes, against the XLA oracle."""
 
 import functools
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -12,12 +14,14 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import tpu9.ops.attention as attention_ops
+from tpu9.ops.attention import paged_kernel_form
 from tpu9.ops import paged_attention as pa
 from tpu9.ops.quant import quantize_kv
 
 BS, D, LAYERS, LAYER = 128, 128, 2, 1
 # cell: KV heads a chip, query heads a KV head, table columns
-SHAPES = {"mixtral": (8, 4, 33), "tp4-long": (2, 4, 129), "ouro": (16, 1, 9)}
+SHAPES = {"mixtral": (8, 4, 33), "tp4-long": (2, 4, 129), "ouro": (16, 1, 9),
+          "evabyte": (32, 1, 7)}
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
 
@@ -50,13 +54,12 @@ def _case(kh, group, columns, lens, dtype, seed=0, shared=0):
         row = (prefix + [order.pop() for _ in range(max(p - shared, 0))])[:p]
         table[b, :p] = row
         owned.update(row)
-    shape = (LAYERS, n_blocks, BS, kh, D)
-    k = rng.standard_normal(shape).astype(np.float32)
-    v = rng.standard_normal(shape).astype(np.float32)
-    unowned = [i for i in range(n_blocks) if i not in owned]
+    k, v = (np.full((LAYERS, n_blocks, BS, kh, D), np.nan, np.float32)
+            for _ in range(2))
+    own = sorted(owned)
     for pool in (k, v):
-        pool[LAYER, unowned] = np.nan
-        pool[1 - LAYER] = np.nan
+        pool[LAYER, own] = rng.standard_normal((len(own), BS, kh, D),
+                                               np.float32)
     q = rng.standard_normal((len(lens), 1, kh * group, D)).astype(np.float32)
     return (jnp.asarray(q, dtype), jnp.asarray(k, dtype),
             jnp.asarray(v, dtype), jnp.asarray(table),
@@ -145,13 +148,76 @@ def test_int8_pool_through_the_same_cases(cell):
 
 
 @pytest.mark.parametrize("cell,pages", [("tp4-long", 32), ("mixtral", 8),
-                                        ("ouro", 4)])
+                                        ("ouro", 4), ("evabyte", 2)])
 def test_pages_a_wave_follow_from_the_page_bytes(cell, pages):
     kh, _, columns = SHAPES[cell]
     assert _wave(kh, columns, jnp.bfloat16) == pages
     # a power of two, never wider than the table, never none
     assert pa._pages_per_wave(BS * kh * D * 2, 3) == 2
     assert pa._pages_per_wave(64 << 20, columns) == 1
+
+
+@pytest.mark.parametrize("kh,group,heads", [
+    (32, 1, 8), (16, 1, 8), (8, 4, 2), (2, 4, 2),     # the cells
+    (8, 1, 8), (4, 1, 4), (2, 1, 2), (1, 1, 1),       # fewer heads than 8
+    (8, 2, 4), (8, 8, 2), (4, 16, 2),                 # a tile or more a pair
+    (12, 1, 12), (3, 2, 3), (8, 3, 8), (4, 7, 4),     # what does not divide
+])
+def test_heads_an_update_follow_from_the_heads_and_their_query_rows(
+        kh, group, heads):
+    """As many KV heads as fill eight rows, at least a pair; all of them
+    where that does not divide the heads or is no whole tile of rows (a
+    float32 pool of any head count; in bfloat16 a group that is no power of
+    two: 28 query heads on 4 KV heads, Qwen2-7B's)."""
+    assert pa._heads_per_update(kh, group) == heads
+    assert kh % heads == 0
+    assert heads == kh or (heads * group) % 8 == 0
+
+
+@pytest.mark.parametrize("kh,group,dtype", [
+    (16, 1, jnp.float32), (16, 1, jnp.bfloat16), (8, 4, jnp.float32),
+    (8, 4, jnp.bfloat16), (8, 2, jnp.bfloat16), (12, 1, jnp.float32),
+    (4, 7, jnp.bfloat16),
+], ids=lambda x: x if isinstance(x, int) else jnp.dtype(x).name)
+def test_a_block_of_heads_equals_a_head_at_a_time(kh, group, dtype):
+    """The walk's update of a block of heads against the grid's, which is
+    ``_head_update`` a head, on the same pool: two blocks of eight and four
+    pairs in a loop, two blocks of four, and heads the block does not divide
+    into whole tiles (twelve at a row a head, four at seven: one block of
+    all)."""
+    assert pa._pages_can_be_cut(
+        jax.ShapeDtypeStruct((LAYERS, 1, BS, kh, D), dtype))
+    columns = 5
+    lens = _lengths(_wave(kh, columns, dtype), columns)
+    q, k, v, table, lens = _case(kh, group, columns, lens, dtype, seed=6)
+    safe = jnp.where(table < k.shape[1], table, 0)
+    k0, v0 = (jnp.nan_to_num(x.astype(jnp.float32)).astype(dtype)
+              for x in (k, v))
+    got = pa._page_walk(q, k, v, table, lens, LAYER, True)
+    want = pa._page_grid(q, (k0, v0), safe, lens, LAYER, True)
+    _close(got, want, dtype, lens)
+
+
+@pytest.mark.parametrize("cell,said", [
+    ("evabyte", "pallas walk, 8 heads x 1 row an update, 2 pages a wave"),
+    ("ouro", "pallas walk, 8 heads x 1 row an update, 4 pages a wave"),
+    ("mixtral", "pallas walk, 2 heads x 4 rows an update, 8 pages a wave"),
+    ("tp4-long", "pallas walk, 2 heads x 4 rows an update, 32 pages a wave"),
+])
+def test_the_form_that_runs_is_named_for_the_health_page(cell, said):
+    kh, group, columns = SHAPES[cell]
+    pool = jax.ShapeDtypeStruct((LAYERS, 9, BS, kh, D), jnp.bfloat16)
+    assert pa.paged_decode_form(pool, kh * group, columns) == said
+    for other in (jax.ShapeDtypeStruct(pool.shape, jnp.int8),
+                  jax.ShapeDtypeStruct((LAYERS, 9, BS, kh, 64),
+                                       jnp.bfloat16)):
+        assert pa.paged_decode_form(other, kh * group, columns) == \
+            "pallas grid, 1 head an update, 1 page a step"
+    # an engine's whole pool on a mesh: the words are about a chip's heads
+    chips = SimpleNamespace(shape={"tp": 4})
+    whole = jax.ShapeDtypeStruct((LAYERS, 9, BS, 4 * kh, D), jnp.bfloat16)
+    assert paged_kernel_form(whole, 4 * kh * group, columns, chips) == said
+    assert paged_kernel_form(pool, kh * group, columns) == said
 
 
 @pytest.mark.parametrize("kh,d,dtype,cut", [

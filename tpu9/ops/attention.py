@@ -381,6 +381,21 @@ def paged_kernel_declined(block_s: int, head_dim: int) -> str:
     return ""
 
 
+def paged_kernel_form(k_pool, q_heads: int, table_width: int,
+                      mesh=None) -> str:
+    """Which body of the paged kernel :func:`paged_attention_dispatch` runs
+    over ``k_pool`` (anything with the whole pool's ``shape`` and ``dtype``),
+    in words: what ``engine.stats()["attention_decode"]`` says on
+    ``/health``. On a mesh the kernel sees one chip's heads
+    (:func:`_per_chip_heads`), so the words are about that share."""
+    from .paged_attention import paged_decode_form
+    tp = 1 if mesh is None else mesh.shape[HEAD_AXIS]
+    *lead, kv_heads, head_dim = k_pool.shape
+    return paged_decode_form(
+        jax.ShapeDtypeStruct((*lead, kv_heads // tp, head_dim), k_pool.dtype),
+        q_heads // tp, table_width)
+
+
 def paged_attention_dispatch(q: jnp.ndarray, k_pool: jnp.ndarray,
                              v_pool: jnp.ndarray, block_table: jnp.ndarray,
                              cache_len: jnp.ndarray,

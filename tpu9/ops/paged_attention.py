@@ -18,9 +18,14 @@ the cache is ever materialized.
 The block-table PAGED kernel (what serving runs) does not spend a grid step
 on a table column: a grid step is a sequence, and the kernel walks that
 sequence's ``ceil(len / BS)`` pages itself, copying them out of the pool a
-wave at a time (:func:`_walk_kernel`). Pools whose pages Mosaic cannot cut
-out of HBM (int8 with its scale planes, heads under 128 wide) keep the
-grid-over-columns form (:func:`_page_grid`).
+wave at a time (:func:`_walk_kernel`). The unit of its arithmetic is a BLOCK
+of KV heads (:func:`_heads_per_update`): the query rows of as many heads as
+fill a float32 tile's eight sublanes are one tile of scores, so the online
+softmax runs once a block on full vector registers — at one query row a KV
+head (plain multi-head attention) eight heads an update, at four the pair
+one 32-bit word of the pool holds (PERF.md §6, PR 47). Pools whose pages
+Mosaic cannot cut out of HBM (int8 with its scale planes, heads under 128
+wide) keep the grid-over-columns form (:func:`_page_grid`), a head an update.
 """
 
 from __future__ import annotations
@@ -189,11 +194,15 @@ def ragged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
 # K bytes a wave copies: the page walk fetches this much of a sequence's keys
 # (and as much of its values) at once, every page's copy in flight together,
 # while it works on the wave before. A page is 64 KB (two KV heads a chip) to
-# 512 KB (sixteen) on the served shapes, so a wave is 32 / 8 / 4 pages: a
-# static shape, no knob. Swept on the chip from 256 KB to 4 MB (PERF.md §6,
-# PR 40): smaller waves pay a copy's latency and an update's fixed cost more
-# often (256 KB: + 15…40 % a call), larger ones gain nothing on two shapes of
-# three and double the 8 MB of buffers.
+# 1 MB (thirty-two) on the served shapes, so a wave is 32 / 8 / 4 / 2 pages:
+# a static shape, no knob. Swept on the chip from 256 KB to 4 MB at 2, 8 and
+# 16 heads (PERF.md §6, PR 40): smaller waves pay a copy's latency and an
+# update's fixed cost more often (256 KB: + 15…40 % a call), larger ones gain
+# nothing on two shapes of three and double the 8 MB of buffers. Swept again
+# with the heads in blocks (PR 47, call 7, this body;
+# ``scripts/paged_kernel_bench.py --wave-bytes``): at 32 heads 2 / 4 / 8 pages
+# a wave cost a call 155.3 / 159.1 / 168.0 µs, at 16 heads 2 / 4 / 8 pages
+# 32.0 / 31.7 / 33.9 µs — 2 MB stays.
 WAVE_BYTES = 2 << 20
 # and at most this many pages: a wave's copies are started one by one
 MAX_WAVE_PAGES = 32
@@ -207,15 +216,35 @@ def _pages_per_wave(page_bytes: int, table_width: int) -> int:
     return 1 << (pages.bit_length() - 1)
 
 
+def _heads_per_update(kv_heads: int, group: int) -> int:
+    """KV heads one online-softmax update covers: as many as fill a float32
+    tile's eight rows with their ``group`` query rows each, and at least the
+    pair one 32-bit word of a bfloat16 pool holds — 8 at one query row a KV
+    head, 2 at four. Where that does not divide the heads, or its rows are
+    not whole tiles, all heads are one block (its rows then start at 0, and
+    nothing is sliced at an offset that is not a tile's): a bfloat16 pool of
+    two or four heads at one row a head, or with a group that is no power of
+    two (28 query heads on 4 KV heads), and a float32 pool of any head count
+    — each of which Mosaic lowers for a described v5e."""
+    heads = max(2, 8 // group)
+    if kv_heads % heads or (heads * group) % 8:
+        heads = kv_heads
+    return heads
+
+
 def _walk_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
-                 k_buf, v_buf, sem, slot_ref, m_scr, l_scr, acc_scr, *,
-                 scale: float, block_s: int, wave: int, kv_heads: int):
+                 k_buf, v_buf, sem, slot_ref, q_scr, m_scr, l_scr, acc_scr,
+                 *, scale: float, block_s: int, wave: int, kv_heads: int,
+                 heads: int):
     """One sequence a grid step; the walk over its pages is in here.
 
     ``k_hbm`` / ``v_hbm`` are the pools whole, in HBM; ``k_buf`` / ``v_buf``
     ``[2, wave * BS, KH, D]`` hold two waves of pages each; ``sem`` ``[2, 2]``
     (slot, k or v); ``slot_ref`` (SMEM; scratch lives across grid steps) is
-    the slot of this step's first wave.
+    the slot of this step's first wave. The query ``[QH, D]``, scaled, and
+    the online softmax's running maximum, sum and accumulator are laid one
+    row a QUERY head (``q_scr``, ``m_scr``, ``l_scr``, ``acc_scr``): the rows
+    of a block of ``heads`` KV heads are one slice of whole tiles.
 
     A sequence owns ``ceil(len / BS)`` pages, taken ``wave`` at a time: one
     copy a page from ``pool[layer, table[b, page]]``, a wave's copies all in
@@ -270,44 +299,108 @@ def _walk_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
         start(0, 0, 0)
 
     first_slot = slot_ref[0]
+    q_scr[...] = q_ref[0].astype(jnp.float32) * scale
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
+    group = q_scr.shape[0] // kv_heads
+    block_rows = heads * group
 
-    def update(h, k, v, first_pos):
-        q = q_ref[0, h].astype(jnp.float32) * scale
-        _head_update(h, q, k, v, first_pos, seq_len, m_scr, l_scr, acc_scr)
+    def block_update(i, slot, first, rows, first_pos):
+        """The online-softmax update of KV heads ``i * heads`` … over
+        ``rows`` cache rows: ONE tile of scores ``[heads * group, rows]``.
+
+        A head's two products stay what they were, its ``[rows, D]`` keys
+        and values against the query rows — but against the whole block's
+        rows (a tile's eight sublanes cost the matrix unit what one does),
+        of which the head's own are kept: the same numbers as a product
+        with its rows alone, bit for bit on the chip (the output's digest
+        at the four served shapes and around every page's and wave's edge,
+        ``scripts/paged_kernel_bench.py``). What changes is that ``max``,
+        ``exp``, ``where``, ``sum`` and the rescale run once a block on full
+        vector registers and the scratch is read and written once a block:
+        at one query row a KV head they ran on one sublane of eight, once a
+        head (PERF.md §6, PR 47: 296.7 → 154.7 µs a call at 32 heads). The
+        heads of a block are straight code so that one head's products hide
+        the latency of the next one's (as a loop: 201.2 µs).
+
+        Both products are float32 ``dot_general``s at the default precision,
+        as they always were: on the chip Mosaic feeds the matrix unit the
+        operands' bfloat16 roundings and accumulates in float32 (measured,
+        same place: 4e-4 … 1.4e-3 against float64, where q, K, V and a
+        three-term p as exact bfloat16 read 4e-7 at the same speed — a more
+        exact result, but another one)."""
+        at = pl.ds(i * block_rows if isinstance(i, int)
+                   else pl.multiple_of(i * block_rows, block_rows),
+                   block_rows)
+        q = q_scr[at, :]
+
+        def head_rows(buf):
+            """``[rows, D]`` float32 of each head of the block, in order. A
+            page lies in VMEM as it does in the pool, ``[BS, KH, D]``: a
+            head's rows are ``KH`` apart, and cutting them out one head at a
+            time re-reads the whole page for every head. A bfloat16 pool is
+            read as 32-bit words instead: one word holds the same lane of
+            two neighbouring heads, so ONE strided load brings a pair of
+            heads' rows, and each half widens to float32 by a shift or a
+            mask, which is exact."""
+            if buf.dtype != jnp.bfloat16:
+                for h in range(heads):
+                    yield buf[slot, pl.ds(first, rows), i * heads + h,
+                              :].astype(jnp.float32)
+                return
+            for j in range(heads // 2):
+                words = head_pair_words(buf, slot * wave * block_s + first,
+                                        rows, i * (heads // 2) + j)
+                for odd in (0, 1):
+                    yield widen_half(words, odd)
+
+        def own_rows(per_head):
+            """One ``[heads * group, n]`` array a head, in order; rows
+            ``h * group`` … of the ``h``-th are head ``h``'s."""
+            per_head = iter(per_head)
+            out = next(per_head)
+            row = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
+            for h, x in enumerate(per_head, 1):
+                out = jnp.where(row >= h * group, x, out)
+            return out
+
+        s = own_rows(jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) for k in head_rows(k_buf))
+        pos = first_pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < seq_len, s, NEG_INF)
+
+        m_prev = m_scr[at, :]
+        l_prev = l_scr[at, :]
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[:, :1])
+        p = jnp.where(pos < seq_len, p, 0.0)
+        l_scr[at, :] = alpha * l_prev + jnp.broadcast_to(
+            jnp.sum(p, axis=-1, keepdims=True), l_prev.shape)
+        acc_scr[at, :] = acc_scr[at, :] * alpha[:, :1] + own_rows(
+            jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            for v in head_rows(v_buf))
+        m_scr[at, :] = m_new
 
     def run_of_pages(slot, first, rows, first_pos):
-        """Every KV head's update over ``rows`` rows from row ``first`` of
-        ``slot``, which start at position ``first_pos``.
-
-        A page lies in VMEM as it does in the pool, ``[BS, KH, D]``: a
-        head's rows are ``KH`` apart, and cutting them out one head at a
-        time re-reads the whole page for every head. A bfloat16 pool is read
-        as 32-bit words instead: one word holds the same lane of two
-        neighbouring heads, so ONE strided load brings a pair of heads'
-        rows, and each half widens to float32 by a shift or a mask, which is
-        exact. The pairs are a loop, one pair a trip, and not unrolled code:
-        every bring-up traces and lowers this body, and sixteen heads times
-        the run lengths cost it seconds (PERF.md §6, PR 40)."""
-        if k_buf.dtype != jnp.bfloat16:
-            for h in range(kv_heads):
-                update(h, *(buf[slot, pl.ds(first, rows), h, :].astype(
-                    jnp.float32) for buf in (k_buf, v_buf)), first_pos)
-            return
-
-        def one_pair(j, _):
-            kw, vw = (head_pair_words(buf, slot * wave * block_s + first,
-                                      rows, j) for buf in (k_buf, v_buf))
-            for odd in (0, 1):
-                update(2 * j + odd, widen_half(kw, odd), widen_half(vw, odd),
-                       first_pos)
-
-        if kv_heads == 2:
-            one_pair(0, None)
+        """Every block's update over ``rows`` rows from row ``first`` of
+        ``slot``, which start at position ``first_pos``. The blocks of a
+        bfloat16 pool are a loop, one block a trip, and not unrolled code:
+        every bring-up traces and lowers this body, and every head of every
+        run length cost it seconds (PERF.md §6, PR 40)."""
+        blocks = kv_heads // heads
+        if blocks == 1 or k_buf.dtype != jnp.bfloat16:
+            for i in range(blocks):
+                block_update(i, slot, first, rows, first_pos)
         else:
-            jax.lax.fori_loop(0, kv_heads // 2, one_pair, None)
+            jax.lax.fori_loop(
+                0, blocks, lambda i, _: block_update(
+                    i, slot, first, rows, first_pos), None)
 
     def one_wave(w, _):
         slot = jax.lax.rem(first_slot + w, 2)
@@ -339,7 +432,8 @@ def _walk_kernel(table_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
         start(b + 1, 0, first_slot)
 
     slot_ref[0] = jax.lax.rem(first_slot + n_waves, 2)
-    _finalize_heads(o_ref, m_scr, l_scr, acc_scr, kv_heads)
+    o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(
+        o_ref.dtype)
 
 
 def _as_pool(layer, k_pool: jnp.ndarray, *rest: jnp.ndarray):
@@ -371,21 +465,24 @@ def _page_walk(q, k_pool, v_pool, block_table, cache_len, layer, interpret):
         block_s * kv_heads * head_dim * k_pool.dtype.itemsize,
         block_table.shape[1])
 
-    qt = q.reshape(batch, kv_heads, group, head_dim)
+    # a row a query head: head ``h``'s rows are ``h * group`` … (a free
+    # reshape of the contiguous ``[B, 1, QH, D]``)
+    qt = q.reshape(batch, q_heads, head_dim)
 
     def q_index(b, table, lens, layer):
-        return (b, 0, 0, 0)
+        return (b, 0, 0)
 
     out = pl.pallas_call(
         functools.partial(_walk_kernel, scale=head_dim ** -0.5,
-                          block_s=block_s, wave=wave, kv_heads=kv_heads),
+                          block_s=block_s, wave=wave, kv_heads=kv_heads,
+                          heads=_heads_per_update(kv_heads, group)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(batch,),
-            in_specs=[pl.BlockSpec((1, kv_heads, group, head_dim), q_index),
+            in_specs=[pl.BlockSpec((1, q_heads, head_dim), q_index),
                       pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, kv_heads, group, head_dim), q_index),
+            out_specs=pl.BlockSpec((1, q_heads, head_dim), q_index),
             scratch_shapes=[
                 pltpu.VMEM((2, wave * block_s, kv_heads, head_dim),
                            k_pool.dtype),
@@ -393,9 +490,10 @@ def _page_walk(q, k_pool, v_pool, block_table, cache_len, layer, interpret):
                            v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((1,), jnp.int32),
-                pltpu.VMEM((kv_heads, group, 128), jnp.float32),
-                pltpu.VMEM((kv_heads, group, 128), jnp.float32),
-                pltpu.VMEM((kv_heads, group, head_dim), jnp.float32),
+                pltpu.VMEM((q_heads, head_dim), jnp.float32),
+                pltpu.VMEM((q_heads, 128), jnp.float32),
+                pltpu.VMEM((q_heads, 128), jnp.float32),
+                pltpu.VMEM((q_heads, head_dim), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
@@ -481,10 +579,29 @@ def _pages_can_be_cut(pool: jnp.ndarray) -> bool:
     Mosaic slices an HBM operand only along dimensions its tiling does not
     pad (asked of the compiler for a described v5e, PR 40): a head of whole
     128-lane rows and, below 32 bits, as many KV heads as a tile has rows
-    (2, 4, or a multiple of 8). The BlockSpec pipeline has no such limit."""
+    (2, 4, or a multiple of 8); an int8 pool never, for its scale planes
+    ``[BS, KH]`` are padded to 128 lanes. The BlockSpec pipeline has no such
+    limit. The one predicate of walk or grid: :func:`paged_decode_attention`
+    decides by it and :func:`paged_decode_form` says what it decided."""
     *_, kv_heads, head_dim = pool.shape
-    return head_dim % 128 == 0 and (
+    return pool.dtype != jnp.int8 and head_dim % 128 == 0 and (
         pool.dtype.itemsize == 4 or kv_heads in (2, 4) or kv_heads % 8 == 0)
+
+
+def paged_decode_form(pool, q_heads: int, table_width: int) -> str:
+    """Which body :func:`paged_decode_attention` (or its int8 twin) runs over
+    ONE chip's ``pool`` (anything with the pool's ``shape`` and ``dtype``),
+    in words (``ops.attention.paged_kernel_form`` takes a chip's share of an
+    engine's pool and hands it here). Static, like the shapes it follows
+    from."""
+    *_, block_s, kv_heads, head_dim = pool.shape
+    if not _pages_can_be_cut(pool):
+        return "pallas grid, 1 head an update, 1 page a step"
+    group = q_heads // kv_heads
+    wave = _pages_per_wave(
+        block_s * kv_heads * head_dim * pool.dtype.itemsize, table_width)
+    return (f"pallas walk, {_heads_per_update(kv_heads, group)} heads x "
+            f"{group} row{'s' * (group > 1)} an update, {wave} pages a wave")
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -594,14 +594,18 @@ class InferenceEngine:
         this engine's shapes, from the same predicates the dispatchers in
         ``ops.attention`` decide with when a program is traced (a paged
         engine's prefill: the chunk kernel at its chunk's and its admission
-        group's widths). A TPU replica whose attention declined a kernel
-        still serves correctly through the oracle, so say why once here,
-        where an operator reads the bring-up log."""
+        group's widths; its decode names the paged kernel's body too, heads
+        an update and pages a wave). A TPU replica whose attention declined
+        a kernel still serves correctly through the oracle, so say why once
+        here, where an operator reads the bring-up log."""
         from ..ops import attention as ops
         hd = self.cfg.head_dim
         s_max = self.ecfg.max_seq_len
+        ran = "pallas"
         if self.paged:
             decode = ops.paged_kernel_declined(self.ecfg.kv_block_size, hd)
+            ran = ops.paged_kernel_form(self.kv_cache["k"], self.cfg.n_heads,
+                                        self._mb, self.policy.mesh)
             # a chunk and an admission group are the two widths admitted
             chunk = self.graphs.chunk
             s_max = self.graphs.scratch_len
@@ -616,7 +620,7 @@ class InferenceEngine:
             logging.getLogger("tpu9.serving").warning(
                 "decode attention runs the XLA oracle, not the pallas "
                 "kernel: %s", decode)
-        return {"decode": f"xla: {decode}" if decode else "pallas",
+        return {"decode": f"xla: {decode}" if decode else ran,
                 "prefill": f"xla: {prefill}" if prefill else "pallas"}
 
     # -- compiled steps (serving.graphs) + scheduling (serving.schedule) ----
