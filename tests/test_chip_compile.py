@@ -241,8 +241,12 @@ def _decode_programs(v5e, monkeypatch, configuration, n_layers=None,
     from tpu9.serving.shard.plan import parse_topology
     from tpu9.serving.shard.policy import MeshPolicy
     import tpu9.ops.grouped_ffn as grouped_ops
-    monkeypatch.setattr(attention_ops, "on_tpu", lambda: True)
-    monkeypatch.setattr(grouped_ops, "on_tpu", lambda: True)
+    import tpu9.ops.held_ffn as held_ops
+    import tpu9.utils
+    # every dispatcher: the three that hold the name, and the function the
+    # KDA step and the latent attention look up at the call
+    for module in (attention_ops, grouped_ops, held_ops, tpu9.utils):
+        monkeypatch.setattr(module, "on_tpu", lambda: True)
     config = manifest.load_config(manifest.load(), configuration)
     family = manifest.family(config)
     cfg = family.program_config(family.model_sizes(config))
@@ -462,15 +466,22 @@ def test_a_layer_patterns_kernels_compile_at_the_published_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 200 * 2 ** 20
 
 
+@pytest.mark.parametrize("form", ["kernel", "xla"])
 def test_the_held_expert_layer_reads_its_stacks_as_they_are_stored(
-        v5e, no_compile_cache):
+        v5e, no_compile_cache, monkeypatch, form):
     """A decode step's expert layer of a chip's share at Ling-3.0-flash's
-    widths (128 lanes, 128 held experts of 2560 x 768): every held expert
-    over every token as a BATCHED product. Without the batch dimension the
-    compiler took one product over all experts' columns and transposed both
-    [128, 2560, 768] stacks a call — 1 GB of copies a layer, hoisted out of
-    a K = 8 window as 5 GB of temporaries (found here, ISSUE 48)."""
+    widths (128 lanes, 128 held experts of 2560 x 768, 512 routed, top-8).
+    As the chip runs it (ISSUE 49): ONE ``held_ffn`` kernel whose step holds
+    an expert's three matrices whole, twice — 23.6 MB of VMEM, more than the
+    default scope, which only the TPU's compiler can refuse. The XLA form:
+    every held expert over every token as a BATCHED product — without the
+    batch dimension the compiler took one product over all experts' columns
+    and transposed both [128, 2560, 768] stacks a call, 1 GB of copies a
+    layer, hoisted out of a K = 8 window as 5 GB of temporaries (found here,
+    ISSUE 48). Neither form copies a stack."""
+    import tpu9.ops.held_ffn as held_ops
     from tpu9.models.moe import MoeConfig, moe_ffn_held
+    monkeypatch.setattr(held_ops, "on_tpu", lambda: form == "kernel")
     e, d, h, n = 128, 2560, 768, 128
     one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
 
@@ -485,11 +496,28 @@ def test_the_held_expert_layer_reads_its_stacks_as_they_are_stored(
                     shared_dim=h, score="sigmoid", select_bias=True,
                     n_groups=8, top_groups=4, gate_scale=2.5,
                     dtype=jnp.bfloat16)
-    compiled = jax.jit(lambda p, x: moe_ffn_held(p, x, cfg)).lower(
-        params, s((n, 1, d))).compile()
+    compiled = jax.jit(lambda p, x, live: moe_ffn_held(p, x, cfg, live)).lower(
+        params, s((n, 1, d)), s((n, 1), jnp.bool_)).compile()
     text = compiled.as_text()
+    assert _kernel_names(text) == (["held_ffn"] if form == "kernel" else [])
     assert not re.search(r"bf16\[128,(2560,768|768,2560)\]\S* copy\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
+def test_a_layer_patterns_decode_window_holds_one_expert_kernel_a_layer(
+        v5e, no_compile_cache, monkeypatch):
+    """The cell's K = 8 decode program (``ling-3.0-flash-l6-ep4`` at its
+    engine's shapes, ISSUE 49): five ``held_ffn`` calls, one an expert
+    layer, one ``kda_state_step`` a KDA layer and ONE
+    ``paged_latent_attention`` — the call the benchmark counts its steps
+    by."""
+    _, family, _, _, jobs = _decode_programs(v5e, monkeypatch,
+                                             "ling-3.0-flash-l6-ep4")
+    (_, fn, args), = [job for job in jobs if job[0] == ("decode", 8)]
+    names = _kernel_names(fn.lower(*args).compile().as_text())
+    assert sorted(names) == sorted(["held_ffn"] * 5
+                                   + [family.KDA_STEP_KERNEL] * 5
+                                   + [family.STEP_MARKER])
 
 
 def test_a_looped_decode_program_carries_the_pool_through_its_pass_loop(

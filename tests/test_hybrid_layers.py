@@ -145,18 +145,30 @@ def test_an_mla_layer_equals_the_reference(params, t):
 
 
 @pytest.mark.parametrize("form,t", [("held", 24), ("held", SORTED_MIN_TOKENS),
+                                    ("held_kernel", 24),
+                                    ("held_kernel", SORTED_MIN_TOKENS),
                                     ("sorted", 24), ("sorted", 300)])
 @pytest.mark.parametrize("held", [(0, 16), (4, 4), (12, 4)])
-def test_an_expert_layer_equals_the_reference(params, form, t, held):
-    """Both dropless forms, the uncut layer and two chips' shares of it:
-    the held experts' terms with gates normalised over all chosen."""
+def test_an_expert_layer_equals_the_reference(monkeypatch, params, form, t,
+                                              held):
+    """The dropless forms — the held experts' einsums, the ``held_ffn``
+    kernel (interpreted) that reads the touched ones, the sorted layout —
+    on the uncut layer and two chips' shares of it: the held experts' terms
+    with gates normalised over all chosen."""
+    import tpu9.ops.held_ffn as held_ops
+    if form == "held_kernel":
+        # done before the layer's next eager line is queued behind it: the
+        # interpreter's callbacks run jax computations of their own
+        monkeypatch.setattr(held_ops, "held_ffn", lambda *a, **kw:
+                            jax.block_until_ready(held_ops.held_ffn_kernel(
+                                *a, interpret=True, **kw)))
     first, count = held
     cfg = replace(SMALL, n_experts=count, moe_held_first=first)
     moe = dict(params["layers"][1]["moe"])
     for name in ("w_gate", "w_up", "w_down"):
         moe[name] = moe[name][first:first + count]
     h = _normed(t + first, t)
-    got, picks = (moe_ffn_held if form == "held" else moe_ffn_sorted)(
+    got, picks = (moe_ffn_sorted if form == "sorted" else moe_ffn_held)(
         moe, h[None], _moe_cfg(cfg))
     want = reference._experts(moe, h, _model(cfg))
     assert np.abs(np.asarray(got[0] - want)).max() < TOL

@@ -348,45 +348,36 @@ def shared_ffn(shared: Params, xf: jnp.ndarray, cfg: MoeConfig):
                             shared["w_down"]).astype(jnp.float32)
 
 
-def moe_ffn_held(params: Params, x: jnp.ndarray, cfg: MoeConfig):
+def moe_ffn_held(params: Params, x: jnp.ndarray, cfg: MoeConfig, live=None):
     """Dropless top-k of a chip's share at FEW rows (a decode step): every
-    held expert over every token, weighted by the token's gate for it (0 for
-    all but its picks). x [B, T, dim] → ([B, T, dim], the chosen experts'
-    global ids int32 [B, T, k]).
+    row against each held expert that a LIVE row picked, weighted by the
+    row's gate for it (0 for all but its picks). x [B, T, dim] → ([B, T,
+    dim], the chosen experts' global ids int32 [B, T, k]). ``live`` bool
+    [B, T]: the rows that are real (None: all) — an idle lane's or a padded
+    tail's gates are 0 and its picks, which it still returns, put no expert
+    on the list.
 
-    It reads each held expert's weights once a call, which is what the call
-    has to read as soon as its tokens' picks touch most held experts (128
-    lanes with 2 of 8 picks here touch 111 of 128); its FLOPs are those of
-    ``n_experts`` rows a token, so it is for calls under
-    ``SORTED_MIN_TOKENS`` rows, where the weights' stream hides them. No
-    capacity: the reference has none."""
-    from ..ops.quant import maybe_einsum
+    ``tpu9.ops.held_ffn`` reads the touched experts' weights once a call and
+    no others. Its FLOPs are those of a row a touched expert and token, so
+    it is for calls of at most ``SORTED_MIN_TOKENS`` rows, where the
+    weights' stream hides them. No capacity: the reference has none."""
+    from ..ops import held_ffn as ops
     b, t, d = x.shape
     n, e = b * t, cfg.n_experts
     xf = x.reshape(n, d)
     with jax.named_scope("moe.route"):
         _, gate_vals, gate_idx = _top_k_gates(params, xf, cfg)
         local = gate_idx - cfg.held_first                        # [N, k]
-        # [N, E]: a token's gate for each held expert (a pick held
+        live = jnp.ones(n, bool) if live is None else live.reshape(n)
+        # [N, E]: a live token's gate for each held expert (a pick held
         # elsewhere is no row of the one-hot)
         weight = (jax.nn.one_hot(local, e, dtype=jnp.float32)
-                  * gate_vals[..., None]).sum(1)
+                  * (gate_vals * live[:, None])[..., None]).sum(1)
+        ids, count = ops.touched_experts(local, live, e)
     with jax.named_scope("moe.experts"):
-        # the tokens as a BATCHED operand, one copy an expert: a product
-        # a held expert over the stacks as they are stored. Without the
-        # batch dimension the compiler takes ONE product over all experts'
-        # columns, and transposes both stacks a call to get it
-        h = jnp.broadcast_to(xf.astype(cfg.dtype), (e, n, d))
-        gate = maybe_einsum("end,edh->enh", h, params["w_gate"])
-        gate = jax.nn.silu(gate) if cfg.act == "silu" \
-            else jax.nn.gelu(gate, approximate=True)
-        up = maybe_einsum("end,edh->enh", h, params["w_up"])
-        # the gate weights the hidden rows, so that the down projection
-        # sums over experts and hidden at once: no [E, N, d] product
-        hidden = (gate * up).astype(jnp.float32) * weight.T[..., None]
-        out = jnp.einsum("enh,ehd->nd", hidden.astype(cfg.dtype),
-                         params["w_down"],
-                         preferred_element_type=jnp.float32)
+        out = ops.held_ffn(
+            xf.astype(cfg.dtype), weight, ids, count, params["w_gate"],
+            params["w_up"], params["w_down"], act=cfg.act)
     if cfg.shared_dim:
         out = out + shared_ffn(params["shared"], xf, cfg)
     return out.reshape(b, t, d).astype(x.dtype), \

@@ -582,16 +582,19 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
 
 
 def _mlp_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
-               compute_dtype=None, serving: bool = False, mesh=None):
+               compute_dtype=None, serving: bool = False, mesh=None,
+               live=None):
     h = _pre_norm(x, layer["mlp_norm"], cfg, compute_dtype)
     if cfg.moe_routed and "moe" in layer:
         # an expert layer that is told which experts it holds: dropless at
-        # every width, every held expert over few rows and the sorted form
-        # with local ids over many; ``aux`` is the experts its tokens chose
+        # every width, the held experts its LIVE rows (``live`` bool [B, T];
+        # None: all) picked over few rows and the sorted form with local ids
+        # over many; ``aux`` is the experts its tokens chose
         from .moe import SORTED_MIN_TOKENS, moe_ffn_held, moe_ffn_sorted
-        wide = h.shape[0] * h.shape[1] > SORTED_MIN_TOKENS
-        y, picks = (moe_ffn_sorted if wide else moe_ffn_held)(
-            layer["moe"], h, _moe_cfg(cfg))
+        if h.shape[0] * h.shape[1] > SORTED_MIN_TOKENS:
+            y, picks = moe_ffn_sorted(layer["moe"], h, _moe_cfg(cfg))
+        else:
+            y, picks = moe_ffn_held(layer["moe"], h, _moe_cfg(cfg), live)
         with jax.named_scope("moe.combine"):
             return x + y, {"picks": picks}
     if cfg.n_experts and not cfg.moe_routed:
@@ -643,6 +646,9 @@ def _pattern_layers(params: Params, x, cfg: DecoderConfig, positions, sin,
     expert layer, int32 [B, T, expert layers, top_k] (a padded row's are
     whatever its padding chose: the caller knows which rows are real)."""
     b, t, _ = x.shape
+    # the rows that are real: the expert layer of a decode step reads the
+    # experts THEY picked (an idle lane's padding picks nothing)
+    live = None if n_valid is None else jnp.arange(t) < n_valid[:, None]
     if n_valid is None:
         n_valid = jnp.full((b,), t, jnp.int32)
     picks = []
@@ -650,7 +656,7 @@ def _pattern_layers(params: Params, x, cfg: DecoderConfig, positions, sin,
         x, kv_cache = _attn_block(layer, x, cfg, positions, sin, cos,
                                   kv_cache, i, cache_len, decode,
                                   n_valid=n_valid)
-        x, aux = _mlp_block(layer, x, cfg)
+        x, aux = _mlp_block(layer, x, cfg, live=live)
         if aux is not None:
             picks.append(aux["picks"])
     return x, kv_cache, jnp.stack(picks, axis=2)
