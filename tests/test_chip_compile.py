@@ -212,15 +212,18 @@ def test_a_decode_programs_only_kernels_are_the_step_markers(
         v5e, no_compile_cache, monkeypatch, configuration):
     """What the benchmark counts decode steps by (``STEP_MARKER``,
     ``marker_calls_per_step``): in a configuration's K = 1 decode program
-    (two layers of it) every Mosaic kernel is an instruction named
-    ``paged_decode_attention``, one a layer, and no other instruction
-    carries that prefix — a helper kernel or a split into partial and
-    combine calls would make the trace's step count wrong."""
+    (two layers of it) the Mosaic kernels are an instruction named
+    ``paged_decode_attention`` a layer — and, where the layers hold experts
+    (ISSUE 50), one ``held_ffn`` a layer beside it — and no other
+    instruction carries the marker's prefix: a helper kernel or a split into
+    partial and combine calls would make the trace's step count wrong."""
     cfg, family, _, _, jobs = _decode_programs(v5e, monkeypatch,
                                                configuration, n_layers=2)
     (_, fn, args), = [job for job in jobs if job[0] == ("decode", 1)]
     text = fn.lower(*args).compile().as_text()
-    assert _kernel_names(text) == [family.STEP_MARKER] * cfg.n_layers
+    experts = ["held_ffn"] * (cfg.n_layers if cfg.n_experts else 0)
+    assert sorted(_kernel_names(text)) == sorted(
+        [family.STEP_MARKER] * cfg.n_layers + experts)
     named = [ln for ln in text.splitlines() if re.match(
         rf"\s+(ROOT )?%?{family.STEP_MARKER}", ln)]
     assert len(named) == cfg.n_layers
@@ -391,7 +394,11 @@ def test_decode_programs_carry_the_pool_whole_on_a_described_v5e(
     for key, fn, args in programs:
         compiled = fn.lower(*args).compile()
         text = compiled.as_text()
-        assert text.count("tpu_custom_call") == cfg.n_layers, key
+        # the paged kernel a layer, and the expert kernel where layers hold
+        # experts (ISSUE 50)
+        assert sorted(_kernel_names(text)) == sorted(
+            ["paged_decode_attention"] * cfg.n_layers
+            + ["held_ffn"] * (cfg.n_layers if cfg.n_experts else 0)), key
         assert compiled.memory_analysis().temp_size_in_bytes \
             < pool_bytes / 4, key
         assert _pool_shaped(text, per_chip) == [], key
@@ -501,6 +508,29 @@ def test_the_held_expert_layer_reads_its_stacks_as_they_are_stored(
     text = compiled.as_text()
     assert _kernel_names(text) == (["held_ffn"] if form == "kernel" else [])
     assert not re.search(r"bf16\[128,(2560,768|768,2560)\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
+def test_a_plain_expert_decoders_step_keeps_no_product_over_every_expert(
+        v5e, no_compile_cache, monkeypatch):
+    """``mixtral-8x7b-l4``'s K = 1 decode program at its published widths and
+    its engine's 32 lanes (ISSUE 50), two layers deep: the expert layer is
+    the ``held_ffn`` kernel — its step three blocks of 4096 x 512 columns,
+    12.6 MB, twice: VMEM that only the TPU's compiler can refuse — and no
+    ``[8, 32, 14336]`` or ``[8, 32, 4096]`` product of the one-hot form over
+    all eight experts is left; beside its tokens the program returns the
+    chosen experts, ``[1, 32, 2, 2]``."""
+    cfg, _, _, _, jobs = _decode_programs(v5e, monkeypatch,
+                                          "mixtral-8x7b-l4", n_layers=2)
+    (_, fn, args), = [job for job in jobs if job[0] == ("decode", 1)]
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert _kernel_names(text).count("held_ffn") == cfg.n_layers
+    assert not re.search(r"bf16\[8,32,(14336|4096)\]", text)
+    assert not re.search(r"bf16\[8,(4096,14336|14336,4096)\]\S* copy\(", text)
+    picks = jax.ShapeDtypeStruct((1, 32, cfg.n_layers, 2), jnp.int32)
+    assert [(o.shape, o.dtype) for o in jax.tree_util.tree_leaves(
+        fn.eval_shape(*args))][-1] == (picks.shape, picks.dtype)
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
 
 

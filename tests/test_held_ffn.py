@@ -202,3 +202,120 @@ def test_the_dispatcher_takes_the_kernel_on_a_tpu_alone(monkeypatch):
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
     ops.held_ffn(*args)
     assert taken == ["xla", "kernel"]
+
+
+# -- a plain expert decoder: every expert held (ISSUE 50) ------------------------
+
+@pytest.fixture(scope="module")
+def plain():
+    """mixtral-tiny in bf16 with experts of three 128-column tiles, dropless
+    as the benchmark's Mixtral states it (capacity factor = E / k), and nine
+    tokens a lane for ten lanes."""
+    from tpu9.models.mixtral import MIXTRAL_PRESETS
+    tiny = MIXTRAL_PRESETS["mixtral-tiny"]
+    cfg = replace(tiny, dtype=jnp.bfloat16, hidden_dim=384,
+                  moe_capacity_factor=tiny.n_experts / tiny.moe_top_k)
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (10, 9), 0,
+                                cfg.vocab_size)
+    return cfg, init_decoder(jax.random.PRNGKey(50), cfg), tokens
+
+
+def _tiled_kernel(seen):
+    """``held_ffn`` as the chip runs it at Mixtral's widths — ``hidden`` cut
+    into tiles — interpreted; ``seen`` gets every call's list."""
+    def call(x, weight, ids, count, *stacks, act):
+        seen.append((np.asarray(ids), int(count[0])))
+        return jax.block_until_ready(ops.held_ffn_kernel.__wrapped__(
+            x, weight, ids, count, *stacks, act=act, interpret=POISONED))
+    return call
+
+
+def _step(monkeypatch, plain, live, tokens=None):
+    """A decode step of the plain decoder over a dense cache that holds
+    eight tokens a lane: (logits [B, V], picks, the kernel's lists)."""
+    from tpu9.models import moe
+    from tpu9.models.transformer import decoder_forward, init_kv_cache
+    cfg, params, given = plain
+    tokens = given if tokens is None else tokens
+    b = tokens.shape[0]
+    seen = []
+    # the form of a served expert layer, at a test's widths
+    monkeypatch.setattr(moe, "HELD_MIN_STACK_BYTES", 0)
+    # three hidden tiles of 128 an expert, as a wide expert is cut
+    monkeypatch.setattr(ops, "STEP_BYTES", 2 * 3 * cfg.dim * 128 * 2)
+    assert ops._step_tile(cfg.dim, cfg.hidden_dim, 2) == 128 < cfg.hidden_dim
+    monkeypatch.setattr(ops, "held_ffn", _tiled_kernel(seen))
+    _, cache = decoder_forward(params, tokens[:, :8], cfg,
+                               kv_cache=init_kv_cache(cfg, b, 16))
+    assert not seen                     # a call with no mask: the one-hot form
+    out = decoder_forward(
+        params, tokens[:, 8:9], cfg, positions=jnp.full((b, 1), 8),
+        kv_cache=cache, cache_len=jnp.full((b,), 9), decode=True,
+        n_valid=None if live is None else jnp.asarray(live, jnp.int32),
+        return_moe_picks=True)
+    logits = np.asarray(out[0][:, 0])
+    return (logits, np.asarray(out[2]) if len(out) > 2 else None, seen)
+
+
+@pytest.mark.parametrize("live_share", [0.0, 0.2, 1.0])
+def test_a_plain_decoders_step_reads_by_the_list(monkeypatch, plain,
+                                                 live_share):
+    """The step of a decoder that holds every expert, through the kernel
+    with ``hidden`` tiled: a live lane's logits are the one-hot form's
+    (``moe_ffn``, dropless) and the cache-less forward's; each layer's list
+    is the experts the live lanes picked, as the engine counts them; every
+    lane, idle or live, says what it chose."""
+    from tpu9.models.transformer import decoder_forward
+    cfg, params, tokens = plain
+    live = np.arange(10) < round(live_share * 10)
+    got, picks, seen = _step(monkeypatch, plain, live)
+    assert picks.shape == (10, 1, cfg.n_layers, cfg.moe_top_k)
+    assert len(seen) == cfg.n_layers
+    for layer, (ids, count) in enumerate(seen):
+        assert count == _note_routed_count(picks[:, 0, layer], live, 0,
+                                           cfg.n_experts)
+        assert set(ids[:count]) == set(picks[live, 0, layer].ravel())
+    one_hot, none, unseen = _step(monkeypatch, plain, None)
+    assert none is None and not unseen
+    whole = np.asarray(decoder_forward(params, tokens, cfg)[:, -1])
+    if live.any():
+        # bf16: the held form rounds the experts' hidden rows once, the
+        # one-hot form each expert's output and the gates too
+        spread = whole.std()
+        assert np.abs(got - one_hot)[live].max() < 0.05 * spread
+        assert np.abs(got - whole)[live].max() < 0.05 * spread
+        assert np.abs(one_hot - whole)[live].max() < 0.05 * spread
+
+
+def test_idle_lanes_of_a_plain_decoder_leave_the_live_lanes_alone(
+        monkeypatch, plain):
+    """Whatever token an idle lane is parked on, the lists hold the live
+    lanes' picks alone and the live lanes' logits do not move by a bit."""
+    cfg, _, tokens = plain
+    live = np.arange(10) < 3
+    parked = tokens.at[3:, 8].set((tokens[3:, 8] + 101) % cfg.vocab_size)
+    first, picks, seen = _step(monkeypatch, plain, live)
+    second, other, again = _step(monkeypatch, plain, live, parked)
+    assert (picks[3:] != other[3:]).any(), "the idle lanes must pick anew"
+    assert (picks[:3] == other[:3]).all()
+    for (ids, count), (ids2, count2) in zip(seen, again):
+        assert count == count2 and (ids == ids2).all()
+    assert (first[:3] == second[:3]).all()
+    # with every lane live the idle lanes' picks are on the list
+    _, _, everyone = _step(monkeypatch, plain, np.ones(10, bool))
+    assert sum(c for _, c in everyone) > sum(c for _, c in seen)
+
+
+@pytest.mark.parametrize("itemsize", [2, 4])
+def test_a_wide_experts_tile_fits_the_kernels_vmem_twice(itemsize):
+    """Mixtral's 4096 x 14336 (352 MB an expert in bf16): ``hidden`` is cut
+    into whole 128-lane registers that divide it, three blocks of a step
+    double-buffered well inside the kernel's VMEM limit — on the chip the
+    512-column tile reads one call alone at the einsums' speed (PERF.md §6,
+    PR 50), so a wide expert keeps ``grouped_ffn``'s tile; Ling's expert
+    stays whole."""
+    from tpu9.ops.grouped_ffn import _VMEM_LIMIT
+    tile = ops._step_tile(4096, 14336, itemsize)
+    assert tile < 14336 and 14336 % tile == 0 and tile % 128 == 0
+    assert 2 * 2 * 3 * 4096 * tile * itemsize <= _VMEM_LIMIT
+    assert ops._step_tile(2560, 768, itemsize) == 768

@@ -17,9 +17,12 @@ import numpy as np
 import pytest
 
 from tpu9.analysis.graphcheck.passes import walk_eqns
+from tpu9.models import moe
 from tpu9.models.moe import (SORTED_MIN_TOKENS, MoeConfig, init_moe_layer,
-                             moe_ffn, moe_ffn_sorted, takes_sorted_form)
+                             moe_ffn, moe_ffn_sorted, takes_held_form,
+                             takes_sorted_form)
 from tpu9.ops import grouped_ffn as grouped_ops
+from tpu9.ops import held_ffn as held_ops
 
 E, DIM, HIDDEN = 8, 128, 384          # three hidden tiles of 128 a step
 GROUPED = {
@@ -138,11 +141,38 @@ def test_the_rule_reads_the_calls_shape():
     assert not takes_sorted_form(f32, 512)
 
 
+def test_the_decode_steps_rule_reads_the_mask_and_the_calls_shape(
+        monkeypatch):
+    """ISSUE 50: a call that says which rows are live, of at most
+    ``SORTED_MIN_TOKENS`` rows, over bf16 stacks in one device's memory that
+    outweigh the kernel's fixed cost a call: Mixtral's 2.8 GB a layer do,
+    a test's 2.4 MB do not."""
+    params = _bf16_stacks()
+    live = jax.ShapeDtypeStruct((32, 1), jnp.bool_)
+    assert not takes_held_form(params, 32, live)
+    wide = jax.ShapeDtypeStruct((8, 4096, 14336), jnp.bfloat16)
+    assert takes_held_form({"w_gate": wide, "w_up": wide, "w_down": wide},
+                           32, live)
+    monkeypatch.setattr(moe, "HELD_MIN_STACK_BYTES", 0)
+    assert takes_held_form(params, 32, live)
+    assert takes_held_form(params, 256, live)
+    assert not takes_held_form(params, 257, live)
+    assert not takes_held_form(params, 32, None)
+    assert not takes_held_form(params, 128, None)       # a chunk: no mask
+    f32 = {k: jax.ShapeDtypeStruct(v.shape, jnp.float32)
+           for k, v in params.items()}
+    assert not takes_held_form(f32, 32, live)
+
+
+def _pallas_calls(jaxpr, name):
+    return [e for e in walk_eqns(jaxpr) if e.primitive.name == "pallas_call"
+            and name in str(e.params.get("name", ""))]
+
+
 def _grouped_calls(jaxpr):
     return [e for e in walk_eqns(jaxpr)
             if e.primitive.name.startswith("ragged_dot")
-            or (e.primitive.name == "pallas_call"
-                and "grouped_ffn" in str(e.params.get("name", "")))]
+            ] + _pallas_calls(jaxpr, "grouped_ffn")
 
 
 def _expert_einsums(jaxpr, moe_params):
@@ -153,9 +183,10 @@ def _expert_einsums(jaxpr, moe_params):
             and shapes & {tuple(v.aval.shape) for v in e.invars}]
 
 
-def _tiny_moe_block(case):
-    """A serving ``_mlp_block`` call of 512 tokens on mixtral-tiny in
-    bf16: plain, with int8 expert entries, or on a two-device mesh."""
+def _tiny_moe_block(case, rows=(1, 512), live=None):
+    """A serving ``_mlp_block`` call of 512 tokens (or ``rows``, with the
+    mask ``live``) on mixtral-tiny in bf16: plain, with int8 expert
+    entries, or on a two-device mesh."""
     from tpu9.models import init_decoder
     from tpu9.models.mixtral import MIXTRAL_PRESETS
     from tpu9.models.transformer import _mlp_block
@@ -170,9 +201,9 @@ def _tiny_moe_block(case):
         mesh = make_mesh(dp=1, fsdp=1, sp=1, tp=2,
                          devices=jax.devices()[:2])
     layer = params["layers"][0]
-    x = jnp.zeros((1, 512, cfg.dim), cfg.dtype)
+    x = jnp.zeros((*rows, cfg.dim), cfg.dtype)
     jaxpr = jax.make_jaxpr(lambda la, x: _mlp_block(
-        la, x, cfg, serving=True, mesh=mesh)[0])(layer, x).jaxpr
+        la, x, cfg, serving=True, mesh=mesh, live=live)[0])(layer, x).jaxpr
     return jaxpr, layer["moe"]
 
 
@@ -187,6 +218,33 @@ def test_int8_entries_and_a_sharded_stack_keep_the_one_hot_form(case):
         assert not grouped and len(einsums) == 3
 
 
+@pytest.mark.parametrize("case,rows,masked", [
+    ("plain", (32, 1), True),       # a decode step: the touched form
+    ("mesh", (32, 1), True), ("int8", (32, 1), True),
+    ("plain", (32, 1), False),      # no one says which rows are live
+    ("plain", (1, 128), False),     # a chunk
+    ("plain", (2, 256), True)],     # too many rows for every row an expert
+    ids=["step", "mesh", "int8", "no-mask", "chunk", "wide"])
+def test_a_decode_step_takes_the_touched_form_and_nothing_else_does(
+        monkeypatch, case, rows, masked):
+    """ISSUE 50: a plain expert decoder's step, as the chip dispatches it,
+    is ONE ``held_ffn`` kernel and no product over a whole stack; a mesh,
+    int8 entries, a call without a live mask and the 128-row chunk keep the
+    one-hot einsums, the wide call its sorted form."""
+    monkeypatch.setattr(held_ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(moe, "HELD_MIN_STACK_BYTES", 0)     # a test's widths
+    live = jnp.ones(rows, bool) if masked else None
+    jaxpr, moe_params = _tiny_moe_block(case, rows, live)
+    held = _pallas_calls(jaxpr, "held_ffn")
+    einsums = _expert_einsums(jaxpr, moe_params)
+    if (case, masked) == ("plain", True) and rows == (32, 1):
+        assert len(held) == 1 and not einsums
+    elif rows == (2, 256):
+        assert not held and not einsums and len(_grouped_calls(jaxpr)) == 3
+    else:
+        assert not held and len(einsums) == 3
+
+
 def test_a_training_call_keeps_the_one_hot_form_and_its_statistics():
     from tpu9.models import init_decoder
     from tpu9.models.mixtral import MIXTRAL_PRESETS
@@ -198,6 +256,9 @@ def test_a_training_call_keeps_the_one_hot_form_and_its_statistics():
     assert set(aux) == {"balance_loss", "dropped_frac", "expert_load"}
     _, aux = _mlp_block(layer, x, cfg, serving=True)
     assert aux is None
+    # a mask alone does not make a call a serving step
+    _, aux = _mlp_block(layer, x[:, :32], cfg, live=jnp.ones((1, 32), bool))
+    assert set(aux) == {"balance_loss", "dropped_frac", "expert_load"}
 
 
 @pytest.fixture(scope="module")
@@ -217,14 +278,15 @@ def mixtral_jobs():
     graphs = GraphFactory(cfg, ecfg, policy, chunk=ecfg.prefill_chunk)
     st = abstract_state(cfg, ecfg, policy)
     params = abstract_params_for(cfg, False)
-    on_tpu, grouped_ops.on_tpu = grouped_ops.on_tpu, lambda: True
+    on_tpu = grouped_ops.on_tpu, held_ops.on_tpu
+    grouped_ops.on_tpu = held_ops.on_tpu = lambda: True
     try:
         jobs = {key: fn.trace(*args).jaxpr.jaxpr
                 for key, fn, args in graphs.lowering_jobs(
                     params, st["kv_cache"], st["pool"], st["scratch"],
                     st["mb"], [ecfg.prefill_chunk], (4,), st["rng"])}
     finally:
-        grouped_ops.on_tpu = on_tpu
+        grouped_ops.on_tpu, held_ops.on_tpu = on_tpu
     return cfg, ecfg, params["layers"][0]["moe"], jobs
 
 
@@ -232,17 +294,23 @@ def mixtral_jobs():
     ("chunk", 128), ("decode", 1), ("decode", 8), ("verify", 4),
     ("chunkgroup", 4)], ids=lambda key: f"{key[0]}{key[1]}")
 def test_only_the_group_program_takes_the_sorted_form(mixtral_jobs, program):
+    """... and only the decode programs the touched form (ISSUE 50): the
+    128-token chunk and the verify window keep the one-hot einsums."""
     cfg, ecfg, moe_params, jobs = mixtral_jobs
     assert (ecfg.max_batch, ecfg.prefill_chunk, ecfg.admit_group_chunks) \
         == (32, 128, 4)
     jaxpr = jobs[program]
     grouped = _grouped_calls(jaxpr)
+    held = _pallas_calls(jaxpr, "held_ffn")
     einsums = _expert_einsums(jaxpr, moe_params)
     if program[0] == "chunkgroup":
         assert len(grouped) == cfg.n_layers         # one fused kernel a layer
-        assert not einsums                          # no [E, C, .] einsum
+        assert not einsums and not held             # no [E, C, .] einsum
+    elif program[0] == "decode":
+        assert len(held) == cfg.n_layers            # the body of the K scan
+        assert not einsums and not grouped
     else:
-        assert not grouped
+        assert not grouped and not held
         assert len(einsums) == 3 * cfg.n_layers
 
 
@@ -268,3 +336,61 @@ def test_a_dense_models_programs_do_not_import_the_grouped_matmul(
         (), st["rng"]) if fn.trace(*args)]
     assert ("chunkgroup", 4) in keys and ("decode", 1) in keys
     assert "tpu9.ops.grouped_ffn" not in sys.modules
+
+
+# -- the engine: a plain expert decoder's windows say what was picked ------------
+
+def _serve_tiny_mixtral(dtype, prompts, new=12):
+    """Three requests on four lanes of a paged engine over mixtral-tiny,
+    dropless: (the engine's stats, the tokens)."""
+    import asyncio
+
+    from tpu9.models import init_decoder
+    from tpu9.models.mixtral import MIXTRAL_PRESETS
+    from tpu9.serving.engine import EngineConfig, InferenceEngine
+    tiny = MIXTRAL_PRESETS["mixtral-tiny"]
+    cfg = replace(tiny, dtype=dtype,
+                  moe_capacity_factor=tiny.n_experts / tiny.moe_top_k)
+    engine = InferenceEngine(
+        init_decoder(jax.random.PRNGKey(0), cfg), cfg,
+        EngineConfig(max_batch=4, max_seq_len=256, prefill_buckets=(32,),
+                     decode_steps=(1, 8), kv_block_size=16,
+                     kv_pool_blocks=40, prefill_chunk=32,
+                     admit_group_chunks=2, temperature=0.0))
+
+    async def go():
+        await engine.start()
+        outs = await asyncio.gather(*(
+            engine.generate(list(p), max_new_tokens=new) for p in prompts))
+        await engine.stop()
+        return outs
+    return cfg, engine.stats, asyncio.run(go())
+
+
+def test_the_engine_counts_the_experts_a_plain_decoders_steps_touched(
+        monkeypatch):
+    """bf16 stacks on one device: every decode window returns the chosen
+    experts beside its tokens and the engine counts, over the live lanes of
+    every step, the experts touched — what ``/health`` carries for the
+    benchmark's ``moe_touched_share``. A float32 model keeps the one-hot
+    form: no window says, the counters stay 0."""
+    monkeypatch.setattr(moe, "HELD_MIN_STACK_BYTES", 0)     # a test's widths
+    rng = np.random.default_rng(50)
+    prompts = [rng.integers(3, 500, n).tolist() for n in (40, 9, 70)]
+    cfg, stats, outs = _serve_tiny_mixtral(jnp.bfloat16, prompts)
+    st = stats()
+    assert [len(o) for o in outs] == [12, 12, 12]
+    assert st["moe_experts_held"] == cfg.n_experts
+    assert st["moe_step_layers"] == cfg.n_layers * st["decode_steps"] > 0
+    # at most three live lanes x top-2 of four experts: some step leaves an
+    # expert untouched, none leaves all
+    assert st["moe_step_layers"] <= st["moe_held_touched"] \
+        < cfg.n_experts * st["moe_step_layers"]
+    assert st["moe_local_picks"] == cfg.moe_top_k * st["moe_token_layers"]
+    assert sum(st["moe_held_pick_hist"]) == st["moe_local_picks"]
+    assert st["graph_compiles_post_warmup"] == 0
+    _, stats, outs = _serve_tiny_mixtral(jnp.float32, prompts)
+    st = stats()
+    assert [len(o) for o in outs] == [12, 12, 12]
+    assert st["moe_experts_held"] == cfg.n_experts
+    assert st["moe_step_layers"] == st["moe_held_touched"] == 0

@@ -349,7 +349,8 @@ def shared_ffn(shared: Params, xf: jnp.ndarray, cfg: MoeConfig):
 
 
 def moe_ffn_held(params: Params, x: jnp.ndarray, cfg: MoeConfig, live=None):
-    """Dropless top-k of a chip's share at FEW rows (a decode step): every
+    """Dropless top-k at FEW rows (a decode step) over the experts this layer
+    holds, a chip's share or all of them (``held_first`` 0): every
     row against each held expert that a LIVE row picked, weighted by the
     row's gate for it (0 for all but its picks). x [B, T, dim] → ([B, T,
     dim], the chosen experts' global ids int32 [B, T, k]). ``live`` bool
@@ -384,15 +385,41 @@ def moe_ffn_held(params: Params, x: jnp.ndarray, cfg: MoeConfig, live=None):
         gate_idx.reshape(b, t, cfg.top_k)
 
 
-def takes_sorted_form(params: Params, n_tokens: int, mesh=None) -> bool:
-    """The shape rule of a serving call: more tokens than
-    ``SORTED_MIN_TOKENS`` and expert stacks that are plain bf16 arrays in
-    one device's memory. Int8 entries keep their scaled einsum and stacks
-    sharded over a mesh keep the einsums XLA partitions (a kernel is not
-    partitioned): both stay with the one-hot form."""
+def _one_devices_bf16_stacks(params: Params, mesh=None) -> bool:
+    """Expert stacks that are plain bf16 arrays in one device's memory, which
+    the kernels read as they are stored. Int8 entries keep their scaled
+    einsum and stacks sharded over a mesh keep the einsums XLA partitions (a
+    kernel is not partitioned): both stay with the one-hot form."""
     from ..ops.quant import is_quantized_entry
     stacks = [params[w] for w in ("w_gate", "w_up", "w_down")]
-    return (n_tokens > SORTED_MIN_TOKENS
-            and (mesh is None or mesh.size == 1)
+    return ((mesh is None or mesh.size == 1)
             and not any(is_quantized_entry(w) for w in stacks)
             and all(w.dtype == jnp.bfloat16 for w in stacks))
+
+
+def takes_sorted_form(params: Params, n_tokens: int, mesh=None) -> bool:
+    """The shape rule of a wide serving call: more tokens than
+    ``SORTED_MIN_TOKENS`` over stacks a kernel can read."""
+    return n_tokens > SORTED_MIN_TOKENS \
+        and _one_devices_bf16_stacks(params, mesh)
+
+
+# A decode step takes the touched form where the stacks it may leave unread
+# outweigh what the kernel costs a call whatever it reads: its pipeline's
+# prologue is ≈ 14 µs (PERF.md §5, ``ling-reason``: 5 x 14 µs a step), the
+# stream of 11.5 MB at a v5e's 819 GB/s. Every served expert layer is far
+# over it (Ling's share 1.5 GB, Mixtral's 2.8 GB a layer); the tiny models of
+# the tests and rehearsals (0.8 MB) stay with the einsums they always ran.
+HELD_MIN_STACK_BYTES = 16 * 1024 * 1024
+
+
+def takes_held_form(params: Params, n_tokens: int, live, mesh=None) -> bool:
+    """The shape rule of a decode step: a serving call that says which of
+    its rows are LIVE, of at most ``SORTED_MIN_TOKENS`` rows, over stacks a
+    kernel can read and of at least ``HELD_MIN_STACK_BYTES``, takes
+    :func:`moe_ffn_held` — the experts no live row picked are not read."""
+    stacks = [params[w] for w in ("w_gate", "w_up", "w_down")]
+    return live is not None and n_tokens <= SORTED_MIN_TOKENS \
+        and _one_devices_bf16_stacks(params, mesh) \
+        and sum(w.size * w.dtype.itemsize
+                for w in stacks) >= HELD_MIN_STACK_BYTES

@@ -598,11 +598,18 @@ def _mlp_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
         with jax.named_scope("moe.combine"):
             return x + y, {"picks": picks}
     if cfg.n_experts and not cfg.moe_routed:
-        from .moe import moe_ffn, moe_ffn_sorted, takes_sorted_form
+        from .moe import (moe_ffn, moe_ffn_held, moe_ffn_sorted,
+                          takes_held_form, takes_sorted_form)
         # one algorithm, dropless top-k, in the form that is cheapest for
-        # the rows of this call (a serving step keeps no router statistics)
-        if serving and takes_sorted_form(
-                layer["moe"], h.shape[0] * h.shape[1], mesh):
+        # the rows of this call (a serving step keeps no router statistics):
+        # a decode step — it says which rows are LIVE — reads the experts
+        # they picked and says which (``aux``, as an expert layer that is
+        # told what it holds does), a wide call sorts its rows by expert
+        n_tokens = h.shape[0] * h.shape[1]
+        if serving and takes_held_form(layer["moe"], n_tokens, live, mesh):
+            y, picks = moe_ffn_held(layer["moe"], h, _moe_cfg(cfg), live)
+            aux = {"picks": picks}
+        elif serving and takes_sorted_form(layer["moe"], n_tokens, mesh):
             y, aux = moe_ffn_sorted(layer["moe"], h, _moe_cfg(cfg)), None
         else:
             y, aux = moe_ffn(layer["moe"], h, _moe_cfg(cfg),
@@ -619,22 +626,39 @@ def _mlp_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
         return x + maybe_matmul(gated, layer["w_down"]), None
 
 
+def _live_rows(n_valid, t: int):
+    """``n_valid`` int32 [B], how many of each row's ``t`` tokens are real,
+    as a mask bool [B, t]; None: no one says, None."""
+    return None if n_valid is None else jnp.arange(t) < n_valid[:, None]
+
+
 def _layers(params: Params, x, cfg: DecoderConfig, positions, sin, cos,
             kv_cache, cache_base, cache_len, decode: bool, mesh,
-            moe_balance, compute_dtype=None):
+            moe_balance, compute_dtype=None, live=None):
     """Every layer once. Layer ``l`` keeps its keys and values at plane
     ``cache_base + l`` of the cache: 0 for a plain decoder (the plane is
     then a Python int, as it always was), a traced ``u * n_layers`` inside
-    the pass loop of a looped one."""
+    the pass loop of a looped one. ``live`` bool [B, T]: the rows that are
+    real, where the caller says (a decode step's lanes). Returns ``(x,
+    kv_cache, moe_balance, picks)``: ``picks`` the experts every token chose
+    in the expert layers that say so, int32 [B, T, layers, top_k] — those of
+    a step that read only the experts its live rows picked — or None."""
+    picks = []
     for i, layer in enumerate(params["layers"]):
         x, kv_cache = _attn_block(layer, x, cfg, positions, sin, cos,
                                   kv_cache, cache_base + i, cache_len,
                                   decode, mesh, compute_dtype)
         x, aux = _mlp_block(layer, x, cfg, compute_dtype,
-                            serving=kv_cache is not None, mesh=mesh)
-        if aux is not None:
+                            serving=kv_cache is not None, mesh=mesh,
+                            live=live)
+        if aux is None:
+            continue
+        if "picks" in aux:
+            picks.append(aux["picks"])
+        else:
             moe_balance = moe_balance + aux["balance_loss"]
-    return x, kv_cache, moe_balance
+    return x, kv_cache, moe_balance, \
+        jnp.stack(picks, axis=2) if picks else None
 
 
 def _pattern_layers(params: Params, x, cfg: DecoderConfig, positions, sin,
@@ -648,7 +672,7 @@ def _pattern_layers(params: Params, x, cfg: DecoderConfig, positions, sin,
     b, t, _ = x.shape
     # the rows that are real: the expert layer of a decode step reads the
     # experts THEY picked (an idle lane's padding picks nothing)
-    live = None if n_valid is None else jnp.arange(t) < n_valid[:, None]
+    live = _live_rows(n_valid, t)
     if n_valid is None:
         n_valid = jnp.full((b,), t, jnp.int32)
     picks = []
@@ -695,9 +719,9 @@ def _looped_passes(params: Params, x, cfg: DecoderConfig, positions, sin,
     def one_pass(u, carry):
         x, kv, balance, remaining, cdf, selected, exit_step, ran = carry
         ran = ran + 1
-        x, kv, balance = _layers(params, x, cfg, positions, sin, cos, kv,
-                                 u * cfg.n_layers, cache_len, decode, mesh,
-                                 balance, compute_dtype)
+        x, kv, balance, _ = _layers(params, x, cfg, positions, sin, cos, kv,
+                                    u * cfg.n_layers, cache_len, decode,
+                                    mesh, balance, compute_dtype)
         with jax.named_scope("loop.norm"):
             x = rms_norm(x, params["final_norm"], cfg.norm_eps,
                          cfg.norm_offset)
@@ -753,12 +777,15 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
                  state the head read at each position (a looped decoder's
                  exit gate; the last pass where there is no gate) and the
                  passes the device ran for it (1 for a plain decoder)
-    - ``n_valid``: int32 [B], a layer pattern only: how many of each row's
-                 tokens are real (a padded chunk tail, an idle decode lane:
-                 KDA state is advanced by real tokens alone)
-    - ``return_moe_picks``: a layer pattern only: also return, last, the
-                 global ids of the experts every token chose in every expert
-                 layer, int32 [B, T, expert layers, top_k]
+    - ``n_valid``: int32 [B]: how many of each row's tokens are real (a
+                 padded chunk tail, an idle decode lane): a layer pattern's
+                 KDA state is advanced by real tokens alone, and an expert
+                 layer at few rows reads the experts the real tokens picked
+    - ``return_moe_picks``: also return, last, the global ids of the experts
+                 every token chose in every expert layer that says so, int32
+                 [B, T, expert layers, top_k]: a layer pattern's always; a
+                 plain expert decoder's where ``n_valid`` made its layers
+                 read only the picked experts, else nothing is added
     """
     b, t = tokens.shape
     if positions is None:
@@ -785,7 +812,7 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
                               cfg.rope_theta)
 
     moe_balance = jnp.zeros((), jnp.float32)
-    exit_info = None
+    exit_info = moe_picks = None
     if not cfg.looped:
         # with ``attn_window`` the residual stream is float32 and every
         # sub-layer still computes in the embeddings' type, as in the pass
@@ -798,9 +825,10 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
                 params, x, cfg, positions, sin, cos, kv_cache, cache_len,
                 decode, n_valid)
         else:
-            x, kv_cache, moe_balance = _layers(
+            x, kv_cache, moe_balance, moe_picks = _layers(
                 params, x, cfg, positions, sin, cos, kv_cache, 0, cache_len,
-                decode, mesh, moe_balance, compute_dtype)
+                decode, mesh, moe_balance, compute_dtype,
+                _live_rows(n_valid, t))
         x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_offset)
         if compute_dtype is not None:
             x = x.astype(compute_dtype)
@@ -828,7 +856,7 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
     if return_moe_aux:
         # mean balance loss across layers (training regularizer)
         out += (moe_balance / max(cfg.n_layers, 1),)
-    if return_moe_picks:
+    if return_moe_picks and moe_picks is not None:
         out += (moe_picks,)
     if return_exit:
         out += (jnp.stack([jnp.zeros((b, t), jnp.int32),

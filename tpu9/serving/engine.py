@@ -538,11 +538,17 @@ class InferenceEngine:
         # decode step: picks that fell on held experts over token-layers
         # routed (2.0 where 2 of 8 picks are held and routing is uniform),
         # held experts touched over (step, layer)s, and how many picks each
-        # held expert took. A plain decoder has none of them
+        # held expert took. A plain expert decoder (ISSUE 50: it holds every
+        # expert) counts the same from the picks ITS decode windows return,
+        # which they do where a step reads only the experts its live lanes
+        # picked (``moe.takes_held_form``; where the one-hot form serves —
+        # int8 stacks, a mesh, a test's tiny experts — no window says and
+        # the counters stay 0). A dense decoder has none of them
         if cfg.layer_group:
             from ..models.hybrid import lane_state_bytes, lane_state_shapes
             self._lane_state_names = tuple(lane_state_shapes(cfg, b))
             self._state_bytes = lane_state_bytes(cfg, b)
+        if cfg.layer_group or cfg.n_experts:
             self._stats.update(moe_local_picks=0, moe_token_layers=0,
                                moe_held_touched=0, moe_step_layers=0)
             self._held_pick_hist = np.zeros((cfg.n_experts,), np.int64)
@@ -1345,6 +1351,7 @@ class InferenceEngine:
             out["state_bytes_per_lane"] = \
                 self._state_bytes // self.ecfg.max_batch
             out["state_lanes_in_use"] = int(self.active.sum())
+        if self.cfg.layer_group or self.cfg.n_experts:
             out["moe_experts_held"] = self.cfg.n_experts
             out["moe_held_pick_hist"] = [int(n) for n in
                                          self._held_pick_hist]
@@ -2576,10 +2583,11 @@ class InferenceEngine:
     def _beside_tokens(self, rest) -> dict:
         """The field of a decode window that holds what its program returns
         beside the tokens: a looped decoder's exit passes, a layer pattern's
-        chosen experts, nothing for a plain decoder."""
+        chosen experts — or a plain expert decoder's, where a step reads the
+        experts its live lanes picked — nothing for a dense decoder."""
         if not rest:
             return {}
-        return {"picks" if self.cfg.layer_group else "exits": rest[0]}
+        return {"exits" if self.cfg.looped else "picks": rest[0]}
 
     @staticmethod
     def _window_arrays(win: _Window) -> tuple:
@@ -2719,13 +2727,14 @@ class InferenceEngine:
                     self._loop_exit_hist[int(step)] += 1
 
     def _note_routed(self, win: _Window, picks) -> None:
-        """A layer pattern's decode window: ``picks`` [k, B, expert layers,
+        """An expert decoder's decode window: ``picks`` [k, B, expert layers,
         top_k], the experts (global ids) the token each lane fed in chose
-        at each step. A request keeps those of the tokens it was delivered
-        (step j fed in the token before the j-th delivered); the counters
-        are over the lanes live at dispatch, every step."""
-        for slot, n in win.delivered.items():
-            win.reqs[slot].routed.append(picks[:n, slot].copy())
+        at each step. A layer pattern's request keeps those of the tokens it
+        was delivered (step j fed in the token before the j-th delivered);
+        the counters are over the lanes live at dispatch, every step."""
+        if self.cfg.layer_group:
+            for slot, n in win.delivered.items():
+                win.reqs[slot].routed.append(picks[:n, slot].copy())
         live = picks[:, win.mask]
         k, n, layers, _ = live.shape
         e = self.cfg.n_experts

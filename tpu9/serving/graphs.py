@@ -221,14 +221,19 @@ class GraphFactory:
             # a looped decoder also says which pass the head read and how
             # many passes ran: ``exits`` is ``(exit_info [B, 1, 2],)`` for it
             # and empty for a plain one
-            if cfg.layer_group:
-                # a layer pattern: an idle lane advances no KDA state, and
-                # the step also says which experts every lane's token chose
-                # in every expert layer, ``(picks [B, 1, layers, top_k],)``
+            if cfg.layer_group or (cfg.n_experts and not cfg.looped):
+                # the step says which lanes are live: an idle lane advances
+                # no KDA state of a layer pattern and puts no expert on the
+                # list of those an expert layer reads; the step then also
+                # says which experts every lane's token chose in every
+                # expert layer, ``(picks [B, 1, layers, top_k],)`` — a layer
+                # pattern always, a plain expert decoder where its layers
+                # read by that list (``moe.takes_held_form``)
                 logits, kv_cache, *exits = decoder_forward(
                     params, last_token, cfg, positions=positions,
                     kv_cache=kv_cache, cache_len=(cache_len + 1) * live,
-                    decode=True, n_valid=live, return_moe_picks=True)
+                    decode=True, mesh=policy.mesh, n_valid=live,
+                    return_moe_picks=True)
             else:
                 logits, kv_cache, *exits = decoder_forward(
                     params, last_token, cfg, positions=positions,
@@ -260,7 +265,7 @@ class GraphFactory:
                 body, (last_token, kv_cache, cache_len, rng), None,
                 length=k)
             # toks [k, B] (and a looped decoder's exit pass and pass count,
-            # [k, B, 2], or a layer pattern's chosen experts, [k, B, expert
+            # [k, B, 2], or an expert decoder's chosen experts, [k, B, expert
             # layers, top_k]): the host consumes the whole window in one sync
             return (last, policy.constrain_kv(kv_cache), cache_len, rng,
                     *per_step)
