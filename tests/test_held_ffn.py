@@ -18,7 +18,7 @@ from benchmark.reference import ling as reference
 from tests.test_hybrid_layers import SMALL, TOL, _model, _normed
 from tpu9.models import init_decoder
 from tpu9.models.moe import SORTED_MIN_TOKENS, moe_ffn_held
-from tpu9.models.transformer import _moe_cfg
+from tpu9.models.transformer import moe_cfg
 from tpu9.ops import held_ffn as ops
 
 # memory no step wrote reads NaN, as on the chip it reads whatever it held
@@ -121,7 +121,7 @@ def test_live_rows_through_the_kernel_equal_the_reference(monkeypatch, moe,
     h = _normed(t + first, t)
     live = np.arange(t) % 3 > 0
     monkeypatch.setattr(ops, "held_ffn", kernel)
-    got, picks = moe_ffn_held(share, h[None], _moe_cfg(cfg),
+    got, picks = moe_ffn_held(share, h[None], moe_cfg(cfg),
                               jnp.asarray(live)[None])
     want = reference._experts(share, h, _model(cfg))
     assert np.abs(np.asarray(got[0] - want))[live].max() < TOL
@@ -146,7 +146,7 @@ def test_the_touched_list(moe, held, live_share):
     first, e = held
     cfg = replace(SMALL, n_experts=e, moe_held_first=first)
     _, picks = moe_ffn_held(_share(moe, first, e), _normed(9, 32)[:, None],
-                            _moe_cfg(cfg))
+                            moe_cfg(cfg))
     picks = np.asarray(picks[:, 0])
     mask = np.random.default_rng(first).random(32) < live_share
     ids, count = ops.touched_experts(jnp.asarray(picks - first),
@@ -164,7 +164,7 @@ def test_idle_rows_put_no_expert_on_the_list(monkeypatch, moe):
     """A decode step's idle lanes: whatever their padding picks, the list is
     the live rows' alone, the live rows' result is what it was, an idle
     row's experts add nothing, and every row still says what it chose."""
-    cfg = _moe_cfg(SMALL)
+    cfg = moe_cfg(SMALL)
     h = _normed(5, 24)[:, None]                     # [B, 1, dim]: a step
     live = jnp.arange(24) < 6
     seen = {}
@@ -234,7 +234,7 @@ def _step(monkeypatch, plain, live, tokens=None):
     """A decode step of the plain decoder over a dense cache that holds
     eight tokens a lane: (logits [B, V], picks, the kernel's lists)."""
     from tpu9.models import moe
-    from tpu9.models.transformer import decoder_forward, init_kv_cache
+    from tpu9.models import decoder_forward, init_kv_cache
     cfg, params, given = plain
     tokens = given if tokens is None else tokens
     b = tokens.shape[0]

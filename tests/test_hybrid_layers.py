@@ -17,11 +17,11 @@ import pytest
 
 from benchmark.reference import ling as reference
 from tpu9.models import decoder_forward, init_decoder, init_kv_cache
-from tpu9.models import hybrid
+from tpu9.models import hybrid, kvstate
 from tpu9.models.llama import LLAMA_PRESETS
 from tpu9.models.moe import (SORTED_MIN_TOKENS, _top_k_gates, moe_ffn_held,
                              moe_ffn_sorted)
-from tpu9.models.transformer import DecoderConfig, _moe_cfg
+from tpu9.models.transformer import DecoderConfig, moe_cfg
 from tpu9.ops import delta_rule
 from tpu9.ops.latent_attention import (expanded_attention,
                                        paged_latent_attention)
@@ -95,7 +95,7 @@ def test_layer_kind_is_the_one_place_that_knows_the_pattern():
     assert PLAIN.layer_kind(1) == ("full", "dense")
     assert PLAIN.kv_layers == PLAIN.n_layers
     assert PLAIN.kv_row == ((PLAIN.n_kv_heads, PLAIN.head_dim),) * 2
-    assert hybrid.lane_state_shapes(PLAIN, 4) == {}
+    assert kvstate.lane_shapes(PLAIN, 4) == {}
     mixtral = replace(PLAIN, n_experts=8)
     assert mixtral.layer_kind(0) == ("full", "experts")
 
@@ -116,7 +116,7 @@ def test_the_trees_follow_the_pattern(params):
     assert cache["kda_state"].shape == (4, 3, 4, 32, 32)
     assert cache["kda_state"].dtype == jnp.float32
     assert cache["kda_conv"].shape == (4, 3, 3, 3 * 128)
-    assert hybrid.lane_state_bytes(SMALL, 3) == 4 * 3 * (
+    assert kvstate.lane_bytes(SMALL, 3) == 4 * 3 * (
         4 * 32 * 32 * 4 + 3 * 384 * 4)
 
 
@@ -169,7 +169,7 @@ def test_an_expert_layer_equals_the_reference(monkeypatch, params, form, t,
         moe[name] = moe[name][first:first + count]
     h = _normed(t + first, t)
     got, picks = (moe_ffn_sorted if form == "sorted" else moe_ffn_held)(
-        moe, h[None], _moe_cfg(cfg))
+        moe, h[None], moe_cfg(cfg))
     want = reference._experts(moe, h, _model(cfg))
     assert np.abs(np.asarray(got[0] - want)).max() < TOL
     # the layer also says which experts every token chose: global ids, the
@@ -199,7 +199,7 @@ def test_rows_no_tile_holds_never_reach_the_result(params):
         written = jnp.arange(ys.shape[0]) < tiles.sum() * ops.ROW_TILE
         return jnp.where(written[:, None], ys, jnp.nan)
 
-    got, _ = moe_ffn_sorted(moe, h[None], _moe_cfg(cfg),
+    got, _ = moe_ffn_sorted(moe, h[None], moe_cfg(cfg),
                             grouped_ffn=poisoned)
     want = reference._experts(moe, h, _model(cfg))
     assert np.isfinite(np.asarray(got)).all()
@@ -509,7 +509,7 @@ def test_expanded_attention_in_query_blocks_equals_one_block(monkeypatch):
 
 def test_the_bias_changes_the_choice_and_never_a_gate(params):
     moe = params["layers"][1]["moe"]
-    mcfg = _moe_cfg(SMALL)
+    mcfg = moe_cfg(SMALL)
     h = _normed(21, 64)
     scores, gates, chosen = _top_k_gates(moe, h, mcfg)
     pushed = dict(moe, bias=moe["bias"].at[7].add(10.0).at[2].add(-10.0))
@@ -567,7 +567,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer(params):
                             for n in ("w_gate", "w_up", "w_down")})
         parts_ref = parts_ref + reference._experts(
             part, h, _model(cfg)) - shared
-        got, _ = moe_ffn_held(part, h[None], _moe_cfg(cfg))
+        got, _ = moe_ffn_held(part, h[None], moe_cfg(cfg))
         parts_got = parts_got + got[0] - shared
     assert np.abs(np.asarray(parts_ref + shared - whole)).max() < TOL
     assert np.abs(np.asarray(parts_got + shared - whole)).max() < TOL

@@ -18,7 +18,7 @@ from benchmark import correctness
 from test_hybrid_layers import (PLAIN, SMALL, TOL, _margin, _model,
                                 _ref_logits)
 from tpu9.models import decoder_forward, init_decoder, init_kv_cache
-from tpu9.models import hybrid
+from tpu9.models import hybrid, kvstate
 from tpu9.models.transformer import (DEVICE_SCOPES, LOOP_SCOPES,
                                      SUMMARY_SCOPES)
 from tpu9.serving.engine import EngineConfig, InferenceEngine
@@ -75,9 +75,9 @@ def programs(params):
         pool = graphs.splice_fn()(
             pool, scratch["k"], scratch["v"], start,
             jnp.asarray(blocks[start // BS:start // BS + 1], jnp.int32))
-    names = tuple(hybrid.lane_state_shapes(SMALL, 1))
+    names = tuple(kvstate.lane_shapes(SMALL, 1))
     junk = {n: jnp.full(shape, 7.0, dt) for n, (shape, dt)
-            in hybrid.lane_state_shapes(SMALL, 2).items()}
+            in kvstate.lane_shapes(SMALL, 2).items()}
     got["lane1_before"] = {n: np.asarray(junk[n][:, 1]) for n in names}
     lanes = graphs.lane_splice_fn()(junk, {n: scratch[n] for n in names}, 0)
     table = np.zeros((2, mb), np.int32)
@@ -304,7 +304,7 @@ def test_feasibility_prices_the_latent_rows_and_the_lanes_state():
     # one row of 64 + 16 numbers a token in the 2 MLA layers, float32 here
     assert kv_block_bytes(SMALL, BS) == 2 * BS * (64 + 16) * 4
     assert kv_cache_bytes(SMALL, 2, S) == 2 * kv_block_bytes(SMALL, S)
-    assert lane_state_bytes(SMALL, 2) == hybrid.lane_state_bytes(SMALL, 2)
+    assert lane_state_bytes(SMALL, 2) == kvstate.lane_bytes(SMALL, 2)
     assert lane_state_bytes(PLAIN, 8) == 0
     # the published widths: 576 numbers a token in bf16, 10.6 MB a lane
     ling = replace(SMALL, dim=2560, n_heads=32, n_kv_heads=32, head_dim=128,

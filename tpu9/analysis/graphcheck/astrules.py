@@ -43,8 +43,7 @@ DEFAULT_GRAPH_CFG = {
     "jit_owners": ["tpu9/serving/graphs.py", "tpu9/serving/shard/policy.py"],
     "int8_sources": ["tpu9.ops.quant"],
     "int8_symbols": ["quantize_kv", "dequantize_kv"],
-    "int8_carriers": ["tpu9.ops", "tpu9.models.transformer",
-                      "tpu9.serving.graphs"],
+    "int8_carriers": ["tpu9.ops", "tpu9.models.kvstate"],
 }
 
 

@@ -6,7 +6,8 @@ Gemma + LoRA (multi-host FSDP fine-tune). All models are functional pytrees —
 params flow through ``jax.jit``/``pjit`` with shardings from tpu9.parallel.
 """
 
-from .transformer import DecoderConfig, init_decoder, decoder_forward, init_kv_cache
+from .transformer import DecoderConfig, init_decoder, decoder_forward
+from .kvstate import init_kv_cache
 from .llama import LLAMA_PRESETS, llama_config
 from .gemma import GEMMA_PRESETS, gemma_config
 from .clip_vit import ClipVisionConfig, init_clip_vision, clip_vision_forward, CLIP_VIT_L14

@@ -6,15 +6,17 @@ attention (MLA). ``DecoderConfig.layer_kind`` says which layer is which;
 What a running sequence keeps differs by kind, and that is the point:
 
 - a KDA layer keeps STATE A LANE — a float32 matrix ``[H, d, d]`` and the
-  last ``kda_conv - 1`` inputs of its short convolution — the same size at
-  any length. It rides in the cache dict as ``kda_state`` ``[P, B, H, d, d]``
-  and ``kda_conv`` ``[P, B, K-1, 3 H d]`` (``P`` KDA layers, ``B`` lanes; the
-  chunked prefill's scratch is the same at ``B = 1``): no table, no pages.
-- an MLA layer keeps ONE ROW A TOKEN — the latent under the cache's ``"k"``
-  and its rotated key under ``"v"`` (``DecoderConfig.kv_row``) — addressed by
-  the table like any paged cache. A prefill expands keys and values from the
-  latents; a decode step absorbs the expansions into the query and the output
-  and attends the latents themselves (``ops.latent_attention``).
+  last taps-less-one inputs of its short convolution — the same size at any
+  length, whatever the lanes (the chunked prefill's scratch keeps one): no
+  table, no pages.
+- an MLA layer keeps ONE ROW A TOKEN — the latent and its rotated key
+  (``DecoderConfig.kv_row``) — addressed by the table like any paged cache. A
+  prefill expands keys and values from the latents; a decode step absorbs the
+  expansions into the query and the output and attends the latents themselves
+  (``ops.latent_attention``).
+
+Where either lives in the cache dict, and how it is read and written, is
+``models.kvstate``'s to say; the layers here call it.
 
 The equations, with every assumption, are in the plain reference the
 benchmark holds this to (``benchmark/reference/ling.py``).
@@ -28,10 +30,11 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import delta_rule
-from ..ops.latent_attention import expanded_attention, paged_latent_attention
+from ..ops.latent_attention import expanded_attention
 from ..ops.norms import rms_norm
 from ..ops.quant import maybe_matmul
 from ..ops.rotary import apply_rope
+from . import kvstate
 
 F32 = jnp.float32
 # device scopes of the two kinds, beside ``transformer.DEVICE_SCOPES``:
@@ -105,30 +108,6 @@ def refuse_unbuilt_pattern(cfg) -> None:
                    "built for sigmoid scores only")
 
 
-def lane_state_shapes(cfg, lanes: int) -> dict:
-    """``name -> (shape, dtype)`` of the state KDA layers keep for ``lanes``
-    running sequences; empty for a decoder without such layers."""
-    planes = len(cfg.layers_of("kda")) if cfg.layer_group else 0
-    if not planes:
-        return {}
-    h, d = cfg.n_heads, cfg.head_dim
-    return {"kda_state": ((planes, lanes, h, d, d), F32),
-            "kda_conv": ((planes, lanes, cfg.kda_conv - 1, 3 * h * d),
-                         cfg.dtype)}
-
-
-def lane_state_bytes(cfg, lanes: int) -> int:
-    """Bytes of :func:`lane_state_shapes`."""
-    import numpy as np
-    return sum(int(np.prod(shape)) * np.dtype(dt).itemsize
-               for shape, dt in lane_state_shapes(cfg, lanes).values())
-
-
-def init_lane_state(cfg, lanes: int) -> dict:
-    return {name: jnp.zeros(shape, dt)
-            for name, (shape, dt) in lane_state_shapes(cfg, lanes).items()}
-
-
 def _dense(rng, in_dim: int, out_dim: int, dtype, fan_out: int = 0):
     scale = (2.0 / (in_dim + (fan_out or out_dim))) ** 0.5
     return (jax.random.normal(rng, (in_dim, out_dim), F32)
@@ -171,8 +150,8 @@ def init_hybrid_layer(rng: jax.Array, cfg, l: int) -> dict:
             "wo": _dense(next(r), h * dv, d_model, dt)}
     if ffn == "experts":
         from .moe import init_moe_layer
-        from .transformer import _moe_cfg
-        layer["moe"] = init_moe_layer(next(r), _moe_cfg(cfg))
+        from .transformer import moe_cfg
+        layer["moe"] = init_moe_layer(next(r), moe_cfg(cfg))
     else:
         layer["w_gate"] = _dense(next(r), d_model, cfg.hidden_dim, dt)
         layer["w_up"] = _dense(next(r), d_model, cfg.hidden_dim, dt)
@@ -208,8 +187,7 @@ def kda_block(p: dict, h: jnp.ndarray, cfg, kv_cache: Optional[dict],
         tail = jnp.zeros((b, cfg.kda_conv - 1, 3 * heads * d), h.dtype)
         n_valid = jnp.full((b,), t, jnp.int32)
     else:
-        state = kv_cache["kda_state"][plane]
-        tail = kv_cache["kda_conv"][plane]
+        state, tail = kvstate.lane_read(kv_cache, plane)
     with jax.named_scope("attn.kda.proj"):
         qkv, tail = delta_rule.causal_conv(
             maybe_matmul(h, p["w_qkv"]), p["conv"], tail, n_valid)
@@ -227,11 +205,10 @@ def kda_block(p: dict, h: jnp.ndarray, cfg, kv_cache: Optional[dict],
                 and not delta_rule.step_kernel_declined(heads, d):
             # the Pallas step: the lanes' states in place at this plane
             states, o = delta_rule.step_pallas(
-                kv_cache["kda_state"], plane, q[:, 0], k[:, 0], v[:, 0],
-                log_alpha[:, 0], beta[:, 0], live=n_valid > 0)
-            return _kda_out(p, o[:, None], out_gate, h, cfg), dict(
-                kv_cache, kda_state=states,
-                kda_conv=kv_cache["kda_conv"].at[plane].set(tail))
+                kvstate.lane_states(kv_cache), plane, q[:, 0], k[:, 0],
+                v[:, 0], log_alpha[:, 0], beta[:, 0], live=n_valid > 0)
+            return _kda_out(p, o[:, None], out_gate, h, cfg), \
+                kvstate.lane_write(kv_cache, plane, tail, states=states)
         if decode:
             state, o = delta_rule.step(
                 state, q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0],
@@ -243,10 +220,7 @@ def kda_block(p: dict, h: jnp.ndarray, cfg, kv_cache: Optional[dict],
             state, o = (delta_rule.chunked if whole else delta_rule.scan)(
                 state, q, k, v, log_alpha, beta, valid)
         if kv_cache is not None:
-            kv_cache = dict(
-                kv_cache,
-                kda_state=kv_cache["kda_state"].at[plane].set(state),
-                kda_conv=kv_cache["kda_conv"].at[plane].set(tail))
+            kv_cache = kvstate.lane_write(kv_cache, plane, tail, state=state)
     return _kda_out(p, o, out_gate, h, cfg), kv_cache
 
 
@@ -262,8 +236,8 @@ def _kda_out(p: dict, o, out_gate, h, cfg):
 def mla_block(p: dict, h: jnp.ndarray, cfg, positions, sin, cos,
               kv_cache: Optional[dict], plane: int, cache_len, decode: bool):
     """One MLA layer's attention over the normed input ``h`` [B, T, D]; the
-    cache's ``"k"`` holds latents and its ``"v"`` rotated keys at ``plane``.
-    Returns ``(y [B, T, D], kv_cache)``."""
+    cache holds latents and their rotated keys at ``plane``. Returns
+    ``(y [B, T, D], kv_cache)``."""
     b, t, _ = h.shape
     heads = cfg.n_heads
     dn, dr, dv, dc = cfg.mla_nope, cfg.mla_rope, cfg.mla_v, cfg.mla_latent
@@ -291,38 +265,24 @@ def mla_block(p: dict, h: jnp.ndarray, cfg, positions, sin, cos,
             out = jax.vmap(expanded_attention,
                            in_axes=(0, 0, 0, 0, 0, 0, None))(
                 q_nope, q_rope, k_nope, k_rope, v, positions, scale)
-    elif "table" in kv_cache and decode:
-        from .transformer import _pool_write
-        table = kv_cache["table"]
-        bs = kv_cache["k"].shape[2]
-        pos = positions[:, 0]
-        bi, oi = table[jnp.arange(b), pos // bs], pos % bs
-        kv_cache = dict(
-            kv_cache,
-            k=_pool_write(kv_cache["k"], plane, bi, oi, c[:, 0, None]),
-            v=_pool_write(kv_cache["v"], plane, bi, oi, k_rope[:, 0, None]))
+    elif kvstate.is_paged(kv_cache) and decode:
+        kv_cache = kvstate.write(kv_cache, plane, c, k_rope, positions,
+                                 decode)
         with jax.named_scope("attn.mla.absorb"):
             q_lat = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_ukv[..., :dn])
         with jax.named_scope("attn.mla.core"):
-            o_lat = paged_latent_attention(
-                q_lat, q_rope[:, 0], kv_cache["k"], kv_cache["v"], table,
-                cache_len, plane, scale)
+            o_lat = kvstate.latent_attend(kv_cache, plane, q_lat,
+                                          q_rope[:, 0], cache_len, scale)
         with jax.named_scope("attn.mla.absorb"):
             out = jnp.einsum("bhc,chd->bhd", o_lat.astype(h.dtype),
                              w_ukv[..., dn:])[:, None]
-    elif "table" not in kv_cache and not decode and cache_len is not None \
-            and b == 1:
+    elif not kvstate.is_paged(kv_cache) and not decode \
+            and cache_len is not None and b == 1:
         # chunked prefill through the batch-1 scratch: this chunk's rows
         # written at its offset, then every row's keys and values expanded
-        from .transformer import _cache_write
-        kv_cache = dict(
-            kv_cache,
-            k=_cache_write(kv_cache["k"], plane, c[:, :, None], positions),
-            v=_cache_write(kv_cache["v"], plane, k_rope[:, :, None],
-                           positions))
-        with jax.named_scope("kv.slice"):
-            latents = kv_cache["k"][plane, 0, :, 0]          # [S, dc]
-            rotated = kv_cache["v"][plane, 0, :, 0]          # [S, dr]
+        kv_cache = kvstate.write(kv_cache, plane, c, k_rope, positions,
+                                 decode)
+        latents, rotated = kvstate.latent_rows(kv_cache, plane)
         k_nope, v = expand(latents)
         with jax.named_scope("attn.mla.core"):
             out = expanded_attention(q_nope[0], q_rope[0], k_nope, rotated,

@@ -1,0 +1,333 @@
+"""What the KV state of a ``DecoderConfig`` is, and how a layer writes it and
+attends over it: THE one owner of the cache's format. ``models.transformer``
+and ``models.hybrid`` call the verbs; the pool, the feasibility gate, the
+graph factory, the sharding policy and the engine ask for shapes and bytes.
+Nothing else under ``models/`` reads a key of the cache dict, and ``serving/``
+names only what it moves itself (the ``"k"`` / ``"v"`` planes its splice and
+gather programs carry, the table it pushes, the wire format's header), so a
+new kind of cache is written here.
+
+**The format.** A running sequence keeps two sorts of state:
+
+- PAGED planes, addressed by cache ENTRY (``DecoderConfig.kv_entry``: a
+  token's position for plain attention): ``"k"`` and ``"v"``, each
+  ``[depth, ., ., *row]`` with ``depth = cfg.kv_layers`` and the rows of
+  ``cfg.kv_row`` — per-head keys and values ``(KH, D)``, or for latent
+  attention one latent ``(1, mla_latent)`` under ``"k"`` and its rotated key
+  ``(1, mla_rope)`` under ``"v"``. In a POOL the two middle axes are
+  ``[n_blocks, block]`` and a ``"table"`` ``[lanes, columns]`` names each
+  lane's blocks; an int8 pool holds the rows as int8 beside float32
+  ``"k_scale"`` / ``"v_scale"`` planes of one rank less (one absmax scale a
+  (token, head) vector, ``ops.quant.quantize_kv``). In a DENSE cache (the
+  dense engine's, the batch-1 prefill scratch, a prefill bucket) they are
+  ``[lanes, rows]``, always in the model's type. Axis ``HEAD_AXIS`` is the
+  KV heads' in every one of them: what a mesh shards.
+- state a LANE, the same size at any length: a KDA layer's float32 matrix
+  ``"kda_state"`` ``[P, lanes, H, d, d]`` and the last ``kda_conv - 1`` inputs
+  of its short convolution ``"kda_conv"`` ``[P, lanes, K-1, 3 H d]`` (``P`` KDA
+  layers). No table, no pages; the dense scratch carries one lane of it.
+
+**The verbs.** :func:`write` puts a layer's fresh rows where their entries
+say and :func:`attend` runs the layer's queries over what is written, through
+the dispatchers of ``ops.attention``; latent attention reads through
+:func:`latent_attend` (a decode step over the pool) and :func:`latent_rows`
+(a chunk over the scratch), a KDA layer through :func:`lane_read` and
+:func:`lane_write`. The cache dict is carried WHOLE from layer to layer:
+every write is into the ``[depth, ...]`` arrays in place (a donated or
+carried array), nothing is sliced out and stacked back.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops.attention import (attention, chunk_prefill_attention,
+                             decode_attention, paged_attention_dispatch,
+                             paged_verify_attention)
+from ..ops.latent_attention import paged_latent_attention
+from ..ops.quant import quantize_kv
+
+TABLE = "table"
+# of every paged array, pool and dense alike: [depth, ., ., KH, ...]
+HEAD_AXIS = 3
+_SCALE = {"k": "k_scale", "v": "v_scale"}
+
+
+# -- the format ---------------------------------------------------------------
+
+def paged_planes(cfg, quantized: bool = False) -> dict:
+    """``name -> (row shape, dtype)`` of the planes a POOL keeps an entry a
+    row of. Latent rows are read as they are written, in the model's type:
+    ``quantized`` is for per-head rows alone."""
+    rows = dict(zip(_SCALE, cfg.kv_row))
+    if cfg.layer_group or not quantized:
+        return {name: (row, cfg.dtype) for name, row in rows.items()}
+    # scales: the payload's [N, BS, KH] indexing, so every write and read
+    # shares the table math
+    return {**{name: (row, jnp.int8) for name, row in rows.items()},
+            **{_SCALE[name]: (row[:-1], jnp.float32)
+               for name, row in rows.items()}}
+
+
+def pool_shapes(cfg, n_blocks: int, block: int,
+                quantized: bool = False) -> dict:
+    """``name -> (shape, dtype)`` of a pool of ``n_blocks`` blocks of
+    ``block`` entries (its table and the lanes' state apart)."""
+    return {name: ((cfg.kv_layers, n_blocks, block) + row, dt)
+            for name, (row, dt) in paged_planes(cfg, quantized).items()}
+
+
+def lane_shapes(cfg, lanes: int) -> dict:
+    """``name -> (shape, dtype)`` of the state KDA layers keep for ``lanes``
+    running sequences; empty for a decoder without such layers."""
+    planes = len(cfg.layers_of("kda")) if cfg.layer_group else 0
+    if not planes:
+        return {}
+    h, d = cfg.n_heads, cfg.head_dim
+    return {"kda_state": ((planes, lanes, h, d, d), jnp.float32),
+            "kda_conv": ((planes, lanes, cfg.kda_conv - 1, 3 * h * d),
+                         cfg.dtype)}
+
+
+def dense_shapes(cfg, lanes: int, rows: int, dtype=None) -> dict:
+    """``name -> (shape, dtype)`` of a dense cache of ``rows`` entries a
+    lane — the dense engine's, the batch-1 scratch that chunked prefill
+    writes through, a prefill bucket — with the lanes' state beside it."""
+    shapes = {name: ((cfg.kv_layers, lanes, rows) + row, dtype or dt)
+              for name, (row, dt) in paged_planes(cfg).items()}
+    return {**shapes, **lane_shapes(cfg, lanes)}
+
+
+def _bytes(shapes: dict) -> int:
+    return sum(math.prod(shape) * np.dtype(dt).itemsize
+               for shape, dt in shapes.values())
+
+
+def block_bytes(cfg, block: int, quantized: bool = False) -> int:
+    """Bytes ONE pool block of ``block`` entries holds across the whole
+    depth of the state: the sum over :func:`pool_shapes`. What the engine's
+    equal-HBM pool sizing, the feasibility gate and the tier's statistics
+    all price blocks with."""
+    return _bytes(pool_shapes(cfg, 1, block, quantized))
+
+
+def lane_bytes(cfg, lanes: int = 1) -> int:
+    """Bytes of :func:`lane_shapes`."""
+    return _bytes(lane_shapes(cfg, lanes))
+
+
+def head_axis(name: str, ndim: int) -> Optional[int]:
+    """The axis of state array ``name`` (of rank ``ndim``) that holds the KV
+    heads, which a mesh shards; None for the table and the lanes' state."""
+    paged = name in _SCALE or name in _SCALE.values()
+    return HEAD_AXIS if paged and ndim > HEAD_AXIS else None
+
+
+def init_kv_cache(cfg, batch: int, max_len: int = 0, dtype=None) -> dict:
+    """Contiguous per-sequence KV cache: k/v ``[L, B, S, *row]``, ``L`` the
+    depth of the KV state (``cfg.kv_layers``), and the KDA layers' state
+    beside it (:func:`dense_shapes`)."""
+    return {name: jnp.zeros(shape, dt) for name, (shape, dt) in dense_shapes(
+        cfg, batch, max_len or cfg.max_seq_len, dtype).items()}
+
+
+# -- what a cache dict is ------------------------------------------------------
+
+def is_paged(kv: dict) -> bool:
+    """Whether ``kv`` is a pool read through a block table (else dense)."""
+    return TABLE in kv
+
+
+def dense_len(kv: dict) -> int:
+    """Rows a lane of a dense cache (0 for a pool: its table bounds it)."""
+    return 0 if is_paged(kv) else kv["k"].shape[2]
+
+
+def pool_rows(pool: dict, k, v) -> list:
+    """The rows ``k`` and ``v`` ``[..., KH, D]`` as ``pool`` stores them,
+    ``[(name, rows)]`` in the order they are written: an int8 pool's are
+    quantized per (token, head) vector, their scales ``[..., KH]`` first."""
+    if _SCALE["k"] not in pool:
+        return [("k", k), ("v", v)]
+    k, sk = quantize_kv(k)
+    v, sv = quantize_kv(v)
+    return [(_SCALE["k"], sk), (_SCALE["v"], sv), ("k", k), ("v", v)]
+
+
+def read_blocks(pool: dict, name: str, index):
+    """Blocks ``index`` of plane ``name`` at every depth, ``[L, len(index),
+    BS, ...]``; an int8 pool's are dequantized (float32)."""
+    g = pool[name][:, index]
+    sc = pool.get(_SCALE[name])
+    if sc is not None:
+        g = g.astype(jnp.float32) * sc[:, index][..., None]
+    return g
+
+
+# -- write --------------------------------------------------------------------
+
+def _pool_write(pool: jnp.ndarray, layer: int, bi, oi, value):
+    """The paged pool ``[L, N, BS, ...]`` with ``value`` written at
+    ``[layer, bi, oi]``: a scatter into the whole array, which XLA does in
+    place on a donated or carried pool — no plane is cut out and none is
+    stacked back."""
+    with jax.named_scope("kv.write"):
+        return pool.at[layer, bi, oi].set(value)
+
+
+def _cache_write(cache: jnp.ndarray, layer: int, item, positions):
+    """The dense cache ``[L, B, S, KH, D]`` with ``item`` ``[B, T, KH, D]``
+    written at ``layer``, each row's ``T`` tokens from that row's first
+    position on: ``dynamic_update_slice`` into the whole array at batch 1,
+    a scatter with the same clamp of the start over several rows."""
+    b, t = item.shape[:2]
+    with jax.named_scope("kv.write"):
+        if b == 1:
+            return jax.lax.dynamic_update_slice(
+                cache, item[None], (layer, 0, positions[0, 0], 0, 0))
+        start = jnp.clip(positions[:, :1], 0, cache.shape[2] - t)
+        return cache.at[layer, jnp.arange(b)[:, None],
+                        start + jnp.arange(t)].set(item)
+
+
+def write(kv: dict, layer, k, v, entries, decode: bool) -> dict:
+    """``kv`` with this layer's fresh rows written at plane ``layer``, where
+    ``entries`` ``[B, T]`` says. ``k`` and ``v`` are per-head keys and values
+    ``[B, T, KH, D]``, or latent rows and their rotated keys ``[B, T, width]``
+    (no head axis: the cache keeps ONE row for all heads).
+
+    Paged: the rows are scattered into the lanes' physical pool blocks. The
+    pool ``[L, N_BLOCKS, BS, ...]`` is shared by all sequences — prefix
+    blocks can be referenced by many tables (prefix reuse). An int8 pool
+    quantizes the write per (token, head) vector. ``decode`` writes one row a
+    lane; otherwise a multi-token VERIFY (speculative decoding): all T
+    window tokens are written in one shot. Rejected draft positions simply
+    hold garbage after the window — attention masks by position, and the
+    next window's writes overwrite them (paged scratch re-splice semantics).
+
+    Dense ``[L, B, S, ...]``: a decode step's row at each lane's position; a
+    CHUNK at its PER-ROW offset — graph shapes are (C, S) no matter how long
+    the prompt is; the engine admits chunks at batch 1, but the signature
+    accepts [B, C] positions, and row 0's offset applied to every row would
+    write other rows' chunks at the wrong cache slots (silently wrong
+    logits), so the write is per row; a whole prompt at [0, t)."""
+    if not is_paged(kv):
+        if k.ndim == 3:       # a latent row under the cache's one-head axis
+            return dict(
+                kv, k=_cache_write(kv["k"], layer, k[:, :, None], entries),
+                v=_cache_write(kv["v"], layer, v[:, :, None], entries))
+        return dict(kv, k=_cache_write(kv["k"], layer, k, entries),
+                    v=_cache_write(kv["v"], layer, v, entries))
+    b = entries.shape[0]
+    table = kv[TABLE]                              # [B, MB]
+    bs = kv["k"].shape[2]                          # [L, N, BS, ...]
+    if k.ndim == 3:
+        # a decode step's latent row (``hybrid.mla_block`` refuses a verify
+        # window over latents)
+        pos = entries[:, 0]
+        bi, oi = table[jnp.arange(b), pos // bs], pos % bs
+        return dict(kv, k=_pool_write(kv["k"], layer, bi, oi, k[:, 0, None]),
+                    v=_pool_write(kv["v"], layer, bi, oi, v[:, 0, None]))
+    if decode:
+        pos = entries[:, 0]                        # [B]
+        bi = table[jnp.arange(b), pos // bs]
+        k, v = k[:, 0], v[:, 0]                    # [B, KH, D]
+    else:
+        pos = entries                              # [B, T]
+        bi = jnp.take_along_axis(table, pos // bs, axis=1)
+    oi = pos % bs
+    with jax.named_scope("kv.write"):
+        rows = pool_rows(kv, k, v)                 # [.., KH, D], [.., KH]
+    kv = dict(kv)
+    for name, value in rows:
+        kv[name] = _pool_write(kv[name], layer, bi, oi, value)
+    return kv
+
+
+# -- attend -------------------------------------------------------------------
+
+def attend(kv: dict, layer, q, k, v, entries, cache_len, decode: bool,
+           mesh=None):
+    """Attention of this layer's queries ``q`` ``[B, T, H, D]`` over what
+    :func:`write` left at plane ``layer``; ``k`` and ``v`` are the fresh rows
+    themselves, which a whole-prompt prefill attends without reading back.
+
+    Paged: a decode step (T = 1) is block-table paged attention over the
+    prefix, an int8 pool dequantized after the block read; a verify window's
+    queries each attend over their own absolute-position prefix. Dense: a
+    decode step over the prefix of its lane; a chunk (``cache_len`` given)
+    over prefix + chunk with the absolute-position mask; a whole prompt,
+    causal within itself."""
+    if is_paged(kv):
+        scales = (kv[_SCALE["k"]], kv[_SCALE["v"]]) \
+            if _SCALE["k"] in kv else ()
+        with jax.named_scope("attn.core"):
+            if decode:
+                return paged_attention_dispatch(
+                    q, kv["k"], kv["v"], kv[TABLE], cache_len, *scales,
+                    mesh=mesh, layer=layer)
+            return paged_verify_attention(
+                q, kv["k"], kv["v"], kv[TABLE], entries, *scales,
+                layer=layer)
+    if not decode and cache_len is None:
+        with jax.named_scope("attn.core"):
+            return attention(q, k, v, causal=True, mesh=mesh)
+    if decode:
+        # one layer's plane, for a decode attention that takes ``[B, S, KH,
+        # D]``: an XLA consumer fuses the slice; the ragged pallas kernel has
+        # it materialised. Chunked prefill cuts no plane: its kernel reads
+        # the cache at its layer (the XLA form it falls back to slices
+        # there, under the same scope)
+        with jax.named_scope("kv.slice"):
+            k_cache, v_cache = kv["k"][layer], kv["v"][layer]
+        with jax.named_scope("attn.core"):
+            return decode_attention(q, k_cache, v_cache, cache_len,
+                                    mesh=mesh)
+    with jax.named_scope("attn.core"):
+        return chunk_prefill_attention(q, kv["k"], kv["v"], entries,
+                                       layer=layer, mesh=mesh)
+
+
+def latent_attend(kv: dict, layer, q_lat, q_rope, cache_len, scale):
+    """A decode step of latent attention over the pool: the queries, already
+    absorbed into the latent's space, over the lanes' latent rows and their
+    rotated keys at plane ``layer`` (``ops.latent_attention``)."""
+    return paged_latent_attention(q_lat, q_rope, kv["k"], kv["v"],
+                                  kv[TABLE], cache_len, layer, scale)
+
+
+def latent_rows(kv: dict, layer):
+    """Every row of the batch-1 dense scratch at plane ``layer``:
+    ``(latents [S, mla_latent], rotated keys [S, mla_rope])``, for a chunk
+    that expands keys and values from them."""
+    with jax.named_scope("kv.slice"):
+        return kv["k"][layer, 0, :, 0], kv["v"][layer, 0, :, 0]
+
+
+# -- state a lane --------------------------------------------------------------
+
+def lane_read(kv: dict, plane: int):
+    """``(state [B, H, d, d], convolution tail [B, K-1, 3 H d])`` of the
+    ``plane``-th KDA layer, every lane's."""
+    return kv["kda_state"][plane], kv["kda_conv"][plane]
+
+
+def lane_states(kv: dict):
+    """Every KDA layer's state, whole ``[P, B, H, d, d]``: what the Pallas
+    step updates in place at its plane."""
+    return kv["kda_state"]
+
+
+def lane_write(kv: dict, plane: int, tail, state=None, states=None) -> dict:
+    """``kv`` with the ``plane``-th KDA layer's state and convolution tail
+    replaced: ``state`` ``[B, H, d, d]`` written at the plane, or ``states``
+    the whole array as a step in place left it."""
+    if states is None:
+        states = kv["kda_state"].at[plane].set(state)
+    return dict(kv, kda_state=states,
+                kda_conv=kv["kda_conv"].at[plane].set(tail))

@@ -19,10 +19,11 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import attention, decode_attention
+from ..ops.attention import attention
 from ..ops.norms import rms_norm
-from ..ops.quant import maybe_matmul, quantize_kv
+from ..ops.quant import maybe_matmul
 from ..ops.rotary import apply_rope, rope_table
+from . import kvstate
 
 Params = dict[str, Any]
 
@@ -288,7 +289,7 @@ def init_decoder(rng: jax.Array, cfg: DecoderConfig) -> Params:
         if cfg.n_experts:
             from .moe import MoeConfig, init_moe_layer
             layer["moe"] = init_moe_layer(
-                jax.random.fold_in(nxt(), li), _moe_cfg(cfg))
+                jax.random.fold_in(nxt(), li), moe_cfg(cfg))
             nxt(), nxt()   # keep the rng schedule aligned with dense
         else:
             layer["w_gate"] = _dense_init(nxt(), cfg.dim, cfg.hidden_dim, dt)
@@ -334,7 +335,8 @@ def init_exit_gate(rng: jax.Array, cfg: DecoderConfig) -> Params:
     return {"w": w[:, 0], "b": jnp.zeros((1,), jnp.float32)}
 
 
-def _moe_cfg(cfg: DecoderConfig):
+def moe_cfg(cfg: DecoderConfig):
+    """The expert layer's own config (``models.moe.MoeConfig``) of ``cfg``."""
     from .moe import MoeConfig
     return MoeConfig(dim=cfg.dim,
                      hidden_dim=cfg.moe_hidden_dim or cfg.hidden_dim,
@@ -347,22 +349,6 @@ def _moe_cfg(cfg: DecoderConfig):
                      n_groups=cfg.moe_groups, top_groups=cfg.moe_top_groups,
                      renormalise=cfg.moe_renormalise,
                      gate_scale=cfg.moe_gate_scale)
-
-
-def init_kv_cache(cfg: DecoderConfig, batch: int, max_len: int = 0,
-                  dtype=None) -> Params:
-    """Contiguous per-sequence KV cache: k/v [L, B, S, KH, D], ``L`` the
-    depth of the KV state (``cfg.kv_layers``)."""
-    s = max_len or cfg.max_seq_len
-    dt = dtype or cfg.dtype
-    if cfg.layer_group:
-        # a row as ``kv_row`` has it, and the KDA layers' state beside it
-        from .hybrid import init_lane_state
-        rows = {name: jnp.zeros((cfg.kv_layers, batch, s) + row, dtype=dt)
-                for name, row in zip(("k", "v"), cfg.kv_row)}
-        return {**rows, **init_lane_state(cfg, batch)}
-    shape = (cfg.kv_layers, batch, s, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype=dt), "v": jnp.zeros(shape, dtype=dt)}
 
 
 def _act(x: jnp.ndarray, kind: str) -> jnp.ndarray:
@@ -395,40 +381,6 @@ LOOP_SCOPES = ("loop.norm", "loop.gate", "loop.select")
 SUMMARY_SCOPES = ("kv.summarise",)
 
 
-def _pool_write(pool: jnp.ndarray, layer_idx: int, bi, oi, value):
-    """The paged pool ``[L, N, BS, ...]`` with ``value`` written at
-    ``[layer_idx, bi, oi]``: a scatter into the whole array, which XLA
-    does in place on a donated or carried pool — no plane is cut out and
-    none is stacked back."""
-    with jax.named_scope("kv.write"):
-        return pool.at[layer_idx, bi, oi].set(value)
-
-
-def _cache_write(cache: jnp.ndarray, layer_idx: int, item, positions):
-    """The dense cache ``[L, B, S, KH, D]`` with ``item`` ``[B, T, KH, D]``
-    written at ``layer_idx``, each row's ``T`` tokens from that row's first
-    position on: ``dynamic_update_slice`` into the whole array at batch 1,
-    a scatter with the same clamp of the start over several rows."""
-    b, t = item.shape[:2]
-    with jax.named_scope("kv.write"):
-        if b == 1:
-            return jax.lax.dynamic_update_slice(
-                cache, item[None], (layer_idx, 0, positions[0, 0], 0, 0))
-        start = jnp.clip(positions[:, :1], 0, cache.shape[2] - t)
-        return cache.at[layer_idx, jnp.arange(b)[:, None],
-                        start + jnp.arange(t)].set(item)
-
-
-def _cache_read(cache: jnp.ndarray, layer_idx: int):
-    """One layer's plane of a dense cache, for a decode attention that
-    takes ``[B, S, KH, D]``: an XLA consumer fuses the slice; the ragged
-    pallas kernel has it materialised. Chunked prefill cuts no plane: its
-    kernel reads the cache at its layer (``chunk_prefill_attention``; the XLA
-    form it falls back to slices there, under the same scope)."""
-    with jax.named_scope("kv.slice"):
-        return cache[layer_idx]
-
-
 def _pre_norm(x: jnp.ndarray, weight: jnp.ndarray, cfg: DecoderConfig,
               compute_dtype):
     """A sub-layer's input norm. ``compute_dtype`` is what the sub-layer
@@ -443,10 +395,11 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
                 kv_cache: Optional[Params], layer_idx: int,
                 cache_len: Optional[jnp.ndarray], decode: bool,
                 mesh=None, compute_dtype=None, n_valid=None):
-    """One layer's attention. Returns ``(x, kv_cache)``: the cache dict is
-    carried WHOLE from layer to layer — every branch writes this layer's
-    k/v into the ``[L, ...]`` arrays in place and reads them at
-    ``layer_idx``; nothing is sliced out and re-stacked."""
+    """One layer's attention: project, rotate, ``kvstate.write``,
+    ``kvstate.attend``, project out. Returns ``(x, kv_cache)``: the cache
+    dict is carried WHOLE from layer to layer — this layer's k/v are written
+    into the ``[L, ...]`` arrays in place and read at ``layer_idx``; nothing
+    is sliced out and re-stacked."""
     b, t, _ = x.shape
     h = _pre_norm(x, layer["attn_norm"], cfg, compute_dtype)
     if cfg.layer_group:
@@ -478,7 +431,8 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
     if cache_len is not None:
         cache_len = cfg.kv_entries(cache_len)
     if cfg.attn_window and kv_cache is not None and (
-            "table" not in kv_cache and (decode or cache_len is None)):
+            not kvstate.is_paged(kv_cache)
+            and (decode or cache_len is None)):
         raise NotImplementedError(
             "attn_window over a dense cache is built for chunked prefill "
             "alone (the paged engine's scratch): a dense decode cache has "
@@ -493,84 +447,13 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
                     cfg.attn_window, cfg.attn_chunk)
             else:
                 out = attention(q, k, v, causal=True, mesh=mesh)
-    elif "table" in kv_cache:
-        # paged: scatter the window's k/v into the slots' physical pool
-        # blocks, then attend over each slot's block table. Pool layout
-        # [L, N_BLOCKS, BS, KH, D] is shared by all sequences — prefix
-        # blocks can be referenced by many tables (prefix reuse). An int8
-        # pool ("k_scale" present) quantizes the write per (token, head)
-        # vector and the attention dequantizes after the block read.
-        #
-        # decode (T = 1): block-table paged attention over the prefix.
-        # Otherwise a multi-token VERIFY (speculative decoding): all T
-        # window tokens are written in one shot and each query attends
-        # over its own absolute-position prefix. Rejected draft positions
-        # simply hold garbage KV after the window — attention masks by
-        # position, and the next window's writes overwrite them (paged
-        # scratch re-splice semantics).
-        from ..ops.attention import (paged_attention_dispatch,
-                                     paged_verify_attention)
-        table = kv_cache["table"]                      # [B, MB]
-        bs = kv_cache["k"].shape[2]                    # [L,N,BS,KH,D]
-        if decode:
-            pos = entries[:, 0]                        # [B]
-            bi = table[jnp.arange(b), pos // bs]
-            k, v = k[:, 0], v[:, 0]                    # [B,KH,D]
-        else:
-            pos = entries                              # [B,T]
-            bi = jnp.take_along_axis(table, pos // bs, axis=1)
-        oi = pos % bs
-        kv_cache = dict(kv_cache)
-        scales = ()
-        if "k_scale" in kv_cache:
-            with jax.named_scope("kv.write"):
-                k, sk = quantize_kv(k)                 # [..,KH,D], [..,KH]
-                v, sv = quantize_kv(v)
-            for name, sc in (("k_scale", sk), ("v_scale", sv)):
-                kv_cache[name] = _pool_write(kv_cache[name], layer_idx,
-                                             bi, oi, sc)
-            scales = (kv_cache["k_scale"], kv_cache["v_scale"])
-        kv_cache["k"] = _pool_write(kv_cache["k"], layer_idx, bi, oi, k)
-        kv_cache["v"] = _pool_write(kv_cache["v"], layer_idx, bi, oi, v)
-        with jax.named_scope("attn.core"):
-            if decode:
-                out = paged_attention_dispatch(
-                    q, kv_cache["k"], kv_cache["v"], table, cache_len,
-                    *scales, mesh=mesh, layer=layer_idx)
-            else:
-                out = paged_verify_attention(
-                    q, kv_cache["k"], kv_cache["v"], table, entries,
-                    *scales, layer=layer_idx)
     else:
-        # dense cache [L, B, S, KH, D]. Decode: this token's k/v at each
-        # row's position, then attention over the prefix. CHUNKED prefill
-        # (cache_len given): this chunk at its PER-ROW offset, then
-        # attention over prefix + chunk with the absolute-position mask —
-        # graph shapes are (C, S) no matter how long the prompt is; the
-        # engine admits chunks at batch 1, but the signature accepts
-        # [B, C] positions, and row 0's offset applied to every row would
-        # write other rows' chunks at the wrong cache slots (silently
-        # wrong logits), so the write is per row. Whole-prompt prefill:
-        # [0, t), then causal attention within the prompt itself.
-        kv_cache = dict(
-            kv_cache,
-            k=_cache_write(kv_cache["k"], layer_idx, k, entries),
-            v=_cache_write(kv_cache["v"], layer_idx, v, entries))
-        if not decode and cache_len is None:
-            with jax.named_scope("attn.core"):
-                out = attention(q, k, v, causal=True, mesh=mesh)
-        elif decode:
-            k_cache = _cache_read(kv_cache["k"], layer_idx)
-            v_cache = _cache_read(kv_cache["v"], layer_idx)
-            with jax.named_scope("attn.core"):
-                out = decode_attention(q, k_cache, v_cache, cache_len,
-                                       mesh=mesh)
-        else:
-            from ..ops.attention import chunk_prefill_attention
-            with jax.named_scope("attn.core"):
-                out = chunk_prefill_attention(
-                    q, kv_cache["k"], kv_cache["v"], entries,
-                    layer=layer_idx, mesh=mesh)
+        # whatever form the cache has (``models.kvstate``): this layer's
+        # keys and values go where their entries say, then its queries
+        # attend over what is written
+        kv_cache = kvstate.write(kv_cache, layer_idx, k, v, entries, decode)
+        out = kvstate.attend(kv_cache, layer_idx, q, k, v, entries,
+                             cache_len, decode, mesh)
 
     with jax.named_scope("attn.out"):
         out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)
@@ -592,9 +475,9 @@ def _mlp_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
         # over many; ``aux`` is the experts its tokens chose
         from .moe import SORTED_MIN_TOKENS, moe_ffn_held, moe_ffn_sorted
         if h.shape[0] * h.shape[1] > SORTED_MIN_TOKENS:
-            y, picks = moe_ffn_sorted(layer["moe"], h, _moe_cfg(cfg))
+            y, picks = moe_ffn_sorted(layer["moe"], h, moe_cfg(cfg))
         else:
-            y, picks = moe_ffn_held(layer["moe"], h, _moe_cfg(cfg), live)
+            y, picks = moe_ffn_held(layer["moe"], h, moe_cfg(cfg), live)
         with jax.named_scope("moe.combine"):
             return x + y, {"picks": picks}
     if cfg.n_experts and not cfg.moe_routed:
@@ -607,12 +490,12 @@ def _mlp_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
         # told what it holds does), a wide call sorts its rows by expert
         n_tokens = h.shape[0] * h.shape[1]
         if serving and takes_held_form(layer["moe"], n_tokens, live, mesh):
-            y, picks = moe_ffn_held(layer["moe"], h, _moe_cfg(cfg), live)
+            y, picks = moe_ffn_held(layer["moe"], h, moe_cfg(cfg), live)
             aux = {"picks": picks}
         elif serving and takes_sorted_form(layer["moe"], n_tokens, mesh):
-            y, aux = moe_ffn_sorted(layer["moe"], h, _moe_cfg(cfg)), None
+            y, aux = moe_ffn_sorted(layer["moe"], h, moe_cfg(cfg)), None
         else:
-            y, aux = moe_ffn(layer["moe"], h, _moe_cfg(cfg),
+            y, aux = moe_ffn(layer["moe"], h, moe_cfg(cfg),
                              ep_sharded=False)
         with jax.named_scope("moe.combine"):
             return x + y, aux
@@ -801,8 +684,8 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
     # (silent long-context degradation, no error) — catch the static-shape
     # mismatch at trace time instead
     rope_len = cfg.max_seq_len
-    if kv_cache is not None and "table" not in kv_cache:
-        cache_s = kv_cache["k"].shape[2]
+    if kv_cache is not None:
+        cache_s = kvstate.dense_len(kv_cache)
         if cache_s > rope_len:
             raise ValueError(
                 f"kv cache length {cache_s} exceeds rope table "

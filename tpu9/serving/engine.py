@@ -42,14 +42,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.transformer import DecoderConfig, init_kv_cache
+from ..models.transformer import DecoderConfig
 from ..observability.metrics import Metrics
 from ..observability.trace import PhaseTotals, phase, tracer
 from ..ops.sampling import sample_logits
 from ..utils.aio import reap
 from .flight import maybe as flight_maybe
 from .graphs import GraphFactory
-from .paged_kv import kv_block_bytes
 from .schedule import WindowScheduler
 
 Params = dict[str, Any]
@@ -396,6 +395,13 @@ class InferenceEngine:
         if self.kv_quant and not self.paged:
             raise ValueError("kv_quant='int8' requires the paged engine "
                              "(kv_block_size > 0)")
+        # imported here, as ``stats`` does, and not at the module's head:
+        # with the import there a one-chip runner's bring-up and its first
+        # foreign program (the benchmark's reference) read 1.5-9 s longer
+        # on the chip, run after run, and with it here they read the
+        # parent's (PR 51, PERF.md §6: found by bisection, the import-time
+        # effect itself not found)
+        from ..models import kvstate
         if self.paged:
             from .kvpool import KvPool
             bs = engine_cfg.kv_block_size
@@ -444,11 +450,12 @@ class InferenceEngine:
             # before splicing into pool blocks — ONE lane, not B of them
             # (a row a cache ENTRY: ``max_seq_len`` for plain attention)
             from .paged_kv import scratch_len
-            self._scratch = policy.place_kv(init_kv_cache(
+            self._scratch = policy.place_kv(kvstate.init_kv_cache(
                 cfg, 1, scratch_len(cfg, s, chunk)))
         else:
             self.pool = None
-            self.kv_cache = policy.place_kv(init_kv_cache(cfg, b, s))
+            self.kv_cache = policy.place_kv(
+                kvstate.init_kv_cache(cfg, b, s))
             self.allocator = None
             self.prefix_cache = None
         # every traced/compiled graph lives in the factory (serving.graphs)
@@ -545,9 +552,8 @@ class InferenceEngine:
         # int8 stacks, a mesh, a test's tiny experts — no window says and
         # the counters stay 0). A dense decoder has none of them
         if cfg.layer_group:
-            from ..models.hybrid import lane_state_bytes, lane_state_shapes
-            self._lane_state_names = tuple(lane_state_shapes(cfg, b))
-            self._state_bytes = lane_state_bytes(cfg, b)
+            self._lane_state_names = tuple(kvstate.lane_shapes(cfg, b))
+            self._state_bytes = kvstate.lane_bytes(cfg, b)
         if cfg.layer_group or cfg.n_experts:
             self._stats.update(moe_local_picks=0, moe_token_layers=0,
                                moe_held_touched=0, moe_step_layers=0)
@@ -777,26 +783,10 @@ class InferenceEngine:
     def _pool_dict(self) -> dict:
         """The kv pool's array view (payload + scales, no table) — the
         pytree the splice/gather/fused-group graphs take and return."""
-        keys = ("k", "v", "k_scale", "v_scale") if self.kv_quant \
-            else ("k", "v")
-        return {k: self.kv_cache[k] for k in keys}
+        return {k: self.kv_cache[k] for k in self.pool.wire_names()}
 
     def _set_pool(self, pool: dict) -> None:
         self.kv_cache.update(pool)
-
-    def bench_reset_slots(self, ctx0: int, budget: int) -> None:
-        """Raw-loop benchmarking support: give every slot physical blocks
-        covering [0, ctx0 + budget) so a paged decode window moves the
-        same HBM traffic it would in production (an all-zero table would
-        read one block B times and fake the bandwidth numbers)."""
-        if not self.paged:
-            return
-        for slot in range(self.ecfg.max_batch):
-            if self._slot_blocks[slot]:
-                self.allocator.release(self._slot_blocks[slot])
-                self._slot_blocks[slot] = []
-            self._ensure_slot_blocks(slot, ctx0 + budget + 1)
-            self._host_len[slot] = ctx0
 
     def _worst_case_tokens(self, req: _Request) -> int:
         # prompt + full generation budget + in-flight overshoot slack,
@@ -1341,8 +1331,9 @@ class InferenceEngine:
         # the depth of the KV state and what one token costs the pool,
         # whole model: a looped decoder keeps a plane a (pass, layer)
         out["kv_layers"] = self.cfg.kv_layers
-        out["kv_bytes_per_token"] = kv_block_bytes(self.cfg, 1,
-                                                   self.kv_quant)
+        from ..models import kvstate
+        out["kv_bytes_per_token"] = kvstate.block_bytes(self.cfg, 1,
+                                                        self.kv_quant)
         if self.cfg.looped:
             out["loop_steps"] = self.cfg.loop_steps
             out["loop_exit_hist"] = list(self._loop_exit_hist)
@@ -1522,7 +1513,7 @@ class InferenceEngine:
         n = len(req.prompt)
         with phase("engine.admit.plan", totals):
             if self._slot_blocks[slot]:
-                # leftovers (bench_reset_slots / defensive): return them
+                # leftovers (defensive): return them
                 self.allocator.release(self._slot_blocks[slot])
                 self._slot_blocks[slot] = []
             self._slot_reserved[slot] = self.allocator.reserve(

@@ -110,22 +110,22 @@ def weight_bytes(cfg, quantized: bool) -> int:
 def kv_cache_bytes(cfg, max_batch: int, max_seq: int,
                    kv_quant: bool = False) -> int:
     """Dense-equivalent KV bytes: ``max_batch`` sequences of ``max_seq``
-    tokens, priced by the SAME helper the engine's pool sizing divides by
-    (``paged_kv.kv_block_bytes`` — one arithmetic, no drift when modes
-    are added) — per cache ENTRY such a sequence can address
-    (``kv_entries_peak``: a token, for plain attention)."""
-    from .paged_kv import kv_block_bytes
-    return max_batch * kv_block_bytes(cfg, cfg.kv_entries_peak(max_seq),
-                                      kv_quant)
+    tokens, priced by the SAME sum over the pool's planes that the engine's
+    pool sizing divides by (``models.kvstate.block_bytes`` — one arithmetic,
+    no drift when modes are added) — per cache ENTRY such a sequence can
+    address (``kv_entries_peak``: a token, for plain attention)."""
+    from ..models import kvstate
+    return max_batch * kvstate.block_bytes(
+        cfg, cfg.kv_entries_peak(max_seq), kv_quant)
 
 
 def lane_state_bytes(cfg, max_batch: int) -> int:
     """Bytes of the state that layers of linear attention keep for
     ``max_batch`` running sequences, beside the cache and whatever their
-    length (``models.hybrid.lane_state_shapes``: 0 for a decoder without such
+    length (``models.kvstate.lane_shapes``: 0 for a decoder without such
     layers). Never sharded: a mesh is refused with it."""
-    from ..models.hybrid import lane_state_bytes as of
-    return of(cfg, max_batch)
+    from ..models import kvstate
+    return kvstate.lane_bytes(cfg, max_batch)
 
 
 def hbm_budget(preset: str, tpu: "str | TpuSpec", *, max_batch: int = 8,
@@ -170,9 +170,9 @@ def hbm_budget(preset: str, tpu: "str | TpuSpec", *, max_batch: int = 8,
         if not kv_block_size:
             raise ValueError("a pinned kv_pool_blocks is priced by its "
                              "kv_block_size: give both")
-        from .paged_kv import kv_block_bytes
+        from ..models import kvstate
         kv = (kv_pool_blocks + 1) \
-            * kv_block_bytes(cfg, kv_block_size, kv_quant) / kv_shard
+            * kvstate.block_bytes(cfg, kv_block_size, kv_quant) / kv_shard
     else:
         kv = kv_cache_bytes(cfg, max_batch, max_seq_len) / kv_shard
     # paged engine's batch-1 dense prefill scratch rides on one chip's

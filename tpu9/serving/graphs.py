@@ -30,9 +30,10 @@ from typing import Any, Iterator
 import jax
 import jax.numpy as jnp
 
-from ..models.hybrid import HYBRID_SCOPES, lane_state_shapes
+from ..models import kvstate
+from ..models.hybrid import HYBRID_SCOPES
 from ..models.transformer import (DEVICE_SCOPES, LOOP_SCOPES, SUMMARY_SCOPES,
-                                  decoder_forward, init_kv_cache)
+                                  decoder_forward)
 from ..ops.sampling import sample_logits
 
 Params = dict[str, Any]
@@ -331,7 +332,8 @@ class GraphFactory:
                 # real token and the per-layer k/v for the prefix.
                 logits, cache = decoder_forward(
                     params, tokens, cfg,
-                    kv_cache=init_kv_cache(cfg, 1, bucket), decode=False,
+                    kv_cache=kvstate.init_kv_cache(cfg, 1, bucket),
+                    decode=False,
                     mesh=policy.mesh)
                 last = logits[0, length - 1]
                 return last, policy.constrain_kv(cache)
@@ -380,7 +382,7 @@ class GraphFactory:
             scratch = dict(scratch, **{
                 name: jnp.where(offset == 0, jnp.zeros_like(scratch[name]),
                                 scratch[name])
-                for name in lane_state_shapes(self.cfg, 1)})
+                for name in kvstate.lane_shapes(self.cfg, 1)})
             logits, scratch, picks = decoder_forward(
                 params, tok_row[None, :], self.cfg, positions=positions,
                 kv_cache=scratch, cache_len=offset + width, decode=False,
@@ -423,14 +425,9 @@ class GraphFactory:
                     scratch_k[:, 0], source(j), bs, axis=1)
                 blk_v = jax.lax.dynamic_slice_in_dim(
                     scratch_v[:, 0], source(j), bs, axis=1)
-                if "k_scale" in pool:
-                    from ..ops.quant import quantize_kv
-                    blk_k, sk = quantize_kv(blk_k)  # [L,bs,KH,D], [L,bs,KH]
-                    blk_v, sv = quantize_kv(blk_v)
-                    pool["k_scale"] = pool["k_scale"].at[:, phys[j]].set(sk)
-                    pool["v_scale"] = pool["v_scale"].at[:, phys[j]].set(sv)
-                pool["k"] = pool["k"].at[:, phys[j]].set(blk_k)
-                pool["v"] = pool["v"].at[:, phys[j]].set(blk_v)
+                # [L,bs,KH,D] as the pool stores them (int8: and [L,bs,KH])
+                for name, rows in kvstate.pool_rows(pool, blk_k, blk_v):
+                    pool[name] = pool[name].at[:, phys[j]].set(rows)
             return self.policy.constrain_kv(pool)
 
     # -- the summarise of a closed window (``attn_window``) -------------------
@@ -564,16 +561,12 @@ class GraphFactory:
                 # slice it off so the densified prefix has the exact
                 # scratch shape (an S+BS-wide scratch trips the rope-table
                 # width validation when max_seq_len == the rope limit)
-                def one(p, sc):
-                    g = p[:, row]                    # [L, MB, BS, KH, D]
-                    if sc is not None:
-                        g = g.astype(jnp.float32) * sc[:, row][..., None]
-                    l, mb_, bs, kh, d = g.shape
+                def one(name):
+                    g = kvstate.read_blocks(pool, name, row)
+                    l, mb_, bs, kh, d = g.shape      # [L, MB, BS, KH, D]
                     return g.astype(dt).reshape(
                         l, 1, mb_ * bs, kh, d)[:, :, :s]
-                return policy.constrain_kv(
-                    {"k": one(pool["k"], pool.get("k_scale")),
-                     "v": one(pool["v"], pool.get("v_scale"))})
+                return policy.constrain_kv({"k": one("k"), "v": one("v")})
 
             return jax.jit(gather)
 
@@ -658,17 +651,15 @@ class GraphFactory:
                         jax.ShapeDtypeStruct((g, c), i32), 0, 0,
                         jax.ShapeDtypeStruct(self.splice_shape(g), i32)))
             if self.cfg.layer_group:
-                names = tuple(lane_state_shapes(self.cfg, 1))
+                names = tuple(kvstate.lane_shapes(self.cfg, 1))
                 akv = policy.abstract(kv_cache, kv=True)
                 yield ("lanesplice", self.lane_splice_fn(),
                        ({n: akv[n] for n in names},
                         {n: ascratch[n] for n in names}, 0))
         else:
-            cfg = self.cfg
             for bucket in buckets:
                 pre = jax.ShapeDtypeStruct(
-                    (cfg.kv_layers, 1, bucket, cfg.n_kv_heads,
-                     cfg.head_dim), cfg.dtype)
+                    *kvstate.dense_shapes(self.cfg, 1, bucket)["k"])
                 adense = policy.abstract(
                     {"k": kv_cache["k"], "v": kv_cache["v"]}, kv=True)
                 yield (bucket, self.prefill_fn(bucket),
@@ -779,8 +770,9 @@ def abstract_state(cfg, ecfg, policy, kv_quant: bool = False) -> dict:
     lowering_jobs`: the kv_cache/pool/scratch ``ShapeDtypeStruct`` trees
     an engine of this (model, engine-config) pair would hold, without
     allocating a byte. Shapes come from the same sources the engine uses
-    (``KvPool`` for the paged pool, ``init_kv_cache`` via ``eval_shape``
-    for dense/scratch), so graphcheck lowers EXACTLY the engine's graphs.
+    (``KvPool`` for the paged pool, ``kvstate.init_kv_cache`` via
+    ``eval_shape`` for dense/scratch), so graphcheck lowers EXACTLY the
+    engine's graphs.
     Returns ``{"kv_cache", "pool", "scratch", "mb", "rng"}`` (paged) or
     the dense equivalents (empty pool/scratch, mb=0)."""
     rng = jax.eval_shape(lambda: jax.random.PRNGKey(0))
@@ -788,16 +780,14 @@ def abstract_state(cfg, ecfg, policy, kv_quant: bool = False) -> dict:
         from .kvpool import KvPool
         mgr = KvPool(cfg, ecfg, kv_quant, policy)
         kv_cache = mgr.array_specs()
-        # the pool's own arrays: not the table, nor state kept by lane
-        pool = {k: v for k, v in kv_cache.items()
-                if k != "table" and k not in lane_state_shapes(cfg, 1)}
+        pool = {name: kv_cache[name] for name in mgr.wire_names()}
         from .paged_kv import scratch_len
         chunk = ecfg.prefill_chunk or min(ecfg.prefill_buckets)
-        scratch = jax.eval_shape(lambda: init_kv_cache(
+        scratch = jax.eval_shape(lambda: kvstate.init_kv_cache(
             cfg, 1, scratch_len(cfg, ecfg.max_seq_len, chunk)))
         return {"kv_cache": kv_cache, "pool": pool, "scratch": scratch,
                 "mb": mgr.mb, "rng": rng}
-    kv_cache = jax.eval_shape(
-        lambda: init_kv_cache(cfg, ecfg.max_batch, ecfg.max_seq_len))
+    kv_cache = jax.eval_shape(lambda: kvstate.init_kv_cache(
+        cfg, ecfg.max_batch, ecfg.max_seq_len))
     return {"kv_cache": kv_cache, "pool": {}, "scratch": {}, "mb": 0,
             "rng": rng}

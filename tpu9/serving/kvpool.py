@@ -22,8 +22,8 @@ from typing import Any, Optional
 
 import numpy as np
 
-from .paged_kv import (BlockAllocator, PrefixCache, blocks_for,
-                       kv_block_bytes)
+from ..models import kvstate
+from .paged_kv import BlockAllocator, PrefixCache, blocks_for
 
 Params = dict[str, Any]
 
@@ -150,10 +150,9 @@ class KvPool:
                 # the bf16 pool would have — ~2x the blocks, which is the
                 # whole point (capacity == admission headroom == the
                 # router's kv_blocks signal)
-                from .paged_kv import kv_block_bytes
                 base_blocks = (base_blocks
-                               * kv_block_bytes(cfg, bs, False)
-                               // kv_block_bytes(cfg, bs, True))
+                               * kvstate.block_bytes(cfg, bs, False)
+                               // kvstate.block_bytes(cfg, bs, True))
         # +1: one dedicated TRASH block absorbs splice writes of the
         # padded tail of a non-block-aligned final chunk
         self.n_blocks = base_blocks + 1
@@ -204,30 +203,14 @@ class KvPool:
     def array_shapes(self) -> dict:
         """``name -> (shape, dtype)`` for every pool array — the ONE shape
         source :meth:`init_arrays` allocates from and
-        :meth:`array_specs` abstracts from (they cannot drift)."""
-        import jax.numpy as jnp
-        cfg, ecfg = self.cfg, self.ecfg
-        pool_shape = (cfg.kv_layers, self.n_blocks, ecfg.kv_block_size,
-                      cfg.n_kv_heads, cfg.head_dim)
-        dt = jnp.int8 if self.kv_quant else cfg.dtype
-        shapes = {"k": (pool_shape, dt), "v": (pool_shape, dt),
-                  "table": (self.table_np.shape, jnp.int32)}
-        if cfg.layer_group:
-            # a layer pattern: the pool's rows are the latent cache's
-            # (``kv_row``), and beside the pool the engine owns the KDA
-            # layers' state, a LANE each — no pages, no table
-            from ..models.hybrid import lane_state_shapes
-            for name, row in zip(("k", "v"), cfg.kv_row):
-                shapes[name] = (pool_shape[:3] + row, dt)
-            shapes.update(lane_state_shapes(cfg, ecfg.max_batch))
-        if self.kv_quant:
-            # per-(position, head) f32 absmax scales alongside the pool
-            # (ops.quant.quantize_kv) — same [N, BS, KH] indexing as the
-            # payload so every write/read shares the table math
-            sc_shape = pool_shape[:-1]
-            shapes["k_scale"] = (sc_shape, jnp.float32)
-            shapes["v_scale"] = (sc_shape, jnp.float32)
-        return shapes
+        :meth:`array_specs` abstracts from (they cannot drift): the pool's
+        planes as ``models.kvstate`` has them, the block table, and beside
+        the pool the state the engine keeps by LANE (no pages, no table)."""
+        return {**kvstate.pool_shapes(self.cfg, self.n_blocks,
+                                      self.ecfg.kv_block_size,
+                                      self.kv_quant),
+                kvstate.TABLE: (self.table_np.shape, np.int32),
+                **kvstate.lane_shapes(self.cfg, self.ecfg.max_batch)}
 
     def init_arrays(self) -> Params:
         """The pool's device state: payload (+ int8 scale planes) and the
@@ -235,8 +218,8 @@ class KvPool:
         tp on a mesh; plain single-device arrays otherwise)."""
         kv = {name: self.policy.zeros(shape, dt, name)
               for name, (shape, dt) in self.array_shapes().items()
-              if name != "table"}
-        kv["table"] = self.policy.device_table(self.table_np)
+              if name != kvstate.TABLE}
+        kv[kvstate.TABLE] = self.policy.device_table(self.table_np)
         return kv
 
     def array_specs(self) -> Params:
@@ -269,9 +252,11 @@ class KvPool:
     # -- kvwire export / import (ISSUE 16) -----------------------------------
 
     def wire_names(self) -> list[str]:
-        """Pool arrays that ship on the wire (payload + scale planes;
-        the table is host bookkeeping — block ids are pool-local)."""
-        return [n for n in self.array_shapes() if n != "table"]
+        """The pool's own arrays (payload + scale planes): what the splice,
+        gather and group programs take and return, and what ships on the
+        wire. Not the table, which is host bookkeeping (block ids are
+        pool-local), nor state kept by lane."""
+        return list(kvstate.paged_planes(self.cfg, self.kv_quant))
 
     def export_blocks(self, kv, blocks: list[int], prefix_key: bytes,
                       n_tokens: int) -> bytes:
@@ -315,12 +300,11 @@ class KvPool:
         if nb <= 0 or not key:
             raise kvwire.KvWireError(
                 f"kvwire: empty prefix payload (n_blocks={nb})")
-        shapes = self.array_shapes()
-        for name in self.wire_names():
+        for name, (want, _) in kvstate.pool_shapes(
+                self.cfg, nb, self.ecfg.kv_block_size, self.kv_quant).items():
             if name not in planes:
                 raise kvwire.KvWireError(
                     f"kvwire: payload missing plane {name!r}")
-            want = (shapes[name][0][0], nb) + tuple(shapes[name][0][2:])
             if tuple(planes[name].shape) != want:
                 raise kvwire.KvWireError(
                     f"kvwire: plane {name!r} shape "
@@ -347,12 +331,11 @@ class KvPool:
         kvwire import and the host-tier up-page — one scatter path means
         the MeshPolicy bit-exactness proof covers both."""
         import jax.numpy as jnp
-        shapes = self.array_shapes()
         idx = jnp.asarray(blocks, dtype=jnp.int32)
         new_kv = dict(kv)
-        for name in self.wire_names():
-            arr = jnp.asarray(np.ascontiguousarray(planes[name]),
-                              dtype=shapes[name][1])
+        for name, (_, dt) in kvstate.paged_planes(self.cfg,
+                                                  self.kv_quant).items():
+            arr = jnp.asarray(np.ascontiguousarray(planes[name]), dtype=dt)
             new_kv[name] = new_kv[name].at[:, idx].set(arr)
         # the scatter above lets GSPMD infer an output sharding;
         # place_kv restores the declared head-axis layout
@@ -473,8 +456,8 @@ class KvPool:
         """Flat occupancy/counter snapshot for the ``kvtier_`` stats
         family (bytes price the DEVICE pool dtype for the device side
         and actual numpy bytes for the host side)."""
-        bb = kv_block_bytes(self.cfg, self.ecfg.kv_block_size,
-                            self.kv_quant)
+        bb = kvstate.block_bytes(self.cfg, self.ecfg.kv_block_size,
+                                 self.kv_quant)
         held = self.prefix_cache.held_blocks
         out = {"device_blocks": held, "device_bytes": held * bb,
                "downpages": self.downpages, "uppages": self.uppages,

@@ -45,22 +45,12 @@ def scratch_len(cfg, max_seq_len: int, chunk: int) -> int:
 
 def kv_block_bytes(cfg, block_s: int, quantized: bool = False) -> int:
     """HBM bytes ONE k+v pool block holds across the whole depth of the KV
-    state of ``cfg`` (``kv_layers``: every layer of every pass).
-    The single source of truth the engine's equal-HBM pool sizing, the
-    feasibility gate, and the quant bench all price blocks with — int8
-    blocks carry 1 byte/element plus one f32 absmax scale per
-    (position, head) vector (``tpu9.ops.quant.quantize_kv``)."""
-    import numpy as np
-    if cfg.layer_group:
-        # a latent cache: ONE row a token for all heads (``kv_row``), in the
-        # layers that have a cache at all; never quantized
-        return cfg.kv_layers * block_s * np.dtype(cfg.dtype).itemsize \
-            * sum(heads * width for heads, width in cfg.kv_row)
-    per_vec = cfg.head_dim * (1 if quantized
-                              else np.dtype(cfg.dtype).itemsize)
-    if quantized:
-        per_vec += 4                       # f32 scale alongside the pool
-    return 2 * cfg.kv_layers * block_s * cfg.n_kv_heads * per_vec
+    state of ``cfg`` (``kv_layers``: every layer of every pass): the sum over
+    the pool's planes as ``models.kvstate`` has them — int8 blocks carry
+    1 byte/element plus one f32 absmax scale per (position, head) vector, a
+    latent cache ONE row a token for all heads, never quantized."""
+    from ..models import kvstate
+    return kvstate.block_bytes(cfg, block_s, quantized)
 
 
 @dataclass

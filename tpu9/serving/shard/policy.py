@@ -163,14 +163,17 @@ class MeshPolicy(SingleDevicePolicy):
     def kv_spec(self, name: str, ndim: int):
         """PartitionSpec for one KV-state array by name/rank: payloads
         ``[..., KH, D]`` and scale planes ``[..., KH]`` shard the head
-        axis; tables (int32 block ids) replicate. Public: this IS the
+        axis, where ``models.kvstate`` says it is; tables (int32 block
+        ids) and state kept by lane replicate. Public: this IS the
         declared KV layout contract graphcheck verifies lowered graphs
         against (ISSUE 11)."""
         from jax.sharding import PartitionSpec as P
-        if name == "table" or ndim < 4:
+        from ...models.kvstate import head_axis
+        axis = head_axis(name, ndim)
+        if axis is None:
             return P()
         dims: list = [None] * ndim
-        dims[ndim - 1 if name.endswith("_scale") else ndim - 2] = _HEAD_AXIS
+        dims[axis] = _HEAD_AXIS
         return P(*dims)
 
     def param_specs(self, tree: Any):
