@@ -550,6 +550,67 @@ def test_a_layer_patterns_decode_window_holds_one_expert_kernel_a_layer(
                                    + [family.STEP_MARKER])
 
 
+@pytest.mark.parametrize("width", [512, 2048])
+def test_the_latent_prefill_kernel_compiles_at_the_published_widths(
+        v5e, no_compile_cache, width):
+    """The blocked prefill of latent attention at Kimi-K2.6's widths and the
+    cell's scratch (ISSUE 52): a chunk's or a group's queries, 64 heads,
+    against a 57,344-row scratch six planes deep, read where it lies at a
+    plane that is not the first. Eight heads' weights, queries and float32
+    accumulators a grid step are more VMEM than the default scope, which
+    only the TPU's compiler can refuse; nothing of the scratch's size, no
+    expanded key or value, is kept outside the kernel."""
+    from tpu9.ops import latent_attention
+    one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    heads, rows, planes = 64, 57344, 6
+    attend = jax.jit(lambda qn, qr, c, r, w, at:
+                     latent_attention.blocked_prefill_attention_kernel(
+                         qn, qr, c, r, w, at, 3, 0.1447))
+    compiled = attend.lower(
+        s((width, heads, 128)), s((width, heads, 64)),
+        s((planes, 1, rows, 1, 512)), s((planes, 1, rows, 1, 64)),
+        s((512, heads, 256)), s((), jnp.int32)).compile()
+    assert _kernel_names(compiled.as_text()) == [
+        latent_attention.PREFILL_KERNEL]
+    # the queries and the weights laid out a head at a time, the output back
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
+def test_latent_attention_in_every_layer_holds_its_kernels(
+        v5e, no_compile_cache, monkeypatch):
+    """``kimi-k2.6-l6-ep32`` at its engine's shapes, two layers deep (one
+    dense, one of experts): a decode step is one ``paged_latent_attention``
+    a layer — the call the benchmark counts its steps by — and one
+    ``held_ffn`` over the 12 held experts of 7,168 x 2,048; a chunk and a
+    group are one ``latent_prefill_attention`` a layer and one grouped
+    expert kernel; and the gather of a prefix's pages into the scratch
+    keeps no copy of the pool (4.0 GB of temporaries taken along axis 1)."""
+    from tpu9.ops import latent_attention
+    cfg, family, _, pool, jobs = _decode_programs(
+        v5e, monkeypatch, "kimi-k2.6-l6-ep32", n_layers=2,
+        kinds=("decode", "chunk", "chunkgroup", "g"))
+    assert pool.shape == (2, 4865, 128, 1, 512)
+    seen = {}
+    for key, fn, args in jobs:
+        compiled = fn.lower(*args).compile()
+        seen[key] = sorted(_kernel_names(compiled.as_text()))
+        if key == "gather":
+            assert compiled.memory_analysis().temp_size_in_bytes \
+                < 256 * 2 ** 20
+    assert family.STEP_MARKER == latent_attention.LATENT_KERNEL
+    assert family.PREFILL_KERNEL == latent_attention.PREFILL_KERNEL
+    for k in (1, 8):
+        assert seen[("decode", k)] == sorted(
+            ["held_ffn"] + [family.STEP_MARKER] * cfg.n_layers)
+    for key in (("chunk", 512), ("chunkgroup", 4)):
+        assert seen[key].count(family.PREFILL_KERNEL) == cfg.n_layers
+        assert len(seen[key]) == cfg.n_layers + 1      # + the grouped FFN
+    assert seen["gather"] == []
+
+
 def test_a_looped_decode_program_carries_the_pool_through_its_pass_loop(
         v5e, no_compile_cache, monkeypatch):
     """The looped configuration at its published widths, two layers deep:

@@ -601,7 +601,7 @@ def test_a_slice_of_the_vocabulary_is_the_uncut_heads_rows(params):
     (dict(mla_rope=15), "latent-attention width"),
     (dict(kda_conv=1), "convolution"),
     (dict(kda_gate_bound=0.5), "negative lower bound"),
-    (dict(layer_group=1), "fewer than 2"),
+    (dict(layer_group=1), "no KDA layer to read them"),
     (dict(tie_embeddings=True), "another family"),
     (dict(moe_routed=0), "told which"),
     (dict(moe_held_first=8, n_experts=16), "inside the routed"),

@@ -165,6 +165,8 @@ def test_the_pattern_programs_name_their_scopes(params):
     for program in ("decode_1", f"chunk_{C}", f"chunkgroup_{G}"):
         for scope in hybrid.HYBRID_SCOPES:
             assert maps[program][scope], (program, scope)
+        # the query's low-rank path, which this model lacks
+        assert not set(hybrid.MLA_QUERY_SCOPES) & set(maps[program])
     assert "lanesplice" in engine.graphs.reachable_keys((C,), ())
     plain = InferenceEngine(init_decoder(jax.random.PRNGKey(0), PLAIN),
                             PLAIN, replace(ecfg, prefill_chunk=32,

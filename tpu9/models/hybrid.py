@@ -1,7 +1,10 @@
 """The two attention kinds of a layer PATTERN (``DecoderConfig.layer_group``):
 delta-rule linear attention with a decay a channel (KDA) and latent
-attention (MLA). ``DecoderConfig.layer_kind`` says which layer is which;
-``models.transformer`` walks the layers and calls in here.
+attention (MLA). A pattern is WHICH LAYERS ARE KDA AND WHICH MLA —
+``DecoderConfig.layer_kind`` says it, and a pattern may have no KDA layer at
+all (``layer_group`` 1: MLA in every layer, no state a lane, and a cache the
+prefix cache can share); ``models.transformer`` walks the layers and calls
+in here.
 
 What a running sequence keeps differs by kind, and that is the point:
 
@@ -11,15 +14,22 @@ What a running sequence keeps differs by kind, and that is the point:
   table, no pages.
 - an MLA layer keeps ONE ROW A TOKEN — the latent and its rotated key
   (``DecoderConfig.kv_row``) — addressed by the table like any paged cache. A
-  prefill expands keys and values from the latents; a decode step absorbs the
-  expansions into the query and the output and attends the latents themselves
-  (``ops.latent_attention``).
+  prefill expands keys and values from the latents — over a long scratch a
+  block of keys at a time inside a kernel (``ops.latent_attention.
+  blocked_prefill_attention``), over a short one every row at once; a decode
+  step absorbs the expansions into the query and the output and attends the
+  latents themselves (``ops.latent_attention``). Its query is one full-rank
+  matrix or goes through a latent with a norm of its own
+  (``mla_q_latent``), its output has a sigmoid gate a head or none
+  (``mla_out_gate``), its positions are plain rotary or YaRN's with the
+  attention temperature in the softmax scale (``rope_yarn``, ``mla_mscale``).
 
 Where either lives in the cache dict, and how it is read and written, is
 ``models.kvstate``'s to say; the layers here call it.
 
-The equations, with every assumption, are in the plain reference the
-benchmark holds this to (``benchmark/reference/ling.py``).
+The equations, with every assumption, are in the plain references the
+benchmark holds this to (``benchmark/reference/ling.py``: KDA closed by one
+MLA layer a group; ``benchmark/reference/kimi.py``: MLA in every layer).
 """
 
 from __future__ import annotations
@@ -30,7 +40,9 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import delta_rule
-from ..ops.latent_attention import expanded_attention
+from ..ops.latent_attention import (blocked_prefill_attention,
+                                    blocked_prefill_declined,
+                                    expanded_attention)
 from ..ops.norms import rms_norm
 from ..ops.quant import maybe_matmul
 from ..ops.rotary import apply_rope
@@ -43,6 +55,12 @@ F32 = jnp.float32
 # into query and output at decode) / its attention; the shared expert
 HYBRID_SCOPES = ("attn.kda.proj", "attn.kda.state", "attn.mla.absorb",
                  "attn.mla.core", "moe.shared")
+# the query's low-rank path, emitted only where the query goes through a
+# latent (``mla_q_latent``). Apart from ``HYBRID_SCOPES``, as the loop's and
+# the summarise's scopes are from ``DEVICE_SCOPES``: the benchmark's
+# accepted tests pin that every scope of that tuple is in a group of the
+# hybrid-linear family, whose programs run nothing under this one
+MLA_QUERY_SCOPES = ("attn.mla.q",)
 
 
 def refuse_unbuilt_pattern(cfg) -> None:
@@ -51,9 +69,9 @@ def refuse_unbuilt_pattern(cfg) -> None:
     def refuse(what: str, why: str):
         raise ValueError(f"layer_group={cfg.layer_group} with {what}: {why}")
 
-    if cfg.layer_group < 2:
-        refuse("fewer than 2 layers a group",
-               "a group is KDA layers closed by one MLA layer")
+    if cfg.layer_group < 1:
+        refuse("a negative group", "the last layer of every group of "
+               "layer_group layers is MLA, the others KDA")
     if cfg.looped:
         refuse("a pass loop (loop_steps > 1 or exit_gate)",
                "a lane's KDA state would need a plane a pass; not built")
@@ -72,11 +90,26 @@ def refuse_unbuilt_pattern(cfg) -> None:
         refuse("a latent-attention width that is not set",
                "mla_latent, mla_nope, mla_rope (even) and mla_v are all "
                "needed")
-    if cfg.kda_conv < 2 or cfg.kda_gate_bound >= 0:
+    if cfg.layer_group == 1:
+        if cfg.kda_conv or cfg.kda_gate_bound:
+            refuse(f"kda_conv={cfg.kda_conv}, kda_gate_bound="
+                   f"{cfg.kda_gate_bound}",
+                   "a group of one layer has no KDA layer to read them")
+    elif cfg.kda_conv < 2 or cfg.kda_gate_bound >= 0:
         refuse(f"kda_conv={cfg.kda_conv}, kda_gate_bound="
                f"{cfg.kda_gate_bound}",
                "the short convolution has at least 2 taps and the log-decay "
                "a negative lower bound")
+    if cfg.mla_q_latent < 0 or cfg.mla_mscale <= 0 or (
+            cfg.rope_yarn and (len(cfg.rope_yarn) != 4
+                               or cfg.rope_yarn[0] < 1
+                               or cfg.rope_yarn[1] <= 0
+                               or cfg.rope_yarn[2] <= cfg.rope_yarn[3])):
+        refuse(f"mla_q_latent={cfg.mla_q_latent}, mla_mscale="
+               f"{cfg.mla_mscale}, rope_yarn={cfg.rope_yarn}",
+               "a query latent is a width, the temperature positive, and "
+               "YaRN is (factor >= 1, original positions, beta_fast > "
+               "beta_slow)")
     if cfg.tie_embeddings or cfg.embed_scale or cfg.logit_softcap \
             or cfg.norm_offset or cfg.act != "silu":
         refuse("a descriptor of another family (tied or scaled embeddings, "
@@ -141,13 +174,23 @@ def init_hybrid_layer(rng: jax.Array, cfg, l: int) -> dict:
             "wo": _dense(next(r), h * d, d_model, dt)}
     else:
         dn, dr, dv, dc = cfg.mla_nope, cfg.mla_rope, cfg.mla_v, cfg.mla_latent
+        # the rngs in the order the full-rank, gated layer always drew them
+        rq, rd, ru, rg, ro = (next(r) for _ in range(5))
         layer["mla"] = {
-            "wq": _dense(next(r), d_model, h * (dn + dr), dt),
-            "w_dkv": _dense(next(r), d_model, dc + dr, dt),
+            "w_dkv": _dense(rd, d_model, dc + dr, dt),
             "kv_norm": jnp.ones((dc,), F32),
-            "w_ukv": _dense(next(r), dc, h * (dn + dv), dt),
-            "w_gate": _dense(next(r), d_model, h, dt),
-            "wo": _dense(next(r), h * dv, d_model, dt)}
+            "w_ukv": _dense(ru, dc, h * (dn + dv), dt),
+            "wo": _dense(ro, h * dv, d_model, dt)}
+        if cfg.mla_q_latent:
+            rq, ruq = jax.random.split(rq)
+            layer["mla"].update(
+                w_dq=_dense(rq, d_model, cfg.mla_q_latent, dt),
+                q_norm=jnp.ones((cfg.mla_q_latent,), F32),
+                w_uq=_dense(ruq, cfg.mla_q_latent, h * (dn + dr), dt))
+        else:
+            layer["mla"]["wq"] = _dense(rq, d_model, h * (dn + dr), dt)
+        if cfg.mla_out_gate:
+            layer["mla"]["w_gate"] = _dense(rg, d_model, h, dt)
     if ffn == "experts":
         from .moe import init_moe_layer
         from .transformer import moe_cfg
@@ -241,12 +284,20 @@ def mla_block(p: dict, h: jnp.ndarray, cfg, positions, sin, cos,
     b, t, _ = h.shape
     heads = cfg.n_heads
     dn, dr, dv, dc = cfg.mla_nope, cfg.mla_rope, cfg.mla_v, cfg.mla_latent
-    scale = (dn + dr) ** -0.5
+    scale = (dn + dr) ** -0.5 * cfg.mla_mscale ** 2
+    if cfg.mla_q_latent:
+        # the query through its latent, with a norm of its own
+        with jax.named_scope("attn.mla.q"):
+            c_q = rms_norm(maybe_matmul(h, p["w_dq"]), p["q_norm"],
+                           cfg.norm_eps).astype(h.dtype)
+            q = maybe_matmul(c_q, p["w_uq"]).reshape(b, t, heads, dn + dr)
     with jax.named_scope("attn.qkv"):
-        q = maybe_matmul(h, p["wq"]).reshape(b, t, heads, dn + dr)
+        if not cfg.mla_q_latent:
+            q = maybe_matmul(h, p["wq"]).reshape(b, t, heads, dn + dr)
         down = maybe_matmul(h, p["w_dkv"])                   # [B, T, dc+dr]
         c = rms_norm(down[..., :dc], p["kv_norm"], cfg.norm_eps)
-        gate = jax.nn.sigmoid(maybe_matmul(h, p["w_gate"]).astype(F32))
+        if cfg.mla_out_gate:
+            gate = jax.nn.sigmoid(maybe_matmul(h, p["w_gate"]).astype(F32))
     with jax.named_scope("attn.rope"):
         q_nope = q[..., :dn]
         q_rope = apply_rope(q[..., dn:], positions, sin, cos)
@@ -282,11 +333,20 @@ def mla_block(p: dict, h: jnp.ndarray, cfg, positions, sin, cos,
         # written at its offset, then every row's keys and values expanded
         kv_cache = kvstate.write(kv_cache, plane, c, k_rope, positions,
                                  decode)
-        latents, rotated = kvstate.latent_rows(kv_cache, plane)
-        k_nope, v = expand(latents)
-        with jax.named_scope("attn.mla.core"):
-            out = expanded_attention(q_nope[0], q_rope[0], k_nope, rotated,
-                                     v, positions[0], scale)[None]
+        if not blocked_prefill_declined(kvstate.dense_len(kv_cache)):
+            # a long scratch: a block of keys at a time, each block's keys
+            # and values made from its latents where they are used
+            with jax.named_scope("attn.mla.core"):
+                out = blocked_prefill_attention(
+                    q_nope[0], q_rope[0], *kvstate.latent_planes(kv_cache),
+                    w_ukv, positions[0, 0], plane, scale)[None]
+        else:
+            latents, rotated = kvstate.latent_rows(kv_cache, plane)
+            k_nope, v = expand(latents)
+            with jax.named_scope("attn.mla.core"):
+                out = expanded_attention(q_nope[0], q_rope[0], k_nope,
+                                         rotated, v, positions[0],
+                                         scale)[None]
     else:
         raise NotImplementedError(
             "latent attention is built for a forward pass without a cache, "
@@ -294,5 +354,7 @@ def mla_block(p: dict, h: jnp.ndarray, cfg, positions, sin, cos,
             "decode step over the paged pool: a verify window and a dense "
             "decode cache are refused when the engine is made")
     with jax.named_scope("attn.out"):
-        out = (out * gate[..., None]).astype(h.dtype)
+        if cfg.mla_out_gate:
+            out = out * gate[..., None]
+        out = out.astype(h.dtype)
         return maybe_matmul(out.reshape(b, t, heads * dv), p["wo"]), kv_cache

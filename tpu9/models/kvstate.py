@@ -31,7 +31,8 @@ new kind of cache is written here.
 say and :func:`attend` runs the layer's queries over what is written, through
 the dispatchers of ``ops.attention``; latent attention reads through
 :func:`latent_attend` (a decode step over the pool) and :func:`latent_rows`
-(a chunk over the scratch), a KDA layer through :func:`lane_read` and
+or :func:`latent_planes` (a chunk over the scratch, every row expanded or a
+block of keys at a time), a KDA layer through :func:`lane_read` and
 :func:`lane_write`. The cache dict is carried WHOLE from layer to layer:
 every write is into the ``[depth, ...]`` arrays in place (a donated or
 carried array), nothing is sliced out and stacked back.
@@ -85,7 +86,7 @@ def pool_shapes(cfg, n_blocks: int, block: int,
 def lane_shapes(cfg, lanes: int) -> dict:
     """``name -> (shape, dtype)`` of the state KDA layers keep for ``lanes``
     running sequences; empty for a decoder without such layers."""
-    planes = len(cfg.layers_of("kda")) if cfg.layer_group else 0
+    planes = len(cfg.layers_of("kda")) if cfg.layer_group > 1 else 0
     if not planes:
         return {}
     h, d = cfg.n_heads, cfg.head_dim
@@ -159,9 +160,21 @@ def pool_rows(pool: dict, k, v) -> list:
     return [(_SCALE["k"], sk), (_SCALE["v"], sv), ("k", k), ("v", v)]
 
 
-def read_blocks(pool: dict, name: str, index):
+def read_blocks(pool: dict, name: str, index, flat: bool = False):
     """Blocks ``index`` of plane ``name`` at every depth, ``[L, len(index),
-    BS, ...]``; an int8 pool's are dequantized (float32)."""
+    BS, ...]``; an int8 pool's are dequantized (float32). ``flat``: the
+    blocks are taken from the pool as ``[L * N, BS, ...]`` (a free reshape)
+    by ``layer * N + index``, a gather along the MAJOR axis. Taken along
+    axis 1, as without it, the chip's compiler first copies the whole pool
+    so that the gathered axis leads — 4.0 GB of temporaries for a 3.8 GB
+    plane of latents against 0.35 GB (compiled for a described v5e, PR 52);
+    the programs of the per-head pools keep the form they were measured
+    with."""
+    if flat:
+        p = pool[name]
+        l, n = p.shape[:2]
+        return p.reshape((l * n,) + p.shape[2:])[
+            jnp.arange(l)[:, None] * n + index[None, :]]
     g = pool[name][:, index]
     sc = pool.get(_SCALE[name])
     if sc is not None:
@@ -307,6 +320,13 @@ def latent_rows(kv: dict, layer):
     that expands keys and values from them."""
     with jax.named_scope("kv.slice"):
         return kv["k"][layer, 0, :, 0], kv["v"][layer, 0, :, 0]
+
+
+def latent_planes(kv: dict):
+    """The batch-1 dense scratch's latents and rotated keys WHOLE, ``([L, 1,
+    S, 1, mla_latent], [L, 1, S, 1, mla_rope])``: what the blocked prefill
+    reads at its layer, where they lie."""
+    return kv["k"], kv["v"]
 
 
 # -- state a lane --------------------------------------------------------------

@@ -71,11 +71,13 @@ class DecoderConfig:
     # (``kv_entry``), and the residual stream is carried in float32
     attn_window: int = 0
     attn_chunk: int = 0
-    # a layer PATTERN (``models.hybrid``): of every ``layer_group`` layers
-    # the last is latent attention (MLA) over a cache of one row a token,
-    # the others delta-rule linear attention (KDA) over a state a LANE that
-    # no cache holds; 0 = every layer is the plain attention above.
-    # :meth:`layer_kind` is the one place that says what layer ``l`` is
+    # a layer PATTERN (``models.hybrid``): which layers are latent attention
+    # (MLA) over a cache of one row a token and which delta-rule linear
+    # attention (KDA) over a state a LANE that no cache holds. Of every
+    # ``layer_group`` layers the last is MLA and the others KDA, so 1 is MLA
+    # in every layer (no KDA layer, no state a lane); 0 = every layer is the
+    # plain attention above. :meth:`layer_kind` is the one place that says
+    # what layer ``l`` is
     layer_group: int = 0
     # latent attention's widths: the cached latent, a head's unrotated and
     # rotated query/key parts, a head's value
@@ -83,6 +85,16 @@ class DecoderConfig:
     mla_nope: int = 0
     mla_rope: int = 0
     mla_v: int = 0
+    # its query: through a latent of ``mla_q_latent`` numbers with a norm of
+    # its own (0 = one full-rank matrix); a sigmoid gate a head on its
+    # output; the softmax scale times ``mla_mscale`` squared (YaRN's
+    # attention temperature)
+    mla_q_latent: int = 0
+    mla_out_gate: bool = True
+    mla_mscale: float = 1.0
+    # YaRN positions: ``(factor, original_max_positions, beta_fast,
+    # beta_slow)`` (``ops.rotary.yarn_inv_freq``); () = ``rope_theta`` alone
+    rope_yarn: tuple = ()
     # KDA: taps of the short causal convolution on q, k, v, and the lower
     # bound of a token's log-decay (a negative number)
     kda_conv: int = 0
@@ -142,12 +154,14 @@ class DecoderConfig:
             refuse_unbuilt_pattern(self)
         elif (self.mla_latent or self.kda_conv or self.moe_dense_layers
               or self.moe_routed or self.moe_shared_dim
-              or self.moe_score != "softmax"):
+              or self.moe_score != "softmax" or self.mla_q_latent
+              or self.rope_yarn or self.mla_mscale != 1.0):
             raise ValueError(
-                "latent attention, the delta rule and the expert layer's "
-                "share, shared expert and sigmoid gates are built for a "
-                "layer pattern only (layer_group > 0): no served model has "
-                "one without the other")
+                "latent attention (its query latent, YaRN positions and "
+                "temperature with it), the delta rule and the expert "
+                "layer's share, shared expert and sigmoid gates are built "
+                "for a layer pattern only (layer_group > 0): no served "
+                "model has one without the other")
 
     @property
     def q_per_kv(self) -> int:
@@ -158,8 +172,9 @@ class DecoderConfig:
     def layer_kind(self, l: int) -> tuple:
         """``(attention, ffn)`` of layer ``l`` (0-based): attention is
         ``"full"`` (the plain attention of a uniform decoder), ``"kda"`` or
-        ``"mla"`` (the last layer of each group of ``layer_group``); the ffn
-        is ``"dense"`` or ``"experts"``. THE one place that knows the
+        ``"mla"`` (the last layer of each group of ``layer_group``: every
+        layer where a group is one layer); the ffn is ``"dense"`` or
+        ``"experts"``. THE one place that knows the
         pattern: ``init_decoder``, the forward pass, the pool's depth and
         the lanes' state all ask here."""
         if not self.layer_group:
@@ -692,7 +707,7 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
                 f"{rope_len} — positions past it would alias")
     with jax.named_scope("attn.rope"):
         sin, cos = rope_table(rope_len, cfg.mla_rope or cfg.head_dim,
-                              cfg.rope_theta)
+                              cfg.rope_theta, cfg.rope_yarn)
 
     moe_balance = jnp.zeros((), jnp.float32)
     exit_info = moe_picks = None

@@ -3,9 +3,20 @@ row is one latent ``c`` [d_c] and one rotated key ``k_rope`` [d_rope] for all
 heads, from which a head's key is ``[W_uk,h c | k_rope]`` and its value
 ``W_uv,h c``.
 
-- :func:`expanded_attention` — a prefill: keys and values expanded from the
-  latents of every cache row, queries masked by absolute position, taken a
-  block of queries at a time so that the float32 scores of a wide call fit.
+- :func:`expanded_attention` — a prefill over a SHORT cache: keys and values
+  expanded from the latents of every cache row, queries masked by absolute
+  position, taken a block of queries at a time so that the float32 scores of
+  a wide call fit.
+- :func:`blocked_prefill_attention` — a prefill over a LONG cache (a chunk or
+  an admission group against the batch-1 scratch), BLOCKED OVER KEYS: an
+  online softmax over blocks of latents, a block's keys and values made from
+  its latents where they are used and never kept, and only the blocks that
+  hold a row some query may see. Expanding every row of a 57,344-row scratch
+  for 64 heads is 1.9 GB of keys and values a layer and 7.5 GB of float32
+  scores a 512-token chunk; blocked, nothing wider than a block exists. A
+  Pallas kernel on the chip (:func:`blocked_prefill_attention_kernel`: the
+  expansion, the scores and the weighted sum of a block in VMEM) and the same
+  sums in ``jax.numpy`` elsewhere (:func:`blocked_prefill_attention_xla`).
 - :func:`paged_latent_attention` — a decode step, ABSORBED: ``W_uk`` has gone
   into the query and ``W_uv`` comes after, so the step attends the latents
   themselves and reads each cache row once for all heads:
@@ -35,6 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .chunk_attention import _lanes
+
 F32 = jnp.float32
 NEG_INF = -1e30
 # queries of a block of :func:`expanded_attention`
@@ -43,6 +56,18 @@ QUERY_BLOCK = 512
 # 0.5 MB a slot in VMEM, and a wave is ONE softmax update over 512 rows
 WAVE_PAGES = 4
 LATENT_KERNEL = "paged_latent_attention"
+# the blocked prefill: its kernel's name in a trace; the scratch rows from
+# which a chunk takes it (under them :func:`expanded_attention` holds
+# everything at once, as it always did: 32 heads x 512 queries x 4,096 rows
+# of float32 scores are 0.27 GB); keys a block; the most queries a tile; the
+# most heads a grid step (their weights, queries and accumulators together
+# in VMEM: a grid step costs ~0.35 us whatever it does, and the key axis of a
+# long scratch has many that do nothing)
+PREFILL_KERNEL = "latent_prefill_attention"
+BLOCKED_MIN_ROWS = 8192
+PREFILL_BLOCK_K = 512
+PREFILL_BLOCK_Q = 1024
+PREFILL_HEADS = 8
 
 
 def _softmax_rows(scores, mask):
@@ -276,3 +301,244 @@ def paged_latent_attention_kernel(q_lat, q_rope, c_pool, r_pool, table,
         interpret=interpret,
     )(table.astype(jnp.int32), lengths.astype(jnp.int32),
       q_lat.astype(c_pool.dtype), s_rope, c_pool)
+
+
+# -- a prefill over a long cache, blocked over keys -----------------------------
+
+def blocked_prefill_declined(s: int) -> str:
+    """Why a chunk against an ``s``-row scratch takes
+    :func:`expanded_attention` ('' = the blocked prefill runs)."""
+    if s < BLOCKED_MIN_ROWS:
+        return (f"a scratch of {s} rows, under {BLOCKED_MIN_ROWS}: every "
+                "row expanded at once")
+    if s % PREFILL_BLOCK_K:
+        return f"a scratch of {s} rows: not whole blocks of " \
+            f"{PREFILL_BLOCK_K} keys"
+    return ""
+
+
+def prefill_kernel_declined(t: int, dn: int, dr: int, dv: int, dc: int,
+                            dtype) -> str:
+    """Why a blocked prefill of ``t`` queries takes the ``jax.numpy`` form
+    ('' = the kernel runs)."""
+    from ..utils import on_tpu
+    if not on_tpu():
+        return "no TPU backend"
+    if dtype != jnp.bfloat16:
+        return f"a {jnp.dtype(dtype).name} cache"
+    if t % 128 or dn % 128 or dv % 128 or dc % 128 or dr % 64:
+        return (f"{t} queries, widths nope={dn}, rope={dr}, v={dv}, "
+                f"latent={dc}: not whole tiles")
+    return ""
+
+
+def blocked_prefill_attention(q_nope, q_rope, c_cache, r_cache, w_ukv,
+                              offset, layer, scale):
+    """``q_nope`` [T, H, dn], ``q_rope`` [T, H, dr] at the contiguous
+    positions ``offset .. offset + T - 1``; the dense scratch ``c_cache``
+    [L, 1, S, 1, dc] / ``r_cache`` [L, 1, S, 1, dr], read at ``layer``, which
+    already holds the chunk's own rows; ``w_ukv`` [dc, H, dn + dv]. Query t
+    attends rows ``s <= offset + t``. Returns [T, H, dv] in the cache's type.
+    The kernel where it runs, else the ``jax.numpy`` form."""
+    dn, dr = q_nope.shape[-1], q_rope.shape[-1]
+    dc = c_cache.shape[-1]
+    if not prefill_kernel_declined(q_nope.shape[0], dn, dr,
+                                   w_ukv.shape[-1] - dn, dc, c_cache.dtype):
+        return blocked_prefill_attention_kernel(
+            q_nope, q_rope, c_cache, r_cache, w_ukv, offset, layer, scale)
+    return blocked_prefill_attention_xla(
+        q_nope, q_rope, c_cache, r_cache, w_ukv, offset, layer, scale)
+
+
+def blocked_prefill_attention_xla(q_nope, q_rope, c_cache, r_cache, w_ukv,
+                                  offset, layer, scale, block_k: int = 0):
+    """:func:`blocked_prefill_attention` in ``jax.numpy``: a device loop
+    over the key blocks that hold a row some query may see."""
+    t, heads, dn = q_nope.shape
+    s = c_cache.shape[2]
+    block_k = min(block_k or PREFILL_BLOCK_K, s)
+    latents, rotated = c_cache[layer, 0, :, 0], r_cache[layer, 0, :, 0]
+    w_uk, w_uv = w_ukv[..., :dn], w_ukv[..., dn:]
+    q_pos = offset + jnp.arange(t)
+
+    def one_block(kb, carry):
+        m, l, acc = carry
+        first = kb * block_k
+        c = jax.lax.dynamic_slice_in_dim(latents, first, block_k)
+        r = jax.lax.dynamic_slice_in_dim(rotated, first, block_k)
+        k = jnp.einsum("sc,chd->shd", c, w_uk)
+        v = jnp.einsum("sc,chd->shd", c, w_uv)
+        scores = (jnp.einsum("thd,shd->hts", q_nope, k,
+                             preferred_element_type=F32)
+                  + jnp.einsum("thd,sd->hts", q_rope, r,
+                               preferred_element_type=F32)) * scale
+        seen = (first + jnp.arange(block_k))[None, None, :] \
+            <= q_pos[None, :, None]
+        scores = jnp.where(seen, scores, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(seen, jnp.exp(scores - m_new[..., None]), 0.0)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "hts,shd->htd", p.astype(v.dtype), v, preferred_element_type=F32)
+        return m_new, alpha * l + jnp.sum(p, axis=-1), acc
+
+    n_blocks = jnp.minimum((offset + t + block_k - 1) // block_k,
+                           s // block_k)
+    _, l, acc = jax.lax.fori_loop(
+        0, n_blocks, one_block,
+        (jnp.full((heads, t), NEG_INF, F32), jnp.zeros((heads, t), F32),
+         jnp.zeros((heads, t, w_uv.shape[-1]), F32)))
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return out.transpose(1, 0, 2).astype(c_cache.dtype)
+
+
+def _prefill_kernel(offset_ref, layer_ref, qn_ref, qr_ref, w_ref, c_ref,
+                    r_ref, o_ref, m_scr, l_scr, acc_scr, *, scale: float,
+                    block_q: int, block_k: int, dn: int):
+    """One key block of one query tile of ``heads`` heads a grid step.
+    ``qn_ref`` [heads, block_q, dn], ``qr_ref`` [heads, block_q, dr],
+    ``w_ref`` [heads, dc, dn + dv]; ``c_ref`` [block_k, dc] and ``r_ref``
+    [block_k, dr] the block's latents and rotated keys. A head's keys and
+    values of the block are made here, from the latents, and live as long as
+    its update. A block wholly at or before the tile's first query is one
+    unmasked update a head; one the diagonal crosses is masked by position,
+    its latents past the tile's last query zeroed (probability 0 times
+    whatever the scratch holds there must stay 0); one past the last query
+    was not copied (the index map clamped) and is not touched."""
+    del layer_ref
+    qi, kb = pl.program_id(0), pl.program_id(2)
+    first_q = offset_ref[0] + qi * block_q
+    last_q = first_q + block_q - 1
+    first_k = kb * block_k
+    last_k = first_k + block_k - 1
+    heads = qn_ref.shape[0]
+
+    @pl.when(kb == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def block_of_keys(masked: bool):
+        c = c_ref[...]
+        r = r_ref[...]
+        if masked:
+            written = first_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, 1), 0) <= last_q
+            c = jnp.where(written, c, jnp.zeros_like(c))
+            r = jnp.where(written, r, jnp.zeros_like(r))
+            visible = first_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1) <= first_q \
+                + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+
+        def one_head(h, _):
+            kv = jax.lax.dot_general(
+                c, w_ref[h], (((1,), (0,)), ((), ())),
+                preferred_element_type=F32).astype(c.dtype)
+            k, v = kv[:, :dn], kv[:, dn:]
+            s = (jax.lax.dot_general(qn_ref[h], k, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=F32)
+                 + jax.lax.dot_general(qr_ref[h], r, (((1,), (1,)), ((), ())),
+                                       preferred_element_type=F32)) * scale
+            if masked:
+                s = jnp.where(visible, s, NEG_INF)
+            m_prev = m_scr[h]                                    # [R, 128]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # row 0 is visible to every query and comes first, so a row's
+            # maximum is a real score from its first update on: a masked
+            # score's exp underflows to exactly 0
+            p = jnp.exp(s - _lanes(m_new, block_k))
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * _lanes(alpha, acc_scr.shape[-1]) \
+                + jax.lax.dot_general(p.astype(v.dtype), v,
+                                      (((1,), (0,)), ((), ())),
+                                      preferred_element_type=F32)
+            m_scr[h] = m_new
+
+        if heads == 1:
+            one_head(0, None)
+        else:
+            jax.lax.fori_loop(0, heads, one_head, None)
+
+    pl.when(last_k <= first_q)(functools.partial(block_of_keys, False))
+    pl.when((last_k > first_q) & (first_k <= last_q))(
+        functools.partial(block_of_keys, True))
+
+    @pl.when(kb == pl.num_programs(2) - 1)
+    def _finalize():
+        def one(h, _):
+            o_ref[h] = (acc_scr[h] / _lanes(l_scr[h], o_ref.shape[-1])
+                        ).astype(o_ref.dtype)
+        jax.lax.fori_loop(0, heads, one, None)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block_k", "interpret"))
+def blocked_prefill_attention_kernel(q_nope, q_rope, c_cache, r_cache, w_ukv,
+                                     offset, layer, scale: float,
+                                     block_k: int = 0,
+                                     interpret: bool = False):
+    """:func:`blocked_prefill_attention` by :func:`_prefill_kernel`. The
+    scratch is read where it lies, at ``layer`` (an int or an int32 scalar)
+    and only as far as it is written: the key block's index clamps at the
+    last block that holds a row the tile's last query sees. The heads are a
+    grid axis, ``PREFILL_HEADS`` a step, each with its own slice of
+    ``w_ukv``; the key axis is innermost, so a step's weights, queries and
+    accumulators stay in VMEM while the latents stream past."""
+    t, heads, dn = q_nope.shape
+    dr, dc = q_rope.shape[-1], c_cache.shape[-1]
+    dv = w_ukv.shape[-1] - dn
+    n_layers, _, s = c_cache.shape[:3]
+    block_k = min(block_k or PREFILL_BLOCK_K, s)
+    block_q = next(b for b in (PREFILL_BLOCK_Q, 512, 256, 128, t)
+                   if b <= t and t % b == 0)
+    hg = next(g for g in (PREFILL_HEADS, 4, 2, 1) if heads % g == 0)
+    assert s % block_k == 0, (s, block_k)
+    n_qb, n_kb = t // block_q, s // block_k
+    dt = c_cache.dtype
+    # [H, T, d] queries, [H, dc, dn + dv] weights: a head's are one block
+    qn = q_nope.astype(dt).transpose(1, 0, 2)
+    qr = q_rope.astype(dt).transpose(1, 0, 2)
+    w = w_ukv.astype(dt).transpose(1, 0, 2)
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+    offset = jnp.reshape(jnp.asarray(offset, jnp.int32), (1,))
+
+    def q_index(qi, h, kb, offset, layer):
+        return (h, qi, 0)
+
+    def w_index(qi, h, kb, offset, layer):
+        return (h, 0, 0)
+
+    def kv_index(qi, h, kb, offset, layer):
+        last = jnp.minimum(
+            jax.lax.div(offset[0] + (qi + 1) * block_q - 1, block_k),
+            n_kb - 1)
+        return (layer[0] * n_kb + jnp.minimum(kb, last), 0)
+
+    out = pl.pallas_call(
+        functools.partial(_prefill_kernel, scale=scale, block_q=block_q,
+                          block_k=block_k, dn=dn),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_qb, heads // hg, n_kb),
+            in_specs=[pl.BlockSpec((hg, block_q, dn), q_index),
+                      pl.BlockSpec((hg, block_q, dr), q_index),
+                      pl.BlockSpec((hg, dc, dn + dv), w_index),
+                      pl.BlockSpec((block_k, dc), kv_index),
+                      pl.BlockSpec((block_k, dr), kv_index)],
+            out_specs=pl.BlockSpec((hg, block_q, dv), q_index),
+            scratch_shapes=[
+                pltpu.VMEM((hg, block_q, 128), F32),
+                pltpu.VMEM((hg, block_q, 128), F32),
+                pltpu.VMEM((hg, block_q, dv), F32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((heads, t, dv), dt),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=96 * 1024 * 1024),
+        name=PREFILL_KERNEL,
+        interpret=interpret,
+    )(offset, layer, qn, qr, w,
+      c_cache.reshape(n_layers * s, dc), r_cache.reshape(n_layers * s, dr))
+    return out.transpose(1, 0, 2)

@@ -210,19 +210,32 @@ def refuse_unbuilt_with_summaries(cfg, ecfg: "EngineConfig",
 def refuse_unbuilt_with_lane_state(cfg, ecfg: "EngineConfig",
                                    topo: dict) -> None:
     """What is not built for a layer pattern (``DecoderConfig.layer_group``:
-    KDA layers whose state is kept by LANE, latent attention over one row a
-    token): refused when the engine is made, each with its reason, and not
-    half-built. Nothing in the engine preempts a running lane, so there is
-    no path that drops a lane's state without re-prefilling it; one that is
-    added has to snapshot or re-prefill (ROADMAP R6)."""
+    which layers are KDA, whose state is kept by LANE, and which latent
+    attention over one row a token): refused when the engine is made, each
+    with its reason, and not half-built.
+
+    Two sorts of refusal. The STATE's — asked of ``cfg.layers_of("kda")``,
+    not of the pattern: the prefix cache (a hit starts a sequence behind
+    cached pages, and a KDA layer would need its state at that boundary),
+    and, where there is state, the state's reasons for verify and the host
+    tier. A pattern with no KDA layer keeps no such state, and its latent
+    pages are shared by the prefix cache like any paged rows. The ROWS' —
+    for every pattern, because a cache row is a latent: the dense cache,
+    verify (no program attends a window of several positions over latents),
+    a mesh, ``kv_quant``, the host tier and kvwire (they address per-head
+    ``k`` / ``v`` planes; no sharding rule and no wire format names a latent
+    row). Nothing in the engine preempts a running lane, so there is no path
+    that drops a lane's state without re-prefilling it; one that is added
+    has to snapshot or re-prefill (ROADMAP R6)."""
     def refuse(what: str, why: str):
         raise ValueError(f"layer_group={cfg.layer_group} with {what}: {why}")
 
+    state = bool(cfg.layers_of("kda"))
     if ecfg.kv_block_size <= 0:
         refuse("kv_block_size=0 (the dense cache)",
                "the latent cache is built as a paged pool, and the dense "
                "prefill buckets carry no KDA state into a lane")
-    if ecfg.prefix_cache_blocks > 0:
+    if ecfg.prefix_cache_blocks > 0 and state:
         refuse(f"prefix_cache_blocks={ecfg.prefix_cache_blocks}",
                "a hit would start a sequence behind cached pages, and the "
                "KDA layers would need a snapshot of their state at that "
@@ -231,13 +244,21 @@ def refuse_unbuilt_with_lane_state(cfg, ecfg: "EngineConfig",
         refuse(f"spec_len={ecfg.spec_len} (verify)",
                "a verify window advances the KDA state over its drafts, and "
                "a rejected draft has then already changed it: there is no "
-               "state to roll back to")
+               "state to roll back to" if state else
+               "a verify window attends several positions a lane at once, "
+               "and latent attention is built for a decode step's one "
+               "(absorbed) and for a chunk over the batch-1 scratch: no "
+               "program attends a window over the pool's latent rows")
     if topo["tp"] > 1 or topo.get("fsdp", 1) > 1 \
             or topo.get("n_chips", 1) > 1:
         refuse(f"a mesh ({topo})",
                "the state a lane, the latent pool and the held experts are "
                "one chip's: no sharding rule names them, and the grouped "
-               "expert kernel is not partitioned")
+               "expert kernel is not partitioned" if state else
+               "a latent row is one row for all heads, so the pool has no "
+               "head axis to shard; no sharding rule names the latent "
+               "pool or the held experts, and the latent and expert "
+               "kernels are not partitioned")
     if ecfg.kv_quant:
         refuse(f"kv_quant={ecfg.kv_quant!r}",
                "the latent rows are read as they are written, in the "
@@ -246,7 +267,10 @@ def refuse_unbuilt_with_lane_state(cfg, ecfg: "EngineConfig",
         refuse(f"kv_host_pool_mb={ecfg.kv_host_pool_mb}",
                "the host tier and the kvwire format ship the pool's rows "
                "and no state a lane: a prefix paged back in would attend "
-               "over latents with zeroed KDA layers")
+               "over latents with zeroed KDA layers" if state else
+               "the host tier and the kvwire format ship per-head key and "
+               "value planes by their names and widths; a latent row and "
+               "its rotated key have no place in either format")
 
 
 @dataclass
@@ -551,13 +575,25 @@ class InferenceEngine:
         # picked (``moe.takes_held_form``; where the one-hot form serves —
         # int8 stacks, a mesh, a test's tiny experts — no window says and
         # the counters stay 0). A dense decoder has none of them
+        # (no KDA layer, no state a lane: no names, and no lane program)
+        self._lane_state_names = tuple(kvstate.lane_shapes(cfg, b))
         if cfg.layer_group:
-            self._lane_state_names = tuple(kvstate.lane_shapes(cfg, b))
             self._state_bytes = kvstate.lane_bytes(cfg, b)
         if cfg.layer_group or cfg.n_experts:
             self._stats.update(moe_local_picks=0, moe_token_layers=0,
                                moe_held_touched=0, moe_step_layers=0)
             self._held_pick_hist = np.zeros((cfg.n_experts,), np.int64)
+        if cfg.mla_latent:
+            # latent attention (ISSUE 52), from the host's mirror of the
+            # lengths: the cache rows the live lanes attend at every decode
+            # step dispatched and those steps; the cache rows every chunk
+            # or group of an admission attended (its own included) and the
+            # (query, row) pairs its causal mask let through; the prompt
+            # rows admitted and those of them a prefix hit reused
+            self._stats.update(latent_rows_attended=0, latent_decode_steps=0,
+                               prefill_rows_attended=0,
+                               prefill_pairs_attended=0, prefix_rows_reused=0,
+                               prompt_rows_admitted=0)
         if cfg.attn_window:
             self._stats.update(windows_closed_prefill=0,
                                windows_closed_decode=0,
@@ -696,13 +732,23 @@ class InferenceEngine:
 
             def ran(why: str) -> str:
                 return f"xla: {why}" if why else "pallas"
-            return {"decode": "latent attention, absorbed: " + ran(
-                        kernel_declined(cfg.n_heads, cfg.mla_latent,
-                                        self.ecfg.kv_block_size, cfg.dtype))
-                    + "; kda step: " + ran(
+            from ..ops.latent_attention import (blocked_prefill_declined,
+                                                prefill_kernel_declined)
+            decode = "latent attention, absorbed: " + ran(
+                kernel_declined(cfg.n_heads, cfg.mla_latent,
+                                self.ecfg.kv_block_size, cfg.dtype))
+            if blocked_prefill_declined(self.graphs.scratch_len):
+                prefill = "xla: latent attention, expanded"
+            else:
+                prefill = "latent attention, blocked over keys: " + ran(
+                    prefill_kernel_declined(
+                        self.graphs.chunk, cfg.mla_nope, cfg.mla_rope,
+                        cfg.mla_v, cfg.mla_latent, cfg.dtype))
+            if not cfg.layers_of("kda"):
+                return {"decode": decode, "prefill": prefill}
+            return {"decode": decode + "; kda step: " + ran(
                         step_kernel_declined(cfg.n_heads, cfg.head_dim)),
-                    "prefill": "xla: latent attention, expanded; kda: "
-                    "chunkwise scan"}
+                    "prefill": prefill + "; kda: chunkwise scan"}
         hd = self.cfg.head_dim
         s_max = self.ecfg.max_seq_len
         ran = "pallas"
@@ -824,6 +870,13 @@ class InferenceEngine:
         ``i`` of a lane that holds ``n`` tokens writes position ``n + i``
         and attends ``kv_entries(n + i + 1)`` entries."""
         cfg = self.cfg
+        if cfg.mla_latent:
+            # step ``i`` of a lane that holds ``n`` tokens attends ``n + i +
+            # 1`` latent rows in every MLA layer
+            lanes = self._host_len[self.active] + self._inflight_steps
+            self._stats["latent_rows_attended"] += int(
+                (lanes[:, None] + np.arange(k)[None, :] + 1).sum())
+            self._stats["latent_decode_steps"] += k
         if not cfg.attn_window:
             return
         lanes = self._host_len[self.active] + self._inflight_steps
@@ -943,7 +996,7 @@ class InferenceEngine:
             self._scratch = {**kept, "k": dense["k"], "v": dense["v"]}
             del dense
             timings["splice_gather_s"] = _time.perf_counter() - t0
-            if self.cfg.layer_group:
+            if self._lane_state_names:
                 t0 = _time.perf_counter()
                 self._splice_lane_state(0)     # zeros over an idle lane
                 timings["lane_splice_s"] = _time.perf_counter() - t0
@@ -1550,6 +1603,9 @@ class InferenceEngine:
                 req, slot, p)
         n_chunks = len(offsets)
         self._stats["admit_chunks"] += n_chunks
+        if self.cfg.mla_latent:
+            self._stats["prompt_rows_admitted"] += n
+            self._stats["prefix_rows_reused"] += p
         if self.cfg.attn_window:
             # a chunk whose first position opens a window closes the one
             # before it (its program summarises at its head)
@@ -1593,6 +1649,13 @@ class InferenceEngine:
                         self._splice_blocks(phys_all, k_chunk, 1, slot,
                                             int(offsets[k_chunk]))))
                     self._stats["admit_dispatches"] += 2
+                if self.cfg.mla_latent:
+                    # the dispatch's queries attend every row before its
+                    # last (a padded tail counts: the program computes it)
+                    at, width = int(offsets[k_chunk]), g * self._chunk
+                    self._stats["prefill_rows_attended"] += at + width
+                    self._stats["prefill_pairs_attended"] += \
+                        width * at + width * (width + 1) // 2
                 if picks:
                     # the dispatch's real rows: all but its last chunk's tail
                     req.routed.append((picks[0], (g - 1) * self._chunk
@@ -1609,7 +1672,7 @@ class InferenceEngine:
         self._scratch = scratch
 
         with phase("engine.admit.finish", totals):
-            if self.cfg.layer_group:
+            if self._lane_state_names:
                 self._splice_lane_state(slot)
                 self._stats["admit_dispatches"] += 1
             if self.ecfg.prefix_cache_blocks > 0:
@@ -2289,7 +2352,8 @@ class InferenceEngine:
         if req is not None:
             if req.routed is not None and req.generated and not req.error:
                 from . import routed_experts
-                routed_experts.note(req.prompt, req.generated, req.routed)
+                routed_experts.note(req.prompt, req.generated, req.routed,
+                                    req.admit_cached)
             self._obs_done(req)
             if req.queue is not None:
                 req.queue.put_nowait(None)

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models import kvstate
-from ..models.hybrid import HYBRID_SCOPES
+from ..models.hybrid import HYBRID_SCOPES, MLA_QUERY_SCOPES
 from ..models.transformer import (DEVICE_SCOPES, LOOP_SCOPES, SUMMARY_SCOPES,
                                   decoder_forward)
 from ..ops.sampling import sample_logits
@@ -552,6 +552,10 @@ class GraphFactory:
         s = self.scratch_len
         dt = self.cfg.dtype
         policy = self.policy
+        # a prefix cache over latent pages (a pattern with no state a lane:
+        # with state the cache is refused and this program is never run)
+        flat = bool(self.cfg.layer_group) \
+            and not kvstate.lane_shapes(self.cfg, 1)
 
         def build():
             @jax.named_scope("kv.gather")
@@ -562,7 +566,7 @@ class GraphFactory:
                 # scratch shape (an S+BS-wide scratch trips the rope-table
                 # width validation when max_seq_len == the rope limit)
                 def one(name):
-                    g = kvstate.read_blocks(pool, name, row)
+                    g = kvstate.read_blocks(pool, name, row, flat)
                     l, mb_, bs, kh, d = g.shape      # [L, MB, BS, KH, D]
                     return g.astype(dt).reshape(
                         l, 1, mb_ * bs, kh, d)[:, :, :s]
@@ -650,8 +654,8 @@ class GraphFactory:
                        (pspec, apool, ascratch,
                         jax.ShapeDtypeStruct((g, c), i32), 0, 0,
                         jax.ShapeDtypeStruct(self.splice_shape(g), i32)))
-            if self.cfg.layer_group:
-                names = tuple(kvstate.lane_shapes(self.cfg, 1))
+            names = tuple(kvstate.lane_shapes(self.cfg, 1))
+            if names:           # a pattern with KDA layers: state a lane
                 akv = policy.abstract(kv_cache, kv=True)
                 yield ("lanesplice", self.lane_splice_fn(),
                        ({n: akv[n] for n in names},
@@ -711,8 +715,10 @@ class GraphFactory:
             keys |= {("chunk", self.chunk), "splice", "gather"}
             if self.group_chunks > 1:
                 keys.add(("chunkgroup", self.group_chunks))
-            if self.cfg.layer_group:
-                # the end of every paged admission of a layer pattern
+            if kvstate.lane_shapes(self.cfg, 1):
+                # the end of every paged admission of a layer pattern that
+                # keeps state a lane (one with no KDA layer has no such
+                # program)
                 keys.add("lanesplice")
         else:
             for bucket in buckets:
@@ -751,7 +757,7 @@ class GraphFactory:
             # a plain program runs nothing under the loop's scopes
             scopes = hlo_scopes(
                 text, DEVICE_SCOPES + LOOP_SCOPES + SUMMARY_SCOPES
-                + HYBRID_SCOPES)
+                + HYBRID_SCOPES + MLA_QUERY_SCOPES)
             if scopes:
                 self.device_scopes[name] = scopes
             else:
