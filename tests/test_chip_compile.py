@@ -172,6 +172,13 @@ def _kernel_names(text: str) -> list:
             if " custom-call(" in ln and "tpu_custom_call" in ln]
 
 
+def _pool_copies(text: str, n_blocks: int) -> list:
+    """The instructions of an optimised HLO text that copy an array as deep
+    and as long as a pool of ``n_blocks`` blocks: a plane of it, whole."""
+    return [ln.strip()[:160] for ln in text.splitlines() if re.search(
+        rf"= \w+\[\d+,{n_blocks},[\d,]+\]\S* copy\(", ln)]
+
+
 @pytest.mark.parametrize("cell", list(CELL_SHAPES))
 def test_the_page_walk_compiles_at_a_cells_shapes(v5e, no_compile_cache,
                                                   monkeypatch, cell):
@@ -436,15 +443,11 @@ def test_the_sorted_moe_layer_compiles_at_mixtral_widths(
 
 def test_a_layer_patterns_kernels_compile_at_the_published_widths(
         v5e, no_compile_cache):
-    """The two kernels of a layer pattern's decode step at Ling-3.0-flash's
-    widths and the cell's batch (ISSUE 48). The KDA step: 128 lanes x 32
-    heads of a float32 ``[128, 128]`` state, plane 2 of 5, IN PLACE — the
-    1.34 GB array is aliased to the output and no copy of it is made. The
-    latent attention: one 512-wide row a token for all 32 heads, a lane's
-    own pages copied where they lie — the 0.54 GB pool is never gathered (a
-    rotated key is half a 128-lane row, which the copy engine refuses to
-    cut out of HBM: only the compiler says so; its scores stay XLA)."""
-    from tpu9.ops import delta_rule, latent_attention
+    """The KDA step of a layer pattern's decode at Ling-3.0-flash's widths
+    and the cell's batch (ISSUE 48): 128 lanes x 32 heads of a float32
+    ``[128, 128]`` state, plane 2 of 5, IN PLACE — the 1.34 GB array is
+    aliased to the output and no copy of it is made."""
+    from tpu9.ops import delta_rule
     one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
 
     def s(shape, dt=jnp.float32):
@@ -458,19 +461,59 @@ def test_a_layer_patterns_kernels_compile_at_the_published_widths(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= planes * b * h * d * d * 4
     assert mem.temp_size_in_bytes < 64 * 2 ** 20
-    dc, dr, bs, mb, blocks = 512, 64, 128, 33, 4097
+
+
+# a cell's decode step of latent attention: lanes, heads, the table's
+# columns, the pool's planes and blocks (``ling-reason``, ``kimi-docs``)
+LATENT_STEPS = {"ling-3.0-flash-l6-ep4": (128, 32, 33, 1, 4097),
+                "kimi-k2.6-l6-ep32": (16, 64, 449, 6, 7184)}
+
+
+@pytest.mark.parametrize("configuration", list(LATENT_STEPS))
+def test_the_latent_decode_kernel_reads_both_planes_where_they_lie(
+        v5e, no_compile_cache, configuration):
+    """Latent attention's decode step at both cells' shapes (ISSUEs 48, 53):
+    one 512-wide latent and one 64-wide rotated key a token for all heads,
+    a lane's own pages copied where they lie — ONE ``tpu_custom_call``,
+    nothing gathered in front of it and no float32 ``[lanes, heads, rows]``
+    score beside it (Kimi's were 237 MB a call, and 1.9 GB a layer moved to
+    have them). What the chip's compiler says of a 64-wide row, and only it
+    says: a ``make_async_copy`` of one page's rotated keys ``[128, 64]`` out
+    of a ``[L, N, 128, 64]`` plane is REFUSED — Mosaic holds that plane as
+    ``memref<LxNx128x128xbf16, tiled<(8,128)(2,1)>>`` and "slice shape along
+    dimension 3 must be aligned to tiling (128), but is 64"; a reshape of
+    the plane to ``[L, N, 64, 128]`` in front of the kernel is ACCEPTED and
+    copies the whole plane a call (1.42 GB of temporaries at Kimi's
+    shapes). Held as ``[L, N, 64, 1, 128]`` — two tokens a row
+    (``pack_rotated``) — the unit axis reshapes away for free and a page is
+    one ``[64, 128]`` copy. A decode step's one row a lane goes into its
+    half row in place: the plane is aliased, never copied."""
+    from tpu9.models import kvstate
+    from tpu9.ops import latent_attention
+    one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    b, h, mb, planes, blocks = LATENT_STEPS[configuration]
+    dc, dr, bs = 512, 64, 128
+    rotated = s((planes, blocks, bs // 2, 1, 2 * dr))
     attend = jax.jit(lambda ql, qr, c, r, t, n:
                      latent_attention.paged_latent_attention_kernel(
-                         ql, qr, c, r, t, n, 0, 192 ** -0.5))
+                         ql, qr, c, r, t, n, planes - 1, 192 ** -0.5))
     compiled = attend.lower(
-        s((b, h, dc), jnp.bfloat16), s((b, h, dr), jnp.bfloat16),
-        s((1, blocks, bs, 1, dc), jnp.bfloat16),
-        s((1, blocks, bs, 1, dr), jnp.bfloat16), s((b, mb), jnp.int32),
-        s((b,), jnp.int32)).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 1
-    # the rotary scores' gather and the scores themselves, nothing of the
-    # latents' size (0.55 GB gathered in the XLA form)
-    assert compiled.memory_analysis().temp_size_in_bytes < 200 * 2 ** 20
+        s((b, h, dc)), s((b, h, dr)), s((planes, blocks, bs, 1, dc)),
+        rotated, s((b, mb), jnp.int32), s((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert _kernel_names(text) == [latent_attention.LATENT_KERNEL]
+    assert " gather(" not in text
+    assert not re.search(rf"f32\[{b},{h},\d{{4,}}\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 20
+    write = jax.jit(lambda pool, bi, oi, row: kvstate._packed_write(
+        pool, planes - 1, bi, oi, row), donate_argnums=(0,))
+    mem = write.lower(rotated, s((b,), jnp.int32), s((b,), jnp.int32),
+                      s((b, dr))).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= planes * blocks * bs * dr * 2
+    assert mem.temp_size_in_bytes < 2 ** 20
 
 
 @pytest.mark.parametrize("form", ["kernel", "xla"])
@@ -550,6 +593,22 @@ def test_a_layer_patterns_decode_window_holds_one_expert_kernel_a_layer(
                                    + [family.STEP_MARKER])
 
 
+def test_a_layer_patterns_splice_writes_the_packed_plane_in_place(
+        v5e, no_compile_cache, monkeypatch):
+    """``ling-3.0-flash-l6-ep4``'s splice and group programs (ISSUE 53): a
+    block of rotated keys is packed two tokens a row on its way into the
+    pool's ONE plane, and the plane is not copied for it — as a single
+    ``dynamic_update_slice`` the compiler tiled the whole plane to suit the
+    update, three copies of 67 MB a group (``kvstate.splice_block``)."""
+    *_, pool, jobs = _decode_programs(
+        v5e, monkeypatch, "ling-3.0-flash-l6-ep4",
+        kinds=("s", "chunkgroup"))
+    assert [str(key) for key, _, _ in jobs] == ["splice", "('chunkgroup', 4)"]
+    for key, fn, args in jobs:
+        assert not _pool_copies(fn.lower(*args).compile().as_text(),
+                                pool.shape[1]), key
+
+
 @pytest.mark.parametrize("width", [512, 2048])
 def test_the_latent_prefill_kernel_compiles_at_the_published_widths(
         v5e, no_compile_cache, width):
@@ -591,15 +650,28 @@ def test_latent_attention_in_every_layer_holds_its_kernels(
     from tpu9.ops import latent_attention
     cfg, family, _, pool, jobs = _decode_programs(
         v5e, monkeypatch, "kimi-k2.6-l6-ep32", n_layers=2,
-        kinds=("decode", "chunk", "chunkgroup", "g"))
+        kinds=("decode", "chunk", "chunkgroup", "g", "s"))
     assert pool.shape == (2, 4865, 128, 1, 512)
     seen = {}
     for key, fn, args in jobs:
         compiled = fn.lower(*args).compile()
-        seen[key] = sorted(_kernel_names(compiled.as_text()))
+        text = compiled.as_text()
+        seen[key] = sorted(_kernel_names(text))
         if key == "gather":
             assert compiled.memory_analysis().temp_size_in_bytes \
                 < 256 * 2 ** 20
+        if key[0] == "decode":
+            # ISSUE 53: no score over the table's width beside the kernel
+            # (f32[16,64,57856] and four more operations a layer), and no
+            # page of rotated keys gathered for one (bf16[7184,128,64])
+            assert not re.search(r"f32\[16,64,\d{4,}\]", text), key
+            assert not re.search(r"bf16\[\d+,128,64\]", text), key
+        # and no program copies a plane of the pool: a scatter straight
+        # into the ``[L, N, BS / 2, 1, 128]`` plane took it in another
+        # tiling than the kernel's ``[L, N, BS / 2, 128]`` view of it, 0.5
+        # GB copied there and back a layer of a decode step (PR 53's first
+        # traced run); every write goes through ``kvstate._lanes_view``
+        assert not _pool_copies(text, 4865), key
     assert family.STEP_MARKER == latent_attention.LATENT_KERNEL
     assert family.PREFILL_KERNEL == latent_attention.PREFILL_KERNEL
     for k in (1, 8):

@@ -23,8 +23,9 @@ from tpu9.models.moe import (SORTED_MIN_TOKENS, _top_k_gates, moe_ffn_held,
                              moe_ffn_sorted)
 from tpu9.models.transformer import DecoderConfig, moe_cfg
 from tpu9.ops import delta_rule
-from tpu9.ops.latent_attention import (expanded_attention,
-                                       paged_latent_attention)
+from tpu9.ops.latent_attention import (WAVE_PAGES, expanded_attention,
+                                       pack_rotated, paged_latent_attention,
+                                       unpack_rotated)
 from tpu9.ops.rotary import rope_table
 
 # the rehearsal's tiny widths: two periods of 3 in 6 layers (KDA, KDA, MLA),
@@ -436,7 +437,9 @@ def test_absorbed_attention_over_paged_latents_equals_expanded():
     lengths = jnp.asarray([37, 5, 0])
     table = jnp.asarray([[3, 5, 1], [2, 0, 0], [0, 0, 0]], jnp.int32)
     c_pool = jax.random.normal(ks[0], (2, 6, bs, 1, dc))
-    r_pool = jax.random.normal(ks[1], (2, 6, bs, 1, dr))
+    rotated = jax.random.normal(ks[1], (2, 6, bs, 1, dr))
+    r_pool = pack_rotated(rotated, 2)           # as the pool holds them
+    assert r_pool.shape == (2, 6, bs // 2, 1, 2 * dr)
     w_ukv = jax.random.normal(ks[2], (dc, h, dn + dv)) * dc ** -0.5
     q_nope = jax.random.normal(ks[3], (3, h, dn))
     q_rope = jax.random.normal(ks[4], (3, h, dr))
@@ -447,7 +450,7 @@ def test_absorbed_attention_over_paged_latents_equals_expanded():
     got = jnp.einsum("bhc,chd->bhd", o_lat, w_ukv[..., dn:])
     for lane, n in ((0, 37), (1, 5)):
         rows = c_pool[1, table[lane]].reshape(-1, dc)[:n]
-        rot = r_pool[1, table[lane]].reshape(-1, dr)[:n]
+        rot = rotated[1, table[lane]].reshape(-1, dr)[:n]
         kv = jnp.einsum("sc,chd->shd", rows, w_ukv)
         want = expanded_attention(
             q_nope[lane][None], q_rope[lane][None], kv[..., :dn], rot,
@@ -456,36 +459,101 @@ def test_absorbed_attention_over_paged_latents_equals_expanded():
     assert (np.asarray(got[2]) == 0).all()          # nothing to attend
 
 
-def test_the_latent_kernel_equals_the_xla_form():
+def test_a_pools_rotated_keys_lie_two_tokens_a_row():
+    """Token ``j`` of a page in the first lanes of row ``j``, token ``j +
+    BS / 2`` in the rest; unpacking gives the rows back in token order."""
+    bs, dr = 8, 4
+    rows = jnp.arange(3 * bs * dr, dtype=jnp.float32).reshape(3, bs, 1, dr)
+    packed = np.asarray(pack_rotated(rows, 1))
+    assert packed.shape == (3, bs // 2, 1, 2 * dr)
+    for j in range(bs):
+        half = j // (bs // 2)
+        assert (packed[:, j % (bs // 2), 0, half * dr:(half + 1) * dr]
+                == np.asarray(rows[:, j, 0])).all()
+    assert (np.asarray(unpack_rotated(packed, 1)) == np.asarray(rows)).all()
+
+
+BS_K, WAVE_K = 16, WAVE_PAGES   # of the kernel's cases below
+# name -> (heads, rotary width, table columns, the lanes' lengths)
+KERNEL_CASES = {
+    # 0, 1, a page, a page and a row, whole waves and the table's width
+    "lengths": (8, 64, 9, [37, 1, 0, BS_K * 9, BS_K, BS_K + 1, 4 * BS_K,
+                           8 * BS_K]),
+    # a page's edge and a wave's, from both sides
+    "edges": (8, 64, WAVE_K + 1, [BS_K - 1, BS_K, BS_K + 1,
+                                  WAVE_K * BS_K - 1, WAVE_K * BS_K,
+                                  WAVE_K * BS_K + 1]),
+    # an idle lane between two live ones, first and last, and two in a row
+    "idle-lanes": (8, 64, 5, [0, 70, 0, 0, 33, 0]),
+    # every lane fills its table; the last wave holds one page
+    "full-tables": (8, 64, WAVE_K + 1, [(WAVE_K + 1) * BS_K] * 3),
+    # Kimi's 64 heads, its 449 columns cut to what the CPU holds (57: the
+    # last wave one page), a rotary width that is not the latent's quarter
+    "64-heads": (64, 32, 57, [57 * BS_K, 0, 449, 29 * BS_K + 3]),
+    # a table narrower than a wave
+    "narrow-table": (8, 64, 3, [3 * BS_K, 20, 0]),
+}
+
+
+@pytest.mark.parametrize("name", list(KERNEL_CASES))
+def test_the_latent_kernel_equals_the_xla_form(name):
     """The Pallas walk (interpreted) over a lane's own pages against the
-    XLA form over the whole table: lengths of 0, 1, a page, a page and a
-    row, whole waves and the table's width; the trash block holds NaN, which
-    the XLA form would carry into every lane and the kernel never reads."""
+    XLA form over the whole table. Every block no lane holds a row in — the
+    trash block among them — holds NaN in BOTH planes for the kernel, and
+    so does the tail of each lane's last page: a row past a lane's length
+    never reaches the sums (the XLA form would carry it into every lane)."""
     from tpu9.ops.latent_attention import (paged_latent_attention_kernel,
                                            paged_latent_attention_xla)
-    h, dc, dr, bs, mb = 8, 128, 64, 16, 9
+    h, dr, mb, lengths = KERNEL_CASES[name]
+    dc, bs, lanes = 128, BS_K, len(lengths)
+    blocks = lanes * mb + 1
     ks = jax.random.split(jax.random.PRNGKey(5), 4)
-    lengths = jnp.asarray([37, 1, 0, bs * mb, bs, bs + 1, 4 * bs, 64])
     rng = np.random.default_rng(0)
-    table = jnp.asarray(rng.permutation(80)[:8 * mb].reshape(8, mb) + 1,
-                        jnp.int32)
-    pages = (np.asarray(lengths) + bs - 1) // bs
-    table = jnp.where(jnp.arange(mb)[None, :] < pages[:, None], table, 0)
-    c_pool = jax.random.normal(ks[0], (2, 81, bs, 1, dc)).astype(jnp.bfloat16)
-    r_pool = jax.random.normal(ks[1], (2, 81, bs, 1, dr)).astype(jnp.bfloat16)
-    q_lat = jax.random.normal(ks[2], (8, h, dc)).astype(jnp.bfloat16)
-    q_rope = jax.random.normal(ks[3], (8, h, dr)).astype(jnp.bfloat16)
-    want = paged_latent_attention_xla(q_lat, q_rope, c_pool, r_pool, table,
-                                      lengths, 1, 0.07)
-    got = paged_latent_attention_kernel(
-        q_lat, q_rope, c_pool.at[:, 0].set(jnp.nan), r_pool, table, lengths,
-        1, 0.07, interpret=True)
-    assert np.isfinite(np.asarray(got)).all()
+    table = rng.permutation(blocks - 1).reshape(lanes, mb) + 1
+    held = np.arange(mb)[None, :] < (np.asarray(lengths)[:, None] + bs - 1) \
+        // bs
+    table = jnp.asarray(np.where(held, table, 0), jnp.int32)
+    lengths = jnp.asarray(lengths)
+    c_pool = jax.random.normal(ks[0], (2, blocks, bs, 1, dc))
+    rotated = jax.random.normal(ks[1], (2, blocks, bs, 1, dr))
+    # what a lane has written, and NaN wherever it has not
+    at = np.full((blocks, bs), False)
+    for lane in range(lanes):
+        for pos in range(int(lengths[lane])):
+            at[int(table[lane, pos // bs]), pos % bs] = True
+    written = jnp.asarray(at)[None, :, :, None, None]
+    c_pool, rotated = (x.astype(jnp.bfloat16) for x in (c_pool, rotated))
+    q_lat = jax.random.normal(ks[2], (lanes, h, dc)).astype(jnp.bfloat16)
+    q_rope = jax.random.normal(ks[3], (lanes, h, dr)).astype(jnp.bfloat16)
+    want = paged_latent_attention_xla(
+        q_lat, q_rope, jnp.where(written, c_pool, 0),
+        pack_rotated(jnp.where(written, rotated, 0), 2), table, lengths, 1,
+        0.07)
+    got = np.asarray(paged_latent_attention_kernel(
+        q_lat, q_rope, jnp.where(written, c_pool, jnp.nan),
+        pack_rotated(jnp.where(written, rotated, jnp.nan), 2), table,
+        lengths, 1, 0.07, interpret=True))
+    assert np.isfinite(got).all()
     # bfloat16 probabilities on both sides, summed in another order
-    assert np.abs(np.asarray(got - want)).max() < 2e-2
-    assert (np.asarray(got[2]) == 0).all()
+    assert np.abs(got - np.asarray(want)).max() < 2e-2
+    assert (got[np.asarray(lengths) == 0] == 0).all()
+    assert np.abs(got[np.asarray(lengths) > 0]).max() > 0.1
+
+
+def test_the_latent_kernel_declines_what_is_not_whole_tiles(monkeypatch):
+    from tpu9 import utils
     from tpu9.ops.latent_attention import kernel_declined
-    assert kernel_declined(32, 512, 128, jnp.bfloat16) == "no TPU backend"
+    assert kernel_declined(32, 512, 64, 128, jnp.bfloat16) \
+        == "no TPU backend"
+    monkeypatch.setattr(utils, "on_tpu", lambda: True)
+    assert kernel_declined(32, 512, 64, 128, jnp.bfloat16) == ""
+    assert kernel_declined(64, 512, 64, 128, jnp.bfloat16) == ""
+    assert "float32" in kernel_declined(32, 512, 64, 128, jnp.float32)
+    # half a page of 16-row tiles; two rotated keys a 128-lane row
+    assert "not whole tiles" in kernel_declined(32, 512, 64, 16,
+                                                jnp.bfloat16)
+    assert "not whole tiles" in kernel_declined(32, 512, 32, 128,
+                                                jnp.bfloat16)
 
 
 def test_expanded_attention_in_query_blocks_equals_one_block(monkeypatch):

@@ -49,8 +49,8 @@ def programs(params):
                         prefill_chunk=C, admit_group_chunks=G)
     graphs = GraphFactory(SMALL, ecfg, SingleDevicePolicy(), chunk=C)
     prompt = np.random.default_rng(1).integers(3, 256, 91).tolist()
-    pool = {n: jnp.zeros((SMALL.kv_layers, 21, BS) + row, jnp.float32)
-            for n, row in zip(("k", "v"), SMALL.kv_row)}
+    pool = {n: jnp.zeros(shape, jnp.float32) for n, (shape, _)
+            in kvstate.pool_shapes(SMALL, 21, BS).items()}
     # a scratch another sequence has used: the first chunk starts from zero
     scratch = jax.tree_util.tree_map(
         lambda a: a + 3.0, init_kv_cache(SMALL, 1, graphs.scratch_len))

@@ -263,3 +263,50 @@ def test_the_prefix_cache_is_the_states_refusal_not_the_rows(params):
     assert engine.ecfg.prefix_cache_blocks == 64
     assert engine.export_prefix_kv(list(range(3, 40))) is None
     assert engine.export_request_kv("nobody") is None
+
+
+@pytest.mark.parametrize("program", ["splice", "decode-write"])
+def test_the_pools_rotated_keys_come_back_as_they_were_written(program):
+    """ISSUE 53: a pool keeps the rotated keys two tokens a row, and no
+    program sees it. Scratch rows spliced into scattered blocks (the splice
+    program) or written a decode step at a time (``kvstate.write``) and
+    gathered back by the gather program are the rows that went in, in token
+    order, both planes; the pool's plane is the packed one."""
+    from tpu9.serving.graphs import GraphFactory
+    from tpu9.serving.shard.policy import SingleDevicePolicy
+    ecfg = _ecfg()
+    graphs = GraphFactory(SMALL, ecfg, SingleDevicePolicy(), chunk=C)
+    rows = graphs.scratch_len
+    scratch = {n: jax.random.normal(jax.random.PRNGKey(i), shape).astype(dt)
+               for i, (n, (shape, dt)) in enumerate(
+                   kvstate.dense_shapes(SMALL, 1, rows).items())}
+    pool = {n: jnp.zeros(shape, dt) for n, (shape, dt)
+            in kvstate.pool_shapes(SMALL, 61, BS).items()}
+    assert pool["v"].shape == (SMALL.kv_layers, 61, BS // 2, 1,
+                               2 * SMALL.mla_rope)
+    mb = S // BS + 1
+    blocks = np.random.default_rng(3).permutation(60)[:mb - 1] + 1
+    table = np.zeros((1, mb), np.int32)
+    table[0, :mb - 1] = blocks
+    n = 3 * C + 5                 # three whole chunks and a tail of 5 tokens
+    if program == "splice":
+        for start in range(0, n, C):
+            pool = graphs.splice_fn()(
+                pool, scratch["k"], scratch["v"], start, jnp.asarray(
+                    blocks[start // BS:(start + C) // BS], jnp.int32))
+        n = -(-n // C) * C        # a splice moves whole blocks
+    else:
+        kv = dict(pool, table=jnp.asarray(table))
+        for pos in range(n):
+            for plane in range(SMALL.kv_layers):
+                kv = kvstate.write(
+                    kv, plane, scratch["k"][plane, :, pos:pos + 1, 0],
+                    scratch["v"][plane, :, pos:pos + 1, 0],
+                    jnp.asarray([[pos]]), True)
+        pool = {name: kv[name] for name in pool}
+    back = graphs.gather_fn()(pool, jnp.asarray(table[0]))
+    for name in ("k", "v"):
+        assert back[name].shape == scratch[name].shape
+        np.testing.assert_array_equal(np.asarray(back[name][:, :, :n]),
+                                      np.asarray(scratch[name][:, :, :n]))
+        assert not np.asarray(back[name][:, :, n:]).any()

@@ -104,9 +104,15 @@ def test_scratch_and_pool_agree_on_every_row(name):
     assert [row for row, _ in kvstate.paged_planes(cfg).values()] \
         == list(cfg.kv_row)
     for plane in ("k", "v"):
-        assert pool[plane][0][:3] == (cfg.kv_layers, BLOCKS, BS)
         assert dense[plane][0][:3] == (cfg.kv_layers, 1, S)
-        assert pool[plane][0][3:] == dense[plane][0][3:]
+        if name == "latent" and plane == "v":
+            # a pool's rotated keys: two tokens a row, the same numbers
+            assert pool[plane][0] == (cfg.kv_layers, BLOCKS, BS // 2, 1,
+                                      2 * cfg.mla_rope)
+            assert dense[plane][0][3:] == (1, cfg.mla_rope)
+        else:
+            assert pool[plane][0][:3] == (cfg.kv_layers, BLOCKS, BS)
+            assert pool[plane][0][3:] == dense[plane][0][3:]
         assert dense[plane][1] == cfg.dtype       # the scratch is never int8
         assert pool[plane][1] == (jnp.int8 if quantized else cfg.dtype)
     if quantized:
@@ -273,16 +279,27 @@ def test_latent_rows_and_lane_state(form):
         for i in range(steps):
             kv = kvstate.write(kv, plane, c[:, i:i + 1], r[:, i:i + 1],
                                first + i, True)
-        hand = []
-        for rows in (c, r):
-            pool = np.zeros(kv["k"].shape[:3] + rows.shape[-1:], np.float32)
-            for b in range(LANES):
-                for i in range(steps):
-                    at = int(first[b, 0]) + i
-                    pool[plane, TABLE[b, at // BS], at % BS] = rows[b, i]
-            hand.append(jnp.asarray(pool)[:, :, :, None])
+        hand = [np.zeros(kv[n].shape, np.float32) for n in ("k", "v")]
+        assert hand[1].shape[2:] == (BS // 2, 1, 2 * dr)
+        for b in range(LANES):
+            for i in range(steps):
+                at = int(first[b, 0]) + i
+                block, row = TABLE[b, at // BS], at % BS
+                hand[0][plane, block, row, 0] = c[b, i]
+                # a rotated key: token j and token j + BS / 2 share row j
+                half = row // (BS // 2)
+                hand[1][plane, block, row % (BS // 2), 0,
+                        half * dr:(half + 1) * dr] = r[b, i]
+        hand = [jnp.asarray(h) for h in hand]
         np.testing.assert_array_equal(np.asarray(kv["k"]), hand[0])
         np.testing.assert_array_equal(np.asarray(kv["v"]), hand[1])
+        # read back in token order, block by block
+        got = kvstate.read_blocks(kv, "v", jnp.asarray(TABLE[0]))
+        assert got.shape == (cfg.kv_layers, TABLE.shape[1], BS, 1, dr)
+        at = int(first[0, 0])
+        np.testing.assert_array_equal(
+            np.asarray(got[plane]).reshape(-1, dr)[at:at + steps],
+            np.asarray(r[0]))
         q_lat = _rows(9, (LANES, cfg.n_heads, dc), cfg.dtype)
         q_rope = _rows(10, (LANES, cfg.n_heads, dr), cfg.dtype)
         lengths = first[:, 0] + steps

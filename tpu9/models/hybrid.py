@@ -18,7 +18,10 @@ What a running sequence keeps differs by kind, and that is the point:
   block of keys at a time inside a kernel (``ops.latent_attention.
   blocked_prefill_attention``), over a short one every row at once; a decode
   step absorbs the expansions into the query and the output and attends the
-  latents themselves (``ops.latent_attention``). Its query is one full-rank
+  latents themselves, one kernel over the pages a lane holds that scores
+  the latents and the rotated keys alike (``ops.latent_attention``; the
+  pool keeps the rotated keys two tokens a row for it, which is
+  ``models.kvstate``'s to know). Its query is one full-rank
   matrix or goes through a latent with a norm of its own
   (``mla_q_latent``), its output has a sigmoid gate a head or none
   (``mla_out_gate``), its positions are plain rotary or YaRN's with the

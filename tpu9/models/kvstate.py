@@ -18,7 +18,15 @@ new kind of cache is written here.
   ``[n_blocks, block]`` and a ``"table"`` ``[lanes, columns]`` names each
   lane's blocks; an int8 pool holds the rows as int8 beside float32
   ``"k_scale"`` / ``"v_scale"`` planes of one rank less (one absmax scale a
-  (token, head) vector, ``ops.quant.quantize_kv``). In a DENSE cache (the
+  (token, head) vector, ``ops.quant.quantize_kv``). A pool's ROTATED KEYS
+  lie two tokens a row, ``[depth, n_blocks, block / 2, 1, 2 mla_rope]``:
+  token ``j`` of a page in the first ``mla_rope`` lanes of row ``j``, token
+  ``j + block / 2`` in the rest (``ops.latent_attention.pack_rotated``) —
+  the same bytes a block, and a page of them is one 128-lane block that the
+  decode kernel copies where it lies, which a 64-wide row is not.
+  :func:`splice_block` packs a block on its way in, :func:`read_blocks`
+  gives rows back in token order, :func:`write` puts a decode step's row
+  into its half row; no caller sees the packing. In a DENSE cache (the
   dense engine's, the batch-1 prefill scratch, a prefill bucket) they are
   ``[lanes, rows]``, always in the model's type. Axis ``HEAD_AXIS`` is the
   KV heads' in every one of them: what a mesh shards.
@@ -50,7 +58,8 @@ import numpy as np
 from ..ops.attention import (attention, chunk_prefill_attention,
                              decode_attention, paged_attention_dispatch,
                              paged_verify_attention)
-from ..ops.latent_attention import paged_latent_attention
+from ..ops.latent_attention import (pack_rotated, paged_latent_attention,
+                                     unpack_rotated)
 from ..ops.quant import quantize_kv
 
 TABLE = "table"
@@ -78,9 +87,19 @@ def paged_planes(cfg, quantized: bool = False) -> dict:
 def pool_shapes(cfg, n_blocks: int, block: int,
                 quantized: bool = False) -> dict:
     """``name -> (shape, dtype)`` of a pool of ``n_blocks`` blocks of
-    ``block`` entries (its table and the lanes' state apart)."""
-    return {name: ((cfg.kv_layers, n_blocks, block) + row, dt)
-            for name, (row, dt) in paged_planes(cfg, quantized).items()}
+    ``block`` entries (its table and the lanes' state apart). Latent
+    attention's rotated keys lie two tokens a row (the module's head)."""
+    planes = paged_planes(cfg, quantized)
+    shapes = {name: ((cfg.kv_layers, n_blocks, block) + row, dt)
+              for name, (row, dt) in planes.items()}
+    if cfg.layer_group:
+        if block % 2:
+            raise ValueError(f"a block of {block} entries: the rotated keys "
+                             "of a latent pool lie two tokens a row")
+        (heads, rope), dt = planes["v"]
+        shapes["v"] = ((cfg.kv_layers, n_blocks, block // 2, heads,
+                        2 * rope), dt)
+    return shapes
 
 
 def lane_shapes(cfg, lanes: int) -> dict:
@@ -111,10 +130,11 @@ def _bytes(shapes: dict) -> int:
 
 def block_bytes(cfg, block: int, quantized: bool = False) -> int:
     """Bytes ONE pool block of ``block`` entries holds across the whole
-    depth of the state: the sum over :func:`pool_shapes`. What the engine's
-    equal-HBM pool sizing, the feasibility gate and the tier's statistics
-    all price blocks with."""
-    return _bytes(pool_shapes(cfg, 1, block, quantized))
+    depth of the state: ``block`` rows of :func:`paged_planes` a plane,
+    however a pool lays them (any ``block``: a sequence's whole length is
+    priced as one). What the engine's equal-HBM pool sizing, the feasibility
+    gate and the tier's statistics all price blocks with."""
+    return cfg.kv_layers * block * _bytes(paged_planes(cfg, quantized))
 
 
 def lane_bytes(cfg, lanes: int = 1) -> int:
@@ -149,10 +169,17 @@ def dense_len(kv: dict) -> int:
     return 0 if is_paged(kv) else kv["k"].shape[2]
 
 
+def _packed(pool: dict) -> bool:
+    """Whether ``pool`` is latent attention's: its ``"v"`` plane the rotated
+    keys, two tokens a row."""
+    return pool["v"].shape[2] != pool["k"].shape[2]
+
+
 def pool_rows(pool: dict, k, v) -> list:
-    """The rows ``k`` and ``v`` ``[..., KH, D]`` as ``pool`` stores them,
-    ``[(name, rows)]`` in the order they are written: an int8 pool's are
-    quantized per (token, head) vector, their scales ``[..., KH]`` first."""
+    """The rows ``k`` and ``v`` ``[..., KH, D]`` as a per-head ``pool``
+    stores them, ``[(name, rows)]`` in the order they are written: an int8
+    pool's are quantized per (token, head) vector, their scales ``[..., KH]``
+    first."""
     if _SCALE["k"] not in pool:
         return [("k", k), ("v", v)]
     k, sk = quantize_kv(k)
@@ -160,9 +187,54 @@ def pool_rows(pool: dict, k, v) -> list:
     return [(_SCALE["k"], sk), (_SCALE["v"], sv), ("k", k), ("v", v)]
 
 
+def _lanes_view(plane):
+    """The rotated keys' pool ``[L, N, BS / 2, 1, 2 d_r]`` without its unit
+    axis, as the decode kernel takes it: a free reshape, and the view every
+    write goes through. Written as it is, the chip's compiler gave the
+    plane another tiling for the write than for the kernel and copied it
+    whole, there and back, a layer of a decode step and a splice (0.5 GB
+    each way at Kimi's sizes: PR 53's first traced run)."""
+    return plane.reshape(plane.shape[:3] + plane.shape[4:])
+
+
+def splice_block(pool: dict, phys, j: int, k, v) -> dict:
+    """``pool`` with one block's scratch rows ``k`` and ``v`` ``[L, BS, KH,
+    D]`` written at physical block ``phys[j]`` of every plane, as the pool
+    stores them: an int8 pool's quantized (:func:`pool_rows`), a latent
+    pool's rotated keys packed two tokens a row. (``phys[j]`` is taken once
+    a plane, as the splice always did: a per-head pool's programs lower to
+    the text they had.)"""
+    pool = dict(pool)
+    if not _packed(pool):
+        for name, rows in pool_rows(pool, k, v):
+            pool[name] = pool[name].at[:, phys[j]].set(rows)
+        return pool
+    block = phys[j]
+    pool["k"] = pool["k"].at[:, block].set(k)
+    # one scatter, TWO windows of ``[BS / 4, 2 d_r]`` a plane: a single
+    # window (any pool of one plane) is a ``dynamic_update_slice`` to the
+    # chip's compiler, which then tiles the whole plane to suit the update
+    # and copies it there and back (compiled for a described v5e: three
+    # copies of Ling's 67 MB plane a group program, two of Kimi's 0.7 GB)
+    rows = pack_rotated(v, 1)[:, :, 0]                 # [L, BS / 2, 2 d_r]
+    l, half_s, width = rows.shape
+    planes, first = jnp.meshgrid(
+        jnp.arange(l), jnp.arange(2) * (half_s // 2), indexing="ij")
+    at = jnp.stack([planes, jnp.full_like(planes, block), first],
+                   axis=-1).reshape(2 * l, 3)
+    pool["v"] = jax.lax.scatter(
+        _lanes_view(pool["v"]), at, rows.reshape(2 * l, half_s // 2, width),
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1, 2), inserted_window_dims=(0, 1),
+            scatter_dims_to_operand_dims=(0, 1, 2))
+    ).reshape(pool["v"].shape)
+    return pool
+
+
 def read_blocks(pool: dict, name: str, index, flat: bool = False):
     """Blocks ``index`` of plane ``name`` at every depth, ``[L, len(index),
-    BS, ...]``; an int8 pool's are dequantized (float32). ``flat``: the
+    BS, ...]`` in token order; an int8 pool's are dequantized (float32), a
+    latent pool's rotated keys unpacked. ``flat``: the
     blocks are taken from the pool as ``[L * N, BS, ...]`` (a free reshape)
     by ``layer * N + index``, a gather along the MAJOR axis. Taken along
     axis 1, as without it, the chip's compiler first copies the whole pool
@@ -173,13 +245,14 @@ def read_blocks(pool: dict, name: str, index, flat: bool = False):
     if flat:
         p = pool[name]
         l, n = p.shape[:2]
-        return p.reshape((l * n,) + p.shape[2:])[
+        g = p.reshape((l * n,) + p.shape[2:])[
             jnp.arange(l)[:, None] * n + index[None, :]]
-    g = pool[name][:, index]
+    else:
+        g = pool[name][:, index]
     sc = pool.get(_SCALE[name])
     if sc is not None:
         g = g.astype(jnp.float32) * sc[:, index][..., None]
-    return g
+    return unpack_rotated(g, 2) if name == "v" and _packed(pool) else g
 
 
 # -- write --------------------------------------------------------------------
@@ -191,6 +264,22 @@ def _pool_write(pool: jnp.ndarray, layer: int, bi, oi, value):
     stacked back."""
     with jax.named_scope("kv.write"):
         return pool.at[layer, bi, oi].set(value)
+
+
+def _packed_write(pool: jnp.ndarray, layer: int, bi, oi, value):
+    """The rotated keys' pool ``[L, N, BS / 2, 1, 2 d_r]`` with a decode
+    step's ``value`` ``[B, d_r]`` written at entry ``oi`` of block ``bi``:
+    each lane's key into its half of row ``oi % (BS / 2)``. One scatter of
+    ``B`` windows of ``d_r`` numbers, in place as :func:`_pool_write`'s."""
+    half_s, dr = pool.shape[2], value.shape[-1]
+    at = jnp.stack([jnp.full_like(bi, layer), bi, oi % half_s,
+                    oi // half_s * dr], axis=-1)
+    with jax.named_scope("kv.write"):
+        return jax.lax.scatter(
+            _lanes_view(pool), at, value, jax.lax.ScatterDimensionNumbers(
+                update_window_dims=(1,), inserted_window_dims=(0, 1, 2),
+                scatter_dims_to_operand_dims=(0, 1, 2, 3))
+        ).reshape(pool.shape)
 
 
 def _cache_write(cache: jnp.ndarray, layer: int, item, positions):
@@ -245,7 +334,7 @@ def write(kv: dict, layer, k, v, entries, decode: bool) -> dict:
         pos = entries[:, 0]
         bi, oi = table[jnp.arange(b), pos // bs], pos % bs
         return dict(kv, k=_pool_write(kv["k"], layer, bi, oi, k[:, 0, None]),
-                    v=_pool_write(kv["v"], layer, bi, oi, v[:, 0, None]))
+                    v=_packed_write(kv["v"], layer, bi, oi, v[:, 0]))
     if decode:
         pos = entries[:, 0]                        # [B]
         bi = table[jnp.arange(b), pos // bs]

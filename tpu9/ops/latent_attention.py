@@ -25,14 +25,19 @@ heads, from which a head's key is ``[W_uk,h c | k_rope]`` and its value
       o^_h = sum_s p_h(s) c_s          (then o_h = W_uv,h o^_h, the caller's)
 
   Two bodies, one result up to the order of the sums. The XLA form gathers
-  the lanes' table rows into ``[B, S, d_c]`` — EVERY column of the table,
-  whatever the lanes hold — and takes three einsums over it: the oracle, and
-  what runs off the chip. :func:`paged_latent_attention_kernel` walks a
-  lane's own pages (``ops.paged_attention``'s walk, PR 40, with ONE row a
-  token for all heads): a grid step a lane, ``ceil(len / BS)`` pages copied
-  in double-buffered waves, the next wave — after a lane's last, the next
-  lane's first — in flight under the arithmetic, an online softmax over a
-  wave's rows. :func:`paged_latent_attention` chooses.
+  the lanes' table rows into ``[B, S, d_c]`` and ``[B, S, d_r]`` — EVERY
+  column of the table, whatever the lanes hold — and takes three einsums
+  over them: the oracle, and what runs off the chip.
+  :func:`paged_latent_attention_kernel` walks a lane's own pages
+  (``ops.paged_attention``'s walk, PR 40, with ONE row a token for all
+  heads): a grid step a lane, ``ceil(len / BS)`` pages copied in
+  double-buffered waves — a page's latents and, with them, its rotated keys
+  — the next wave — after a lane's last, the next lane's first — in flight
+  under the arithmetic, BOTH parts of the scores and an online softmax over
+  a wave's rows. :func:`paged_latent_attention` chooses. A pool's rotated
+  keys lie two tokens a 128-lane row (:func:`pack_rotated`), so that a page
+  of them is a block the copy engine takes as it lies; the dense scratch of
+  the prefills keeps a token a row.
 
 bf16 operands, float32 accumulation and softmax, as the other attention ops.
 """
@@ -52,9 +57,13 @@ F32 = jnp.float32
 NEG_INF = -1e30
 # queries of a block of :func:`expanded_attention`
 QUERY_BLOCK = 512
-# pages of a wave of the decode kernel: 4 x 128 rows of 512 numbers are
-# 0.5 MB a slot in VMEM, and a wave is ONE softmax update over 512 rows
-WAVE_PAGES = 4
+# pages of a wave of the decode kernel: 8 x 128 rows of 576 numbers are
+# 1.1 MB a slot in VMEM, and a wave is ONE softmax update over 1,024 rows.
+# One call alone at Kimi's / Ling's shapes, 2 / 4 / 8 pages a wave: 1,748 /
+# 1,207 / 971 and 1,100 / 791 / 664 us (PR 53): what a wave costs beside its
+# products — the waits, the statistics, the accumulator's rescale — is paid
+# half as often
+WAVE_PAGES = 8
 LATENT_KERNEL = "paged_latent_attention"
 # the blocked prefill: its kernel's name in a trace; the scratch rows from
 # which a chunk takes it (under them :func:`expanded_attention` holds
@@ -106,27 +115,49 @@ def expanded_attention(q_nope, q_rope, k_nope, k_rope, v, q_pos, scale):
     return out.reshape((t,) + out.shape[2:])
 
 
-def kernel_declined(heads: int, latent: int, block_s: int, dtype) -> str:
-    """Why a decode step takes the XLA form ('' = the kernel runs)."""
+def kernel_declined(heads: int, latent: int, rope: int, block_s: int,
+                    dtype) -> str:
+    """Why a decode step takes the XLA form ('' = the kernel runs). The
+    kernel copies a page as two halves of ``block_s / 2`` latents and one
+    ``[block_s / 2, 2 * rope]`` block of rotated keys: each whole tiles."""
     from ..utils import on_tpu
     if not on_tpu():
         return "no TPU backend"
     if dtype != jnp.bfloat16:
         return f"a {jnp.dtype(dtype).name} cache"
-    if heads % 8 or latent % 128 or block_s % 16:
-        return (f"heads={heads}, latent={latent}, block={block_s}: not "
-                "whole tiles")
+    if heads % 8 or latent % 128 or 2 * rope % 128 or block_s % 32:
+        return (f"heads={heads}, latent={latent}, rope={rope}, "
+                f"block={block_s}: not whole tiles")
     return ""
+
+
+def pack_rotated(rows, axis: int):
+    """Rotated keys ``[..., BS, ..., d_r]`` (``axis`` the tokens' of one
+    page) as the POOL holds them, ``[..., BS / 2, ..., 2 d_r]``: token ``j``
+    in lanes ``0 .. d_r - 1`` of row ``j``, token ``j + BS / 2`` in lanes
+    ``d_r .. 2 d_r - 1`` of the same row. A rotated key is 64 numbers, half a
+    128-lane row, which the chip's copy engine will not cut out of HBM; two a
+    row, a page's are one block that it copies as it lies, and each half of
+    a row block is a contiguous run of tokens."""
+    first, second = jnp.split(rows, 2, axis=axis)
+    return jnp.concatenate([first, second], axis=-1)
+
+
+def unpack_rotated(packed, axis: int):
+    """:func:`pack_rotated` undone: ``[..., BS, ..., d_r]`` in token order."""
+    first, second = jnp.split(packed, 2, axis=-1)
+    return jnp.concatenate([first, second], axis=axis)
 
 
 def paged_latent_attention(q_lat, q_rope, c_pool, r_pool, table, lengths,
                            layer, scale):
     """``q_lat`` [B, H, d_c] (``W_uk`` absorbed), ``q_rope`` [B, H, d_r];
-    pools ``[L, N, BS, 1, d]``; ``table`` [B, MB]; lane b attends its first
-    ``lengths[b]`` rows (0: none, zeros out). Returns [B, H, d_c] float32.
-    The kernel where it runs, else the XLA form."""
+    the latents' pool ``[L, N, BS, 1, d_c]`` and the rotated keys' ``[L, N,
+    BS / 2, 1, 2 d_r]`` (:func:`pack_rotated`); ``table`` [B, MB]; lane b
+    attends its first ``lengths[b]`` rows (0: none, zeros out). Returns
+    [B, H, d_c] float32. The kernel where it runs, else the XLA form."""
     if not kernel_declined(q_lat.shape[1], c_pool.shape[-1],
-                           c_pool.shape[2], c_pool.dtype):
+                           q_rope.shape[-1], c_pool.shape[2], c_pool.dtype):
         return paged_latent_attention_kernel(
             q_lat, q_rope, c_pool, r_pool, table, lengths, layer, scale)
     return paged_latent_attention_xla(q_lat, q_rope, c_pool, r_pool, table,
@@ -139,7 +170,7 @@ def paged_latent_attention_xla(q_lat, q_rope, c_pool, r_pool, table, lengths,
     b, mb = table.shape
     bs = c_pool.shape[2]
     c = c_pool[layer, table].reshape(b, mb * bs, -1)             # [B, S, dc]
-    r = r_pool[layer, table].reshape(b, mb * bs, -1)
+    r = unpack_rotated(r_pool[layer, table], 2).reshape(b, mb * bs, -1)
     scores = (jnp.einsum("bhc,bsc->bhs", q_lat.astype(c.dtype), c,
                          preferred_element_type=F32)
               + jnp.einsum("bhr,bsr->bhs", q_rope.astype(r.dtype), r,
@@ -152,19 +183,24 @@ def paged_latent_attention_xla(q_lat, q_rope, c_pool, r_pool, table, lengths,
 
 # -- the decode step as a Pallas kernel ----------------------------------------
 
-def _latent_kernel(table_ref, len_ref, q_lat_ref, s_rope_ref, c_hbm, o_ref,
-                   c_buf, sem, slot_ref, m_scr, l_scr, acc_scr,
+def _latent_kernel(table_ref, len_ref, q_lat_ref, q_rope_ref, c_hbm, r_hbm,
+                   o_ref, c_buf, r_buf, sem, slot_ref, m_scr, l_scr, acc_scr,
                    *, scale: float, layer: int, block_s: int, wave: int):
-    """One lane a grid step. ``c_hbm`` ``[L, N, BS, d_c]`` is the latents'
-    pool whole, in HBM; ``c_buf`` ``[2, wave * BS, d_c]`` holds two waves of
-    pages, ``sem`` ``[2]`` a slot; ``slot_ref`` (SMEM, alive across grid
-    steps) is the slot of this step's first wave, whose copies the step
-    before started. ``s_rope_ref`` ``[1, H, waves * wave * BS]``: the rotary
-    part of the lane's scores, unscaled. Running maximum and sum
-    ``[H, 128]`` (the same in all lanes of a row), accumulator ``[H, d_c]``,
-    float32."""
+    """One lane a grid step. ``c_hbm`` ``[L, N, BS, d_c]`` and ``r_hbm``
+    ``[L, N, BS / 2, 2 d_r]`` are the pool whole, in HBM. A page comes as its
+    rows lie in ``r_hbm``, in two HALVES: half 0 its first ``BS / 2`` tokens
+    (lanes ``0 .. d_r - 1`` of the rotated keys' rows), half 1 the rest.
+    ``c_buf`` ``[2, 2, wave * BS / 2, d_c]`` (slot, half) and ``r_buf``
+    ``[2, wave * BS / 2, 2 d_r]`` hold two waves of pages, ``sem`` ``[2]`` a
+    slot; a softmax does not care in which order a wave's rows come, so no
+    score is ever shuffled into token order. ``slot_ref`` (SMEM, alive
+    across grid steps) is the slot of this step's first wave, whose copies
+    the step before started. Running maximum and sum ``[H, 128]`` (the same
+    in all lanes of a row), accumulator ``[H, d_c]``, float32."""
     b = pl.program_id(0)
     batch = pl.num_programs(0)
+    half_s = block_s // 2
+    dr = q_rope_ref.shape[-1]
 
     def pages_of(lane):
         return jnp.minimum(
@@ -173,12 +209,16 @@ def _latent_kernel(table_ref, len_ref, q_lat_ref, s_rope_ref, c_hbm, o_ref,
 
     def for_wave(lane, w, slot, act):
         """``act`` on every copy that brings wave ``w`` of ``lane`` into
-        ``slot``: one a page the lane holds there."""
+        ``slot``: three a page the lane holds there."""
         def one(page, _):
             block = table_ref[lane, w * wave + page]
-            rows = pl.ds(pl.multiple_of(page * block_s, block_s), block_s)
-            act(pltpu.make_async_copy(c_hbm.at[layer, block],
-                                      c_buf.at[slot, rows], sem.at[slot]))
+            rows = pl.ds(pl.multiple_of(page * half_s, half_s), half_s)
+            for half in range(2):
+                act(pltpu.make_async_copy(
+                    c_hbm.at[layer, block, pl.ds(half * half_s, half_s)],
+                    c_buf.at[slot, half, rows], sem.at[slot]))
+            act(pltpu.make_async_copy(r_hbm.at[layer, block],
+                                      r_buf.at[slot, rows], sem.at[slot]))
         jax.lax.fori_loop(
             0, jnp.minimum(pages_of(lane) - w * wave, wave), one, None)
 
@@ -201,8 +241,17 @@ def _latent_kernel(table_ref, len_ref, q_lat_ref, s_rope_ref, c_hbm, o_ref,
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
-    q_lat = q_lat_ref[0]
-    rows = wave * block_s
+    q_lat, q_rope = q_lat_ref[0], q_rope_ref[0]
+    rows = wave * half_s
+
+    def place(shape, axis):
+        """Where row ``i`` of a half wave's buffer lies in half 0 of the
+        wave's tokens: page ``i // half_s``, row ``i % half_s`` of it."""
+        i = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+        return i + half_s * sum((i >= page * half_s).astype(jnp.int32)
+                                for page in range(1, wave))
+
+    down, across = place((rows, 1), 0), place((q_lat.shape[0], rows), 1)
 
     def one_wave(w, _):
         slot = jax.lax.rem(first_slot + w, 2)
@@ -216,26 +265,36 @@ def _latent_kernel(table_ref, len_ref, q_lat_ref, s_rope_ref, c_hbm, o_ref,
             start(b + 1, 0, 1 - slot)
 
         wait(b, w, slot)
-        first = pl.multiple_of(w * rows, rows)
-        # a row past the lane's length — the tail of its last page, a page
-        # of the wave it does not hold — is whatever VMEM held: zeroed
-        # before it meets a probability of 0
-        live = first + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, 1), 0) < seq_len
-        c = jnp.where(live, c_buf[slot], 0)
-        s = (jax.lax.dot_general(q_lat, c, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=F32)
-             + s_rope_ref[0, :, pl.ds(first, rows)]) * scale
-        pos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < seq_len, s, NEG_INF)
+        r = r_buf[slot]
+        c, s, seen = [], [], []
+        for half in range(2):
+            first = w * wave * block_s + half * half_s
+            # a row past the lane's length — the tail of its last page, a
+            # page of the wave it does not hold — is whatever VMEM held:
+            # its latent zeroed before it meets a probability of 0, its
+            # score (the rotated key's part with it) replaced
+            c.append(jnp.where(first + down < seq_len, c_buf[slot, half], 0))
+            seen.append(first + across < seq_len)
+            s.append(jnp.where(seen[half], (
+                jax.lax.dot_general(q_lat, c[half], (((1,), (1,)), ((), ())),
+                                    preferred_element_type=F32)
+                + jax.lax.dot_general(
+                    q_rope, r[:, half * dr:(half + 1) * dr],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=F32)) * scale, NEG_INF))
         m_prev, l_prev = m_scr[...], l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_new = jnp.maximum(m_prev, jnp.max(
+            jnp.maximum(s[0], s[1]), axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(pos < seq_len, jnp.exp(s - m_new[:, :1]), 0.0)
-        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + jax.lax.dot_general(
-            p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
-            preferred_element_type=F32)
+        p = [jnp.where(seen[half], jnp.exp(s[half] - m_new[:, :1]), 0.0)
+             for half in range(2)]
+        l_scr[...] = alpha * l_prev + jnp.sum(p[0] + p[1], axis=-1,
+                                              keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha[:, :1] + sum(
+            jax.lax.dot_general(p[half].astype(c[half].dtype), c[half],
+                                (((1,), (0,)), ((), ())),
+                                preferred_element_type=F32)
+            for half in range(2))
         m_scr[...] = m_new
 
     jax.lax.fori_loop(0, n_waves, one_wave, None)
@@ -251,24 +310,20 @@ def _latent_kernel(table_ref, len_ref, q_lat_ref, s_rope_ref, c_hbm, o_ref,
 def paged_latent_attention_kernel(q_lat, q_rope, c_pool, r_pool, table,
                                   lengths, layer: int, scale: float,
                                   interpret: bool = False):
-    """:func:`paged_latent_attention` by :func:`_latent_kernel`: the
-    latents — eight ninths of a row — are read where they lie, the pages
-    that hold tokens and each once for all heads; an idle lane costs one
-    empty grid step. The rotary part of the scores stays ``jax.numpy``: a
-    rotated key is 64 numbers, half a 128-lane row, which the chip's copy
-    engine will not cut out of HBM; gathered over the table's width it is an
-    eighth of what the XLA form gathers."""
+    """:func:`paged_latent_attention` by :func:`_latent_kernel`: a row —
+    the latent and its rotated key — is read where it lies, the pages that
+    hold tokens and each once for all heads; an idle lane costs one empty
+    grid step. Nothing is gathered in front of the kernel and no score
+    exists outside it."""
     batch, heads, dc = q_lat.shape
+    dr = q_rope.shape[-1]
     mb = table.shape[1]
     n_layers, n_blocks, block_s = c_pool.shape[:3]
     wave = min(WAVE_PAGES, mb)
-    rows = -(-mb // wave) * wave * block_s          # whole waves
-    r = r_pool[layer, table].reshape(batch, mb * block_s, -1)
-    s_rope = jnp.einsum("bhr,bsr->bhs", q_rope.astype(r.dtype), r,
-                        preferred_element_type=F32)
-    s_rope = jnp.pad(s_rope, ((0, 0), (0, 0), (0, rows - mb * block_s)))
-    # the pool's unit axis away: a free reshape
+    rows = wave * block_s // 2
+    # the pools' unit axis away: free reshapes
     c_pool = c_pool.reshape(n_layers, n_blocks, block_s, dc)
+    r_pool = r_pool.reshape(n_layers, n_blocks, block_s // 2, 2 * dr)
 
     def lane(b, table, lens):
         return (b, 0, 0)
@@ -280,11 +335,13 @@ def paged_latent_attention_kernel(q_lat, q_rope, c_pool, r_pool, table,
             num_scalar_prefetch=2,
             grid=(batch,),
             in_specs=[pl.BlockSpec((1, heads, dc), lane),
-                      pl.BlockSpec((1, heads, rows), lane),
+                      pl.BlockSpec((1, heads, dr), lane),
+                      pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((1, heads, dc), lane),
             scratch_shapes=[
-                pltpu.VMEM((2, wave * block_s, dc), c_pool.dtype),
+                pltpu.VMEM((2, 2, rows, dc), c_pool.dtype),
+                pltpu.VMEM((2, rows, 2 * dr), r_pool.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((heads, 128), F32),
@@ -300,7 +357,8 @@ def paged_latent_attention_kernel(q_lat, q_rope, c_pool, r_pool, table,
         name=LATENT_KERNEL,
         interpret=interpret,
     )(table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q_lat.astype(c_pool.dtype), s_rope, c_pool)
+      q_lat.astype(c_pool.dtype), q_rope.astype(r_pool.dtype), c_pool,
+      r_pool)
 
 
 # -- a prefill over a long cache, blocked over keys -----------------------------

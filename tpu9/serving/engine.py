@@ -735,7 +735,7 @@ class InferenceEngine:
             from ..ops.latent_attention import (blocked_prefill_declined,
                                                 prefill_kernel_declined)
             decode = "latent attention, absorbed: " + ran(
-                kernel_declined(cfg.n_heads, cfg.mla_latent,
+                kernel_declined(cfg.n_heads, cfg.mla_latent, cfg.mla_rope,
                                 self.ecfg.kv_block_size, cfg.dtype))
             if blocked_prefill_declined(self.graphs.scratch_len):
                 prefill = "xla: latent attention, expanded"

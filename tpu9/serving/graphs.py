@@ -406,7 +406,6 @@ class GraphFactory:
         An int8 pool quantizes each block on the way in (per-vector absmax
         scales land in the scale planes at the same physical index)."""
         bs = self.ecfg.kv_block_size
-        pool = dict(pool)
         # the scratch is addressed by entry. With ``attn_window`` the first
         # block of ``phys`` takes the page BEFORE the chunk's own: the
         # summaries of the window that this chunk's program closed (the
@@ -425,9 +424,8 @@ class GraphFactory:
                     scratch_k[:, 0], source(j), bs, axis=1)
                 blk_v = jax.lax.dynamic_slice_in_dim(
                     scratch_v[:, 0], source(j), bs, axis=1)
-                # [L,bs,KH,D] as the pool stores them (int8: and [L,bs,KH])
-                for name, rows in kvstate.pool_rows(pool, blk_k, blk_v):
-                    pool[name] = pool[name].at[:, phys[j]].set(rows)
+                # [L,bs,KH,D], written as the pool stores them
+                pool = kvstate.splice_block(pool, phys, j, blk_k, blk_v)
             return self.policy.constrain_kv(pool)
 
     # -- the summarise of a closed window (``attn_window``) -------------------
