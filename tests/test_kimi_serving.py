@@ -170,6 +170,56 @@ def test_the_served_routing_of_a_hit_is_whole(params, served, monkeypatch):
     assert (a[:2 * C] == b[:2 * C]).all()
 
 
+def test_a_page_aligned_hit_resumes_at_its_page_and_its_record_is_whole(
+        params, monkeypatch):
+    """ISSUE 54, at the rehearsal's engine shapes (block 16, chunk 32): a
+    hit of three pages is no chunk multiple. The suffix starts at token 48
+    all the same — through one chunk program, and through a group and a
+    chunk — no cached row is computed again, the tokens are those of an
+    engine without a cache, and the record takes positions 0–47 from the
+    sequence that made the pages."""
+    from benchmark.reference import served_routing
+    from tpu9.serving import routed_experts
+    rng = np.random.default_rng(54)
+    shared = rng.integers(3, 250, 3 * BS + 5).tolist()
+    probes = [{"prompt": shared + rng.integers(3, 250, n).tolist()}
+              for n in (8, 8, 70)]
+    kw = dict(prefill_chunk=2 * BS, prefill_buckets=(2 * BS,))
+    engine = InferenceEngine(params, SMALL, _ecfg(**kw))
+    _serve(engine, probes, 6)
+    stats = engine.stats()
+    assert stats["prefix_cache"]["hits"] == 2
+    assert stats["prefix_rows_reused"] == 2 * 3 * BS
+    assert stats["prefix_rows_recomputed"] == 0
+    # 61 tokens cold: a group of 2; 13 behind 48: a chunk; 75 behind 48: a
+    # group at token 48 and a chunk at 112
+    assert stats["admit_chunks"] == 2 + 1 + 3
+    assert stats["admit_chunks_grouped"] == 2 + 0 + 2
+    assert stats["graph_compiles_post_warmup"] == 0
+    fresh = InferenceEngine(params, SMALL,
+                            _ecfg(prefix_cache_blocks=0, **kw))
+    again = [dict(p, tokens=None) for p in probes[1:]]
+    _serve(fresh, again, 6)
+    assert [p["tokens"] for p in again] == [p["tokens"] for p in probes[1:]]
+
+    monkeypatch.setattr(served_routing, "provider", routed_experts.records)
+    kept_of = {tuple(fed): picks for fed, picks in routed_experts.records()}
+    reference = correctness.load_reference("kimi")
+    first = kept_of[tuple((probes[0]["prompt"] + probes[0]["tokens"])[:-1])]
+    for p in probes[1:]:
+        seq = p["prompt"] + p["tokens"]
+        kept = kept_of[tuple(seq[:-1])]
+        assert kept.shape == (len(seq) - 1, 2, 4)
+        assert (kept[:3 * BS] == first[:3 * BS]).all()
+        told = []
+        reference.forward(params, jnp.asarray(seq, jnp.int32),
+                          _model(routing_tie=1e-5), told)
+        for layer, said in enumerate(told):
+            assert (np.asarray(said["served"])[:len(kept)]
+                    == kept[:, layer]).all()
+            assert np.asarray(said["taken"])[:len(kept)].all()
+
+
 def test_a_record_behind_a_hit_nobody_made_is_left_out():
     from tpu9.serving import routed_experts
     before = list(routed_experts._finished)

@@ -402,6 +402,45 @@ def test_unaligned_prefix_hit_does_not_corrupt_kv(tiny):
     assert warm == cold
 
 
+@pytest.mark.parametrize("max_seq_len,n,recomputed,chunks", [
+    (256, 76, 0, 1),       # room: resume at the hit's own page, 28 tokens
+    (128, 120, 16, 3),     # 48 + 3 x 32 passes 128: round down to 32
+])
+def test_a_hit_resumes_at_its_own_page_where_the_scratch_has_room(
+        tiny, max_seq_len, n, recomputed, chunks):
+    """ISSUE 54; a fast twin of the slow test above. Block 16 / chunk 32: a
+    hit of 48 tokens is no chunk multiple. Where the admission's last chunk
+    window stays inside the scratch the suffix starts at token 48 — one
+    chunk fewer than from 32 — and nothing cached is computed again; where
+    that window would pass ``max_seq_len`` the hit rounds down, as it always
+    did. Either way the tokens are the cold engine's."""
+    prompt_a = [(i * 13) % 251 + 1 for i in range(50)]    # caches 48 tokens
+    prompt_b = prompt_a[:48] + [(i * 7) % 251 + 1 for i in range(n - 48)]
+
+    def make(prefix_blocks):
+        return _engine(tiny, max_seq_len=max_seq_len, kv_block_size=16,
+                       prefill_chunk=32, prefill_buckets=(32,),
+                       kv_pool_blocks=24, prefix_cache_blocks=prefix_blocks)
+
+    async def run(engine):
+        await engine.start()
+        await engine.generate(prompt_a, max_new_tokens=2)
+        before = engine.stats()["admit_chunks"]
+        out = await engine.generate(prompt_b, max_new_tokens=6)
+        await engine.stop()
+        return out, engine.stats()["admit_chunks"] - before
+
+    cold, _ = _run(run(make(0)))
+    warm_engine = make(4)
+    warm, warm_chunks = _run(run(warm_engine))
+    stats = warm_engine.stats()
+    assert stats["prefix_cache"]["hits"] == 1
+    assert stats["prefix_rows_recomputed"] == recomputed
+    # from token 32 the suffix is 2 chunks (44 tokens) and 3 (88)
+    assert warm_chunks == chunks == -(-(n - 32) // 32) - (recomputed == 0)
+    assert warm == cold
+
+
 def test_max_seq_len_not_chunk_multiple_rejected(tiny):
     """Advisor r04 (medium): max_seq_len % prefill_chunk != 0 lets the
     final chunk of even an UNCACHED long prompt clamp past the cache end —

@@ -533,6 +533,10 @@ class InferenceEngine:
                        # went through the wide group forward
                        "admit_chunks": 0, "admit_chunks_grouped": 0,
                        "admit_interleaved_windows": 0,
+                       # cached rows below a prefix hit that an admission
+                       # prefilled again: the hit rounded down to a chunk
+                       # (``_admit_lookup``'s fall-back)
+                       "prefix_rows_recomputed": 0,
                        "spec_windows": 0, "spec_proposed": 0,
                        "spec_accepted": 0, "deadline_expired": 0,
                        # kvwire (ISSUE 16): block-ship accounting — flat
@@ -1702,7 +1706,7 @@ class InferenceEngine:
 
     async def _admit_lookup(self, req: _Request) -> tuple:
         """Prefix-cache lookup of one admission: ``(shared blocks, retained
-        for the slot; cached tokens, rounded down to a chunk)``."""
+        for the slot; cached tokens the suffix resumes behind)``."""
         entry = self.prefix_cache.lookup(req.prompt) \
             if self.ecfg.prefix_cache_blocks > 0 else None
         if entry is not None and entry.tier == "host":
@@ -1713,15 +1717,20 @@ class InferenceEngine:
             entry = await self._uppage_entry(entry, req.request_id)
         shared: list[int] = list(entry.blocks) if entry else []
         p = entry.n_tokens if entry else 0
-        # cached prefixes land on BLOCK boundaries, chunk windows on CHUNK
-        # boundaries; an unaligned p would put the final window past
-        # max_seq_len where dynamic_update_slice clamps its start backwards
-        # over valid prefix KV (advisor r04). Round p down to a chunk
-        # multiple: positions [p', p) are recomputed and re-spliced with
-        # bit-identical values (KV at position t depends only on tokens
-        # <= t, which the cached prefix shares), so overwriting the shared
-        # blocks is value-safe.
-        p -= p % self._chunk
+        # the suffix resumes at the hit's own page: the chunk programs take
+        # their offset as an operand and mask by position, and the splice
+        # addresses whole pages. One case cannot: cached prefixes land on
+        # BLOCK boundaries, chunk windows are CHUNK wide, and a last window
+        # that would pass max_seq_len has its start clamped backwards by
+        # dynamic_update_slice, over valid prefix KV (advisor r04). Only
+        # then round p down to a chunk multiple: positions [p', p) are
+        # recomputed and re-spliced with bit-identical values (KV at
+        # position t depends only on tokens <= t, which the cached prefix
+        # shares), so overwriting the shared blocks is value-safe.
+        c = self._chunk
+        if p + -(-(len(req.prompt) - p) // c) * c > self.ecfg.max_seq_len:
+            self._stats["prefix_rows_recomputed"] += p % c
+            p -= p % c
         self.allocator.retain(shared)
         if entry is not None:
             # blocks are retained: a concurrent admission's eviction can
@@ -1864,7 +1873,7 @@ class InferenceEngine:
     def _kvtier_tick(self) -> None:
         """Window-boundary down-paging: when the scheduler's low-water
         check fires, LRU unpinned prefix entries spill to host DRAM
-        *before* allocation pressure lets ``_evict_one`` destroy them.
+        *before* allocation pressure lets eviction destroy them.
         Runs only at the window boundary — the gather is a device sync
         and must never ride the per-token path."""
         quota = self.scheduler.downpage_quota()

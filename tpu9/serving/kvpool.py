@@ -17,7 +17,6 @@ via stats, not by code); this module owns their engine-side composition.
 from __future__ import annotations
 
 import collections
-import time
 from typing import Any, Optional
 
 import numpy as np
@@ -356,7 +355,7 @@ class KvPool:
         pool blocks, keep the entry alive under ``tier="host"``. Called
         at window boundaries only — the gather is a device sync and must
         never sit on the per-token path. False = the host tier could not
-        fit it (the caller lets ``_evict_one`` destroy it as before)."""
+        fit it (the caller lets eviction destroy it as before)."""
         if self.host_tier is None or entry.pins or not entry.blocks:
             return False
         idx = np.asarray(entry.blocks, dtype=np.int32)  # tpu9: noqa[JAX001] host-side block-index list, no device value involved
@@ -413,7 +412,7 @@ class KvPool:
         their prefix-cache entries are journaled as evicted. Either way
         the choice leaves a ``kv_tier`` decision record."""
         from . import kvwire
-        now = time.monotonic()
+        now = self.prefix_cache.clock()
         for key, ent in reaped:
             pe = self.prefix_cache._entries.get(key)
             score = 0.0

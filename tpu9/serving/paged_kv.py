@@ -23,7 +23,7 @@ from __future__ import annotations
 import collections
 import hashlib
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Optional
 
 
@@ -58,7 +58,8 @@ class PrefixEntry:
     key: bytes
     blocks: list[int]          # full, block-aligned prefix blocks (shared)
     n_tokens: int
-    last_used: float = field(default_factory=time.monotonic)
+    # stamped by the owning cache's clock at insert / adopt and every hit
+    last_used: float = 0.0
     # admissions holding this entry between lookup() and retaining its
     # blocks: eviction must not release blocks out from under them
     pins: int = 0
@@ -136,16 +137,33 @@ class PrefixCache:
     Entries hold refcounts on their blocks; eviction (LRU, or on-demand
     when the allocator runs dry) releases them. Keys are hashes of
     block-aligned token prefixes, so a lookup walks from the longest
-    possible prefix down and the first hit is the best reuse."""
+    possible prefix down and the first hit is the best reuse.
+
+    The budget ``max_blocks`` is held against the DISTINCT pool blocks that
+    device-tier entries reference (``held_blocks``): the pages a session's
+    consecutive turns share are one page each, however many entries name
+    them. It bounds the entries too: no two entries end on the same page
+    (a page's content is its tokens' and the key is their hash), so the
+    entries that add no page of their own (an older turn under a newer
+    one) never outnumber the pages they ride on."""
 
     def __init__(self, allocator: BlockAllocator, max_blocks: int):
         self.allocator = allocator
         self.max_blocks = max_blocks
+        # stamps every entry's ``last_used``; a test replays a schedule by
+        # setting it
+        self.clock = time.monotonic
         self._entries: dict[bytes, PrefixEntry] = {}
+        # device-tier entries referencing each pool block, and the number
+        # of blocks with at least one: kept where an entry gains or loses
+        # its blocks (_attach / _detach)
+        self._block_entries = [0] * allocator.n_blocks
+        self._held = 0
         self.hits = 0
         self.misses = 0
         self.tokens_reused = 0
         self.evictions = 0      # lifetime counter (flight-recorder deltas)
+        self.evictions_freed = 0    # ... that gave the allocator a block back
         self.pinned = 0         # live lookup pins (O(1), not an entry scan)
         self.adopted = 0        # entries imported off the wire (ISSUE 16)
         self.spills = 0         # device→host down-pages (prefix survives)
@@ -183,7 +201,31 @@ class PrefixCache:
 
     @property
     def held_blocks(self) -> int:
-        return sum(len(e.blocks) for e in self._entries.values())
+        """Distinct pool blocks that device-tier entries reference."""
+        return self._held
+
+    def _attach(self, entry: PrefixEntry, blocks: list[int]) -> None:
+        """``entry`` becomes device-resident over ``blocks``, whose
+        allocator references are the entry's from here on."""
+        entry.blocks = blocks
+        entry.tier = "device"
+        counts = self._block_entries
+        for b in blocks:
+            if counts[b] == 0:
+                self._held += 1
+            counts[b] += 1
+
+    def _detach(self, entry: PrefixEntry) -> int:
+        """``entry`` gives up its blocks; returns how many of them went
+        back to the allocator's free list (nobody else held them)."""
+        counts = self._block_entries
+        for b in entry.blocks:
+            counts[b] -= 1
+            if counts[b] == 0:
+                self._held -= 1
+        free = self.allocator.free_count
+        self.allocator.release(entry.blocks)
+        return self.allocator.free_count - free
 
     def contains(self, key: bytes) -> bool:
         return key in self._entries
@@ -203,7 +245,7 @@ class PrefixCache:
         while nb > 0:
             entry = self._entries.get(self._key(prompt[:nb * bs]))
             if entry is not None:
-                entry.last_used = time.monotonic()
+                entry.last_used = self.clock()
                 entry.pins += 1
                 entry.hits += 1
                 self.pinned += 1
@@ -243,7 +285,7 @@ class PrefixCache:
             # host-tier entries hold no pool blocks to gather — keep
             # walking down to the longest DEVICE-resident prefix
             if entry is not None and entry.tier == "device":
-                entry.last_used = time.monotonic()
+                entry.last_used = self.clock()
                 entry.pins += 1
                 self.pinned += 1
                 return entry
@@ -261,8 +303,9 @@ class PrefixCache:
         if (nb == 0 or self.max_blocks <= 0 or nb > self.max_blocks
                 or key in self._entries):
             return False
-        self._entries[key] = PrefixEntry(key=key, blocks=list(blocks),
-                                         n_tokens=n_tokens)
+        self._entries[key] = entry = PrefixEntry(
+            key=key, blocks=[], n_tokens=n_tokens, last_used=self.clock())
+        self._attach(entry, list(blocks))
         self.adopted += 1
         self._evict_to_budget()
         return True
@@ -280,31 +323,33 @@ class PrefixCache:
         key = self._key(prompt[:nb * bs])
         ent = self._entries.get(key)
         if ent is not None:
-            ent.last_used = time.monotonic()
+            ent.last_used = self.clock()
             # a host-tier entry re-prefilled on-device (recompute beat the
             # up-page, or tiering raced admission): upgrade it in place —
             # share the fresh slot blocks, drop the redundant host copy
             if ent.tier == "host" and not ent.blocks:
                 blocks = slot_blocks[:nb]
                 self.allocator.retain(blocks)
-                ent.blocks = blocks
-                ent.tier = "device"
+                self._attach(ent, blocks)
                 ent.n_tokens = nb * bs
                 if self.on_host_drop is not None:
                     self.on_host_drop(key)
+                self._evict_to_budget()
             return
         blocks = slot_blocks[:nb]
         self.allocator.retain(blocks)
-        self._entries[key] = PrefixEntry(key=key, blocks=blocks,
-                                         n_tokens=nb * bs)
+        self._entries[key] = ent = PrefixEntry(
+            key=key, blocks=[], n_tokens=nb * bs, last_used=self.clock())
+        self._attach(ent, blocks)
         self._evict_to_budget()
 
     def _evict_to_budget(self) -> None:
-        while self.held_blocks > self.max_blocks and self._evict_one():
-            pass
+        self._evict_while(lambda: self._held > self.max_blocks)
 
-    def _evict_one(self) -> bool:
-        """Evict the LRU *unpinned* DEVICE entry. Pinned entries (a
+    def _evict_while(self, over) -> None:
+        """Evict unpinned DEVICE entries, least recently used first, for
+        as long as ``over()`` holds; the victims are ordered once a call
+        (nothing awaits in here, so the order stands). Pinned entries (a
         lookup handed their blocks to an admission that hasn't retained
         them yet) are untouchable — evicting one would release blocks
         another coroutine is about to splice into a slot. Host-tier
@@ -313,23 +358,30 @@ class PrefixCache:
         eviction lands in the delta journal so the next heartbeat
         retracts the directory advertisement (ISSUE 20 satellite — the
         silent prefix-loss window)."""
-        victims = [e for e in self._entries.values()
-                   if e.pins == 0 and e.tier == "device"]
-        if not victims:
-            return False
-        oldest = min(victims, key=lambda e: e.last_used)
-        del self._entries[oldest.key]
-        self.allocator.release(oldest.blocks)
+        if not over():
+            return
+        victims = sorted((e for e in self._entries.values()
+                          if e.pins == 0 and e.tier == "device"),
+                         key=lambda e: e.last_used)
+        for entry in victims:
+            if not over():
+                return
+            self._destroy(entry, "evict")
+
+    def _destroy(self, entry: PrefixEntry, kind: str) -> None:
+        """Forget ``entry`` and release its blocks, journaling the loss."""
+        del self._entries[entry.key]
         self.evictions += 1
-        self._note_delta("evict", oldest.key)
-        return True
+        if self._detach(entry):
+            self.evictions_freed += 1
+        self._note_delta(kind, entry.key)
 
     # -- host tier transitions (ISSUE 20) ------------------------------------
 
     def spill_candidates(self, n: int) -> list[PrefixEntry]:
         """Up to ``n`` LRU unpinned device entries — what a window-
         boundary down-page would move to host DRAM instead of letting
-        ``_evict_one`` destroy. Pinned / in-flight entries never move."""
+        eviction destroy. Pinned / in-flight entries never move."""
         victims = [e for e in self._entries.values()
                    if e.pins == 0 and e.tier == "device" and e.blocks]
         victims.sort(key=lambda e: e.last_used)
@@ -341,7 +393,7 @@ class PrefixCache:
         survives for lookup. Caller guarantees the planes were captured
         first and the entry is unpinned."""
         assert entry.pins == 0 and entry.tier == "device"
-        self.allocator.release(entry.blocks)
+        self._detach(entry)
         entry.blocks = []
         entry.tier = "host"
         self.spills += 1
@@ -353,33 +405,29 @@ class PrefixCache:
         now back the entry on-device. The host copy is dropped by the
         pool, not here."""
         assert entry.tier == "host" and not entry.blocks
-        entry.blocks = list(blocks)
-        entry.tier = "device"
+        self._attach(entry, list(blocks))
 
     def drop(self, key: bytes, kind: str = "evict") -> None:
         """Destroy an entry outright (host-tier reap, or adoption
         cleanup), journaling the loss for the directory."""
-        ent = self._entries.pop(key, None)
-        if ent is None:
-            return
-        if ent.blocks:
-            self.allocator.release(ent.blocks)
-        self.evictions += 1
-        self._note_delta(kind, key)
+        ent = self._entries.get(key)
+        if ent is not None:
+            self._destroy(ent, kind)
 
     def evict_for_space(self, blocks_needed: int) -> None:
         """Free cache-held blocks until the allocator can satisfy an
         allocation (called when a fresh alloc comes up short)."""
-        while (self.allocator.free_count < blocks_needed
-               and self._evict_one()):
-            pass
+        self._evict_while(
+            lambda: self.allocator.free_count < blocks_needed)
 
     def stats(self) -> dict:
         return {"entries": len(self._entries),
                 "held_blocks": self.held_blocks,
                 "hits": self.hits, "misses": self.misses,
                 "tokens_reused": self.tokens_reused,
-                "evictions": self.evictions, "pinned": self.pinned,
+                "evictions": self.evictions,
+                "evictions_freed": self.evictions_freed,
+                "pinned": self.pinned,
                 "adopted": self.adopted, "spills": self.spills,
                 "hits_device": self.hits_device,
                 "hits_host": self.hits_host}
