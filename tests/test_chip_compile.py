@@ -238,11 +238,12 @@ def test_a_decode_programs_only_kernels_are_the_step_markers(
 
 
 def _decode_programs(v5e, monkeypatch, configuration, n_layers=None,
-                     kinds=("decode",)):
+                     kinds=("decode",), cut=None):
     """``(cfg, family, n_chips, k_pool, jobs)``: a benchmark configuration's
     decode programs (or those of ``kinds``) at its engine's shapes (abstract
     arguments, no weights; as many described chips as its topology names),
-    dispatching as on the chip; ``n_layers`` cuts the model's depth."""
+    dispatching as on the chip; ``n_layers`` cuts the model's depth, and
+    ``cut`` (config -> config) cuts what a count alone cannot."""
     from dataclasses import replace
 
     from benchmark import manifest, serve
@@ -262,6 +263,8 @@ def _decode_programs(v5e, monkeypatch, configuration, n_layers=None,
     cfg = family.program_config(family.model_sizes(config))
     if n_layers:
         cfg = replace(cfg, n_layers=n_layers)
+    if cut:
+        cfg = cut(cfg)
     ecfg = serve.engine_config(config["engine"])
     topology = parse_topology(config["engine"]["topology"])
     policy = MeshPolicy(topology, devices=v5e[:topology.n_chips])
@@ -681,6 +684,83 @@ def test_latent_attention_in_every_layer_holds_its_kernels(
         assert seen[key].count(family.PREFILL_KERNEL) == cfg.n_layers
         assert len(seen[key]) == cfg.n_layers + 1      # + the grouped FFN
     assert seen["gather"] == []
+
+
+def test_the_state_space_step_compiles_at_the_published_widths(
+        v5e, no_compile_cache):
+    """The Mamba-2 step of a listed pattern's decode at Granite 4.0-H
+    Micro's widths and the cell's lanes (ISSUE 55): 64 lanes x 64 heads of a
+    float32 ``[64, 128]`` state, plane 7 of 36, IN PLACE — the 4.83 GB array
+    is aliased to the output and no copy of it is made — the live lanes a
+    prefetched list."""
+    from tpu9.ops import ssd
+    one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def s(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    b, h, p, n, planes = 64, 64, 64, 128, 36
+    assert not ssd.step_kernel_declined(h, p, n).replace(
+        "no TPU backend", "")
+    stored = ssd.state_shape(h, p, n)
+    assert stored == (32, 128, 128)     # two heads side by side a row
+    step = jax.jit(lambda st, x, dt, a, bm, cm, live: ssd.step_pallas(
+        st, 7, x, dt, a, bm, cm, live), donate_argnums=(0,))
+    compiled = step.lower(s((planes, b) + stored), s((b, h, p)), s((b, h)),
+                          s((h,)), s((b, 1, n)), s((b, 1, n)),
+                          s((b,), jnp.bool_)).compile()
+    assert _kernel_names(compiled.as_text()) == [ssd.STEP_KERNEL]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= planes * b * h * p * n * 4
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_a_listed_pattern_holds_its_kernels_at_the_published_widths(
+        v5e, no_compile_cache, monkeypatch):
+    """``granite-4.0-h-micro`` at its engine's shapes, three layers deep (a
+    state-space layer each side of an attention layer): a decode step is
+    one ``ssm_state_step`` a state-space layer, in place over the lanes'
+    0.27 GB of state here (4.83 GB at 36 planes), and one
+    ``paged_decode_attention`` — the call the benchmark counts its steps by
+    — over rows of two 64-wide heads; a
+    chunk and a group attend in the chunk kernel; no program copies the
+    state."""
+    from dataclasses import replace
+
+    from tpu9.models import kvstate
+    from tpu9.ops import ssd
+    cfg, family, _, pool, jobs = _decode_programs(
+        v5e, monkeypatch, "granite-4.0-h-micro",
+        kinds=("decode", "chunk", "chunkgroup", "l"),
+        cut=lambda c: replace(c, n_layers=3,
+                              layer_pattern=("ssm", "full", "ssm")))
+    # two KV heads of 64 a row of 128 lanes (``kvstate.heads_per_row``)
+    assert pool.shape == (1, 801, 128, 4, 128)
+    state = kvstate.lane_shapes(cfg, 64)["ssm_state"][0]
+    assert state == (2, 64, 32, 128, 128)
+    shaped = "f32[" + ",".join(str(n) for n in state) + "]"
+    seen = {}
+    for key, fn, args in jobs:
+        compiled = fn.lower(*args).compile()
+        text = compiled.as_text()
+        seen[key] = sorted(_kernel_names(text))
+        if key[0] == "decode":
+            # the whole state is an operand and a result, and never a copy
+            assert not re.search(r"= " + re.escape(shaped) + r"[^=]* copy\(",
+                                 text), key
+            # and no program copies a plane of the pool (a pool of 64-wide
+            # heads was laid out anew at every program's door and every
+            # write: PR 55's first traced run, 37 % of a step)
+            assert not _pool_copies(text, 801), key
+            assert compiled.memory_analysis().temp_size_in_bytes \
+                < 256 * 2 ** 20, key
+    assert family.STEP_MARKER == "paged_decode_attention"
+    assert family.SSM_STEP_KERNEL == ssd.STEP_KERNEL
+    for k in (1, 8):
+        assert seen[("decode", k)] == sorted(
+            [family.STEP_MARKER] + [ssd.STEP_KERNEL] * 2)
+    for key in (("chunk", 512), ("chunkgroup", 2)):
+        assert seen[key] == [CHUNK_KERNEL]
+    assert seen["lanesplice"] == []
 
 
 def test_a_looped_decode_program_carries_the_pool_through_its_pass_loop(

@@ -52,7 +52,17 @@ CASES = {
         head_dim=32, hidden_dim=256, max_seq_len=512, layer_group=3,
         mla_latent=64, mla_nope=32, mla_rope=16, mla_v=32, kda_conv=4,
         kda_gate_bound=-5.0, dtype=jnp.float32), False),
+    # state a lane beside PER-HEAD rows: a listed pattern (ISSUE 55), a pool
+    # of two planes for seven layers
+    "listed": (DecoderConfig(
+        vocab_size=256, dim=64, n_layers=7, n_heads=4, n_kv_heads=2,
+        head_dim=16, hidden_dim=128, max_seq_len=512, tie_embeddings=True,
+        layer_pattern=("ssm", "ssm", "full", "ssm", "ssm", "full", "ssm"),
+        ssm_heads=4, ssm_head_dim=16, ssm_state=32, ssm_conv=4, rope=False,
+        dtype=jnp.float32), False),
 }
+# the kind of layer whose state a case keeps by lane
+LANE_KIND = {"latent": "kda", "listed": "ssm"}
 PER_HEAD = [name for name in CASES if name != "latent"]
 case = pytest.mark.parametrize("name", list(CASES))
 
@@ -87,7 +97,8 @@ def test_pool_bytes_are_blocks_times_block_bytes(name):
         assert kv_block_bytes(cfg, BS, quantized) == block
         assert kv_cache_bytes(cfg, LANES, S, quantized) == LANES \
             * kvstate.block_bytes(cfg, cfg.kv_entries_peak(S), quantized)
-    assert bool(kvstate.lane_bytes(cfg)) == (name == "latent")
+    assert bool(kvstate.lane_bytes(cfg)) == (name in LANE_KIND)
+    assert cfg.lane_state == ((LANE_KIND[name],) if name in LANE_KIND else ())
     if quantized:
         # equal-HBM sizing: the int8 pool spends what the plain one would
         int8, plain = (KvPool(cfg, _ecfg(q), q, SingleDevicePolicy())
@@ -264,8 +275,8 @@ def test_dense_write_then_attend_is_the_oracle(name, form):
     assert not np.asarray(kv["v"][:layer]).any()
 
 
-@pytest.mark.parametrize("form", ["decode", "chunk", "lanes"])
-def test_latent_rows_and_lane_state(form):
+@pytest.mark.parametrize("form", ["decode", "chunk"])
+def test_latent_rows(form):
     cfg, _ = CASES["latent"]
     plane, steps = cfg.kv_layers - 1, 9
     (_, dc), (_, dr) = cfg.kv_row
@@ -317,23 +328,41 @@ def test_latent_rows_and_lane_state(form):
         np.testing.assert_array_equal(
             np.asarray(rotated), np.asarray(_by_hand(r[:1], positions, 64)[0]))
         assert not np.asarray(kv["k"][:plane]).any()
-    else:
-        kv = kvstate.init_kv_cache(cfg, LANES, 16)
-        shapes = kvstate.lane_shapes(cfg, LANES)
-        last = shapes["kda_state"][0][0] - 1
-        state = _rows(11, shapes["kda_state"][0][1:], jnp.float32)
-        tail = _rows(12, shapes["kda_conv"][0][1:], cfg.dtype)
-        kv = kvstate.lane_write(kv, last, tail, state=state)
-        for got, want in zip(kvstate.lane_read(kv, last), (state, tail)):
-            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-        assert not np.asarray(kvstate.lane_read(kv, 0)[0]).any()
-        # a step in place hands the whole array back
-        whole = kvstate.lane_states(kv) + 1.0
-        kv = kvstate.lane_write(kv, 0, tail, states=whole)
-        np.testing.assert_array_equal(np.asarray(kvstate.lane_states(kv)),
-                                      np.asarray(whole))
-        np.testing.assert_array_equal(
-            np.asarray(kvstate.lane_read(kv, 0)[1]), np.asarray(tail))
+
+
+@pytest.mark.parametrize("name", list(LANE_KIND))
+def test_lane_state_is_read_and_written_at_its_plane(name):
+    """State a lane, beside latent rows (KDA) and beside per-head planes (a
+    listed pattern's state-space layers): a plane written and read back,
+    the others untouched, a step in place handing the whole array back —
+    and the dense scratch carrying exactly one lane of it."""
+    cfg, _ = CASES[name]
+    kind = LANE_KIND[name]
+    state_name, conv_name = kvstate.LANE_KINDS[kind]
+    kv = kvstate.init_kv_cache(cfg, LANES, 16)
+    shapes = kvstate.lane_shapes(cfg, LANES)
+    assert list(shapes) == [state_name, conv_name]
+    assert shapes[state_name][0][:2] == (len(cfg.layers_of(kind)), LANES)
+    assert shapes[state_name][1] == jnp.float32
+    last = shapes[state_name][0][0] - 1
+    state = _rows(11, shapes[state_name][0][1:], jnp.float32)
+    tail = _rows(12, shapes[conv_name][0][1:], cfg.dtype)
+    kv = kvstate.lane_write(kv, last, tail, state=state, kind=kind)
+    for got, want in zip(kvstate.lane_read(kv, last, kind), (state, tail)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.asarray(kvstate.lane_read(kv, 0, kind)[0]).any()
+    # a step in place hands the whole array back
+    whole = kvstate.lane_states(kv, kind) + 1.0
+    kv = kvstate.lane_write(kv, 0, tail, states=whole, kind=kind)
+    np.testing.assert_array_equal(np.asarray(kvstate.lane_states(kv, kind)),
+                                  np.asarray(whole))
+    np.testing.assert_array_equal(
+        np.asarray(kvstate.lane_read(kv, 0, kind)[1]), np.asarray(tail))
+    # the scratch of a chunked prefill: one lane, the same planes
+    one = kvstate.dense_shapes(cfg, 1, 32)
+    for n, (shape, dt) in shapes.items():
+        assert one[n] == (shape[:1] + (1,) + shape[2:], dt)
+    assert kvstate.lane_bytes(cfg, LANES) == LANES * kvstate.lane_bytes(cfg)
 
 
 # ---------------------------------------------------------------------------

@@ -18,7 +18,14 @@ new kind of cache is written here.
   ``[n_blocks, block]`` and a ``"table"`` ``[lanes, columns]`` names each
   lane's blocks; an int8 pool holds the rows as int8 beside float32
   ``"k_scale"`` / ``"v_scale"`` planes of one rank less (one absmax scale a
-  (token, head) vector, ``ops.quant.quantize_kv``). A pool's ROTATED KEYS
+  (token, head) vector, ``ops.quant.quantize_kv``). A listed pattern's heads
+  narrower than the chip's 128 lanes lie ``cfg.kv_pack`` to a row
+  (:func:`heads_per_row`, :func:`pack_heads`: a row
+  of 64-wide heads is half-empty 128-lane registers, and the chip's
+  compiler then keeps the pool in a layout of its own and copies it to the
+  kernels' and back, every write of every step: PR 55's first traced run),
+  which ``cfg.kv_row`` states and every shape below follows. A pool's
+  ROTATED KEYS
   lie two tokens a row, ``[depth, n_blocks, block / 2, 1, 2 mla_rope]``:
   token ``j`` of a page in the first ``mla_rope`` lanes of row ``j``, token
   ``j + block / 2`` in the rest (``ops.latent_attention.pack_rotated``) —
@@ -33,15 +40,23 @@ new kind of cache is written here.
 - state a LANE, the same size at any length: a KDA layer's float32 matrix
   ``"kda_state"`` ``[P, lanes, H, d, d]`` and the last ``kda_conv - 1`` inputs
   of its short convolution ``"kda_conv"`` ``[P, lanes, K-1, 3 H d]`` (``P`` KDA
-  layers). No table, no pages; the dense scratch carries one lane of it.
+  layers), beside latent rows; a state-space layer's float32 matrix
+  ``"ssm_state"`` ``[P, lanes, H / pack, N, pack d]`` (as ``ops.ssd`` stores
+  it: ``pack`` heads side by side along 128 lanes) and its convolution's
+  last inputs
+  ``"ssm_conv"`` ``[P, lanes, K-1, H d + 2 G N]`` (``P`` such layers of a
+  listed pattern), beside PER-HEAD rows. No table, no pages; the dense
+  scratch carries one lane of it. ``LANE_KINDS`` names the two arrays of
+  each kind; ``DecoderConfig.lane_state`` says which kinds a decoder has.
 
 **The verbs.** :func:`write` puts a layer's fresh rows where their entries
 say and :func:`attend` runs the layer's queries over what is written, through
 the dispatchers of ``ops.attention``; latent attention reads through
 :func:`latent_attend` (a decode step over the pool) and :func:`latent_rows`
 or :func:`latent_planes` (a chunk over the scratch, every row expanded or a
-block of keys at a time), a KDA layer through :func:`lane_read` and
-:func:`lane_write`. The cache dict is carried WHOLE from layer to layer:
+block of keys at a time), a KDA or state-space layer through
+:func:`lane_read` and :func:`lane_write`. The cache dict is carried WHOLE
+from layer to layer:
 every write is into the ``[depth, ...]`` arrays in place (a donated or
 carried array), nothing is sliced out and stacked back.
 """
@@ -61,8 +76,12 @@ from ..ops.attention import (attention, chunk_prefill_attention,
 from ..ops.latent_attention import (pack_rotated, paged_latent_attention,
                                      unpack_rotated)
 from ..ops.quant import quantize_kv
+from ..ops.ssd import state_shape as ssd_state_shape
 
 TABLE = "table"
+# the two arrays a kind of layer keeps by LANE: (state, convolution tail)
+LANE_KINDS = {"kda": ("kda_state", "kda_conv"),
+              "ssm": ("ssm_state", "ssm_conv")}
 # of every paged array, pool and dense alike: [depth, ., ., KH, ...]
 HEAD_AXIS = 3
 _SCALE = {"k": "k_scale", "v": "v_scale"}
@@ -103,8 +122,21 @@ def pool_shapes(cfg, n_blocks: int, block: int,
 
 
 def lane_shapes(cfg, lanes: int) -> dict:
-    """``name -> (shape, dtype)`` of the state KDA layers keep for ``lanes``
-    running sequences; empty for a decoder without such layers."""
+    """``name -> (shape, dtype)`` of the state that KDA layers, or a listed
+    pattern's state-space layers, keep for ``lanes`` running sequences;
+    empty for a decoder without such layers."""
+    if cfg.layer_pattern:
+        planes = len(cfg.layers_of("ssm"))
+        if not planes:
+            return {}
+        h, d = cfg.ssm_heads, cfg.ssm_head_dim
+        width = h * d + 2 * cfg.ssm_groups * cfg.ssm_state
+        # (the matrix as ``ops.ssd`` stores it: ``state_shape`` says why)
+        return {"ssm_state": ((planes, lanes) + ssd_state_shape(
+                                  h, d, cfg.ssm_state, cfg.ssm_groups),
+                              jnp.float32),
+                "ssm_conv": ((planes, lanes, cfg.ssm_conv - 1, width),
+                             cfg.dtype)}
     planes = len(cfg.layers_of("kda")) if cfg.layer_group > 1 else 0
     if not planes:
         return {}
@@ -155,6 +187,61 @@ def init_kv_cache(cfg, batch: int, max_len: int = 0, dtype=None) -> dict:
     beside it (:func:`dense_shapes`)."""
     return {name: jnp.zeros(shape, dt) for name, (shape, dt) in dense_shapes(
         cfg, batch, max_len or cfg.max_seq_len, dtype).items()}
+
+
+# -- heads packed to whole rows -------------------------------------------------
+
+# numbers a vector register holds side by side on the chip
+ROW_LANES = 128
+
+
+def heads_per_row(cfg) -> int:
+    """KV heads a cache row holds side by side (``cfg.kv_pack``; 1 = a head
+    a row). Worked out, not stated: the per-head rows of a LISTED pattern
+    (``layer_pattern``: new with the packing, PR 55) whose heads are
+    narrower than ``ROW_LANES`` hold as many as fill the lanes — two of 64,
+    all of them where they are fewer — when that is a whole number of
+    heads a row and of rows. Everything else keeps a head a row: latent
+    rows have no heads, a window's summarise reads a head a row, and a
+    uniform decoder's programs stay what they were (its heads are 128 wide
+    in every served model; the int8 pool's scale is one a (token, head),
+    which a packed row would share). A listed pattern always keeps state a
+    lane (``models.ssm.refuse_unbuilt_list``), and the engine refuses
+    ``kv_quant`` and a mesh beside it."""
+    hd, heads = cfg.head_dim, cfg.n_kv_heads
+    if not cfg.layer_pattern or hd >= ROW_LANES or ROW_LANES % hd:
+        return 1
+    pack = min(ROW_LANES // hd, heads)
+    return pack if heads % pack == 0 else 1
+
+
+def pack_heads(q, k, v, pack: int):
+    """Keys, values and queries ``[B, T, heads, D]`` as a cache of ``pack``
+    KV heads a row takes them (:func:`heads_per_row`): ``k`` and ``v``
+    ``[B, T, KH / pack, pack D]`` — consecutive heads side by side, a free
+    reshape — and each query ``[B, T, QH, pack D]``, its ``D`` numbers where
+    its KV head lies in the row and zero elsewhere, so that its product with
+    a row is its product with its own head. It also carries ``sqrt(pack)``
+    (multiplied in float32, rounded once to the queries' type): every
+    attention form divides the scores by the root of the ROW's width."""
+    b, t, kh, d = k.shape
+    group = q.shape[2] // kh
+    k, v = (a.reshape(b, t, kh // pack, pack * d) for a in (k, v))
+    q = (q.astype(jnp.float32) * pack ** 0.5).astype(q.dtype).reshape(
+        b, t, kh // pack, pack, group, 1, d)
+    here = jnp.eye(pack, dtype=q.dtype)[:, None, :, None]   # [pack,1,pack,1]
+    return (q * here).reshape(b, t, kh * group, pack * d), k, v
+
+
+def unpack_heads(out, kv_heads: int, pack: int):
+    """The attention's output over packed rows ``[B, T, QH, pack D]`` as the
+    heads' own ``[B, T, QH, D]``: of each query's row-wide result the part
+    that its KV head's values gave."""
+    b, t, qh, width = out.shape
+    d, group = width // pack, qh // kv_heads
+    out = out.reshape(b, t, kv_heads // pack, pack, group, pack, d)
+    here = jnp.eye(pack, dtype=out.dtype)[:, None, :, None]
+    return jnp.sum(out * here, axis=5).reshape(b, t, qh, d)
 
 
 # -- what a cache dict is ------------------------------------------------------
@@ -420,23 +507,25 @@ def latent_planes(kv: dict):
 
 # -- state a lane --------------------------------------------------------------
 
-def lane_read(kv: dict, plane: int):
-    """``(state [B, H, d, d], convolution tail [B, K-1, 3 H d])`` of the
-    ``plane``-th KDA layer, every lane's."""
-    return kv["kda_state"][plane], kv["kda_conv"][plane]
+def lane_read(kv: dict, plane: int, kind: str = "kda"):
+    """``(state [B, H, ., .], convolution tail [B, K-1, channels])`` of the
+    ``plane``-th layer of ``kind`` (``LANE_KINDS``), every lane's."""
+    state, conv = LANE_KINDS[kind]
+    return kv[state][plane], kv[conv][plane]
 
 
-def lane_states(kv: dict):
-    """Every KDA layer's state, whole ``[P, B, H, d, d]``: what the Pallas
-    step updates in place at its plane."""
-    return kv["kda_state"]
+def lane_states(kv: dict, kind: str = "kda"):
+    """Every ``kind`` layer's state, whole ``[P, B, H, ., .]``: what the
+    Pallas step updates in place at its plane."""
+    return kv[LANE_KINDS[kind][0]]
 
 
-def lane_write(kv: dict, plane: int, tail, state=None, states=None) -> dict:
-    """``kv`` with the ``plane``-th KDA layer's state and convolution tail
-    replaced: ``state`` ``[B, H, d, d]`` written at the plane, or ``states``
-    the whole array as a step in place left it."""
+def lane_write(kv: dict, plane: int, tail, state=None, states=None,
+               kind: str = "kda") -> dict:
+    """``kv`` with the state and convolution tail of the ``plane``-th layer
+    of ``kind`` replaced: ``state`` ``[B, H, ., .]`` written at the plane, or
+    ``states`` the whole array as a step in place left it."""
+    name, conv = LANE_KINDS[kind]
     if states is None:
-        states = kv["kda_state"].at[plane].set(state)
-    return dict(kv, kda_state=states,
-                kda_conv=kv["kda_conv"].at[plane].set(tail))
+        states = kv[name].at[plane].set(state)
+    return dict(kv, **{name: states, conv: kv[conv].at[plane].set(tail)})

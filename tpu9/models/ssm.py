@@ -1,0 +1,252 @@
+"""A layer pattern stated as a LIST (``DecoderConfig.layer_pattern``): which
+layers are Mamba-2 state-space mixers (``"ssm"``) and which the plain
+attention of a uniform decoder (``"full"``) — the hybrid state-space family
+(``granitemoehybrid``: nine mixers to one attention layer, every layer
+closed by the same dense SwiGLU). ``models.transformer`` walks the layers
+and calls in here for the mixers; the plain-attention layers of a list are
+``transformer._attn_block``'s own, at the plane their place among
+themselves gives.
+
+What a running sequence keeps differs by kind:
+
+- an ``"ssm"`` layer keeps STATE A LANE — a float32 matrix ``[H, P, N]``
+  (stored as ``ops.ssd.state_shape`` says) and
+  the last taps-less-one inputs of its short convolution — the same size at
+  any length: no table, no pages (``models.kvstate.lane_shapes``).
+- a ``"full"`` layer keeps per-head keys and values a token, in a pool only
+  as deep as there are such layers.
+
+The mixer, with ``u`` the normed input:
+
+    [z | xBC | dt] = u W_in                       (no bias)
+    xBC = silu(conv(xBC) + b_conv)                depthwise, causal, K taps
+    [x | B | C] = xBC;  dt = softplus(dt + dt_bias);  A_h = -exp(A_log_h)
+    S_t = exp(dt_t A_h) S_{t-1} + dt_t x_t (x) B_t;  y_t = S_t C_t + D_h x_t
+    out = RMSNorm_w(y * silu(z)) W_out            the gate BEFORE the norm
+
+``ops.ssd`` has the recurrence in its four forms; the equations, with every
+assumption, are in the plain reference the benchmark holds this to
+(``benchmark/reference/granitehybrid.py``).
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from ..ops import ssd
+from ..ops.delta_rule import causal_conv
+from ..ops.norms import rms_norm
+from ..ops.quant import maybe_matmul
+from . import kvstate
+from .hybrid import _dense, _project32
+
+F32 = jnp.float32
+# device scopes of a state-space layer, beside ``transformer.DEVICE_SCOPES``
+# and ``hybrid.HYBRID_SCOPES`` (a tuple of their own: the benchmark's
+# accepted tests pin those two to the families that have them): the
+# projection in, the convolution, ``dt`` and the gate / the recurrence (a
+# decode step or a prefill's scan over blocks)
+SSM_SCOPES = ("attn.ssm.proj", "attn.ssm.state")
+KINDS = ("ssm", "full")
+
+
+def refuse_unbuilt_list(cfg) -> None:
+    """``DecoderConfig.__post_init__`` for a listed pattern and for the
+    descriptors that came with it: every combination that is not built is
+    refused, with its reason."""
+    def refuse(what: str, why: str):
+        raise ValueError(f"{what}: {why}")
+
+    if cfg.attn_scale:
+        exact = cfg.attn_scale * cfg.head_dim ** 0.5
+        if cfg.attn_scale < 0 or math.frexp(exact)[0] != 0.5:
+            refuse(f"attn_scale={cfg.attn_scale} at head_dim={cfg.head_dim}",
+                   "the kernels fix head_dim ** -0.5 and the queries carry "
+                   f"the rest, {exact}: only a power of two multiplies a "
+                   "bfloat16 query without rounding it")
+    if min(cfg.embed_mult, cfg.residual_mult, cfg.logit_div) <= 0:
+        refuse(f"embed_mult={cfg.embed_mult}, residual_mult="
+               f"{cfg.residual_mult}, logit_div={cfg.logit_div}",
+               "a multiplier is positive (1 = off)")
+    plain = (cfg.rope and not cfg.attn_scale and cfg.embed_mult == 1.0
+             and cfg.residual_mult == 1.0 and cfg.logit_div == 1.0)
+    if not plain and (cfg.layer_group or cfg.looped or cfg.attn_window
+                      or cfg.n_experts):
+        refuse("attention without rotary or at a scale of its own, or a "
+               "multiplier on embeddings, residuals or logits, with a "
+               "layer_group, a pass loop, attn_window or experts",
+               "latent attention has positions and a temperature of its "
+               "own, the other branches add to the stream in their own "
+               "code; no served model has both, not run")
+    sizes = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_conv)
+    if not cfg.layer_pattern:
+        if any(sizes) or cfg.ssm_groups != 1:
+            refuse(f"ssm sizes {sizes} without a layer_pattern",
+                   "no layer would read them")
+        return
+    what = f"layer_pattern of {len(cfg.layer_pattern)} layers"
+    if len(cfg.layer_pattern) != cfg.n_layers \
+            or any(k not in KINDS for k in cfg.layer_pattern):
+        refuse(what, f"one kind of {KINDS} for each of the {cfg.n_layers} "
+               "layers (KDA and MLA layers are stated by layer_group)")
+    if cfg.layer_group:
+        refuse(f"{what} with layer_group={cfg.layer_group}",
+               "a pattern is stated once, as the rule or as the list")
+    if cfg.looped or cfg.attn_window or cfg.sandwich_norm or cfg.n_experts:
+        refuse(f"{what} with a pass loop, attn_window, sandwich_norm or "
+               "experts", "a lane's state would need a plane a pass, a "
+               "window's summarise knows no pool of fewer planes than "
+               "layers, and no listed pattern was run with output norms or "
+               "an expert layer; not built")
+    if cfg.embed_scale or cfg.logit_softcap or cfg.norm_offset \
+            or cfg.act != "silu":
+        refuse(f"{what} with a descriptor of another family (sqrt(dim) "
+               "embeddings, soft-capped logits, offset norms, gelu)",
+               "no served model has both; not run")
+    if "ssm" not in cfg.layer_pattern:
+        refuse(f"{what} without an ssm layer",
+               "that is a uniform decoder, stated without a list: a list "
+               "packs narrow heads to whole cache rows "
+               "(kvstate.heads_per_row), which only the engine's refusals "
+               "beside state a lane keep from an int8 pool and a mesh")
+    if min(sizes) <= 0 or cfg.ssm_conv < 2 or cfg.ssm_groups < 1 \
+            or cfg.ssm_heads % cfg.ssm_groups:
+        refuse(f"{what} with ssm sizes {sizes}, ssm_groups="
+               f"{cfg.ssm_groups}",
+               "heads, a head's width, the state's width and at least "
+               "2 taps are all needed, and the groups divide the heads")
+
+
+def conv_width(cfg) -> int:
+    """Channels of the short convolution: ``x``, ``B`` and ``C``."""
+    return cfg.ssm_heads * cfg.ssm_head_dim + 2 * cfg.ssm_groups * cfg.ssm_state
+
+
+def init_listed_layer(rng: jax.Array, cfg, l: int) -> dict:
+    """Layer ``l`` of a listed pattern, seeded. What a checkpoint would
+    bring and a seed has to choose is Mamba-2's published initialisation:
+    ``A_log = log(uniform[1, 16])``, ``dt_bias`` the inverse softplus of a
+    ``dt`` log-uniform over [0.001, 0.1], ``D = 1``, the convolution's taps
+    and bias uniform over ``+- 1 / sqrt(taps)`` (a depthwise convolution's
+    default)."""
+    kind = cfg.layer_kind(l)[0]
+    dt, d = cfg.dtype, cfg.dim
+    r = iter(jax.random.split(rng, 12))
+    layer = {"attn_norm": jnp.ones((d,), F32),
+             "mlp_norm": jnp.ones((d,), F32)}
+    if kind == "ssm":
+        h, inner = cfg.ssm_heads, cfg.ssm_heads * cfg.ssm_head_dim
+        width, bound = conv_width(cfg), cfg.ssm_conv ** -0.5
+        step = jnp.exp(jax.random.uniform(
+            next(r), (h,), F32, math.log(0.001), math.log(0.1)))
+        layer["ssm"] = {
+            "w_in": _dense(next(r), d, inner + width + h, dt),
+            "conv": jax.random.uniform(next(r), (cfg.ssm_conv, width), F32,
+                                       -bound, bound),
+            "conv_bias": jax.random.uniform(next(r), (width,), F32,
+                                            -bound, bound),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "a_log": jnp.log(jax.random.uniform(next(r), (h,), F32,
+                                                1.0, 16.0)),
+            "d_skip": jnp.ones((h,), F32),
+            "norm": jnp.ones((inner,), F32),
+            "w_out": _dense(next(r), inner, d, dt)}
+    else:
+        q_dim, kv_dim = cfg.n_heads * cfg.head_dim, \
+            cfg.n_kv_heads * cfg.head_dim
+        layer.update(wq=_dense(next(r), d, q_dim, dt),
+                     wk=_dense(next(r), d, kv_dim, dt),
+                     wv=_dense(next(r), d, kv_dim, dt),
+                     wo=_dense(next(r), q_dim, d, dt))
+    layer["w_gate"] = _dense(next(r), d, cfg.hidden_dim, dt)
+    layer["w_up"] = _dense(next(r), d, cfg.hidden_dim, dt)
+    layer["w_down"] = _dense(next(r), cfg.hidden_dim, d, dt)
+    return layer
+
+
+def step_form(cfg) -> str:
+    """Which form of the recurrence a decode step takes, in words
+    (``/health``'s kernel report)."""
+    why = ssd.step_kernel_declined(cfg.ssm_heads, cfg.ssm_head_dim,
+                                   cfg.ssm_state, cfg.ssm_groups)
+    return f"xla: {why}" if why else "pallas, in place, live lanes only"
+
+
+def scan_form(width: int) -> str:
+    """Which form of the recurrence a prefill of ``width`` tokens takes."""
+    block = min(ssd.BLOCK, width)
+    if width % block:
+        return "xla: a token at a time (not whole blocks)"
+    return f"xla: chunkwise (SSD), blocks of {block}"
+
+
+def ssm_block(p: dict, u: jnp.ndarray, cfg, kv_cache: Optional[dict],
+              plane: int, decode: bool, n_valid):
+    """One state-space layer's mixer over the normed input ``u`` [B, T, D].
+    With a cache dict the layer's state is read at ``plane`` and written
+    back, advanced by the first ``n_valid[b]`` tokens of lane ``b`` only (a
+    padded tail and an idle lane leave it untouched); without one the
+    sequence starts from zero state and nothing is kept. Returns ``(y [B,
+    T, D], kv_cache)``."""
+    b, t, _ = u.shape
+    heads, hd, n, g = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, \
+        cfg.ssm_groups
+    inner, width = heads * hd, conv_width(cfg)
+    if n_valid is None:
+        n_valid = jnp.full((b,), t, jnp.int32)
+    if kv_cache is None:
+        state = jnp.zeros((b, heads, hd, n), F32)
+        tail = jnp.zeros((b, cfg.ssm_conv - 1, width), u.dtype)
+    else:
+        # (the state as it is stored; the ``jax.numpy`` forms unpack it)
+        state, tail = kvstate.lane_read(kv_cache, plane, "ssm")
+    with jax.named_scope("attn.ssm.proj"):
+        # the gate's and dt's pre-activations stay float32 (``_project32``
+        # says why); the convolution's input is rounded to the model's type,
+        # as its tail keeps it
+        proj = _project32(u, p["w_in"])
+        z = proj[..., :inner]
+        xbc, tail = causal_conv(proj[..., inner:inner + width].astype(u.dtype),
+                                p["conv"], tail, n_valid, p["conv_bias"])
+        xbc = jax.nn.silu(xbc)
+        x = xbc[..., :inner].reshape(b, t, heads, hd)
+        bm = xbc[..., inner:inner + g * n].reshape(b, t, g, n)
+        cm = xbc[..., inner + g * n:].reshape(b, t, g, n)
+        dt = jax.nn.softplus(proj[..., inner + width:] + p["dt_bias"])
+        a_head = -jnp.exp(p["a_log"])
+    with jax.named_scope("attn.ssm.state"):
+        live = n_valid > 0
+        if decode and kv_cache is not None and not ssd.step_kernel_declined(
+                heads, hd, n, g):
+            # the Pallas step: the live lanes' states in place at this plane
+            states, y = ssd.step_pallas(
+                kvstate.lane_states(kv_cache, "ssm"), plane, x[:, 0],
+                dt[:, 0], a_head, bm[:, 0], cm[:, 0], live=live)
+            kv_cache = kvstate.lane_write(kv_cache, plane, tail,
+                                          states=states, kind="ssm")
+            y = y[:, None]
+        else:
+            if kv_cache is not None:
+                state = ssd.unpack_state(state, hd)
+            if decode:
+                state, y = ssd.step(state, x[:, 0], dt[:, 0], a_head,
+                                    bm[:, 0], cm[:, 0], live=live)
+                y = y[:, None]
+            else:
+                valid = jnp.arange(t)[None, :] < n_valid[:, None]
+                whole = t % min(ssd.BLOCK, t) == 0
+                state, y = (ssd.chunked if whole else ssd.scan)(
+                    state, x, dt, a_head, bm, cm, valid)
+            if kv_cache is not None:
+                kv_cache = kvstate.lane_write(
+                    kv_cache, plane, tail, kind="ssm",
+                    state=ssd.pack_state(state, ssd.head_pack(heads, hd, g)))
+        y = y + p["d_skip"][:, None] * x
+    with jax.named_scope("attn.out"):
+        y = rms_norm((y * jax.nn.silu(z).reshape(b, t, heads, hd)).reshape(
+            b, t, inner), p["norm"], cfg.norm_eps)
+        return maybe_matmul(y.astype(u.dtype), p["w_out"]), kv_cache

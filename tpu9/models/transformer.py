@@ -117,6 +117,30 @@ class DecoderConfig:
     moe_top_groups: int = 0
     moe_renormalise: bool = True
     moe_gate_scale: float = 1.0
+    # a layer pattern stated as a LIST (``models.ssm``): the attention kind
+    # of every layer, ``"ssm"`` (a Mamba-2 state-space mixer over a state a
+    # LANE that no cache holds) or ``"full"`` (the plain attention above,
+    # over per-head rows of a pool only as deep as there are such layers);
+    # () = no list. ``layer_group`` is the one RULE (KDA closed by MLA) and
+    # stays what it was; :meth:`layer_kind` reads whichever is stated
+    layer_pattern: tuple = ()
+    # the state-space mixer's sizes: heads, a head's width, the state's
+    # width, the groups that share ``B`` and ``C``, the taps of the short
+    # causal convolution (with a bias) on ``x``, ``B`` and ``C``
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 0
+    # plain attention without positions (False: no rotary, no table), and
+    # at a softmax scale of its own (0 = ``head_dim ** -0.5``)
+    rope: bool = True
+    attn_scale: float = 0.0
+    # multipliers (1 = off): on the embeddings, on what every sub-layer adds
+    # to the stream, and the divisor of the logits
+    embed_mult: float = 1.0
+    residual_mult: float = 1.0
+    logit_div: float = 1.0
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self):
@@ -149,6 +173,8 @@ class DecoderConfig:
                     "for a cache of loop_steps planes a layer, and no "
                     "expert layer was ever run over a float32 stream")
 
+        from .ssm import refuse_unbuilt_list
+        refuse_unbuilt_list(self)
         if self.layer_group:
             from .hybrid import refuse_unbuilt_pattern
             refuse_unbuilt_pattern(self)
@@ -173,11 +199,14 @@ class DecoderConfig:
         """``(attention, ffn)`` of layer ``l`` (0-based): attention is
         ``"full"`` (the plain attention of a uniform decoder), ``"kda"`` or
         ``"mla"`` (the last layer of each group of ``layer_group``: every
-        layer where a group is one layer); the ffn is ``"dense"`` or
+        layer where a group is one layer), or what ``layer_pattern`` lists
+        for the layer (``"ssm"`` or ``"full"``); the ffn is ``"dense"`` or
         ``"experts"``. THE one place that knows the
         pattern: ``init_decoder``, the forward pass, the pool's depth and
         the lanes' state all ask here."""
-        if not self.layer_group:
+        if self.layer_pattern:
+            attention = self.layer_pattern[l]
+        elif not self.layer_group:
             attention = "full"
         else:
             attention = "mla" if (l + 1) % self.layer_group == 0 else "kda"
@@ -198,7 +227,25 @@ class DecoderConfig:
         numbers a token."""
         if self.layer_group:
             return (1, self.mla_latent), (1, self.mla_rope)
-        return ((self.n_kv_heads, self.head_dim),) * 2
+        pack = self.kv_pack
+        return ((self.n_kv_heads // pack, self.head_dim * pack),) * 2
+
+    @property
+    def kv_pack(self) -> int:
+        """KV heads a cache row holds side by side (1 = a head a row):
+        ``models.kvstate.heads_per_row`` works it out from the head's
+        width."""
+        return kvstate.heads_per_row(self)
+
+    @property
+    def lane_state(self) -> tuple:
+        """The attention kinds of this decoder that keep state by LANE
+        (``models.kvstate.lane_shapes``), in layer order of first use; ()
+        for a decoder whose whole state is paged."""
+        if not (self.layer_group or self.layer_pattern):
+            return ()
+        kinds = [self.layer_kind(l)[0] for l in range(self.n_layers)]
+        return tuple(k for k in ("kda", "ssm") if k in kinds)
 
     @property
     def looped(self) -> bool:
@@ -213,6 +260,9 @@ class DecoderConfig:
         if self.layer_group:
             # only the latent-attention layers have a cache at all
             return len(self.layers_of("mla"))
+        if self.layer_pattern:
+            # a listed pattern: only its plain-attention layers
+            return len(self.layers_of("full"))
         return self.n_layers * self.loop_steps
 
     # -- where a token lives in its cache -----------------------------------
@@ -271,8 +321,13 @@ def init_decoder(rng: jax.Array, cfg: DecoderConfig) -> Params:
         return rngs[next(it)]
 
     params: Params = {
+        # (a table that is fed in times ``embed_mult`` is seeded that much
+        # smaller, so that the stream starts where an unscaled table's
+        # does: at 0.02 x 12 a token's own row of a TIED head outweighs
+        # every other logit and a seeded model only repeats its input)
         "embed": (jax.random.normal(nxt(), (cfg.vocab_size, cfg.dim),
-                                    dtype=jnp.float32) * 0.02).astype(dt),
+                                    dtype=jnp.float32)
+                  * (0.02 / cfg.embed_mult)).astype(dt),
         "final_norm": jnp.ones((cfg.dim,), dtype=jnp.float32) - cfg.norm_offset,
         "layers": [],
     }
@@ -291,6 +346,11 @@ def init_decoder(rng: jax.Array, cfg: DecoderConfig) -> Params:
             # folded from the tree's, as every later addition's are
             from .hybrid import init_hybrid_layer
             params["layers"].append(init_hybrid_layer(
+                jax.random.fold_in(rng, li), cfg, li))
+            continue
+        if cfg.layer_pattern:
+            from .ssm import init_listed_layer
+            params["layers"].append(init_listed_layer(
                 jax.random.fold_in(rng, li), cfg, li))
             continue
         layer = {
@@ -429,6 +489,17 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
             y, kv_cache = mla_block(layer["mla"], h, cfg, positions, sin,
                                     cos, kv_cache, plane, cache_len, decode)
         return x + y, kv_cache
+    if cfg.layer_pattern:
+        # a listed pattern: a state-space layer, or plain attention whose
+        # plane is its place among the plain-attention layers
+        kind = cfg.layer_kind(layer_idx)[0]
+        plane = cfg.layers_of(kind).index(layer_idx)
+        if kind == "ssm":
+            from .ssm import ssm_block
+            y, kv_cache = ssm_block(layer["ssm"], h, cfg, kv_cache, plane,
+                                    decode, n_valid)
+            return _residual(x, y, cfg), kv_cache
+        layer_idx = plane
     with jax.named_scope("attn.qkv"):
         q = maybe_matmul(h, layer["wq"]).reshape(
             b, t, cfg.n_heads, cfg.head_dim)
@@ -436,9 +507,27 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
             b, t, cfg.n_kv_heads, cfg.head_dim)
         v = maybe_matmul(h, layer["wv"]).reshape(
             b, t, cfg.n_kv_heads, cfg.head_dim)
-    with jax.named_scope("attn.rope"):
-        q = apply_rope(q, positions, sin, cos)
-        k = apply_rope(k, positions, sin, cos)
+    if cfg.rope:
+        with jax.named_scope("attn.rope"):
+            q = apply_rope(q, positions, sin, cos)
+            k = apply_rope(k, positions, sin, cos)
+    if cfg.attn_scale:
+        # the kernels fix ``head_dim ** -0.5``: the queries carry the rest,
+        # a power of two (``ssm.refuse_unbuilt_list``), so this product
+        # rounds nothing. (Where heads are packed to a row, below,
+        # ``kvstate.pack_heads`` multiplies the queries by ``sqrt(kv_pack)``
+        # too, in float32, and rounds them ONCE to the model's type: a
+        # bfloat16 rounding of q that a head a row does not have.)
+        with jax.named_scope("attn.qkv"):
+            q = q * jnp.asarray(cfg.attn_scale * cfg.head_dim ** 0.5, q.dtype)
+    packed = cfg.kv_pack > 1 and kv_cache is not None
+    if packed:
+        # the cache keeps ``kv_pack`` heads a row: keys and values as they
+        # lie, the queries widened to a row (zero where the row holds
+        # another head) and carrying ``sqrt(kv_pack)``, because the kernels
+        # then fix ``(kv_pack head_dim) ** -0.5``
+        with jax.named_scope("attn.qkv"):
+            q, k, v = kvstate.pack_heads(q, k, v, cfg.kv_pack)
 
     # rotary keeps the position; the cache is addressed, and attention is
     # masked, by ENTRY (the same arrays for plain attention)
@@ -471,12 +560,22 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
                              cache_len, decode, mesh)
 
     with jax.named_scope("attn.out"):
+        if packed:
+            out = kvstate.unpack_heads(out, cfg.n_kv_heads, cfg.kv_pack)
         out = out.reshape(b, t, cfg.n_heads * cfg.head_dim)
         if cfg.sandwich_norm:
             return x + rms_norm(maybe_matmul(out, layer["wo"]),
                                 layer["attn_post_norm"], cfg.norm_eps,
                                 cfg.norm_offset), kv_cache
-        return x + maybe_matmul(out, layer["wo"]), kv_cache
+        return _residual(x, maybe_matmul(out, layer["wo"]), cfg), kv_cache
+
+
+def _residual(x, y, cfg: DecoderConfig):
+    """The stream with a sub-layer's output added, times
+    ``residual_mult`` where that is stated."""
+    if cfg.residual_mult == 1.0:
+        return x + y
+    return x + y.astype(x.dtype) * jnp.asarray(cfg.residual_mult, x.dtype)
 
 
 def _mlp_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
@@ -521,7 +620,7 @@ def _mlp_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
             return x + rms_norm(maybe_matmul(gated, layer["w_down"]),
                                 layer["mlp_post_norm"], cfg.norm_eps,
                                 cfg.norm_offset), None
-        return x + maybe_matmul(gated, layer["w_down"]), None
+        return _residual(x, maybe_matmul(gated, layer["w_down"]), cfg), None
 
 
 def _live_rows(n_valid, t: int):
@@ -560,13 +659,16 @@ def _layers(params: Params, x, cfg: DecoderConfig, positions, sin, cos,
 
 
 def _pattern_layers(params: Params, x, cfg: DecoderConfig, positions, sin,
-                    cos, kv_cache, cache_len, decode: bool, n_valid):
+                    cos, kv_cache, cache_len, decode: bool, n_valid,
+                    compute_dtype=None):
     """Every layer of a layer pattern once (``layer_group``). ``n_valid``
     [B]: how many of each row's tokens are real — a padded chunk tail and an
     idle lane advance no KDA state; None: all. Returns ``(x, kv_cache,
     picks)``: the global ids of the experts every token chose in every
     expert layer, int32 [B, T, expert layers, top_k] (a padded row's are
-    whatever its padding chose: the caller knows which rows are real)."""
+    whatever its padding chose: the caller knows which rows are real).
+    ``compute_dtype``: as for ``_layers`` (a float32 stream whose sub-layers
+    compute in that type; None: the stream's own)."""
     b, t, _ = x.shape
     # the rows that are real: the expert layer of a decode step reads the
     # experts THEY picked (an idle lane's padding picks nothing)
@@ -577,11 +679,12 @@ def _pattern_layers(params: Params, x, cfg: DecoderConfig, positions, sin,
     for i, layer in enumerate(params["layers"]):
         x, kv_cache = _attn_block(layer, x, cfg, positions, sin, cos,
                                   kv_cache, i, cache_len, decode,
+                                  compute_dtype=compute_dtype,
                                   n_valid=n_valid)
-        x, aux = _mlp_block(layer, x, cfg, live=live)
+        x, aux = _mlp_block(layer, x, cfg, compute_dtype, live=live)
         if aux is not None:
             picks.append(aux["picks"])
-    return x, kv_cache, jnp.stack(picks, axis=2)
+    return x, kv_cache, jnp.stack(picks, axis=2) if picks else None
 
 
 def _looped_passes(params: Params, x, cfg: DecoderConfig, positions, sin,
@@ -693,6 +796,8 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
         x = params["embed"][tokens].astype(cfg.dtype)
         if cfg.embed_scale:
             x = x * jnp.asarray(cfg.dim ** 0.5, dtype=cfg.dtype)
+        if cfg.embed_mult != 1.0:
+            x = x * jnp.asarray(cfg.embed_mult, dtype=cfg.dtype)
 
     # the rope table must cover every cache slot: positions past the table
     # are CLAMPED by JAX's gather, rotating distinct positions identically
@@ -705,23 +810,30 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
             raise ValueError(
                 f"kv cache length {cache_s} exceeds rope table "
                 f"{rope_len} — positions past it would alias")
-    with jax.named_scope("attn.rope"):
-        sin, cos = rope_table(rope_len, cfg.mla_rope or cfg.head_dim,
-                              cfg.rope_theta, cfg.rope_yarn)
+    sin = cos = None
+    if cfg.rope:
+        with jax.named_scope("attn.rope"):
+            sin, cos = rope_table(rope_len, cfg.mla_rope or cfg.head_dim,
+                                  cfg.rope_theta, cfg.rope_yarn)
 
     moe_balance = jnp.zeros((), jnp.float32)
     exit_info = moe_picks = None
     if not cfg.looped:
-        # with ``attn_window`` the residual stream is float32 and every
-        # sub-layer still computes in the embeddings' type, as in the pass
-        # loop (``_looped_passes``); None: the stream's own type throughout
-        compute_dtype = x.dtype if cfg.attn_window else None
+        # with ``attn_window``, and under a listed pattern, the residual
+        # stream is float32 and every sub-layer still computes in the
+        # embeddings' type, as in the pass loop (``_looped_passes``); None:
+        # the stream's own type throughout. (A listed pattern: eighty
+        # branches times ``residual_mult`` each round a bfloat16 stream
+        # whole, which was a third of the served program's distance from
+        # the float32 reference: PERF.md section 6, PR 55)
+        compute_dtype = x.dtype if cfg.attn_window or cfg.layer_pattern \
+            else None
         if compute_dtype is not None:
             x = x.astype(jnp.float32)
-        if cfg.layer_group:
+        if cfg.layer_group or cfg.layer_pattern:
             x, kv_cache, moe_picks = _pattern_layers(
                 params, x, cfg, positions, sin, cos, kv_cache, cache_len,
-                decode, n_valid)
+                decode, n_valid, compute_dtype)
         else:
             x, kv_cache, moe_balance, moe_picks = _layers(
                 params, x, cfg, positions, sin, cos, kv_cache, 0, cache_len,
@@ -747,6 +859,8 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
             if cfg.logit_softcap > 0:
                 logits = cfg.logit_softcap * jnp.tanh(
                     logits / cfg.logit_softcap)
+            if cfg.logit_div != 1.0:
+                logits = logits / cfg.logit_div
 
     out = (x if return_hidden else logits,)
     if kv_cache is not None:

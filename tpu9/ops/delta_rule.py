@@ -44,10 +44,11 @@ BLOCK = 64
 
 
 def causal_conv(x: jnp.ndarray, taps: jnp.ndarray, tail: jnp.ndarray,
-                n_valid: jnp.ndarray):
+                n_valid: jnp.ndarray, bias=None):
     """Depthwise causal convolution over time. ``x`` [B, T, C] follows
     ``tail`` [B, K-1, C] (the lane's last inputs; zeros at a sequence's
-    start), ``taps`` [K, C]: ``y_t = sum_j taps[j] x_{t-(K-1)+j}``. Returns
+    start), ``taps`` [K, C]: ``y_t = sum_j taps[j] x_{t-(K-1)+j}`` (plus
+    ``bias`` [C] where one is given: the state-space mixer's). Returns
     ``(y [B, T, C] float32, new tail)``: the last ``K-1`` inputs once
     ``n_valid`` [B] of the ``T`` tokens are taken as real — with 0 real
     tokens the tail comes back as it was."""
@@ -56,6 +57,8 @@ def causal_conv(x: jnp.ndarray, taps: jnp.ndarray, tail: jnp.ndarray,
     seq = jnp.concatenate([tail.astype(x.dtype), x], axis=1)  # [B, K-1+T, C]
     seq32 = seq.astype(F32)
     y = sum(taps[j].astype(F32) * seq32[:, j:j + t] for j in range(k))
+    if bias is not None:
+        y = y + bias.astype(F32)
     new_tail = jax.vmap(
         lambda s, n: jax.lax.dynamic_slice_in_dim(s, n, k - 1, axis=0))(
             seq, n_valid.astype(jnp.int32))

@@ -56,9 +56,9 @@ def _layer_specs(layer: Params, tp: str, fsdp: Optional[str],
     }
     out = {name: _quant_aware(spec, layer.get(name))
            for name, spec in base.items() if name in layer}
-    for kind in ("kda", "mla"):
-        # a layer pattern's attention (``models.hybrid``): one chip's, a
-        # mesh is refused with it — every leaf replicated
+    for kind in ("kda", "mla", "ssm"):
+        # a layer pattern's attention (``models.hybrid``, ``models.ssm``): one
+        # chip's, a mesh is refused with it — every leaf replicated
         if kind in layer:
             out[kind] = replicate_specs(layer[kind])
     if "moe" in layer:

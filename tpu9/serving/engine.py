@@ -209,65 +209,89 @@ def refuse_unbuilt_with_summaries(cfg, ecfg: "EngineConfig",
 
 def refuse_unbuilt_with_lane_state(cfg, ecfg: "EngineConfig",
                                    topo: dict) -> None:
-    """What is not built for a layer pattern (``DecoderConfig.layer_group``:
-    which layers are KDA, whose state is kept by LANE, and which latent
-    attention over one row a token): refused when the engine is made, each
-    with its reason, and not half-built.
+    """What is not built for a decoder whose layers are not all plain
+    attention — a layer pattern stated by rule (``layer_group``: KDA layers,
+    whose state is kept by LANE, closed by latent attention over one row a
+    token) or as a list (``layer_pattern``: state-space layers, state a lane
+    too, around plain attention over per-head rows): refused when the engine
+    is made, each with its reason, and not half-built.
 
-    Two sorts of refusal. The STATE's — asked of ``cfg.layers_of("kda")``,
-    not of the pattern: the prefix cache (a hit starts a sequence behind
-    cached pages, and a KDA layer would need its state at that boundary),
-    and, where there is state, the state's reasons for verify and the host
-    tier. A pattern with no KDA layer keeps no such state, and its latent
-    pages are shared by the prefix cache like any paged rows. The ROWS' —
-    for every pattern, because a cache row is a latent: the dense cache,
-    verify (no program attends a window of several positions over latents),
-    a mesh, ``kv_quant``, the host tier and kvwire (they address per-head
-    ``k`` / ``v`` planes; no sharding rule and no wire format names a latent
-    row). Nothing in the engine preempts a running lane, so there is no path
-    that drops a lane's state without re-prefilling it; one that is added
-    has to snapshot or re-prefill (ROADMAP R6)."""
+    Two sorts of refusal, each option asked once. The STATE's — asked of
+    ``cfg.lane_state`` (does any layer keep state a lane), not of the
+    pattern: the prefix cache (a hit starts a sequence behind cached pages,
+    and those layers would need their state at that boundary) and, where
+    there is state, the state's reasons for the dense cache, verify, a mesh
+    and the host tier. A pattern with no such layer keeps no such state, and
+    its latent pages are shared by the prefix cache like any paged rows. The
+    ROWS' — for every ``layer_group``, because a cache row is a latent: the
+    dense cache, verify (no program attends a window of several positions
+    over latents), a mesh, ``kv_quant``, the host tier and kvwire (they
+    address per-head ``k`` / ``v`` planes; no sharding rule and no wire
+    format names a latent row). Per-head rows beside state are the plain
+    pool's, and only the state refuses. Nothing in the engine preempts a
+    running lane, so there is no path that drops a lane's state without
+    re-prefilling it; one that is added has to snapshot or re-prefill
+    (ROADMAP R6)."""
+    latent, state = bool(cfg.layer_group), cfg.lane_state
+    layers = "KDA" if "kda" in state else "state-space"
+    label = f"layer_group={cfg.layer_group}" if latent else \
+        f"layer_pattern with state a lane ({'/'.join(state)})"
+
     def refuse(what: str, why: str):
-        raise ValueError(f"layer_group={cfg.layer_group} with {what}: {why}")
+        raise ValueError(f"{label} with {what}: {why}")
 
-    state = bool(cfg.layers_of("kda"))
     if ecfg.kv_block_size <= 0:
         refuse("kv_block_size=0 (the dense cache)",
-               "the latent cache is built as a paged pool, and the dense "
-               "prefill buckets carry no KDA state into a lane")
+               " and ".join(
+                   ["the latent cache is built as a paged pool"] * latent
+                   + ["the dense prefill buckets carry no state into a lane "
+                      "(it is carried chunk to chunk through the paged "
+                      "engine's batch-1 scratch and spliced in at "
+                      "admission)"] * bool(state)))
     if ecfg.prefix_cache_blocks > 0 and state:
         refuse(f"prefix_cache_blocks={ecfg.prefix_cache_blocks}",
                "a hit would start a sequence behind cached pages, and the "
-               "KDA layers would need a snapshot of their state at that "
-               "block boundary, which nothing keeps")
+               f"{layers} layers would need a snapshot of their state at "
+               "that block boundary, which nothing keeps")
     if ecfg.spec_len > 0:
         refuse(f"spec_len={ecfg.spec_len} (verify)",
-               "a verify window advances the KDA state over its drafts, and "
-               "a rejected draft has then already changed it: there is no "
-               "state to roll back to" if state else
+               f"a verify window advances the {layers} state over its "
+               "drafts, and a rejected draft has then already changed it: "
+               "there is no state to roll back to" if state else
                "a verify window attends several positions a lane at once, "
                "and latent attention is built for a decode step's one "
                "(absorbed) and for a chunk over the batch-1 scratch: no "
                "program attends a window over the pool's latent rows")
     if topo["tp"] > 1 or topo.get("fsdp", 1) > 1 \
             or topo.get("n_chips", 1) > 1:
-        refuse(f"a mesh ({topo})",
+        refuse(f"a mesh ({topo})", {
+            (True, True):
                "the state a lane, the latent pool and the held experts are "
                "one chip's: no sharding rule names them, and the grouped "
-               "expert kernel is not partitioned" if state else
+               "expert kernel is not partitioned",
+            (True, False):
                "a latent row is one row for all heads, so the pool has no "
                "head axis to shard; no sharding rule names the latent "
                "pool or the held experts, and the latent and expert "
-               "kernels are not partitioned")
+               "kernels are not partitioned",
+            (False, True):
+               "the state a lane is one chip's: no sharding rule names it "
+               "(which axis of [planes, lanes, heads, width, state] a mesh "
+               "shards), and the step kernel is not partitioned",
+        }[latent, bool(state)])
     if ecfg.kv_quant:
         refuse(f"kv_quant={ecfg.kv_quant!r}",
                "the latent rows are read as they are written, in the "
-               "model's type; no scale planes are kept for them")
+               "model's type; no scale planes are kept for them" if latent
+               else
+               "the state is float32 by the configuration and most of what "
+               "a lane keeps; an int8 pool of the few attention planes "
+               "beside it was never run against the reference")
     if ecfg.kv_host_pool_mb > 0:
         refuse(f"kv_host_pool_mb={ecfg.kv_host_pool_mb}",
                "the host tier and the kvwire format ship the pool's rows "
                "and no state a lane: a prefix paged back in would attend "
-               "over latents with zeroed KDA layers" if state else
+               f"its rows with zeroed {layers} layers" if state else
                "the host tier and the kvwire format ship per-head key and "
                "value planes by their names and widths; a latent row and "
                "its rotated key have no place in either format")
@@ -397,7 +421,7 @@ class InferenceEngine:
         self.paged = engine_cfg.kv_block_size > 0
         if cfg.attn_window:
             refuse_unbuilt_with_summaries(cfg, engine_cfg, topo)
-        if cfg.layer_group:
+        if cfg.layer_group or cfg.lane_state:
             refuse_unbuilt_with_lane_state(cfg, engine_cfg, topo)
             from ..ops.quant import is_quantized_entry
             if any(is_quantized_entry(leaf) for leaf in
@@ -406,7 +430,9 @@ class InferenceEngine:
                 raise ValueError(
                     f"layer_group={cfg.layer_group} with int8 weights: the "
                     "pattern's layers and the held experts' einsum are "
-                    "built for the model's own type")
+                    "built for the model's own type" if cfg.layer_group else
+                    "layer_pattern with int8 weights: the state-space "
+                    "mixer's projections are built for the model's own type")
         from ..ops.quant import validate_quant_mode
         _kvq = validate_quant_mode(engine_cfg.kv_quant, "kv_quant")
         if _kvq and _kvq != "int8":
@@ -581,7 +607,7 @@ class InferenceEngine:
         # the counters stay 0). A dense decoder has none of them
         # (no KDA layer, no state a lane: no names, and no lane program)
         self._lane_state_names = tuple(kvstate.lane_shapes(cfg, b))
-        if cfg.layer_group:
+        if cfg.layer_group or cfg.lane_state:
             self._state_bytes = kvstate.lane_bytes(cfg, b)
         if cfg.layer_group or cfg.n_experts:
             self._stats.update(moe_local_picks=0, moe_token_layers=0,
@@ -725,7 +751,6 @@ class InferenceEngine:
         an update and pages a wave). A TPU replica whose attention declined
         a kernel still serves correctly through the oracle, so say why once
         here, where an operator reads the bring-up log."""
-        from ..ops import attention as ops
         if self.cfg.layer_group:
             # a layer pattern: latent attention (``ops.latent_attention``)
             # and the KDA recurrence (``ops.delta_rule``), each a kernel on
@@ -753,6 +778,22 @@ class InferenceEngine:
             return {"decode": decode + "; kda step: " + ran(
                         step_kernel_declined(cfg.n_heads, cfg.head_dim)),
                     "prefill": prefill + "; kda: chunkwise scan"}
+        if self.cfg.lane_state:
+            # a listed pattern: the plain attention's kernels below, and
+            # the state-space recurrence (``ops.ssd``) beside them
+            from ..models.ssm import scan_form, step_form
+            plain = self._plain_attention_paths()
+            widths = {self.graphs.chunk,
+                      self.graphs.chunk * self.graphs.group_chunks}
+            return {"decode": plain["decode"] + "; ssm step: "
+                    + step_form(self.cfg),
+                    "prefill": plain["prefill"] + "; ssm scan: " + ", ".join(
+                        sorted({scan_form(w) for w in widths}))}
+        return self._plain_attention_paths()
+
+    def _plain_attention_paths(self) -> dict:
+        """:meth:`_attention_paths` of the plain attention's kernels."""
+        from ..ops import attention as ops
         hd = self.cfg.head_dim
         s_max = self.ecfg.max_seq_len
         ran = "pallas"
@@ -1205,7 +1246,8 @@ class InferenceEngine:
         window summaries, whose blocks are addressed by cache entry and not
         by token position (the kvwire format's ``n_tokens``): the caller
         re-prefills, as for any miss."""
-        if not self.paged or self.cfg.attn_window or self.cfg.layer_group:
+        if not self.paged or self.cfg.attn_window or self.cfg.layer_group \
+                or self.cfg.lane_state:
             # (a layer pattern: the format ships rows and no state a lane)
             return None
         from .paged_kv import PrefixCache
@@ -1394,7 +1436,7 @@ class InferenceEngine:
         if self.cfg.looped:
             out["loop_steps"] = self.cfg.loop_steps
             out["loop_exit_hist"] = list(self._loop_exit_hist)
-        if self.cfg.layer_group:
+        if self.cfg.layer_group or self.cfg.lane_state:
             out["state_bytes"] = self._state_bytes
             out["state_bytes_per_lane"] = \
                 self._state_bytes // self.ecfg.max_batch

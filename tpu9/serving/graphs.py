@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from ..models import kvstate
 from ..models.hybrid import HYBRID_SCOPES, MLA_QUERY_SCOPES
+from ..models.ssm import SSM_SCOPES
 from ..models.transformer import (DEVICE_SCOPES, LOOP_SCOPES, SUMMARY_SCOPES,
                                   decoder_forward)
 from ..ops.sampling import sample_logits
@@ -222,7 +223,8 @@ class GraphFactory:
             # a looped decoder also says which pass the head read and how
             # many passes ran: ``exits`` is ``(exit_info [B, 1, 2],)`` for it
             # and empty for a plain one
-            if cfg.layer_group or (cfg.n_experts and not cfg.looped):
+            if cfg.layer_group or cfg.lane_state \
+                    or (cfg.n_experts and not cfg.looped):
                 # the step says which lanes are live: an idle lane advances
                 # no KDA state of a layer pattern and puts no expert on the
                 # list of those an expert layer reads; the step then also
@@ -375,7 +377,7 @@ class GraphFactory:
         positions = offset + jnp.arange(width)[None, :]
         if self.cfg.attn_window:
             scratch = self.traced_summarise_scratch(params, scratch, offset)
-        if self.cfg.layer_group:
+        if self.cfg.layer_group or self.cfg.lane_state:
             # the scratch carries the admitted sequence's KDA state from
             # chunk to chunk: zero where the sequence starts, advanced by the
             # chunk's real tokens alone (its tail is padding)
@@ -383,12 +385,13 @@ class GraphFactory:
                 name: jnp.where(offset == 0, jnp.zeros_like(scratch[name]),
                                 scratch[name])
                 for name in kvstate.lane_shapes(self.cfg, 1)})
-            logits, scratch, picks = decoder_forward(
+            # (a listed pattern without experts says no picks)
+            logits, scratch, *picks = decoder_forward(
                 params, tok_row[None, :], self.cfg, positions=positions,
                 kv_cache=scratch, cache_len=offset + width, decode=False,
                 n_valid=jnp.reshape(last_idx + 1, (1,)),
                 return_moe_picks=True)
-            extras = (picks[0],)
+            extras = tuple(p[0] for p in picks)
         else:
             extras = ()
             logits, scratch = decoder_forward(
@@ -755,7 +758,7 @@ class GraphFactory:
             # a plain program runs nothing under the loop's scopes
             scopes = hlo_scopes(
                 text, DEVICE_SCOPES + LOOP_SCOPES + SUMMARY_SCOPES
-                + HYBRID_SCOPES + MLA_QUERY_SCOPES)
+                + HYBRID_SCOPES + MLA_QUERY_SCOPES + SSM_SCOPES)
             if scopes:
                 self.device_scopes[name] = scopes
             else:
