@@ -18,6 +18,7 @@ from benchmark import correctness
 from test_granite_layers import ONE, SMALL, TOL, _model, _ref_logits
 from tpu9.models import init_decoder, kvstate
 from tpu9.models import hybrid, ssm
+from tpu9.ops import ssd
 from tpu9.models.transformer import (DEVICE_SCOPES, LOOP_SCOPES,
                                      SUMMARY_SCOPES)
 from tpu9.serving.engine import EngineConfig, InferenceEngine
@@ -134,8 +135,10 @@ def test_the_kernel_report_says_which_form_ran(monkeypatch):
     assert ssm.scan_form(512) == "xla: chunkwise (SSD), blocks of 256"
     assert ssm.scan_form(300) == "xla: a token at a time (not whole blocks)"
     monkeypatch.setattr(tpu9.utils, "on_tpu", lambda: True)
-    assert ssm.step_form(replace(SMALL, ssm_head_dim=64)) \
-        == "pallas, in place, live lanes only"
+    # (the words name the kernel's constant, ``ops.ssd.GROUP_LANES``)
+    assert ssm.step_form(replace(SMALL, ssm_head_dim=64)) == (
+        "pallas, in place, one call walks the live lanes, "
+        f"{ssd.GROUP_LANES} read, stepped and written back at a time")
     assert ssm.step_form(replace(SMALL, ssm_head_dim=48)).startswith("xla: ")
 
 

@@ -108,23 +108,48 @@ def test_the_stored_state_packs_heads_to_whole_rows():
             == np.asarray(state)).all()
 
 
+def _live_case(case):
+    """A case of the step kernel's test as a tuple of bools: itself, or by
+    name one at the edges of the kernel's groups — ``ssd.GROUP_LANES`` slots,
+    three lanes more than that, the live ones scattered from lane 1 to the
+    array's last (never a prefix)."""
+    if not isinstance(case, str):
+        return case
+    group = ssd.GROUP_LANES
+    b = group + 3
+    n = {"last-only": 1, "group-1": group - 1, "group": group,
+         "group+1": group + 1, "all": b}[case]
+    if n == b:
+        return (True,) * b
+    lanes = {int(round(float(x))) for x in np.linspace(b - 1, 1, n)}
+    assert len(lanes) == n and b - 1 in lanes and 0 not in lanes
+    return tuple(i in lanes for i in range(b))
+
+
 @pytest.mark.parametrize("live", [(True, False, True, True),
                                   (False, False, True, False),
-                                  (False, False, False, False)],
-                         ids=["three", "one", "none"])
+                                  (False, False, False, False),
+                                  "last-only", "group-1", "group", "group+1",
+                                  "all"],
+                         ids=["three", "one", "none", "last-only", "group-1",
+                              "group", "group+1", "all"])
 @pytest.mark.parametrize("groups,p", [(1, 8), (2, 8), (1, 64), (2, 64)])
 def test_the_step_kernel_equals_the_step_in_place_on_live_lanes(live, groups,
                                                                 p):
     """The Pallas step (interpreted) over the STORED state (a head a row at
     P = 8, two heads a row at P = 64): plane 1 of three advanced for the
     live lanes, every idle lane's state and both other planes bit for bit,
-    an idle lane's output zero."""
-    state, x, dt, a_head, bm, cm = _inputs(6, 4, 1, p=p, g=groups)
+    an idle lane's output zero — with no lane live (nothing is copied), one,
+    and as many as a group of the kernel has slots, one fewer, one more (a
+    second group of one) and every lane (:func:`_live_case`), the live list
+    scattered."""
+    live = jnp.asarray(_live_case(live))
+    state, x, dt, a_head, bm, cm = _inputs(6, live.shape[0], 1, p=p,
+                                           g=groups)
     pack = ssd.head_pack(H, p, groups)
     assert pack == (2 if p == 64 else 1)
     planes = jnp.stack([ssd.pack_state(s, pack)
                         for s in (state + 1.0, state, state - 1.0)])
-    live = jnp.asarray(live)
     args = (x[:, 0], dt[:, 0], a_head, bm[:, 0], cm[:, 0])
     want, want_out = ssd.step(state, *args, live=live)
     step = jax.jit(lambda planes, *a: ssd.step_pallas(planes, 1, *a,
@@ -142,11 +167,11 @@ def test_the_step_kernel_equals_the_step_in_place_on_live_lanes(live, groups,
     assert (got[2] == np.asarray(planes[2])).all()
 
 
-def test_the_live_lanes_come_first_and_the_list_repeats_its_last():
+def test_the_live_lanes_come_first_in_order():
     lanes, n = ssd.live_lanes(jnp.asarray([False, True, False, True, False]))
-    assert np.asarray(lanes).tolist() == [1, 3, 3, 3, 3] and int(n[0]) == 2
+    assert np.asarray(lanes).tolist() == [1, 3, 0, 2, 4] and int(n[0]) == 2
     lanes, n = ssd.live_lanes(jnp.zeros((3,), bool))
-    assert np.asarray(lanes).tolist() == [0, 0, 0] and int(n[0]) == 0
+    assert np.asarray(lanes).tolist() == [0, 1, 2] and int(n[0]) == 0
 
 
 def test_the_kernel_declines_off_the_chip_and_at_ragged_tiles(monkeypatch):

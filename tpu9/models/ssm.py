@@ -173,7 +173,9 @@ def step_form(cfg) -> str:
     (``/health``'s kernel report)."""
     why = ssd.step_kernel_declined(cfg.ssm_heads, cfg.ssm_head_dim,
                                    cfg.ssm_state, cfg.ssm_groups)
-    return f"xla: {why}" if why else "pallas, in place, live lanes only"
+    return f"xla: {why}" if why else (
+        "pallas, in place, one call walks the live lanes, "
+        f"{ssd.GROUP_LANES} read, stepped and written back at a time")
 
 
 def scan_form(width: int) -> str:

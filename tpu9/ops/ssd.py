@@ -21,9 +21,11 @@ remembers):
   the array does not fit beside it), which is kept in the STORED form
   ``[planes, lanes, H / pack, N, pack P]`` (:func:`state_shape`,
   :func:`pack_state`: the ``jax.numpy`` forms take ``[B, H, P, N]``), and
-  moves the LIVE lanes only: the lanes to step are a prefetched list, a
-  grid step past its end names the block of the step before, which the
-  pipeline neither fetches nor writes again.
+  moves the LIVE lanes only: the array stays in HBM and ONE invocation walks
+  a prefetched list of the live lanes, a group at a time — the group's
+  blocks copied in, each stepped as it lands, all copied back
+  (:func:`_step_kernel`). An idle lane costs nothing, no live lane nothing
+  at all.
 - :func:`scan`: a token at a time under ``lax.scan`` — the oracle.
 - :func:`chunked`: the chunkwise-parallel (SSD) form — a prefill. Inside a
   block of ``BLOCK`` tokens, with ``a_t = dt_t A_h``, ``g_t = sum_{i<=t} a_i``
@@ -131,50 +133,113 @@ def step_kernel_declined(heads: int, head_dim: int, state_dim: int,
     return ""
 
 
-def _step_kernel(lanes_ref, n_ref, s_ref, a_ref, x_ref, b_ref, c_ref,
-                 s_out, y_ref, *, groups: int):
-    """Grid step ``i``: lane ``lanes_ref[i]``, every head. ``s_ref`` [H /
-    pack, N, W] (:func:`state_shape`); ``a_ref`` [H / pack, W] the decays
-    and ``x_ref`` the inputs ``dt x``, a head's along its lanes: ROWS that
-    broadcast down the sublanes of a ``[N, W]`` state; ``b_ref``, ``c_ref``
-    [N, G], a group's a COLUMN that broadcasts along the lanes; ``y_ref``
-    [H / pack, W], rows (the sum over ``N`` runs down the sublanes)."""
-    i, n = pl.program_id(0), n_ref[0]
-    rows = s_ref.shape[0]
-    shape = s_ref.shape[1:]
+# lanes a group of the step kernel: it reads a group's blocks (a lane's whole
+# stored state, 2 MB at the published widths), steps them and writes them
+# back, ONE direction of copies in flight at a time. On a v5e the blocks of 26
+# scattered lanes are read at 715 GB/s and written at 631, and an in-place
+# copy that keeps reads and write-backs in flight TOGETHER — however many —
+# moves 630 in all; a group read, then written, 665–670 (PR 56, the kernel
+# alone: groups of 4 / 8 / 13 / 26 lanes 165.1 / 164.1 / 163.1 / 163.1 us a
+# call). Eight: 16 MB of VMEM
+GROUP_LANES = 8
 
-    @pl.when(i < n)
-    def _():
-        wide = {}
-        for r in range(rows):
-            g = r * groups // rows
-            if g not in wide:
-                col = slice(g, g + 1)
-                wide[g] = (jnp.broadcast_to(b_ref[:, col], shape),
-                           jnp.broadcast_to(c_ref[:, col], shape))
-            bb, cb = wide[g]
-            row = slice(r, r + 1)
-            new = s_ref[r] * a_ref[row, :] + bb * x_ref[row, :]
-            s_out[r] = new
-            y_ref[row, :] = jnp.sum(new * cb, axis=0, keepdims=True)
 
-    @pl.when((i == 0) & (n == 0))
-    def _():
-        # no lane is live: block 0 was fetched and is written back, as it
-        # came (the one lane such a step carries)
-        s_out[...] = s_ref[...]
-        y_ref[...] = jnp.zeros_like(y_ref)
+def _column(ref, vec, n: int, width: int):
+    """Vector ``vec`` of ``ref`` — ``[vectors x ceil(n / LANES), LANES]``, a
+    vector's numbers in whole rows of ``LANES`` — as ``[n, width]``: its
+    numbers down the sublanes, each broadcast along the lanes, a square
+    tile's transpose at a time."""
+    chunks = -(-n // LANES)
+    tiles = [jnp.broadcast_to(ref[pl.ds(vec * chunks + k, 1), :],
+                              (LANES, LANES)).T for k in range(chunks)]
+    col = (tiles[0] if chunks == 1 else jnp.concatenate(tiles, 0))[:n]
+    reps = -(-width // LANES)
+    if reps > 1:
+        col = jnp.concatenate([col] * reps, axis=1)
+    return col[:, :width]
+
+
+def _step_kernel(lanes_ref, n_ref, s_hbm, a_ref, x_ref, b_ref, c_ref,
+                 s_out, y_ref, buf, sem_in, sem_out, *, plane: int,
+                 groups: int):
+    """ONE invocation steps the live lanes ``lanes_ref[:n_ref[0]]``, a group
+    of ``GROUP_LANES`` at a time. ``s_hbm`` and ``s_out`` are the whole
+    states array where it lies (one buffer: the output is aliased to the
+    input); ``buf`` [GROUP_LANES, H / pack, N, W] the slots a lane's state
+    (:func:`state_shape`) is copied into, stepped in and copied back from.
+    ``a_ref`` [B, H / pack, W] the decays and ``x_ref`` the inputs ``dt x``,
+    a head's along its lanes: ROWS that broadcast down the sublanes of a
+    ``[N, W]`` state; ``b_ref``, ``c_ref`` a lane's and group's ``N`` numbers
+    in rows that :func:`_column` lays down the sublanes; ``y_ref`` [B, H /
+    pack, W], rows (the sum over ``N`` runs down the sublanes).
+
+    A group: its reads are all started, and each lane is stepped as its read
+    lands, under the reads behind it; when the last has landed the stepped
+    lanes' write-backs start, under the last lane's arithmetic; the next
+    group's reads start when the write-backs are done — reads and
+    write-backs are never in flight together (``GROUP_LANES`` says why). No
+    live lane: no group, nothing is copied, the array stays as it is."""
+    n = n_ref[0]
+    group, rows, n_state, width = buf.shape
+
+    def read(j, slot):
+        return pltpu.make_async_copy(s_hbm.at[plane, lanes_ref[j]],
+                                     buf.at[slot], sem_in.at[slot])
+
+    def write(j, slot):
+        return pltpu.make_async_copy(buf.at[slot],
+                                     s_out.at[plane, lanes_ref[j]],
+                                     sem_out.at[slot])
+
+    def step_lane(j, slot):
+        lane = lanes_ref[j]
+        for g in range(groups):
+            vec = lane * groups + g
+            bb = _column(b_ref, vec, n_state, width)
+            cb = _column(c_ref, vec, n_state, width)
+
+            def one_row(r, _):
+                row = pl.ds(r, 1)
+                new = buf[slot, r] * a_ref[lane, row, :] \
+                    + bb * x_ref[lane, row, :]
+                buf[slot, r] = new
+                y_ref[lane, row, :] = jnp.sum(new * cb, axis=0,
+                                              keepdims=True)
+
+            jax.lax.fori_loop(g * rows // groups, (g + 1) * rows // groups,
+                              one_row, None)
+
+    def one_group(g, _):
+        first = g * group
+        count = jnp.minimum(n - first, group)
+
+        def each(stop, act):
+            jax.lax.fori_loop(0, stop, lambda k, _: act(first + k, k), None)
+
+        each(count, lambda j, slot: read(j, slot).start())
+
+        def one_lane(k, _):
+            read(first + k, k).wait()
+
+            @pl.when(k == count - 1)
+            def _():
+                each(k, lambda j, slot: write(j, slot).start())
+
+            step_lane(first + k, k)
+
+        jax.lax.fori_loop(0, count, one_lane, None)
+        write(first + count - 1, count - 1).start()
+        each(count, lambda j, slot: write(j, slot).wait())
+
+    jax.lax.fori_loop(0, pl.cdiv(n, group), one_group, None)
 
 
 def live_lanes(live):
     """``(lanes [B], n [1])``: the live lanes first, in order, and how many
-    they are; past its end the list repeats its last live lane (lane 0
-    where none is), so that the grid steps there name a block the pipeline
-    already holds."""
-    b = live.shape[0]
+    they are — the kernel's prefetched list and its trip count (the idle
+    lanes follow; it never reads them)."""
     n = jnp.sum(live.astype(jnp.int32))
-    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
-    lanes = jnp.where(jnp.arange(b) < n, order, order[jnp.maximum(n - 1, 0)])
+    lanes = jnp.argsort(~live, stable=True).astype(jnp.int32)
     return lanes, jnp.reshape(n, (1,))
 
 
@@ -184,9 +249,10 @@ def step_pallas(states, plane: int, x, dt, a_head, bm, cm, live=None,
     pack P] (the STORED form, :func:`state_shape`), in place: the kernel
     reads a LIVE lane's state once and writes it once — what the recurrence
     has to move — and an idle lane's not at all; the other planes are not
-    touched (the array is aliased to the output). ``x`` [B, H, P], ``dt``
-    [B, H], ``bm, cm`` [B, G, N]. Returns ``(states, y [B, H, P])``, an idle
-    lane's ``y`` zero."""
+    touched (the array stays in HBM, aliased to the output, and the kernel
+    copies the live lanes' blocks itself: :func:`_step_kernel`). ``x`` [B, H,
+    P], ``dt`` [B, H], ``bm, cm`` [B, G, N]. Returns ``(states, y [B, H,
+    P])``, an idle lane's ``y`` zero."""
     _, b, rows, n, width = states.shape
     h, p = x.shape[1:]
     g = bm.shape[1]
@@ -198,33 +264,43 @@ def step_pallas(states, plane: int, x, dt, a_head, bm, cm, live=None,
         b, rows, width)
     xdt = (dt[..., None] * x).astype(F32).reshape(b, rows, width)
 
-    def lane(*block):
-        return pl.BlockSpec((None,) + block,
-                            lambda i, lanes, n: (lanes[i], 0, 0))
+    def vectors(m):     # [B, G, N] in whole rows of ``LANES`` numbers
+        return jnp.pad(m.astype(F32).reshape(b * g, n),
+                       ((0, 0), (0, -n % LANES))).reshape(-1, LANES)
 
-    state = pl.BlockSpec((None, None, rows, n, width),
-                         lambda i, lanes, n: (plane, lanes[i], 0, 0, 0))
+    whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+    where_it_lies = pl.BlockSpec(memory_space=pl.ANY)
+    block = rows * n * width * 4
+    # (a group's slots in 48 MB of VMEM at most: a lane's block is 2 MB at
+    # the published widths)
+    group = max(1, min(GROUP_LANES, b, (48 << 20) // block))
     states, y = pl.pallas_call(
-        functools.partial(_step_kernel, groups=g),
+        functools.partial(_step_kernel, plane=plane, groups=g),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(b,),
-            in_specs=[state, lane(rows, width), lane(rows, width),
-                      lane(n, g), lane(n, g)],
-            out_specs=[state, lane(rows, width)]),
+            num_scalar_prefetch=2, grid=(1,),
+            in_specs=[where_it_lies, whole, whole, whole, whole],
+            out_specs=[where_it_lies, whole],
+            scratch_shapes=[pltpu.VMEM((group, rows, n, width), F32),
+                            pltpu.SemaphoreType.DMA((group,)),
+                            pltpu.SemaphoreType.DMA((group,))]),
         out_shape=[jax.ShapeDtypeStruct(states.shape, F32),
                    jax.ShapeDtypeStruct((b, rows, width), F32)],
         # operand 2 (after the two prefetched scalars) is the states array
         input_output_aliases={2: 0},
+        # VMEM: the slots and 8 MB, no more — what the call reserves XLA
+        # cannot prefetch the layers' weights into. And NO cost estimate: the
+        # scheduler reads one as time under which to hide those prefetches,
+        # and this call leaves the HBM none — told the bytes it moves, XLA
+        # piled the next projections' weights on it and left the short
+        # operations around it with nothing in flight (PR 56: a decode step
+        # of granite-4.0-h-micro 16.55 ms told, 15.45 untold; 15.84 with 64
+        # MB reserved)
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=64 * 1024 * 1024),
-        cost_estimate=pl.CostEstimate(
-            flops=5 * b * h * p * n, transcendentals=0,
-            bytes_accessed=2 * b * h * p * n * 4),
+            vmem_limit_bytes=group * block + (8 << 20)),
         name=STEP_KERNEL,
         interpret=interpret,
-    )(lanes, count, states, decay, xdt,
-      jnp.swapaxes(bm, 1, 2).astype(F32), jnp.swapaxes(cm, 1, 2).astype(F32))
+    )(lanes, count, states, decay, xdt, vectors(bm), vectors(cm))
     y = jnp.where(live[:, None, None], y.reshape(b, h, p), 0.0)
     return states, y
 
