@@ -40,6 +40,9 @@ GATEWAY = {"pre": "tpu9_gateway_stream_pre_s",
            "first": "tpu9_gateway_stream_first_s"}
 RUNNER = ("ingest", "runner_first")         # /health latency.<x>_*
 ENGINE = ("ttft", "stream_lag", "queue_wait", "prefill", "first_hold")
+# a token's gap, the last token's hops as the first's (ISSUE 57)
+GAP = "tpu9_gateway_stream_gap_s"
+GAPS = ("runner_gap", "tpot", "gap_max")    # /health latency.<x>_*
 N_STREAMS = 5
 N_TOKENS = 16
 PROMPT = [5, 3, 9, 4]
@@ -58,7 +61,7 @@ def _latency_totals(health: dict) -> dict:
     lat = health.get("latency") or {}
     return {part: (lat.get(f"{part}_count", 0),
                    lat.get(f"{part}_mean_s", 0.0) * lat.get(f"{part}_count", 0))
-            for part in RUNNER + ENGINE}
+            for part in RUNNER + ENGINE + GAPS}
 
 
 def _delta(a: dict, b: dict) -> dict:
@@ -121,7 +124,8 @@ async def _spans_of(stack, trace_id: str, want: set) -> list:
 SPAN_NAMES = {"gateway.invoke", "gateway.pre_forward", "gateway.connect",
               "gateway.first_token", "runner.ingest", "runner.first_token",
               "engine.request", "engine.queue_wait", "engine.prefill",
-              "engine.first_hold", "engine.decode"}
+              "engine.first_hold", "engine.decode",
+              "gateway.stream", "runner.stream"}
 
 
 @pytest.fixture(scope="module")
@@ -288,6 +292,45 @@ def test_a_failover_attempt_observes_nothing_again(served):
     for name in ("gateway.pre_forward", "gateway.connect",
                  "gateway.first_token"):
         assert names.count(name) == 1, names
+
+
+def _gap_total(snap: dict) -> tuple:
+    s = snap["summaries"].get(GAP)
+    return (s["count"], s["mean"] * s["count"]) if s else (0, 0.0)
+
+
+def test_a_streams_gap_is_told_once_a_hop(served):
+    """One observation a stream at the gateway, the runner and the engine
+    (N_TOKENS tokens a stream: one a token would read 16 x), and the three
+    nest: each hop's interval opens after and closes before the next
+    outer one's, over the same tokens less one."""
+    n0, t0 = _gap_total(served["gateway0"])
+    n1, t1 = _gap_total(served["gateway1"])
+    assert n1 - n0 == N_STREAMS
+    r = _delta(_latency_totals(served["health0"]),
+               _latency_totals(served["health1"]))
+    assert {part: r[part][0] for part in GAPS} == dict.fromkeys(
+        GAPS, N_STREAMS), r
+    sp = {s["name"]: s for s in served["spans"]}
+    for name in ("gateway.stream", "runner.stream"):
+        assert sp[name]["attributes"]["tokens"] == N_TOKENS, sp[name]
+    dec = sp["engine.decode"]["attributes"]
+    assert dec["tokens"] == N_TOKENS - 1
+    assert dec["admissions_behind"] == 0 and dec["admit_stall_ms"] == 0
+    assert dec["gap_max_ms"] >= dec["gap_mean_ms"] > 0
+    # a stream alone: its decode span IS its gaps, first token -> last
+    assert sp["engine.decode"]["durationMs"] == pytest.approx(
+        dec["gap_mean_ms"] * (N_TOKENS - 1), abs=0.02)
+
+
+def test_a_failover_tells_no_gap(served):
+    """The first attempt died before its done event and the second is not
+    the first: neither tells the stream's gap (its tokens came from two
+    replicas, with a failover between)."""
+    assert _gap_total(served["gateway3"])[0] \
+        == _gap_total(served["gateway2"])[0]
+    assert "gateway.stream" not in [
+        s["name"] for s in served["failover_spans"]]
 
 
 def test_the_response_names_its_trace(served):

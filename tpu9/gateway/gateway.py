@@ -2469,14 +2469,30 @@ class Gateway:
         hop_attrs = {"stub_id": stub.stub_id,
                      "workspace_id": stub.workspace_id}
 
+        t_first_written = 0.0
+
         def first_token_written() -> None:
             # a child that outlives its parent: gateway.invoke ended with
             # the headers, this with the first token on the client's socket
+            nonlocal t_first_written
+            t_first_written = time.monotonic()
             tracer.record_interval(
                 "gateway.first_token", metrics,
                 "tpu9_gateway_stream_first_s", t_gateway,
-                handle.t_open_mono, time.monotonic(), trace=trace_ref,
+                handle.t_open_mono, t_first_written, trace=trace_ref,
                 attrs=dict(hop_attrs))
+
+        def stream_done(t_last_written: float) -> None:
+            # the client's gap between tokens as the gateway wrote them
+            # (ISSUE 57): first token written -> last, over the tokens of
+            # this attempt, the first (the watermark counts them)
+            n = resume.watermark
+            if n >= 2 and t_first_written:
+                tracer.record_interval(
+                    "gateway.stream", metrics, "tpu9_gateway_stream_gap_s",
+                    t_gateway, t_first_written, t_last_written,
+                    trace=trace_ref, attrs={**hop_attrs, "tokens": n},
+                    per=n - 1)
         finished = False
         terminal_error = False         # stream ended on a forwarded error
         last_failure: Optional[sv.AttemptOutcome] = None
@@ -2687,9 +2703,11 @@ class Gateway:
                         await handle.close()
                         await _finish_journal(499)
                         return sr
+                first = budget.attempt == 1
                 outcome = await self._relay_stream_events(
                     handle, resume, sr,
-                    first_token_written if budget.attempt == 1 else None)
+                    first_token_written if first else None,
+                    stream_done if first else None)
                 await handle.close()
                 if outcome.kind == "done":
                     finished = True
@@ -2852,18 +2870,20 @@ class Gateway:
 
     async def _relay_stream_events(self, handle, resume,
                                    sr: web.StreamResponse,
-                                   on_first_token=None):
+                                   on_first_token=None, on_done=None):
         """Event-aware relay for one attempt of a resumable LLM stream:
         forward token events (advancing the watermark), swallow the
         attempt's own done/error events (the terminal event is owned by
         the failover loop — a resumed attempt's done only knows its own
         suffix), and classify how the attempt ended. ``on_first_token``
         is called once, when the first token event of the attempt has
-        been written to the client."""
+        been written to the client; ``on_done`` at the attempt's done
+        event, with the stamp at which its last token had been written."""
         import aiohttp as _aiohttp
         from . import survival as sv
         parser = sv.SseParser()
         it = handle.iter_chunks().__aiter__()
+        t_written = 0.0
         while True:
             try:
                 chunk = await it.__anext__()
@@ -2892,6 +2912,7 @@ class Gateway:
                     except (ConnectionResetError, OSError) as exc:
                         log.debug("client gone mid-stream: %s", exc)
                         return sv.AttemptOutcome(kind="client_gone")
+                    t_written = time.monotonic()
                     if on_first_token is not None:
                         on_first_token()
                         on_first_token = None
@@ -2903,6 +2924,8 @@ class Gateway:
                     resume.note_kv(str(ev.get("kv_key", "")),
                                    int(ev.get("n_tokens", 0) or 0))
                 elif ev.get("done"):
+                    if on_done is not None:
+                        on_done(t_written)
                     return sv.AttemptOutcome(kind="done")
                 elif "error" in ev:
                     msg = str(ev.get("error", ""))

@@ -266,7 +266,8 @@ class Tracer:
     def record_interval(self, name: str, registry, summary: str,
                         anchor: tuple, t0_mono: float, t1_mono: float,
                         trace: Optional[tuple] = None,
-                        attrs: Optional[dict] = None) -> float:
+                        attrs: Optional[dict] = None,
+                        per: int = 1) -> float:
         """One interval between two ``time.monotonic()`` stamps of THIS
         process, told twice (ISSUE 41): always an observation of
         ``summary`` in ``registry`` (a :class:`Metrics`), and, where the
@@ -274,10 +275,12 @@ class Tracer:
         the span ``name`` under it. ``anchor`` is the hop's one
         ``(wall, monotonic)`` pair, as for :meth:`record_window`. A hop
         stamps its boundaries once and hands each pair here, so a summary
-        and its span can never disagree about what they cover. Returns
-        the seconds."""
+        and its span can never disagree about what they cover. ``per``: the
+        interval holds that many equal steps (a stream's gaps between its
+        tokens), and the summary takes one step's seconds while the span
+        covers them all. Returns the interval's seconds."""
         seconds = max(t1_mono - t0_mono, 0.0)
-        registry.observe(summary, seconds)
+        registry.observe(summary, seconds / per)
         if trace is not None and trace[0]:
             self.record_window(name, anchor[0], anchor[1], t0_mono, t1_mono,
                                trace_id=trace[0], parent_id=trace[1],

@@ -350,6 +350,7 @@ async def amain() -> None:
         await sr.prepare(request)
         state["engine"].note_ingest(req, t_in, t_enqueued, _now.monotonic())
         out: list = []
+        t_written = 0.0     # the newest token's write (ISSUE 57)
         # export_after_prefill (ISSUE 16): announce once, right after the
         # first token proves prefill (and its prefix-cache insert) is done
         kv_pending = kv_export and kv_client is not None
@@ -371,6 +372,7 @@ async def amain() -> None:
                     os._exit(17)
                 await sr.write(
                     f"data: {json.dumps({'token': tok})}\n\n".encode())
+                t_written = _now.monotonic()
                 if len(out) == 1:
                     # stream lag and the runner's first-token interval:
                     # both end where the first token is written
@@ -385,6 +387,8 @@ async def amain() -> None:
                 await sr.write(
                     f"data: {json.dumps({'error': req.error})}\n\n".encode())
             else:
+                # the runner's own gap between tokens: first write -> last
+                state["engine"].note_last_write(req, t_written)
                 await sr.write(
                     f"data: {json.dumps({'done': True, 'tokens': out})}\n\n"
                     .encode())

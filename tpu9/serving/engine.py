@@ -331,6 +331,14 @@ class _Window:
     kv_snap: tuple = ()       # (used, free, reserved) at dispatch (paged)
     delivered: Any = None     # {slot: tokens delivered} (host processing)
     spec_stats: tuple = ()    # (proposed, accepted) (verify processing)
+    # a token's gap (ISSUE 57): the admission clock at dispatch, and at the
+    # fan-out the time since the fan-out before it, the part of that the
+    # admission clock moved by, and whether no admission touched the
+    # window (``_obs_gap``)
+    gap_clock0: float = 0.0
+    period_s: float = 0.0
+    admit_s: float = 0.0
+    clean: bool = False
 
 
 @dataclass
@@ -371,6 +379,15 @@ class _Request:
     # anchors of its first window's dispatch, and the span's attributes
     dec_anchor: tuple = ()
     dec: Optional[dict] = None
+    # a stream's own gap (ISSUE 57): the marks (``_gap_marks``: stamp,
+    # admission clock, admissions begun) at its first token and at its last
+    # delivery — the one tuple a window makes, shared by every lane it
+    # delivered to — and the largest period between two of its deliveries;
+    # the runner's first write (``note_first_write``)
+    gap_first: tuple = ()
+    gap_last: tuple = ()
+    gap_max: float = 0.0
+    t_first_write_mono: float = 0.0
     # a layer pattern's expert layers (``routed_experts``): the experts
     # every routed position chose, [n, expert layers, top_k] a prefill
     # dispatch (device arrays with their real rows until the first token is
@@ -559,6 +576,21 @@ class InferenceEngine:
                        # went through the wide group forward
                        "admit_chunks": 0, "admit_chunks_grouped": 0,
                        "admit_interleaved_windows": 0,
+                       # what those chunks held (ISSUE 57): the suffixes'
+                       # real tokens, and the tokens their programs ran
+                       # over (whole chunks)
+                       "admit_tokens": 0, "admit_tokens_padded": 0,
+                       # a token's gap, told from inside (ISSUE 57): the
+                       # tokens the windows delivered; summed over the
+                       # lanes they delivered to, the seconds since each
+                       # lane's delivery before, the part of them the
+                       # admission clock moved by, and the windows' steps;
+                       # the same period and steps over the lanes of the
+                       # windows no admission touched; admissions begun
+                       "gap_tokens": 0, "gap_lane_period_s": 0.0,
+                       "gap_lane_admit_s": 0.0, "gap_lane_steps": 0,
+                       "gap_clean_lane_period_s": 0.0,
+                       "gap_clean_lane_steps": 0, "gap_admissions": 0,
                        # cached rows below a prefix hit that an admission
                        # prefilled again: the hit rounded down to a chunk
                        # (``_admit_lookup``'s fall-back)
@@ -714,6 +746,12 @@ class InferenceEngine:
         # stamped on host paths the loop already runs — zero new syncs.
         self._windows_processed = 0
         self._last_dispatch_mono = 0.0
+        # the admission clock (ISSUE 57): seconds the serve loop has spent
+        # in closed admission episodes, the start of the open one (0: none),
+        # and the marks of the last window that delivered tokens
+        self._admit_clock_s = 0.0
+        self._admit_open_mono = 0.0
+        self._gap_prev: tuple = ()
         self._last_progress_mono = time.monotonic()
         # HBM watermarks: live per-chip residency sampled on the stats()
         # READ path (heartbeat cadence) vs the planned residency computed
@@ -1535,7 +1573,8 @@ class InferenceEngine:
         summaries = self.metrics.to_dict()["summaries"]
         for part in ("ttft", "queue_wait", "prefill", "first_hold",
                      "stream_lag", "ingest", "runner_first",
-                     "decode_window", "e2e"):
+                     "decode_window", "e2e", "tpot", "gap_max",
+                     "runner_gap"):
             snap = summaries.get(f"tpu9_engine_{part}_s")
             if snap:
                 lat[f"{part}_p50_s"] = round(snap["p50"], 6)
@@ -1649,6 +1688,8 @@ class InferenceEngine:
                 req, slot, p)
         n_chunks = len(offsets)
         self._stats["admit_chunks"] += n_chunks
+        self._stats["admit_tokens"] += n - req.admit_cached
+        self._stats["admit_tokens_padded"] += n_chunks * self._chunk
         if self.cfg.mla_latent:
             self._stats["prompt_rows_admitted"] += n
             self._stats["prefix_rows_reused"] += p
@@ -2040,6 +2081,7 @@ class InferenceEngine:
         # liveness watermark (ISSUE 14): the watchdog's "did the loop
         # still reach a dispatch" stamp
         self._last_dispatch_mono = win.t_mono
+        win.gap_clock0 = self._admit_clock(win.t_mono)
         win.pick = self._pick_reason
         if self.paged:
             win.kv_snap = (self.allocator.used_count,
@@ -2053,7 +2095,8 @@ class InferenceEngine:
         recorded once the request has retired (per-window detail lives in
         the flight record and the ``engine.window.*`` phases). ``wait_s``
         (dispatch → fan-out start) includes the deliberate one-window
-        overlap; ``host_s`` is the fan-out."""
+        overlap; ``host_s`` is the fan-out. ``period_s`` is what a running
+        stream sees of the window (``_obs_gap``)."""
         now_m = time.monotonic()
         self.metrics.observe("tpu9_engine_decode_window_s",
                              max(t_host0 - win.t_mono, 0.0))
@@ -2063,6 +2106,7 @@ class InferenceEngine:
         self._windows_processed += 1
         self._last_progress_mono = now_m
         delivered = win.delivered or {}
+        self._obs_gap(win, t_host0, delivered)
         if self.flight is not None:
             slots = {s: r.request_id
                      for s, r in enumerate(win.reqs)
@@ -2071,7 +2115,10 @@ class InferenceEngine:
                    "batch": int(win.mask.sum()),
                    "slots": slots, "tokens": delivered,
                    "wait_s": round(max(t_host0 - win.t_mono, 0.0), 6),
-                   "host_s": round(max(now_m - t_host0, 0.0), 6)}
+                   "host_s": round(max(now_m - t_host0, 0.0), 6),
+                   "period_s": round(win.period_s, 6),
+                   "admit_s": round(win.admit_s, 6),
+                   "lanes": len(delivered)}
             topo = self.policy.describe()
             if topo["n_chips"] > 1:
                 # stamp the submesh onto multichip window records only —
@@ -2097,11 +2144,10 @@ class InferenceEngine:
                     self._flight_evictions = ev
             self.flight.record(win.kind, **rec)
         for slot, req in enumerate(win.reqs):
-            if (req is None or req.trace is None or not req.span_id
-                    or not win.mask[slot]):
+            if req is None or not win.mask[slot]:
                 continue
             n_tok = delivered.get(slot, 0)
-            if n_tok > 0:
+            if n_tok > 0 and req.span_id:
                 if req.dec is None:
                     req.dec_anchor = (win.t_wall, win.t_mono)
                     req.dec = {"request_id": req.request_id, "windows": 0,
@@ -2113,14 +2159,93 @@ class InferenceEngine:
                 req.dec["k1_windows"] += win.k == 1
                 req.dec["tokens"] += n_tok
                 req.dec["interleaved_windows"] += win.pick == "interleave"
-            if req.dec is not None and req.done.is_set():
+            if req.gap_last and req.done.is_set():
                 # retired inside this window's fan-out (or, cancelled,
                 # before it): its decode interval is complete
-                attrs, req.dec = req.dec, None
-                tracer.record_span(
-                    "engine.decode", req.trace[0], req.span_id,
-                    *req.dec_anchor, end_mono=req.t_done_mono or now_m,
-                    attrs=attrs)
+                self._obs_stream_gap(req)
+
+    def _admit_clock(self, t_mono: float) -> float:
+        """The admission clock at a stamp: the seconds the serve loop has
+        spent in admission episodes, the open one up to the stamp."""
+        if self._admit_open_mono:
+            return self._admit_clock_s + (t_mono - self._admit_open_mono)
+        return self._admit_clock_s
+
+    def _gap_marks(self, t_mono: float) -> tuple:
+        """A delivery stamp with the admission clock at it, and the
+        admissions begun by then. Differences of two stamps' marks are a
+        period, its admit part, and the admissions that fell inside it."""
+        return (t_mono, self._admit_clock(t_mono),
+                self._stats["gap_admissions"])
+
+    def _obs_gap(self, win: _Window, t_host0: float, delivered: dict) -> None:
+        """A window's period as its lanes see it (ISSUE 57). A running
+        stream gets tokens only here, so the time since a lane's delivery
+        before — the window before, for every lane that window also
+        delivered to: ONE period they share; its own first token, for a
+        lane that joined since — is the gap its tokens waited, and the
+        admission clock's movement inside it the part that admissions of
+        other requests took. Once a window, two attributes a lane; nothing
+        a token, no clock read (``t_host0`` is the fan-out's own)."""
+        marks, prev = self._gap_marks(t_host0), self._gap_prev
+        if prev:
+            win.period_s = marks[0] - prev[0]
+            win.admit_s = marks[1] - prev[1]
+        if not delivered:
+            return
+        shared = 0
+        joined_s = joined_admit_s = 0.0
+        for slot in delivered:
+            req = win.reqs[slot]
+            last = req.gap_last or req.gap_first or marks
+            if last is prev:
+                shared += 1
+            else:
+                joined_s += marks[0] - last[0]
+                joined_admit_s += marks[1] - last[1]
+            req.gap_max = max(req.gap_max, marks[0] - last[0])
+            req.gap_last = marks
+        st = self._stats
+        st["gap_tokens"] += sum(delivered.values())
+        st["gap_lane_period_s"] += shared * win.period_s + joined_s
+        st["gap_lane_admit_s"] += shared * win.admit_s + joined_admit_s
+        st["gap_lane_steps"] += len(delivered) * win.k
+        # no admission touched it: the clock has stood still since the
+        # delivery before AND since its own dispatch (a window interleaved
+        # inside an admission is fanned out after it, behind another). Its
+        # period over its steps is the step its lanes saw; what the lanes
+        # of the other windows saw beyond that a step is what admissions
+        # cost them, the decode windows interleaved inside an admission
+        # taken off (``tpot_admit_stall_ms``). Weighed by lanes, as a
+        # stream's own gap is.
+        if shared and marks[1] == min(prev[1], win.gap_clock0):
+            win.clean = True
+            st["gap_clean_lane_period_s"] += shared * win.period_s
+            st["gap_clean_lane_steps"] += shared * win.k
+        self._gap_prev = marks
+
+    def _obs_stream_gap(self, req: _Request) -> None:
+        """Once a request, when the window that retired it is fanned out:
+        the engine's own time per output token, (last delivery - first
+        token) / (tokens - 1), the summary ``tpu9_engine_tpot_s`` and the
+        ONE ``engine.decode`` span of a traced request; the largest period
+        it sat through, ``tpu9_engine_gap_max_s``."""
+        first, last, req.gap_last = req.gap_first, req.gap_last, ()
+        n = len(req.generated)
+        if n < 2:
+            return
+        attrs, req.dec = req.dec, None
+        if attrs is not None:
+            attrs.update(
+                gap_mean_ms=round((last[0] - first[0]) / (n - 1) * 1e3, 3),
+                gap_max_ms=round(req.gap_max * 1e3, 3),
+                admit_stall_ms=round((last[1] - first[1]) * 1e3, 3),
+                admissions_behind=last[2] - first[2])
+        tracer.record_interval(
+            "engine.decode", self.metrics, "tpu9_engine_tpot_s",
+            req.dec_anchor, first[0], last[0], trace=self._under(req),
+            attrs=attrs, per=n - 1)
+        self.metrics.observe("tpu9_engine_gap_max_s", req.gap_max)
 
     def _obs_first_token(self, req: _Request) -> None:
         """TTFT, and its last part: the hold between the end of a request's
@@ -2167,7 +2292,7 @@ class InferenceEngine:
         here — queue wait + admission + hold + stream lag, less what of
         the enqueue lay before the headers; ``ttft`` + ``stream_lag`` is
         its check."""
-        now = time.monotonic()
+        now = req.t_first_write_mono = time.monotonic()
         if req.t_first_mono:
             self.metrics.observe(
                 "tpu9_engine_stream_lag_s",
@@ -2178,6 +2303,22 @@ class InferenceEngine:
                 "tpu9_engine_runner_first_s", req.ingest_anchor,
                 req.t_ready_mono, now, trace=req.trace,
                 attrs={"request_id": req.request_id})
+
+    def note_last_write(self, req: _Request, t_last: float) -> None:
+        """Fed by the runner when a request's stream has ended without an
+        error, with the stamp at which its handler had written the last
+        token: the runner's own time per output token (ISSUE 57), (last
+        write - first write) / (tokens - 1) — the engine's, plus what the
+        handler and the event loop it shares with the serve loop put
+        between two writes. Told once a request."""
+        t_first, req.t_first_write_mono = req.t_first_write_mono, 0.0
+        n = len(req.generated)
+        if t_first and n >= 2 and req.ingest_anchor:
+            tracer.record_interval(
+                "runner.stream", self.metrics, "tpu9_engine_runner_gap_s",
+                req.ingest_anchor, t_first, t_last, trace=req.trace,
+                attrs={"request_id": req.request_id, "tokens": n},
+                per=n - 1)
 
     def _obs_done(self, req: _Request) -> None:
         """Idempotent: reachable from both _retire (slot completion) and
@@ -2337,6 +2478,11 @@ class InferenceEngine:
                    request_id=req.trace[0] if req.trace else req.request_id,
                    prompt_tokens=len(req.prompt)) as ph:
             self._obs_admit_start(req, t0_mono, t0_wall)
+            # the admission clock runs from the first admission of a pass
+            # of the serve loop to the end of its ``deliver_first``
+            if not self._admit_open_mono:
+                self._admit_open_mono = t0_mono
+            self._stats["gap_admissions"] += 1
             il0 = self._stats["admit_interleaved_windows"]
             if self.paged:
                 first = await self._admit_paged(req, slot)
@@ -2564,6 +2710,14 @@ class InferenceEngine:
                 with phase("engine.deliver_first", totals):
                     for (req, _), first in zip(pending, firsts):
                         self._deliver_first(req, int(first))
+                self._admit_clock_s += \
+                    time.monotonic() - self._admit_open_mono
+                self._admit_open_mono = 0.0
+                # a stream's decode interval opens at its first token, with
+                # the clock as the episode that admitted it leaves it: what
+                # the clock moves by from here on is other requests'
+                for req, _ in pending:
+                    req.gap_first = self._gap_marks(req.t_first_mono)
                 # windows dispatched during those admissions: their tokens
                 # are ready by now (device work ordered before firsts) —
                 # drain them in one transfer
@@ -2762,7 +2916,10 @@ class InferenceEngine:
             else:
                 self._process_decode_host(win, window, second)
             self._obs_window(win, t_host0)
-            ph.set(tokens=sum(win.delivered.values()))
+            ph.set(tokens=sum(win.delivered.values()),
+                   lanes=len(win.delivered), k=win.k, clean=int(win.clean),
+                   period_us=round(win.period_s * 1e6),
+                   admit_us=round(win.admit_s * 1e6))
 
     def _process_decode_host(self, win: _Window, window,
                              second=None) -> None:
