@@ -270,6 +270,12 @@ def test_prefix_reuse_hits_and_is_correct(tiny):
     st = warm.prefix_cache.stats()
     assert st["hits"] >= 1
     assert st["tokens_reused"] >= 96      # ≥ 3 full blocks of the prefix
+    # each admission hashed its prompt once (ISSUE 58): the lookup's walk
+    # over the four whole pages is the insert's too, and nothing hashes
+    # behind the engine's counter; a cache that is off hashes nothing
+    assert warm.stats()["prefix_tokens_hashed"] == st["tokens_hashed"] \
+        == 2 * 128 <= warm.stats()["admit_tokens"] + st["tokens_reused"]
+    assert cold.stats()["prefix_tokens_hashed"] == 0
 
 
 def test_prefix_reuse_is_faster(tiny):

@@ -17,7 +17,8 @@ from tpu9.abstractions.common.buffer import ForwardResult
 from tpu9.router import (AffinityRouter, FleetRouter, QueuedRequest,
                          ReplicaBudgets, TenantFairQueue, block_keys,
                          estimate_cost)
-from tpu9.serving.paged_kv import PrefixCache
+from tpu9.router.affinity import MAX_KEY_BLOCKS
+from tpu9.serving.paged_kv import BlockAllocator, PrefixCache, prefix_keys
 from tpu9.statestore import MemoryStore
 from tpu9.types import ContainerState, ContainerStatus, Stub, StubConfig
 
@@ -175,18 +176,41 @@ def test_estimate_cost_shapes():
 # affinity
 # ---------------------------------------------------------------------------
 
-def test_block_keys_match_engine_prefix_cache_keying():
+@pytest.mark.parametrize("bs, n", [
+    (16, 49),                       # 3 blocks and a token (the first case)
+    (16, 48), (16, 47), (128, 5 * 128), (128, 5 * 128 - 1),
+    (128, 5 * 128 + 1), (16, 70 * 16 + 3)],
+    ids=["16-over", "16-at", "16-under", "128-at", "128-under", "128-over",
+         "past-the-cap"])
+def test_block_keys_match_engine_prefix_cache_keying(bs, n):
     """The router's token keys must be EXACTLY PrefixCache._key at the
     same block boundaries — otherwise placement and engine-level reuse
-    silently diverge."""
-    tokens = list(range(1, 50))
+    silently diverge. They are the same function's (``prefix_keys``):
+    strict, longest first, the cap on the blocks changing no key."""
+    tokens = list(range(1, n + 1))
     keys = block_keys(json.dumps({"tokens": tokens}).encode(),
-                      block_tokens=16)
+                      block_tokens=bs)
     # strict prefix: (49-1)//16 = 3 blocks → keys for 48, 32, 16 tokens
-    assert len(keys) == 3
-    assert keys[0] == PrefixCache._key(tokens[:48])
-    assert keys[1] == PrefixCache._key(tokens[:32])
-    assert keys[2] == PrefixCache._key(tokens[:16])
+    nb = min((n - 1) // bs, MAX_KEY_BLOCKS)
+    assert len(keys) == nb
+    assert keys == [PrefixCache._key(tokens[:k * bs])
+                    for k in range(nb, 0, -1)]
+    assert keys == prefix_keys(tokens, bs, strict=True,
+                               max_blocks=MAX_KEY_BLOCKS)[::-1]
+
+
+def test_a_routed_prompt_finds_the_engines_entry():
+    """End to end over the two planes: what the engine's cache inserts for a
+    served prompt is what the router's walk of the next turn's body names,
+    at the engine's page size."""
+    alloc = BlockAllocator(8, 16)
+    pc = PrefixCache(alloc, 8)
+    served = list(range(100, 100 + 3 * 16))
+    pc.insert(served, alloc.alloc(3))
+    nxt = json.dumps({"tokens": served + [7, 8, 9]}).encode()
+    assert [pc.contains(k) for k in block_keys(nxt, 16)] \
+        == [True, False, False]
+    assert pc.lookup(served + [7, 8, 9]).key == block_keys(nxt, 16)[0]
 
 
 def test_block_keys_text_fallback():

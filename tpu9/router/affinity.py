@@ -1,9 +1,12 @@
 """KV-affinity replica selection: block-boundary prefix keys + JSQ fallback.
 
 The engine's :class:`tpu9.serving.paged_kv.PrefixCache` caches KV for
-FULL, block-aligned prompt prefixes, keyed by a hash of the token prefix
-(``PrefixCache._key``). A fleet router that wants its placement to turn
-into engine-level cache hits must therefore key on the SAME boundaries:
+FULL, block-aligned prompt prefixes, keyed by a hash of the token prefix:
+the sha1 of the prefix's tokens as 64-bit little-endian integers, made for
+every boundary of a prompt in one pass by
+:func:`tpu9.utils.prefixkey.prefix_keys`, the key's one owner. A fleet
+router that wants its placement to turn into engine-level cache hits must
+therefore key on the SAME boundaries with the SAME function:
 hashing the whole prompt (or a fixed byte prefix, like the per-instance
 ``LlmRouter``) makes "shares a 2-block system prompt" and "identical
 request" look different, and the replica that holds the prefix is never
@@ -25,6 +28,8 @@ import hashlib
 import json
 import time
 from typing import Callable, Optional
+
+from ..utils.prefixkey import prefix_keys
 
 # longest prefix worth keying, in blocks: bounds per-request hash work and
 # table growth on pathological prompts (64 blocks × 16 tok = 1k tokens of
@@ -51,7 +56,9 @@ def extract_prompt_tokens(body: bytes) -> Optional[list[int]]:
 def block_keys(body: bytes, block_tokens: int) -> list[bytes]:
     """Block-aligned prefix keys for a request body, longest first.
 
-    Token bodies use the engine's exact keying. Text payloads (prompt /
+    Token bodies take the engine's keys from the engine's function
+    (``prefix_keys``, strict, at most ``MAX_KEY_BLOCKS`` blocks: a key does
+    not depend on the cap). Text payloads (prompt /
     messages / raw bytes) approximate a block as ``4 × block_tokens``
     characters — byte-prefix blocks keep the longest-first walk semantics
     even when the gateway never sees token ids.
@@ -59,25 +66,14 @@ def block_keys(body: bytes, block_tokens: int) -> list[bytes]:
     bs = max(block_tokens, 1)
     tokens = extract_prompt_tokens(body)
     if tokens is not None:
-        # EXACTLY PrefixCache._key at each block boundary — the router's
-        # table key and the engine's cache key must agree or affinity
-        # placement and actual KV reuse silently diverge. One incremental
-        # pass: the joined bytes for prefix k are a prefix of those for
-        # k+1, so a running hash + copy() per boundary is O(n), not the
-        # O(n²) of hashing every prefix from scratch (this runs 2-3 times
-        # per routed request on the gateway's single thread).
-        # Strict prefix, like PrefixCache.lookup: at least one token must
-        # remain to prefill.
-        nb = min((len(tokens) - 1) // bs, MAX_KEY_BLOCKS)
-        h = hashlib.sha1()
-        keys = []
-        for n in range(1, nb + 1):
-            if n > 1:
-                h.update(b",")
-            h.update(b",".join(str(t).encode()
-                               for t in tokens[(n - 1) * bs: n * bs]))
-            keys.append(h.copy().digest())
-        return keys[::-1]
+        # the engine's own walk (``utils.prefixkey.prefix_keys``, the one
+        # owner of the key): the router's table key and the engine's cache
+        # key agree because they are made by the same code, in one pass
+        # over the tokens (this runs 2-3 times per routed request on the
+        # gateway's single thread). Strict, like PrefixCache.lookup: at
+        # least one token must remain to prefill.
+        return prefix_keys(tokens, bs, strict=True,
+                           max_blocks=MAX_KEY_BLOCKS)[::-1]
     raw = body
     try:
         payload = json.loads(body)

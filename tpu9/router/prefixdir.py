@@ -22,10 +22,14 @@ beat gap); consumers must treat every hit as a HINT — the engine
 degrades a lost prefix to recompute, never an error, and the regression
 test pins that.
 
-Key digests are the first 16 hex chars of the engine's
-``PrefixCache._key`` sha1 — long enough that collisions are noise-level
-for fleet-sized key sets, short enough that a 48-entry summary rides a
-heartbeat in ~1.3 KB.
+Key digests are the first 16 hex chars of the engine's prefix key (the
+sha1 of the prefix's tokens as 64-bit little-endian integers; its one owner
+is :func:`tpu9.utils.prefixkey.prefix_keys`, which the engine's
+``PrefixCache`` and this router's ``block_keys`` both call, so a replica's
+advertisement and a request's walk name a prefix alike) — long enough that
+collisions are noise-level for fleet-sized key sets, short enough that a
+48-entry summary rides a heartbeat in ~1.3 KB. The directory holds them in
+memory only, under a TTL.
 """
 
 from __future__ import annotations

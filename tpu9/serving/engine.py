@@ -374,6 +374,9 @@ class _Request:
     t_done_mono: float = 0.0                # terminal answer given
     admit_cached: int = 0                   # prefix-cache tokens reused
     admit_chunks: int = 0                   # prefill chunks dispatched
+    # the prompt's block-aligned prefix keys (``PrefixCache.walk``), made
+    # once at the admission's lookup and taken again by its insert
+    prefix_keys: Optional[list] = None
     # the ONE engine.decode span of a traced request (ISSUE 24), summed
     # over its windows and recorded when it retires: the wall/monotonic
     # anchors of its first window's dispatch, and the span's attributes
@@ -580,6 +583,11 @@ class InferenceEngine:
                        # real tokens, and the tokens their programs ran
                        # over (whole chunks)
                        "admit_tokens": 0, "admit_tokens_padded": 0,
+                       # the tokens the prefix cache's key passed through
+                       # its hash (ISSUE 58): one walk a prompt, so at most
+                       # the prompts' own tokens. The cache counts them;
+                       # ``stats()`` reads its count
+                       "prefix_tokens_hashed": 0,
                        # a token's gap, told from inside (ISSUE 57): the
                        # tokens the windows delivered; summed over the
                        # lanes they delivered to, the seconds since each
@@ -1288,7 +1296,6 @@ class InferenceEngine:
                 or self.cfg.lane_state:
             # (a layer pattern: the format ships rows and no state a lane)
             return None
-        from .paged_kv import PrefixCache
         for slot in range(self.ecfg.max_batch):
             req = self.slot_req[slot]
             if req is None or not self.active[slot] \
@@ -1302,7 +1309,7 @@ class InferenceEngine:
             t0 = time.perf_counter()
             payload = self.pool.export_blocks(
                 self.kv_cache, self._slot_blocks[slot][:nb],
-                PrefixCache._key(seq[:nb * bs]), nb * bs)
+                self.prefix_cache.walk(seq[:nb * bs])[-1], nb * bs)
             self.metrics.observe("tpu9_kvwire_export_s",
                                  time.perf_counter() - t0)
             self._stats["kvwire_exports"] += 1
@@ -1627,6 +1634,7 @@ class InferenceEngine:
             out["kv_quant"] = self.ecfg.kv_quant if self.kv_quant else ""
             out["queued"] += len(self._wait_room)
             out["prefix_cache"] = self.prefix_cache.stats()
+            out["prefix_tokens_hashed"] = self.prefix_cache.tokens_hashed
             # admission pressure for the router: reserved fraction is the
             # honest "can I take another request" signal under paging
             out["token_pressure"] = max(
@@ -1764,7 +1772,9 @@ class InferenceEngine:
                 self._stats["admit_dispatches"] += 1
             if self.ecfg.prefix_cache_blocks > 0:
                 self.prefix_cache.insert(req.prompt,
-                                         self._slot_blocks[slot])
+                                         self._slot_blocks[slot],
+                                         req.prefix_keys)
+                req.prefix_keys = None
             self._push_table(slot)        # real row becomes visible NOW
             self.cache_len = self.cache_len.at[slot].set(n)
             self._host_len[slot] = n
@@ -1789,9 +1799,13 @@ class InferenceEngine:
 
     async def _admit_lookup(self, req: _Request) -> tuple:
         """Prefix-cache lookup of one admission: ``(shared blocks, retained
-        for the slot; cached tokens the suffix resumes behind)``."""
-        entry = self.prefix_cache.lookup(req.prompt) \
-            if self.ecfg.prefix_cache_blocks > 0 else None
+        for the slot; cached tokens the suffix resumes behind)``. The
+        prompt is hashed here, once: the walk stays on the request for
+        ``engine.admit.finish``'s insert."""
+        entry = None
+        if self.ecfg.prefix_cache_blocks > 0:
+            req.prefix_keys = self.prefix_cache.walk(req.prompt)
+            entry = self.prefix_cache.lookup(req.prompt, req.prefix_keys)
         if entry is not None and entry.tier == "host":
             # host-tier hit (ISSUE 20): re-place the planes through the
             # sharding policy before the blocks can be shared. Degrades

@@ -22,9 +22,13 @@ Header fields:
   ``head_dim``, ``kv_dtype`` ("bfloat16" | "int8" | ...) — must match
   the importing pool exactly (block ids are meaningless across
   geometries);
-- ``n_blocks`` / ``n_tokens`` / ``prefix_key`` (hex sha1 of the
-  block-aligned token prefix, :meth:`PrefixCache._key`) — what the
-  importer adopts into its prefix cache;
+- ``n_blocks`` / ``n_tokens`` / ``prefix_key`` (hex of the block-aligned
+  token prefix's key — the sha1 of its tokens as 64-bit little-endian
+  integers, made by :func:`tpu9.utils.prefixkey.prefix_keys`, the key's one
+  owner, which ``PrefixCache`` and the router share) — what the importer
+  adopts into its prefix cache. The key is compared with keys the same code
+  made in the same deployment and never persisted: a payload from another
+  version of the key adopts under a name no lookup asks for, and ages out;
 - ``topology`` (``policy.describe()``) — informational: planes are
   always CANONICAL full-head arrays (``[L, nb, BS, KH, D]`` payload,
   ``[L, nb, BS, KH]`` f32 scales), because export gathers head shards

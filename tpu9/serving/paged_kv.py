@@ -26,6 +26,11 @@ import time
 from dataclasses import dataclass
 from typing import Optional
 
+# the prefix key's one owner: a leaf under tpu9.utils, because the router
+# takes its keys from the same function and may not import the serving stack
+# (analysis/boundaries.toml)
+from ..utils.prefixkey import prefix_keys
+
 
 def blocks_for(n_tokens: int, block_s: int) -> int:
     """Physical blocks needed so positions [0, n_tokens) are addressable."""
@@ -136,7 +141,8 @@ class PrefixCache:
 
     Entries hold refcounts on their blocks; eviction (LRU, or on-demand
     when the allocator runs dry) releases them. Keys are hashes of
-    block-aligned token prefixes, so a lookup walks from the longest
+    block-aligned token prefixes (:func:`prefix_keys`: every boundary of a
+    prompt from one pass over it), so a lookup probes from the longest
     possible prefix down and the first hit is the best reuse.
 
     The budget ``max_blocks`` is held against the DISTINCT pool blocks that
@@ -169,6 +175,7 @@ class PrefixCache:
         self.spills = 0         # device→host down-pages (prefix survives)
         self.hits_device = 0    # lookup hits split by serving tier
         self.hits_host = 0
+        self.tokens_hashed = 0  # tokens passed through the key's hash
         # tier-change journal for the directory (ISSUE 20 satellite):
         # every eviction/spill appends (seq, kind, key-hex16) so the next
         # heartbeat ships a delta — without it, an entry evicted between
@@ -195,9 +202,21 @@ class PrefixCache:
 
     @staticmethod
     def _key(tokens: list[int]) -> bytes:
-        h = hashlib.sha1()
-        h.update(b",".join(str(t).encode() for t in tokens))
-        return h.digest()
+        """The key of ``tokens`` whole: the last of :func:`prefix_keys`'s
+        walk, whatever the block size."""
+        n = len(tokens)
+        return prefix_keys(tokens, n, strict=False)[0] if n \
+            else hashlib.sha1().digest()
+
+    def walk(self, tokens: list[int]) -> list[bytes]:
+        """Every block-aligned prefix key of ``tokens`` (:func:`prefix_keys`,
+        not strict: a prompt that ends on a boundary has its own key last,
+        which ``insert`` wants and ``lookup`` skips), counted in
+        ``tokens_hashed``. An admission makes it once and hands it to
+        ``lookup`` and ``insert`` both."""
+        keys = prefix_keys(tokens, self.allocator.block_s, strict=False)
+        self.tokens_hashed += len(keys) * self.allocator.block_s
+        return keys
 
     @property
     def held_blocks(self) -> int:
@@ -230,20 +249,23 @@ class PrefixCache:
     def contains(self, key: bytes) -> bool:
         return key in self._entries
 
-    def lookup(self, prompt: list[int]) -> Optional[PrefixEntry]:
+    def lookup(self, prompt: list[int],
+               keys: Optional[list[bytes]] = None) -> Optional[PrefixEntry]:
         """Longest cached block-aligned strict prefix of ``prompt``.
         Strict: at least one prompt token must remain to prefill, because
         admission samples the first output from the suffix's logits.
+        ``keys`` is ``walk(prompt)`` where the caller already made it.
 
         The returned entry is PINNED: a concurrent admission's
         ``evict_for_space`` (interleaved at any await point) must not
         release the blocks before the caller retains them. Call
         :meth:`release_pin` once the blocks are retained (or the entry is
         abandoned)."""
-        bs = self.allocator.block_s
-        nb = (len(prompt) - 1) // bs
+        if keys is None:
+            keys = self.walk(prompt)
+        nb = (len(prompt) - 1) // self.allocator.block_s
         while nb > 0:
-            entry = self._entries.get(self._key(prompt[:nb * bs]))
+            entry = self._entries.get(keys[nb - 1])
             if entry is not None:
                 entry.last_used = self.clock()
                 entry.pins += 1
@@ -267,8 +289,9 @@ class PrefixCache:
 
     # -- kvwire export/adopt (ISSUE 16) --------------------------------------
 
-    def acquire_for_export(self,
-                           tokens: list[int]) -> Optional[PrefixEntry]:
+    def acquire_for_export(self, tokens: list[int],
+                           keys: Optional[list[bytes]] = None
+                           ) -> Optional[PrefixEntry]:
         """Longest cached block-aligned prefix of ``tokens`` for a kvwire
         export, PINNED for the duration of the payload gather — the same
         race class as the lookup/evict pin fix (PR 2): an eviction
@@ -278,10 +301,11 @@ class PrefixCache:
         hit/miss/tokens_reused signals the router keys affinity on.
         Balance with :meth:`release_pin`. Non-strict: a whole-prompt
         entry is exactly what a handoff wants to ship."""
-        bs = self.allocator.block_s
-        nb = len(tokens) // bs
+        if keys is None:
+            keys = self.walk(tokens)
+        nb = len(keys)
         while nb > 0:
-            entry = self._entries.get(self._key(tokens[:nb * bs]))
+            entry = self._entries.get(keys[nb - 1])
             # host-tier entries hold no pool blocks to gather — keep
             # walking down to the longest DEVICE-resident prefix
             if entry is not None and entry.tier == "device":
@@ -310,17 +334,19 @@ class PrefixCache:
         self._evict_to_budget()
         return True
 
-    def insert(self, prompt: list[int], slot_blocks: list[int]) -> None:
+    def insert(self, prompt: list[int], slot_blocks: list[int],
+               keys: Optional[list[bytes]] = None) -> None:
         """Register the prompt's full-block prefix, sharing the slot's
         physical blocks (retained; safe because decode never writes into
-        full prefix blocks)."""
+        full prefix blocks). ``keys`` is ``walk(prompt)`` where the caller
+        already made it (the admission's lookup did)."""
         bs = self.allocator.block_s
         nb = len(prompt) // bs
         # an entry alone bigger than the whole budget could only evict
         # everything and then itself — refuse it instead
         if nb == 0 or self.max_blocks <= 0 or nb > self.max_blocks:
             return
-        key = self._key(prompt[:nb * bs])
+        key = (self.walk(prompt) if keys is None else keys)[nb - 1]
         ent = self._entries.get(key)
         if ent is not None:
             ent.last_used = self.clock()
@@ -430,4 +456,5 @@ class PrefixCache:
                 "pinned": self.pinned,
                 "adopted": self.adopted, "spills": self.spills,
                 "hits_device": self.hits_device,
-                "hits_host": self.hits_host}
+                "hits_host": self.hits_host,
+                "tokens_hashed": self.tokens_hashed}
