@@ -689,9 +689,10 @@ def test_latent_attention_in_every_layer_holds_its_kernels(
 
 @pytest.mark.parametrize("b,h,p,n,g,planes", [
     (64, 64, 64, 128, 1, 36), (8, 8, 128, 64, 1, 3), (8, 64, 64, 128, 2, 3),
-    (8, 16, 128, 256, 4, 2), (8, 8, 256, 128, 1, 2)],
+    (8, 16, 128, 256, 4, 2), (8, 8, 256, 128, 1, 2),
+    (64, 128, 64, 128, 8, 5)],
     ids=["granite-4.0-h-micro", "state-64", "two-groups", "state-256",
-         "two-tiles-wide"])
+         "two-tiles-wide", "nemotron-3-super"])
 def test_the_state_space_step_compiles_at_the_published_widths(
         v5e, no_compile_cache, b, h, p, n, g, planes):
     """The Mamba-2 step of a listed pattern's decode at Granite 4.0-H
@@ -701,7 +702,9 @@ def test_the_state_space_step_compiles_at_the_published_widths(
     it is made — the live lanes a prefetched list that ONE invocation walks
     behind its own copies (ISSUE 56); and at every other kind of shape
     ``step_kernel_declined`` lets through: a state narrower and wider than
-    a register's 128 lanes, several groups, a row two registers wide."""
+    a register's 128 lanes, several groups, a row two registers wide; and at
+    Nemotron 3 Super's (ISSUE 59): 128 heads of 64 in 8 groups, a lane's
+    block 4.19 MB, eight of them 33.5 MB of VMEM a group of lanes."""
     from tpu9.ops import ssd
     one_chip = jax.sharding.SingleDeviceSharding(v5e[0])
 
@@ -712,6 +715,8 @@ def test_the_state_space_step_compiles_at_the_published_widths(
     stored = ssd.state_shape(h, p, n, g)
     if planes == 36:
         assert stored == (32, 128, 128)     # two heads side by side a row
+    if h == 128:
+        assert stored == (64, 128, 128)     # 16 heads a group: 8 rows of 2
     step = jax.jit(lambda st, x, dt, a, bm, cm, live: ssd.step_pallas(
         st, 1, x, dt, a, bm, cm, live), donate_argnums=(0,))
     compiled = step.lower(s((planes, b) + stored), s((b, h, p)), s((b, h)),
@@ -774,6 +779,58 @@ def test_a_listed_pattern_holds_its_kernels_at_the_published_widths(
             [family.STEP_MARKER] + [ssd.STEP_KERNEL] * 2)
     for key in (("chunk", 512), ("chunkgroup", 2)):
         assert seen[key] == [CHUNK_KERNEL]
+    assert seen["lanesplice"] == []
+
+
+def test_a_list_of_half_layers_holds_its_kernels_at_the_published_widths(
+        v5e, no_compile_cache, monkeypatch):
+    """``nemotron-3-super-l11-ep4`` at its engine's shapes, one layer of each
+    kind deep (``M*E``, ISSUE 59): a decode step is one ``ssm_state_step``
+    over 128 heads in 8 groups, in place, one ``paged_decode_attention`` —
+    the call the benchmark counts its steps by — over a pool ONE plane deep
+    of two 128-wide heads, and one UNGATED ``held_ffn`` whose step holds an
+    expert's two matrices of 1,024 x 2,688 whole, twice (22 MB of VMEM); a
+    chunk and a group attend in the chunk kernel and sort their rows into
+    ``grouped_ffn`` at the whole hidden width; beside its tokens a window
+    returns the picks of its one expert layer; no program copies the state
+    or an expert stack."""
+    from dataclasses import replace
+
+    from tpu9.models import kvstate
+    from tpu9.ops import ssd
+    cfg, family, _, pool, jobs = _decode_programs(
+        v5e, monkeypatch, "nemotron-3-super-l11-ep4",
+        kinds=("decode", "chunk", "chunkgroup", "l"),
+        cut=lambda c: replace(c, n_layers=3,
+                              layer_pattern=("ssm", "full", "none"),
+                              ffn_pattern=("none", "none", "experts")))
+    assert pool.shape == (1, 2049, 128, 2, 128)
+    state = kvstate.lane_shapes(cfg, 64)["ssm_state"][0]
+    assert state == (1, 64, 64, 128, 128)
+    shaped = "f32[" + ",".join(str(n) for n in state) + "]"
+    seen = {}
+    for key, fn, args in jobs:
+        compiled = fn.lower(*args).compile()
+        text = compiled.as_text()
+        seen[key] = sorted(_kernel_names(text))
+        # an expert stack is read where it lies
+        assert not re.search(
+            r"bf16\[128,(1024,2688|2688,1024)\]\S* copy\(", text), key
+        if key[0] == "decode":
+            assert not re.search(r"= " + re.escape(shaped) + r"[^=]* copy\(",
+                                 text), key
+            assert not _pool_copies(text, 2049), key
+            mem = compiled.memory_analysis()
+            assert mem.alias_size_in_bytes >= math.prod(state) * 4, key
+            assert mem.temp_size_in_bytes < 256 * 2 ** 20, key
+            picks = jax.tree_util.tree_leaves(fn.eval_shape(*args))[-1]
+            assert picks.shape == (key[1], 64, 1, 22)
+    assert family.EXPERT_STEP_KERNEL == "held_ffn"
+    for k in (1, 8):
+        assert seen[("decode", k)] == sorted(
+            [family.STEP_MARKER, ssd.STEP_KERNEL, family.EXPERT_STEP_KERNEL])
+    for key in (("chunk", 512), ("chunkgroup", 4)):
+        assert seen[key] == sorted([CHUNK_KERNEL, "grouped_ffn"])
     assert seen["lanesplice"] == []
 
 

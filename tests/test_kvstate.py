@@ -60,9 +60,24 @@ CASES = {
         layer_pattern=("ssm", "ssm", "full", "ssm", "ssm", "full", "ssm"),
         ssm_heads=4, ssm_head_dim=16, ssm_state=32, ssm_conv=4, rope=False,
         dtype=jnp.float32), False),
+    # a list of HALF-layers (ISSUE 59): five mixers' state a lane (heads in
+    # two groups), ONE plane of per-head rows for eleven layers, and expert
+    # layers that keep nothing
+    "halves": (DecoderConfig(
+        vocab_size=256, dim=64, n_layers=11, n_heads=4, n_kv_heads=2,
+        head_dim=16, hidden_dim=48, max_seq_len=512, act="relu2", rope=False,
+        layer_pattern=("ssm", "none") * 3 + ("ssm", "full", "none", "ssm",
+                                             "none"),
+        ffn_pattern=("none", "experts") * 3 + ("none", "none", "experts",
+                                               "none", "experts"),
+        ssm_heads=8, ssm_head_dim=16, ssm_state=32, ssm_groups=2, ssm_conv=4,
+        ssm_norm_groups=2, n_experts=4, moe_top_k=4, moe_hidden_dim=48,
+        moe_routed=16, moe_shared_dim=96, moe_score="sigmoid",
+        moe_select_bias=True, moe_gate_scale=5.0, moe_gated=False,
+        moe_latent_dim=32, dtype=jnp.float32), False),
 }
 # the kind of layer whose state a case keeps by lane
-LANE_KIND = {"latent": "kda", "listed": "ssm"}
+LANE_KIND = {"latent": "kda", "listed": "ssm", "halves": "ssm"}
 PER_HEAD = [name for name in CASES if name != "latent"]
 case = pytest.mark.parametrize("name", list(CASES))
 

@@ -119,29 +119,36 @@ def refuse_unbuilt_pattern(cfg) -> None:
                "soft-capped logits, offset norms, gelu)",
                "no served model has both; not run")
     if cfg.n_experts:
-        if not cfg.moe_routed:
-            refuse("moe_routed=0", "a pattern's expert layer is told which "
-                   "experts it holds (all of them: moe_routed = n_experts): "
-                   "the capacity form is not run under a pattern")
-        routed = cfg.moe_routed
-        if cfg.moe_held_first < 0 \
-                or cfg.moe_held_first + cfg.n_experts > routed:
-            refuse(f"experts {cfg.moe_held_first}..+{cfg.n_experts} held of "
-                   f"{routed}", "the held experts lie inside the routed ones")
-        if cfg.moe_score not in ("softmax", "sigmoid"):
-            refuse(f"moe_score={cfg.moe_score!r}", "softmax or sigmoid")
-        if cfg.moe_groups and (routed % cfg.moe_groups
-                               or not 0 < cfg.moe_top_groups <= cfg.moe_groups
-                               or routed // cfg.moe_groups < 2):
-            refuse(f"moe_groups={cfg.moe_groups}, moe_top_groups="
-                   f"{cfg.moe_top_groups}",
-                   "groups divide the routed experts, hold at least 2 each "
-                   "(a group's score is the sum of its top 2), and some are "
-                   "kept")
-        if cfg.moe_score == "softmax" and (cfg.moe_select_bias
-                                           or cfg.moe_groups):
-            refuse("a selection bias or groups under softmax scores",
-                   "built for sigmoid scores only")
+        refuse_unbuilt_share(cfg, refuse)
+
+
+def refuse_unbuilt_share(cfg, refuse) -> None:
+    """The expert layer of a pattern (by rule, or ``models.ssm``'s list):
+    what is not built of its share and its gates, through the caller's
+    ``refuse(what, why)``."""
+    if not cfg.moe_routed:
+        refuse("moe_routed=0", "a pattern's expert layer is told which "
+               "experts it holds (all of them: moe_routed = n_experts): "
+               "the capacity form is not run under a pattern")
+    routed = cfg.moe_routed
+    if cfg.moe_held_first < 0 \
+            or cfg.moe_held_first + cfg.n_experts > routed:
+        refuse(f"experts {cfg.moe_held_first}..+{cfg.n_experts} held of "
+               f"{routed}", "the held experts lie inside the routed ones")
+    if cfg.moe_score not in ("softmax", "sigmoid"):
+        refuse(f"moe_score={cfg.moe_score!r}", "softmax or sigmoid")
+    if cfg.moe_groups and (routed % cfg.moe_groups
+                           or not 0 < cfg.moe_top_groups <= cfg.moe_groups
+                           or routed // cfg.moe_groups < 2):
+        refuse(f"moe_groups={cfg.moe_groups}, moe_top_groups="
+               f"{cfg.moe_top_groups}",
+               "groups divide the routed experts, hold at least 2 each "
+               "(a group's score is the sum of its top 2), and some are "
+               "kept")
+    if cfg.moe_score == "softmax" and (cfg.moe_select_bias
+                                       or cfg.moe_groups):
+        refuse("a selection bias or groups under softmax scores",
+               "built for sigmoid scores only")
 
 
 def _dense(rng, in_dim: int, out_dim: int, dtype, fan_out: int = 0):

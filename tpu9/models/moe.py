@@ -59,10 +59,33 @@ class MoeConfig:
     top_groups: int = 0
     renormalise: bool = True
     gate_scale: float = 1.0
+    # the experts' form: the gated three matrices ``(act(x W_gate) * x W_up)
+    # W_down`` (the default), or two, ``act(x W_up) W_down`` — no ``w_gate``
+    # in the tree, the shared expert of the same form. ``act`` may then be
+    # "relu2", ``relu(.) ** 2``
+    gated: bool = True
+    # routed experts that work in a LATENT of this many numbers (0 = at
+    # ``dim``): their stacks are ``[E, latent_dim, hidden]`` / ``[E, hidden,
+    # latent_dim]``, and two matrices shared by all experts, ``w_latent_in``
+    # ``[dim, latent_dim]`` in front of the dispatch and ``w_latent_out``
+    # behind the combine, lead into the latent and out of it. The router
+    # and the shared expert read the full ``dim``
+    latent_dim: int = 0
 
     @property
     def routed(self) -> int:
         return self.n_routed or self.n_experts
+
+    @property
+    def expert_dim(self) -> int:
+        """The width the routed experts read and write."""
+        return self.latent_dim or self.dim
+
+    @property
+    def stacks(self) -> tuple:
+        """The names of an expert's matrices in the tree."""
+        return ("w_gate", "w_up", "w_down") if self.gated \
+            else ("w_up", "w_down")
 
     @property
     def share(self) -> bool:
@@ -76,6 +99,7 @@ def init_moe_layer(rng: jax.Array, cfg: MoeConfig) -> Params:
     r1, r2, r3, r4 = jax.random.split(rng, 4)
     dt = cfg.dtype
     e, d, h = cfg.n_experts, cfg.dim, cfg.hidden_dim
+    de = cfg.expert_dim
 
     def dense(r, shape, fan):
         scale = (2.0 / sum(fan)) ** 0.5
@@ -84,10 +108,15 @@ def init_moe_layer(rng: jax.Array, cfg: MoeConfig) -> Params:
     params = {
         "router": dense(r1, (d, cfg.routed), (d, cfg.routed)).astype(
             jnp.float32),
-        "w_gate": dense(r2, (e, d, h), (d, h)),
-        "w_up": dense(r3, (e, d, h), (d, h)),
-        "w_down": dense(r4, (e, h, d), (h, d)),
+        "w_up": dense(r3, (e, de, h), (de, h)),
+        "w_down": dense(r4, (e, h, de), (h, de)),
     }
+    if cfg.gated:
+        params["w_gate"] = dense(r2, (e, de, h), (de, h))
+    if cfg.latent_dim:
+        l1, l2 = jax.random.split(jax.random.fold_in(rng, 7))
+        params["w_latent_in"] = dense(l1, (d, de), (d, de))
+        params["w_latent_out"] = dense(l2, (de, d), (de, d))
     if cfg.select_bias:
         # seeded weights only: large enough to flip some choices
         params["bias"] = jax.random.normal(
@@ -95,9 +124,10 @@ def init_moe_layer(rng: jax.Array, cfg: MoeConfig) -> Params:
     if cfg.shared_dim:
         s1, s2, s3 = jax.random.split(jax.random.fold_in(rng, 6), 3)
         sh = cfg.shared_dim
-        params["shared"] = {"w_gate": dense(s1, (d, sh), (d, sh)),
-                            "w_up": dense(s2, (d, sh), (d, sh)),
+        params["shared"] = {"w_up": dense(s2, (d, sh), (d, sh)),
                             "w_down": dense(s3, (sh, d), (sh, d))}
+        if cfg.gated:
+            params["shared"]["w_gate"] = dense(s1, (d, sh), (d, sh))
     return params
 
 
@@ -116,15 +146,14 @@ def moe_param_specs(params: Params, axis: str = "ep") -> Params:
             return {"q": spec, "scale": spec}
         return spec
 
-    specs = {
-        "router": P(),
-        "w_gate": stack(params["w_gate"]),
-        "w_up": stack(params["w_up"]),
-        "w_down": stack(params["w_down"]),
-    }
-    # a chip's share: the selection bias and the shared expert replicate
-    if "bias" in params:
-        specs["bias"] = P()
+    specs = {"router": P(),
+             **{name: stack(params[name])
+                for name in ("w_gate", "w_up", "w_down") if name in params}}
+    # a chip's share: the selection bias, the projections into the experts'
+    # latent and out of it, and the shared expert replicate
+    for name in ("bias", "w_latent_in", "w_latent_out"):
+        if name in params:
+            specs[name] = P()
     if "shared" in params:
         specs["shared"] = {name: P() for name in params["shared"]}
     return specs
@@ -312,11 +341,15 @@ def moe_ffn_sorted(params: Params, x: jnp.ndarray, cfg: MoeConfig,
             dest = first_row[expert] + rank         # the row that holds it
             token = jnp.zeros(n_rows, jnp.int32).at[dest].set(
                 jnp.arange(n * k, dtype=jnp.int32) // k, unique_indices=True)
-        xs = xf.astype(cfg.dtype)[token]                         # [R, d]
+        rows = xf.astype(cfg.dtype)
+    if cfg.latent_dim:
+        rows = _into_latent(params, rows)
+    with jax.named_scope("moe.route"):
+        xs = rows[token]                                         # [R, d]
 
     with jax.named_scope("moe.experts"):
         ys = (grouped_ffn or ops.grouped_ffn)(
-            xs, tiles, params["w_gate"], params["w_up"], params["w_down"],
+            xs, tiles, *(params[w] for w in cfg.stacks),
             act=cfg.act)                                         # [R, d] f32
 
     with jax.named_scope("moe.combine"):
@@ -327,6 +360,8 @@ def moe_ffn_sorted(params: Params, x: jnp.ndarray, cfg: MoeConfig,
             # not) times a gate of 0 is not 0 — selected away, not weighted
             picked = jnp.where(held.reshape(n, k, 1), picked, 0.0)
         out = (picked * gate_vals[..., None]).sum(1)
+    if cfg.latent_dim:
+        out = _out_of_latent(params, out, cfg)
     if cfg.shared_dim:
         out = out + shared_ffn(params["shared"], xf, cfg)
     if cfg.share:
@@ -336,16 +371,34 @@ def moe_ffn_sorted(params: Params, x: jnp.ndarray, cfg: MoeConfig,
     return out.reshape(b, t, d).astype(x.dtype)
 
 
+def _into_latent(params: Params, rows: jnp.ndarray) -> jnp.ndarray:
+    """The rows ``[N, dim]`` as the routed experts read them, ``[N,
+    latent_dim]``: one matrix for all experts, in front of the dispatch."""
+    with jax.named_scope("moe.latent.in"):
+        return rows @ params["w_latent_in"]
+
+
+def _out_of_latent(params: Params, out: jnp.ndarray, cfg: MoeConfig):
+    """The experts' weighted sum ``[N, latent_dim]`` (float32) back at the
+    model's width, float32: one matrix for all experts, behind the combine
+    (the sum is rounded to the model's type once, as a matmul's operand)."""
+    with jax.named_scope("moe.latent.out"):
+        return jnp.matmul(out.astype(cfg.dtype), params["w_latent_out"],
+                          preferred_element_type=jnp.float32)
+
+
 def shared_ffn(shared: Params, xf: jnp.ndarray, cfg: MoeConfig):
-    """The shared expert: every token, float32 out."""
+    """The shared expert: every token, float32 out; gated or not as the
+    routed experts are."""
     with jax.named_scope("moe.shared"):
+        from ..ops.grouped_ffn import _act
         from ..ops.quant import maybe_matmul
         h = xf.astype(cfg.dtype)
-        gate = maybe_matmul(h, shared["w_gate"])
-        gate = jax.nn.silu(gate) if cfg.act == "silu" \
-            else jax.nn.gelu(gate, approximate=True)
-        return maybe_matmul(gate * maybe_matmul(h, shared["w_up"]),
-                            shared["w_down"]).astype(jnp.float32)
+        hidden = _act(maybe_matmul(
+            h, shared["w_gate" if cfg.gated else "w_up"]), cfg.act)
+        if cfg.gated:
+            hidden = hidden * maybe_matmul(h, shared["w_up"])
+        return maybe_matmul(hidden, shared["w_down"]).astype(jnp.float32)
 
 
 def moe_ffn_held(params: Params, x: jnp.ndarray, cfg: MoeConfig, live=None):
@@ -375,14 +428,39 @@ def moe_ffn_held(params: Params, x: jnp.ndarray, cfg: MoeConfig, live=None):
         weight = (jax.nn.one_hot(local, e, dtype=jnp.float32)
                   * (gate_vals * live[:, None])[..., None]).sum(1)
         ids, count = ops.touched_experts(local, live, e)
+    rows = xf.astype(cfg.dtype)
+    if cfg.latent_dim:
+        rows = _into_latent(params, rows)
     with jax.named_scope("moe.experts"):
         out = ops.held_ffn(
-            xf.astype(cfg.dtype), weight, ids, count, params["w_gate"],
-            params["w_up"], params["w_down"], act=cfg.act)
+            rows, weight, ids, count, *(params[w] for w in cfg.stacks),
+            act=cfg.act)
+    if cfg.latent_dim:
+        out = _out_of_latent(params, out, cfg)
     if cfg.shared_dim:
         out = out + shared_ffn(params["shared"], xf, cfg)
     return out.reshape(b, t, d).astype(x.dtype), \
         gate_idx.reshape(b, t, cfg.top_k)
+
+
+def share_forms(cfg: MoeConfig, decode_rows: int, prefill_rows) -> dict:
+    """Which form an expert layer that is told what it holds takes, in
+    words (``/health``'s ``ffn_decode`` / ``ffn_prefill``): by its rows a
+    call, as ``transformer._mlp_block`` decides, and by the backend, as the
+    dispatchers of ``ops.held_ffn`` and ``ops.grouped_ffn`` do."""
+    from ..utils import on_tpu
+    ran = "pallas" if on_tpu() else "xla: no TPU backend"
+    what = ("gated" if cfg.gated else "ungated") + f" {cfg.act}" \
+        + (f", in a latent of {cfg.latent_dim}" if cfg.latent_dim else "")
+
+    def form(rows: int) -> str:
+        if rows > SORTED_MIN_TOKENS:
+            return f"sorted by expert, grouped_ffn ({what}): {ran}"
+        return f"the touched of the held experts, held_ffn ({what}): {ran}"
+
+    return {"decode": form(decode_rows),
+            "prefill": "; ".join(f"{rows} rows: {form(rows)}"
+                                 for rows in sorted(set(prefill_rows)))}
 
 
 def _one_devices_bf16_stacks(params: Params, mesh=None) -> bool:

@@ -7,6 +7,15 @@ and calls in here for the mixers; the plain-attention layers of a list are
 ``transformer._attn_block``'s own, at the plane their place among
 themselves gives.
 
+A list may state the OTHER half of every layer too (``ffn_pattern``), and
+then a layer is one sub-layer alone (``nemotron_h``: a mixer, attention or
+an expert layer, each ``x + f(norm(x))`` with one norm): ``"none"`` is the
+half a layer lacks, and it keeps neither that half's norm nor its weights.
+The expert layer of such a list is ``models.moe``'s, told which experts it
+holds (``moe_routed``), its experts ungated (``moe_gated`` False, ``act``
+``"relu2"``) in a latent (``moe_latent_dim``); the mixer's gated norm may
+run over groups of channels (``ssm_norm_groups``).
+
 What a running sequence keeps differs by kind:
 
 - an ``"ssm"`` layer keeps STATE A LANE — a float32 matrix ``[H, P, N]``
@@ -22,7 +31,9 @@ The mixer, with ``u`` the normed input:
     xBC = silu(conv(xBC) + b_conv)                depthwise, causal, K taps
     [x | B | C] = xBC;  dt = softplus(dt + dt_bias);  A_h = -exp(A_log_h)
     S_t = exp(dt_t A_h) S_{t-1} + dt_t x_t (x) B_t;  y_t = S_t C_t + D_h x_t
-    out = RMSNorm_w(y * silu(z)) W_out            the gate BEFORE the norm
+    out = RMSNorm_w(y * silu(z)) W_out            the gate BEFORE the norm,
+                                                  over each of ``ssm_norm_groups``
+                                                  groups of channels
 
 ``ops.ssd`` has the recurrence in its four forms; the equations, with every
 assumption, are in the plain reference the benchmark holds this to
@@ -52,6 +63,9 @@ F32 = jnp.float32
 # decode step or a prefill's scan over blocks)
 SSM_SCOPES = ("attn.ssm.proj", "attn.ssm.state")
 KINDS = ("ssm", "full")
+# what ``ffn_pattern`` may say of a layer (and ``layer_pattern``, beside
+# ``KINDS``, where it is stated): the half that is there, or "none"
+HALF_KINDS = ("experts", "none")
 
 
 def refuse_unbuilt_list(cfg) -> None:
@@ -72,41 +86,58 @@ def refuse_unbuilt_list(cfg) -> None:
         refuse(f"embed_mult={cfg.embed_mult}, residual_mult="
                f"{cfg.residual_mult}, logit_div={cfg.logit_div}",
                "a multiplier is positive (1 = off)")
-    plain = (cfg.rope and not cfg.attn_scale and cfg.embed_mult == 1.0
-             and cfg.residual_mult == 1.0 and cfg.logit_div == 1.0)
-    if not plain and (cfg.layer_group or cfg.looped or cfg.attn_window
-                      or cfg.n_experts):
+    multiplied = bool(cfg.attn_scale) or cfg.embed_mult != 1.0 \
+        or cfg.residual_mult != 1.0 or cfg.logit_div != 1.0
+    halves = bool(cfg.ffn_pattern)
+    # (a listed pattern of half-layers runs its expert layers beside
+    # attention without positions; the multipliers it has never run with)
+    if (multiplied or not cfg.rope) and (
+            cfg.layer_group or cfg.looped or cfg.attn_window
+            or (cfg.n_experts and (multiplied or not halves))):
         refuse("attention without rotary or at a scale of its own, or a "
                "multiplier on embeddings, residuals or logits, with a "
                "layer_group, a pass loop, attn_window or experts",
                "latent attention has positions and a temperature of its "
                "own, the other branches add to the stream in their own "
                "code; no served model has both, not run")
+    if not halves and (not cfg.moe_gated or cfg.moe_latent_dim
+                       or cfg.act == "relu2"):
+        refuse("ungated experts, moe_latent_dim or relu2 without an "
+               "ffn_pattern", "they are a list's expert layers': no layer "
+               "would read them")
     sizes = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_conv)
     if not cfg.layer_pattern:
-        if any(sizes) or cfg.ssm_groups != 1:
-            refuse(f"ssm sizes {sizes} without a layer_pattern",
-                   "no layer would read them")
+        if any(sizes) or cfg.ssm_groups != 1 or cfg.ssm_norm_groups != 1 \
+                or halves:
+            refuse(f"ssm sizes {sizes}, ssm_norm_groups="
+                   f"{cfg.ssm_norm_groups} or an ffn_pattern without a "
+                   "layer_pattern", "no layer would read them")
         return
     what = f"layer_pattern of {len(cfg.layer_pattern)} layers"
+    kinds = KINDS + ("none",) * halves
     if len(cfg.layer_pattern) != cfg.n_layers \
-            or any(k not in KINDS for k in cfg.layer_pattern):
-        refuse(what, f"one kind of {KINDS} for each of the {cfg.n_layers} "
-               "layers (KDA and MLA layers are stated by layer_group)")
+            or any(k not in kinds for k in cfg.layer_pattern):
+        refuse(what, f"one kind of {kinds} for each of the {cfg.n_layers} "
+               "layers (KDA and MLA layers are stated by layer_group; "
+               "\"none\" only beside an ffn_pattern)")
     if cfg.layer_group:
         refuse(f"{what} with layer_group={cfg.layer_group}",
                "a pattern is stated once, as the rule or as the list")
-    if cfg.looped or cfg.attn_window or cfg.sandwich_norm or cfg.n_experts:
+    if cfg.looped or cfg.attn_window or cfg.sandwich_norm \
+            or (cfg.n_experts and not halves):
         refuse(f"{what} with a pass loop, attn_window, sandwich_norm or "
                "experts", "a lane's state would need a plane a pass, a "
                "window's summarise knows no pool of fewer planes than "
-               "layers, and no listed pattern was run with output norms or "
-               "an expert layer; not built")
+               "layers, and no listed pattern was run with output norms; an "
+               "expert layer is built for a list that states its ffn_pattern")
     if cfg.embed_scale or cfg.logit_softcap or cfg.norm_offset \
-            or cfg.act != "silu":
+            or cfg.act != ("relu2" if halves else "silu"):
         refuse(f"{what} with a descriptor of another family (sqrt(dim) "
-               "embeddings, soft-capped logits, offset norms, gelu)",
+               "embeddings, soft-capped logits, offset norms, gelu; relu2 "
+               "is the ungated experts' of an ffn_pattern, and only theirs)",
                "no served model has both; not run")
+    if halves:
+        _refuse_unbuilt_halves(cfg, what, refuse)
     if "ssm" not in cfg.layer_pattern:
         refuse(f"{what} without an ssm layer",
                "that is a uniform decoder, stated without a list: a list "
@@ -119,6 +150,42 @@ def refuse_unbuilt_list(cfg) -> None:
                f"{cfg.ssm_groups}",
                "heads, a head's width, the state's width and at least "
                "2 taps are all needed, and the groups divide the heads")
+    if cfg.ssm_norm_groups < 1 or cfg.ssm_heads % cfg.ssm_norm_groups:
+        refuse(f"{what} with ssm_norm_groups={cfg.ssm_norm_groups}",
+               "the gated norm's groups are whole heads: they divide "
+               f"ssm_heads={cfg.ssm_heads}")
+
+
+def _refuse_unbuilt_halves(cfg, what: str, refuse) -> None:
+    """A listed pattern that states its ``ffn_pattern``: what is built is a
+    list of HALF-layers — a mixer, attention or an expert layer alone — whose
+    expert layers are told which experts they hold, ungated relu2 experts
+    with or without a latent; everything wider is refused."""
+    what = f"{what} with an ffn_pattern of {len(cfg.ffn_pattern)}"
+    if len(cfg.ffn_pattern) != cfg.n_layers \
+            or any(k not in HALF_KINDS for k in cfg.ffn_pattern):
+        refuse(what, f"one kind of {HALF_KINDS} for each of the "
+               f"{cfg.n_layers} layers (a dense feed-forward part is the "
+               "rule's, stated without an ffn_pattern)")
+    if any((a == "none") == (f == "none")
+           for a, f in zip(cfg.layer_pattern, cfg.ffn_pattern)):
+        refuse(what, "every layer is ONE half, a mixer or attention or a "
+               "feed-forward part (exactly one of the two lists says "
+               "\"none\"): whole layers beside half-layers were never run")
+    if "experts" not in cfg.ffn_pattern or not cfg.n_experts \
+            or cfg.moe_dense_layers:
+        refuse(f"{what}, n_experts={cfg.n_experts}, moe_dense_layers="
+               f"{cfg.moe_dense_layers}",
+               "the list says which layers are expert layers: it names at "
+               "least one, there are experts, and no rule beside it")
+    from .hybrid import refuse_unbuilt_share
+    refuse_unbuilt_share(cfg, refuse)
+    if cfg.moe_gated or cfg.moe_latent_dim < 0:
+        refuse(f"{what}, moe_gated={cfg.moe_gated}, moe_latent_dim="
+               f"{cfg.moe_latent_dim}",
+               "a list's expert layers are built and run with ungated "
+               "relu2 experts alone (two matrices an expert), in a latent "
+               "of moe_latent_dim numbers or (0) at the model's width")
 
 
 def conv_width(cfg) -> int:
@@ -133,11 +200,13 @@ def init_listed_layer(rng: jax.Array, cfg, l: int) -> dict:
     ``dt`` log-uniform over [0.001, 0.1], ``D = 1``, the convolution's taps
     and bias uniform over ``+- 1 / sqrt(taps)`` (a depthwise convolution's
     default)."""
-    kind = cfg.layer_kind(l)[0]
+    kind, ffn = cfg.layer_kind(l)
     dt, d = cfg.dtype, cfg.dim
     r = iter(jax.random.split(rng, 12))
-    layer = {"attn_norm": jnp.ones((d,), F32),
-             "mlp_norm": jnp.ones((d,), F32)}
+    # (a half-layer keeps the norm of the half it has, and no other)
+    layer = {name: jnp.ones((d,), F32)
+             for name, half in (("attn_norm", kind), ("mlp_norm", ffn))
+             if half != "none"}
     if kind == "ssm":
         h, inner = cfg.ssm_heads, cfg.ssm_heads * cfg.ssm_head_dim
         width, bound = conv_width(cfg), cfg.ssm_conv ** -0.5
@@ -155,16 +224,21 @@ def init_listed_layer(rng: jax.Array, cfg, l: int) -> dict:
             "d_skip": jnp.ones((h,), F32),
             "norm": jnp.ones((inner,), F32),
             "w_out": _dense(next(r), inner, d, dt)}
-    else:
+    elif kind == "full":
         q_dim, kv_dim = cfg.n_heads * cfg.head_dim, \
             cfg.n_kv_heads * cfg.head_dim
         layer.update(wq=_dense(next(r), d, q_dim, dt),
                      wk=_dense(next(r), d, kv_dim, dt),
                      wv=_dense(next(r), d, kv_dim, dt),
                      wo=_dense(next(r), q_dim, d, dt))
-    layer["w_gate"] = _dense(next(r), d, cfg.hidden_dim, dt)
-    layer["w_up"] = _dense(next(r), d, cfg.hidden_dim, dt)
-    layer["w_down"] = _dense(next(r), cfg.hidden_dim, d, dt)
+    if ffn == "experts":
+        from .moe import init_moe_layer
+        from .transformer import moe_cfg
+        layer["moe"] = init_moe_layer(next(r), moe_cfg(cfg))
+    elif ffn == "dense":
+        layer["w_gate"] = _dense(next(r), d, cfg.hidden_dim, dt)
+        layer["w_up"] = _dense(next(r), d, cfg.hidden_dim, dt)
+        layer["w_down"] = _dense(next(r), cfg.hidden_dim, d, dt)
     return layer
 
 
@@ -249,6 +323,13 @@ def ssm_block(p: dict, u: jnp.ndarray, cfg, kv_cache: Optional[dict],
                     state=ssd.pack_state(state, ssd.head_pack(heads, hd, g)))
         y = y + p["d_skip"][:, None] * x
     with jax.named_scope("attn.out"):
-        y = rms_norm((y * jax.nn.silu(z).reshape(b, t, heads, hd)).reshape(
-            b, t, inner), p["norm"], cfg.norm_eps)
+        y = (y * jax.nn.silu(z).reshape(b, t, heads, hd)).reshape(b, t, inner)
+        ng = cfg.ssm_norm_groups
+        if ng == 1:
+            y = rms_norm(y, p["norm"], cfg.norm_eps)
+        else:
+            # the norm over each group's channels, the weight a channel
+            y = rms_norm(y.reshape(b, t, ng, inner // ng),
+                         p["norm"].reshape(ng, inner // ng),
+                         cfg.norm_eps).reshape(b, t, inner)
         return maybe_matmul(y.astype(u.dtype), p["w_out"]), kv_cache

@@ -42,7 +42,8 @@ class DecoderConfig:
     max_seq_len: int = 8192
     # what the families differ in: descriptors of the architecture, read
     # by ``init_decoder`` and ``decoder_forward``; no model is named
-    act: str = "silu"              # silu (llama) | gelu (gemma)
+    # silu (llama) | gelu (gemma) | relu2 (a list's ungated experts alone)
+    act: str = "silu"
     norm_offset: float = 0.0       # 1.0 for gemma's (1+w) RMSNorm
     embed_scale: bool = False      # gemma scales embeddings by sqrt(dim)
     logit_softcap: float = 0.0     # gemma-2 style; 0 = off
@@ -132,6 +133,25 @@ class DecoderConfig:
     ssm_state: int = 0
     ssm_groups: int = 1
     ssm_conv: int = 0
+    # the groups of the mixer's gated norm: the norm runs over each group's
+    # ``ssm_heads * ssm_head_dim / ssm_norm_groups`` channels (1 = over all)
+    ssm_norm_groups: int = 1
+    # the OTHER half of a listed layer, stated as a list beside
+    # ``layer_pattern``: the feed-forward kind of every layer, ``"experts"``
+    # or ``"none"``; () = the rule above (``moe_dense_layers`` dense, then
+    # experts where there are any). With it ``layer_pattern`` says ``"none"``
+    # too, and every layer is ONE sub-layer — a mixer alone, attention alone
+    # or an expert layer alone, ``x + f(norm(x))`` with one norm and no
+    # weights for the half it lacks
+    ffn_pattern: tuple = ()
+    # the expert layer of a listed pattern: experts of two matrices,
+    # ``act(x W_up) W_down`` (False; True = the gated three), with the
+    # shared expert of the same form; and routed experts that work in a
+    # LATENT of ``moe_latent_dim`` numbers — one projection down in front of
+    # the dispatch and one up behind the combine, shared by all experts,
+    # while the router and the shared expert read the full width (0 = none)
+    moe_gated: bool = True
+    moe_latent_dim: int = 0
     # plain attention without positions (False: no rotary, no table), and
     # at a softmax scale of its own (0 = ``head_dim ** -0.5``)
     rope: bool = True
@@ -179,15 +199,18 @@ class DecoderConfig:
             from .hybrid import refuse_unbuilt_pattern
             refuse_unbuilt_pattern(self)
         elif (self.mla_latent or self.kda_conv or self.moe_dense_layers
-              or self.moe_routed or self.moe_shared_dim
-              or self.moe_score != "softmax" or self.mla_q_latent
-              or self.rope_yarn or self.mla_mscale != 1.0):
+              or self.mla_q_latent or self.rope_yarn
+              or self.mla_mscale != 1.0
+              or ((self.moe_routed or self.moe_shared_dim
+                   or self.moe_score != "softmax")
+                  and "experts" not in self.ffn_pattern)):
             raise ValueError(
                 "latent attention (its query latent, YaRN positions and "
                 "temperature with it), the delta rule and the expert "
                 "layer's share, shared expert and sigmoid gates are built "
-                "for a layer pattern only (layer_group > 0): no served "
-                "model has one without the other")
+                "for a layer pattern only (layer_group > 0, or a listed "
+                "pattern whose ffn_pattern says which layers are expert "
+                "layers): no served model has one without the other")
 
     @property
     def q_per_kv(self) -> int:
@@ -200,12 +223,15 @@ class DecoderConfig:
         ``"full"`` (the plain attention of a uniform decoder), ``"kda"`` or
         ``"mla"`` (the last layer of each group of ``layer_group``: every
         layer where a group is one layer), or what ``layer_pattern`` lists
-        for the layer (``"ssm"`` or ``"full"``); the ffn is ``"dense"`` or
-        ``"experts"``. THE one place that knows the
+        for the layer (``"ssm"``, ``"full"`` or ``"none"``); the ffn is
+        ``"dense"`` or ``"experts"``, or what ``ffn_pattern`` lists
+        (``"none"`` too: a layer of one half). THE one place that knows the
         pattern: ``init_decoder``, the forward pass, the pool's depth and
         the lanes' state all ask here."""
         if self.layer_pattern:
             attention = self.layer_pattern[l]
+            if self.ffn_pattern:
+                return attention, self.ffn_pattern[l]
         elif not self.layer_group:
             attention = "full"
         else:
@@ -423,7 +449,8 @@ def moe_cfg(cfg: DecoderConfig):
                      select_bias=cfg.moe_select_bias,
                      n_groups=cfg.moe_groups, top_groups=cfg.moe_top_groups,
                      renormalise=cfg.moe_renormalise,
-                     gate_scale=cfg.moe_gate_scale)
+                     gate_scale=cfg.moe_gate_scale, gated=cfg.moe_gated,
+                     latent_dim=cfg.moe_latent_dim)
 
 
 def _act(x: jnp.ndarray, kind: str) -> jnp.ndarray:
@@ -450,6 +477,11 @@ DEVICE_SCOPES = ("embed", "attn.qkv", "attn.rope", "kv.slice", "kv.write",
 # no plain program runs anything under them. Apart from ``DEVICE_SCOPES``:
 # the benchmark's accepted tests pin what that tuple leaves ungrouped.
 LOOP_SCOPES = ("loop.norm", "loop.gate", "loop.select")
+# An expert layer whose routed experts work in a latent (``moe_latent_dim``):
+# the projection down in front of the dispatch and the one up behind the
+# combine. Apart for the same reason (``moe.shared`` is ``hybrid.
+# HYBRID_SCOPES``')
+LATENT_MOE_SCOPES = ("moe.latent.in", "moe.latent.out")
 # The summarise of a closed window (``attn_window``, ISSUE 46), in every
 # program that runs it: ``serving.graphs`` opens the scope. Apart for the
 # same reason.
@@ -677,10 +709,15 @@ def _pattern_layers(params: Params, x, cfg: DecoderConfig, positions, sin,
         n_valid = jnp.full((b,), t, jnp.int32)
     picks = []
     for i, layer in enumerate(params["layers"]):
-        x, kv_cache = _attn_block(layer, x, cfg, positions, sin, cos,
-                                  kv_cache, i, cache_len, decode,
-                                  compute_dtype=compute_dtype,
-                                  n_valid=n_valid)
+        # (a listed layer may be one half alone: ``"none"`` is the other)
+        attention, ffn = cfg.layer_kind(i)
+        if attention != "none":
+            x, kv_cache = _attn_block(layer, x, cfg, positions, sin, cos,
+                                      kv_cache, i, cache_len, decode,
+                                      compute_dtype=compute_dtype,
+                                      n_valid=n_valid)
+        if ffn == "none":
+            continue
         x, aux = _mlp_block(layer, x, cfg, compute_dtype, live=live)
         if aux is not None:
             picks.append(aux["picks"])

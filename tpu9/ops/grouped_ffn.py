@@ -1,11 +1,13 @@
-"""Grouped expert FFN: every expert's gated FFN over that expert's rows only.
+"""Grouped expert FFN: every expert's FFN over that expert's rows only.
 
 The serving MoE dispatch (``tpu9.models.moe.moe_ffn_sorted``) lays the
 (token, slot) rows of a call out by expert, each expert's rows padded to
 whole ``ROW_TILE``-row tiles. This module multiplies them:
 ``y[rows of e] = (act(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]`` — three
 grouped matmuls over the E row groups, fused so that the ``[rows, hidden]``
-intermediate never leaves VMEM.
+intermediate never leaves VMEM. Experts of TWO matrices (``stacks`` = ``(w_up,
+w_down)``: ``y = act(x @ w_up[e]) @ w_down[e]``, ungated) go through the
+same kernel and the same oracle without the gate's operand.
 
 How the kernel gets ONE pass over the expert weights: the grid is
 ``(segment, hidden tile)``, a segment being up to ``SEGMENT_TILES`` row
@@ -54,18 +56,46 @@ _VMEM_LIMIT = 100 * 1024 * 1024
 def _act(x, act: str):
     if act == "silu":
         return jax.nn.silu(x)
+    if act == "relu2":
+        return jnp.square(jax.nn.relu(x))
     return jax.nn.gelu(x, approximate=True)
 
 
-def _hidden_tile(hidden: int) -> int:
-    for tile in (HIDDEN_TILE, 256, 128):
+# the weight blocks of a step, double-buffered, where a hidden width that
+# neither 512 nor 256 divides is taken WHOLE (2,688 = 21 x 128 in a latent
+# of 1,024: two matrices of 5.5 MB, 22 MB twice, where tiles of 128 columns
+# would be 21 steps of 256-byte rows)
+WHOLE_BYTES = 48 * 1024 * 1024
+
+
+def _hidden_tile(hidden: int, block_bytes: int = 0) -> int:
+    """Hidden columns of a step. ``block_bytes``: what a step's weight
+    blocks take at the whole hidden width."""
+    for tile in (HIDDEN_TILE, 256):
         if hidden % tile == 0:
             return tile
-    return hidden
+    if 0 < 2 * block_bytes <= WHOLE_BYTES:
+        return hidden
+    return 128 if hidden % 128 == 0 else hidden
 
 
-def _kernel(expert_ref, first_ref, count_ref, x_hbm, wg_ref, wu_ref, wd_ref,
-            out_hbm, x_rows, acc, sem, *, act: str):
+def _into_hidden(x, w_in) -> list:
+    """``x`` times each of the one or two matrices (or blocks of them) that
+    lead into the hidden width, float32."""
+    return [jnp.dot(x, w[...], preferred_element_type=jnp.float32)
+            for w in w_in]
+
+
+def _gated(into: list, act: str):
+    """``act(x @ w_gate) * (x @ w_up)`` of :func:`_into_hidden`'s two
+    products, or ungated ``act(x @ w_up)`` of its one."""
+    hidden = _act(into[0], act)
+    return hidden if len(into) == 1 else hidden * into[1]
+
+
+def _kernel(expert_ref, first_ref, count_ref, x_hbm, *refs, act: str):
+    # (the one or two matrices into the hidden width, then the one out)
+    *w_in, wd_ref, out_hbm, x_rows, acc, sem = refs
     del expert_ref                      # read by the weights' index maps
     g, j = pl.program_id(0), pl.program_id(1)
     first, count = first_ref[g], count_ref[g]
@@ -86,9 +116,7 @@ def _kernel(expert_ref, first_ref, count_ref, x_hbm, wg_ref, wu_ref, wd_ref,
     def one_tile(t, _):
         rows = rows_of(t)
         x = x_rows[rows, :]
-        gate = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
-        up = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
-        h = (_act(gate, act) * up).astype(x.dtype)
+        h = _gated(_into_hidden(x, w_in), act).astype(x.dtype)
         y = jnp.dot(h, wd_ref[...], preferred_element_type=jnp.float32)
 
         @pl.when(j == 0)
@@ -127,15 +155,20 @@ def _segments(tiles, n_segments: int):
 
 
 @functools.partial(jax.jit, static_argnames=("act", "interpret"))
-def grouped_ffn_kernel(xs, tiles, w_gate, w_up, w_down, *, act: str = "silu",
+def grouped_ffn_kernel(xs, tiles, *stacks, act: str = "silu",
                        interpret: bool = False):
     """xs [R, d]: rows grouped by expert, expert ``e`` owning ``tiles[e]``
     whole ``ROW_TILE``-row tiles, in order, from row 0 (R a multiple of
-    ``ROW_TILE``; ``tiles`` int32 [E]). Returns float32 [R, d]; rows of
-    tiles past ``tiles.sum()`` are not written."""
+    ``ROW_TILE``; ``tiles`` int32 [E]); ``stacks`` the experts' ``(w_gate,
+    w_up, w_down)`` or ungated ``(w_up, w_down)``, ``[E, d, hidden]`` into
+    the hidden width and ``[E, hidden, d]`` out of it. Returns float32 [R,
+    d]; rows of tiles past ``tiles.sum()`` are not written."""
     r, d = xs.shape
-    n_experts, _, hidden = w_gate.shape
-    th = _hidden_tile(hidden)
+    *w_in, w_down = stacks
+    n_experts, _, hidden = w_down.shape[0], d, w_down.shape[1]
+    # (the gated three keep the tiles they were measured with)
+    th = _hidden_tile(hidden) if len(w_in) == 2 else _hidden_tile(
+        hidden, len(stacks) * d * hidden * w_down.dtype.itemsize)
     last = hidden // th - 1
     # every expert's last segment may be short: at most one more each
     n_segments = r // ROW_TILE // SEGMENT_TILES + n_experts
@@ -152,8 +185,7 @@ def grouped_ffn_kernel(xs, tiles, w_gate, w_up, w_down, *, act: str = "silu",
         grid=(n_segments, last + 1),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((None, d, th), gate_block),
-            pl.BlockSpec((None, d, th), gate_block),
+            *[pl.BlockSpec((None, d, th), gate_block) for _ in w_in],
             pl.BlockSpec((None, th, d), down_block),
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
@@ -163,8 +195,7 @@ def grouped_ffn_kernel(xs, tiles, w_gate, w_up, w_down, *, act: str = "silu",
             pltpu.SemaphoreType.DMA(()),
         ],
     )
-    weight_bytes = sum(w.size * w.dtype.itemsize
-                       for w in (w_gate, w_up, w_down))
+    weight_bytes = sum(w.size * w.dtype.itemsize for w in stacks)
     return pl.pallas_call(
         functools.partial(_kernel, act=act),
         grid_spec=grid_spec,
@@ -173,25 +204,28 @@ def grouped_ffn_kernel(xs, tiles, w_gate, w_up, w_down, *, act: str = "silu",
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
-            flops=6 * r * d * hidden, transcendentals=r * hidden,
+            flops=2 * len(stacks) * r * d * hidden,
+            transcendentals=r * hidden,
             bytes_accessed=weight_bytes + r * d * (xs.dtype.itemsize + 4)),
         name="grouped_ffn",
         interpret=interpret,
-    )(*_segments(tiles.astype(jnp.int32), n_segments), xs, w_gate, w_up,
-      w_down)
+    )(*_segments(tiles.astype(jnp.int32), n_segments), xs, *stacks)
 
 
-def grouped_ffn_xla(xs, tiles, w_gate, w_up, w_down, *, act: str = "silu"):
+def grouped_ffn_xla(xs, tiles, *stacks, act: str = "silu"):
     """The same layout through ``jax.lax.ragged_dot``: the oracle the kernel
     is held to, and what a backend without the kernel runs."""
     dot = functools.partial(jax.lax.ragged_dot,
                             group_sizes=tiles.astype(jnp.int32) * ROW_TILE,
                             preferred_element_type=jnp.float32)
-    h = (_act(dot(xs, w_gate), act) * dot(xs, w_up)).astype(xs.dtype)
-    return dot(h, w_down)
+    *w_in, w_down = stacks
+    h = _act(dot(xs, w_in[0]), act)
+    if len(w_in) == 2:
+        h = h * dot(xs, w_in[1])
+    return dot(h.astype(xs.dtype), w_down)
 
 
-def grouped_ffn(xs, tiles, w_gate, w_up, w_down, *, act: str = "silu"):
+def grouped_ffn(xs, tiles, *stacks, act: str = "silu"):
     if on_tpu():
-        return grouped_ffn_kernel(xs, tiles, w_gate, w_up, w_down, act=act)
-    return grouped_ffn_xla(xs, tiles, w_gate, w_up, w_down, act=act)
+        return grouped_ffn_kernel(xs, tiles, *stacks, act=act)
+    return grouped_ffn_xla(xs, tiles, *stacks, act=act)

@@ -5,7 +5,10 @@ A decode step's expert layer (``tpu9.models.moe.moe_ffn_held``) has few rows
 some: ``out = sum over touched e of (act(x @ w_gate[e]) * (x @ w_up[e]) *
 weight[:, e]) @ w_down[e]``, ``weight[n, e]`` the gate of row ``n`` for held
 expert ``e`` (0 for all but its picks). An expert no live row picked adds
-nothing whatever its weights are, so its weights need not cross HBM.
+nothing whatever its weights are, so its weights need not cross HBM. Experts
+of TWO matrices (``stacks`` = ``(w_up, w_down)``: ``act(x @ w_up[e]) *
+weight[:, e]) @ w_down[e]``, ungated) go through the same kernel and the same
+oracle without the gate's operand.
 
 How the kernel reads only the touched experts: the grid is ``(slot, hidden
 tile)``, one slot a held expert; the touched experts' ids, ascending (the
@@ -36,7 +39,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..utils import on_tpu
-from .grouped_ffn import _VMEM_LIMIT, _act, _hidden_tile
+from .grouped_ffn import (_VMEM_LIMIT, _act, _gated, _hidden_tile,
+                          _into_hidden)
 
 # the weight blocks of a step, double-buffered: an expert's three matrices
 # whole while they fit (Ling: 3 x 2560 x 768 bf16 = 11.8 MB, 23.6 MB twice)
@@ -63,16 +67,17 @@ def touched_experts(local, live, n_experts: int):
             ends[-1:])
 
 
-def _step_tile(d: int, hidden: int, itemsize: int) -> int:
-    """Hidden columns of a step: all of them where an expert's three
+def _step_tile(d: int, hidden: int, itemsize: int, matrices: int = 3) -> int:
+    """Hidden columns of a step: all of them where an expert's ``matrices``
     matrices fit ``STEP_BYTES`` twice, else ``grouped_ffn``'s tile."""
-    if 2 * 3 * d * hidden * itemsize <= STEP_BYTES:
+    if 2 * matrices * d * hidden * itemsize <= STEP_BYTES:
         return hidden
     return _hidden_tile(hidden)
 
 
-def _kernel(ids_ref, count_ref, x_ref, gates_ref, wg_ref, wu_ref, wd_ref,
-            out_ref, *, act: str):
+def _kernel(ids_ref, count_ref, x_ref, gates_ref, *refs, act: str):
+    # (the one or two matrices into the hidden width, then the one out)
+    *w_in, wd_ref, out_ref = refs
     s, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when((s == 0) & (j == 0))
@@ -82,28 +87,30 @@ def _kernel(ids_ref, count_ref, x_ref, gates_ref, wg_ref, wu_ref, wd_ref,
     @pl.when(s < count_ref[0])
     def _expert():
         x = x_ref[...]
-        gate = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
-        up = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
+        into = _into_hidden(x, w_in)
         # the rows' gates for this expert: its column of [N, E], picked by a
         # mask (a lane cannot be sliced at a traced index)
         gates = gates_ref[...]
         lane = jax.lax.broadcasted_iota(jnp.int32, gates.shape, 1)
         column = jnp.sum(jnp.where(lane == ids_ref[s], gates, 0.0), axis=1,
                          keepdims=True)
-        h = (_act(gate, act) * up * column).astype(x.dtype)
+        h = (_gated(into, act) * column).astype(x.dtype)
         out_ref[...] += jnp.dot(h, wd_ref[...],
                                 preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("act", "interpret"))
-def held_ffn_kernel(x, weight, ids, count, w_gate, w_up, w_down, *,
-                    act: str = "silu", interpret: bool = False):
+def held_ffn_kernel(x, weight, ids, count, *stacks, act: str = "silu",
+                    interpret: bool = False):
     """x [N, d]; weight float32 [N, E]; ``ids`` int32 [E] and ``count``
-    int32 [1] as :func:`touched_experts` gives them; the stacks [E, d, h],
-    [E, d, h], [E, h, d] in ``x``'s type. Returns float32 [N, d]."""
+    int32 [1] as :func:`touched_experts` gives them; ``stacks`` the experts'
+    ``(w_gate, w_up, w_down)`` or ungated ``(w_up, w_down)``, [E, d, h] into
+    the hidden width and [E, h, d] out of it, in ``x``'s type. Returns
+    float32 [N, d]."""
     n, d = x.shape
-    n_experts, _, hidden = w_gate.shape
-    th = _step_tile(d, hidden, w_gate.dtype.itemsize)
+    *w_in, w_down = stacks
+    n_experts, hidden = w_down.shape[:2]
+    th = _step_tile(d, hidden, w_down.dtype.itemsize, len(stacks))
     last = hidden // th - 1
 
     def rows(s, j, ids, count):
@@ -122,14 +129,12 @@ def held_ffn_kernel(x, weight, ids, count, w_gate, w_up, w_down, *,
         in_specs=[
             pl.BlockSpec((n, d), rows),
             pl.BlockSpec((n, n_experts), rows),
-            pl.BlockSpec((None, d, th), gate_block),
-            pl.BlockSpec((None, d, th), gate_block),
+            *[pl.BlockSpec((None, d, th), gate_block) for _ in w_in],
             pl.BlockSpec((None, th, d), down_block),
         ],
         out_specs=pl.BlockSpec((n, d), rows),
     )
-    weight_bytes = sum(w.size * w.dtype.itemsize
-                       for w in (w_gate, w_up, w_down))
+    weight_bytes = sum(w.size * w.dtype.itemsize for w in stacks)
     return pl.pallas_call(
         functools.partial(_kernel, act=act),
         grid_spec=grid_spec,
@@ -140,38 +145,37 @@ def held_ffn_kernel(x, weight, ids, count, w_gate, w_up, w_down, *,
         # every held expert touched, the upper bound: the count is a traced
         # value, and a step reads count / n_experts of these weights
         cost_estimate=pl.CostEstimate(
-            flops=6 * n * d * hidden * n_experts,
+            flops=2 * len(stacks) * n * d * hidden * n_experts,
             transcendentals=n * hidden * n_experts,
             bytes_accessed=weight_bytes + n * d * (x.dtype.itemsize + 4)),
         name="held_ffn",
         interpret=interpret,
-    )(ids, count, x, weight, w_gate, w_up, w_down)
+    )(ids, count, x, weight, *stacks)
 
 
-def held_ffn_xla(x, weight, ids, count, w_gate, w_up, w_down, *,
-                 act: str = "silu"):
+def held_ffn_xla(x, weight, ids, count, *stacks, act: str = "silu"):
     """Every held expert over every row, the untouched ones too (their gates
     are 0): the oracle the kernel is held to, and what a backend without the
     kernel runs."""
     del ids, count
-    n_experts = w_gate.shape[0]
+    *w_in, w_down = stacks
+    n_experts = w_down.shape[0]
     # the rows as a BATCHED operand, one copy an expert: a product a held
     # expert over the stacks as they are stored. Without the batch dimension
     # the compiler takes ONE product over all experts' columns, and
     # transposes both stacks a call to get it
     h = jnp.broadcast_to(x, (n_experts, *x.shape))
-    gate = _act(jnp.einsum("end,edh->enh", h, w_gate), act)
-    up = jnp.einsum("end,edh->enh", h, w_up)
+    hidden = _act(jnp.einsum("end,edh->enh", h, w_in[0]), act)
+    if len(w_in) == 2:
+        hidden = hidden * jnp.einsum("end,edh->enh", h, w_in[1])
     # the gate weights the hidden rows, so that the down projection sums
     # over experts and hidden at once: no [E, N, d] product
-    hidden = (gate * up).astype(jnp.float32) * weight.T[..., None]
+    hidden = hidden.astype(jnp.float32) * weight.T[..., None]
     return jnp.einsum("enh,ehd->nd", hidden.astype(x.dtype), w_down,
                       preferred_element_type=jnp.float32)
 
 
-def held_ffn(x, weight, ids, count, w_gate, w_up, w_down, *,
-             act: str = "silu"):
+def held_ffn(x, weight, ids, count, *stacks, act: str = "silu"):
     if on_tpu():
-        return held_ffn_kernel(x, weight, ids, count, w_gate, w_up, w_down,
-                               act=act)
-    return held_ffn_xla(x, weight, ids, count, w_gate, w_up, w_down, act=act)
+        return held_ffn_kernel(x, weight, ids, count, *stacks, act=act)
+    return held_ffn_xla(x, weight, ids, count, *stacks, act=act)

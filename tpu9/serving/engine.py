@@ -228,7 +228,10 @@ def refuse_unbuilt_with_lane_state(cfg, ecfg: "EngineConfig",
     over latents), a mesh, ``kv_quant``, the host tier and kvwire (they
     address per-head ``k`` / ``v`` planes; no sharding rule and no wire
     format names a latent row). Per-head rows beside state are the plain
-    pool's, and only the state refuses. Nothing in the engine preempts a
+    pool's, and only the state refuses. A list's expert layers beside state
+    (``ffn_pattern``) are told which experts they hold and take the dropless
+    held / sorted forms on one device: every refusal here stands for them
+    too (no mesh, no int8 stacks, no prefix cache, no verify). Nothing in the engine preempts a
     running lane, so there is no path that drops a lane's state without
     re-prefilling it; one that is added has to snapshot or re-prefill
     (ROADMAP R6)."""
@@ -277,7 +280,9 @@ def refuse_unbuilt_with_lane_state(cfg, ecfg: "EngineConfig",
             (False, True):
                "the state a lane is one chip's: no sharding rule names it "
                "(which axis of [planes, lanes, heads, width, state] a mesh "
-               "shards), and the step kernel is not partitioned",
+               "shards), and the step kernel is not partitioned"
+               + ("; a list's held experts take the dropless kernels on one "
+                  "device alone" if cfg.moe_routed else ""),
         }[latent, bool(state)])
     if ecfg.kv_quant:
         refuse(f"kv_quant={ecfg.kv_quant!r}",
@@ -452,7 +457,9 @@ class InferenceEngine:
                     "pattern's layers and the held experts' einsum are "
                     "built for the model's own type" if cfg.layer_group else
                     "layer_pattern with int8 weights: the state-space "
-                    "mixer's projections are built for the model's own type")
+                    "mixer's projections (and a list's expert kernels, which "
+                    "read the stacks as they are stored) are built for the "
+                    "model's own type")
         from ..ops.quant import validate_quant_mode
         _kvq = validate_quant_mode(engine_cfg.kv_quant, "kv_quant")
         if _kvq and _kvq != "int8":
@@ -748,6 +755,11 @@ class InferenceEngine:
         # which attention path each phase takes (pallas kernel, or the XLA
         # oracle with the reason the kernel declined these shapes)
         self._attention = self._attention_paths()
+        self._pattern = self._pattern_report()
+        # an expert layer that is told what it holds (a pattern's, by rule
+        # or list) says in every program which experts each token chose,
+        # and a finished request leaves them in ``routed_experts``
+        self._keeps_routing = bool(cfg.layer_group or cfg.moe_routed)
         # ---- replica health plane (ISSUE 14) ----
         # liveness watermark: monotonic progress counters + dispatch/
         # progress stamps the runner-side watchdog classifies from. All
@@ -836,6 +848,33 @@ class InferenceEngine:
                     "prefill": plain["prefill"] + "; ssm scan: " + ", ".join(
                         sorted({scan_form(w) for w in widths}))}
         return self._plain_attention_paths()
+
+    def _pattern_report(self) -> dict:
+        """What ``/health`` says of a layer pattern beside its attention
+        paths: ``layers_by_kind`` (a half a listed layer lacks is not a
+        kind), and for an expert layer that is told what it holds
+        ``ffn_decode`` / ``ffn_prefill`` — the form it takes at a decode
+        step's rows and at a prefill dispatch's, as ``attention_decode``
+        says of the attention — and ``moe_latent`` (0 = none). Empty for a
+        uniform decoder."""
+        cfg, out = self.cfg, {}
+        if cfg.layer_group or cfg.layer_pattern:
+            kinds = [k for l in range(cfg.n_layers)
+                     for k in cfg.layer_kind(l) if k != "none"]
+            out["layers_by_kind"] = {k: kinds.count(k)
+                                     for k in dict.fromkeys(kinds)}
+        if cfg.moe_routed:
+            from ..models.moe import share_forms
+            from ..models.transformer import moe_cfg
+            forms = share_forms(
+                moe_cfg(cfg), self.ecfg.max_batch,
+                (self.graphs.chunk,
+                 self.graphs.chunk * self.graphs.group_chunks)
+                if self.paged else self.ecfg.prefill_buckets)
+            out.update(ffn_decode=forms["decode"],
+                       ffn_prefill=forms["prefill"],
+                       moe_latent=cfg.moe_latent_dim)
+        return out
 
     def _plain_attention_paths(self) -> dict:
         """:meth:`_attention_paths` of the plain attention's kernels."""
@@ -1495,6 +1534,10 @@ class InferenceEngine:
         out["device_count"] = len(self._devices)
         out["attention_decode"] = self._attention["decode"]
         out["attention_prefill"] = self._attention["prefill"]
+        # a pattern's layers by kind; which form its expert layer takes at
+        # a decode step's rows and at a prefill dispatch's, and the
+        # experts' latent (``_pattern_report``)
+        out.update(self._pattern)
         # tpu_custom_call count per AOT-compiled graph (precompile only)
         out["graph_kernels"] = dict(self.graphs.kernel_calls)
         # topology (ISSUE 9): flat scalars so the runner heartbeat can
@@ -1709,7 +1752,7 @@ class InferenceEngine:
         last = None
         group = self.graphs.group_chunks
         k_chunk = 0
-        if self.cfg.layer_group:
+        if self._keeps_routing:
             req.routed = []
         while k_chunk < n_chunks:
             # FULL groups use the wide group graph warmup compiled; a
@@ -3009,7 +3052,7 @@ class InferenceEngine:
         at each step. A layer pattern's request keeps those of the tokens it
         was delivered (step j fed in the token before the j-th delivered);
         the counters are over the lanes live at dispatch, every step."""
-        if self.cfg.layer_group:
+        if self._keeps_routing:
             for slot, n in win.delivered.items():
                 win.reqs[slot].routed.append(picks[:n, slot].copy())
         live = picks[:, win.mask]

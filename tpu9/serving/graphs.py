@@ -33,7 +33,8 @@ import jax.numpy as jnp
 from ..models import kvstate
 from ..models.hybrid import HYBRID_SCOPES, MLA_QUERY_SCOPES
 from ..models.ssm import SSM_SCOPES
-from ..models.transformer import (DEVICE_SCOPES, LOOP_SCOPES, SUMMARY_SCOPES,
+from ..models.transformer import (DEVICE_SCOPES, LATENT_MOE_SCOPES,
+                                  LOOP_SCOPES, SUMMARY_SCOPES,
                                   decoder_forward)
 from ..ops.sampling import sample_logits
 
@@ -758,7 +759,8 @@ class GraphFactory:
             # a plain program runs nothing under the loop's scopes
             scopes = hlo_scopes(
                 text, DEVICE_SCOPES + LOOP_SCOPES + SUMMARY_SCOPES
-                + HYBRID_SCOPES + MLA_QUERY_SCOPES + SSM_SCOPES)
+                + HYBRID_SCOPES + MLA_QUERY_SCOPES + SSM_SCOPES
+                + LATENT_MOE_SCOPES)
             if scopes:
                 self.device_scopes[name] = scopes
             else:
