@@ -266,7 +266,7 @@ C, S_ENGINE = 128, 1536
 @pytest.fixture(scope="module")
 def tiny():
     cfg = replace(LLAMA_PRESETS["llama-tiny"], dtype=jnp.float32,
-                  max_seq_len=2048)         # the rope table: no parameter
+                  max_seq_len=2048)         # the positions: no parameter
     return cfg, init_decoder(jax.random.PRNGKey(0), cfg)
 
 

@@ -606,3 +606,80 @@ def test_repo_graph_gate_is_green(capsys):
     assert f"{len(MATRIX)} cells" in out.out and " 0 findings" in out.out, \
         out.out[-2000:]
     assert rc == 0, out.err[-2000:]
+
+
+# ---------------------------------------------------------------------------
+# no program holds a rope table (PR 60): the benchmark's configurations
+# ---------------------------------------------------------------------------
+
+def _benchmark_config_names():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return [c["name"] for c in json.load(f)["configs"]]
+
+
+def _rehearsal_programs(name: str):
+    """``(cfg, {key: lowered text})`` of configuration ``name``'s forward
+    programs (decode, chunk, group) at its rehearsal size, built as
+    ``benchmark/tools/lowered_configs.py`` builds them."""
+    from benchmark import manifest, serve
+    from tpu9.serving.graphs import GraphFactory, abstract_state
+    from tpu9.serving.presets import abstract_params_for
+    from tpu9.serving.shard import make_policy
+    m = manifest.load()
+    config = manifest.load_config(m, name)
+    family = manifest.family(config)
+    tiny, _ = manifest.module("run").apply_rehearsal(config, name)
+    cfg = family.program_config(family.model_sizes(tiny))
+    ecfg = serve.engine_config(tiny["engine"])
+    policy = make_policy(tiny["engine"]["topology"])
+    factory = GraphFactory(cfg, ecfg, policy, chunk=ecfg.prefill_chunk)
+    st = abstract_state(cfg, ecfg, policy)
+    texts = {}
+    for key, fn, args in factory.lowering_jobs(
+            abstract_params_for(cfg, False), st["kv_cache"], st["pool"],
+            st["scratch"], st["mb"], [ecfg.prefill_chunk], (), st["rng"]):
+        if isinstance(key, tuple) and key[0] in ("decode", "chunk",
+                                                 "chunkgroup"):
+            texts[key] = fn.lower(*args).as_text()
+    return cfg, texts
+
+
+def _table_type(cfg) -> str:
+    """The lowered type of a rope table over every position ``cfg``'s model
+    could hold: ``max_seq_len`` rows of ``half`` float32 columns."""
+    return (f"tensor<{cfg.max_seq_len}x"
+            f"{(cfg.mla_rope or cfg.head_dim) // 2}xf32>")
+
+
+def test_the_table_a_program_must_not_hold_is_told_from_its_text():
+    """The control of the test below: a program that builds the table (as
+    every program did until PR 60) has the type the test looks for."""
+    import jax
+    import jax.numpy as jnp
+    from tpu9.models.transformer import DecoderConfig
+    cfg = DecoderConfig(max_seq_len=2048, head_dim=16)
+
+    def gathers(positions):
+        angles = jnp.arange(cfg.max_seq_len, dtype=jnp.float32)[:, None] \
+            * jnp.ones(cfg.head_dim // 2, jnp.float32)
+        return jnp.sin(angles)[positions]
+
+    text = jax.jit(gathers).lower(jnp.zeros((4, 1), jnp.int32)).as_text()
+    assert _table_type(cfg) == "tensor<2048x8xf32>"
+    assert _table_type(cfg) in text
+
+
+@pytest.mark.parametrize("name", _benchmark_config_names())
+def test_no_program_holds_a_rope_table(name):
+    """A configuration with rotary embeddings takes the sines of the rows
+    its program feeds and no tensor of a decode, chunk or group program is a
+    table over ``max_seq_len`` positions; one without takes no sine at
+    all."""
+    cfg, texts = _rehearsal_programs(name)
+    assert {key[0] for key in texts} == {"decode", "chunk", "chunkgroup"}
+    for key, text in texts.items():
+        if cfg.rope:
+            assert text.count("stablehlo.sine") > 0, key
+            assert text.count(_table_type(cfg)) == 0, key
+        else:
+            assert text.count("stablehlo.sine") == 0, key

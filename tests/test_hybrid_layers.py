@@ -26,7 +26,7 @@ from tpu9.ops import delta_rule
 from tpu9.ops.latent_attention import (WAVE_PAGES, expanded_attention,
                                        pack_rotated, paged_latent_attention,
                                        unpack_rotated)
-from tpu9.ops.rotary import rope_table
+from tpu9.ops.rotary import rope_rows
 
 # the rehearsal's tiny widths: two periods of 3 in 6 layers (KDA, KDA, MLA),
 # layer 0 dense, 16 experts routed in 4 groups of which 2 are kept, top-4
@@ -138,9 +138,10 @@ def test_a_kda_layer_equals_the_reference(params, t):
 @pytest.mark.parametrize("t", [1, 40, 130])
 def test_an_mla_layer_equals_the_reference(params, t):
     p, n = params["layers"][2]["mla"], _normed(t + 7, t)
-    sin, cos = rope_table(t, SMALL.mla_rope, SMALL.rope_theta)
-    got, _ = hybrid.mla_block(p, n[None], SMALL, jnp.arange(t)[None], sin,
-                              cos, None, 0, None, False)
+    positions = jnp.arange(t)[None]
+    sin, cos = rope_rows(positions, SMALL.mla_rope, SMALL.rope_theta)
+    got, _ = hybrid.mla_block(p, n[None], SMALL, positions, sin, cos, None,
+                              0, None, False)
     want = reference._mla(p, n, _model())
     assert np.abs(np.asarray(got[0] - want)).max() < TOL
 

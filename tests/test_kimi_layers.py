@@ -21,7 +21,7 @@ from tpu9.models import decoder_forward, init_decoder
 from tpu9.models import kvstate
 from tpu9.models.transformer import DecoderConfig
 from tpu9.ops import latent_attention as la
-from tpu9.ops.rotary import rope_table, yarn_inv_freq, yarn_mscale
+from tpu9.ops.rotary import rope_rows, yarn_inv_freq, yarn_mscale
 
 YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 8, "mscale": 1,
         "mscale_all_dim": 1, "original_max_position_embeddings": 64,
@@ -177,12 +177,13 @@ def test_yarn_frequencies_against_the_closed_form(factor):
     assert np.allclose(got, want, rtol=1e-6)
     assert np.allclose(got[:low + 1], f[:low + 1], rtol=1e-6)
     assert np.allclose(got[high:], f[high:] / factor, rtol=1e-6)
-    sin, cos = rope_table(128, 64, theta, (factor, 4096, 32.0, 1.0))
+    sin, cos = rope_rows(jnp.arange(128), 64, theta,
+                         (factor, 4096, 32.0, 1.0))
     ang = np.arange(128)[:, None] * want[None, :]
     assert np.allclose(np.asarray(sin), np.sin(ang), atol=2e-5)
     assert np.allclose(np.asarray(cos), np.cos(ang), atol=2e-5)
     if factor == 1.0:
-        plain = rope_table(128, 64, theta)
+        plain = rope_rows(jnp.arange(128), 64, theta)
         assert np.allclose(np.asarray(plain[0]), np.asarray(sin), atol=1e-6)
 
 

@@ -58,6 +58,20 @@ class TestDecoder:
                 cache_len=jnp.array([i + 1]), decode=True)
             np.testing.assert_allclose(step_logits[:, 0], full[:, i], atol=2e-3)
 
+    def test_a_cache_longer_than_the_models_positions_is_refused(self):
+        # at trace time, from the shapes: the model has no such positions
+        cfg = f32(TINY)
+        params = init_decoder(jax.random.PRNGKey(0), cfg)
+        cache = init_kv_cache(cfg, 1, cfg.max_seq_len + 16)
+        with pytest.raises(ValueError, match=f"exceeds the model's "
+                                             f"{cfg.max_seq_len} positions"):
+            decoder_forward(params, jnp.array([[1, 2, 3]]), cfg,
+                            kv_cache=cache)
+        logits, _ = decoder_forward(params, jnp.array([[1, 2, 3]]), cfg,
+                                    kv_cache=init_kv_cache(cfg, 1,
+                                                           cfg.max_seq_len))
+        assert logits.shape == (1, 3, cfg.vocab_size)
+
     def test_gemma_forward_and_tied_head(self):
         cfg = f32(GTINY)
         params = init_decoder(jax.random.PRNGKey(0), cfg)

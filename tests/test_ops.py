@@ -4,7 +4,8 @@ import numpy as np
 import pytest
 
 from tpu9.ops import (apply_rope, decode_attention, flash_attention, rms_norm,
-                      rope_table, sample_logits, xla_attention)
+                      rope_rows, sample_logits, xla_attention)
+from tpu9.ops.rotary import yarn_inv_freq
 
 
 def rand(shape, seed=0, dtype=jnp.float32):
@@ -52,32 +53,93 @@ class TestAttention:
         np.testing.assert_allclose(full[:, 16:], tail, atol=1e-5)
 
 
+def rope_table(max_len, head_dim, theta=10000.0, yarn=()):
+    """The oracle ``rope_rows`` is held to: the table every program built
+    inside itself until PR 60, (sin, cos) each [max_len, head_dim//2], f32,
+    a row a position the model could hold."""
+    half = head_dim // 2
+    if yarn:
+        freqs = yarn_inv_freq(half, theta, *yarn)
+    else:
+        freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32)
+                                 / half))
+    angles = jnp.arange(max_len, dtype=jnp.float32)[:, None] * freqs[None, :]
+    return jnp.sin(angles), jnp.cos(angles)
+
+
+def apply_rope_table(x, positions, sin, cos):
+    """The rotation as it was: the tables' rows gathered by ``positions``."""
+    s = sin[positions].astype(jnp.float32)[..., None, :]
+    c = cos[positions].astype(jnp.float32)[..., None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    return out.astype(x.dtype)
+
+
+# ``rope_scaling`` of kimi-k2.6-l6-ep32 as ``families/kimi.py`` hands it on
+KIMI_YARN = (64.0, 4096, 32.0, 1.0)
+MAX_POSITIONS = 262144
+
+
 class TestRope:
     def test_rotation_preserves_norm(self):
-        sin, cos = rope_table(128, 32)
         x = rand((2, 16, 4, 32))
         pos = jnp.broadcast_to(jnp.arange(16), (2, 16))
-        y = apply_rope(x, pos, sin, cos)
+        y = apply_rope(x, *rope_rows(pos, 32))
         np.testing.assert_allclose(jnp.linalg.norm(y, axis=-1),
                                    jnp.linalg.norm(x, axis=-1), rtol=1e-5)
 
     def test_position_zero_identity(self):
-        sin, cos = rope_table(8, 16)
         x = rand((1, 1, 2, 16))
-        y = apply_rope(x, jnp.zeros((1, 1), jnp.int32), sin, cos)
+        y = apply_rope(x, *rope_rows(jnp.zeros((1, 1), jnp.int32), 16))
         np.testing.assert_allclose(y, x, atol=1e-6)
 
     def test_relative_property(self):
         # <rope(q, m), rope(k, n)> depends only on m - n
-        sin, cos = rope_table(64, 32)
         q, k = rand((1, 1, 1, 32)), rand((1, 1, 1, 32), 1)
 
         def dot_at(m, n):
-            qr = apply_rope(q, jnp.array([[m]]), sin, cos)
-            kr = apply_rope(k, jnp.array([[n]]), sin, cos)
+            qr = apply_rope(q, *rope_rows(jnp.array([[m]]), 32))
+            kr = apply_rope(k, *rope_rows(jnp.array([[n]]), 32))
             return float(jnp.sum(qr * kr))
 
         assert abs(dot_at(5, 3) - dot_at(10, 8)) < 1e-4
+
+    @pytest.mark.parametrize("head_dim", [64, 128])
+    @pytest.mark.parametrize("yarn", [(), KIMI_YARN], ids=["plain", "yarn"])
+    @pytest.mark.parametrize("theta", [1e4, 1e6, 6e6])
+    def test_the_rows_are_the_tables_rows_bit_for_bit(self, theta, yarn,
+                                                      head_dim):
+        """``rope_rows`` of a position IS that row of the table, and the
+        rotation by the rows IS the rotation by the gathered rows: ``==``,
+        like for like — jitted both (as the programs are) or eager both
+        (XLA's own ``theta ** x`` is not the eager op's to the last bit)."""
+        rng = np.random.default_rng(int(theta) + head_dim + len(yarn))
+        batches = (jnp.array([0, 1, 4095, 131071, 262143], jnp.int32),
+                   jnp.asarray(rng.integers(0, MAX_POSITIONS, (3, 40)),
+                               jnp.int32),
+                   jnp.asarray(rng.integers(0, MAX_POSITIONS, (16, 1)),
+                               jnp.int32))
+
+        def eager(fn, **_):
+            return fn
+
+        for run in (eager, jax.jit):
+            table = run(rope_table, static_argnums=(0, 1, 2, 3))(
+                MAX_POSITIONS, head_dim, theta, yarn)
+            rows = run(rope_rows, static_argnums=(1, 2, 3))
+            for positions in batches:
+                sin, cos = rows(positions, head_dim, theta, yarn)
+                assert sin.dtype == cos.dtype == jnp.float32
+                assert sin.shape == positions.shape + (head_dim // 2,)
+                assert np.array_equal(sin, table[0][positions])
+                assert np.array_equal(cos, table[1][positions])
+                for dtype in (jnp.bfloat16, jnp.float32):
+                    x = rand(positions.shape + (4, head_dim), 7, dtype)
+                    got = run(apply_rope)(x, sin, cos)
+                    assert got.dtype == dtype
+                    assert np.array_equal(
+                        got, run(apply_rope_table)(x, positions, *table))
 
 
 class TestNormSampling:

@@ -465,7 +465,7 @@ def test_fused_admission_dispatch_count(tiny):
     not 32 chunk+splice calls — and zero host syncs inside admission (the
     loop's single firsts-sync is the only one)."""
     cfg, params = tiny
-    cfg = replace(cfg, max_seq_len=2048)    # the rope table: no parameter
+    cfg = replace(cfg, max_seq_len=2048)    # the positions: no parameter
     paged = InferenceEngine(params, cfg, EngineConfig(
         max_batch=2, max_seq_len=2048, prefill_buckets=(128,),
         decode_steps=(1, 4), kv_block_size=128, kv_pool_blocks=40,

@@ -23,7 +23,7 @@ from tpu9.ops.paged_attention import (paged_decode_attention,
                                       paged_decode_attention_quant,
                                       xla_paged_decode_attention)
 from tpu9.ops.quant import maybe_matmul, quantize_kv
-from tpu9.ops.rotary import apply_rope, rope_table
+from tpu9.ops.rotary import apply_rope, rope_rows
 from tpu9.serving.engine import EngineConfig, InferenceEngine
 from tpu9.serving.graphs import GraphFactory
 from tpu9.serving.shard.policy import SingleDevicePolicy
@@ -142,7 +142,7 @@ def _slice_and_stack_forward(params, tokens, cfg, positions, cache,
     table, bs = cache["table"], cache["k"].shape[2]
     names = [n for n in cache if n != "table"]
     x = params["embed"][tokens].astype(cfg.dtype)
-    sin, cos = rope_table(cfg.max_seq_len, cfg.head_dim, cfg.rope_theta)
+    sin, cos = rope_rows(positions, cfg.head_dim, cfg.rope_theta)
     bi = jnp.take_along_axis(table, positions // bs, axis=1)      # [B, T]
     oi = positions % bs
     planes = {n: [] for n in names}
@@ -153,8 +153,8 @@ def _slice_and_stack_forward(params, tokens, cfg, positions, cache,
                    for w, heads in (("wq", cfg.n_heads),
                                     ("wk", cfg.n_kv_heads),
                                     ("wv", cfg.n_kv_heads)))
-        q = apply_rope(q, positions, sin, cos)
-        new = {"k": apply_rope(k, positions, sin, cos), "v": v}
+        q = apply_rope(q, sin, cos)
+        new = {"k": apply_rope(k, sin, cos), "v": v}
         if "k_scale" in cache:
             new["k"], new["k_scale"] = quantize_kv(new["k"])
             new["v"], new["v_scale"] = quantize_kv(new["v"])

@@ -310,8 +310,8 @@ def mla_block(p: dict, h: jnp.ndarray, cfg, positions, sin, cos,
             gate = jax.nn.sigmoid(maybe_matmul(h, p["w_gate"]).astype(F32))
     with jax.named_scope("attn.rope"):
         q_nope = q[..., :dn]
-        q_rope = apply_rope(q[..., dn:], positions, sin, cos)
-        k_rope = apply_rope(down[..., None, dc:], positions, sin,
+        q_rope = apply_rope(q[..., dn:], sin, cos)
+        k_rope = apply_rope(down[..., None, dc:], sin,
                             cos)[..., 0, :]                  # [B, T, dr]
     w_ukv = p["w_ukv"].reshape(dc, heads, dn + dv)
 

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from ..ops.attention import attention
 from ..ops.norms import rms_norm
 from ..ops.quant import maybe_matmul
-from ..ops.rotary import apply_rope, rope_table
+from ..ops.rotary import apply_rope, rope_rows
 from . import kvstate
 
 Params = dict[str, Any]
@@ -152,7 +152,7 @@ class DecoderConfig:
     # while the router and the shared expert read the full width (0 = none)
     moe_gated: bool = True
     moe_latent_dim: int = 0
-    # plain attention without positions (False: no rotary, no table), and
+    # plain attention without positions (False: no rotary, no angles), and
     # at a softmax scale of its own (0 = ``head_dim ** -0.5``)
     rope: bool = True
     attn_scale: float = 0.0
@@ -541,8 +541,8 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
             b, t, cfg.n_kv_heads, cfg.head_dim)
     if cfg.rope:
         with jax.named_scope("attn.rope"):
-            q = apply_rope(q, positions, sin, cos)
-            k = apply_rope(k, positions, sin, cos)
+            q = apply_rope(q, sin, cos)
+            k = apply_rope(k, sin, cos)
     if cfg.attn_scale:
         # the kernels fix ``head_dim ** -0.5``: the queries carry the rest,
         # a power of two (``ssm.refuse_unbuilt_list``), so this product
@@ -836,22 +836,22 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
         if cfg.embed_mult != 1.0:
             x = x * jnp.asarray(cfg.embed_mult, dtype=cfg.dtype)
 
-    # the rope table must cover every cache slot: positions past the table
-    # are CLAMPED by JAX's gather, rotating distinct positions identically
-    # (silent long-context degradation, no error) — catch the static-shape
-    # mismatch at trace time instead
-    rope_len = cfg.max_seq_len
+    # a cache longer than the model's positions (``max_seq_len``) is a
+    # configuration error: its far slots are positions the model was never
+    # trained to hold — refuse the static-shape mismatch at trace time
     if kv_cache is not None:
         cache_s = kvstate.dense_len(kv_cache)
-        if cache_s > rope_len:
+        if cache_s > cfg.max_seq_len:
             raise ValueError(
-                f"kv cache length {cache_s} exceeds rope table "
-                f"{rope_len} — positions past it would alias")
+                f"kv cache length {cache_s} exceeds the model's "
+                f"{cfg.max_seq_len} positions")
+    # the angles of the rows this forward feeds, once: every layer and every
+    # pass of a looped decoder rotates by the one pair
     sin = cos = None
     if cfg.rope:
         with jax.named_scope("attn.rope"):
-            sin, cos = rope_table(rope_len, cfg.mla_rope or cfg.head_dim,
-                                  cfg.rope_theta, cfg.rope_yarn)
+            sin, cos = rope_rows(positions, cfg.mla_rope or cfg.head_dim,
+                                 cfg.rope_theta, cfg.rope_yarn)
 
     moe_balance = jnp.zeros((), jnp.float32)
     exit_info = moe_picks = None

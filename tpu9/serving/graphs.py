@@ -565,8 +565,9 @@ class GraphFactory:
                 # pool [L, N, BS, KH, D], row [MB] → dense [L, 1, S, KH,
                 # D]. The row's final column is the ALWAYS-TRASH block —
                 # slice it off so the densified prefix has the exact
-                # scratch shape (an S+BS-wide scratch trips the rope-table
-                # width validation when max_seq_len == the rope limit)
+                # scratch shape (an S+BS-wide scratch trips the forward's
+                # refusal of a cache longer than the model's positions
+                # when max_seq_len == that limit)
                 def one(name):
                     g = kvstate.read_blocks(pool, name, row, flat)
                     l, mb_, bs, kh, d = g.shape      # [L, MB, BS, KH, D]
