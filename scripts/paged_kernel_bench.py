@@ -34,13 +34,17 @@ BS = D = 128
 CELL_SHAPES = {"mistral-tp4-long": (8, 8, 4, 129, 897, 32, 4),
                "ouro-qa": (16, 16, 1, 9, 31, 192, 1),
                "mixtral": (32, 8, 4, 33, 513, 4, 1),
-               "evabyte-files": (16, 32, 1, 24, 216, 16, 1)}
+               "evabyte-files": (16, 32, 1, 24, 216, 16, 1),
+               # (eight 64-wide KV heads packed two a 128-lane row: four
+               # rows, each read by eight query heads; three planes)
+               "lfm2-sessions": (32, 4, 8, 261, 4481, 3, 1)}
 # resident entries of a cell's live lanes in a decode step (``mixtral``:
 # the batch cell's); the other slots are empty
 LIVE = {"mistral-tp4-long": np.linspace(8300, 12000, 8),
         "ouro-qa": (200, 450, 700),
         "mixtral": np.linspace(128, 1280, 32),
-        "evabyte-files": (1300, 1600, 3072)}
+        "evabyte-files": (1300, 1600, 3072),
+        "lfm2-sessions": np.linspace(8500, 30000, 24)}
 
 
 def case(name, seed=0, edges=False):

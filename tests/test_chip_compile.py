@@ -834,6 +834,51 @@ def test_a_list_of_half_layers_holds_its_kernels_at_the_published_widths(
     assert seen["lanesplice"] == []
 
 
+def test_a_list_around_short_convolutions_holds_its_kernels_at_the_published_widths(
+        v5e, no_compile_cache, monkeypatch):
+    """``lfm2-8b-a1b-l14`` at its engine's shapes, a layer of each kind deep
+    (a conv layer closed by the dense SwiGLU, then attention and a conv layer
+    closed by experts, ISSUE 62): a decode step is one
+    ``paged_decode_attention`` — the call the benchmark counts its steps by —
+    over a pool of 64-wide heads two a 128-lane row, one gated ``held_ffn``
+    an expert layer, and NO kernel for the mixers; a chunk and a group attend
+    in the chunk kernel and sort their rows into ``grouped_ffn``; the splice
+    carries the pages' tails in place, the restore reads one page's; beside
+    its tokens a window returns the picks of its expert layers; no program
+    copies the pool, the tails' plane or an expert stack."""
+    from dataclasses import replace
+
+    from tpu9.models import kvstate
+    cfg, family, _, pool, jobs = _decode_programs(
+        v5e, monkeypatch, "lfm2-8b-a1b-l14",
+        kinds=("decode", "chunk", "chunkgroup", "s", "t", "l"),
+        cut=lambda c: replace(c, n_layers=3, moe_dense_layers=1,
+                              layer_pattern=("conv", "full", "conv")))
+    assert pool.shape == (1, 4481, 128, 4, 128)
+    tails = kvstate.block_tail_shapes(cfg, 4481)[kvstate.BLOCK_TAIL][0]
+    assert tails == (2, 4481, 2, 2048)
+    seen = {}
+    for key, fn, args in jobs:
+        compiled = fn.lower(*args).compile()
+        text = compiled.as_text()
+        seen[key] = sorted(_kernel_names(text))
+        assert not re.search(
+            r"bf16\[32,(2048,1792|1792,2048)\]\S* copy\(", text), key
+        assert not re.search(r"= bf16\[2,4481,2,2048\]\S* copy\(", text), key
+        if key[0] == "decode":
+            assert not _pool_copies(text, 4481), key
+            assert compiled.memory_analysis().temp_size_in_bytes \
+                < 256 * 2 ** 20, key
+            picks = jax.tree_util.tree_leaves(fn.eval_shape(*args))[-1]
+            assert picks.shape == (key[1], 32, 2, 4)
+    for k in (1, 8):
+        assert seen[("decode", k)] == sorted(
+            [family.STEP_MARKER] + [family.EXPERT_STEP_KERNEL] * 2)
+    for key in (("chunk", 512), ("chunkgroup", 4)):
+        assert seen[key] == sorted([CHUNK_KERNEL] + ["grouped_ffn"] * 2)
+    assert seen["splice"] == seen["tailrestore"] == seen["lanesplice"] == []
+
+
 def test_a_looped_decode_program_carries_the_pool_through_its_pass_loop(
         v5e, no_compile_cache, monkeypatch):
     """The looped configuration at its published widths, two layers deep:

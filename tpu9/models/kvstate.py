@@ -46,8 +46,21 @@ new kind of cache is written here.
   last inputs
   ``"ssm_conv"`` ``[P, lanes, K-1, H d + 2 G N]`` (``P`` such layers of a
   listed pattern), beside PER-HEAD rows. No table, no pages; the dense
-  scratch carries one lane of it. ``LANE_KINDS`` names the two arrays of
-  each kind; ``DecoderConfig.lane_state`` says which kinds a decoder has.
+  scratch carries one lane of it. A listed pattern's gated short
+  convolutions (``"conv"``) keep ONE array, ``"conv_tail"`` ``[P, lanes, K-1,
+  D]``: the last ``conv_taps - 1`` rows of the product they convolve — for
+  them the tail IS the state. ``LANE_KINDS`` names the arrays of each kind
+  (a state where the kind has one, then its convolution's tail);
+  ``DecoderConfig.lane_state`` says which kinds a decoder has.
+- state a BLOCK, beside such a tail: ``BLOCK_TAIL`` ``[P, n_blocks, K-1,
+  D]`` in a pool — for every page, every convolution layer's tail as of the
+  page's LAST row, which is what a sequence admitted behind that page (a
+  prefix hit) starts from — and ``[P, lanes, rows / block, K-1, D]`` in the
+  batch-1 scratch, where a chunk's forward leaves the tails of the pages it
+  fills (:func:`block_tails_write`) for the splice to carry into the pool
+  (:func:`splice_block_tails`; :func:`block_tail_read` is the hit's read).
+  The first plane that is neither a row a token nor state a lane. A decode
+  step writes none: the prefix cache shares PROMPT pages alone.
 
 **The verbs.** :func:`write` puts a layer's fresh rows where their entries
 say and :func:`attend` runs the layer's queries over what is written, through
@@ -79,9 +92,14 @@ from ..ops.quant import quantize_kv
 from ..ops.ssd import state_shape as ssd_state_shape
 
 TABLE = "table"
-# the two arrays a kind of layer keeps by LANE: (state, convolution tail)
+# the arrays a kind of layer keeps by LANE: (state, convolution tail), or the
+# tail alone where that is all the state there is (a short convolution's)
 LANE_KINDS = {"kda": ("kda_state", "kda_conv"),
-              "ssm": ("ssm_state", "ssm_conv")}
+              "ssm": ("ssm_state", "ssm_conv"),
+              "conv": ("conv_tail",)}
+# the plane a pool keeps a BLOCK: every convolution layer's tail as of the
+# block's last row
+BLOCK_TAIL = "conv_block_tail"
 # of every paged array, pool and dense alike: [depth, ., ., KH, ...]
 HEAD_AXIS = 3
 _SCALE = {"k": "k_scale", "v": "v_scale"}
@@ -126,6 +144,9 @@ def lane_shapes(cfg, lanes: int) -> dict:
     pattern's state-space layers, keep for ``lanes`` running sequences;
     empty for a decoder without such layers."""
     if cfg.layer_pattern:
+        if cfg.conv_taps:
+            return {"conv_tail": ((len(cfg.layers_of("conv")), lanes,
+                                   cfg.conv_taps - 1, cfg.dim), cfg.dtype)}
         planes = len(cfg.layers_of("ssm"))
         if not planes:
             return {}
@@ -146,13 +167,31 @@ def lane_shapes(cfg, lanes: int) -> dict:
                          cfg.dtype)}
 
 
-def dense_shapes(cfg, lanes: int, rows: int, dtype=None) -> dict:
+def block_tail_shapes(cfg, n_blocks: int, lanes: int = 0) -> dict:
+    """``name -> (shape, dtype)`` of the state kept a BLOCK, for a pool of
+    ``n_blocks`` blocks (``lanes`` 0) or a dense cache of as many a lane:
+    the tails of a listed pattern's short convolutions (the module's head);
+    empty for every other decoder."""
+    if not cfg.conv_taps:
+        return {}
+    blocks = (lanes, n_blocks) if lanes else (n_blocks,)
+    return {BLOCK_TAIL: ((len(cfg.layers_of("conv")),) + blocks
+                         + (cfg.conv_taps - 1, cfg.dim), cfg.dtype)}
+
+
+def dense_shapes(cfg, lanes: int, rows: int, dtype=None,
+                 block: int = 0) -> dict:
     """``name -> (shape, dtype)`` of a dense cache of ``rows`` entries a
     lane — the dense engine's, the batch-1 scratch that chunked prefill
-    writes through, a prefill bucket — with the lanes' state beside it."""
+    writes through, a prefill bucket — with the lanes' state beside it, and
+    where the cache is cut into pages of ``block`` entries (the scratch) the
+    state a block."""
     shapes = {name: ((cfg.kv_layers, lanes, rows) + row, dtype or dt)
               for name, (row, dt) in paged_planes(cfg).items()}
-    return {**shapes, **lane_shapes(cfg, lanes)}
+    if block and rows % block:
+        raise ValueError(f"a dense cache of {rows} rows in pages of {block}")
+    return {**shapes, **lane_shapes(cfg, lanes),
+            **(block_tail_shapes(cfg, rows // block, lanes) if block else {})}
 
 
 def _bytes(shapes: dict) -> int:
@@ -174,6 +213,12 @@ def lane_bytes(cfg, lanes: int = 1) -> int:
     return _bytes(lane_shapes(cfg, lanes))
 
 
+def block_tail_bytes(cfg, n_blocks: int = 1) -> int:
+    """Bytes of :func:`block_tail_shapes`: what ``n_blocks`` pool blocks
+    hold BESIDE their rows (:func:`block_bytes` prices those)."""
+    return _bytes(block_tail_shapes(cfg, n_blocks))
+
+
 def head_axis(name: str, ndim: int) -> Optional[int]:
     """The axis of state array ``name`` (of rank ``ndim``) that holds the KV
     heads, which a mesh shards; None for the table and the lanes' state."""
@@ -181,12 +226,13 @@ def head_axis(name: str, ndim: int) -> Optional[int]:
     return HEAD_AXIS if paged and ndim > HEAD_AXIS else None
 
 
-def init_kv_cache(cfg, batch: int, max_len: int = 0, dtype=None) -> dict:
+def init_kv_cache(cfg, batch: int, max_len: int = 0, dtype=None,
+                  block: int = 0) -> dict:
     """Contiguous per-sequence KV cache: k/v ``[L, B, S, *row]``, ``L`` the
-    depth of the KV state (``cfg.kv_layers``), and the KDA layers' state
-    beside it (:func:`dense_shapes`)."""
+    depth of the KV state (``cfg.kv_layers``), and the state kept a lane —
+    and, in pages of ``block``, a block — beside it (:func:`dense_shapes`)."""
     return {name: jnp.zeros(shape, dt) for name, (shape, dt) in dense_shapes(
-        cfg, batch, max_len or cfg.max_seq_len, dtype).items()}
+        cfg, batch, max_len or cfg.max_seq_len, dtype, block).items()}
 
 
 # -- heads packed to whole rows -------------------------------------------------
@@ -509,9 +555,9 @@ def latent_planes(kv: dict):
 
 def lane_read(kv: dict, plane: int, kind: str = "kda"):
     """``(state [B, H, ., .], convolution tail [B, K-1, channels])`` of the
-    ``plane``-th layer of ``kind`` (``LANE_KINDS``), every lane's."""
-    state, conv = LANE_KINDS[kind]
-    return kv[state][plane], kv[conv][plane]
+    ``plane``-th layer of ``kind`` (``LANE_KINDS``), every lane's; ``(tail,)``
+    of a kind that keeps the one array."""
+    return tuple(kv[name][plane] for name in LANE_KINDS[kind])
 
 
 def lane_states(kv: dict, kind: str = "kda"):
@@ -524,8 +570,55 @@ def lane_write(kv: dict, plane: int, tail, state=None, states=None,
                kind: str = "kda") -> dict:
     """``kv`` with the state and convolution tail of the ``plane``-th layer
     of ``kind`` replaced: ``state`` ``[B, H, ., .]`` written at the plane, or
-    ``states`` the whole array as a step in place left it."""
-    name, conv = LANE_KINDS[kind]
-    if states is None:
-        states = kv[name].at[plane].set(state)
-    return dict(kv, **{name: states, conv: kv[conv].at[plane].set(tail)})
+    ``states`` the whole array as a step in place left it; the tail alone for
+    a kind that keeps the one array."""
+    *name, conv = LANE_KINDS[kind]
+    if name and states is None:
+        states = kv[name[0]].at[plane].set(state)
+    return dict(kv, **dict.fromkeys(name, states),
+                **{conv: kv[conv].at[plane].set(tail)})
+
+
+# -- state a block --------------------------------------------------------------
+
+def block_tails_write(kv: dict, plane: int, z, first):
+    """The dense scratch ``kv`` with the tails of the pages a chunk fills:
+    ``z`` ``[1, T, D]`` is what the ``plane``-th convolution layer convolves
+    over the chunk's ``T`` rows, which start at entry ``first`` (a page's
+    first row: chunks are whole pages). The tail as of a page's last row is
+    that page's own last ``K - 1`` rows of ``z`` — a strided read of a tensor
+    the forward has in hand. A cache that keeps no state a block (a test's
+    dense cache, a decode step's pool) comes back as it is."""
+    if BLOCK_TAIL not in kv or is_paged(kv):
+        return kv
+    tails = kv[BLOCK_TAIL]                     # [P, 1, S / BS, K-1, D]
+    block, keep = dense_len(kv) // tails.shape[2], tails.shape[3]
+    b, t, d = z.shape
+    if t % block or keep > block:
+        raise ValueError(f"a chunk of {t} rows over pages of {block} with "
+                         f"tails of {keep}: whole pages, each at least as "
+                         "long as a tail")
+    ends = z.reshape(b, t // block, block, d)[:, :, block - keep:]
+    with jax.named_scope("kv.write"):
+        return dict(kv, **{BLOCK_TAIL: jax.lax.dynamic_update_slice(
+            tails, ends.astype(tails.dtype)[None],
+            (plane, 0, first // block, 0, 0))})
+
+
+def splice_block_tails(pool: dict, scratch_tails, first_page, phys) -> dict:
+    """``pool`` with the tails of the scratch's pages ``first_page ..
+    first_page + len(phys) - 1`` (``scratch_tails`` ``[P, 1, S / BS, K-1,
+    D]``) written at the physical blocks ``phys`` ``[n]``, every convolution
+    layer's in one scatter (the trash block may stand several times in
+    ``phys``: whichever page lands there is never read)."""
+    pages = jax.lax.dynamic_slice_in_dim(scratch_tails[:, 0], first_page,
+                                         phys.shape[0], axis=1)
+    return dict(pool, **{BLOCK_TAIL: pool[BLOCK_TAIL].at[:, phys].set(pages)})
+
+
+def block_tail_read(plane, block):
+    """Every convolution layer's tail as of the last row of physical block
+    ``block`` of a pool's ``BLOCK_TAIL`` ``plane``, as one lane of state
+    ``[P, 1, K-1, D]``: what a sequence admitted behind that page starts
+    from."""
+    return jax.lax.dynamic_index_in_dim(plane, block, axis=1)

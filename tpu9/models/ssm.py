@@ -62,7 +62,7 @@ F32 = jnp.float32
 # projection in, the convolution, ``dt`` and the gate / the recurrence (a
 # decode step or a prefill's scan over blocks)
 SSM_SCOPES = ("attn.ssm.proj", "attn.ssm.state")
-KINDS = ("ssm", "full")
+KINDS = ("ssm", "full", "conv")
 # what ``ffn_pattern`` may say of a layer (and ``layer_pattern``, beside
 # ``KINDS``, where it is stated): the half that is there, or "none"
 HALF_KINDS = ("experts", "none")
@@ -106,6 +106,13 @@ def refuse_unbuilt_list(cfg) -> None:
                "ffn_pattern", "they are a list's expert layers': no layer "
                "would read them")
     sizes = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_conv)
+    conv = "conv" in cfg.layer_pattern
+    if (cfg.conv_taps or cfg.qk_norm) and not conv:
+        refuse(f"conv_taps={cfg.conv_taps}, qk_norm={cfg.qk_norm} without a "
+               "\"conv\" layer in a layer_pattern",
+               "the taps are a short convolution's, and the norm a head on "
+               "queries and keys was built and run with that list's rotary "
+               "attention alone: no other layer would read them")
     if not cfg.layer_pattern:
         if any(sizes) or cfg.ssm_groups != 1 or cfg.ssm_norm_groups != 1 \
                 or halves:
@@ -124,18 +131,21 @@ def refuse_unbuilt_list(cfg) -> None:
         refuse(f"{what} with layer_group={cfg.layer_group}",
                "a pattern is stated once, as the rule or as the list")
     if cfg.looped or cfg.attn_window or cfg.sandwich_norm \
-            or (cfg.n_experts and not halves):
+            or (cfg.n_experts and not (halves or conv)):
         refuse(f"{what} with a pass loop, attn_window, sandwich_norm or "
                "experts", "a lane's state would need a plane a pass, a "
                "window's summarise knows no pool of fewer planes than "
                "layers, and no listed pattern was run with output norms; an "
-               "expert layer is built for a list that states its ffn_pattern")
+               "expert layer is built for a list that states its ffn_pattern "
+               "or, in whole layers, for one around short convolutions")
     if cfg.embed_scale or cfg.logit_softcap or cfg.norm_offset \
             or cfg.act != ("relu2" if halves else "silu"):
         refuse(f"{what} with a descriptor of another family (sqrt(dim) "
                "embeddings, soft-capped logits, offset norms, gelu; relu2 "
                "is the ungated experts' of an ffn_pattern, and only theirs)",
                "no served model has both; not run")
+    if conv:
+        return _refuse_unbuilt_conv_list(cfg, what, sizes, refuse)
     if halves:
         _refuse_unbuilt_halves(cfg, what, refuse)
     if "ssm" not in cfg.layer_pattern:
@@ -154,6 +164,54 @@ def refuse_unbuilt_list(cfg) -> None:
         refuse(f"{what} with ssm_norm_groups={cfg.ssm_norm_groups}",
                "the gated norm's groups are whole heads: they divide "
                f"ssm_heads={cfg.ssm_heads}")
+
+
+def _refuse_unbuilt_conv_list(cfg, what: str, sizes, refuse) -> None:
+    """A listed pattern with ``"conv"`` layers: what is built is a list of
+    WHOLE layers — gated short convolutions around plain rotary attention,
+    each closed by the rule's feed-forward part (``moe_dense_layers`` dense,
+    then expert layers that hold all they route over) — and nothing wider."""
+    what = f"{what} with \"conv\" layers"
+    if cfg.ffn_pattern or "ssm" in cfg.layer_pattern or any(sizes) \
+            or cfg.ssm_groups != 1 or cfg.ssm_norm_groups != 1:
+        refuse(f"{what} and an ffn_pattern, \"ssm\" layers or ssm sizes",
+               "short convolutions were built and run in whole layers "
+               "beside plain attention alone: half-layers and a second kind "
+               "of state a lane beside theirs were never run")
+    if "full" not in cfg.layer_pattern:
+        refuse(f"{what} and no \"full\" layer",
+               "a pool of no plane: no program pages nothing, not run")
+    if cfg.conv_taps < 2:
+        refuse(f"{what}, conv_taps={cfg.conv_taps}",
+               "at least 2 taps: the state is the taps-less-one rows before")
+    if not cfg.rope or cfg.attn_scale or cfg.embed_mult != 1.0 \
+            or cfg.residual_mult != 1.0 or cfg.logit_div != 1.0:
+        refuse(f"{what} without rotary, or with a scale or multiplier",
+               "their attention was built and run with positions at "
+               "head_dim ** -0.5 and a plain stream; no served model has "
+               "both")
+    if not cfg.n_experts:
+        if cfg.moe_dense_layers:
+            refuse(f"{what}, moe_dense_layers={cfg.moe_dense_layers} and no "
+                   "experts", "no layer would read it")
+        return
+    from .hybrid import refuse_unbuilt_share
+    refuse_unbuilt_share(cfg, refuse)
+    if cfg.moe_routed != cfg.n_experts or cfg.moe_held_first \
+            or cfg.moe_shared_dim or cfg.moe_latent_dim or not cfg.moe_gated \
+            or cfg.moe_groups:
+        refuse(f"{what}, {cfg.n_experts} of moe_routed={cfg.moe_routed} "
+               f"experts from {cfg.moe_held_first}, moe_shared_dim="
+               f"{cfg.moe_shared_dim}, moe_latent_dim={cfg.moe_latent_dim}, "
+               f"moe_gated={cfg.moe_gated}, moe_groups={cfg.moe_groups}",
+               "the expert layers of whole listed layers hold every expert "
+               "they route over (moe_routed = n_experts), gated, at the "
+               "model's width, with no shared expert and no groups: the "
+               "only form run")
+    if not 0 <= cfg.moe_dense_layers < cfg.n_layers:
+        refuse(f"{what}, moe_dense_layers={cfg.moe_dense_layers}",
+               "a leading run of dense layers, then at least one expert "
+               "layer")
 
 
 def _refuse_unbuilt_halves(cfg, what: str, refuse) -> None:
@@ -224,6 +282,9 @@ def init_listed_layer(rng: jax.Array, cfg, l: int) -> dict:
             "d_skip": jnp.ones((h,), F32),
             "norm": jnp.ones((inner,), F32),
             "w_out": _dense(next(r), inner, d, dt)}
+    elif kind == "conv":
+        from .shortconv import init_conv_mixer
+        layer["conv"] = init_conv_mixer(next(r), cfg)
     elif kind == "full":
         q_dim, kv_dim = cfg.n_heads * cfg.head_dim, \
             cfg.n_kv_heads * cfg.head_dim
@@ -231,6 +292,9 @@ def init_listed_layer(rng: jax.Array, cfg, l: int) -> dict:
                      wk=_dense(next(r), d, kv_dim, dt),
                      wv=_dense(next(r), d, kv_dim, dt),
                      wo=_dense(next(r), q_dim, d, dt))
+        if cfg.qk_norm:
+            layer.update(q_norm=jnp.ones((cfg.head_dim,), F32),
+                         k_norm=jnp.ones((cfg.head_dim,), F32))
     if ffn == "experts":
         from .moe import init_moe_layer
         from .transformer import moe_cfg

@@ -161,6 +161,17 @@ class DecoderConfig:
     embed_mult: float = 1.0
     residual_mult: float = 1.0
     logit_div: float = 1.0
+    # a third kind of a listed layer, ``"conv"`` (``models.shortconv``): a
+    # gated short convolution of ``conv_taps`` taps a channel, whose whole
+    # state is the last ``conv_taps - 1`` rows it convolved — kept a LANE and,
+    # for the prefix cache, a BLOCK (``models.kvstate``). Such a list is of
+    # WHOLE layers (no ``ffn_pattern``): a mixer or rotary attention, then
+    # the rule's feed-forward part — ``moe_dense_layers`` dense, then expert
+    # layers that hold every expert they route over (0 = no such layer)
+    conv_taps: int = 0
+    # an RMSNorm a head, with a weight, on the plain attention's queries and
+    # keys before the rotation (a list with ``"conv"`` layers alone)
+    qk_norm: bool = False
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self):
@@ -198,19 +209,21 @@ class DecoderConfig:
         if self.layer_group:
             from .hybrid import refuse_unbuilt_pattern
             refuse_unbuilt_pattern(self)
-        elif (self.mla_latent or self.kda_conv or self.moe_dense_layers
-              or self.mla_q_latent or self.rope_yarn
-              or self.mla_mscale != 1.0
+        elif (self.mla_latent or self.kda_conv or self.mla_q_latent
+              or self.rope_yarn or self.mla_mscale != 1.0
+              or (self.moe_dense_layers and not self.conv_taps)
               or ((self.moe_routed or self.moe_shared_dim
                    or self.moe_score != "softmax")
-                  and "experts" not in self.ffn_pattern)):
+                  and "experts" not in self.ffn_pattern
+                  and not self.conv_taps)):
             raise ValueError(
                 "latent attention (its query latent, YaRN positions and "
                 "temperature with it), the delta rule and the expert "
                 "layer's share, shared expert and sigmoid gates are built "
-                "for a layer pattern only (layer_group > 0, or a listed "
+                "for a layer pattern only (layer_group > 0, a listed "
                 "pattern whose ffn_pattern says which layers are expert "
-                "layers): no served model has one without the other")
+                "layers, or a list of whole layers around short "
+                "convolutions): no served model has one without the other")
 
     @property
     def q_per_kv(self) -> int:
@@ -223,7 +236,7 @@ class DecoderConfig:
         ``"full"`` (the plain attention of a uniform decoder), ``"kda"`` or
         ``"mla"`` (the last layer of each group of ``layer_group``: every
         layer where a group is one layer), or what ``layer_pattern`` lists
-        for the layer (``"ssm"``, ``"full"`` or ``"none"``); the ffn is
+        for the layer (``"ssm"``, ``"conv"``, ``"full"`` or ``"none"``); the ffn is
         ``"dense"`` or ``"experts"``, or what ``ffn_pattern`` lists
         (``"none"`` too: a layer of one half). THE one place that knows the
         pattern: ``init_decoder``, the forward pass, the pool's depth and
@@ -271,7 +284,7 @@ class DecoderConfig:
         if not (self.layer_group or self.layer_pattern):
             return ()
         kinds = [self.layer_kind(l)[0] for l in range(self.n_layers)]
-        return tuple(k for k in ("kda", "ssm") if k in kinds)
+        return tuple(k for k in ("kda", "ssm", "conv") if k in kinds)
 
     @property
     def looped(self) -> bool:
@@ -531,6 +544,11 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
             y, kv_cache = ssm_block(layer["ssm"], h, cfg, kv_cache, plane,
                                     decode, n_valid)
             return _residual(x, y, cfg), kv_cache
+        if kind == "conv":
+            from .shortconv import conv_block
+            y, kv_cache = conv_block(layer["conv"], h, cfg, kv_cache, plane,
+                                     decode, n_valid, positions)
+            return _residual(x, y, cfg), kv_cache
         layer_idx = plane
     with jax.named_scope("attn.qkv"):
         q = maybe_matmul(h, layer["wq"]).reshape(
@@ -539,6 +557,11 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
             b, t, cfg.n_kv_heads, cfg.head_dim)
         v = maybe_matmul(h, layer["wv"]).reshape(
             b, t, cfg.n_kv_heads, cfg.head_dim)
+    if cfg.qk_norm:
+        # over each head's own numbers, before the rotation
+        with jax.named_scope("attn.qk_norm"):
+            q = rms_norm(q, layer["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, layer["k_norm"], cfg.norm_eps)
     if cfg.rope:
         with jax.named_scope("attn.rope"):
             q = apply_rope(q, sin, cos)

@@ -46,6 +46,8 @@ def _layer_specs(layer: Params, tp: str, fsdp: Optional[str],
         "mlp_post_norm": P(),
         "summary_mu": P(),          # [KH, D] a layer: replicated like a norm
         "summary_phi": P(),
+        "q_norm": P(),              # [D] a head (``DecoderConfig.qk_norm``)
+        "k_norm": P(),
         "wq": P(fsdp, tp),
         "wk": P(fsdp, tp),
         "wv": P(fsdp, tp),
@@ -56,8 +58,9 @@ def _layer_specs(layer: Params, tp: str, fsdp: Optional[str],
     }
     out = {name: _quant_aware(spec, layer.get(name))
            for name, spec in base.items() if name in layer}
-    for kind in ("kda", "mla", "ssm"):
-        # a layer pattern's attention (``models.hybrid``, ``models.ssm``): one
+    for kind in ("kda", "mla", "ssm", "conv"):
+        # a layer pattern's attention (``models.hybrid``, ``models.ssm``,
+        # ``models.shortconv``): one
         # chip's, a mesh is refused with it — every leaf replicated
         if kind in layer:
             out[kind] = replicate_specs(layer[kind])

@@ -210,33 +210,39 @@ def refuse_unbuilt_with_summaries(cfg, ecfg: "EngineConfig",
 def refuse_unbuilt_with_lane_state(cfg, ecfg: "EngineConfig",
                                    topo: dict) -> None:
     """What is not built for a decoder whose layers are not all plain
-    attention — a layer pattern stated by rule (``layer_group``: KDA layers,
-    whose state is kept by LANE, closed by latent attention over one row a
-    token) or as a list (``layer_pattern``: state-space layers, state a lane
-    too, around plain attention over per-head rows): refused when the engine
-    is made, each with its reason, and not half-built.
+    attention — a layer pattern stated by rule (``layer_group``: delta-rule
+    layers, whose state is kept by LANE, closed by latent attention over one
+    row a token) or as a list (``layer_pattern``: state-space layers or gated
+    short convolutions, state a lane too, around plain attention over
+    per-head rows): refused when the engine is made, each with its reason,
+    and not half-built.
 
     Two sorts of refusal, each option asked once. The STATE's — asked of
     ``cfg.lane_state`` (does any layer keep state a lane), not of the
-    pattern: the prefix cache (a hit starts a sequence behind cached pages,
-    and those layers would need their state at that boundary) and, where
-    there is state, the state's reasons for the dense cache, verify, a mesh
-    and the host tier. A pattern with no such layer keeps no such state, and
-    its latent pages are shared by the prefix cache like any paged rows. The
-    ROWS' — for every ``layer_group``, because a cache row is a latent: the
-    dense cache, verify (no program attends a window of several positions
-    over latents), a mesh, ``kv_quant``, the host tier and kvwire (they
-    address per-head ``k`` / ``v`` planes; no sharding rule and no wire
-    format names a latent row). Per-head rows beside state are the plain
-    pool's, and only the state refuses. A list's expert layers beside state
-    (``ffn_pattern``) are told which experts they hold and take the dropless
-    held / sorted forms on one device: every refusal here stands for them
-    too (no mesh, no int8 stacks, no prefix cache, no verify). Nothing in the engine preempts a
+    pattern: the state's reasons for the dense cache, verify, a mesh and the
+    host tier, and the prefix cache — a hit starts a sequence behind cached
+    pages, and those layers need their state as of that page's last row. The
+    kinds are told apart by what such a snapshot keeps (``SNAPSHOT``): a
+    matrix a head that every token rewrites (``"kda"``, ``"ssm"``: megabytes
+    a boundary, which nothing keeps — refused), or the last rows of a product
+    the prefill has in hand (``"conv"``: kilobytes a layer, which the pool
+    keeps a BLOCK, ``kvstate.BLOCK_TAIL`` — built, for a decoder whose only
+    state a lane is that). A pattern with no such layer keeps no such state,
+    and its latent pages are shared by the prefix cache like any paged rows.
+    The ROWS' — for every ``layer_group``, because a cache row is a latent:
+    the dense cache, verify (no program attends a window of several
+    positions over latents), a mesh, ``kv_quant``, the host tier and kvwire
+    (they address per-head ``k`` / ``v`` planes; no sharding rule and no
+    wire format names a latent row). Per-head rows beside state are the
+    plain pool's, and only the state refuses. A list's expert layers beside
+    state are told which experts they hold and take the dropless held /
+    sorted forms on one device: every refusal here stands for them too (no
+    mesh, no int8 stacks, no verify). Nothing in the engine preempts a
     running lane, so there is no path that drops a lane's state without
     re-prefilling it; one that is added has to snapshot or re-prefill
     (ROADMAP R6)."""
     latent, state = bool(cfg.layer_group), cfg.lane_state
-    layers = "KDA" if "kda" in state else "state-space"
+    layers = " and ".join(SNAPSHOT[kind] for kind in state)
     label = f"layer_group={cfg.layer_group}" if latent else \
         f"layer_pattern with state a lane ({'/'.join(state)})"
 
@@ -251,14 +257,22 @@ def refuse_unbuilt_with_lane_state(cfg, ecfg: "EngineConfig",
                       "(it is carried chunk to chunk through the paged "
                       "engine's batch-1 scratch and spliced in at "
                       "admission)"] * bool(state)))
-    if ecfg.prefix_cache_blocks > 0 and state:
+    if ecfg.prefix_cache_blocks > 0 and state and state != ("conv",):
+        from ..models import kvstate
+        each = kvstate.lane_bytes(cfg)
         refuse(f"prefix_cache_blocks={ecfg.prefix_cache_blocks}",
                "a hit would start a sequence behind cached pages, and the "
-               f"{layers} layers would need a snapshot of their state at "
-               "that block boundary, which nothing keeps")
+               f"layers that keep {layers} would need a snapshot of it at "
+               f"that block boundary — {each:,} B a boundary here, beside "
+               f"{kvstate.block_bytes(cfg, ecfg.kv_block_size):,} B of rows "
+               "a block — which nothing keeps")
+    if state == ("conv",) and 0 < ecfg.kv_block_size < cfg.conv_taps - 1:
+        refuse(f"kv_block_size={ecfg.kv_block_size}",
+               f"a page's tail is its own last {cfg.conv_taps - 1} rows: a "
+               "page is at least that long")
     if ecfg.spec_len > 0:
         refuse(f"spec_len={ecfg.spec_len} (verify)",
-               f"a verify window advances the {layers} state over its "
+               f"a verify window advances {layers} over its "
                "drafts, and a rejected draft has then already changed it: "
                "there is no state to roll back to" if state else
                "a verify window attends several positions a lane at once, "
@@ -279,8 +293,8 @@ def refuse_unbuilt_with_lane_state(cfg, ecfg: "EngineConfig",
                "kernels are not partitioned",
             (False, True):
                "the state a lane is one chip's: no sharding rule names it "
-               "(which axis of [planes, lanes, heads, width, state] a mesh "
-               "shards), and the step kernel is not partitioned"
+               "(which axis of [planes, lanes, ...] a mesh shards), and "
+               "the step kernel is not partitioned"
                + ("; a list's held experts take the dropless kernels on one "
                   "device alone" if cfg.moe_routed else ""),
         }[latent, bool(state)])
@@ -289,17 +303,33 @@ def refuse_unbuilt_with_lane_state(cfg, ecfg: "EngineConfig",
                "the latent rows are read as they are written, in the "
                "model's type; no scale planes are kept for them" if latent
                else
+               "a short convolution's tail is the model's type and the "
+               "attention rows lie two narrow heads to a cache row, which "
+               "would share an int8 pool's one scale a (token, head); never "
+               "run against the reference" if state == ("conv",) else
                "the state is float32 by the configuration and most of what "
                "a lane keeps; an int8 pool of the few attention planes "
                "beside it was never run against the reference")
     if ecfg.kv_host_pool_mb > 0:
         refuse(f"kv_host_pool_mb={ecfg.kv_host_pool_mb}",
                "the host tier and the kvwire format ship the pool's rows "
-               "and no state a lane: a prefix paged back in would attend "
-               f"its rows with zeroed {layers} layers" if state else
+               "and no state a lane or a block: a prefix paged back in "
+               f"would attend its rows with zeroed layers of {layers}"
+               if state else
                "the host tier and the kvwire format ship per-head key and "
                "value planes by their names and widths; a latent row and "
                "its rotated key have no place in either format")
+
+
+# what a snapshot of each kind of state a lane keeps, in words, for the
+# refusals above (bytes are ``kvstate.lane_bytes``')
+SNAPSHOT = {
+    "kda": "a float32 matrix a head (the delta rule's) and its short "
+           "convolution's tail",
+    "ssm": "a float32 matrix a head (the state-space recurrence's) and its "
+           "short convolution's tail",
+    "conv": "the last rows a short convolution convolved",
+}
 
 
 @dataclass
@@ -456,8 +486,8 @@ class InferenceEngine:
                     f"layer_group={cfg.layer_group} with int8 weights: the "
                     "pattern's layers and the held experts' einsum are "
                     "built for the model's own type" if cfg.layer_group else
-                    "layer_pattern with int8 weights: the state-space "
-                    "mixer's projections (and a list's expert kernels, which "
+                    "layer_pattern with int8 weights: the listed mixers' "
+                    "projections (and a list's expert kernels, which "
                     "read the stacks as they are stored) are built for the "
                     "model's own type")
         from ..ops.quant import validate_quant_mode
@@ -528,7 +558,7 @@ class InferenceEngine:
             # (a row a cache ENTRY: ``max_seq_len`` for plain attention)
             from .paged_kv import scratch_len
             self._scratch = policy.place_kv(kvstate.init_kv_cache(
-                cfg, 1, scratch_len(cfg, s, chunk)))
+                cfg, 1, scratch_len(cfg, s, chunk), block=bs))
         else:
             self.pool = None
             self.kv_cache = policy.place_kv(
@@ -654,12 +684,25 @@ class InferenceEngine:
         # the counters stay 0). A dense decoder has none of them
         # (no KDA layer, no state a lane: no names, and no lane program)
         self._lane_state_names = tuple(kvstate.lane_shapes(cfg, b))
+        # state the pool keeps a BLOCK (short convolutions' tails): the pages
+        # whose tails the splices wrote, and the admissions that started
+        # behind a prefix hit with the tail of its last page restored
+        if kvstate.block_tail_shapes(cfg, 1):
+            self._stats.update(conv_tail_blocks_written=0,
+                               conv_tail_restores=0)
         if cfg.layer_group or cfg.lane_state:
             self._state_bytes = kvstate.lane_bytes(cfg, b)
         if cfg.layer_group or cfg.n_experts:
             self._stats.update(moe_local_picks=0, moe_token_layers=0,
                                moe_held_touched=0, moe_step_layers=0)
             self._held_pick_hist = np.zeros((cfg.n_experts,), np.int64)
+        # the prompt rows admitted and those of them a prefix hit reused:
+        # counted where a hit is what the configuration is deployed for
+        # (latent rows; rows beside short convolutions' tails)
+        self._counts_prefix_rows = bool(cfg.mla_latent
+                                        or cfg.lane_state == ("conv",))
+        if self._counts_prefix_rows:
+            self._stats.update(prefix_rows_reused=0, prompt_rows_admitted=0)
         if cfg.mla_latent:
             # latent attention (ISSUE 52), from the host's mirror of the
             # lengths: the cache rows the live lanes attend at every decode
@@ -669,8 +712,7 @@ class InferenceEngine:
             # rows admitted and those of them a prefix hit reused
             self._stats.update(latent_rows_attended=0, latent_decode_steps=0,
                                prefill_rows_attended=0,
-                               prefill_pairs_attended=0, prefix_rows_reused=0,
-                               prompt_rows_admitted=0)
+                               prefill_pairs_attended=0)
         if cfg.attn_window:
             self._stats.update(windows_closed_prefill=0,
                                windows_closed_decode=0,
@@ -836,6 +878,13 @@ class InferenceEngine:
             return {"decode": decode + "; kda step: " + ran(
                         step_kernel_declined(cfg.n_heads, cfg.head_dim)),
                     "prefill": prefill + "; kda: chunkwise scan"}
+        if self.cfg.lane_state == ("conv",):
+            # a list around short convolutions: the plain attention's
+            # kernels, and the mixers in ``jax.numpy`` (three multiplies and
+            # two adds a channel between two matrix products)
+            plain = self._plain_attention_paths()
+            return {phase: f"{path}; short convolution: xla"
+                    for phase, path in plain.items()}
         if self.cfg.lane_state:
             # a listed pattern: the plain attention's kernels below, and
             # the state-space recurrence (``ops.ssd``) beside them
@@ -957,9 +1006,10 @@ class InferenceEngine:
     # bookkeeping in serving.kvpool) ----------------------------------------
 
     def _pool_dict(self) -> dict:
-        """The kv pool's array view (payload + scales, no table) — the
-        pytree the splice/gather/fused-group graphs take and return."""
-        return {k: self.kv_cache[k] for k in self.pool.wire_names()}
+        """The kv pool's array view (payload + scales and what it keeps a
+        block, no table) — the pytree the splice/gather/fused-group graphs
+        take and return."""
+        return {k: self.kv_cache[k] for k in self.pool.program_names()}
 
     def _set_pool(self, pool: dict) -> None:
         self.kv_cache.update(pool)
@@ -1111,7 +1161,7 @@ class InferenceEngine:
                             jnp.int32)
             self._set_pool(self._splice_fn()(
                 self._pool_dict(), self._scratch["k"], self._scratch["v"],
-                0, phys))
+                0, phys, *self.graphs.scratch_tails(self._scratch)))
             # as in admission: the gathered copy REPLACES the scratch, and
             # the old one is let go first so that the two are never live
             # together (1.6 GB each where the KV state is 192 planes deep)
@@ -1126,6 +1176,10 @@ class InferenceEngine:
             self._scratch = {**kept, "k": dense["k"], "v": dense["v"]}
             del dense
             timings["splice_gather_s"] = _time.perf_counter() - t0
+            if self.graphs.restores_tails:
+                # a prefix hit's read of a page's tails (the trash block's)
+                self._scratch.update(self.graphs.restore_tails(
+                    self.kv_cache, self._trash_block))
             if self._lane_state_names:
                 t0 = _time.perf_counter()
                 self._splice_lane_state(0)     # zeros over an idle lane
@@ -1302,7 +1356,9 @@ class InferenceEngine:
         ``tokens`` into a kvwire payload (None = nothing cached). The
         entry stays PINNED across the gather so a concurrent admission's
         eviction cannot recycle a block mid-device_get."""
-        if not self.paged or self.ecfg.prefix_cache_blocks <= 0:
+        if not self.paged or self.ecfg.prefix_cache_blocks <= 0 \
+                or self.cfg.lane_state:
+            # (state a lane: the format ships rows and no tail a block)
             return None
         entry = self.prefix_cache.acquire_for_export(list(tokens))
         if entry is None:
@@ -1365,7 +1421,10 @@ class InferenceEngine:
         (pool pressure / prefix budget) — the caller falls back to plain
         re-prefill. Malformed payloads raise :class:`KvWireError` before
         any pool mutation."""
-        if not self.paged or self.ecfg.prefix_cache_blocks <= 0:
+        if not self.paged or self.ecfg.prefix_cache_blocks <= 0 \
+                or self.cfg.lane_state:
+            # (state a lane: a payload's pages would come without the tails
+            # a hit behind them starts from)
             self._stats["kvwire_import_fallbacks"] += 1
             return False
         t0 = time.perf_counter()
@@ -1521,6 +1580,7 @@ class InferenceEngine:
             out["loop_steps"] = self.cfg.loop_steps
             out["loop_exit_hist"] = list(self._loop_exit_hist)
         if self.cfg.layer_group or self.cfg.lane_state:
+            out["state_kinds"] = list(self.cfg.lane_state)
             out["state_bytes"] = self._state_bytes
             out["state_bytes_per_lane"] = \
                 self._state_bytes // self.ecfg.max_batch
@@ -1727,12 +1787,22 @@ class InferenceEngine:
             with phase("engine.admit.dispatch", totals, g=0):
                 # the densified prefix REPLACES the scratch: let the old
                 # one go first, or both are live at the gather's peak
+                # (what it keeps by lane and by block is no gather's)
+                kept = {name: a for name, a in scratch.items()
+                        if name not in ("k", "v")}
                 scratch = self._scratch = None
                 dense = self._gather_fn()(self._pool_dict(),
                                           jnp.asarray(row))
-                scratch = {"k": dense["k"], "v": dense["v"]}
+                scratch = {**kept, "k": dense["k"], "v": dense["v"]}
                 del dense
                 self._stats["admit_dispatches"] += 1
+                if self.graphs.restores_tails:
+                    # the suffix starts from the tails as of the last row
+                    # of the hit's last page
+                    scratch.update(self.graphs.restore_tails(
+                        self.kv_cache, int(row[p // bs - 1])))
+                    self._stats["admit_dispatches"] += 1
+                    self._stats["conv_tail_restores"] += 1
 
         with phase("engine.admit.plan", totals):
             toks_all, offsets, last_idxs, phys_all = self._chunk_tables(
@@ -1741,9 +1811,12 @@ class InferenceEngine:
         self._stats["admit_chunks"] += n_chunks
         self._stats["admit_tokens"] += n - req.admit_cached
         self._stats["admit_tokens_padded"] += n_chunks * self._chunk
-        if self.cfg.mla_latent:
+        if self._counts_prefix_rows:
             self._stats["prompt_rows_admitted"] += n
             self._stats["prefix_rows_reused"] += p
+        if "conv_tail_blocks_written" in self._stats:
+            self._stats["conv_tail_blocks_written"] += int(
+                (phys_all != self._trash_block).sum())
         if self.cfg.attn_window:
             # a chunk whose first position opens a window closes the one
             # before it (its program summarises at its head)
@@ -1785,7 +1858,8 @@ class InferenceEngine:
                         self._pool_dict(), scratch["k"], scratch["v"],
                         int(offsets[k_chunk]),
                         self._splice_blocks(phys_all, k_chunk, 1, slot,
-                                            int(offsets[k_chunk]))))
+                                            int(offsets[k_chunk])),
+                        *self.graphs.scratch_tails(scratch)))
                     self._stats["admit_dispatches"] += 2
                 if self.cfg.mla_latent:
                     # the dispatch's queries attend every row before its

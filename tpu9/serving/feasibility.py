@@ -183,6 +183,15 @@ def hbm_budget(preset: str, tpu: "str | TpuSpec", *, max_batch: int = 8,
     # scratch's one lane
     kv += lane_state_bytes(cfg, max_batch)
     scratch += lane_state_bytes(cfg, 1)
+    if kv_block_size:
+        # state a BLOCK (short convolutions' tails, one a page): the pool's
+        # pages — pinned, or dense parity — and the scratch's
+        from ..models import kvstate
+        reach = -(-cfg.kv_entries_peak(max_seq_len) // kv_block_size)
+        kv += kvstate.block_tail_bytes(
+            cfg, kv_pool_blocks + 1 if kv_pool_blocks
+            else max_batch * reach + 1)
+        scratch += kvstate.block_tail_bytes(cfg, reach)
 
     return HbmBudget(
         tpu=spec.name, chips=spec.chips, tp=tp, fsdp=max(fsdp, 1),

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from ..models import kvstate
 from ..models.hybrid import HYBRID_SCOPES, MLA_QUERY_SCOPES
+from ..models.shortconv import CONV_SCOPES
 from ..models.ssm import SSM_SCOPES
 from ..models.transformer import (DEVICE_SCOPES, LATENT_MOE_SCOPES,
                                   LOOP_SCOPES, SUMMARY_SCOPES,
@@ -403,13 +404,22 @@ class GraphFactory:
             logits[0], last_idx, axis=0, keepdims=False)
         return (last, scratch) + extras
 
-    def traced_splice(self, pool, scratch_k, scratch_v, offset, phys):
+    def traced_splice(self, pool, scratch_k, scratch_v, offset, phys,
+                      scratch_tails=None):
         """Traced block copy shared by the splice and admission-group
         graphs: scratch positions [offset, offset + len(phys)·BS) → pool
         blocks phys[0..] (one chunk's C/BS blocks, or a group's g·C/BS).
         An int8 pool quantizes each block on the way in (per-vector absmax
-        scales land in the scale planes at the same physical index)."""
+        scales land in the scale planes at the same physical index).
+        ``scratch_tails``: the state the scratch keeps a BLOCK (a listed
+        pattern's short convolutions: ``kvstate.BLOCK_TAIL``), which goes
+        into the pool beside the pages' rows; None for every other
+        decoder."""
         bs = self.ecfg.kv_block_size
+        if scratch_tails is not None:
+            with jax.named_scope("kv.splice"):
+                pool = kvstate.splice_block_tails(pool, scratch_tails,
+                                                  offset // bs, phys)
         # the scratch is addressed by entry. With ``attn_window`` the first
         # block of ``phys`` takes the page BEFORE the chunk's own: the
         # summaries of the window that this chunk's program closed (the
@@ -527,6 +537,33 @@ class GraphFactory:
 
         return self._build("lanesplice", build)
 
+    def tail_restore_fn(self):
+        """Jitted read of one page's tails (``kvstate.BLOCK_TAIL``) as one
+        lane of state: the scratch's, for a sequence admitted behind that
+        page. The program of a prefix hit beside short convolutions, run
+        once an admission after the gather."""
+        def build():
+            @jax.named_scope("kv.gather")
+            def tail_restore(tails, block):
+                (name,) = kvstate.LANE_KINDS["conv"]
+                return {name: kvstate.block_tail_read(tails, block)}
+
+            return jax.jit(tail_restore)
+
+        return self._build("tailrestore", build)
+
+    def restore_tails(self, kv_cache, block: int) -> dict:
+        """``{name: one lane of state}``: the tails as of the last row of
+        physical block ``block``, for the scratch to start a suffix from."""
+        return self.tail_restore_fn()(kv_cache[kvstate.BLOCK_TAIL], block)
+
+    @property
+    def restores_tails(self) -> bool:
+        """Whether a prefix hit has state to restore: the prefix cache
+        beside layers whose state the pool keeps a block."""
+        return self.ecfg.prefix_cache_blocks > 0 \
+            and bool(kvstate.block_tail_shapes(self.cfg, 1))
+
     def chunk_fn(self):
         """Jitted chunked-prefill step: write one C-token chunk into the
         batch-1 dense scratch at ``offset``, attend over prefix+chunk, and
@@ -588,6 +625,13 @@ class GraphFactory:
             return (g * nb + self.splice_lead,)
         return (nb,) if g == 1 else (g, nb)
 
+    @staticmethod
+    def scratch_tails(scratch) -> tuple:
+        """The last operand of the splice program: ``(the scratch's state a
+        block,)`` where it keeps any, else nothing."""
+        return (scratch[kvstate.BLOCK_TAIL],) \
+            if kvstate.BLOCK_TAIL in scratch else ()
+
     def splice_fn(self):
         """Jitted copy of one chunk's blocks from the scratch into their
         physical pool blocks. C/BS is static → one graph."""
@@ -613,7 +657,7 @@ class GraphFactory:
                     (g - 1) * c + last_idx)
                 pool = self.traced_splice(
                     pool, scratch["k"], scratch["v"], offset,
-                    phys.reshape(-1))
+                    phys.reshape(-1), scratch.get(kvstate.BLOCK_TAIL))
                 return (pool, policy.constrain_kv(scratch), last, *picks)
 
             return jax.jit(group, donate_argnums=(1, 2))
@@ -648,7 +692,11 @@ class GraphFactory:
                     0))
             yield ("splice", self.splice_fn(),
                    (apool, ascratch["k"], ascratch["v"], 0,
-                    jax.ShapeDtypeStruct(self.splice_shape(1), i32)))
+                    jax.ShapeDtypeStruct(self.splice_shape(1), i32))
+                   + self.scratch_tails(ascratch))
+            if self.restores_tails:
+                yield ("tailrestore", self.tail_restore_fn(),
+                       (apool[kvstate.BLOCK_TAIL], 0))
             yield ("gather", self.gather_fn(),
                    (apool, jax.ShapeDtypeStruct((mb,), i32)))
             g = self.group_chunks
@@ -723,6 +771,8 @@ class GraphFactory:
                 # keeps state a lane (one with no KDA layer has no such
                 # program)
                 keys.add("lanesplice")
+            if self.restores_tails:
+                keys.add("tailrestore")      # behind a prefix hit's gather
         else:
             for bucket in buckets:
                 keys |= {bucket, ("dsplice", bucket)}
@@ -761,7 +811,7 @@ class GraphFactory:
             scopes = hlo_scopes(
                 text, DEVICE_SCOPES + LOOP_SCOPES + SUMMARY_SCOPES
                 + HYBRID_SCOPES + MLA_QUERY_SCOPES + SSM_SCOPES
-                + LATENT_MOE_SCOPES)
+                + LATENT_MOE_SCOPES + CONV_SCOPES)
             if scopes:
                 self.device_scopes[name] = scopes
             else:
@@ -790,11 +840,12 @@ def abstract_state(cfg, ecfg, policy, kv_quant: bool = False) -> dict:
         from .kvpool import KvPool
         mgr = KvPool(cfg, ecfg, kv_quant, policy)
         kv_cache = mgr.array_specs()
-        pool = {name: kv_cache[name] for name in mgr.wire_names()}
+        pool = {name: kv_cache[name] for name in mgr.program_names()}
         from .paged_kv import scratch_len
         chunk = ecfg.prefill_chunk or min(ecfg.prefill_buckets)
         scratch = jax.eval_shape(lambda: kvstate.init_kv_cache(
-            cfg, 1, scratch_len(cfg, ecfg.max_seq_len, chunk)))
+            cfg, 1, scratch_len(cfg, ecfg.max_seq_len, chunk),
+            block=ecfg.kv_block_size))
         return {"kv_cache": kv_cache, "pool": pool, "scratch": scratch,
                 "mb": mgr.mb, "rng": rng}
     kv_cache = jax.eval_shape(lambda: kvstate.init_kv_cache(

@@ -204,12 +204,14 @@ class KvPool:
         source :meth:`init_arrays` allocates from and
         :meth:`array_specs` abstracts from (they cannot drift): the pool's
         planes as ``models.kvstate`` has them, the block table, and beside
-        the pool the state the engine keeps by LANE (no pages, no table)."""
+        the pool the state the engine keeps by LANE (no pages, no table) and
+        by BLOCK (a tail a page: ``kvstate.block_tail_shapes``)."""
         return {**kvstate.pool_shapes(self.cfg, self.n_blocks,
                                       self.ecfg.kv_block_size,
                                       self.kv_quant),
                 kvstate.TABLE: (self.table_np.shape, np.int32),
-                **kvstate.lane_shapes(self.cfg, self.ecfg.max_batch)}
+                **kvstate.lane_shapes(self.cfg, self.ecfg.max_batch),
+                **kvstate.block_tail_shapes(self.cfg, self.n_blocks)}
 
     def init_arrays(self) -> Params:
         """The pool's device state: payload (+ int8 scale planes) and the
@@ -256,6 +258,14 @@ class KvPool:
         wire. Not the table, which is host bookkeeping (block ids are
         pool-local), nor state kept by lane."""
         return list(kvstate.paged_planes(self.cfg, self.kv_quant))
+
+    def program_names(self) -> list[str]:
+        """What the splice and group programs take and return: the pool's
+        own arrays and, beside them, the state it keeps a BLOCK (no wire
+        format ships that: kvwire and the host tier are refused beside
+        state a lane)."""
+        return self.wire_names() + list(
+            kvstate.block_tail_shapes(self.cfg, 1))
 
     def export_blocks(self, kv, blocks: list[int], prefix_key: bytes,
                       n_tokens: int) -> bytes:
