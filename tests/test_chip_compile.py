@@ -22,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # the benchmark's cells as the kernel sees them: one table, the script's
 from scripts.paged_kernel_bench import CELL_SHAPES
+from scripts.program_copies import relaid_copies
 import tpu9.ops.attention as attention_ops
 from tpu9.ops.attention import flash_attention, paged_attention_dispatch
 from tpu9.ops.paged_attention import (paged_decode_attention,
@@ -896,6 +897,98 @@ def test_a_looped_decode_program_carries_the_pool_through_its_pass_loop(
     assert text.count("tpu_custom_call") == cfg.n_layers
     assert " while(" in text
     assert _pool_shaped(text, pool) == []
+
+
+@pytest.mark.parametrize("k", [1, 8])
+@pytest.mark.parametrize("configuration", [
+    "evabyte-6.5b-l16", "ouro-2.6b", "mistral-7b-v0.3-tp4"])
+def test_a_decode_program_reads_its_weights_as_they_are_stored(
+        v5e, no_compile_cache, monkeypatch, configuration, k):
+    """A decode program two layers deep at its engine's shapes (ISSUE 63;
+    four described chips for the tensor-parallel one, whose shards are the
+    parameters there): no ``copy`` or ``copy-start`` writes a weight — or a
+    ``ConcatBitcast`` of a weight's slices, or a bitcast that reads it as
+    its transpose — out again in another layout than the one it is stored
+    in. ``wq`` / ``wk`` / ``wv`` were: their products, split into heads,
+    folded the split in at a step's few rows and wanted the contracted
+    dimension minor, so every CALL transposed them whole before its first
+    step (1.6 GB a call on ``evabyte-6.5b-l16``, 1.2 GB on ``ouro-2.6b``:
+    into temporaries in HBM, or at K = 1 into VMEM). The rest of a
+    program's arguments (a table, the lengths) are a call's own and small:
+    not held here."""
+    _, _, _, _, jobs = _decode_programs(v5e, monkeypatch, configuration,
+                                        n_layers=2)
+    (_, fn, args), = [job for job in jobs if job[0] == ("decode", k)]
+    text = fn.lower(*args).compile().as_text()
+    assert [(c["parameter"], c["shape"], c["layout"], c["where"])
+            for c in relaid_copies(text)
+            if c["parameter"].startswith("params")] == []
+
+
+RELAID = '''HloModule jit_decode
+%fused_copy (param_0.1: bf16[64,256]) -> bf16[64,256] {
+  %param_0.1 = bf16[64,256]{1,0:T(8,128)(2,1)} parameter(0)
+  ROOT %copy.9 = bf16[64,256]{0,1:T(8,128)(2,1)} copy(%param_0.1)
+}
+%body (state: (s32[], bf16[256,256], bf16[256,256])) -> (s32[], bf16[256,256], bf16[256,256]) {
+  %state = (s32[]{:T(128)}, bf16[256,256]{1,0:T(8,128)(2,1)}, bf16[256,256]{0,1:T(8,128)(2,1)}) parameter(0)
+  %gte.1 = bf16[256,256]{1,0:T(8,128)(2,1)} get-tuple-element(%state), index=1
+  %gte.2 = bf16[256,256]{0,1:T(8,128)(2,1)} get-tuple-element(%state), index=2
+  %copy-start.1 = (bf16[256,256]{1,0:T(8,128)(2,1)S(1)}, bf16[256,256]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%gte.1)
+  %copy-start.2 = (bf16[256,256]{0,1:T(8,128)(2,1)S(1)}, bf16[256,256]{0,1:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%gte.2)
+  %copy.5 = bf16[256,256]{0,1:T(8,128)(2,1)} copy(%gte.1)
+}
+ENTRY %main.7 (wq: bf16[256,256], wk: bf16[256,256], wo: bf16[64,256], x: bf16[16,256]) -> bf16[16,256] {
+  %params__wq__.1 = bf16[256,256]{1,0:T(8,128)(2,1)} parameter(0)
+  %params__wk__.1 = bf16[256,256]{1,0:T(8,128)(2,1)} parameter(1)
+  %params__wo__.1 = bf16[64,256]{1,0:T(8,128)(2,1)} parameter(2)
+  %x.1 = bf16[16,256]{1,0:T(8,128)(2,1)} parameter(3)
+  %slice-start = ((bf16[256,256]{1,0:T(8,128)(2,1)}), bf16[128,256]{1,0:T(8,128)(2,1)S(1)}, s32[]{:S(2)}) slice-start(%params__wk__.1), slice={[0:128], [0:256]}
+  %slice-done = bf16[128,256]{1,0:T(8,128)(2,1)S(1)} slice-done(%slice-start)
+  %slice-start.1 = ((bf16[256,256]{1,0:T(8,128)(2,1)}), bf16[128,256]{1,0:T(8,128)(2,1)S(1)}, s32[]{:S(2)}) slice-start(%params__wk__.1), slice={[128:256], [0:256]}
+  %slice-done.1 = bf16[128,256]{1,0:T(8,128)(2,1)S(1)} slice-done(%slice-start.1)
+  %custom-call.3 = bf16[256,256]{1,0:T(8,128)(2,1)S(1)} custom-call(%slice-done, %slice-done.1), custom_call_target="ConcatBitcast"
+  %copy.2 = bf16[256,256]{0,1:T(8,128)(2,1)} copy(%custom-call.3)
+  %copy-start.7 = (bf16[256,256]{1,0:T(8,128)(2,1)S(1)}, bf16[256,256]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%params__wq__.1)
+  %copy-done.7 = bf16[256,256]{1,0:T(8,128)(2,1)S(1)} copy-done(%copy-start.7)
+  %copy.1 = bf16[256,256]{0,1:T(8,128)(2,1)} copy(%copy-done.7)
+  %copy.3 = bf16[16,256]{0,1:T(8,128)(2,1)} copy(%dot.4)
+  %bitcast.4 = bf16[256,256]{0,1:T(8,128)(2,1)} bitcast(%params__wq__.1)
+  %copy.4 = bf16[256,256]{1,0:T(8,128)(2,1)S(1)} copy(%bitcast.4)
+  %fusion.8 = bf16[64,256]{0,1:T(8,128)(2,1)} fusion(%params__wo__.1), kind=kLoop, calls=%fused_copy
+  %tuple.6 = (s32[]{:T(128)}, bf16[256,256]{1,0:T(8,128)(2,1)}, bf16[256,256]{0,1:T(8,128)(2,1)}) tuple(%zero, %params__wq__.1, %copy.1)
+  %while.5 = (s32[]{:T(128)}, bf16[256,256]{1,0:T(8,128)(2,1)}, bf16[256,256]{0,1:T(8,128)(2,1)}) while(%tuple.6), condition=%cond, body=%body
+}
+'''
+
+
+@pytest.mark.parametrize("copy,parameter,where,order", [
+    # a prefetched weight, transposed at the program's door
+    ("copy.1", "params__wq__.1", "entry", "0,1"),
+    # a weight's slices, put together and transposed
+    ("copy.2", "params__wk__.1", "entry", "0,1"),
+    # the same weight through the loop's state, transposed every step
+    ("copy.5", "params__wq__.1", "loop", "0,1"),
+    # a fusion that is a transposing copy of its parameter
+    ("copy.9", "params__wo__.1", "entry", "0,1"),
+    # a square weight read as its transpose, then copied into the stored
+    # order of dimensions: the K = 1 programs' form
+    ("copy.4", "params__wq__.1", "entry", "1,0"),
+], ids=["prefetched", "slices", "in_loop", "fusion", "bitcast"])
+def test_relaid_copies_finds_a_weight_written_out_in_another_layout(
+        copy, parameter, where, order):
+    """The reader above (``scripts/program_copies.py``), on hand-made text:
+    what it finds — and that a prefetch in the weight's own layout
+    (``copy-start.7``, ``copy-start.1``), a copy of what the program
+    computed (``copy.3``) and the prefetch of an already transposed
+    temporary (``copy-start.2``) are none of it."""
+    found = {c["copy"]: c for c in relaid_copies(RELAID)}
+    assert sorted(found) == ["copy.1", "copy.2", "copy.4", "copy.5",
+                             "copy.9"]
+    assert (found[copy]["parameter"], found[copy]["where"],
+            found[copy]["layout"]) == (parameter, where,
+                                       order + ":T(8,128)(2,1)")
+    assert found[copy]["parameter_layout"] == "1,0:T(8,128)(2,1)"
 
 
 def test_pool_shaped_finds_what_the_old_program_did():

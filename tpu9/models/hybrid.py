@@ -47,7 +47,7 @@ from ..ops.latent_attention import (blocked_prefill_attention,
                                     blocked_prefill_declined,
                                     expanded_attention)
 from ..ops.norms import rms_norm
-from ..ops.quant import maybe_matmul
+from ..ops.quant import maybe_matmul, project_heads
 from ..ops.rotary import apply_rope
 from . import kvstate
 
@@ -300,10 +300,10 @@ def mla_block(p: dict, h: jnp.ndarray, cfg, positions, sin, cos,
         with jax.named_scope("attn.mla.q"):
             c_q = rms_norm(maybe_matmul(h, p["w_dq"]), p["q_norm"],
                            cfg.norm_eps).astype(h.dtype)
-            q = maybe_matmul(c_q, p["w_uq"]).reshape(b, t, heads, dn + dr)
+            q = project_heads(c_q, p["w_uq"], heads, dn + dr)
     with jax.named_scope("attn.qkv"):
         if not cfg.mla_q_latent:
-            q = maybe_matmul(h, p["wq"]).reshape(b, t, heads, dn + dr)
+            q = project_heads(h, p["wq"], heads, dn + dr)
         down = maybe_matmul(h, p["w_dkv"])                   # [B, T, dc+dr]
         c = rms_norm(down[..., :dc], p["kv_norm"], cfg.norm_eps)
         if cfg.mla_out_gate:

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from ..ops.attention import attention
 from ..ops.norms import rms_norm
-from ..ops.quant import maybe_matmul
+from ..ops.quant import maybe_matmul, project_heads
 from ..ops.rotary import apply_rope, rope_rows
 from . import kvstate
 
@@ -551,12 +551,9 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
             return _residual(x, y, cfg), kv_cache
         layer_idx = plane
     with jax.named_scope("attn.qkv"):
-        q = maybe_matmul(h, layer["wq"]).reshape(
-            b, t, cfg.n_heads, cfg.head_dim)
-        k = maybe_matmul(h, layer["wk"]).reshape(
-            b, t, cfg.n_kv_heads, cfg.head_dim)
-        v = maybe_matmul(h, layer["wv"]).reshape(
-            b, t, cfg.n_kv_heads, cfg.head_dim)
+        q = project_heads(h, layer["wq"], cfg.n_heads, cfg.head_dim)
+        k = project_heads(h, layer["wk"], cfg.n_kv_heads, cfg.head_dim)
+        v = project_heads(h, layer["wv"], cfg.n_kv_heads, cfg.head_dim)
     if cfg.qk_norm:
         # over each head's own numbers, before the rotation
         with jax.named_scope("attn.qk_norm"):

@@ -240,6 +240,20 @@ def maybe_matmul(x: jnp.ndarray, w) -> jnp.ndarray:
     return x @ w
 
 
+def project_heads(x: jnp.ndarray, w, n_heads: int,
+                  head_dim: int) -> jnp.ndarray:
+    """``x [..., D] @ w [D, n_heads * head_dim]`` split into heads:
+    ``[..., n_heads, head_dim]``. The product stays 2-D up to the barrier,
+    so the compiler reads ``w`` in the layout it is stored in, as it does
+    ``wo`` and the feed-forward matrices. Left to fold the split into the
+    product at a decode step's few rows, it takes the form that wants the
+    CONTRACTED dimension minor, and every decode call re-lays the whole
+    parameter into a temporary before its first step (ISSUE 63;
+    ``scripts/program_copies.py`` counts such copies)."""
+    y = jax.lax.optimization_barrier(maybe_matmul(x, w))
+    return y.reshape(*y.shape[:-1], n_heads, head_dim)
+
+
 def maybe_einsum(spec: str, x: jnp.ndarray, w) -> jnp.ndarray:
     """Einsum that accepts a plain stacked array or a stacked int8 entry
     (the MoE forward's mixed-tree analogue of :func:`maybe_matmul`)."""
