@@ -1,10 +1,9 @@
-"""The two attention kinds of a layer PATTERN (``DecoderConfig.layer_group``):
-delta-rule linear attention with a decay a channel (KDA) and latent
-attention (MLA). A pattern is WHICH LAYERS ARE KDA AND WHICH MLA —
-``DecoderConfig.layer_kind`` says it, and a pattern may have no KDA layer at
-all (``layer_group`` 1: MLA in every layer, no state a lane, and a cache the
-prefix cache can share); ``models.transformer`` walks the layers and calls
-in here.
+"""The two attention kinds of the latent family's layer PATTERN: delta-rule
+linear attention with a decay a channel (KDA) and latent attention (MLA). A
+pattern is WHICH LAYERS ARE KDA AND WHICH MLA — ``DecoderConfig.layers`` says
+it, and a pattern may have no KDA layer at all (MLA in every layer, no state
+a lane, and a cache the prefix cache can share); ``models.transformer`` walks
+the layers and calls in here.
 
 What a running sequence keeps differs by kind, and that is the point:
 
@@ -70,7 +69,7 @@ def refuse_unbuilt_pattern(cfg) -> None:
     """``DecoderConfig.__post_init__`` for a layer pattern: every
     combination that is not built is refused, with its reason."""
     def refuse(what: str, why: str):
-        raise ValueError(f"layer_group={cfg.layer_group} with {what}: {why}")
+        raise ValueError(f"{cfg.pattern_label} with {what}: {why}")
 
     if cfg.layer_group < 1:
         refuse("a negative group", "the last layer of every group of "
@@ -93,7 +92,7 @@ def refuse_unbuilt_pattern(cfg) -> None:
         refuse("a latent-attention width that is not set",
                "mla_latent, mla_nope, mla_rope (even) and mla_v are all "
                "needed")
-    if cfg.layer_group == 1:
+    if "kda" not in cfg.lane_state:
         if cfg.kda_conv or cfg.kda_gate_bound:
             refuse(f"kda_conv={cfg.kda_conv}, kda_gate_bound="
                    f"{cfg.kda_gate_bound}",
@@ -151,68 +150,58 @@ def refuse_unbuilt_share(cfg, refuse) -> None:
                "built for sigmoid scores only")
 
 
-def _dense(rng, in_dim: int, out_dim: int, dtype, fan_out: int = 0):
+def dense_init(rng, in_dim: int, out_dim: int, dtype, fan_out: int = 0):
+    """A seeded matrix ``[in, out]``, normal at the fan-in / fan-out scale
+    (``fan_out``: of the part of ``out_dim`` that is one projection's)."""
     scale = (2.0 / (in_dim + (fan_out or out_dim))) ** 0.5
     return (jax.random.normal(rng, (in_dim, out_dim), F32)
             * scale).astype(dtype)
 
 
-def init_hybrid_layer(rng: jax.Array, cfg, l: int) -> dict:
-    """Layer ``l`` of a pattern, seeded. What a checkpoint would bring and a
-    seed has to choose: the convolution's taps normal x 0.5; ``a_log``
-    uniform in log-space over [1/4, 4], so that decays differ by head;
-    ``b_a`` normal around -2, so that a typical token's decay is neither 1
-    nor the bound."""
-    attention, ffn = cfg.layer_kind(l)
-    dt, d_model = cfg.dtype, cfg.dim
-    h, d = cfg.n_heads, cfg.head_dim
-    r = iter(jax.random.split(rng, 16))
-    layer = {"attn_norm": jnp.ones((d_model,), F32),
-             "mlp_norm": jnp.ones((d_model,), F32)}
-    if attention == "kda":
-        layer["kda"] = {
-            "w_qkv": _dense(next(r), d_model, 3 * h * d, dt, fan_out=h * d),
-            "conv": jax.random.normal(next(r), (cfg.kda_conv, 3 * h * d),
-                                      F32) * 0.5,
-            "w_a": _dense(next(r), d_model, h * d, dt),
-            "b_a": jax.random.normal(next(r), (h * d,), F32) - 2.0,
-            "a_log": jax.random.uniform(next(r), (h,), F32,
-                                        -jnp.log(4.0), jnp.log(4.0)),
-            "w_b": _dense(next(r), d_model, h, dt),
-            "w_g": _dense(next(r), d_model, h * d, dt),
-            "o_norm": jnp.ones((d,), F32),
-            "wo": _dense(next(r), h * d, d_model, dt)}
-    else:
-        dn, dr, dv, dc = cfg.mla_nope, cfg.mla_rope, cfg.mla_v, cfg.mla_latent
-        # the rngs in the order the full-rank, gated layer always drew them
-        rq, rd, ru, rg, ro = (next(r) for _ in range(5))
-        layer["mla"] = {
-            "w_dkv": _dense(rd, d_model, dc + dr, dt),
-            "kv_norm": jnp.ones((dc,), F32),
-            "w_ukv": _dense(ru, dc, h * (dn + dv), dt),
-            "wo": _dense(ro, h * dv, d_model, dt)}
-        if cfg.mla_q_latent:
-            rq, ruq = jax.random.split(rq)
-            layer["mla"].update(
-                w_dq=_dense(rq, d_model, cfg.mla_q_latent, dt),
-                q_norm=jnp.ones((cfg.mla_q_latent,), F32),
-                w_uq=_dense(ruq, cfg.mla_q_latent, h * (dn + dr), dt))
-        else:
-            layer["mla"]["wq"] = _dense(rq, d_model, h * (dn + dr), dt)
-        if cfg.mla_out_gate:
-            layer["mla"]["w_gate"] = _dense(rg, d_model, h, dt)
-    if ffn == "experts":
-        from .moe import init_moe_layer
-        from .transformer import moe_cfg
-        layer["moe"] = init_moe_layer(next(r), moe_cfg(cfg))
-    else:
-        layer["w_gate"] = _dense(next(r), d_model, cfg.hidden_dim, dt)
-        layer["w_up"] = _dense(next(r), d_model, cfg.hidden_dim, dt)
-        layer["w_down"] = _dense(next(r), cfg.hidden_dim, d_model, dt)
-    return layer
+def init_kda(r, cfg) -> dict:
+    """One KDA layer's attention, seeded from the rngs ``r`` yields. What a
+    checkpoint would bring and a seed has to choose: the convolution's taps
+    normal x 0.5; ``a_log`` uniform in log-space over [1/4, 4], so that
+    decays differ by head; ``b_a`` normal around -2, so that a typical
+    token's decay is neither 1 nor the bound."""
+    dt, d_model, h, d = cfg.dtype, cfg.dim, cfg.n_heads, cfg.head_dim
+    return {
+        "w_qkv": dense_init(next(r), d_model, 3 * h * d, dt, fan_out=h * d),
+        "conv": jax.random.normal(next(r), (cfg.kda_conv, 3 * h * d),
+                                  F32) * 0.5,
+        "w_a": dense_init(next(r), d_model, h * d, dt),
+        "b_a": jax.random.normal(next(r), (h * d,), F32) - 2.0,
+        "a_log": jax.random.uniform(next(r), (h,), F32,
+                                    -jnp.log(4.0), jnp.log(4.0)),
+        "w_b": dense_init(next(r), d_model, h, dt),
+        "w_g": dense_init(next(r), d_model, h * d, dt),
+        "o_norm": jnp.ones((d,), F32),
+        "wo": dense_init(next(r), h * d, d_model, dt)}
 
 
-def _project32(h, w):
+def init_mla(r, cfg) -> dict:
+    """One MLA layer's attention, seeded from the rngs ``r`` yields."""
+    dt, d_model, h = cfg.dtype, cfg.dim, cfg.n_heads
+    dn, dr, dv, dc = cfg.mla_nope, cfg.mla_rope, cfg.mla_v, cfg.mla_latent
+    # the rngs in the order the full-rank, gated layer always drew them
+    rq, rd, ru, rg, ro = (next(r) for _ in range(5))
+    mla = {"w_dkv": dense_init(rd, d_model, dc + dr, dt),
+           "kv_norm": jnp.ones((dc,), F32),
+           "w_ukv": dense_init(ru, dc, h * (dn + dv), dt),
+           "wo": dense_init(ro, h * dv, d_model, dt)}
+    if cfg.mla_q_latent:
+        rq, ruq = jax.random.split(rq)
+        mla.update(w_dq=dense_init(rq, d_model, cfg.mla_q_latent, dt),
+                   q_norm=jnp.ones((cfg.mla_q_latent,), F32),
+                   w_uq=dense_init(ruq, cfg.mla_q_latent, h * (dn + dr), dt))
+    else:
+        mla["wq"] = dense_init(rq, d_model, h * (dn + dr), dt)
+    if cfg.mla_out_gate:
+        mla["w_gate"] = dense_init(rg, d_model, h, dt)
+    return mla
+
+
+def project32(h, w):
     """``h @ w`` with the product kept in float32 (the operands stay in the
     model's type): a gate's pre-activation. Rounded to bfloat16 it is off by
     up to 0.4 % of its size, and the decay's is scaled by ``exp(A_h)`` and
@@ -247,12 +236,12 @@ def kda_block(p: dict, h: jnp.ndarray, cfg, kv_cache: Optional[dict],
         q, k, v = (a.reshape(b, t, heads, d) for a in
                    jnp.split(jax.nn.silu(qkv), 3, axis=-1))
         q, k = _unit(q) * d ** -0.5, _unit(k)
-        gate_in = (_project32(h, p["w_a"]) + p["b_a"]).reshape(
+        gate_in = (project32(h, p["w_a"]) + p["b_a"]).reshape(
             b, t, heads, d) * jnp.exp(p["a_log"])[None, None, :, None]
         log_alpha = cfg.kda_gate_bound * jax.nn.sigmoid(gate_in)
-        beta = jax.nn.sigmoid(_project32(h, p["w_b"]))
+        beta = jax.nn.sigmoid(project32(h, p["w_b"]))
         out_gate = jax.nn.sigmoid(
-            _project32(h, p["w_g"])).reshape(b, t, heads, d)
+            project32(h, p["w_g"])).reshape(b, t, heads, d)
     with jax.named_scope("attn.kda.state"):
         if decode and kv_cache is not None \
                 and not delta_rule.step_kernel_declined(heads, d):

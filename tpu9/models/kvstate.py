@@ -112,7 +112,7 @@ def paged_planes(cfg, quantized: bool = False) -> dict:
     row of. Latent rows are read as they are written, in the model's type:
     ``quantized`` is for per-head rows alone."""
     rows = dict(zip(_SCALE, cfg.kv_row))
-    if cfg.layer_group or not quantized:
+    if cfg.latent_rows or not quantized:
         return {name: (row, cfg.dtype) for name, row in rows.items()}
     # scales: the payload's [N, BS, KH] indexing, so every write and read
     # shares the table math
@@ -129,7 +129,7 @@ def pool_shapes(cfg, n_blocks: int, block: int,
     planes = paged_planes(cfg, quantized)
     shapes = {name: ((cfg.kv_layers, n_blocks, block) + row, dt)
               for name, (row, dt) in planes.items()}
-    if cfg.layer_group:
+    if cfg.latent_rows:
         if block % 2:
             raise ValueError(f"a block of {block} entries: the rotated keys "
                              "of a latent pool lie two tokens a row")
@@ -140,31 +140,31 @@ def pool_shapes(cfg, n_blocks: int, block: int,
 
 
 def lane_shapes(cfg, lanes: int) -> dict:
-    """``name -> (shape, dtype)`` of the state that KDA layers, or a listed
-    pattern's state-space layers, keep for ``lanes`` running sequences;
-    empty for a decoder without such layers."""
-    if cfg.layer_pattern:
-        if cfg.conv_taps:
-            return {"conv_tail": ((len(cfg.layers_of("conv")), lanes,
-                                   cfg.conv_taps - 1, cfg.dim), cfg.dtype)}
-        planes = len(cfg.layers_of("ssm"))
-        if not planes:
-            return {}
+    """``name -> (shape, dtype)`` of the state that the layers of
+    ``cfg.lane_state``'s kinds — KDA, state-space, short convolutions — keep
+    for ``lanes`` running sequences, a plane a layer of the kind; empty for
+    a decoder without such layers."""
+    planes = {kind: (len(cfg.layers_of(kind)), lanes)
+              for kind in cfg.lane_state}
+    shapes = {}
+    if "kda" in planes:
+        h, d = cfg.n_heads, cfg.head_dim
+        shapes.update(
+            kda_state=(planes["kda"] + (h, d, d), jnp.float32),
+            kda_conv=(planes["kda"] + (cfg.kda_conv - 1, 3 * h * d),
+                      cfg.dtype))
+    if "ssm" in planes:
         h, d = cfg.ssm_heads, cfg.ssm_head_dim
         width = h * d + 2 * cfg.ssm_groups * cfg.ssm_state
         # (the matrix as ``ops.ssd`` stores it: ``state_shape`` says why)
-        return {"ssm_state": ((planes, lanes) + ssd_state_shape(
-                                  h, d, cfg.ssm_state, cfg.ssm_groups),
-                              jnp.float32),
-                "ssm_conv": ((planes, lanes, cfg.ssm_conv - 1, width),
-                             cfg.dtype)}
-    planes = len(cfg.layers_of("kda")) if cfg.layer_group > 1 else 0
-    if not planes:
-        return {}
-    h, d = cfg.n_heads, cfg.head_dim
-    return {"kda_state": ((planes, lanes, h, d, d), jnp.float32),
-            "kda_conv": ((planes, lanes, cfg.kda_conv - 1, 3 * h * d),
-                         cfg.dtype)}
+        shapes.update(
+            ssm_state=(planes["ssm"] + ssd_state_shape(
+                h, d, cfg.ssm_state, cfg.ssm_groups), jnp.float32),
+            ssm_conv=(planes["ssm"] + (cfg.ssm_conv - 1, width), cfg.dtype))
+    if "conv" in planes:
+        shapes["conv_tail"] = (planes["conv"] + (cfg.conv_taps - 1, cfg.dim),
+                               cfg.dtype)
+    return shapes
 
 
 def block_tail_shapes(cfg, n_blocks: int, lanes: int = 0) -> dict:
@@ -241,23 +241,15 @@ def init_kv_cache(cfg, batch: int, max_len: int = 0, dtype=None,
 ROW_LANES = 128
 
 
-def heads_per_row(cfg) -> int:
-    """KV heads a cache row holds side by side (``cfg.kv_pack``; 1 = a head
-    a row). Worked out, not stated: the per-head rows of a LISTED pattern
-    (``layer_pattern``: new with the packing, PR 55) whose heads are
-    narrower than ``ROW_LANES`` hold as many as fill the lanes — two of 64,
-    all of them where they are fewer — when that is a whole number of
-    heads a row and of rows. Everything else keeps a head a row: latent
-    rows have no heads, a window's summarise reads a head a row, and a
-    uniform decoder's programs stay what they were (its heads are 128 wide
-    in every served model; the int8 pool's scale is one a (token, head),
-    which a packed row would share). A listed pattern always keeps state a
-    lane (``models.ssm.refuse_unbuilt_list``), and the engine refuses
-    ``kv_quant`` and a mesh beside it."""
-    hd, heads = cfg.head_dim, cfg.n_kv_heads
-    if not cfg.layer_pattern or hd >= ROW_LANES or ROW_LANES % hd:
+def heads_per_row(head_dim: int, heads: int) -> int:
+    """KV heads of ``head_dim`` numbers that a cache row holds side by side
+    where narrow heads are packed (``DecoderConfig.kv_pack`` says where; 1 =
+    a head a row): heads narrower than ``ROW_LANES`` lie as many as fill the
+    lanes — two of 64, all of them where they are fewer — when that is a
+    whole number of heads a row and of rows."""
+    if head_dim >= ROW_LANES or ROW_LANES % head_dim:
         return 1
-    pack = min(ROW_LANES // hd, heads)
+    pack = min(ROW_LANES // head_dim, heads)
     return pack if heads % pack == 0 else 1
 
 

@@ -1,9 +1,9 @@
-"""The gated short convolution of a listed pattern (``layer_pattern`` says
-``"conv"``: the ``lfm2`` family, three such layers to one of plain rotary
-attention, each a WHOLE layer closed by a dense SwiGLU or an expert layer).
+"""The gated short convolution of a listed pattern (a ``"conv"`` layer: the
+``lfm2`` family, three such layers to one of plain rotary attention, each a
+WHOLE layer closed by a dense SwiGLU or an expert layer).
 ``models.transformer`` walks the layers and calls in here for the mixer; the
 refusals are ``models.ssm.refuse_unbuilt_list``'s, the seeded layer
-``models.ssm.init_listed_layer``'s.
+``models.transformer.init_pattern_layer``'s.
 
 The mixer, with ``u`` the normed input and ``K = conv_taps``:
 
@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from ..ops.delta_rule import causal_conv
 from ..ops.quant import maybe_matmul
 from . import kvstate
+from .hybrid import dense_init
 
 F32 = jnp.float32
 # device scopes of the mixer, beside ``transformer.DEVICE_SCOPES`` (a tuple of
@@ -45,13 +46,12 @@ def init_conv_mixer(rng: jax.Array, cfg) -> dict:
     """One mixer, seeded: the projections normal at the fan-in / fan-out
     scale like every matrix, the taps uniform over ``+- 1 / sqrt(taps)`` (a
     depthwise convolution's default). A checkpoint brings its own."""
-    from .hybrid import _dense
     d, k = cfg.dim, cfg.conv_taps
     r_in, r_taps, r_out = jax.random.split(rng, 3)
-    return {"w_in": _dense(r_in, d, 3 * d, cfg.dtype, fan_out=d),
+    return {"w_in": dense_init(r_in, d, 3 * d, cfg.dtype, fan_out=d),
             "conv": jax.random.uniform(r_taps, (k, d), F32,
                                        -k ** -0.5, k ** -0.5),
-            "w_out": _dense(r_out, d, d, cfg.dtype)}
+            "w_out": dense_init(r_out, d, d, cfg.dtype)}
 
 
 def conv_block(p: dict, u: jnp.ndarray, cfg, kv_cache: Optional[dict],

@@ -1,7 +1,7 @@
-"""A layer pattern stated as a LIST (``DecoderConfig.layer_pattern``): which
-layers are Mamba-2 state-space mixers (``"ssm"``) and which the plain
-attention of a uniform decoder (``"full"``) — the hybrid state-space family
-(``granitemoehybrid``: nine mixers to one attention layer, every layer
+"""A layer pattern stated as a LIST (``DecoderConfig.layers`` is what it
+says): which layers are Mamba-2 state-space mixers (``"ssm"``) and which the
+plain attention of a uniform decoder (``"full"``) — the hybrid state-space
+family (``granitemoehybrid``: nine mixers to one attention layer, every layer
 closed by the same dense SwiGLU). ``models.transformer`` walks the layers
 and calls in here for the mixers; the plain-attention layers of a list are
 ``transformer._attn_block``'s own, at the plane their place among
@@ -53,7 +53,7 @@ from ..ops.delta_rule import causal_conv
 from ..ops.norms import rms_norm
 from ..ops.quant import maybe_matmul
 from . import kvstate
-from .hybrid import _dense, _project32
+from .hybrid import dense_init, project32
 
 F32 = jnp.float32
 # device scopes of a state-space layer, beside ``transformer.DEVICE_SCOPES``
@@ -148,7 +148,7 @@ def refuse_unbuilt_list(cfg) -> None:
         return _refuse_unbuilt_conv_list(cfg, what, sizes, refuse)
     if halves:
         _refuse_unbuilt_halves(cfg, what, refuse)
-    if "ssm" not in cfg.layer_pattern:
+    if "ssm" not in cfg.lane_state:
         refuse(f"{what} without an ssm layer",
                "that is a uniform decoder, stated without a list: a list "
                "packs narrow heads to whole cache rows "
@@ -172,13 +172,13 @@ def _refuse_unbuilt_conv_list(cfg, what: str, sizes, refuse) -> None:
     each closed by the rule's feed-forward part (``moe_dense_layers`` dense,
     then expert layers that hold all they route over) — and nothing wider."""
     what = f"{what} with \"conv\" layers"
-    if cfg.ffn_pattern or "ssm" in cfg.layer_pattern or any(sizes) \
+    if cfg.ffn_pattern or "ssm" in cfg.lane_state or any(sizes) \
             or cfg.ssm_groups != 1 or cfg.ssm_norm_groups != 1:
         refuse(f"{what} and an ffn_pattern, \"ssm\" layers or ssm sizes",
                "short convolutions were built and run in whole layers "
                "beside plain attention alone: half-layers and a second kind "
                "of state a lane beside theirs were never run")
-    if "full" not in cfg.layer_pattern:
+    if not cfg.layers_of("full"):
         refuse(f"{what} and no \"full\" layer",
                "a pool of no plane: no program pages nothing, not run")
     if cfg.conv_taps < 2:
@@ -225,8 +225,7 @@ def _refuse_unbuilt_halves(cfg, what: str, refuse) -> None:
         refuse(what, f"one kind of {HALF_KINDS} for each of the "
                f"{cfg.n_layers} layers (a dense feed-forward part is the "
                "rule's, stated without an ffn_pattern)")
-    if any((a == "none") == (f == "none")
-           for a, f in zip(cfg.layer_pattern, cfg.ffn_pattern)):
+    if any((a == "none") == (f == "none") for a, f in cfg.layers):
         refuse(what, "every layer is ONE half, a mixer or attention or a "
                "feed-forward part (exactly one of the two lists says "
                "\"none\"): whole layers beside half-layers were never run")
@@ -251,59 +250,29 @@ def conv_width(cfg) -> int:
     return cfg.ssm_heads * cfg.ssm_head_dim + 2 * cfg.ssm_groups * cfg.ssm_state
 
 
-def init_listed_layer(rng: jax.Array, cfg, l: int) -> dict:
-    """Layer ``l`` of a listed pattern, seeded. What a checkpoint would
-    bring and a seed has to choose is Mamba-2's published initialisation:
-    ``A_log = log(uniform[1, 16])``, ``dt_bias`` the inverse softplus of a
-    ``dt`` log-uniform over [0.001, 0.1], ``D = 1``, the convolution's taps
-    and bias uniform over ``+- 1 / sqrt(taps)`` (a depthwise convolution's
-    default)."""
-    kind, ffn = cfg.layer_kind(l)
+def init_mixer(r, cfg) -> dict:
+    """One state-space mixer, seeded from the rngs ``r`` yields. What a
+    checkpoint would bring and a seed has to choose is Mamba-2's published
+    initialisation: ``A_log = log(uniform[1, 16])``, ``dt_bias`` the inverse
+    softplus of a ``dt`` log-uniform over [0.001, 0.1], ``D = 1``, the
+    convolution's taps and bias uniform over ``+- 1 / sqrt(taps)`` (a
+    depthwise convolution's default)."""
     dt, d = cfg.dtype, cfg.dim
-    r = iter(jax.random.split(rng, 12))
-    # (a half-layer keeps the norm of the half it has, and no other)
-    layer = {name: jnp.ones((d,), F32)
-             for name, half in (("attn_norm", kind), ("mlp_norm", ffn))
-             if half != "none"}
-    if kind == "ssm":
-        h, inner = cfg.ssm_heads, cfg.ssm_heads * cfg.ssm_head_dim
-        width, bound = conv_width(cfg), cfg.ssm_conv ** -0.5
-        step = jnp.exp(jax.random.uniform(
-            next(r), (h,), F32, math.log(0.001), math.log(0.1)))
-        layer["ssm"] = {
-            "w_in": _dense(next(r), d, inner + width + h, dt),
-            "conv": jax.random.uniform(next(r), (cfg.ssm_conv, width), F32,
-                                       -bound, bound),
-            "conv_bias": jax.random.uniform(next(r), (width,), F32,
-                                            -bound, bound),
-            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
-            "a_log": jnp.log(jax.random.uniform(next(r), (h,), F32,
-                                                1.0, 16.0)),
-            "d_skip": jnp.ones((h,), F32),
-            "norm": jnp.ones((inner,), F32),
-            "w_out": _dense(next(r), inner, d, dt)}
-    elif kind == "conv":
-        from .shortconv import init_conv_mixer
-        layer["conv"] = init_conv_mixer(next(r), cfg)
-    elif kind == "full":
-        q_dim, kv_dim = cfg.n_heads * cfg.head_dim, \
-            cfg.n_kv_heads * cfg.head_dim
-        layer.update(wq=_dense(next(r), d, q_dim, dt),
-                     wk=_dense(next(r), d, kv_dim, dt),
-                     wv=_dense(next(r), d, kv_dim, dt),
-                     wo=_dense(next(r), q_dim, d, dt))
-        if cfg.qk_norm:
-            layer.update(q_norm=jnp.ones((cfg.head_dim,), F32),
-                         k_norm=jnp.ones((cfg.head_dim,), F32))
-    if ffn == "experts":
-        from .moe import init_moe_layer
-        from .transformer import moe_cfg
-        layer["moe"] = init_moe_layer(next(r), moe_cfg(cfg))
-    elif ffn == "dense":
-        layer["w_gate"] = _dense(next(r), d, cfg.hidden_dim, dt)
-        layer["w_up"] = _dense(next(r), d, cfg.hidden_dim, dt)
-        layer["w_down"] = _dense(next(r), cfg.hidden_dim, d, dt)
-    return layer
+    h, inner = cfg.ssm_heads, cfg.ssm_heads * cfg.ssm_head_dim
+    width, bound = conv_width(cfg), cfg.ssm_conv ** -0.5
+    step = jnp.exp(jax.random.uniform(
+        next(r), (h,), F32, math.log(0.001), math.log(0.1)))
+    return {
+        "w_in": dense_init(next(r), d, inner + width + h, dt),
+        "conv": jax.random.uniform(next(r), (cfg.ssm_conv, width), F32,
+                                   -bound, bound),
+        "conv_bias": jax.random.uniform(next(r), (width,), F32,
+                                        -bound, bound),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "a_log": jnp.log(jax.random.uniform(next(r), (h,), F32, 1.0, 16.0)),
+        "d_skip": jnp.ones((h,), F32),
+        "norm": jnp.ones((inner,), F32),
+        "w_out": dense_init(next(r), inner, d, dt)}
 
 
 def step_form(cfg) -> str:
@@ -345,10 +314,10 @@ def ssm_block(p: dict, u: jnp.ndarray, cfg, kv_cache: Optional[dict],
         # (the state as it is stored; the ``jax.numpy`` forms unpack it)
         state, tail = kvstate.lane_read(kv_cache, plane, "ssm")
     with jax.named_scope("attn.ssm.proj"):
-        # the gate's and dt's pre-activations stay float32 (``_project32``
+        # the gate's and dt's pre-activations stay float32 (``project32``
         # says why); the convolution's input is rounded to the model's type,
         # as its tail keeps it
-        proj = _project32(u, p["w_in"])
+        proj = project32(u, p["w_in"])
         z = proj[..., :inner]
         xbc, tail = causal_conv(proj[..., inner:inner + width].astype(u.dtype),
                                 p["conv"], tail, n_valid, p["conv_bias"])

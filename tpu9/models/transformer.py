@@ -23,7 +23,8 @@ from ..ops.attention import attention
 from ..ops.norms import rms_norm
 from ..ops.quant import maybe_matmul, project_heads
 from ..ops.rotary import apply_rope, rope_rows
-from . import kvstate
+from . import hybrid, kvstate, shortconv, ssm
+from .hybrid import dense_init
 
 Params = dict[str, Any]
 
@@ -77,8 +78,8 @@ class DecoderConfig:
     # attention (KDA) over a state a LANE that no cache holds. Of every
     # ``layer_group`` layers the last is MLA and the others KDA, so 1 is MLA
     # in every layer (no KDA layer, no state a lane); 0 = every layer is the
-    # plain attention above. :meth:`layer_kind` is the one place that says
-    # what layer ``l`` is
+    # plain attention above. However the pattern is spelled, ``layers`` is
+    # the one statement of what layer ``l`` is
     layer_group: int = 0
     # latent attention's widths: the cached latent, a head's unrotated and
     # rotated query/key parts, a head's value
@@ -123,7 +124,7 @@ class DecoderConfig:
     # LANE that no cache holds) or ``"full"`` (the plain attention above,
     # over per-head rows of a pool only as deep as there are such layers);
     # () = no list. ``layer_group`` is the one RULE (KDA closed by MLA) and
-    # stays what it was; :meth:`layer_kind` reads whichever is stated
+    # stays what it was; ``layers`` is built from whichever is stated
     layer_pattern: tuple = ()
     # the state-space mixer's sizes: heads, a head's width, the state's
     # width, the groups that share ``B`` and ``C``, the taps of the short
@@ -204,11 +205,12 @@ class DecoderConfig:
                     "for a cache of loop_steps planes a layer, and no "
                     "expert layer was ever run over a float32 stream")
 
-        from .ssm import refuse_unbuilt_list
-        refuse_unbuilt_list(self)
+        # what the layers ARE, once an instance and however they were
+        # spelled (derived: no field, so no part of equality or the hash)
+        object.__setattr__(self, "layers", _layer_list(self))
+        ssm.refuse_unbuilt_list(self)
         if self.layer_group:
-            from .hybrid import refuse_unbuilt_pattern
-            refuse_unbuilt_pattern(self)
+            hybrid.refuse_unbuilt_pattern(self)
         elif (self.mla_latent or self.kda_conv or self.mla_q_latent
               or self.rope_yarn or self.mla_mscale != 1.0
               or (self.moe_dense_layers and not self.conv_taps)
@@ -230,33 +232,37 @@ class DecoderConfig:
         return self.n_heads // self.n_kv_heads
 
     # -- what layer ``l`` is ---------------------------------------------------
+    # ``layers`` (``_layer_list``) is THE statement: a tuple of ``(attention,
+    # ffn)``, one a layer. Everything below reads it and nothing else; the
+    # named questions are the closed set of what a program, the state's
+    # format and the engine may ask of the pattern, each with its rule here.
 
     def layer_kind(self, l: int) -> tuple:
         """``(attention, ffn)`` of layer ``l`` (0-based): attention is
-        ``"full"`` (the plain attention of a uniform decoder), ``"kda"`` or
-        ``"mla"`` (the last layer of each group of ``layer_group``: every
-        layer where a group is one layer), or what ``layer_pattern`` lists
-        for the layer (``"ssm"``, ``"conv"``, ``"full"`` or ``"none"``); the ffn is
-        ``"dense"`` or ``"experts"``, or what ``ffn_pattern`` lists
-        (``"none"`` too: a layer of one half). THE one place that knows the
-        pattern: ``init_decoder``, the forward pass, the pool's depth and
-        the lanes' state all ask here."""
-        if self.layer_pattern:
-            attention = self.layer_pattern[l]
-            if self.ffn_pattern:
-                return attention, self.ffn_pattern[l]
-        elif not self.layer_group:
-            attention = "full"
-        else:
-            attention = "mla" if (l + 1) % self.layer_group == 0 else "kda"
-        experts = self.n_experts and l >= self.moe_dense_layers
-        return attention, "experts" if experts else "dense"
+        ``"full"`` (the plain attention of a uniform decoder), ``"kda"``,
+        ``"mla"``, ``"ssm"``, ``"conv"`` or ``"none"``; the ffn ``"dense"``,
+        ``"experts"`` or ``"none"`` (a layer of one half)."""
+        return self.layers[l]
 
     def layers_of(self, attention: str) -> tuple:
         """The layers whose attention is of this kind, in order: layer
         ``layers_of(kind)[p]`` keeps plane ``p`` of that kind's state."""
-        return tuple(l for l in range(self.n_layers)
-                     if self.layer_kind(l)[0] == attention)
+        return tuple(l for l, (kind, _) in enumerate(self.layers)
+                     if kind == attention)
+
+    @property
+    def uniform(self) -> bool:
+        """Whether every layer is the plain attention: the decoder that
+        takes a mesh, a balance loss and a pass loop. Otherwise the layers
+        are a PATTERN: its forward pass is told the live rows (``n_valid``)
+        and returns the experts its tokens chose."""
+        return all(kind == "full" for kind, _ in self.layers)
+
+    @property
+    def latent_rows(self) -> bool:
+        """Whether a cache row is one latent for all heads: latent
+        attention's, alone or closing groups of KDA layers."""
+        return any(kind in ("kda", "mla") for kind, _ in self.layers)
 
     @property
     def kv_row(self) -> tuple:
@@ -264,27 +270,50 @@ class DecoderConfig:
         token a layer. Latent attention keeps ONE latent for all heads under
         "k" and its one rotated key under "v": ``mla_latent + mla_rope``
         numbers a token."""
-        if self.layer_group:
+        if self.latent_rows:
             return (1, self.mla_latent), (1, self.mla_rope)
         pack = self.kv_pack
         return ((self.n_kv_heads // pack, self.head_dim * pack),) * 2
 
     @property
     def kv_pack(self) -> int:
-        """KV heads a cache row holds side by side (1 = a head a row):
-        ``models.kvstate.heads_per_row`` works it out from the head's
-        width."""
-        return kvstate.heads_per_row(self)
+        """KV heads a cache row holds side by side (1 = a head a row).
+        Worked out, not stated: per-head rows BESIDE state a lane (new with
+        the packing, PR 55) hold as many narrow heads as fill a row
+        (``models.kvstate.heads_per_row``). Everything else keeps a head a
+        row: latent rows have no heads, a window's summarise reads a head a
+        row, and a uniform decoder's programs stay what they were (the int8
+        pool's scale is one a (token, head), which a packed row would share;
+        beside state a lane the engine refuses ``kv_quant`` and a mesh)."""
+        if self.latent_rows or not self.lane_state:
+            return 1
+        return kvstate.heads_per_row(self.head_dim, self.n_kv_heads)
 
     @property
     def lane_state(self) -> tuple:
         """The attention kinds of this decoder that keep state by LANE
         (``models.kvstate.lane_shapes``), in layer order of first use; ()
         for a decoder whose whole state is paged."""
-        if not (self.layer_group or self.layer_pattern):
-            return ()
-        kinds = [self.layer_kind(l)[0] for l in range(self.n_layers)]
+        kinds = {kind for kind, _ in self.layers}
         return tuple(k for k in ("kda", "ssm", "conv") if k in kinds)
+
+    @property
+    def wide_stream(self) -> bool:
+        """Whether the residual stream is carried in float32 while every
+        sub-layer computes in the embeddings' type: under ``attn_window``,
+        in the pass loop (``_looped_passes`` says why), and for per-head
+        rows beside state a lane — eighty branches times ``residual_mult``
+        each round a bfloat16 stream whole, which was a third of the served
+        program's distance from the float32 reference (PERF.md section 6,
+        PR 55)."""
+        return bool(self.attn_window or self.looped
+                    or (self.lane_state and not self.latent_rows))
+
+    @property
+    def pattern_label(self) -> str:
+        """The pattern as it was stated, for a refusal's message alone."""
+        return f"layer_group={self.layer_group}" if self.layer_group \
+            else "layer_pattern"
 
     @property
     def looped(self) -> bool:
@@ -295,14 +324,11 @@ class DecoderConfig:
     @property
     def kv_layers(self) -> int:
         """Depth of the KV state: a token owns one plane of keys and
-        values per (pass, layer), although the weights have ``n_layers``."""
-        if self.layer_group:
-            # only the latent-attention layers have a cache at all
-            return len(self.layers_of("mla"))
-        if self.layer_pattern:
-            # a listed pattern: only its plain-attention layers
-            return len(self.layers_of("full"))
-        return self.n_layers * self.loop_steps
+        values per (pass, layer) that attends over rows — a pattern's
+        latent-attention or plain-attention layers alone — although the
+        weights have ``n_layers``."""
+        return len(self.layers_of("mla") + self.layers_of("full")) \
+            * self.loop_steps
 
     # -- where a token lives in its cache -----------------------------------
     # ``pos // block`` used to be the place of a token in its cache
@@ -344,75 +370,128 @@ class DecoderConfig:
                    self.kv_entry(n - 1) + 1)
 
 
-def _dense_init(rng, in_dim: int, out_dim: int, dtype) -> jnp.ndarray:
-    scale = (2.0 / (in_dim + out_dim)) ** 0.5
-    return (jax.random.normal(rng, (in_dim, out_dim), dtype=jnp.float32)
-            * scale).astype(dtype)
+def _layer_list(cfg: DecoderConfig) -> tuple:
+    """``DecoderConfig.layers``: ``(attention, ffn)`` of every layer, from
+    whichever spelling the constructor was given — the rule (``layer_group``:
+    of every group the last layer ``"mla"``, the others ``"kda"``), the list
+    (``layer_pattern``), the two lists (``ffn_pattern`` beside it) or none
+    (every layer ``"full"``); without an ``ffn_pattern`` the first
+    ``moe_dense_layers`` layers are ``"dense"`` and the rest ``"experts"``
+    where there are any. The one reader of the spelling beside the refusals
+    ``__post_init__`` calls next, which turn away a spelling that is wrong
+    (a list of another length or of unknown words, both a rule and a list,
+    an ``ffn_pattern`` alone) in the order they always did: tests pin which
+    refusal a configuration with several faults meets first."""
+    rule, n = cfg.layer_group, cfg.n_layers
+    attention = cfg.layer_pattern or tuple(
+        ("mla" if (l + 1) % rule == 0 else "kda") if rule else "full"
+        for l in range(n))
+    ffn = cfg.layer_pattern and cfg.ffn_pattern or tuple(
+        "experts" if cfg.n_experts and l >= cfg.moe_dense_layers else "dense"
+        for l in range(n))
+    return tuple(zip(attention, ffn))
 
 
 def init_decoder(rng: jax.Array, cfg: DecoderConfig) -> Params:
-    n_rngs = cfg.n_layers * 7 + 3
-    rngs = jax.random.split(rng, n_rngs)
-    it = iter(range(n_rngs))
+    keys = iter(jax.random.split(rng, cfg.n_layers * 7 + 3))
     dt = cfg.dtype
-
-    def nxt():
-        return rngs[next(it)]
-
     params: Params = {
         # (a table that is fed in times ``embed_mult`` is seeded that much
         # smaller, so that the stream starts where an unscaled table's
         # does: at 0.02 x 12 a token's own row of a TIED head outweighs
         # every other logit and a seeded model only repeats its input)
-        "embed": (jax.random.normal(nxt(), (cfg.vocab_size, cfg.dim),
+        "embed": (jax.random.normal(next(keys), (cfg.vocab_size, cfg.dim),
                                     dtype=jnp.float32)
                   * (0.02 / cfg.embed_mult)).astype(dt),
         "final_norm": jnp.ones((cfg.dim,), dtype=jnp.float32) - cfg.norm_offset,
         "layers": [],
     }
     if not cfg.tie_embeddings:
-        params["lm_head"] = _dense_init(nxt(), cfg.dim, cfg.vocab_size, dt)
+        params["lm_head"] = dense_init(next(keys), cfg.dim, cfg.vocab_size,
+                                       dt)
     else:
-        nxt()
+        next(keys)
     if cfg.exit_gate:
         params["exit_gate"] = init_exit_gate(rng, cfg)
 
+    if not cfg.uniform:
+        # a pattern's layers have trees of their own; their rngs are folded
+        # from the tree's, as every later addition's are
+        params["layers"] = [
+            init_pattern_layer(jax.random.fold_in(rng, li), cfg, li)
+            for li in range(cfg.n_layers)]
+        return params
     q_dim = cfg.n_heads * cfg.head_dim
     kv_dim = cfg.n_kv_heads * cfg.head_dim
     for li in range(cfg.n_layers):
-        if cfg.layer_group:
-            # a pattern's layers have trees of their own; their rngs are
-            # folded from the tree's, as every later addition's are
-            from .hybrid import init_hybrid_layer
-            params["layers"].append(init_hybrid_layer(
-                jax.random.fold_in(rng, li), cfg, li))
-            continue
-        if cfg.layer_pattern:
-            from .ssm import init_listed_layer
-            params["layers"].append(init_listed_layer(
-                jax.random.fold_in(rng, li), cfg, li))
-            continue
         layer = {
             "attn_norm": jnp.ones((cfg.dim,), dtype=jnp.float32) - cfg.norm_offset,
             "mlp_norm": jnp.ones((cfg.dim,), dtype=jnp.float32) - cfg.norm_offset,
-            "wq": _dense_init(nxt(), cfg.dim, q_dim, dt),
-            "wk": _dense_init(nxt(), cfg.dim, kv_dim, dt),
-            "wv": _dense_init(nxt(), cfg.dim, kv_dim, dt),
-            "wo": _dense_init(nxt(), q_dim, cfg.dim, dt),
+            "wq": dense_init(next(keys), cfg.dim, q_dim, dt),
+            "wk": dense_init(next(keys), cfg.dim, kv_dim, dt),
+            "wv": dense_init(next(keys), cfg.dim, kv_dim, dt),
+            "wo": dense_init(next(keys), q_dim, cfg.dim, dt),
         }
         if cfg.n_experts:
-            from .moe import MoeConfig, init_moe_layer
+            from .moe import init_moe_layer
             layer["moe"] = init_moe_layer(
-                jax.random.fold_in(nxt(), li), moe_cfg(cfg))
-            nxt(), nxt()   # keep the rng schedule aligned with dense
+                jax.random.fold_in(next(keys), li), moe_cfg(cfg))
+            next(keys), next(keys)   # the rng schedule stays the dense one
         else:
-            layer["w_gate"] = _dense_init(nxt(), cfg.dim, cfg.hidden_dim, dt)
-            layer["w_up"] = _dense_init(nxt(), cfg.dim, cfg.hidden_dim, dt)
-            layer["w_down"] = _dense_init(nxt(), cfg.hidden_dim, cfg.dim, dt)
+            layer.update(_init_dense_ffn(keys, cfg))
         layer.update(init_post_norms(cfg))
         layer.update(init_summary_vectors(jax.random.fold_in(rng, li), cfg))
         params["layers"].append(layer)
     return params
+
+
+def _init_dense_ffn(r, cfg: DecoderConfig) -> Params:
+    """The dense SwiGLU's three matrices, their rngs drawn from ``r``."""
+    d, hidden, dt = cfg.dim, cfg.hidden_dim, cfg.dtype
+    return {"w_gate": dense_init(next(r), d, hidden, dt),
+            "w_up": dense_init(next(r), d, hidden, dt),
+            "w_down": dense_init(next(r), hidden, d, dt)}
+
+
+def init_pattern_layer(rng: jax.Array, cfg: DecoderConfig, l: int) -> Params:
+    """Layer ``l`` of a pattern, seeded: a norm for each half it has, its
+    attention kind's tree (what a seed has to choose for a kind is said where
+    the kind is: ``hybrid.init_kda`` / ``init_mla``, ``ssm.init_mixer``,
+    ``shortconv.init_conv_mixer``), then its feed-forward part. Every kind
+    draws from the one split in its own order, and the split is the width
+    its family always had (16 for the latent family's kinds, 12 for a
+    list's): a seeded tree is part of what the benchmark serves."""
+    attention, ffn = cfg.layers[l]
+    d, dt = cfg.dim, cfg.dtype
+    r = iter(jax.random.split(rng, 16 if attention in ("kda", "mla") else 12))
+    # (a half-layer keeps the norm of the half it has, and no other)
+    layer = {name: jnp.ones((d,), jnp.float32)
+             for name, half in (("attn_norm", attention), ("mlp_norm", ffn))
+             if half != "none"}
+    if attention == "kda":
+        layer["kda"] = hybrid.init_kda(r, cfg)
+    elif attention == "mla":
+        layer["mla"] = hybrid.init_mla(r, cfg)
+    elif attention == "ssm":
+        layer["ssm"] = ssm.init_mixer(r, cfg)
+    elif attention == "conv":
+        layer["conv"] = shortconv.init_conv_mixer(next(r), cfg)
+    elif attention == "full":
+        q_dim, kv_dim = cfg.n_heads * cfg.head_dim, \
+            cfg.n_kv_heads * cfg.head_dim
+        layer.update(wq=dense_init(next(r), d, q_dim, dt),
+                     wk=dense_init(next(r), d, kv_dim, dt),
+                     wv=dense_init(next(r), d, kv_dim, dt),
+                     wo=dense_init(next(r), q_dim, d, dt))
+        if cfg.qk_norm:
+            layer.update(q_norm=jnp.ones((cfg.head_dim,), jnp.float32),
+                         k_norm=jnp.ones((cfg.head_dim,), jnp.float32))
+    if ffn == "experts":
+        from .moe import init_moe_layer
+        layer["moe"] = init_moe_layer(next(r), moe_cfg(cfg))
+    elif ffn == "dense":
+        layer.update(_init_dense_ffn(r, cfg))
+    return layer
 
 
 def init_summary_vectors(rng: jax.Array, cfg: DecoderConfig) -> Params:
@@ -444,8 +523,7 @@ def init_exit_gate(rng: jax.Array, cfg: DecoderConfig) -> Params:
     """The exit gate: one logit a position, ``w . h + b``, float32 like
     the norm vectors. Its rng is folded from the tree's own, so the
     schedule of every other leaf is the plain decoder's."""
-    w = _dense_init(jax.random.fold_in(rng, cfg.dim), cfg.dim, 1,
-                    jnp.float32)
+    w = dense_init(jax.random.fold_in(rng, cfg.dim), cfg.dim, 1, jnp.float32)
     return {"w": w[:, 0], "b": jnp.zeros((1,), jnp.float32)}
 
 
@@ -514,42 +592,36 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
                 positions: jnp.ndarray, sin, cos,
                 kv_cache: Optional[Params], layer_idx: int,
                 cache_len: Optional[jnp.ndarray], decode: bool,
-                mesh=None, compute_dtype=None, n_valid=None):
-    """One layer's attention: project, rotate, ``kvstate.write``,
-    ``kvstate.attend``, project out. Returns ``(x, kv_cache)``: the cache
-    dict is carried WHOLE from layer to layer — this layer's k/v are written
-    into the ``[L, ...]`` arrays in place and read at ``layer_idx``; nothing
-    is sliced out and re-stacked."""
+                mesh=None, compute_dtype=None, n_valid=None, cache_base=0):
+    """Layer ``layer_idx``'s attention, by its kind. The plain attention:
+    project, rotate, ``kvstate.write``, ``kvstate.attend``, project out; the
+    other kinds in their modules. Returns ``(x, kv_cache)``: the cache dict
+    is carried WHOLE from layer to layer — this layer's k/v (or its state a
+    lane) are written into the ``[L, ...]`` arrays in place and read at the
+    layer's PLANE, ``cache_base`` plus its place among the layers of its
+    kind; nothing is sliced out and re-stacked."""
     b, t, _ = x.shape
+    kind = cfg.layers[layer_idx][0]
+    # (a uniform decoder's is ``cache_base + layer_idx``: a Python int where
+    # the base is one)
+    plane = cache_base + cfg.layers_of(kind).index(layer_idx)
     h = _pre_norm(x, layer["attn_norm"], cfg, compute_dtype)
-    if cfg.layer_group:
-        # a pattern: this layer's kind and its plane of that kind's state
-        from .hybrid import kda_block, mla_block
-        kind = cfg.layer_kind(layer_idx)[0]
-        plane = cfg.layers_of(kind).index(layer_idx)
+    if kind != "full":
         if kind == "kda":
-            y, kv_cache = kda_block(layer["kda"], h, cfg, kv_cache, plane,
-                                    decode, n_valid)
+            y, kv_cache = hybrid.kda_block(layer["kda"], h, cfg, kv_cache,
+                                           plane, decode, n_valid)
+        elif kind == "mla":
+            y, kv_cache = hybrid.mla_block(
+                layer["mla"], h, cfg, positions, sin, cos, kv_cache, plane,
+                cache_len, decode)
+        elif kind == "ssm":
+            y, kv_cache = ssm.ssm_block(layer["ssm"], h, cfg, kv_cache,
+                                        plane, decode, n_valid)
         else:
-            y, kv_cache = mla_block(layer["mla"], h, cfg, positions, sin,
-                                    cos, kv_cache, plane, cache_len, decode)
-        return x + y, kv_cache
-    if cfg.layer_pattern:
-        # a listed pattern: a state-space layer, or plain attention whose
-        # plane is its place among the plain-attention layers
-        kind = cfg.layer_kind(layer_idx)[0]
-        plane = cfg.layers_of(kind).index(layer_idx)
-        if kind == "ssm":
-            from .ssm import ssm_block
-            y, kv_cache = ssm_block(layer["ssm"], h, cfg, kv_cache, plane,
-                                    decode, n_valid)
-            return _residual(x, y, cfg), kv_cache
-        if kind == "conv":
-            from .shortconv import conv_block
-            y, kv_cache = conv_block(layer["conv"], h, cfg, kv_cache, plane,
-                                     decode, n_valid, positions)
-            return _residual(x, y, cfg), kv_cache
-        layer_idx = plane
+            y, kv_cache = shortconv.conv_block(
+                layer["conv"], h, cfg, kv_cache, plane, decode, n_valid,
+                positions)
+        return _residual(x, y, cfg), kv_cache
     with jax.named_scope("attn.qkv"):
         q = project_heads(h, layer["wq"], cfg.n_heads, cfg.head_dim)
         k = project_heads(h, layer["wk"], cfg.n_kv_heads, cfg.head_dim)
@@ -607,8 +679,8 @@ def _attn_block(layer: Params, x: jnp.ndarray, cfg: DecoderConfig,
         # whatever form the cache has (``models.kvstate``): this layer's
         # keys and values go where their entries say, then its queries
         # attend over what is written
-        kv_cache = kvstate.write(kv_cache, layer_idx, k, v, entries, decode)
-        out = kvstate.attend(kv_cache, layer_idx, q, k, v, entries,
+        kv_cache = kvstate.write(kv_cache, plane, k, v, entries, decode)
+        out = kvstate.attend(kv_cache, plane, q, k, v, entries,
                              cache_len, decode, mesh)
 
     with jax.named_scope("attn.out"):
@@ -683,20 +755,33 @@ def _live_rows(n_valid, t: int):
 
 def _layers(params: Params, x, cfg: DecoderConfig, positions, sin, cos,
             kv_cache, cache_base, cache_len, decode: bool, mesh,
-            moe_balance, compute_dtype=None, live=None):
-    """Every layer once. Layer ``l`` keeps its keys and values at plane
-    ``cache_base + l`` of the cache: 0 for a plain decoder (the plane is
-    then a Python int, as it always was), a traced ``u * n_layers`` inside
-    the pass loop of a looped one. ``live`` bool [B, T]: the rows that are
-    real, where the caller says (a decode step's lanes). Returns ``(x,
-    kv_cache, moe_balance, picks)``: ``picks`` the experts every token chose
-    in the expert layers that say so, int32 [B, T, layers, top_k] — those of
-    a step that read only the experts its live rows picked — or None."""
+            moe_balance, compute_dtype=None, n_valid=None):
+    """Every layer once, each half it has (``cfg.layers``; ``"none"`` is the
+    half a listed layer lacks). The planes a layer keeps start at
+    ``cache_base``: 0 for a decoder without a pass loop (a plane is then a
+    Python int, as it always was), a traced ``u * n_layers`` inside the loop.
+    ``n_valid`` int32 [B]: how many of each row's tokens are real, where the
+    caller says (a padded chunk tail, an idle decode lane) — they alone
+    advance state a lane, and an expert layer at few rows reads the experts
+    THEY picked; None: all. Returns ``(x, kv_cache, moe_balance, picks)``:
+    ``picks`` the experts every token chose in the expert layers that say
+    so, int32 [B, T, layers, top_k] (global ids; a padded row's are whatever
+    its padding chose: the caller knows which rows are real), or None."""
+    b, t, _ = x.shape
+    live = _live_rows(n_valid, t)
+    if n_valid is None and not cfg.uniform:
+        # (a pattern's blocks are always told; a uniform decoder's trace
+        # keeps no constant that nothing reads)
+        n_valid = jnp.full((b,), t, jnp.int32)
     picks = []
-    for i, layer in enumerate(params["layers"]):
-        x, kv_cache = _attn_block(layer, x, cfg, positions, sin, cos,
-                                  kv_cache, cache_base + i, cache_len,
-                                  decode, mesh, compute_dtype)
+    for i, (layer, (attention, ffn)) in enumerate(
+            zip(params["layers"], cfg.layers)):
+        if attention != "none":
+            x, kv_cache = _attn_block(layer, x, cfg, positions, sin, cos,
+                                      kv_cache, i, cache_len, decode, mesh,
+                                      compute_dtype, n_valid, cache_base)
+        if ffn == "none":
+            continue
         x, aux = _mlp_block(layer, x, cfg, compute_dtype,
                             serving=kv_cache is not None, mesh=mesh,
                             live=live)
@@ -708,40 +793,6 @@ def _layers(params: Params, x, cfg: DecoderConfig, positions, sin, cos,
             moe_balance = moe_balance + aux["balance_loss"]
     return x, kv_cache, moe_balance, \
         jnp.stack(picks, axis=2) if picks else None
-
-
-def _pattern_layers(params: Params, x, cfg: DecoderConfig, positions, sin,
-                    cos, kv_cache, cache_len, decode: bool, n_valid,
-                    compute_dtype=None):
-    """Every layer of a layer pattern once (``layer_group``). ``n_valid``
-    [B]: how many of each row's tokens are real — a padded chunk tail and an
-    idle lane advance no KDA state; None: all. Returns ``(x, kv_cache,
-    picks)``: the global ids of the experts every token chose in every
-    expert layer, int32 [B, T, expert layers, top_k] (a padded row's are
-    whatever its padding chose: the caller knows which rows are real).
-    ``compute_dtype``: as for ``_layers`` (a float32 stream whose sub-layers
-    compute in that type; None: the stream's own)."""
-    b, t, _ = x.shape
-    # the rows that are real: the expert layer of a decode step reads the
-    # experts THEY picked (an idle lane's padding picks nothing)
-    live = _live_rows(n_valid, t)
-    if n_valid is None:
-        n_valid = jnp.full((b,), t, jnp.int32)
-    picks = []
-    for i, layer in enumerate(params["layers"]):
-        # (a listed layer may be one half alone: ``"none"`` is the other)
-        attention, ffn = cfg.layer_kind(i)
-        if attention != "none":
-            x, kv_cache = _attn_block(layer, x, cfg, positions, sin, cos,
-                                      kv_cache, i, cache_len, decode,
-                                      compute_dtype=compute_dtype,
-                                      n_valid=n_valid)
-        if ffn == "none":
-            continue
-        x, aux = _mlp_block(layer, x, cfg, compute_dtype, live=live)
-        if aux is not None:
-            picks.append(aux["picks"])
-    return x, kv_cache, jnp.stack(picks, axis=2) if picks else None
 
 
 def _looped_passes(params: Params, x, cfg: DecoderConfig, positions, sin,
@@ -876,26 +927,15 @@ def decoder_forward(params: Params, tokens: jnp.ndarray, cfg: DecoderConfig,
     moe_balance = jnp.zeros((), jnp.float32)
     exit_info = moe_picks = None
     if not cfg.looped:
-        # with ``attn_window``, and under a listed pattern, the residual
-        # stream is float32 and every sub-layer still computes in the
-        # embeddings' type, as in the pass loop (``_looped_passes``); None:
-        # the stream's own type throughout. (A listed pattern: eighty
-        # branches times ``residual_mult`` each round a bfloat16 stream
-        # whole, which was a third of the served program's distance from
-        # the float32 reference: PERF.md section 6, PR 55)
-        compute_dtype = x.dtype if cfg.attn_window or cfg.layer_pattern \
-            else None
+        # a stream carried in float32 (``cfg.wide_stream``), every sub-layer
+        # still computing in the embeddings' type, as in the pass loop
+        # (``_looped_passes``); None: the stream's own type throughout
+        compute_dtype = x.dtype if cfg.wide_stream else None
         if compute_dtype is not None:
             x = x.astype(jnp.float32)
-        if cfg.layer_group or cfg.layer_pattern:
-            x, kv_cache, moe_picks = _pattern_layers(
-                params, x, cfg, positions, sin, cos, kv_cache, cache_len,
-                decode, n_valid, compute_dtype)
-        else:
-            x, kv_cache, moe_balance, moe_picks = _layers(
-                params, x, cfg, positions, sin, cos, kv_cache, 0, cache_len,
-                decode, mesh, moe_balance, compute_dtype,
-                _live_rows(n_valid, t))
+        x, kv_cache, moe_balance, moe_picks = _layers(
+            params, x, cfg, positions, sin, cos, kv_cache, 0, cache_len,
+            decode, mesh, moe_balance, compute_dtype, n_valid)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_offset)
         if compute_dtype is not None:
             x = x.astype(compute_dtype)

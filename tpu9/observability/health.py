@@ -222,9 +222,7 @@ _KVTIER_GAUGES = (
     ("tpu9_kvtier_uppage_failures", "kvtier_uppage_failures"),
     ("tpu9_kvtier_peer_spills", "kvtier_peer_spills"),
     ("tpu9_kvtier_hits_device", "kvtier_hits_device"),
-    ("tpu9_kvtier_hits_host", "kvtier_hits_host"),
-    ("tpu9_kvtier_downpage_p95_s", "kvtier_downpage_p95_s"),
-    ("tpu9_kvtier_uppage_p95_s", "kvtier_uppage_p95_s"))
+    ("tpu9_kvtier_hits_host", "kvtier_hits_host"))
 
 
 def forget_replica(container_id: str) -> None:

@@ -130,7 +130,7 @@ def _random_quantized(rng, in_dim: int, out_dim: int) -> dict:
     16 GiB-HBM chip just to quantize it down to 8 GiB."""
     rq, rs = jax.random.split(rng)
     q = jax.random.randint(rq, (in_dim, out_dim), -127, 128, dtype=jnp.int8)
-    # per-output-channel scales matching _dense_init's variance:
+    # per-output-channel scales matching ``hybrid.dense_init``'s variance:
     # std = sqrt(2/(in+out)); int8 values ~U[-127,127] have std ~73, so
     # scale ≈ std/73 reproduces the dense init's magnitude
     std = (2.0 / (in_dim + out_dim)) ** 0.5

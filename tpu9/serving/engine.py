@@ -241,10 +241,10 @@ def refuse_unbuilt_with_lane_state(cfg, ecfg: "EngineConfig",
     running lane, so there is no path that drops a lane's state without
     re-prefilling it; one that is added has to snapshot or re-prefill
     (ROADMAP R6)."""
-    latent, state = bool(cfg.layer_group), cfg.lane_state
+    latent, state = cfg.latent_rows, cfg.lane_state
     layers = " and ".join(SNAPSHOT[kind] for kind in state)
-    label = f"layer_group={cfg.layer_group}" if latent else \
-        f"layer_pattern with state a lane ({'/'.join(state)})"
+    label = cfg.pattern_label if latent else \
+        f"{cfg.pattern_label} with state a lane ({'/'.join(state)})"
 
     def refuse(what: str, why: str):
         raise ValueError(f"{label} with {what}: {why}")
@@ -476,20 +476,20 @@ class InferenceEngine:
         self.paged = engine_cfg.kv_block_size > 0
         if cfg.attn_window:
             refuse_unbuilt_with_summaries(cfg, engine_cfg, topo)
-        if cfg.layer_group or cfg.lane_state:
+        if not cfg.uniform:
             refuse_unbuilt_with_lane_state(cfg, engine_cfg, topo)
             from ..ops.quant import is_quantized_entry
             if any(is_quantized_entry(leaf) for leaf in
                    jax.tree_util.tree_leaves(
                        params, is_leaf=is_quantized_entry)):
                 raise ValueError(
-                    f"layer_group={cfg.layer_group} with int8 weights: the "
-                    "pattern's layers and the held experts' einsum are "
-                    "built for the model's own type" if cfg.layer_group else
-                    "layer_pattern with int8 weights: the listed mixers' "
-                    "projections (and a list's expert kernels, which "
-                    "read the stacks as they are stored) are built for the "
-                    "model's own type")
+                    f"{cfg.pattern_label} with int8 weights: " + (
+                        "the pattern's layers and the held experts' einsum "
+                        "are built for the model's own type"
+                        if cfg.latent_rows else
+                        "the listed mixers' projections (and a list's "
+                        "expert kernels, which read the stacks as they are "
+                        "stored) are built for the model's own type"))
         from ..ops.quant import validate_quant_mode
         _kvq = validate_quant_mode(engine_cfg.kv_quant, "kv_quant")
         if _kvq and _kvq != "int8":
@@ -690,9 +690,9 @@ class InferenceEngine:
         if kvstate.block_tail_shapes(cfg, 1):
             self._stats.update(conv_tail_blocks_written=0,
                                conv_tail_restores=0)
-        if cfg.layer_group or cfg.lane_state:
+        if not cfg.uniform:
             self._state_bytes = kvstate.lane_bytes(cfg, b)
-        if cfg.layer_group or cfg.n_experts:
+        if cfg.n_experts:
             self._stats.update(moe_local_picks=0, moe_token_layers=0,
                                moe_held_touched=0, moe_step_layers=0)
             self._held_pick_hist = np.zeros((cfg.n_experts,), np.int64)
@@ -801,7 +801,7 @@ class InferenceEngine:
         # an expert layer that is told what it holds (a pattern's, by rule
         # or list) says in every program which experts each token chose,
         # and a finished request leaves them in ``routed_experts``
-        self._keeps_routing = bool(cfg.layer_group or cfg.moe_routed)
+        self._keeps_routing = bool(cfg.moe_routed)
         # ---- replica health plane (ISSUE 14) ----
         # liveness watermark: monotonic progress counters + dispatch/
         # progress stamps the runner-side watchdog classifies from. All
@@ -851,7 +851,7 @@ class InferenceEngine:
         an update and pages a wave). A TPU replica whose attention declined
         a kernel still serves correctly through the oracle, so say why once
         here, where an operator reads the bring-up log."""
-        if self.cfg.layer_group:
+        if self.cfg.latent_rows:
             # a layer pattern: latent attention (``ops.latent_attention``)
             # and the KDA recurrence (``ops.delta_rule``), each a kernel on
             # the chip at whole tiles and ``jax.numpy`` elsewhere
@@ -907,9 +907,8 @@ class InferenceEngine:
         says of the attention — and ``moe_latent`` (0 = none). Empty for a
         uniform decoder."""
         cfg, out = self.cfg, {}
-        if cfg.layer_group or cfg.layer_pattern:
-            kinds = [k for l in range(cfg.n_layers)
-                     for k in cfg.layer_kind(l) if k != "none"]
+        if not cfg.uniform:
+            kinds = [k for layer in cfg.layers for k in layer if k != "none"]
             out["layers_by_kind"] = {k: kinds.count(k)
                                      for k in dict.fromkeys(kinds)}
         if cfg.moe_routed:
@@ -1387,8 +1386,7 @@ class InferenceEngine:
         window summaries, whose blocks are addressed by cache entry and not
         by token position (the kvwire format's ``n_tokens``): the caller
         re-prefills, as for any miss."""
-        if not self.paged or self.cfg.attn_window or self.cfg.layer_group \
-                or self.cfg.lane_state:
+        if not self.paged or self.cfg.attn_window or not self.cfg.uniform:
             # (a layer pattern: the format ships rows and no state a lane)
             return None
         for slot in range(self.ecfg.max_batch):
@@ -1579,13 +1577,13 @@ class InferenceEngine:
         if self.cfg.looped:
             out["loop_steps"] = self.cfg.loop_steps
             out["loop_exit_hist"] = list(self._loop_exit_hist)
-        if self.cfg.layer_group or self.cfg.lane_state:
+        if not self.cfg.uniform:
             out["state_kinds"] = list(self.cfg.lane_state)
             out["state_bytes"] = self._state_bytes
             out["state_bytes_per_lane"] = \
                 self._state_bytes // self.ecfg.max_batch
             out["state_lanes_in_use"] = int(self.active.sum())
-        if self.cfg.layer_group or self.cfg.n_experts:
+        if self.cfg.n_experts:
             out["moe_experts_held"] = self.cfg.n_experts
             out["moe_held_pick_hist"] = [int(n) for n in
                                          self._held_pick_hist]

@@ -225,8 +225,7 @@ class GraphFactory:
             # a looped decoder also says which pass the head read and how
             # many passes ran: ``exits`` is ``(exit_info [B, 1, 2],)`` for it
             # and empty for a plain one
-            if cfg.layer_group or cfg.lane_state \
-                    or (cfg.n_experts and not cfg.looped):
+            if not cfg.uniform or (cfg.n_experts and not cfg.looped):
                 # the step says which lanes are live: an idle lane advances
                 # no KDA state of a layer pattern and puts no expert on the
                 # list of those an expert layer reads; the step then also
@@ -379,7 +378,7 @@ class GraphFactory:
         positions = offset + jnp.arange(width)[None, :]
         if self.cfg.attn_window:
             scratch = self.traced_summarise_scratch(params, scratch, offset)
-        if self.cfg.layer_group or self.cfg.lane_state:
+        if not self.cfg.uniform:
             # the scratch carries the admitted sequence's KDA state from
             # chunk to chunk: zero where the sequence starts, advanced by the
             # chunk's real tokens alone (its tail is padding)
@@ -593,8 +592,7 @@ class GraphFactory:
         policy = self.policy
         # a prefix cache over latent pages (a pattern with no state a lane:
         # with state the cache is refused and this program is never run)
-        flat = bool(self.cfg.layer_group) \
-            and not kvstate.lane_shapes(self.cfg, 1)
+        flat = self.cfg.latent_rows and not self.cfg.lane_state
 
         def build():
             @jax.named_scope("kv.gather")
