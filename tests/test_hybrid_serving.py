@@ -87,12 +87,13 @@ def programs(params):
     first_tok = int(np.asarray(got["partial"]).argmax())
     last = jnp.asarray([[first_tok], [0]], jnp.int32)
     clen = jnp.asarray([n, 0], jnp.int32)
-    active = jnp.asarray([True, False])
+    # steps each lane may run a call: lane 1 is idle
+    steps = jnp.asarray([8, 0], jnp.int32)
     key = jax.random.PRNGKey(0)
     served = [first_tok]
     for k in (1,) + (8,) * 5 + (1,):
         last, kv, clen, key, toks, chose = graphs.decode_k(k)(
-            params, kv, last, clen, active, key)
+            params, kv, last, clen, steps, key)
         served += np.asarray(toks)[:, 0].tolist()
         picks.append(np.asarray(chose)[:, 0])
     got.update(served=served, cache_len=int(clen[0]), idle_len=int(clen[1]),

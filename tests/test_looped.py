@@ -114,12 +114,14 @@ def programs(params):
         last = jnp.asarray([[first_tok], [0]], jnp.int32)
         clen = jnp.asarray([n, 0], jnp.int32)
         active = jnp.asarray([True, False])
+        # steps each lane may run a decode call: lane 1 is idle
+        steps = jnp.asarray([8, 0], jnp.int32)
         key = jax.random.PRNGKey(0)
         served = [first_tok]
         got["exits"] = []
         for k in (1, 8):
             last, kv, clen, key, toks, exits = graphs.decode_k(k)(
-                params, kv, last, clen, active, key)
+                params, kv, last, clen, steps, key)
             served += np.asarray(toks)[:, 0].tolist()
             got["exits"] += np.asarray(exits)[:, 0, 0].tolist()
             got["ran"] = got.get("ran", []) + \
@@ -264,7 +266,8 @@ def test_a_plain_decoder_has_no_loop_counters_and_no_loop_scope():
     assert st["kv_layers"] == PLAIN.n_layers
     text = engine.graphs.decode_k(1).lower(
         params, engine.kv_cache, engine.last_token, engine.cache_len,
-        jnp.asarray(engine.active), engine._rng).compile().as_text()
+        jnp.asarray(engine.active, jnp.int32), engine._rng
+        ).compile().as_text()
     assert not hlo_scopes(text, LOOP_SCOPES)
     assert hlo_scopes(text, DEVICE_SCOPES)
 
@@ -275,7 +278,8 @@ def test_a_looped_decode_program_names_the_loop_scopes(params):
     engine = InferenceEngine(params, TINY, _ecfg())
     text = engine.graphs.decode_k(1).lower(
         params, engine.kv_cache, engine.last_token, engine.cache_len,
-        jnp.asarray(engine.active), engine._rng).compile().as_text()
+        jnp.asarray(engine.active, jnp.int32), engine._rng
+        ).compile().as_text()
     scopes = hlo_scopes(text, DEVICE_SCOPES + LOOP_SCOPES)
     assert set(LOOP_SCOPES) <= set(scopes), sorted(scopes)
     # what the engine reports on /health after a precompile knows them too

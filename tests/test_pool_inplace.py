@@ -236,18 +236,18 @@ def test_k4_window_equals_four_single_steps(models, quant):
     cache["table"] = _table()
     last = jnp.asarray([[7], [11]], jnp.int32)
     clen = jnp.asarray([14, 30], jnp.int32)         # row 0 crosses a block
-    active = jnp.asarray([True, True])
+    steps = jnp.asarray([4, 4], jnp.int32)       # neither lane is parked
     key = jax.random.PRNGKey(0)
 
     def fresh():                                    # the pool is donated
         return {n: jnp.array(a) for n, a in cache.items()}
 
     last4, kv4, clen4, _, toks4 = graphs.decode_k(4)(
-        params, fresh(), last, clen, active, key)
+        params, fresh(), last, clen, steps, key)
     kv, step_last, step_len, r, toks = fresh(), last, clen, key, []
     for _ in range(4):
         step_last, kv, step_len, r, tok = graphs.decode_k(1)(
-            params, kv, step_last, step_len, active, r)
+            params, kv, step_last, step_len, steps, r)
         toks.append(np.asarray(tok)[0])
     np.testing.assert_array_equal(np.asarray(toks4), np.stack(toks))
     np.testing.assert_array_equal(np.asarray(last4), np.asarray(step_last))

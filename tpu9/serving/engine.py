@@ -345,6 +345,10 @@ class _Window:
     toks: Any                 # device [k, B] (decode) / [B, k] (verify)
     mask: Any                 # np active snapshot at dispatch
     reqs: tuple               # slot_req snapshot at dispatch
+    steps: Any                # np int32 [B]: the steps each lane was given
+    #                           (decode: the program parks it after them; a
+    #                           verify window: ``k`` for every lane in it)
+    parked: int = 0           # lane-steps its lanes sit out parked (decode)
     n_acc: Any = None         # device [B] (verify): accepted drafts/slot
     exits: Any = None         # device [k, B, 2] (decode, looped decoder):
     #                           the pass whose state the head read and the
@@ -600,6 +604,11 @@ class InferenceEngine:
         # their steps (_inflight_steps)
         self._deferred_windows: list[_Window] = []
         self._inflight_steps = 0
+        # ... and, a lane, the steps it was GIVEN in them (a lane the
+        # program parks inside a window has fewer than the window's): what
+        # a lane's number for the next window leaves out
+        # (``WindowScheduler.lane_steps``)
+        self._lane_inflight = np.zeros((b,), dtype=np.int32)
         # ---- speculative decoding (ISSUE 5) ----
         # verify-graph length buckets (each is one compiled graph). A
         # single full-size bucket: on the paged path the verify cost is
@@ -612,6 +621,10 @@ class InferenceEngine:
         self._spec_disabled_windows = 0
         self._stats = {"active_streams": 0, "queued": 0, "tokens_generated": 0,
                        "decode_steps": 0, "admit_dispatches": 0,
+                       # lane-steps of dispatched decode windows that a lane
+                       # live at dispatch sat out, parked by the program past
+                       # its budget or cache room (ISSUE 65)
+                       "decode_lane_steps_parked": 0,
                        # prefill chunks admitted, and those of them that
                        # went through the wide group forward
                        "admit_chunks": 0, "admit_chunks_grouped": 0,
@@ -983,8 +996,8 @@ class InferenceEngine:
     def _admission_can_proceed(self) -> bool:
         return self.scheduler.admission_can_proceed()
 
-    def _pick_steps(self) -> int:
-        return self.scheduler.pick_steps()
+    def _pick_steps(self, left=None) -> int:
+        return self.scheduler.pick_steps(left)
 
     def _spec_room_len(self) -> int:
         return self.scheduler.spec_room_len()
@@ -1014,9 +1027,11 @@ class InferenceEngine:
         self.kv_cache.update(pool)
 
     def _worst_case_tokens(self, req: _Request) -> int:
-        # prompt + full generation budget + in-flight overshoot slack,
-        # clamped to the cache: positions never exceed max_seq_len, so a
-        # near-max prompt must not over-reserve itself into rejection.
+        # prompt + full generation budget + slack, clamped to the cache:
+        # positions never exceed max_seq_len, so a near-max prompt must not
+        # over-reserve itself into rejection. A decode window writes no row
+        # past ``prompt + max_new_tokens`` (the program parks a lane at its
+        # budget: ISSUE 65), so for classic decode the slack is only spare.
         # With speculation on, up to TWO verify windows can be in flight
         # past the budget check (the steady-state overlap window plus the
         # one being dispatched), so the slack covers 2·(1+spec_len).
@@ -1043,23 +1058,28 @@ class InferenceEngine:
         st["kv_pages_over_reservation"] = max(
             st["kv_pages_over_reservation"], used - self.allocator.reserved)
 
-    def _note_decode_entries(self, k: int) -> None:
-        """The summary-cache counters of one decode window of ``k`` steps
-        about to be dispatched, from the host's mirror of the lengths: step
-        ``i`` of a lane that holds ``n`` tokens writes position ``n + i``
-        and attends ``kv_entries(n + i + 1)`` entries."""
+    def _note_decode_entries(self, k: int, given) -> None:
+        """The latent and summary-cache counters of one decode window of
+        ``k`` steps about to be dispatched, from the host's mirror of the
+        lengths: step ``i`` of a lane that holds ``n`` tokens writes position
+        ``n + i`` and attends ``kv_entries(n + i + 1)`` entries — in the
+        ``given[lane]`` steps it runs; the steps a lane sits out parked read
+        nothing, and are counted as nothing."""
         cfg = self.cfg
+        if not (cfg.mla_latent or cfg.attn_window):
+            return
+        on = self.active
+        lanes = self._host_len[on] + self._lane_inflight[on]
+        pos = lanes[:, None] + np.arange(k)[None, :]      # written positions
+        run = np.arange(k)[None, :] < given[on][:, None]  # not parked
         if cfg.mla_latent:
             # step ``i`` of a lane that holds ``n`` tokens attends ``n + i +
             # 1`` latent rows in every MLA layer
-            lanes = self._host_len[self.active] + self._inflight_steps
-            self._stats["latent_rows_attended"] += int(
-                (lanes[:, None] + np.arange(k)[None, :] + 1).sum())
+            self._stats["latent_rows_attended"] += int((pos + 1)[run].sum())
             self._stats["latent_decode_steps"] += k
         if not cfg.attn_window:
             return
-        lanes = self._host_len[self.active] + self._inflight_steps
-        pos = lanes[:, None] + np.arange(k)[None, :]      # written positions
+        pos = pos[run]
         windows = pos // cfg.attn_window                  # closed before it
         st = self._stats
         st["windows_closed_decode"] += int(
@@ -1222,12 +1242,13 @@ class InferenceEngine:
                         cache["k"], cache["v"], 0)
                 timings[f"dsplice_{bucket}_s"] = _time.perf_counter() - t0
         inactive = jnp.zeros((self.ecfg.max_batch,), bool)
+        no_steps = jnp.zeros((self.ecfg.max_batch,), jnp.int32)
         for k in self.ecfg.decode_steps:
             t0 = _time.perf_counter()
             (self.last_token, self.kv_cache, self.cache_len, self._rng,
              toks, *_exits) = self._decode_k(k)(
                 self.params, self.kv_cache, self.last_token,
-                self.cache_len, inactive, self._rng)
+                self.cache_len, no_steps, self._rng)
             np.asarray(jax.device_get(toks[-1, :4]))
             timings[f"decode_k{k}_s"] = _time.perf_counter() - t0
         for s in self._spec_lens:
@@ -1876,7 +1897,9 @@ class InferenceEngine:
                 # and let streaming consumers drain
                 with phase("engine.window.dispatch", totals, kind="decode",
                            pick="interleave") as ph:
-                    ph.set(k=self._interleave_decode_window())
+                    k = self._interleave_decode_window()
+                    ph.set(k=k, parked=self._deferred_windows[-1].parked
+                           if k else 0)
                 with phase("engine.yield", totals):
                     await asyncio.sleep(0)
         self._scratch = scratch
@@ -2242,6 +2265,7 @@ class InferenceEngine:
                      if r is not None and win.mask[s]}
             rec = {"k": win.k, "pick": win.pick,
                    "batch": int(win.mask.sum()),
+                   "parked": win.parked,
                    "slots": slots, "tokens": delivered,
                    "wait_s": round(max(t_host0 - win.t_mono, 0.0), 6),
                    "host_s": round(max(now_m - t_host0, 0.0), 6),
@@ -2281,13 +2305,14 @@ class InferenceEngine:
                     req.dec_anchor = (win.t_wall, win.t_mono)
                     req.dec = {"request_id": req.request_id, "windows": 0,
                                "k1_windows": 0, "tokens": 0,
-                               "interleaved_windows": 0}
+                               "interleaved_windows": 0, "parked_steps": 0}
                     if self.cfg.looped:
                         req.dec["loop_steps"] = self.cfg.loop_steps
                 req.dec["windows"] += 1
                 req.dec["k1_windows"] += win.k == 1
                 req.dec["tokens"] += n_tok
                 req.dec["interleaved_windows"] += win.pick == "interleave"
+                req.dec["parked_steps"] += win.k - int(win.steps[slot])
             if req.gap_last and req.done.is_set():
                 # retired inside this window's fan-out (or, cancelled,
                 # before it): its decode interval is complete
@@ -2543,57 +2568,25 @@ class InferenceEngine:
 
     def _interleave_decode_window(self) -> int:
         """Dispatch one decode window for the active batch WITHOUT syncing
-        (results processed after the admission sync). Room accounting must
-        include steps already in flight from earlier interleaved windows.
-        Returns the window's steps (0: none dispatched)."""
+        (results processed after the admission sync). Returns the window's
+        steps (0: none dispatched)."""
         if not self.active.any():
             return 0
         ks = self.ecfg.decode_steps
         want = ks[1] if len(ks) > 1 else ks[0]
-        # total in-flight overshoot must stay within the max(decode_steps)
-        # +1 slack _worst_case_tokens reserved per slot — past that, block
-        # growth could eat another slot's reservation
+        # the steps in flight inside one admission stay within
+        # max(decode_steps): how many interleave is the trade between this
+        # admission's first token and the running streams' gap. The lanes'
+        # numbers are the classic window's (``WindowScheduler.lane_steps``):
+        # the lane with the most left decides, the program parks the rest
         slack = max(ks) - self._inflight_steps
-        limit = min(want, slack)
-        for slot in range(self.ecfg.max_batch):
-            req = self.slot_req[slot]
-            if req is None or not self.active[slot]:
-                continue
-            # budget is SOFT (same rationale as _pick_steps: overshoot
-            # tokens are discarded host-side at retire, and one nearly-
-            # done stream must not stall interleaving for all the others);
-            # cache room is HARD
-            remaining = (req.max_new_tokens - len(req.generated)
-                         - self._inflight_steps)
-            room = (self.ecfg.max_seq_len - 1 - int(self._host_len[slot])
-                    - self._inflight_steps)
-            limit = min(limit, max(1, remaining), max(0, room))
-        k = 0
-        for cand in ks:
-            if cand <= limit:
-                k = max(k, cand)
+        left = self.scheduler.lane_steps()
+        k = self.scheduler.bucket_within(
+            min(want, slack, int(left.max(initial=0))))
         if k <= 0:
-            return 0            # out of cache room or reservation slack
-        for slot in range(self.ecfg.max_batch):
-            if self.active[slot]:
-                self._ensure_slot_blocks(
-                    slot, min(int(self._host_len[slot])
-                              + self._inflight_steps + k + 1,
-                              self.ecfg.max_seq_len))
-        self._note_decode_entries(k)
-        # the window's own copy: the device may read the host's buffer
-        # where it lies (the CPU backend), after ``active`` has moved on
-        mask = self.active.copy()
-        (self.last_token, self.kv_cache, self.cache_len, self._rng,
-         toks, *exits) = self._decode_k(k)(
-            self.params, self.kv_cache, self.last_token, self.cache_len,
-            jnp.asarray(mask), self._rng)
+            return 0      # no slack, or every lane's last steps are in flight
         self._pick_reason = "interleave"
-        self._deferred_windows.append(self._obs_stamp_window(
-            _Window(kind="decode", k=k, toks=toks, mask=mask,
-                    reqs=tuple(self.slot_req), **self._beside_tokens(exits))))
-        self._inflight_steps += k
-        self._stats["decode_steps"] += k
+        self._deferred_windows.append(self._run_decode_window(k, left))
         self._stats["admit_interleaved_windows"] += 1
         return k
 
@@ -2870,7 +2863,8 @@ class InferenceEngine:
                 win = self._dispatch_window()
                 if win is not None:
                     ph.set(kind=win.kind, k=win.k, pick=win.pick,
-                           batch=int(win.mask.sum()))
+                           batch=int(win.mask.sum()),
+                           parked=win.parked)
             if win is not None:
                 self._deferred_windows.append(win)
                 # steady-state overlap (ISSUE 5 satellite): keep exactly
@@ -2905,31 +2899,60 @@ class InferenceEngine:
                 return self._dispatch_verify(s, drafts, n_real)
             # nothing to propose anywhere: a verify pass would be a pure
             # waste — fall through to a classic window
-        k = self._pick_steps()
+        left = self.scheduler.lane_steps()
+        if not left.any() and self._deferred_windows:
+            # every live lane's last steps are in flight: there is nothing
+            # to run until their fan-out has retired the lanes
+            self._drain_windows()
+            if not self.active.any():
+                return None
+            left = self.scheduler.lane_steps()
+        return self._run_decode_window(self._pick_steps(left), left)
+
+    def _run_decode_window(self, k: int, left) -> _Window:
+        """Dispatch ONE decode window of ``k`` steps for the active batch,
+        without syncing: lane ``b`` runs ``min(k, left[b])`` of them
+        (``left``: ``WindowScheduler.lane_steps``) and the program parks it
+        for the rest."""
+        given = np.minimum(left, k).astype(np.int32)
         if self.paged:
-            # lazy physical growth: each active slot gets blocks for this
-            # window's writes (covered by its reservation). Clamp to
-            # max_seq_len: _pick_steps already bounds in-window positions
-            # to the cache, and a near-full slot must not demand a 17th
-            # block of a 16-wide table.
-            for slot in range(self.ecfg.max_batch):
-                if self.active[slot]:
-                    self._ensure_slot_blocks(
-                        slot, min(int(self._host_len[slot])
-                                  + self._inflight_steps + k + 1,
-                                  self.ecfg.max_seq_len))
-        self._note_decode_entries(k)
-        mask = self.active.copy()       # the window's own, as above
+            # lazy physical growth: each active slot gets blocks for the
+            # rows it writes in this window (covered by its reservation) —
+            # its steps' own, and the one a parked lane's step lands on
+            # past them. Clamp to max_seq_len: a lane's number bounds its
+            # positions to the cache, and a near-full slot must not demand
+            # a 17th block of a 16-wide table.
+            for slot in map(int, np.flatnonzero(self.active)):
+                self._ensure_slot_blocks(
+                    slot, min(int(self._host_len[slot])
+                              + int(self._lane_inflight[slot])
+                              + int(given[slot]) + 1,
+                              self.ecfg.max_seq_len))
+        self._note_decode_entries(k, given)
+        # the window's own copies: the device may read the host's buffers
+        # where they lie (the CPU backend), after ``active`` has moved on
+        mask = self.active.copy()
         (self.last_token, self.kv_cache,
          self.cache_len, self._rng, toks, *exits) = self._decode_k(k)(
             self.params, self.kv_cache, self.last_token,
-            self.cache_len, jnp.asarray(mask), self._rng)
+            self.cache_len, jnp.asarray(given), self._rng)
         self._stats["decode_steps"] += k
-        self._inflight_steps += k
-        return self._obs_stamp_window(
-            _Window(kind="decode", k=k, toks=toks,
-                    mask=mask, reqs=tuple(self.slot_req),
-                    **self._beside_tokens(exits)))
+        parked = int((k - given[mask]).sum())
+        self._stats["decode_lane_steps_parked"] += parked
+        return self._window_in(self._obs_stamp_window(
+            _Window(kind="decode", k=k, toks=toks, mask=mask, steps=given,
+                    parked=parked, reqs=tuple(self.slot_req),
+                    **self._beside_tokens(exits))))
+
+    def _window_in(self, win: _Window) -> _Window:
+        """A dispatched window's steps are in flight until its fan-out."""
+        self._inflight_steps += win.k
+        self._lane_inflight += win.steps
+        return win
+
+    def _window_out(self, win: _Window) -> None:
+        self._inflight_steps -= win.k
+        self._lane_inflight -= win.steps
 
     def _dispatch_verify(self, s: int, drafts, n_real) -> _Window:
         t = s + 1
@@ -2946,12 +2969,13 @@ class InferenceEngine:
             jnp.asarray(drafts), self.cache_len, jnp.asarray(mask),
             self._rng)
         self._stats["spec_windows"] += 1
-        self._inflight_steps += t
         self._pick_reason = "spec"
-        return self._obs_stamp_window(
+        # (a lane advances by 1 .. t: until the fan-out, count them all)
+        return self._window_in(self._obs_stamp_window(
             _Window(kind="verify", k=t, toks=out, n_acc=n_acc,
-                    mask=mask, reqs=tuple(self.slot_req),
-                    spec_len=s, n_real=n_real))
+                    mask=mask, steps=(mask * t).astype(np.int32),
+                    reqs=tuple(self.slot_req),
+                    spec_len=s, n_real=n_real)))
 
     def _drain_windows(self) -> None:
         """Host-process every in-flight window. ONE transfer for all of
@@ -2964,7 +2988,7 @@ class InferenceEngine:
             # tpu9: noqa[JAX001] intended sync point: the ONE batched window-boundary device_get (PR 5); N sequential reads would pay N round-trips
             payload = jax.device_get([self._window_arrays(w) for w in wins])
         for w, arrs in zip(wins, payload):
-            self._inflight_steps -= w.k
+            self._window_out(w)
             self._process_window_host(
                 w, np.asarray(arrs[0]),  # tpu9: noqa[JAX001] arrs are already host memory (device_get above); asarray is a no-copy view
                 np.asarray(arrs[1]) if len(arrs) > 1 else None)  # tpu9: noqa[JAX001] host memory, no device sync
@@ -2992,7 +3016,7 @@ class InferenceEngine:
         with phase("engine.window.sync", self.host_phases, windows=1):
             # tpu9: noqa[JAX001] intended sync point: the window's compute is DONE (one-window-overlap drains here); this ONE batched read is the host fan-out
             arrs = jax.device_get(self._window_arrays(win))
-        self._inflight_steps -= win.k
+        self._window_out(win)
         self._process_window_host(
             win, np.asarray(arrs[0]),  # tpu9: noqa[JAX001] host memory after device_get, no sync
             np.asarray(arrs[1]) if len(arrs) > 1 else None)  # tpu9: noqa[JAX001] host memory after device_get, no sync
@@ -3070,9 +3094,13 @@ class InferenceEngine:
                 if st is not None and self._slot_live(win, slot):
                     shadow[slot] = st.proposer.propose(m)
         delivered: list[list[int]] = [[] for _ in range(self.ecfg.max_batch)]
+        given = list(map(int, win.steps))
         for step in range(win.k):
             for slot in range(self.ecfg.max_batch):
-                if not self._slot_live(win, slot):
+                # (a lane's tokens past the steps it was given are a parked
+                # lane's noise; one that ran to its budget retires at its
+                # last token anyway)
+                if step >= given[slot] or not self._slot_live(win, slot):
                     continue
                 if self.slot_req[slot].cancelled:
                     # client gone mid-stream: stop decoding into a queue
@@ -3123,7 +3151,9 @@ class InferenceEngine:
         top_k], the experts (global ids) the token each lane fed in chose
         at each step. A layer pattern's request keeps those of the tokens it
         was delivered (step j fed in the token before the j-th delivered);
-        the counters are over the lanes live at dispatch, every step."""
+        the counters are over the lanes live at dispatch, in the steps each
+        ran: a parked lane's row enters no expert's list, so it counts as
+        no pick."""
         if self._keeps_routing:
             for slot, n in win.delivered.items():
                 win.reqs[slot].routed.append(picks[:n, slot].copy())
@@ -3131,7 +3161,8 @@ class InferenceEngine:
         k, n, layers, _ = live.shape
         e = self.cfg.n_experts
         local = live - self.cfg.moe_held_first
-        held = (local >= 0) & (local < e)
+        ran = np.arange(k)[:, None] < win.steps[win.mask][None, :]
+        held = (local >= 0) & (local < e) & ran[:, :, None, None]
         # one cell a (step, layer, held expert): how many picks it took
         cell = (np.arange(k)[:, None, None, None] * layers
                 + np.arange(layers)[None, None, :, None]) * e + local
@@ -3140,7 +3171,7 @@ class InferenceEngine:
         st["moe_local_picks"] += int(held.sum())
         st["moe_held_touched"] += int((hits > 0).sum())
         st["moe_step_layers"] += k * layers
-        st["moe_token_layers"] += k * layers * n
+        st["moe_token_layers"] += layers * int(ran.sum())
         self._held_pick_hist += hits.reshape(k * layers, e).sum(0)
 
     def _process_verify_host(self, win: _Window, out, n_acc) -> None:

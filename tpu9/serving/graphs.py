@@ -208,20 +208,37 @@ class GraphFactory:
     # -- decode window -------------------------------------------------------
 
     def build_decode(self, k: int = 1):
+        """The decode window of ``k`` steps. ``steps_left`` int32 [B] says how
+        many of them each lane may run (0: an idle lane): lane ``b`` is live
+        in step ``j`` iff ``steps_left[b] > j``, and a lane past its number
+        is PARKED — an idle lane from that step on, whatever the window's
+        size: its length stands, no state of its own advances, no expert
+        enters the held list for it, and its tokens are noise the host never
+        reads. The host's budget and cache room end HERE (``WindowScheduler.
+        lane_steps``), so no lane's ending shrinks the others' window."""
         cfg, ecfg, policy = self.cfg, self.ecfg, self.policy
 
         def one_step(params, kv_cache, last_token, cache_len, active, rng,
                      vectors):
             positions = cache_len[:, None]          # next position per slot
+            # an idle lane attends to nothing: its length is 0, so the paged
+            # kernel walks no page for it (its token, like its write to the
+            # trash block, is discarded). A parked lane's table is real: its
+            # one row a step lands at its own next position, past its last
+            # token, which every kernel masks by length and no page another
+            # table shares holds (a shared page is full of a prompt's rows)
+            live = active.astype(jnp.int32)
+            table = None
             if cfg.attn_window:
                 # a lane whose next token opens a window: the window it
                 # closed becomes its summaries first
                 kv_cache = self.traced_summarise_pool(vectors, kv_cache,
                                                       cache_len, active)
-            # an idle lane attends to nothing: its length is 0, so the paged
-            # kernel walks no page for it (its token, like its write to the
-            # trash block, is discarded)
-            live = active.astype(jnp.int32)
+                # ... which a parked lane's did not, so the entry of its next
+                # position lies INSIDE the closed window's rows: its row goes
+                # through a table of trash blocks (block 0) instead
+                table = kv_cache["table"]
+                kv_cache = dict(kv_cache, table=table * live[:, None])
             # a looped decoder also says which pass the head read and how
             # many passes ran: ``exits`` is ``(exit_info [B, 1, 2],)`` for it
             # and empty for a plain one
@@ -243,6 +260,8 @@ class GraphFactory:
                     params, last_token, cfg, positions=positions,
                     kv_cache=kv_cache, cache_len=(cache_len + 1) * live,
                     decode=True, mesh=policy.mesh, return_exit=cfg.looped)
+            if table is not None:
+                kv_cache = dict(kv_cache, table=table)
             rng, sub = jax.random.split(rng)
             next_tok = sample_logits(logits[:, -1], sub,
                                      temperature=ecfg.temperature,
@@ -253,21 +272,21 @@ class GraphFactory:
             return (next_tok[:, None].astype(jnp.int32), kv_cache, new_len,
                     rng, exits)
 
-        def decode(params, kv_cache, last_token, cache_len, active, rng):
+        def decode(params, kv_cache, last_token, cache_len, steps_left, rng):
             # the layers' summary vectors, stacked once a program
             vectors = self._summary_vectors(params) if cfg.attn_window \
                 else None
 
-            def body(carry, _):
+            def body(carry, step):
                 last, kv, clen, r = carry
-                last, kv, clen, r, exits = one_step(params, kv, last, clen,
-                                                    active, r, vectors)
+                last, kv, clen, r, exits = one_step(
+                    params, kv, last, clen, steps_left > step, r, vectors)
                 return (last, kv, clen, r), \
                     (last[:, 0], *(e[:, 0] for e in exits))
 
             (last, kv_cache, cache_len, rng), per_step = jax.lax.scan(
-                body, (last_token, kv_cache, cache_len, rng), None,
-                length=k)
+                body, (last_token, kv_cache, cache_len, rng),
+                jnp.arange(k, dtype=jnp.int32))
             # toks [k, B] (and a looped decoder's exit pass and pass count,
             # [k, B, 2], or an expert decoder's chosen experts, [k, B, expert
             # layers, top_k]): the host consumes the whole window in one sync
@@ -725,7 +744,7 @@ class GraphFactory:
             yield (("decode", k), self.decode_k(k),
                    (pspec, kv_spec, jax.ShapeDtypeStruct((b, 1), i32),
                     jax.ShapeDtypeStruct((b,), i32),
-                    jax.ShapeDtypeStruct((b,), jnp.bool_),
+                    jax.ShapeDtypeStruct((b,), i32),
                     arng))
         for s in spec_lens:
             yield (("verify", s), self.verify_fn(s),

@@ -1,9 +1,10 @@
 """Window scheduling for the serving engine (ISSUE 9 engine split).
 
 The engine split's scheduling third: every "what should the next device
-dispatch be" decision — decode-window size (K), speculative-verify
-eligibility and the acceptance-EWMA gate, and the admission-can-proceed
-check that shrinks windows when a queued request could actually land.
+dispatch be" decision — how many steps each lane may still run and the
+decode-window size (K) the lane with the most left can fill, speculative-
+verify eligibility and the acceptance-EWMA gate, and the admission-can-
+proceed check that shrinks windows when a queued request could actually land.
 Pure host arithmetic over the engine's scheduling state (host length
 mirrors, budgets, in-flight step counts); it never touches device arrays
 or dispatches anything itself, so it is identical on one chip and on a
@@ -15,6 +16,8 @@ responsibility, not an RPC boundary) and records WHY it chose a window in
 """
 
 from __future__ import annotations
+
+import numpy as np
 
 
 class WindowScheduler:
@@ -44,35 +47,53 @@ class WindowScheduler:
                 head = q[0]
         return head is not None and e._room_for(head)
 
-    def pick_steps(self) -> int:
-        """Largest decode-window bucket every active slot can absorb: no
-        slot may outrun its max_new_tokens budget past the window (tokens
-        beyond a stop are discarded host-side, so only bounded compute is
-        wasted) nor its cache room. Budget/room subtract steps already in
-        flight (the steady-state overlap window). Admission latency wins
-        when an admission could actually proceed: K=1."""
+    def lane_steps(self) -> np.ndarray:
+        """int32 [max_batch]: the decode steps each lane may still be GIVEN —
+        what is left of its ``max_new_tokens`` budget and of its cache room,
+        whichever is less, without the steps it already has in flight (the
+        steady-state overlap window, a window interleaved inside an
+        admission); 0 for an idle lane. The ONE per-lane number of the
+        window paths: the decode program takes it as it is and stops the
+        lane itself after that many steps (``GraphFactory.build_decode``),
+        so budget and room are hard on the device and neither bounds the
+        window's size."""
+        e = self.engine
+        left = np.zeros((e.ecfg.max_batch,), np.int32)
+        for slot in np.flatnonzero(e.active):
+            req = e.slot_req[slot]
+            budget = req.max_new_tokens - len(req.generated)
+            room = e.ecfg.max_seq_len - 1 - int(e._host_len[slot])
+            left[slot] = max(0, min(budget, room)
+                             - int(e._lane_inflight[slot]))
+        return left
+
+    def pick_steps(self, left=None) -> int:
+        """The decode window for lanes with ``left`` steps to run
+        (:meth:`lane_steps`): the largest bucket that the lane with the MOST
+        left can fill — a lane with less is parked by the program when its
+        number is up, so one nearly-done stream costs the others nothing,
+        and no window runs a step in which every lane is parked — else the
+        smallest bucket. Admission latency wins when an admission could
+        actually proceed: the smallest bucket."""
         e = self.engine
         if self.admission_can_proceed():
             # shrink to the smallest window so the waiting head admits
             # sooner — the flight recorder's "why was K small" answer
             e._pick_reason = "admission"
             return e.ecfg.decode_steps[0]
-        limit = max(e.ecfg.decode_steps)
-        for slot in range(e.ecfg.max_batch):
-            req = e.slot_req[slot]
-            if req is None or not e.active[slot]:
-                continue
-            remaining = (req.max_new_tokens - len(req.generated)
-                         - e._inflight_steps)
-            room = (e.ecfg.max_seq_len - 1 - e._host_len[slot]
-                    - e._inflight_steps)
-            limit = min(limit, max(1, remaining), max(1, room))
-        e._pick_reason = ("max" if limit >= max(e.ecfg.decode_steps)
+        if left is None:
+            left = self.lane_steps()
+        k = self.bucket_within(int(left.max(initial=0))) \
+            or e.ecfg.decode_steps[0]
+        # "budget": the lane with the most left could not fill the largest
+        e._pick_reason = ("max" if k >= max(e.ecfg.decode_steps)
                           else "budget")
-        for k in reversed(e.ecfg.decode_steps):
-            if k <= limit:
-                return k
-        return e.ecfg.decode_steps[0]
+        return k
+
+    def bucket_within(self, limit: int) -> int:
+        """The largest decode bucket of at most ``limit`` steps; 0: none."""
+        return max((k for k in self.engine.ecfg.decode_steps if k <= limit),
+                   default=0)
 
     def spec_room_len(self) -> int:
         """Largest spec bucket the batch has ROOM for, or 0 when
